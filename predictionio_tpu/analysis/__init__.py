@@ -5,8 +5,7 @@ Rule families (catalog with incidents: ``docs/static_analysis.md``;
 ``pio check --explain RULE`` prints any entry):
 
 - **J-series** (``rules_jax``): the jax version-drift and tracing
-  invariants -- drift-shim policy (J001), legacy donation miscompile
-  (J002), control flow on tracers (J003), host sync inside jit (J004),
+  invariants -- drift-shim policy (J001), control flow on tracers (J003), host sync inside jit (J004),
   the 0.4.37 concat+reshard GSPMD miscompile (J005), loop-invariant
   h2d transfers (J006).
 - **C-series** (``rules_concurrency``): built on the phase-2 whole-

@@ -26,7 +26,7 @@ What it tracks, package-wide:
   bound mesh, and in/out spec axis strings;
 - **jit/pjit placement**: ``in_shardings``/``out_shardings``/
   ``donate_argnums``/``donate_argnames`` (callee parameter names resolved
-  the way J002 does, through the ``jit(make_step(...))`` factory form);
+  through the ``jit(make_step(...))`` factory form);
 - **collectives**: ``psum`` / ``psum_scatter`` / ``all_gather`` /
   ``axis_index`` / ... with their STRING-LITERAL axis names (variable
   axis names are honestly unknown and stay out of the domain).
@@ -160,7 +160,6 @@ class DonatedCallable:
     name: str                   # the dotted callee name ("step", "self._step")
     jit_line: int
     positions: tuple            # donated positional indices into the CALL args
-    gated: bool                 # IS_LEGACY_JAX-gated donation (the fix shape)
 
 
 @dataclass
@@ -722,26 +721,26 @@ class MeshFlow:
         """``x = jit(body, donate_argnums=...)`` / ``self.attr = jit(...)``
         assignments visible to this function: call-site positions that
         hand their buffer over. donate_argnames resolves against the
-        jitted callee's parameters (J002's resolution, via the graph)."""
+        jitted callee's parameters, via the graph."""
         don = self._donation_of(fi, node.value)
         if don is None:
             return
-        positions, gated = don
+        positions = don
         for t in node.targets:
             d = dotted(t)
             if d is None:
                 continue
-            rec = DonatedCallable(d, node.value.lineno, positions, gated)
+            rec = DonatedCallable(d, node.value.lineno, positions)
             self.donations.setdefault(fi.key, []).append(rec)
             # class-attr donations are callable from sibling methods too
             if d.startswith("self.") and fi.cls is not None:
                 key = (fi.path, fi.cls, "__donated__")
                 self.attr_vals.setdefault(key, set()).add(
-                    (d, node.value.lineno, positions, gated)
+                    (d, node.value.lineno, positions)
                 )
 
     def _donation_of(self, fi, call: ast.Call):
-        """(donated positions, gated?) of a jit call, else None."""
+        """Donated positions of a jit call, else None."""
         if _last(call_name(call)) not in _JIT_LAST:
             return None
         params: list = []
@@ -750,15 +749,11 @@ class MeshFlow:
                 params = target.params()
                 break
         positions: list = []
-        gated = False
         for kwname in ("donate_argnums", "donate_argnames"):
             kw = keyword(call, kwname)
             if kw is None:
                 continue
             value = kw.value
-            if isinstance(value, ast.IfExp) and self._legacy_gated(value.test):
-                gated = True
-                continue
             if kwname == "donate_argnums":
                 for c in ast.walk(value):
                     if isinstance(c, ast.Constant) and isinstance(c.value, int):
@@ -768,18 +763,9 @@ class MeshFlow:
                     if isinstance(c, ast.Constant) and isinstance(c.value, str):
                         if c.value in params:
                             positions.append(params.index(c.value))
-        if not positions and not gated:
+        if not positions:
             return None
-        return tuple(sorted(set(positions))), gated
-
-    @staticmethod
-    def _legacy_gated(test: ast.AST) -> bool:
-        for n in ast.walk(test):
-            if isinstance(n, ast.Name) and n.id == "IS_LEGACY_JAX":
-                return True
-            if isinstance(n, ast.Attribute) and n.attr == "IS_LEGACY_JAX":
-                return True
-        return False
+        return tuple(sorted(set(positions)))
 
     def donated_callables(self, fi) -> list:
         """DonatedCallables callable from ``fi``: its own assignments plus
@@ -790,9 +776,9 @@ class MeshFlow:
                 (fi.path, fi.cls, "__donated__"), ()
             ):
                 if isinstance(rec, tuple):
-                    name, line, positions, gated = rec
+                    name, line, positions = rec
                     if not any(d.name == name for d in out):
-                        out.append(DonatedCallable(name, line, positions, gated))
+                        out.append(DonatedCallable(name, line, positions))
         return out
 
     # -- context propagation --------------------------------------------------

@@ -303,16 +303,13 @@ class RuleS004(PackageRule):
     back into the call without rebinding it) -- the buffer was handed to
     XLA and may already hold the output. Rebinding the name from the
     call's result (``params, opt = step(params, opt)``) is the intended
-    shape and stays silent, as is the ``(0,) if IS_LEGACY_JAX else
-    (0, 1)`` gated form (the J002 fix shape: the gate exists precisely
-    to keep donation correct per jax version).
+    shape and stays silent.
 
-    Incident: the tp-sharded adam-state donation bug (PR 4/J002's
-    sibling): on legacy jax the donated opt-state pytree paired wrong
-    buffers inside XLA, and the debugging tail chased a caller that
-    logged ``opt_state`` AFTER the donated step -- a read of a buffer
-    that no longer belonged to it, returning plausible garbage that
-    masked the real corruption for days."""
+    Incident: while chasing a corrupted tp-sharded adam state (PR 4), the
+    debugging tail followed a caller that logged ``opt_state`` AFTER the
+    donated step -- a read of a buffer that no longer belonged to it,
+    returning plausible garbage that masked the real corruption for
+    days."""
 
     rule_id = "S004"
     severity = "error"
@@ -323,7 +320,7 @@ class RuleS004(PackageRule):
             flow.graph.functions.values(), key=lambda f: f.key
         ):
             donated = {
-                d.name: d for d in flow.donated_callables(fi) if not d.gated
+                d.name: d for d in flow.donated_callables(fi)
             }
             if not donated:
                 continue

@@ -20,7 +20,6 @@ from predictionio_tpu.models._als_common import (
 from predictionio_tpu.models.ncf.kernel import (
     make_all_items_scorer,
     make_batch_scorer,
-    reference_score_all_items,
 )
 from predictionio_tpu.models.ncf.model import (
     NCFConfig,
@@ -96,45 +95,6 @@ class NCFModel:
         state.setdefault("_batch_scorer", None)
         self.__dict__.update(state)
 
-    def _pallas_with_fallback(self):
-        """Pallas all-items scorer that degrades to the XLA reference path.
-
-        A model trained with usePallas=True can deploy onto a host whose
-        backend cannot lower the kernel (CPU fallback after an accelerator
-        outage). Build failures and first-call lowering failures both log
-        once and permanently swap in reference_score_all_items -- a
-        working slower path beats a serving endpoint that 500s forever.
-        """
-        import logging
-
-        n = len(self.item_ids)
-        log = logging.getLogger("pio.ncf")
-        try:
-            fast = make_all_items_scorer(self.params, n, interpret=False)
-        except Exception as exc:
-            log.warning(
-                "Pallas scorer build failed (%s); serving via the XLA "
-                "reference path", exc,
-            )
-            return lambda u: reference_score_all_items(self.params, u, n)
-        def score(user_idx):
-            nonlocal fast
-            if fast is not None:
-                try:
-                    return fast(user_idx)
-                except Exception as exc:
-                    # drop the dead scorer so its device-resident tables
-                    # (full embedding + MLP uploads) are freed, not pinned
-                    # for the model's serving lifetime on a degraded host
-                    fast = None
-                    log.warning(
-                        "Pallas scorer failed at call time (%s); falling "
-                        "back to the XLA reference path permanently", exc,
-                    )
-            return reference_score_all_items(self.params, user_idx, n)
-
-        return score
-
     def scorer(self):
         # the query server is a ThreadingHTTPServer: concurrent first
         # queries must not each upload the tables and compile the kernel
@@ -144,36 +104,25 @@ class NCFModel:
             with _SCORER_BUILD_LOCK:
                 if self._scorer is None:
                     if self.use_pallas:
-                        self._scorer = self._pallas_with_fallback()
+                        # the kernel or nothing: a build or lowering
+                        # failure fails the deploy (warm_up), it never
+                        # swaps in another path. Interpreted only where
+                        # every kernel here is: off the TPU.
+                        import jax
+
+                        self._scorer = make_all_items_scorer(
+                            self.params, len(self.item_ids),
+                            interpret=jax.devices()[0].platform != "tpu",
+                        )
                     else:
                         # route single queries through the SAME jitted
                         # program family the micro-batched path uses
                         # (bucket of 1): batched and unbatched serving
-                        # answers stay numerically identical, and a lone
-                        # query still beats the numpy reference walk
-                        try:
-                            batch = self.batch_scorer()
-                            self._scorer = lambda u: batch(
-                                np.asarray([u], np.int32)
-                            )[0]
-                        except Exception:
-                            # the fallback serves, but batched and single
-                            # answers are no longer the same program --
-                            # say so, or the identity loss is undebuggable
-                            import logging
-
-                            logging.getLogger("pio.ncf").warning(
-                                "batch scorer build failed; single-query "
-                                "serving falls back to the numpy reference "
-                                "path (batched/unbatched responses may "
-                                "differ at float precision)", exc_info=True,
-                            )
-                            n = len(self.item_ids)
-                            self._scorer = (
-                                lambda u: reference_score_all_items(
-                                    self.params, u, n
-                                )
-                            )
+                        # answers stay numerically identical
+                        batch = self.batch_scorer()
+                        self._scorer = lambda u: batch(
+                            np.asarray([u], np.int32)
+                        )[0]
         return self._scorer
 
     def batch_scorer(self):
@@ -243,7 +192,7 @@ class NCFAlgorithm(TPUAlgorithm):
             item_ids=data.item_ids,
             item_index={iid: j for j, iid in enumerate(data.item_ids)},
             seen=seen,
-            use_pallas=p.get_or("usePallas", backend not in ("cpu",)),
+            use_pallas=p.get_or("usePallas", backend == "tpu"),
             seen_mode=seen_mode,
             app_name=getattr(data, "app_name", ""),
             channel_name=getattr(data, "channel_name", None),
@@ -254,8 +203,10 @@ class NCFAlgorithm(TPUAlgorithm):
         """Build both serving scorers at deploy (tables upload + kernel
         compile), not on the first unlucky query: /queries.json serves
         through scorer(), the batch-predict workflow through
-        batch_scorer() -- prepare_deploy precedes both."""
-        model.scorer()
+        batch_scorer() -- prepare_deploy precedes both. The single-query
+        scorer is called once, because jit compiles on the first call: a
+        kernel the device refuses fails the deploy here."""
+        model.scorer()(0)
         model.batch_scorer()
 
     @staticmethod

@@ -24,9 +24,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from predictionio_tpu.parallel.mesh import (
     check_steps_ran,
     fetch_global,
+    one_step_in_flight,
     put_global,
 )
-from predictionio_tpu.utils.jax_compat import IS_LEGACY_JAX, broadcast_one_to_all
+from predictionio_tpu.utils.jax_compat import broadcast_one_to_all
 
 
 @dataclass
@@ -132,11 +133,7 @@ def train_ncf(
             {"user": data_shard, "item": data_shard, "label": data_shard},
         ),
         out_shardings=(p_shard, None, NamedSharding(mesh, P())),
-        # donating the tp-sharded adam state miscompiles on legacy (0.4.x)
-        # jax: XLA pairs the donated buffers with wrong-shaped outputs.
-        # Params alone carry the bulk of the memory; the moments re-donate
-        # once the floor moves past the fixed runtime
-        donate_argnums=(0,) if IS_LEGACY_JAX else (0, 1),
+        donate_argnums=(0, 1),
     )
 
     np_rng = np.random.default_rng(config.seed)
@@ -197,6 +194,7 @@ def train_ncf(
                 "label": put_global(labels[take].astype(np.float32), data_shard),
             }
             params, opt_state, loss = step_fn(params, opt_state, b)
+            one_step_in_flight(mesh, loss)
             step += 1
             if log_every and step % log_every == 0:
                 losses.append(float(loss))

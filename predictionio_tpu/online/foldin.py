@@ -172,8 +172,8 @@ def _build_solver(solver: str, implicit: bool, rank: int, platform: str):
         _half_step_implicit,
     )
 
-    unroll = platform != "cpu"
-    interpret = platform == "cpu"
+    unroll = platform == "tpu"
+    interpret = not unroll
 
     def step(indices, values, n_obs, factors, reg, alpha):
         full = _append_zero_row(factors)
@@ -228,7 +228,7 @@ def fold_in_users(
 
     if num_rows == 0:
         return np.zeros((0, item_factors.shape[1]), np.float32)
-    platform = jax.default_backend()
+    platform = jax.devices()[0].platform  # where the jitted step will run
     solver = resolve_solver(config.solver, platform)
     counts = np.bincount(np.asarray(rows, np.int64), minlength=num_rows)
     longest = int(counts.max()) if counts.size else 1

@@ -133,6 +133,13 @@ class RetrainLoop:
             blob = record.models if record else None
             base_until_ms = ts_ms(self.instance.start_time)
             self.current_version = None
+        # resolve the platform once, up front: beside a deploy that holds the
+        # one chip this raises and names it (run the follower with
+        # PIO_PLATFORM=cpu there -- docs/operations.md), it never drifts to
+        # the host on its own
+        from predictionio_tpu.utils.platform import ensure_backend
+
+        ensure_backend((self.instance.runtime_conf or {}).get("pio.platform"))
         self.ctx = RuntimeContext(self.instance.runtime_conf)
         self.models = self.engine.prepare_deploy(
             self.ctx, self.engine_params, self.instance.id, blob

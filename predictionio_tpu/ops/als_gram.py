@@ -29,21 +29,30 @@ Contract (shared with the XLA path -- ``parallel.als`` padding invariant):
 Layout/VMEM budget (mirrors the hard-won notes in ``ops/flash_attention``):
 
 - Blocks keep their last two dims equal to the array dims (K is far below
-  a lane, so (BR, K, K) / (BR, K) output blocks are exact-dim blocks; the
-  [BR, C, K] gather scratch pads K up to a lane internally).
+  a lane, so (BR, K, K) / (BR, K) output blocks are exact-dim blocks).
+- The gather table is laid out for the DMA engine inside ``gram_rhs``: f32,
+  each row padded to a whole number of 128-lane vectors ([S + 1, KP]). A
+  one-row DMA needs a 32-bit row a lane multiple wide: out of a [S + 1, K]
+  table Mosaic refuses it (K < 128: "Slice shape along dimension 1 must be
+  aligned to tiling (128)"; bf16 at any K: rows pack in pairs per sublane,
+  "dimension 0 must be aligned to tiling (8)"). The upcast is exact and the
+  pad lanes are sliced off before the Gram, so the result is what the
+  [S + 1, K] table defines; a bf16 table still folds in one bf16 MXU pass.
+  The price is table memory ((S + 1) * KP * 4 bytes per half-step, 71 MB at
+  138k rows) and 512-byte row reads where K * itemsize would do.
 - The index block rides SMEM -- DMA source addressing is scalar work; a
   [BR, L] i32 block is BR*L*4 bytes (8 KB at BR=8, L=256).
-- VMEM per program ~= BR*L*4 (values) + 2*BR*C*K*itemsize (double-buffered
-  gather scratch) + BR*(K*K + K)*4 (accumulator blocks): ~0.3 MB at the
-  bench shape (BR=8, L=256, C=128, K=16, bf16 table) -- far under the
-  ~16 MB/core budget, leaving the auto-pipeliner room to double-buffer
-  the idx/val streams across grid steps.
+- VMEM per program ~= BR*L*4 (values) + 2*BR*C*KP*4 (double-buffered
+  gather scratch) + BR*(K*K + K)*4 (accumulator blocks): ~2.1 MB at the
+  bench shape (BR=8, L=256, C=256, KP=128) -- under the ~16 MB/core
+  budget, leaving the auto-pipeliner room to double-buffer the idx/val
+  streams across grid steps.
 - The gather itself is one row-DMA per (row, l) slot: the DMA engine keeps
   BR*C descriptors in flight per chunk while the MXU folds the PREVIOUS
   chunk (classic two-slot double buffering over the L dimension). Each
-  descriptor moves only K*itemsize bytes, so the gather runs at the
-  random-row bandwidth the layout admits -- the win over XLA is not a
-  faster gather but the intermediate that never hits HBM.
+  descriptor moves one KP*4-byte row and costs a start and a wait on the
+  scalar core -- what this buys over XLA is not a faster gather but the
+  intermediate that never hits HBM.
 - On CPU meshes the kernels run in interpret mode (the
   ``ops/flash_attention`` precedent), so tier-1 CPU tests exercise this
   exact kernel code.
@@ -61,6 +70,7 @@ from predictionio_tpu.utils.jax_compat import (
     pallas_tpu as pltpu,
     shape_struct,
 )
+from predictionio_tpu.utils.platform import note_kernel
 
 #: rows per grid step (a CAP: the largest power of two <= this that divides
 #: the block's rows is used, so a 24-row block split over a 2-device data
@@ -71,6 +81,18 @@ BLOCK_ROWS = 8
 #: gather chunk (columns of the L dimension folded per double-buffer slot);
 #: the largest of these dividing L is used, so L only needs 8-alignment.
 _CHUNKS = (256, 128, 64, 32, 16, 8)
+
+#: lanes of one vector register row: the unit a row DMA must be a multiple of
+_LANES = 128
+
+#: longest run of the L dimension one grid step takes. The index block rides
+#: SMEM double-buffered (BR * tile * 4 bytes * 2 = 128 KB here, of 1 MB), and
+#: the chunk loop unrolls tile/chunk times. A block longer than this (the
+#: recommendation template's single-bucket item side at the ML-1M shape is
+#: [3712, 23832]) is walked by a second grid axis that accumulates into the
+#: same output block; without it the chip's compiler refuses the program:
+#: "Allocation (size=1531904) would exceed memory (size=1048576) ... smem".
+MAX_TILE_LEN = 2048
 
 
 def _pick_chunk(pad_len: int) -> int:
@@ -87,24 +109,35 @@ def _gram_rhs_kernel(
     idx_ref,    # SMEM [BR, L] i32
     val_ref,    # VMEM [BR, L] f32
     alpha_ref,  # SMEM [1, 1]  f32 (ignored in explicit mode)
-    table_ref,  # ANY  [S + 1, K] factor dtype (stays in HBM)
+    table_ref,  # ANY  [S + 1, KP] f32, lane-padded (stays in HBM)
     gram_ref,   # VMEM [BR, K, K] f32 out
     rhs_ref,    # VMEM [BR, K] f32 out
-    gathered,   # VMEM scratch [2, BR, C, K] factor dtype
+    gathered,   # VMEM scratch [2, BR, C, KP] f32
     sem,        # DMA semaphores [2] (one per buffer slot)
     *,
     implicit: bool,
     chunk: int,
+    bf16_exact: bool,
+    pad_len: int,
 ):
-    br, pad_len = idx_ref.shape
-    n_chunks = pad_len // chunk
-    k = table_ref.shape[1]
+    br, tile = idx_ref.shape
+    n_chunks = tile // chunk
+    k = gram_ref.shape[1]
+    # the last L tile of a long block may hang over the block's end: its
+    # window then holds stale slots, which are pointed at the zero row (and
+    # their values zeroed) exactly like packed padding
+    ragged = pad_len % tile != 0
+    base = pl.program_id(1) * tile
+    zero_row = table_ref.shape[0] - 1
 
     def dma(slot: int, ci: int, p):
         r, cl = p // chunk, p % chunk
+        idx = idx_ref[r, ci * chunk + cl]
+        if ragged:
+            idx = jnp.where(base + ci * chunk + cl < pad_len, idx, zero_row)
         return pltpu.make_async_copy(
-            table_ref.at[idx_ref[r, ci * chunk + cl]],
-            gathered.at[slot, r, cl],
+            table_ref.at[pl.ds(idx, 1)],
+            gathered.at[slot, r, pl.ds(cl, 1)],
             sem.at[slot],
         )
 
@@ -136,22 +169,42 @@ def _gram_rhs_kernel(
         if ci + 1 < n_chunks:
             issue(ci + 1)  # next chunk's DMAs fly while this one folds
         drain(ci)
-        g = gathered[ci % 2].astype(jnp.float32)              # [BR, C, K]
+        g = gathered[ci % 2][:, :, :k]                        # [BR, C, K]
         v = val_ref[:, ci * chunk : (ci + 1) * chunk]         # [BR, C]
+        if ragged:
+            col = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+            v = jnp.where(base + ci * chunk + col < pad_len, v, 0.0)
         if implicit:
             w = alpha_ref[0, 0] * v
             gram_w, rhs_w = w, 1.0 + w
         else:
             gram_w, rhs_w = None, v
         lhs = g if gram_w is None else g * gram_w[..., None]
+        if bf16_exact:
+            # the table's values ARE bf16 (upcast for the gather only): one
+            # MXU pass with f32 accumulation is exact
+            lhs, rhs_op, precision = (
+                lhs.astype(jnp.bfloat16), g.astype(jnp.bfloat16), None
+            )
+        else:
+            # f32 operands: the same "highest" the XLA path asks for, or
+            # the MXU would round them to bf16
+            rhs_op, precision = g, jax.lax.Precision.HIGHEST
         gram_acc = gram_acc + jax.lax.dot_general(
-            lhs, g,
+            lhs, rhs_op,
             dimension_numbers=(((1,), (1,)), ((0,), (0,))),
+            precision=precision,
             preferred_element_type=jnp.float32,
         )
         rhs_acc = rhs_acc + jnp.sum(g * rhs_w[..., None], axis=1)
-    gram_ref[...] = gram_acc
-    rhs_ref[...] = rhs_acc
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        gram_ref[...] = jnp.zeros_like(gram_ref)
+        rhs_ref[...] = jnp.zeros_like(rhs_ref)
+
+    gram_ref[...] += gram_acc
+    rhs_ref[...] += rhs_acc
 
 
 def gram_rhs(
@@ -177,36 +230,49 @@ def gram_rhs(
     br = min(block_rows, r)
     while br > 1 and r % br:
         br //= 2  # e.g. 12 rows/device under a 2-way data split -> BR=4
-    chunk = _pick_chunk(pad_len)
+    tile = min(pad_len, MAX_TILE_LEN)
+    chunk = _pick_chunk(tile)
+    note_kernel("als_gram_rhs", interpret)
+    # gather layout the chip admits (see module docstring): 32-bit rows a
+    # whole number of lanes wide. The upcast is exact and the pad lanes are
+    # never read, so Gram/rhs are what the [S + 1, K] table defines.
+    kp = -(-k // _LANES) * _LANES
+    table = jnp.pad(factors.astype(jnp.float32), ((0, 0), (0, kp - k)))
     alpha_arr = jnp.asarray(alpha, jnp.float32).reshape(1, 1)
     kernel = functools.partial(
-        _gram_rhs_kernel, implicit=implicit, chunk=chunk
+        _gram_rhs_kernel, implicit=implicit, chunk=chunk,
+        # explicit mode multiplies bf16 values by themselves only; implicit
+        # mode weights one operand in f32 first
+        bf16_exact=factors.dtype == jnp.bfloat16 and not implicit,
+        pad_len=pad_len,
     )
     return pl.pallas_call(
         kernel,
-        grid=(r // br,),
+        # rows, then L tiles: the output block is revisited along the
+        # second axis and accumulates there
+        grid=(r // br, -(-pad_len // tile)),
         in_specs=[
-            pl.BlockSpec((br, pad_len), lambda i: (i, 0),
+            pl.BlockSpec((br, tile), lambda i, j: (i, j),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((br, pad_len), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0),
+            pl.BlockSpec((br, tile), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1), lambda i, j: (0, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((br, k, k), lambda i: (i, 0, 0)),
-            pl.BlockSpec((br, k), lambda i: (i, 0)),
+            pl.BlockSpec((br, k, k), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((br, k), lambda i, j: (i, 0)),
         ],
         out_shape=[
             shape_struct((r, k, k), jnp.float32, indices),
             shape_struct((r, k), jnp.float32, indices),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, br, chunk, k), factors.dtype),
+            pltpu.VMEM((2, br, chunk, kp), jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
-    )(jnp.asarray(indices, jnp.int32), values, alpha_arr, factors)
+    )(jnp.asarray(indices, jnp.int32), values, alpha_arr, table)
 
 
 def half_step_bytes(

@@ -37,7 +37,8 @@ Layout/VMEM budget (mirrors ``ops/als_gram``):
 
 - Query block ``[BB, K]`` f32 and item tile ``[BI, K]`` int8 are
   exact-dim blocks (K is far below a lane and pads internally); the
-  per-tile scale rides SMEM as a (1, 1) scalar.
+  per-tile scale is a ``[1, 1, 1]`` VMEM block and the candidates leave
+  tile-major (``[nb, B, R]``), the block shapes the TPU lowering admits.
 - VMEM per program ~= BB*K*4 + BI*K*1 + BB*BI*4 (the score tile) +
   BB*R*8 (outputs): ~25 KB at the defaults (BB=8, BI=512, K=16, R=16) --
   far under the ~16 MB/core budget, leaving the auto-pipeliner room to
@@ -103,12 +104,10 @@ def mips_block_topk(
     import jax
     import jax.numpy as jnp
 
-    from predictionio_tpu.utils.jax_compat import (
-        pallas as pl,
-        pallas_tpu as pltpu,
-        shape_struct,
-    )
+    from predictionio_tpu.utils.jax_compat import pallas as pl, shape_struct
+    from predictionio_tpu.utils.platform import note_kernel
 
+    note_kernel("mips_block_topk", interpret)
     b, k = queries.shape
     padded_items = q_table.shape[0]
     nb = scales.shape[0]
@@ -129,52 +128,65 @@ def mips_block_topk(
     def kernel(
         q_ref,       # VMEM [BB, K] f32
         table_ref,   # VMEM [BI, K] int8 (one quantization block)
-        scale_ref,   # SMEM [1, 1] f32
-        score_ref,   # VMEM [BB, 1, R] f32 out
-        idx_ref,     # VMEM [BB, 1, R] i32 out
+        scale_ref,   # VMEM [1, 1, 1] f32
+        score_ref,   # VMEM [1, BB, R] f32 out
+        idx_ref,     # VMEM [1, BB, R] i32 out
     ):
         bb = q_ref.shape[0]
-        g = table_ref[...].astype(jnp.float32) * scale_ref[0, 0]  # [BI, K]
+        g = table_ref[...].astype(jnp.float32) * scale_ref[0]     # [BI, K]
         s = jax.lax.dot_general(
             q_ref[...], g,
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )                                                         # [BB, BI]
         col = jax.lax.broadcasted_iota(jnp.int32, (bb, bi), 1)
+        slot = jax.lax.broadcasted_iota(jnp.int32, (bb, r), 1)
         base = pl.program_id(1) * bi
         # padding rows dequantize to score 0, which would outrank real
         # negative scores -- mask them below any real score pre-selection
         s = jnp.where(base + col < num_items, s, _NEG)
+        top_s = jnp.zeros((bb, r), jnp.float32)
+        top_i = jnp.zeros((bb, r), jnp.int32)
         # R unrolled select-and-mask passes (pure VPU: Mosaic has no
         # in-kernel sort); first-match (min index) argmax so ties inside
-        # a tile resolve to the lowest catalog index, like argsort
+        # a tile resolve to the lowest catalog index, like argsort. Every
+        # value stays 2-D and the [BB, R] result is stored once.
         for step in range(r):
-            m = jnp.max(s, axis=1)                                # [BB]
-            hit = s == m[:, None]
-            local = jnp.min(jnp.where(hit, col, bi), axis=1)      # [BB]
-            score_ref[:, 0, step] = m
-            idx_ref[:, 0, step] = base + local
-            s = jnp.where(col == local[:, None], _SEL, s)
+            m = jnp.max(s, axis=1, keepdims=True)                 # [BB, 1]
+            local = jnp.min(
+                jnp.where(s == m, col, bi), axis=1, keepdims=True
+            )                                                     # [BB, 1]
+            top_s = jnp.where(slot == step, m, top_s)
+            top_i = jnp.where(slot == step, base + local, top_i)
+            s = jnp.where(col == local, _SEL, s)
+        score_ref[0] = top_s
+        idx_ref[0] = top_i
 
+    # block shapes the TPU lowering admits: the per-tile scale is a
+    # [1, 1, 1] VMEM block (an SMEM (1, 1) block of a [nb, 1] array is
+    # refused), and the candidates leave tile-major, [nb, B, R] in
+    # (1, BB, R) blocks, because a (BB, 1, R) block of [B, nb, R] is
+    # neither 8-divisible nor whole in its second-minor dimension
     scores, idx = pl.pallas_call(
         kernel,
         grid=(b // BLOCK_QUERIES, nb),
         in_specs=[
             pl.BlockSpec((BLOCK_QUERIES, k), lambda i, j: (i, 0)),
             pl.BlockSpec((bi, k), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (j, 0),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, 1), lambda i, j: (j, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((BLOCK_QUERIES, 1, r), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((BLOCK_QUERIES, 1, r), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, BLOCK_QUERIES, r), lambda i, j: (j, i, 0)),
+            pl.BlockSpec((1, BLOCK_QUERIES, r), lambda i, j: (j, i, 0)),
         ],
         out_shape=[
-            shape_struct((b, nb, r), jnp.float32, queries),
-            shape_struct((b, nb, r), jnp.int32, queries),
+            shape_struct((nb, b, r), jnp.float32, queries),
+            shape_struct((nb, b, r), jnp.int32, queries),
         ],
         interpret=interpret,
-    )(queries, q_table, scales)
+    )(queries, q_table, scales.reshape(nb, 1, 1))
+    scores = jnp.swapaxes(scores, 0, 1)
+    idx = jnp.swapaxes(idx, 0, 1)
     return scores.reshape(b, nb * r), idx.reshape(b, nb * r)
 
 
@@ -211,7 +223,7 @@ def _search_program(
         gathered = table_f32[jnp.clip(sel, 0, num_items - 1)]
         exact = jnp.einsum(
             "bk,bsk->bs", queries, gathered,
-            preferred_element_type=jnp.float32,
+            precision="highest", preferred_element_type=jnp.float32,
         )
         exact = jnp.where(sel < num_items, exact, -jnp.inf)
         return sel, exact
@@ -230,9 +242,11 @@ def _search_program(
     # score ties by global index, byte-matching the full scan's order
     sel = jnp.sort(sel, axis=1)
     gathered = table_f32[jnp.clip(sel, 0, num_items - 1)]        # [B, S, K]
+    # "highest": the re-rank is the EXACT stage; at the MXU's default the
+    # f32 operands would be rounded to bf16
     exact = jnp.einsum(
         "bk,bsk->bs", queries, gathered,
-        preferred_element_type=jnp.float32,
+        precision="highest", preferred_element_type=jnp.float32,
     )
     exact = jnp.where(sel < num_items, exact, -jnp.inf)
     return sel, exact
@@ -317,13 +331,15 @@ class RetrievalIndex:
         )
         self.num_items = packed.num_items
         self.packed_bytes = packed.packed_bytes
+        device = jax.devices()[0]
         if interpret is None:
-            # the flash_attention/als_gram precedent: CPU backends run the
-            # same kernel code through the Pallas interpreter
-            interpret = jax.devices()[0].platform == "cpu"
-        self._q = jax.device_put(packed.q)
-        self._scales = jax.device_put(packed.scales)
-        self._table = jax.device_put(np.asarray(factors, np.float32))
+            # decided by the device the tables are placed on, like every
+            # other kernel here: anything but a TPU runs the same kernel
+            # code through the Pallas interpreter
+            interpret = device.platform != "tpu"
+        self._q = jax.device_put(packed.q, device)
+        self._scales = jax.device_put(packed.scales, device)
+        self._table = jax.device_put(np.asarray(factors, np.float32), device)
         self._program = jax.jit(
             functools.partial(
                 _search_program,

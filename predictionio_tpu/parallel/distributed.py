@@ -95,16 +95,6 @@ def init_distributed(
     process_id = int(
         process_id if process_id is not None else os.environ.get("PIO_PROCESS_ID", "0")
     )
-    try:
-        # cross-process collectives on the CPU backend need an explicit
-        # transport on legacy (0.4.x) jax ("Multiprocess computations
-        # aren't implemented on the CPU backend" otherwise); newer jax
-        # selects gloo on its own. Must be set before backend init, which
-        # initialize() below triggers.
-        if jax.config.jax_platforms and "cpu" in str(jax.config.jax_platforms):
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # unknown option on some versions: defaults are fine
-        pass
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=num_processes,

@@ -31,7 +31,7 @@ from predictionio_tpu.parallel.ring_attention import plain_attention
 
 def _ulysses_local(
     q, k, v, kv_mask, *, axis_name: str, causal: bool, sm_scale,
-    use_flash: bool = False,
+    use_flash: bool = False, interpret: bool = False,
 ):
     """Per-shard body. Shapes: q,k,v [B, Tl, H, D]; kv_mask [B, Tl].
 
@@ -51,7 +51,7 @@ def _ulysses_local(
 
         out = flash_attention(
             q_h, k_h, v_h, mask_full, causal=causal, sm_scale=sm_scale,
-            interpret=jax.default_backend() != "tpu",
+            interpret=interpret,
         )
     else:
         out = plain_attention(
@@ -93,11 +93,12 @@ def ulysses_attention(
     # flash-in-interpret (CPU tests) trips shard_map's vma checker on the
     # interpreter's internal index constants; this body never uses pcast,
     # so the check can be dropped exactly when that combination is active
-    interpret_flash = use_flash and jax.default_backend() != "tpu"
+    interpret = mesh.devices.flat[0].platform != "tpu"
+    interpret_flash = use_flash and interpret
     fn = seq_parallel_shard_map(
         functools.partial(
             _ulysses_local, axis_name=axis_name, causal=causal,
-            sm_scale=sm_scale, use_flash=use_flash,
+            sm_scale=sm_scale, use_flash=use_flash, interpret=interpret,
         ),
         mesh,
         axis_name,

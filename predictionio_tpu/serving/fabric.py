@@ -81,6 +81,26 @@ class _Frontend:
         self.dead = False
 
 
+def _refuse_device_paths(variant) -> None:
+    """Scorer shards run on the host (``_child_env``). An engine whose
+    params ask for a device query path cannot be sharded this way: refuse
+    at deploy instead of serving an interpreted kernel from N processes."""
+    for name, params in variant.engine_params.algorithm_params_list:
+        retrieval = params.get("retrieval") or {}
+        wants = []
+        if isinstance(retrieval, dict) and retrieval.get("mode") == "mips":
+            wants.append('"retrieval": {"mode": "mips"}')
+        if params.get("usePallas"):
+            wants.append('"usePallas": true')
+        if wants:
+            raise ValueError(
+                f"--scorer-shards runs its scorer processes on the host"
+                f" (several processes cannot share one chip), but algorithm"
+                f" {name!r} asks for a device path ({', '.join(wants)});"
+                " deploy it without --scorer-shards"
+            )
+
+
 class ShardFabric:
     """Deploy-side owner of the sharded serving tier. Same
     ``start()/stop()/port`` surface as ``MultiprocServiceHandle``."""
@@ -103,6 +123,7 @@ class ShardFabric:
     ):
         if num_shards < 2:
             raise ValueError("the sharded fabric needs --scorer-shards >= 2")
+        _refuse_device_paths(variant)
         self.variant = variant
         self._host = host
         self._requested_port = port
@@ -241,6 +262,12 @@ class ShardFabric:
         env["PYTHONPATH"] = os.pathsep.join(
             dict.fromkeys(p for p in sys.path if p)
         )
+        # one process for each chip: N scorer shards are N JAX processes on
+        # one host, so they are host-only by construction (their query path
+        # is the host-numpy scan). Said here, never discovered by a shard
+        # that found the chip taken.
+        env["JAX_PLATFORMS"] = "cpu"
+        env.pop("PIO_PLATFORM", None)
         return env
 
     def _launch_shard(self, index: int) -> _Shard:

@@ -366,8 +366,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    if os.environ.get("JAX_PLATFORMS") is None:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
     main()

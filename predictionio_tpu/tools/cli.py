@@ -42,9 +42,9 @@ def cmd_status(args: argparse.Namespace) -> int:
     print(f"pio (predictionio_tpu) {__version__}")
     # the accelerator is this framework's execution substrate (the role
     # SPARK_HOME verification played in the reference's `pio status`).
-    # Probe it in a BOUNDED subprocess: initializing a registered-but-
-    # wedged tunnel plugin blocks forever, and the diagnostic command a
-    # user runs to debug a broken setup must always answer.
+    # Probe it in a child with a time limit: this process never imports
+    # JAX, so a status check beside a running train or deploy cannot take
+    # the chip from it, and the command always answers.
     import subprocess
 
     probe = (
@@ -54,6 +54,7 @@ def cmd_status(args: argparse.Namespace) -> int:
         "ds = jax.devices()\n"
         "print('PIO_ACCEL|' + p + '|' + str(len(ds)) + '|' + ds[0].device_kind)\n"
     )
+    device_ok = False
     try:
         proc = subprocess.run(
             [sys.executable, "-c", probe],
@@ -70,15 +71,13 @@ def cmd_status(args: argparse.Namespace) -> int:
             None,
         )
         if fields is None:
-            print("Accelerator: probe failed -- training will fall back to CPU")
-        elif fields[1] == "cpu":
-            print("Accelerator: none (CPU backend) -- training and serving"
-                  " run on the host")
+            reason = (proc.stderr.strip().splitlines() or ["no output"])[-1]
+            print(f"Device: NOT AVAILABLE -- {reason}")
         else:
-            print(f"Accelerator: {fields[1]} x{fields[2]} ({fields[3]})")
+            device_ok = True
+            print(f"Device: {fields[1]} x{fields[2]} ({fields[3]})")
     except subprocess.TimeoutExpired:
-        print("Accelerator: probe timed out -- the accelerator plugin may be"
-              " wedged; trains fall back to CPU (utils/platform ladder)")
+        print("Device: NOT AVAILABLE -- the backend probe timed out")
     print("Storage configuration:")
     for repo, cfg in storage.config_summary().items():
         detail = ", ".join(f"{k}={v}" for k, v in cfg.items() if k not in ("source",))
@@ -88,6 +87,10 @@ def cmd_status(args: argparse.Namespace) -> int:
         print("Storage check FAILED:")
         for failure in failures:
             print(f"  - {failure}")
+        return 1
+    if not device_ok:
+        print("Storage check OK, but the configured JAX platform did not"
+              " come up: train and deploy will fail.")
         return 1
     print("Storage check OK. Your system is all ready to go.")
     return 0

@@ -380,6 +380,13 @@ def cmd_train(args: argparse.Namespace) -> int:
         resume=args.resume,
     )
     instance = run_train(variant, params)
+    import json
+
+    from predictionio_tpu.utils.platform import device_report
+
+    # what the train ran on, from JAX itself: a run on the wrong platform
+    # cannot pass for a chip run
+    print(f"Device: {json.dumps(device_report())}")
     print(f"Training completed. Engine instance ID: {instance.id}")
     return 0
 

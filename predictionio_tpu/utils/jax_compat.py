@@ -1,32 +1,20 @@
-"""Version-drift shims for the jax API surface this repo rides.
+"""The one module that touches ``jax.experimental`` and the sharding API.
 
-The codebase targets the current jax API (``jax.shard_map``, varying-mesh-
-axes types, ``jax.lax.pcast``); the installed jax may predate it (0.4.x
-exposes ``shard_map`` only under ``jax.experimental`` with the vma checker
-named ``check_rep`` and no vma machinery at all). Every call site imports
-from HERE instead of feature-testing jax inline, so the drift policy lives
-in one module and the day the floor moves past the new API this file
-deletes down to three aliases.
+The repo runs on one installation (jax 0.9.0, jaxlib 0.9.0, libtpu 0.0.34),
+so nothing here tests for a version: the names are plain aliases. They stay
+in one module so that a move of any of them inside jax is a one-line change.
 
-Mapping rules:
-
-- ``shard_map``: ``jax.shard_map`` when present, else
-  ``jax.experimental.shard_map.shard_map`` with the ``check_vma`` kwarg
-  renamed to its old spelling ``check_rep`` (same meaning: False disables
-  the output-replication/varying checker, which pallas-in-interpret bodies
-  trip on both APIs).
-- ``pcast_varying``: ``jax.lax.pcast(..., to="varying")`` when present,
-  else identity -- pre-vma jax has no varying/unvarying distinction, so a
-  fresh constant already has whatever type the checker expects.
+- ``shard_map``, ``axis_size``: ``jax.shard_map``, ``jax.lax.axis_size``.
+- ``pcast_varying``: ``jax.lax.pcast(..., to="varying")`` -- scan carries
+  must match their varying body outputs under the vma checker.
 - ``shape_struct``: ``jax.ShapeDtypeStruct`` carrying the vma of a model
-  array (so pallas out_shapes compose under ``shard_map(check_vma=True)``)
-  when ``jax.typeof`` exists; the plain struct otherwise.
+  array, so pallas out_shapes compose under ``shard_map(check_vma=True)``.
 - ``pallas`` / ``pallas_tpu``: the Pallas modules, resolved through module
-  ``__getattr__`` so importing this shim stays cheap for callers that only
-  need ``IS_LEGACY_JAX`` (Pallas pulls in Mosaic lowering machinery).
+  ``__getattr__`` so importing this module stays cheap for callers that
+  never touch Pallas (it pulls in the Mosaic lowering machinery).
 - ``broadcast_one_to_all`` / ``process_allgather`` /
   ``create_hybrid_device_mesh``: lazy fronts for the multihost/mesh utils
-  that still live under ``jax.experimental`` on every supported jax.
+  that live under ``jax.experimental``.
 
 ``pio check`` rule J001 enforces that every ``jax.experimental`` /
 ``jax.shard_map`` / ``pjit`` touch in the package routes through here.
@@ -36,42 +24,13 @@ from __future__ import annotations
 
 import jax
 
-#: True on pre-``jax.shard_map`` (0.4.x) installs. Gates the few behaviors
-#: the legacy stack MISCOMPILES rather than lacks: donating a tp-sharded
-#: optimizer-state pytree pairs donated buffers with wrong-shaped outputs
-#: inside XLA ("Expected aliased input ... to have the same size").
-IS_LEGACY_JAX = not hasattr(jax, "shard_map")
-
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
-                  **kwargs):
-        """``jax.shard_map`` signature on the legacy experimental API."""
-        return _legacy_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma, **kwargs,
-        )
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a mapped mesh axis (``jax.lax.axis_size``); the old
-    API spells it ``psum(1, name)``, which constant-folds to a python int
-    at trace time."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size
 
 
 def pcast_varying(x, axis_name):
-    """Cast a fresh constant to a "varying" collective type (scan carries
-    must match their varying body outputs under the vma checker); identity
-    on pre-vma jax, where constants and collectives share one type."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis_name, to="varying")
-    return x
+    """Cast a fresh constant to a "varying" collective type."""
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def __getattr__(name: str):
@@ -115,11 +74,9 @@ def create_hybrid_device_mesh(mesh_shape, dcn_mesh_shape, devices=None, **kwargs
 
 
 def shape_struct(shape, dtype, like=None):
-    """ShapeDtypeStruct inheriting ``like``'s varying-mesh-axes, when the
-    installed jax tracks them; plain (non-sharded) callers and pre-vma jax
-    get the ordinary struct."""
-    typeof = getattr(jax, "typeof", None)
-    vma = getattr(typeof(like), "vma", None) if typeof and like is not None else None
+    """ShapeDtypeStruct inheriting ``like``'s varying-mesh-axes; plain
+    (non-sharded) callers get the ordinary struct."""
+    vma = jax.typeof(like).vma if like is not None else None
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)

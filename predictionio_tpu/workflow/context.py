@@ -116,11 +116,8 @@ class RuntimeContext:
         )
         from predictionio_tpu.utils.platform import ensure_backend
 
-        # a wedged or unregistered accelerator plugin must not take the
-        # whole training CLI down -- this call site opts into the
-        # degradation ladder (fallback=True; a warning still records the
-        # pin that was abandoned)
-        ensure_backend(self.runtime_conf.get("pio.platform"), fallback=True)
+        # the configured platform comes up or the train fails: no fallback
+        ensure_backend(self.runtime_conf.get("pio.platform"))
         return build_mesh(
             self.runtime_conf.get("pio.mesh_shape", [-1, 1]),
             tuple(self.runtime_conf.get("pio.mesh_axes", ("data", "model"))),

@@ -346,12 +346,8 @@ class QueryService:
         instance = resolve_engine_instance(self.variant, self.requested_instance_id)
         engine_params = engine_params_from_instance(instance)
         # resolve the instance FIRST so an explicit pio.platform in its
-        # runtime conf wins; serving must come up even with a wedged
-        # accelerator plugin, so this call site opts into the degradation
-        # ladder (fallback=True) -- availability over pin fidelity here
-        ensure_backend(
-            (instance.runtime_conf or {}).get("pio.platform"), fallback=True
-        )
+        # runtime conf wins; the deploy fails if that platform is not there
+        ensure_backend((instance.runtime_conf or {}).get("pio.platform"))
         blob_record = storage.get_model_data_models().get(instance.id)
         blob = blob_record.models if blob_record else None
         if blob is not None:
@@ -429,9 +425,7 @@ class QueryService:
                 resolve_engine_instance(self.variant, entry.instance_id or None)
             )
         )
-        ensure_backend(
-            (self.variant.runtime_conf or {}).get("pio.platform"), fallback=True
-        )
+        ensure_backend((self.variant.runtime_conf or {}).get("pio.platform"))
         ctx = RuntimeContext(self.variant.runtime_conf)
         models = self.engine.prepare_deploy(
             ctx, engine_params, entry.instance_id or "", blob,
@@ -476,9 +470,14 @@ class QueryService:
 
     # -- handlers -----------------------------------------------------------
     def handle_info(self, request: Request) -> Response:
+        from predictionio_tpu.utils.platform import device_report
+
         with self._lock:
             body = {
                 "status": "alive",
+                # the device this server scores on and the Pallas kernels
+                # it has built (compiled or interpreted), from JAX itself
+                "device": device_report(),
                 "engineInstance": {
                     "id": self.instance.id,
                     "engineVariant": self.variant.variant_id,

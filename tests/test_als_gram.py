@@ -49,6 +49,33 @@ def _reference(indices, values, table, alpha, implicit):
     return np.asarray(gram), np.asarray(rhs)
 
 
+class TestLongBlocksTileOverL:
+    """A block longer than ``MAX_TILE_LEN`` is walked by a second grid axis
+    that accumulates into the same output block; the last tile may hang
+    over the block's end (its stale slots must die like packed padding)."""
+
+    @pytest.mark.parametrize("implicit", [False, True], ids=["explicit", "implicit"])
+    @pytest.mark.parametrize("pad_len", [128, 152], ids=["even_tiles", "ragged_last_tile"])
+    def test_tiled_matches_einsum_path(self, monkeypatch, pad_len, implicit):
+        from predictionio_tpu.ops import als_gram
+
+        monkeypatch.setattr(als_gram, "MAX_TILE_LEN", 64)
+        rng = np.random.default_rng(5)
+        rows, slots, k = 16, 40, 6
+        table = jnp.asarray(np.concatenate(
+            [rng.normal(size=(slots, k)), np.zeros((1, k))]), jnp.float32)
+        indices = rng.integers(0, slots, size=(rows, pad_len)).astype(np.int32)
+        values = rng.normal(size=(rows, pad_len)).astype(np.float32)
+        # packed padding at the tail of every row, as pack_padded_csr leaves it
+        indices[:, pad_len - 20:] = slots
+        values[:, pad_len - 20:] = 0.0
+        gram, rhs = gram_rhs(jnp.asarray(indices), jnp.asarray(values), table,
+                             3.0, implicit=implicit, interpret=True)
+        gram_ref, rhs_ref = _reference(indices, values, table, 3.0, implicit)
+        np.testing.assert_allclose(np.asarray(gram), gram_ref, atol=1e-4)
+        np.testing.assert_allclose(np.asarray(rhs), rhs_ref, atol=1e-4)
+
+
 class TestKernelParity:
     """gram_rhs vs the einsum reference on real padded-CSR blocks."""
 

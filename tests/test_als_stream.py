@@ -288,12 +288,19 @@ class TestFeederResidency:
             als_fit_streamed(sd, cfg, mesh)  # warm the jit caches first:
             # tracing/compilation allocates ~MBs of host memory once per
             # program and would drown the feeder's footprint
-            tracemalloc.start()
-            try:
-                als_fit_streamed(sd, cfg, mesh)
-                _, peak = tracemalloc.get_traced_memory()
-            finally:
-                tracemalloc.stop()
+            # tracemalloc counts every thread of the process, so another
+            # thread's allocations (seen once in seven whole-suite runs
+            # under six workers: 4.36 MB against a 3.9 MB budget) only ever
+            # add to the reading: the feeder's bound is the smaller of two
+            peaks = []
+            for _ in range(2):
+                tracemalloc.start()
+                try:
+                    als_fit_streamed(sd, cfg, mesh)
+                    peaks.append(tracemalloc.get_traced_memory()[1])
+                finally:
+                    tracemalloc.stop()
+            peak = min(peaks)
         # feeder bound: 2 blocks in flight + transient copies + factor
         # init/readback (entities * rank, f64) + slack; nothing near the
         # full store size

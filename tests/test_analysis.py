@@ -40,7 +40,6 @@ from predictionio_tpu.analysis.rules_resources import (
 )
 from predictionio_tpu.analysis.rules_jax import (
     RuleJ001,
-    RuleJ002,
     RuleJ003,
     RuleJ004,
     RuleJ005,
@@ -119,80 +118,6 @@ class TestJ001:
         assert run_rule(RuleJ001, """
             from jax.experimental.shard_map import shard_map
         """, path="predictionio_tpu/utils/jax_compat.py") == []
-
-
-# -- J002: legacy donation of sharded optimizer state -------------------------
-
-_J002_BUG = """
-    import jax
-    from jax.sharding import NamedSharding
-
-    def make_train_step(model, optimizer):
-        def train_step(params, opt_state, batch, rng):
-            return params, opt_state
-        return train_step
-
-    def train(model, optimizer, rep):
-        step_fn = jax.jit(
-            make_train_step(model, optimizer),
-            in_shardings=(rep, None, None, None),
-            donate_argnums=(0, 1),
-        )
-        return step_fn
-"""
-
-_J002_FIXED = _J002_BUG.replace(
-    "donate_argnums=(0, 1),",
-    "donate_argnums=(0,) if IS_LEGACY_JAX else (0, 1),",
-)
-
-
-class TestJ002:
-    def test_fires_on_ungated_opt_state_donation(self):
-        hits = run_rule(RuleJ002, _J002_BUG)
-        assert [f.rule_id for f in hits] == ["J002"]
-        assert "opt_state" in hits[0].message
-
-    def test_silent_when_gated_on_legacy_flag(self):
-        assert run_rule(RuleJ002, _J002_FIXED) == []
-
-    def test_silent_when_donation_is_not_optimizer_state(self):
-        assert run_rule(RuleJ002, """
-            import jax
-            from jax.sharding import NamedSharding
-
-            def iteration(u_blocks, i_blocks, users, items):
-                return users, items
-
-            def build(rep):
-                return jax.jit(iteration, donate_argnums=(2, 3),
-                               in_shardings=(rep, rep, rep, rep))
-        """) == []
-
-    def test_silent_in_unsharded_module(self):
-        # no sharded placement -> the legacy miscompile cannot trigger
-        assert run_rule(RuleJ002, """
-            import jax
-
-            def make_train_step():
-                def train_step(params, opt_state):
-                    return params, opt_state
-                return train_step
-
-            step = jax.jit(make_train_step(), donate_argnums=(0, 1))
-        """) == []
-
-    def test_fires_on_decorator_form(self):
-        hits = run_rule(RuleJ002, """
-            import functools
-            import jax
-            from jax.sharding import NamedSharding
-
-            @functools.partial(jax.jit, donate_argnums=(1,))
-            def train_step(params, opt_state):
-                return params, opt_state
-        """)
-        assert [f.rule_id for f in hits] == ["J002"]
 
 
 # -- J003: python control flow on traced values -------------------------------
@@ -3112,23 +3037,6 @@ class TestS004:
         """)
         assert findings == []
 
-    def test_legacy_gated_donation_is_the_negative(self):
-        # the J002 fix shape: the gate exists to keep donation correct
-        findings = run_rule(RuleS004, """
-            import jax
-            from predictionio_tpu.utils.jax_compat import IS_LEGACY_JAX
-
-            def train(params, opt_state, batch):
-                step = jax.jit(
-                    _step,
-                    donate_argnums=(0,) if IS_LEGACY_JAX else (0, 1),
-                )
-                params, opt_state = step(params, opt_state)
-                print(opt_state)
-                return params
-        """)
-        assert findings == []
-
     def test_donate_argnames_resolved_through_callee_params(self):
         findings = run_rule(RuleS004, """
             import jax
@@ -4005,4 +3913,4 @@ def test_analysis_rules_total_includes_s_family():
     ids = {r.rule_id for r in all_rules()}
     assert {"S001", "S002", "S003", "S004", "S005"} <= ids
     assert {"P001", "P002", "P003", "P004", "P005"} <= ids
-    assert len(ids) == 25
+    assert len(ids) == 24

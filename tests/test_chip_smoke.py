@@ -1,0 +1,71 @@
+"""``chip_smoke.py`` rehearsed on the CPU at 1/100 size.
+
+The script is the proof that the system starts on the chip; here it has no
+chip, so it must walk every phase (kernels interpreted, as the program does
+on a CPU mesh) and then refuse: ``{"ok": false`` last and a non-zero exit.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PHASES = ["device", "compile_cache", "ingest", "train_als", "als_full_width",
+          "serve_als", "train_serve_ncf"]
+
+
+def _run(args, tmp_path, timeout, **env_overrides):
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "jax_cache")}
+    # one CPU device, and the real cache path logic, in the script's children
+    env.pop("XLA_FLAGS", None)
+    env.pop("JAX_ENABLE_COMPILATION_CACHE", None)
+    env.update(env_overrides)
+    return subprocess.run(
+        [sys.executable, os.path.join(ROOT, "chip_smoke.py"),
+         "--out", str(tmp_path / "out"), *args],
+        env=env, capture_output=True, text=True, timeout=timeout, cwd=str(tmp_path),
+    )
+
+
+def test_rehearsal_reaches_every_phase_then_refuses_the_cpu(tmp_path):
+    proc = _run(["--scale", "0.01"], tmp_path, timeout=900)
+    lines = [json.loads(l) for l in proc.stdout.splitlines() if l.startswith("{")]
+    passed = [l["phase"] for l in lines if l.get("phase") in PHASES and l.get("ok")]
+    assert passed == PHASES, proc.stdout[-3000:] + proc.stderr[-2000:]
+    last = proc.stdout.strip().splitlines()[-1]
+    assert last.startswith('{"ok": false'), last
+    assert json.loads(last)["device"]["platform"] == "cpu"
+    assert "not tpu" in json.loads(last)["error"]
+    assert proc.returncode != 0
+    by_phase = {l["phase"]: l for l in lines if "phase" in l}
+    assert by_phase["serve_als"]["top10_equal"] is True
+    assert by_phase["serve_als"]["mips_kernel"] == "interpreted"
+    assert by_phase["train_serve_ncf"]["kernel"] == "interpreted"
+    assert by_phase["compile_cache"]["cache_dir"] == str(tmp_path / "jax_cache")
+    assert all(r["agrees"] for r in by_phase["als_full_width"]["runs"])
+
+
+def test_without_a_chip_the_default_run_stops_at_the_device_phase(tmp_path):
+    """As the driver runs it (no --scale): nothing is run on the host."""
+    proc = _run([], tmp_path, timeout=300)
+    lines = [json.loads(l) for l in proc.stdout.splitlines() if l.startswith("{")]
+    assert [l["phase"] for l in lines if "phase" in l][:2] == ["device", "device"]
+    assert lines[1]["ok"] is False and "no accelerator" in lines[1]["error"]
+    assert proc.stdout.strip().splitlines()[-1].startswith('{"ok": false')
+    assert proc.returncode != 0
+
+
+def test_alone_in_a_directory_it_fails_without_a_result(tmp_path):
+    import shutil
+
+    shutil.copy(os.path.join(ROOT, "chip_smoke.py"), tmp_path / "chip_smoke.py")
+    proc = subprocess.run(
+        [sys.executable, str(tmp_path / "chip_smoke.py")],
+        capture_output=True, text=True, timeout=60, cwd=str(tmp_path),
+    )
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout

@@ -1,6 +1,10 @@
 """CLI verb tests (reference Console scope, SURVEY.md section 2.4)."""
 
+import os
+
 from predictionio_tpu.tools.cli import main
+
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def run(capsys, *argv):
@@ -55,6 +59,72 @@ class TestAppVerbs:
         assert "ready to go" in out
         code, out = run(capsys, "version")
         assert code == 0
+
+    def test_status_names_the_device_it_found(self, storage_env, capsys):
+        code, out = run(capsys, "status")
+        assert code == 0
+        assert "Device: cpu x8 (cpu)" in out
+
+    def test_status_fails_when_the_platform_does_not_come_up(
+        self, storage_env, capsys, monkeypatch
+    ):
+        """No "falls back to CPU": a platform that is not there is named
+        and the diagnostic returns non-zero."""
+        monkeypatch.setenv("PIO_PLATFORM", "nochip")
+        code, out = run(capsys, "status")
+        assert code == 1
+        assert "Device: NOT AVAILABLE" in out and "nochip" in out
+        assert "falls back" not in out and "fall back" not in out
+
+
+class TestNoChipNoNumber:
+    def test_train_with_an_unavailable_platform_fails_and_names_it(
+        self, storage_env, tmp_path
+    ):
+        import json
+        import subprocess
+        import sys
+
+        from predictionio_tpu.data import DataMap, Event
+        from predictionio_tpu.data.storage.base import App
+
+        app_id = storage_env.get_meta_data_apps().insert(App(name="MyApp"))
+        le = storage_env.get_l_events()
+        le.init_channel(app_id)
+        le.batch_insert(
+            [Event(event="rate", entity_type="user", entity_id=f"u{k % 7}",
+                   target_entity_type="item", target_entity_id=f"i{k % 5}",
+                   properties=DataMap({"rating": float(1 + k % 5)}))
+             for k in range(40)],
+            app_id=app_id,
+        )
+        with open(os.path.join(_REPO_ROOT, "examples", "recommendation", "engine.json")) as f:
+            (tmp_path / "engine.json").write_text(json.dumps(json.load(f)))
+        proc = subprocess.run(
+            [sys.executable, "-m", "predictionio_tpu.tools.cli", "train",
+             "--engine-dir", str(tmp_path)],
+            env={**os.environ, "PIO_PLATFORM": "nochip", "PYTHONPATH": _REPO_ROOT},
+            capture_output=True, text=True, timeout=180, cwd=str(tmp_path),
+        )
+        assert proc.returncode != 0
+        assert "'nochip' (from PIO_PLATFORM) did not initialise" in proc.stderr
+        assert "Training completed" not in proc.stdout
+
+    def test_bench_without_a_chip_prints_no_number_and_exits_non_zero(self, tmp_path):
+        import json
+        import subprocess
+        import sys
+
+        env = {**os.environ, "PIO_BENCH_DEADLINE_S": "120"}
+        env.pop("PIO_BENCH_PLATFORM", None)
+        proc = subprocess.run(
+            [sys.executable, os.path.join(_REPO_ROOT, "bench.py")],
+            env=env, capture_output=True, text=True, timeout=300, cwd=str(tmp_path),
+        )
+        assert proc.returncode != 0
+        last = json.loads(proc.stdout.strip().splitlines()[-1])
+        assert last["ok"] is False and "no accelerator" in last["error"]
+        assert "value" not in last and "metric" not in last
 
 
 class TestBuildVerbs:

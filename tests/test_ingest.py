@@ -351,7 +351,16 @@ class TestPartitionedPipeline:
                 f"{base}/events.json", params={"accessKey": key}, json=VALID
             )
             assert r.status_code == 201
-            text = requests.get(f"{base}/metrics").text
+            # the 201 is the WAL ack; the commit histogram is observed by
+            # the writer after the storage flush that follows it, so poll
+            # instead of racing it (lost on a loaded 2-core box)
+            deadline = time.time() + 10.0
+            while True:
+                text = requests.get(f"{base}/metrics").text
+                if 'pio_ingest_commit_seconds_count{part="' in text:
+                    break
+                assert time.time() < deadline, text[-600:]
+                time.sleep(0.05)
         finally:
             svc.stop()
         assert "pio_ingest_partitions 3" in text

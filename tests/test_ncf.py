@@ -53,32 +53,50 @@ class TestPallasKernel:
         np.testing.assert_allclose(via_model, via_ref, rtol=1e-4, atol=1e-5)
 
 
-class TestServingFallback:
-    def test_pallas_scorer_falls_back_on_cpu(self, tiny_params):
-        """A model trained with usePallas=True that deploys onto a host
-        whose backend cannot lower the kernel must serve through the XLA
-        reference path (permanently, after one logged failure) instead of
-        500-ing every /queries.json call."""
+class TestServingRefusesToDegrade:
+    def _model(self, tiny_params, **kw):
         from predictionio_tpu.models.ncf.engine import NCFModel
 
         config, params = tiny_params
-        model = NCFModel(
+        return NCFModel(
             params=params,
             user_index={"u0": 0},
             item_ids=[f"i{j}" for j in range(config.num_items)],
             item_index={f"i{j}": j for j in range(config.num_items)},
             seen={},
-            use_pallas=True,  # on the CPU test backend Mosaic can't lower
+            **kw,
         )
+
+    def test_kernel_build_failure_raises_at_warm_up(self, tiny_params, monkeypatch):
+        """With usePallas the scorer is the kernel or the deploy fails:
+        a kernel the device refuses must surface at warm_up, not be
+        swapped for the XLA reference for the life of the process."""
+        from predictionio_tpu.models.ncf import engine as ncf_engine
+
+        def refuse(*args, **kwargs):
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+        monkeypatch.setattr(ncf_engine, "make_all_items_scorer", refuse)
+        model = self._model(tiny_params, use_pallas=True)
+        algo = ncf_engine.NCFAlgorithm({})
+        with pytest.raises(RuntimeError, match="Mosaic failed"):
+            algo.warm_up(model)
+        assert model._scorer is None  # nothing was installed in its place
+
+    def test_warm_up_compiles_the_kernel_it_will_serve(self, tiny_params):
+        """On the CPU test backend the same kernel code runs interpreted
+        (the platform decides, as for every kernel here) and warm_up has
+        already called it once; the registry names which way it ran."""
+        from predictionio_tpu.models.ncf import engine as ncf_engine
+        from predictionio_tpu.utils.platform import device_report
+
+        config, params = tiny_params
+        model = self._model(tiny_params, use_pallas=True)
+        ncf_engine.NCFAlgorithm({}).warm_up(model)
         got = np.asarray(model.scorer()(3))
         want = reference_score_all_items(params, 3, config.num_items)
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
-        # and the swap is sticky: a second call goes straight to fallback
-        got2 = np.asarray(model.scorer()(5))
-        np.testing.assert_allclose(
-            got2, reference_score_all_items(params, 5, config.num_items),
-            rtol=2e-4, atol=2e-5,
-        )
+        assert device_report()["kernels"]["ncf_score_all_items"] == "interpreted"
 
 
 class TestTraining:

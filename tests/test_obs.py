@@ -591,7 +591,7 @@ class TestHttpTracing:
         assert 'version="' in line
         assert "jax_version=" in line
         assert "backend=" in line
-        assert "legacy_jax=" in line
+        assert "legacy_jax=" not in line
         assert line.rstrip().endswith(" 1")
 
     def test_span_histogram_bridge(self, server):

@@ -524,6 +524,32 @@ def _start_fabric(variant, num_shards=2, workers=1, model_version=None):
     return fabric
 
 
+class TestOneProcessForEachChip:
+    """Scorer shards are N JAX processes on one host: host-only, said by
+    the supervisor, and refused for engines that ask for a device path."""
+
+    def test_shard_children_are_told_the_cpu(self, rec_app, tmp_path, monkeypatch):
+        from predictionio_tpu.serving.fabric import ShardFabric
+
+        variant, _ = _train_rec_variant(tmp_path)
+        monkeypatch.setenv("PIO_PLATFORM", "tpu")
+        env = ShardFabric(variant, num_shards=2)._child_env()
+        assert env["JAX_PLATFORMS"] == "cpu"
+        assert "PIO_PLATFORM" not in env
+
+    @pytest.mark.parametrize("params, named", [
+        ({"retrieval": {"mode": "mips"}}, "mips"),
+        ({"usePallas": True}, "usePallas"),
+    ])
+    def test_device_query_paths_are_refused(self, rec_app, tmp_path, params, named):
+        from predictionio_tpu.serving.fabric import ShardFabric
+
+        variant, _ = _train_rec_variant(tmp_path)
+        variant.engine_params.algorithm_params_list[0][1].update(params)
+        with pytest.raises(ValueError, match=named):
+            ShardFabric(variant, num_shards=2)
+
+
 class TestShardFabric:
     def test_byte_identity_and_per_shard_swap(self, rec_app, tmp_path):
         """End-to-end through real processes: every user's response from

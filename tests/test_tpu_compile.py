@@ -1,0 +1,177 @@
+"""Compile the main path's kernels for a described TPU v5e, without the chip.
+
+The only test file that describes the chip. The TPU compiler is installed
+here and compiles for a ``v5e:2x2`` that is described and not attached, so
+what Mosaic or the Pallas lowering would refuse on the machine with the chip
+(a slice not aligned to the tiling, a block shape the lowering does not
+admit) is refused here, where it costs no chip time. Interpret-mode tests
+cannot see any of it. Nothing runs: a compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, every xdist worker
+imports every test file, and only the worker that is given this file may
+load it. Keep every described-chip test in THIS file.
+"""
+
+import functools
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A described-chip compile is written to the persistent cache but
+    cannot be read back without a chip: keep the cache off around these."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, *shapes) -> str:
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+@pytest.mark.parametrize("rank", [16, 128])
+@pytest.mark.parametrize("implicit", [False, True], ids=["explicit", "implicit"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_gram_rhs_compiles(one_chip, no_persistent_cache, dtype, implicit, rank):
+    """The fused gather->Gram half-step at the bench block shape, against a
+    27k-row factor table. The seed kernel was refused here in every mode (a
+    one-row DMA out of a [rows, K] table: sublane tiling for bf16, lane
+    tiling for K < 128)."""
+    from predictionio_tpu.ops.als_gram import gram_rhs
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    text = _compiled_text(
+        functools.partial(gram_rhs, implicit=implicit, interpret=False),
+        sds((4096, 256), jnp.int32),
+        sds((4096, 256), jnp.float32),
+        sds((27_001, rank), dtype),
+        sds((), jnp.float32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_gram_rhs_compiles_for_a_block_longer_than_smem(one_chip, no_persistent_cache):
+    """The recommendation template's single-bucket item side at the
+    MovieLens-1M shape: [3712, 23832]. One grid step over the whole length
+    wants a 1.5 MB index window in 1 MB of SMEM; tiled over L it fits."""
+    from predictionio_tpu.ops.als_gram import gram_rhs
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    text = _compiled_text(
+        functools.partial(gram_rhs, implicit=False, interpret=False),
+        sds((3712, 23_832), jnp.int32),
+        sds((3712, 23_832), jnp.float32),
+        sds((6_041, 16), jnp.float32),
+        sds((), jnp.float32),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rank", [16, 128])
+def test_mips_block_topk_compiles(one_chip, no_persistent_cache, rank):
+    """Stage 1 of device retrieval at 1M items, default tile and top-R."""
+    from predictionio_tpu.ops.mips import BLOCK_QUERIES, mips_block_topk
+    from predictionio_tpu.ops.quantize import BLOCK_ITEMS
+
+    items = 1_000_000
+    padded = -(-items // BLOCK_ITEMS) * BLOCK_ITEMS
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    text = _compiled_text(
+        functools.partial(
+            mips_block_topk, block_topk=16, num_items=items, interpret=False
+        ),
+        sds((BLOCK_QUERIES, rank), jnp.float32),
+        sds((padded, rank), jnp.int8),
+        sds((padded // BLOCK_ITEMS, 1), jnp.float32),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("shape", [(8, 512, 2, 32), (4, 2048, 4, 64)],
+                         ids=["b8_t512_h2_d32", "b4_t2048_h4_d64"])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_attention_compiles(one_chip, no_persistent_cache, direction, shape):
+    from predictionio_tpu.ops.flash_attention import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, None, True, None, False)
+
+    fn = fwd
+    if direction == "bwd":
+        fn = jax.grad(lambda q, k, v: fwd(q, k, v).sum(), argnums=(0, 1, 2))
+    qkv = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    assert "tpu_custom_call" in _compiled_text(fn, qkv, qkv, qkv)
+
+
+def test_ncf_scorer_compiles(one_chip, no_persistent_cache):
+    """The all-items NeuMF scorer of examples/ncf (E=32, hidden (64, 32))
+    over a 27k-item catalog."""
+    from predictionio_tpu.models.ncf.kernel import TILE_I, score_call
+
+    e, h0, h1 = 32, 64, 32
+    padded = -(-27_000 // TILE_I) * TILE_I
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    text = _compiled_text(
+        score_call(padded, e, h0, h1, interpret=False),
+        sds(padded, e), sds(padded, e), sds(1, e), sds(1, e),
+        sds(e, h0), sds(e, h0), sds(1, h0), sds(h0, h1), sds(1, h1),
+        sds(1, e), sds(1, h1), sds(1, 1),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_model_sharded_als_compiles_on_four_chips(topo, no_persistent_cache):
+    """The ALX block body (``factor_sharding="model"``) on ``Mesh(topo.devices)``
+    as data=2 x model=2: the fused kernel per device and a reduce-scatter of
+    its partial Gram/rhs over the model axis."""
+    from predictionio_tpu.parallel.als import ALSConfig, make_iteration
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    config = ALSConfig(rank=8, factor_sharding="model", solver="auto")
+    row, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    fsh = NamedSharding(mesh, P("model"))
+    rows, length = 1024, 64
+
+    def block():
+        return ((
+            jax.ShapeDtypeStruct((rows, length), jnp.int32, sharding=row),
+            jax.ShapeDtypeStruct((rows, length), jnp.float32, sharding=row),
+            jax.ShapeDtypeStruct((rows,), jnp.float32, sharding=row),
+        ),)
+
+    factors = jax.ShapeDtypeStruct((rows, 8), jnp.float32, sharding=fsh)
+    scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=rep)
+    text = make_iteration(mesh, config).lower(
+        block(), block(), factors, factors, scalar, scalar
+    ).compile().as_text()
+    assert "reduce-scatter" in text
+    assert "tpu_custom_call" in text  # auto resolved to pallas: the mesh is TPU
