@@ -1,0 +1,7 @@
+"""Device milliseconds per ALS iteration under ``als.item_half_step``."""
+
+from benchmarks import scopes
+
+
+def read(run):
+    return scopes.per_iteration_ms(run, "sides", "als.item_half_step")

@@ -55,17 +55,30 @@ def prepare_als_data(
         model_shards = ctx.mesh.shape.get("model", 1)
     except Exception:
         pass  # no devices available (pure-host tests)
-    return build_als_data(
-        users,
-        items,
-        values,
-        num_users,
-        num_items,
-        config,
-        times=times,
-        num_shards=num_shards,
-        model_shards=model_shards,
-    )
+    from predictionio_tpu.obs.trace import global_tracer
+
+    with global_tracer().span("als.pack") as span:
+        data = build_als_data(
+            users,
+            items,
+            values,
+            num_users,
+            num_items,
+            config,
+            times=times,
+            num_shards=num_shards,
+            model_shards=model_shards,
+        )
+        # counts the packer has already: no pass over the data for them
+        edges = len(users)
+        span.set_attr("edges", edges)
+        for name, side in (("by_row", data.by_row), ("by_col", data.by_col)):
+            span.set_attr(name, {
+                "retained_edges": edges - side.truncated,
+                "padded_slots": side.padded_slots,
+                "buckets": len(side.blocks),
+            })
+    return data
 
 
 #: packing knobs the PREPARATOR consumes; a natural mistake is putting
@@ -593,8 +606,12 @@ def _build_telemetry(ctx, als_data, config: ALSConfig, mesh, name: str):
         return TrainTelemetry(
             os.path.join(str(profile_dir), f"{name}-telemetry.jsonl"),
             edges=real_edges(als_data),
-            modeled_bytes_per_iter=modeled_bytes_per_iteration(
-                als_data, config.rank, itemsize, fused=solver == "pallas"
+            # the model counts padded slots through the XLA tail's gathered
+            # intermediate; it does not describe the fused kernel, so the
+            # journal writes no achieved_gbps there
+            modeled_bytes_per_iter=None if solver == "pallas" else
+            modeled_bytes_per_iteration(
+                als_data, config.rank, itemsize, fused=False
             ),
             meta={
                 "name": name,

@@ -26,6 +26,10 @@ from predictionio_tpu.utils.platform import note_kernel
 
 logger = logging.getLogger("pio.ncf")
 
+#: the kernel's name in ``device_report``, the compiled program and a
+#: profiler trace
+KERNEL_NAME = "ncf_score_all_items"
+
 # 1024 = XLA's tile for 1-D f32 arrays (8 sublanes x 128 lanes): the
 # kernel's output block must match it exactly -- real TPU lowering rejects
 # a T(512) Mosaic layout against XLA's T(1024) (interpret mode cannot see
@@ -105,6 +109,7 @@ def score_call(padded: int, e: int, h0: int, h1: int, interpret: bool):
         ],
         out_specs=pl.BlockSpec((TILE_I,), lambda i: (i,)),
         interpret=interpret,
+        name=KERNEL_NAME,
     )
 
 
@@ -129,7 +134,7 @@ def make_all_items_scorer(params, num_items: int, interpret: bool):
         return lambda user_index: reference_score_all_items(
             params, user_index, num_items
         )
-    note_kernel("ncf_score_all_items", interpret)
+    note_kernel(KERNEL_NAME, interpret)
     e = params["gmf_user"]["embedding"].shape[1]
     h0 = params["mlp_0"]["kernel"].shape[1]
     h1 = params["mlp_1"]["kernel"].shape[1]

@@ -5,9 +5,11 @@ the primary training metric; the ``jax.profiler`` trace gives the deep
 view but needs tensorboard/xprof to open. This journal is the cheap,
 always-parseable companion: one JSON line per training step with wall
 time, edges/sec, and the achieved HBM GB/s implied by the bytes-moved
-model (``ops.als_gram.half_step_bytes``), plus the jit recompile count so
-a shape-instability regression (recompiling every step) is visible as a
-climbing integer instead of a mysteriously slow run.
+model (``ops.als_gram.half_step_bytes``; not written for the fused Pallas
+path, which that model does not describe), plus the process's count of
+compilations (``pio_jit_compiles_total``) so a shape-instability regression
+(recompiling every step) is visible as a climbing integer instead of a
+mysteriously slow run.
 
 Lines are flushed as written: a crashed or preempted run keeps every
 completed step's record.
@@ -37,6 +39,11 @@ class TrainTelemetry:
         self.edges = edges
         self.modeled_bytes_per_iter = modeled_bytes_per_iter
         self.steps = 0
+        # a loop that was not started through ``ensure_backend`` still gets
+        # its compilations counted from the journal's first line on
+        from predictionio_tpu.utils.platform import count_compiles
+
+        count_compiles()
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         self._f = open(path, "w")
         self._write(
@@ -93,10 +100,10 @@ class TrainTelemetry:
         self.close()
 
 
-def jit_cache_size(fn) -> int | None:
-    """Compiled-program count of a ``jax.jit`` callable (the recompile
-    counter's source), or None where the private API is absent."""
-    try:
-        return int(fn._cache_size())
-    except Exception:
-        return None
+def compiles_so_far() -> int:
+    """Programs this process has compiled or loaded from the persistent
+    cache since its counters started (``pio_jit_compiles_total``): the
+    journal's ``recompile_count``."""
+    from predictionio_tpu.utils.metrics import global_registry
+
+    return int(global_registry().counter_value("pio_jit_compiles_total"))

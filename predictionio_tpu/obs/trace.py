@@ -35,6 +35,7 @@ import logging
 import os
 import random
 import re
+import sys
 import threading
 import time
 from collections import deque
@@ -241,11 +242,17 @@ class Span:
     the ring consumer, attach around the micro-batcher submit so
     ``current_context()`` captures it, detach, and finish from the
     flusher's ``Future.add_done_callback``. ``__enter__``/``__exit__``
-    are exactly ``attach()`` + (``detach()``; ``finish()``)."""
+    are ``attach()`` + (``detach()``; ``finish()``), and while a
+    ``jax.profiler`` session runs (``pio train --profile``, a traced
+    benchmark window) they also open a ``TraceAnnotation`` under the op
+    the span was started with: the span then lies on the profiler's host
+    plane, on the device's clock. Handle-style spans get none (an
+    annotation closes on the thread that opened it), and explicitly timed
+    records (``record_span``, ``record_fanout``) stay host-clock only."""
 
     __slots__ = (
         "_tracer", "op", "trace_id", "span_id", "parent_id", "attrs",
-        "status", "_start_pc", "_root", "_finished",
+        "status", "_start_pc", "_root", "_finished", "_annotation",
     )
 
     def __init__(self, tracer: "Tracer", op: str, trace_id: str,
@@ -259,6 +266,7 @@ class Span:
         self.status = "ok"
         self._root = root
         self._finished = False
+        self._annotation = None
         if root:
             # register the trace as live IMMEDIATELY: record_span from
             # another thread can attach to it for the root's whole lifetime
@@ -316,9 +324,18 @@ class Span:
         self._tracer._span_finished(record, self._root)
 
     def __enter__(self) -> "Span":
+        # only where jax is in the process already (obs never imports it);
+        # with no session running this is one dictionary lookup and one call
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is not None and profiler.TraceAnnotation.is_enabled():
+            self._annotation = profiler.TraceAnnotation(self.op)
+            self._annotation.__enter__()
         return self.attach()
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         self.detach()
         if exc_type is not None:
             self.status = "error"

@@ -72,6 +72,10 @@ from predictionio_tpu.utils.jax_compat import (
 )
 from predictionio_tpu.utils.platform import note_kernel
 
+#: the kernel's name: in ``device_report`` and, as the custom call's name, in
+#: the compiled program and a profiler trace
+KERNEL_NAME = "als_gram_rhs"
+
 #: rows per grid step (a CAP: the largest power of two <= this that divides
 #: the block's rows is used, so a 24-row block split over a 2-device data
 #: axis -- 12 rows per device -- runs at BR=4 instead of failing). 8 keeps
@@ -232,7 +236,7 @@ def gram_rhs(
         br //= 2  # e.g. 12 rows/device under a 2-way data split -> BR=4
     tile = min(pad_len, MAX_TILE_LEN)
     chunk = _pick_chunk(tile)
-    note_kernel("als_gram_rhs", interpret)
+    note_kernel(KERNEL_NAME, interpret)
     # gather layout the chip admits (see module docstring): 32-bit rows a
     # whole number of lanes wide. The upcast is exact and the pad lanes are
     # never read, so Gram/rhs are what the [S + 1, K] table defines.
@@ -272,6 +276,7 @@ def gram_rhs(
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
+        name=KERNEL_NAME,
     )(jnp.asarray(indices, jnp.int32), values, alpha_arr, table)
 
 

@@ -67,6 +67,15 @@ from predictionio_tpu.ops.quantize import (
 #: query rows per grid step (f32 sublane multiple)
 BLOCK_QUERIES = 8
 
+#: the stage-1 kernel's name in ``device_report``, the compiled program and
+#: a profiler trace
+KERNEL_NAME = "mips_block_topk"
+
+#: device scopes of ``_search_program`` (``jax.named_scope``, metadata only)
+SCOPE_STAGE1 = "mips.stage1"
+SCOPE_MERGE = "mips.merge"
+SCOPE_RERANK = "mips.rerank"
+
 #: matches plain_attention/flash_attention's finite masked-score constant:
 #: masking stays finite inside the kernel; -inf sentinels are applied at
 #: the (host/XLA) merge where they are cheap and safe. Padding rows mask
@@ -107,7 +116,7 @@ def mips_block_topk(
     from predictionio_tpu.utils.jax_compat import pallas as pl, shape_struct
     from predictionio_tpu.utils.platform import note_kernel
 
-    note_kernel("mips_block_topk", interpret)
+    note_kernel(KERNEL_NAME, interpret)
     b, k = queries.shape
     padded_items = q_table.shape[0]
     nb = scales.shape[0]
@@ -184,6 +193,7 @@ def mips_block_topk(
             shape_struct((nb, b, r), jnp.int32, queries),
         ],
         interpret=interpret,
+        name=KERNEL_NAME,
     )(queries, q_table, scales.reshape(nb, 1, 1))
     scores = jnp.swapaxes(scores, 0, 1)
     idx = jnp.swapaxes(idx, 0, 1)
@@ -215,41 +225,42 @@ def _search_program(
     import jax
     import jax.numpy as jnp
 
-    if num_items <= shortlist:
-        width = min(shortlist, q_table.shape[0])
-        base = jnp.arange(width, dtype=jnp.int32)
-        sel = jnp.where(base < num_items, base, num_items)
-        sel = jnp.broadcast_to(sel, (queries.shape[0], width))
-        gathered = table_f32[jnp.clip(sel, 0, num_items - 1)]
-        exact = jnp.einsum(
-            "bk,bsk->bs", queries, gathered,
-            precision="highest", preferred_element_type=jnp.float32,
-        )
-        exact = jnp.where(sel < num_items, exact, -jnp.inf)
-        return sel, exact
+    def rerank(sel):
+        with jax.named_scope(SCOPE_RERANK):
+            gathered = table_f32[jnp.clip(sel, 0, num_items - 1)]  # [B, S, K]
+            # "highest": the re-rank is the EXACT stage; at the MXU's default
+            # the f32 operands would be rounded to bf16
+            exact = jnp.einsum(
+                "bk,bsk->bs", queries, gathered,
+                precision="highest", preferred_element_type=jnp.float32,
+            )
+            return jnp.where(sel < num_items, exact, -jnp.inf)
 
-    cand_s, cand_i = mips_block_topk(
-        queries, q_table, scales,
-        block_topk=block_topk, num_items=num_items, interpret=interpret,
-    )
-    valid = cand_i < num_items
-    cand_s = jnp.where(valid, cand_s, -jnp.inf)
-    cand_i = jnp.where(valid, cand_i, num_items)   # sentinel sorts last
-    s = min(shortlist, cand_s.shape[1])
-    _, pos = jax.lax.top_k(cand_s, s)
-    sel = jnp.take_along_axis(cand_i, pos, axis=1)
-    # ascending catalog order: the host tail's stable ranking then breaks
-    # score ties by global index, byte-matching the full scan's order
-    sel = jnp.sort(sel, axis=1)
-    gathered = table_f32[jnp.clip(sel, 0, num_items - 1)]        # [B, S, K]
-    # "highest": the re-rank is the EXACT stage; at the MXU's default the
-    # f32 operands would be rounded to bf16
-    exact = jnp.einsum(
-        "bk,bsk->bs", queries, gathered,
-        precision="highest", preferred_element_type=jnp.float32,
-    )
-    exact = jnp.where(sel < num_items, exact, -jnp.inf)
-    return sel, exact
+    if num_items <= shortlist:
+        with jax.named_scope(SCOPE_MERGE):
+            width = min(shortlist, q_table.shape[0])
+            base = jnp.arange(width, dtype=jnp.int32)
+            sel = jnp.where(base < num_items, base, num_items)
+            sel = jnp.broadcast_to(sel, (queries.shape[0], width))
+        return sel, rerank(sel)
+
+    with jax.named_scope(SCOPE_STAGE1):
+        cand_s, cand_i = mips_block_topk(
+            queries, q_table, scales,
+            block_topk=block_topk, num_items=num_items, interpret=interpret,
+        )
+    with jax.named_scope(SCOPE_MERGE):
+        valid = cand_i < num_items
+        cand_s = jnp.where(valid, cand_s, -jnp.inf)
+        cand_i = jnp.where(valid, cand_i, num_items)   # sentinel sorts last
+        s = min(shortlist, cand_s.shape[1])
+        _, pos = jax.lax.top_k(cand_s, s)
+        sel = jnp.take_along_axis(cand_i, pos, axis=1)
+        # ascending catalog order: the host tail's stable ranking then
+        # breaks score ties by global index, byte-matching the full scan's
+        # order
+        sel = jnp.sort(sel, axis=1)
+    return sel, rerank(sel)
 
 
 @dataclass(frozen=True)

@@ -51,6 +51,24 @@ from predictionio_tpu.utils.jax_compat import axis_size, shard_map
 
 logger = logging.getLogger("pio.als")
 
+#: Device scopes of one iteration (``jax.named_scope``; metadata only, no
+#: operation). Every device operation of ``_build_iteration``'s program falls
+#: under ``als.<side>_half_step/bucket<i>/<stage>`` or, outside the buckets,
+#: ``als.<side>_half_step/assemble``. A profiler trace or the compiled text
+#: carries them as each instruction's ``op_name``; other components (``jit(
+#: iteration)``, ``shard_map``) may sit between these.
+SCOPE_HALF_STEP = {"user": "als.user_half_step", "item": "als.item_half_step"}
+SCOPE_BUCKET = "bucket{}"
+#: the fused kernel, or the gather (with its model-axis exchange) and the two
+#: einsums; on the Pallas path also the kernel's lane-padded f32 copy of the
+#: gather table, which ``gram_rhs`` makes for every bucket
+SCOPE_GRAM = "gram"
+#: ridge, ``ops.linalg.batched_spd_solve``, the cast back to the factor dtype
+SCOPE_SOLVE = "solve"
+#: the zero row, the replicated constraint, YtY, and putting the buckets'
+#: rows together
+SCOPE_ASSEMBLE = "assemble"
+
 
 @dataclass
 class ALSConfig:
@@ -370,9 +388,10 @@ def _finish_explicit(gram, rhs, n_obs, reg, rank, unroll, out_dtype):
     (so solver parity reduces to Gram/rhs parity)."""
     # MLlib-style weighted regularization: lambda * n_obs (ALS-WR); constant
     # lambda would also be defensible -- n_obs matches the reference template
-    ridge = reg * jnp.maximum(n_obs, 1.0)
-    gram = gram + ridge[:, None, None] * jnp.eye(rank, dtype=gram.dtype)
-    return batched_spd_solve(gram, rhs, unroll=unroll).astype(out_dtype)
+    with jax.named_scope(SCOPE_SOLVE):
+        ridge = reg * jnp.maximum(n_obs, 1.0)
+        gram = gram + ridge[:, None, None] * jnp.eye(rank, dtype=gram.dtype)
+        return batched_spd_solve(gram, rhs, unroll=unroll).astype(out_dtype)
 
 
 def _finish_implicit(gram_fix, rhs, yty, reg, rank, unroll, out_dtype):
@@ -380,8 +399,9 @@ def _finish_implicit(gram_fix, rhs, yty, reg, rank, unroll, out_dtype):
 
     ``gram_fix`` holds only the per-row observed-entry corrections
     sum_obs (c-1) y y^T; the replicated global Gram lands here."""
-    gram = yty[None] + gram_fix + reg * jnp.eye(rank, dtype=yty.dtype)
-    return batched_spd_solve(gram, rhs, unroll=unroll).astype(out_dtype)
+    with jax.named_scope(SCOPE_SOLVE):
+        gram = yty[None] + gram_fix + reg * jnp.eye(rank, dtype=yty.dtype)
+        return batched_spd_solve(gram, rhs, unroll=unroll).astype(out_dtype)
 
 
 def _gram_solve_explicit(gathered, values, n_obs, reg, rank, unroll, out_dtype):
@@ -404,15 +424,16 @@ def _gram_solve_explicit(gathered, values, n_obs, reg, rank, unroll, out_dtype):
     back to ``out_dtype`` on return. ``reg`` may be a traced scalar (the
     iteration program is shared across regularization values).
     """
-    gram = jnp.einsum(
-        "rlk,rlj->rkj", gathered, gathered,
-        precision=_factor_precision(gathered.dtype),
-        preferred_element_type=jnp.float32,
-    )
-    rhs = jnp.einsum(
-        "rlk,rl->rk", gathered, values,
-        precision="highest", preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope(SCOPE_GRAM):
+        gram = jnp.einsum(
+            "rlk,rlj->rkj", gathered, gathered,
+            precision=_factor_precision(gathered.dtype),
+            preferred_element_type=jnp.float32,
+        )
+        rhs = jnp.einsum(
+            "rlk,rl->rk", gathered, values,
+            precision="highest", preferred_element_type=jnp.float32,
+        )
     return _finish_explicit(gram, rhs, n_obs, reg, rank, unroll, out_dtype)
 
 
@@ -426,15 +447,16 @@ def _gram_solve_implicit(gathered, values, yty, reg, alpha, rank, unroll, out_dt
     multiplies the gathered zero row). Implicit mode uses constant lambda
     (MLlib trainImplicit parity), so no n_obs.
     """
-    conf_minus_1 = alpha * values
-    gram_fix = jnp.einsum(
-        "rlk,rl,rlj->rkj", gathered, conf_minus_1, gathered,
-        precision="highest", preferred_element_type=jnp.float32,
-    )
-    rhs = jnp.einsum(
-        "rlk,rl->rk", gathered, (1.0 + conf_minus_1),
-        precision="highest", preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope(SCOPE_GRAM):
+        conf_minus_1 = alpha * values
+        gram_fix = jnp.einsum(
+            "rlk,rl,rlj->rkj", gathered, conf_minus_1, gathered,
+            precision="highest", preferred_element_type=jnp.float32,
+        )
+        rhs = jnp.einsum(
+            "rlk,rl->rk", gathered, (1.0 + conf_minus_1),
+            precision="highest", preferred_element_type=jnp.float32,
+        )
     return _finish_implicit(gram_fix, rhs, yty, reg, rank, unroll, out_dtype)
 
 
@@ -449,7 +471,8 @@ def _factors_yty(factors):
 
 def _half_step_explicit(indices, values, n_obs, factors, reg, rank, unroll):
     """Replicated-factor explicit half-step (gather + shared tail)."""
-    gathered = factors[indices]                       # [R, L, K]
+    with jax.named_scope(SCOPE_GRAM):
+        gathered = factors[indices]                   # [R, L, K]
     return _gram_solve_explicit(
         gathered, values, n_obs, reg, rank, unroll, factors.dtype
     )
@@ -465,7 +488,8 @@ def _half_step_implicit(indices, values, n_obs, factors, yty, reg, alpha,
     would redo the [S, K] reduction for every bucket).
     """
     del n_obs
-    gathered = factors[indices]
+    with jax.named_scope(SCOPE_GRAM):
+        gathered = factors[indices]
     return _gram_solve_implicit(
         gathered, values, yty, reg, alpha, rank, unroll, factors.dtype
     )
@@ -481,9 +505,10 @@ def _half_step_pallas(idx, values, n_obs, factors, yty, reg, alpha,
     factor table and solves its rows locally -- no collectives; the
     [rows, L, K] gathered intermediate never exists in HBM.
     """
-    gram, rhs = gram_rhs(
-        idx, values, factors, alpha, implicit=implicit, interpret=interpret
-    )
+    with jax.named_scope(SCOPE_GRAM):
+        gram, rhs = gram_rhs(
+            idx, values, factors, alpha, implicit=implicit, interpret=interpret
+        )
     if implicit:
         return _finish_implicit(
             gram, rhs, yty, reg, rank, unroll, factors.dtype
@@ -522,42 +547,47 @@ def _sharded_block_body(idx, values, n_obs, opp_local, yty, reg, alpha,
     Output rows per device: the model-axis slice of the local data shard,
     i.e. global layout P(("data", "model")).
     """
-    m = axis_size("model")
-    mi = jax.lax.axis_index("model")
-    s_m = opp_local.shape[0]
-    loc = idx - mi * s_m
-    rows = idx.shape[0] // m
+    with jax.named_scope(SCOPE_GRAM):
+        m = axis_size("model")
+        mi = jax.lax.axis_index("model")
+        s_m = opp_local.shape[0]
+        loc = idx - mi * s_m
+        rows = idx.shape[0] // m
     if solver == "pallas":
-        hit = (loc >= 0) & (loc < s_m)
-        safe = jnp.where(hit, loc, s_m).astype(jnp.int32)
-        gram, rhs = gram_rhs(
-            safe, values, _append_zero_row(opp_local), alpha,
-            implicit=implicit, interpret=interpret,
-        )
-        gram = jax.lax.psum_scatter(
-            gram, "model", scatter_dimension=0, tiled=True
-        )
-        rhs = jax.lax.psum_scatter(
-            rhs, "model", scatter_dimension=0, tiled=True
-        )
+        with jax.named_scope(SCOPE_GRAM):
+            hit = (loc >= 0) & (loc < s_m)
+            safe = jnp.where(hit, loc, s_m).astype(jnp.int32)
+            gram, rhs = gram_rhs(
+                safe, values, _append_zero_row(opp_local), alpha,
+                implicit=implicit, interpret=interpret,
+            )
+            gram = jax.lax.psum_scatter(
+                gram, "model", scatter_dimension=0, tiled=True
+            )
+            rhs = jax.lax.psum_scatter(
+                rhs, "model", scatter_dimension=0, tiled=True
+            )
         if implicit:
             return _finish_implicit(
                 gram, rhs, yty, reg, rank, unroll, opp_local.dtype
             )
-        n_s = jax.lax.dynamic_slice_in_dim(n_obs, mi * rows, rows, 0)
+        with jax.named_scope(SCOPE_SOLVE):
+            n_s = jax.lax.dynamic_slice_in_dim(n_obs, mi * rows, rows, 0)
         return _finish_explicit(
             gram, rhs, n_s, reg, rank, unroll, opp_local.dtype
         )
-    hit = (loc >= 0) & (loc < s_m)
-    g = opp_local[jnp.clip(loc, 0, s_m - 1)]
-    g = g * hit[..., None].astype(g.dtype)
-    g = jax.lax.psum_scatter(g, "model", scatter_dimension=0, tiled=True)
-    val_s = jax.lax.dynamic_slice_in_dim(values, mi * rows, rows, 0)
+    with jax.named_scope(SCOPE_GRAM):
+        hit = (loc >= 0) & (loc < s_m)
+        g = opp_local[jnp.clip(loc, 0, s_m - 1)]
+        g = g * hit[..., None].astype(g.dtype)
+        g = jax.lax.psum_scatter(g, "model", scatter_dimension=0, tiled=True)
+        val_s = jax.lax.dynamic_slice_in_dim(values, mi * rows, rows, 0)
     if implicit:
         return _gram_solve_implicit(
             g, val_s, yty, reg, alpha, rank, unroll, opp_local.dtype
         )
-    n_s = jax.lax.dynamic_slice_in_dim(n_obs, mi * rows, rows, 0)
+    with jax.named_scope(SCOPE_SOLVE):
+        n_s = jax.lax.dynamic_slice_in_dim(n_obs, mi * rows, rows, 0)
     return _gram_solve_explicit(
         g, val_s, n_s, reg, rank, unroll, opp_local.dtype
     )
@@ -686,38 +716,47 @@ def _build_iteration(mesh, rank: int, implicit: bool,
                 # inter-bucket padding rows are zero and the sentinel is
                 # out of every shard, so the full sharded [S, K] Gram is
                 # the implicit global term (GSPMD psums it once per side)
-                yty = side_yty(opp)
-                outs = [
-                    smapped(idx, val, n_obs, opp, yty, reg, alpha)
-                    for idx, val, n_obs in blocks
-                ]
-                if len(outs) == 1:
-                    # reshard P(("data","model")) -> P("model"): the
-                    # all-gather over 'data' that readies this side for
-                    # the next gather
-                    return jax.lax.with_sharding_constraint(outs[0], fsh)
-                # multi-bucket assembly resharded PIECEWISE via
-                # dynamic_update_slice: jnp.concatenate of differently
-                # tuple-sharded bucket outputs followed by a reshard
-                # miscompiles under the legacy (0.4.x) GSPMD partitioner
-                # (values land in the wrong rows); updating each bucket's
-                # rows into a P("model") buffer keeps every reshard a
-                # single-array one, which partitions correctly on both
-                # APIs and lowers to the same all-gather traffic
-                total = sum(o.shape[0] for o in outs)
-                buf = jax.lax.with_sharding_constraint(
-                    jnp.zeros((total, outs[0].shape[1]), outs[0].dtype),
-                    fsh,
-                )
-                off = 0
-                for o in outs:
-                    piece = jax.lax.with_sharding_constraint(o, fsh)
-                    buf = jax.lax.dynamic_update_slice(buf, piece, (off, 0))
-                    off += o.shape[0]
-                return jax.lax.with_sharding_constraint(buf, fsh)
+                with jax.named_scope(SCOPE_ASSEMBLE):
+                    yty = side_yty(opp)
+                outs = []
+                for b, (idx, val, n_obs) in enumerate(blocks):
+                    with jax.named_scope(SCOPE_BUCKET.format(b)):
+                        outs.append(
+                            smapped(idx, val, n_obs, opp, yty, reg, alpha)
+                        )
+                with jax.named_scope(SCOPE_ASSEMBLE):
+                    if len(outs) == 1:
+                        # reshard P(("data","model")) -> P("model"): the
+                        # all-gather over 'data' that readies this side
+                        # for the next gather
+                        return jax.lax.with_sharding_constraint(outs[0], fsh)
+                    # multi-bucket assembly resharded PIECEWISE via
+                    # dynamic_update_slice: jnp.concatenate of differently
+                    # tuple-sharded bucket outputs followed by a reshard
+                    # miscompiles under the legacy (0.4.x) GSPMD
+                    # partitioner (values land in the wrong rows); updating
+                    # each bucket's rows into a P("model") buffer keeps
+                    # every reshard a single-array one, which partitions
+                    # correctly on both APIs and lowers to the same
+                    # all-gather traffic
+                    total = sum(o.shape[0] for o in outs)
+                    buf = jax.lax.with_sharding_constraint(
+                        jnp.zeros((total, outs[0].shape[1]), outs[0].dtype),
+                        fsh,
+                    )
+                    off = 0
+                    for o in outs:
+                        piece = jax.lax.with_sharding_constraint(o, fsh)
+                        buf = jax.lax.dynamic_update_slice(
+                            buf, piece, (off, 0)
+                        )
+                        off += o.shape[0]
+                    return jax.lax.with_sharding_constraint(buf, fsh)
 
-            users = solve_side(u_blocks, items)
-            items = solve_side(i_blocks, users)
+            with jax.named_scope(SCOPE_HALF_STEP["user"]):
+                users = solve_side(u_blocks, items)
+            with jax.named_scope(SCOPE_HALF_STEP["item"]):
+                items = solve_side(i_blocks, users)
             return users, items
 
         return jax.jit(
@@ -754,31 +793,31 @@ def _build_iteration(mesh, rank: int, implicit: bool,
                 _half_step_explicit, reg=reg, rank=rank, unroll=unroll
             )
 
-        def solve_side(blocks, opp_full):
-            if solver == "pallas":
-                yty = side_yty(opp_full[:-1])
-                outs = [
-                    step(idx, val, n_obs, opp_full, yty, reg, alpha)
-                    for idx, val, n_obs in blocks
-                ]
-            elif implicit:
-                yty = side_yty(opp_full[:-1])
-                outs = [
-                    step(idx, val, n_obs, opp_full, yty)
-                    for idx, val, n_obs in blocks
-                ]
-            else:
-                outs = [
-                    step(idx, val, n_obs, opp_full)
-                    for idx, val, n_obs in blocks
-                ]
-            out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
-            return jax.lax.with_sharding_constraint(out, row)
+        def solve_side(blocks, opp):
+            with jax.named_scope(SCOPE_ASSEMBLE):
+                opp_full = jax.lax.with_sharding_constraint(
+                    _append_zero_row(opp), rep
+                )
+                # what each step takes after the table: the explicit XLA
+                # step nothing (and no YtY is computed for it)
+                if solver == "pallas":
+                    rest = (side_yty(opp_full[:-1]), reg, alpha)
+                elif implicit:
+                    rest = (side_yty(opp_full[:-1]),)
+                else:
+                    rest = ()
+            outs = []
+            for b, (idx, val, n_obs) in enumerate(blocks):
+                with jax.named_scope(SCOPE_BUCKET.format(b)):
+                    outs.append(step(idx, val, n_obs, opp_full, *rest))
+            with jax.named_scope(SCOPE_ASSEMBLE):
+                out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
+                return jax.lax.with_sharding_constraint(out, row)
 
-        items_full = jax.lax.with_sharding_constraint(_append_zero_row(items), rep)
-        users = solve_side(u_blocks, items_full)
-        users_full = jax.lax.with_sharding_constraint(_append_zero_row(users), rep)
-        items = solve_side(i_blocks, users_full)
+        with jax.named_scope(SCOPE_HALF_STEP["user"]):
+            users = solve_side(u_blocks, items)
+        with jax.named_scope(SCOPE_HALF_STEP["item"]):
+            items = solve_side(i_blocks, users)
         return users, items
 
     return jax.jit(
@@ -920,7 +959,8 @@ def als_fit(
 
     ``telemetry`` (``obs.telemetry.TrainTelemetry``) records one journal
     line per iteration: wall time, edges/sec, achieved GB/s vs the
-    bytes-moved model, recompile count. Per-step timing needs a device
+    bytes-moved model, and the programs this process has compiled or loaded
+    so far (steady after the first step). Per-step timing needs a device
     sync after EVERY iteration (a one-scalar fetch), which serializes the
     dispatch pipeline -- that cost is only paid when profiling is on;
     the un-profiled loop keeps its async chain.
@@ -1028,7 +1068,7 @@ def als_fit(
         return fetch(factors)[side.slot_of].astype(np.float32)
 
     if telemetry is not None:
-        from predictionio_tpu.obs.telemetry import jit_cache_size
+        from predictionio_tpu.obs.telemetry import compiles_so_far
 
         def step_sync(x) -> None:
             # one-scalar fetch: a hard device sync; the donated-buffer
@@ -1062,7 +1102,7 @@ def als_fit(
                 telemetry.record_step(
                     it,
                     time.perf_counter() - step_t0,
-                    recompile_count=jit_cache_size(iteration),
+                    recompile_count=compiles_so_far(),
                 )
         else:
             user_factors, item_factors = iteration(
@@ -1418,15 +1458,10 @@ def als_fit_streamed(
         return fetch_global(factors)[side.slot_of].astype(np.float32)
 
     if telemetry is not None:
-        from predictionio_tpu.obs.telemetry import jit_cache_size
+        from predictionio_tpu.obs.telemetry import compiles_so_far
 
         def step_sync(x) -> None:
             np.asarray(jax.device_get(x[:1, :1]))
-
-        def recompiles() -> int:
-            return sum(
-                jit_cache_size(programs.step(flag)) for flag in (True, False)
-            )
 
     for it in range(start_iteration, config.iterations):
         if telemetry is not None:
@@ -1442,7 +1477,7 @@ def als_fit_streamed(
                 telemetry.record_step(
                     it,
                     _time.perf_counter() - step_t0,
-                    recompile_count=recompiles(),
+                    recompile_count=compiles_so_far(),
                 )
         else:
             user_factors = solve_side(

@@ -81,6 +81,14 @@ class MetricsRegistry:
             series = self._counters.setdefault(name, {})
             series[key] = series.get(key, 0.0) + amount
 
+    def counter_value(
+        self, name: str, labels: Mapping[str, str] | None = None
+    ) -> float | None:
+        """One counter series as it stands, or None if nothing recorded it."""
+        key = tuple(sorted((labels or {}).items()))
+        with self._lock:
+            return self._counters.get(name, {}).get(key)
+
     def set_counter(
         self,
         name: str,

@@ -10,7 +10,8 @@ read as a chip result.
 
 ``ensure_backend`` also places the persistent compile cache, so ``pio
 train``, ``pio deploy``, scorer shards, bench children and ``chip_smoke.py``
-children all share one.
+children all share one, and starts the ``pio_jit_*`` counters
+(``count_compiles``).
 """
 
 from __future__ import annotations
@@ -53,6 +54,84 @@ def configure_compile_cache() -> str:
     return path
 
 
+#: ``jax.monitoring`` duration events -> the counter of seconds each feeds
+#: (names as jax 0.9.0's ``jax/_src/dispatch.py`` has them). The third fires
+#: once for every program compiled OR loaded from the persistent cache.
+_DURATION_COUNTERS = {
+    "/jax/core/compile/jaxpr_trace_duration": (
+        "pio_jit_trace_seconds_total",
+        "Seconds spent tracing Python functions to jaxprs",
+    ),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": (
+        "pio_jit_lower_seconds_total",
+        "Seconds spent lowering jaxprs to MLIR modules",
+    ),
+    "/jax/core/compile/backend_compile_duration": (
+        "pio_jit_compile_seconds_total",
+        "Seconds spent compiling programs or loading them from the"
+        " persistent cache",
+    ),
+}
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+#: the persistent cache's own events (``jax/_src/compiler.py``,
+#: ``compilation_cache.py``). A miss is counted where a compiled program is
+#: written to the cache: one that compiled in under CACHE_MIN_COMPILE_SECS is
+#: never written, and is neither a hit nor a miss.
+_EVENT_COUNTERS = {
+    "/jax/compilation_cache/cache_hits": (
+        "pio_jit_cache_hits_total",
+        "Programs loaded from the persistent compilation cache",
+    ),
+    "/jax/compilation_cache/cache_misses": (
+        "pio_jit_cache_misses_total",
+        "Programs compiled and written to the persistent compilation cache",
+    ),
+}
+_COMPILES_TOTAL = (
+    "pio_jit_compiles_total",
+    "Programs compiled or loaded from the persistent cache",
+)
+
+_counting = False
+
+
+def count_compiles() -> None:
+    """Feed JAX's compile events into ``utils.metrics.global_registry()``,
+    which every service's ``/metrics`` merges in: a query server that
+    recompiles on a batch shape it has not seen shows as a counter that
+    climbs. Registers once a process; the counters start at 0 so that a
+    scrape shows them before the first compile."""
+    global _counting
+    if _counting:
+        return
+    _counting = True
+    from jax import monitoring
+
+    from predictionio_tpu.utils.metrics import global_registry
+
+    registry = global_registry()
+    for name, help_ in (*_DURATION_COUNTERS.values(),
+                        *_EVENT_COUNTERS.values(), _COMPILES_TOTAL):
+        registry.inc(name, amount=0.0, help=help_)
+
+    def on_duration(event: str, secs: float, **_) -> None:
+        counter = _DURATION_COUNTERS.get(event)
+        if counter is None:
+            return
+        registry.inc(counter[0], amount=secs)
+        if event == _COMPILE_EVENT:
+            registry.inc(_COMPILES_TOTAL[0])
+
+    def on_event(event: str, **_) -> None:
+        counter = _EVENT_COUNTERS.get(event)
+        if counter is not None:
+            registry.inc(counter[0])
+
+    monitoring.register_event_duration_secs_listener(on_duration)
+    monitoring.register_event_listener(on_event)
+
+
 def ensure_backend(platform: str | None = None) -> str:
     """Initialise the configured JAX backend; returns its platform name.
 
@@ -70,6 +149,7 @@ def ensure_backend(platform: str | None = None) -> str:
         want = jax.config.jax_platforms
         source = "JAX_PLATFORMS"
     configure_compile_cache()
+    count_compiles()
     try:
         device = jax.devices()[0]
     except RuntimeError as exc:

@@ -305,3 +305,88 @@ class TestImplicitALS:
         # synthetic's density (25% random mask leaves unobserved pairs weakly
         # structured)
         assert scores[uu, ii].mean() > scores[~mask].mean() + 0.1
+
+
+class TestDeviceScopes:
+    """``jax.named_scope`` inside the iteration: names only, same program."""
+
+    @staticmethod
+    def _program_and_args(synthetic, solver, sharding, implicit, build=None):
+        import jax.numpy as jnp
+
+        from predictionio_tpu.parallel import als
+
+        n_u, n_i, uu, ii, rr, _ = synthetic
+        model = 2 if sharding == "model" else 1
+        mesh = local_mesh(2, model)
+        cfg = ALSConfig(rank=6, buckets=2, implicit=implicit, solver=solver,
+                        factor_sharding=sharding)
+        data = build_als_data(uu, ii, rr, n_u, n_i, cfg, num_shards=2,
+                              model_shards=model)
+        blocks = [
+            tuple((jnp.asarray(b.indices), jnp.asarray(b.values),
+                   jnp.asarray(b.mask.sum(axis=1))) for b in side.blocks)
+            for side in (data.by_row, data.by_col)
+        ]
+        factors = [
+            jnp.asarray(als._initial_side_factors(side, 6, seed), jnp.float32)
+            for side, seed in ((data.by_row, 1), (data.by_col, 2))
+        ]
+        build = build or als._build_iteration
+        program = build(mesh, 6, implicit, sharding, solver)
+        return program, (*blocks, *factors, jnp.float32(0.05), jnp.float32(2.0))
+
+    @pytest.mark.parametrize("implicit", [False, True], ids=["explicit", "implicit"])
+    @pytest.mark.parametrize("sharding", ["replicated", "model"])
+    @pytest.mark.parametrize("solver", ["xla", "pallas"])
+    def test_every_scope_is_in_the_traced_program(self, synthetic, solver,
+                                                   sharding, implicit):
+        from predictionio_tpu.parallel import als
+
+        program, args = self._program_and_args(synthetic, solver, sharding, implicit)
+        stacks = set(self._name_stacks(program.trace(*args).jaxpr.jaxpr))
+        assert len(args[0]) == 2  # the user side keeps both its buckets
+        for side, blocks in zip(als.SCOPE_HALF_STEP.values(), args):
+            assert f"{side}/{als.SCOPE_ASSEMBLE}" in stacks
+            for bucket in range(len(blocks)):
+                for stage in (als.SCOPE_GRAM, als.SCOPE_SOLVE):
+                    assert f"{side}/{als.SCOPE_BUCKET.format(bucket)}/{stage}" in stacks
+        # and nothing of the iteration lies outside them
+        assert all(stack.startswith("als.") for stack in stacks), sorted(stacks)[:5]
+
+    @classmethod
+    def _name_stacks(cls, jaxpr, outer=""):
+        """Every equation's scope, those of nested programs (a shard_map's
+        body, a jitted helper) under their caller's."""
+        for eqn in jaxpr.eqns:
+            here = "/".join(filter(None, [outer, str(eqn.source_info.name_stack)]))
+            yield here
+            for param in eqn.params.values():
+                inner = getattr(param, "jaxpr", param)
+                if hasattr(inner, "eqns"):
+                    yield from cls._name_stacks(inner, here)
+
+    @pytest.mark.parametrize("sharding", ["replicated", "model"])
+    @pytest.mark.parametrize("solver", ["xla", "pallas"])
+    def test_factors_equal_the_unscoped_programs_bit_for_bit(
+            self, synthetic, monkeypatch, solver, sharding):
+        """The same builder with every ``named_scope`` taken out is the
+        program this one replaced."""
+        import contextlib
+
+        import jax
+
+        from predictionio_tpu.parallel import als
+
+        fresh = als._build_iteration.__wrapped__  # past the per-mesh cache
+        scoped, args = self._program_and_args(synthetic, solver, sharding, False, fresh)
+        scopes = lambda f: set(self._name_stacks(f.trace(*args).jaxpr.jaxpr))
+        copy = lambda tree: jax.tree_util.tree_map(lambda a: a + 0, tree)  # donated
+        with monkeypatch.context() as patch:  # traced and run with the scopes out
+            patch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+            bare, _ = self._program_and_args(synthetic, solver, sharding, False, fresh)
+            assert not any("als." in stack for stack in scopes(bare))
+            want = bare(*copy(args))
+        assert all(stack.startswith("als.") for stack in scopes(scoped))
+        for got, unscoped in zip(scoped(*copy(args)), want):
+            assert np.array_equal(np.asarray(got), np.asarray(unscoped))

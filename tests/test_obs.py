@@ -1000,12 +1000,42 @@ class TestTrainTelemetry:
             if json.loads(l).get("event") == "step"
         ]
         assert [s["step"] for s in steps] == [0, 1, 2]
+        from predictionio_tpu.utils.metrics import global_registry
+
         for s in steps:
             assert s["edges_per_sec"] > 0
             assert "achieved_gbps" in s
             assert s["recompile_count"] >= 1
         # steady state: no recompile churn after the first step
         assert steps[1]["recompile_count"] == steps[2]["recompile_count"]
+        # the journal reads the process's counter, not a private jax API
+        assert steps[2]["recompile_count"] <= global_registry().counter_value(
+            "pio_jit_compiles_total"
+        )
+
+    @pytest.mark.parametrize("solver,has_gbps", [("xla", True), ("pallas", False)])
+    def test_journal_models_bytes_for_the_xla_tail_only(
+        self, tmp_path, solver, has_gbps
+    ):
+        """``modeled_bytes_per_iteration`` counts padded slots through the
+        gathered intermediate: it does not describe the fused kernel, so a
+        run that resolves to ``pallas`` writes no ``achieved_gbps``."""
+        import numpy as np
+
+        from predictionio_tpu.models._als_common import _build_telemetry
+        from predictionio_tpu.parallel.als import ALSConfig, build_als_data
+
+        class Ctx:
+            runtime_conf = {"pio.profile": str(tmp_path)}
+
+        config = ALSConfig(rank=4, solver=solver)
+        data = build_als_data(
+            np.arange(12) % 5, np.arange(12) % 3, np.ones(12, np.float32), 5, 3, config
+        )
+        with _build_telemetry(Ctx(), data, config, None, "als") as tel:
+            step = tel.record_step(0, 0.5)
+        assert ("achieved_gbps" in step) is has_gbps
+        assert step["edges_per_sec"] > 0
 
     def test_train_profile_cli_flag(self):
         from predictionio_tpu.tools.cli import build_parser
@@ -1073,6 +1103,262 @@ class TestTrainTelemetry:
         ]
         assert len(steps) == 2
         assert all("edges_per_sec" in s and "achieved_gbps" in s for s in steps)
+
+
+class TestCompileCounters:
+    """``utils.platform.count_compiles``: jax's compile events as
+    ``pio_jit_*`` counters of the global registry."""
+
+    NAMES = (
+        "pio_jit_trace_seconds_total", "pio_jit_lower_seconds_total",
+        "pio_jit_compile_seconds_total", "pio_jit_compiles_total",
+        "pio_jit_cache_hits_total", "pio_jit_cache_misses_total",
+    )
+
+    @staticmethod
+    def _read():
+        from predictionio_tpu.utils.metrics import global_registry
+
+        return {
+            name: global_registry().counter_value(name)
+            for name in TestCompileCounters.NAMES
+        }
+
+    def test_one_compile_for_a_fresh_function_and_none_for_its_second_call(self):
+        import jax
+        import numpy as np
+
+        from predictionio_tpu.utils.platform import count_compiles
+
+        count_compiles()
+        count_compiles()  # registering again must not count an event twice
+        start = self._read()
+        assert all(value is not None for value in start.values())  # there from 0
+
+        @jax.jit
+        def fresh(x):
+            return x * 3.0 + 1.0
+
+        x = np.arange(7, dtype=np.float32)  # numpy in: no helper program compiles
+        fresh(x).block_until_ready()
+        first = self._read()
+        assert first["pio_jit_compiles_total"] == start["pio_jit_compiles_total"] + 1
+        for name in self.NAMES[:3]:
+            assert first[name] > start[name]
+        fresh(x).block_until_ready()
+        assert self._read() == first
+        fresh(np.arange(9, dtype=np.float32)).block_until_ready()  # a new shape
+        assert (
+            self._read()["pio_jit_compiles_total"]
+            == first["pio_jit_compiles_total"] + 1
+        )
+
+    def test_the_events_are_the_installed_jaxs(self):
+        """The listener matches on event names; a jax that renames one would
+        leave its counter at 0 without an error anywhere else."""
+        from jax._src import dispatch
+
+        from predictionio_tpu.utils import platform
+
+        assert set(platform._DURATION_COUNTERS) == {
+            dispatch.JAXPR_TRACE_EVENT,
+            dispatch.JAXPR_TO_MLIR_MODULE_EVENT,
+            dispatch.BACKEND_COMPILE_EVENT,
+        }
+
+    def test_cache_hits_and_misses_count_in_a_process_with_the_cache_on(self, tmp_path):
+        """The suite runs with the persistent cache off; a child with its
+        own cache directory compiles (a miss, written), then loads (a hit)."""
+        import os
+        import subprocess
+        import sys
+
+        code = (
+            "import json, sys, numpy as np\n"
+            "from predictionio_tpu.utils.platform import ensure_backend\n"
+            "from predictionio_tpu.utils.metrics import global_registry\n"
+            "ensure_backend()\n"
+            "import jax\n"
+            "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)\n"
+            "f = jax.jit(lambda x: (x @ x.T).sum() * 3.0)\n"
+            "f(np.ones((64, 64), np.float32)).block_until_ready()\n"
+            "print(json.dumps({n: global_registry().counter_value(n) for n in"
+            " ('pio_jit_cache_hits_total', 'pio_jit_cache_misses_total',"
+            " 'pio_jit_compiles_total')}))\n"
+        )
+        env = dict(os.environ, JAX_PLATFORMS="cpu", JAX_ENABLE_COMPILATION_CACHE="true",
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+        runs = []
+        for _ in range(2):
+            proc = subprocess.run([sys.executable, "-c", code], env=env, text=True,
+                                  capture_output=True, timeout=300)
+            assert proc.returncode == 0, proc.stderr[-2000:]
+            runs.append(json.loads(proc.stdout.strip().splitlines()[-1]))
+        cold, warm = runs
+        assert cold["pio_jit_cache_misses_total"] >= 1
+        assert cold["pio_jit_cache_hits_total"] == 0
+        assert warm["pio_jit_cache_hits_total"] >= 1
+        assert warm["pio_jit_cache_misses_total"] == 0
+        assert warm["pio_jit_compiles_total"] == cold["pio_jit_compiles_total"]
+
+    def test_query_server_metrics_show_a_recompile(self, storage_env, tmp_path):
+        """``/metrics`` of a deployed query server carries the counters, and a
+        micro-batch of a size not seen before (more queries than one query
+        block) raises ``pio_jit_compiles_total``."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        from predictionio_tpu.data import DataMap, Event
+        from predictionio_tpu.data.storage.base import App
+        from predictionio_tpu.workflow.core_workflow import run_train
+        from predictionio_tpu.workflow.create_server import create_query_server
+        from predictionio_tpu.workflow.json_extractor import load_engine_variant
+        from predictionio_tpu.workflow.microbatch import BatchConfig
+
+        app_id = storage_env.get_meta_data_apps().insert(App(name="JitApp"))
+        le = storage_env.get_l_events()
+        le.init_channel(app_id)
+        le.batch_insert(
+            [
+                Event(
+                    event="rate", entity_type="user", entity_id=f"u{k % 16}",
+                    target_entity_type="item", target_entity_id=f"i{(k * 7) % 11}",
+                    properties=DataMap({"rating": float(1 + k % 5)}),
+                )
+                for k in range(120)
+            ],
+            app_id=app_id,
+        )
+        variant_path = tmp_path / "engine.json"
+        variant_path.write_text(json.dumps({
+            "id": "jit-test",
+            "engineFactory":
+                "predictionio_tpu.models.recommendation.engine.engine_factory",
+            "datasource": {"params": {"appName": "JitApp"}},
+            "algorithms": [{
+                "name": "als",
+                "params": {
+                    "rank": 4, "numIterations": 2, "checkpointInterval": 0,
+                    "retrieval": {"mode": "mips"},
+                },
+            }],
+        }))
+        variant = load_engine_variant(str(variant_path))
+        run_train(variant)
+        thread, _ = create_query_server(
+            variant, host="127.0.0.1", port=0,
+            batching=BatchConfig(max_batch_size=64, window_ms=500.0, idle_ms=200.0),
+        )
+        thread.start()
+        base = f"http://127.0.0.1:{thread.port}"
+
+        def compiles() -> float:
+            text = urllib.request.urlopen(f"{base}/metrics", timeout=10).read().decode()
+            for name in self.NAMES:
+                assert f"# TYPE {name} counter" in text
+            line = next(l for l in text.splitlines()
+                        if l.startswith("pio_jit_compiles_total "))
+            return float(line.split()[1])
+
+        def query(user: int) -> int:
+            req = urllib.request.Request(
+                f"{base}/queries.json",
+                data=json.dumps({"user": f"u{user}", "num": 3}).encode(),
+                headers={"Content-Type": "application/json"},
+            )
+            with urllib.request.urlopen(req, timeout=120) as resp:
+                return resp.status
+
+        try:
+            assert query(0) == 200
+            assert query(1) == 200
+            steady = compiles()
+            assert query(2) == 200
+            assert compiles() == steady  # the same batch shape: nothing compiles
+            with ThreadPoolExecutor(12) as pool:  # one micro-batch of 12 > 8 rows
+                assert list(pool.map(query, range(12))) == [200] * 12
+            assert compiles() > steady
+        finally:
+            thread.stop()
+
+
+class TestProgramSpans:
+    def test_als_pack_carries_the_packers_counts(self):
+        import numpy as np
+
+        from predictionio_tpu.controller.base import Params
+        from predictionio_tpu.models._als_common import prepare_als_data
+        from predictionio_tpu.obs.trace import global_tracer
+
+        rng = np.random.default_rng(3)
+        users = rng.integers(0, 30, 900)
+        items = rng.integers(0, 12, 900)
+        vals = rng.integers(1, 6, 900).astype(np.float32)
+
+        class Ctx:
+            mesh = None
+
+        data = prepare_als_data(
+            Ctx(), Params({"maxEventsPerUser": 24, "buckets": 2}),
+            users, items, vals, 30, 12, times=np.arange(900),
+        )
+        newest = global_tracer().snapshot(op="als.pack")["recent"][0]
+        span = next(s for s in newest["spans"] if s["op"] == "als.pack")
+        assert span["attrs"]["edges"] == 900
+        for name, side in (("by_row", data.by_row), ("by_col", data.by_col)):
+            counts = span["attrs"][name]
+            assert counts["retained_edges"] == sum(int(b.mask.sum()) for b in side.blocks)
+            assert counts["padded_slots"] == side.padded_slots
+            assert counts["buckets"] == len(side.blocks)
+        # the cap bites on the item side (900 / 12 > 24): retained < edges
+        assert span["attrs"]["by_col"]["retained_edges"] == 12 * 24 < 900
+
+    def test_a_span_is_a_host_event_of_a_profiler_trace(self, tmp_path):
+        import glob
+
+        import jax
+        import jax.numpy as jnp
+        from jax.profiler import ProfileData
+
+        tracer = Tracer()
+        with tracer.span("outside.any.session") as before:
+            assert before._annotation is None  # no session: one check, no annotation
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tracer.span("als.pack"):
+                with tracer.span("als.transfer"):
+                    jnp.ones(8).block_until_ready()
+            assert trace_mod.NULL_TRACER.span("never") is NULL_SPAN  # disabled: untouched
+        finally:
+            jax.profiler.stop_trace()
+        (xplane,) = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")
+        events = {
+            ev.name: ev.duration_ns
+            for plane in ProfileData.from_file(xplane).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines
+            for ev in line.events
+        }
+        assert events["als.pack"] >= events["als.transfer"] > 0
+        assert "outside.any.session" not in events
+        recorded = {s["op"] for t in tracer.snapshot()["recent"] for s in t["spans"]}
+        assert {"als.pack", "als.transfer", "outside.any.session"} <= recorded
+
+    def test_a_process_without_jax_opens_no_annotation(self):
+        import subprocess
+        import sys
+
+        code = (
+            "import sys\n"
+            "from predictionio_tpu.obs.trace import Tracer\n"
+            "tracer = Tracer()\n"
+            "with tracer.span('train.algorithm') as span:\n"
+            "    assert span._annotation is None\n"
+            "assert 'jax' not in sys.modules\n"
+            "assert tracer.snapshot()['recent'][0]['op'] == 'train.algorithm'\n"
+        )
+        proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                              text=True, timeout=120)
+        assert proc.returncode == 0, proc.stderr[-2000:]
 
 
 class TestPioTop:

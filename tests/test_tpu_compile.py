@@ -58,6 +58,17 @@ def _compiled_text(fn, *shapes) -> str:
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
+def _kernel_instructions(text: str, name: str) -> list[str]:
+    """The custom calls of ``text`` that carry the kernel's name: what a
+    profiler trace prints for them (``<name>.<n> tpu_custom_call``)."""
+    import re
+
+    return re.findall(
+        rf"%({re.escape(name)}(?:\.\d+)?) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text,
+    )
+
+
 @pytest.mark.parametrize("rank", [16, 128])
 @pytest.mark.parametrize("implicit", [False, True], ids=["explicit", "implicit"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
@@ -77,6 +88,7 @@ def test_gram_rhs_compiles(one_chip, no_persistent_cache, dtype, implicit, rank)
         sds((), jnp.float32),
     )
     assert "tpu_custom_call" in text
+    assert _kernel_instructions(text, "als_gram_rhs")
 
 
 def test_gram_rhs_compiles_for_a_block_longer_than_smem(one_chip, no_persistent_cache):
@@ -114,6 +126,34 @@ def test_mips_block_topk_compiles(one_chip, no_persistent_cache, rank):
         sds((padded // BLOCK_ITEMS, 1), jnp.float32),
     )
     assert "tpu_custom_call" in text
+    assert _kernel_instructions(text, "mips_block_topk")
+
+
+def test_search_program_names_its_kernel_and_its_stages(one_chip, no_persistent_cache):
+    """The whole device search (stage 1, merge, exact re-rank) over 100k
+    items: the kernel under its name, every stage under its scope."""
+    from predictionio_tpu.ops import mips
+    from predictionio_tpu.ops.quantize import BLOCK_ITEMS
+
+    items, rank = 100_000, 16
+    padded = -(-items // BLOCK_ITEMS) * BLOCK_ITEMS
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    text = _compiled_text(
+        functools.partial(
+            mips._search_program, block_topk=16, shortlist=512, num_items=items,
+            interpret=False,
+        ),
+        sds((mips.BLOCK_QUERIES, rank), jnp.float32),
+        sds((padded, rank), jnp.int8),
+        sds((padded // BLOCK_ITEMS, 1), jnp.float32),
+        sds((items, rank), jnp.float32),
+    )
+    (kernel,) = _kernel_instructions(text, mips.KERNEL_NAME)
+    assert f"/{mips.SCOPE_STAGE1}/" in next(
+        line for line in text.splitlines() if f"%{kernel} = " in line
+    )
+    for scope in (mips.SCOPE_STAGE1, mips.SCOPE_MERGE, mips.SCOPE_RERANK):
+        assert f"/{scope}/" in text
 
 
 @pytest.mark.parametrize("shape", [(8, 512, 2, 32), (4, 2048, 4, 64)],
@@ -147,6 +187,7 @@ def test_ncf_scorer_compiles(one_chip, no_persistent_cache):
         sds(1, e), sds(1, h1), sds(1, 1),
     )
     assert "tpu_custom_call" in text
+    assert _kernel_instructions(text, "ncf_score_all_items")
 
 
 def test_model_sharded_als_compiles_on_four_chips(topo, no_persistent_cache):
@@ -175,3 +216,77 @@ def test_model_sharded_als_compiles_on_four_chips(topo, no_persistent_cache):
     ).compile().as_text()
     assert "reduce-scatter" in text
     assert "tpu_custom_call" in text  # auto resolved to pallas: the mesh is TPU
+
+
+#: instructions of an entry computation that move or name data and do no
+#: arithmetic: the compiler's own, which carry no scope (PERF.md section 5
+#: lists what they cost in a trace)
+_NO_WORK = {
+    "parameter", "constant", "tuple", "get-tuple-element", "bitcast", "copy",
+    "copy-start", "copy-done", "slice-start", "slice-done", "dynamic-update-slice",
+}
+
+
+@pytest.mark.parametrize("solver", ["pallas", "xla"])
+def test_als_iteration_names_its_kernel_and_scopes_its_work(
+    topo, no_persistent_cache, solver
+):
+    """One chip, two buckets a side, bf16 factors: the kernel once per bucket
+    under its name, and every instruction of the entry computation that does
+    work under an ``als.`` scope. The compiler leaves the ``op_name`` off some
+    fusions it forms itself; those are held to their fused instructions."""
+    import re
+    from collections import Counter
+
+    from predictionio_tpu.parallel import als
+
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    config = als.ALSConfig(rank=8, solver=solver, dtype="bfloat16")
+    row, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+
+    def blocks(*shapes):
+        return tuple((
+            jax.ShapeDtypeStruct((rows, length), jnp.int32, sharding=row),
+            jax.ShapeDtypeStruct((rows, length), jnp.float32, sharding=row),
+            jax.ShapeDtypeStruct((rows,), jnp.float32, sharding=row),
+        ) for rows, length in shapes)
+
+    users = jax.ShapeDtypeStruct((768, 8), jnp.bfloat16, sharding=row)
+    items = jax.ShapeDtypeStruct((384, 8), jnp.bfloat16, sharding=row)
+    scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=rep)
+    text = als.make_iteration(mesh, config).lower(
+        blocks((256, 64), (512, 16)), blocks((128, 128), (256, 32)),
+        users, items, scalar, scalar,
+    ).compile().as_text()
+
+    kernels = _kernel_instructions(text, "als_gram_rhs")
+    assert len(kernels) == (4 if solver == "pallas" else 0)
+    assert "%iteration" not in text and "_unknown_" not in text
+
+    def scope(line: str):
+        """As the benchmark's reader takes an ``op_name`` apart."""
+        from benchmarks.scopes import parse_scope
+
+        found = re.search(r'op_name="([^"]*)"', line)
+        return parse_scope(found.group(1)) if found else None
+
+    computations = dict(re.findall(r"\n(?:ENTRY )?%([\w.\-]+) [^\n]*\{\n(.*?)\n\}", text, re.S))
+    entry = re.search(r"\nENTRY %[^\n]*\{\n(.*?)\n\}", text, re.S).group(1)
+    seen = Counter()
+    for line in entry.splitlines():
+        opcode = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = .*? ([\w\-]+)\(", line).group(1)
+        if opcode in _NO_WORK:
+            continue
+        found = scope(line)
+        if found is None and opcode == "fusion":
+            inner = computations[re.search(r"calls=%([\w.\-]+)", line).group(1)]
+            votes = Counter(filter(None, map(scope, inner.splitlines())))
+            found = votes.most_common(1)[0][0] if votes else None
+        assert found is not None and found[1] is not None, line[:200]
+        seen[found] += 1
+    for side in als.SCOPE_HALF_STEP.values():
+        for stage in (als.SCOPE_GRAM, als.SCOPE_SOLVE):
+            assert seen[(side, stage)] > 0, (side, stage, seen)
+    for line in text.splitlines():
+        if any(f"%{kernel} = " in line for kernel in kernels):
+            assert scope(line)[1] == als.SCOPE_GRAM
