@@ -1109,9 +1109,9 @@ def child_main(mode: str, result_path: str) -> None:
         dtype="bfloat16" if mode == "tpu" else "float32",
         buckets=int(os.environ.get("PIO_BENCH_BUCKETS", "4"))
         if mode == "tpu" else 1,
-        # per-platform default: fused Pallas gather->Gram half-step on the
-        # TPU, XLA einsums on the CPU baseline; PIO_BENCH_ALS_SOLVER pins
-        # either path for A/B runs
+        # "auto": the XLA einsums (at this shape every block fits the
+        # chip, so none takes the fused Pallas kernel);
+        # PIO_BENCH_ALS_SOLVER pins either path for A/B runs
         solver=os.environ.get("PIO_BENCH_ALS_SOLVER", "auto"),
     )
     from predictionio_tpu.parallel.als import resolve_solver

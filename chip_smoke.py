@@ -296,10 +296,12 @@ class Smoke:
         if not m or not inst:
             raise PhaseFailed(f"{name}: no Device/instance line: ...{text[-800:]}")
         facts = {"instance": inst.group(1), "device": self.saw(name, json.loads(m.group(1)))}
-        als = re.search(r"als_fit: platform=(\S+) devices=(\d+) solver=(\S+) first_call_s=([\d.]+)", text)
+        als = re.search(r"als_fit: platform=(\S+) devices=(\d+) solver=(\S+) blocks_xla=(\d+)"
+                        r" blocks_pallas=(\d+) first_call_s=([\d.]+)", text)
         if als:
-            facts["solver"] = als.group(3)
-            facts["first_call_s"] = float(als.group(4))
+            # which half-step path the program's blocks took (parallel/als.py:block_solver)
+            facts.update(solver=als.group(3), blocks_xla=int(als.group(4)),
+                         blocks_pallas=int(als.group(5)), first_call_s=float(als.group(6)))
         timings = re.search(r"stage timings: (.*)$", text, re.M)
         if timings:
             facts["stage_timings"] = timings.group(1).strip()
@@ -360,16 +362,29 @@ class Smoke:
             raise PhaseFailed(f"the second process missed the cache: entries {cold['entries_after']} -> {warm['entries_after']}")
         if self.device["platform"] == "tpu" and missed and not warm["compile_s"] < cold["compile_s"]:
             raise PhaseFailed(f"warm compile {warm['compile_s']}s not under cold {cold['compile_s']}s")
-        if self.device["platform"] == "tpu" and cold["solver"] == "pallas" and not cold["tpu_custom_call"]:
-            raise PhaseFailed("solver pallas on tpu but no tpu_custom_call in the compiled iteration")
+        self.kernel_where_asked("compile_cache", cold)
         self.line(
             "compile_cache", t0, cache_dir=cold["cache_dir"],
             cold_compile_s=cold["compile_s"], warm_compile_s=warm["compile_s"],
             cold_was_a_miss=missed, entries_before=cold["entries_before"],
             entries_after=warm["entries_after"], solver=cold["solver"],
+            blocks_xla=cold["blocks_xla"], blocks_pallas=cold["blocks_pallas"],
             tpu_custom_call=cold["tpu_custom_call"],
             device_memory_bytes=cold["device_memory_bytes"],
         )
+
+    def kernel_where_asked(self, phase: str, compiled: dict) -> None:
+        """On the chip the compiled iteration holds one ``tpu_custom_call`` for
+        each block the solver asked to run the fused kernel (every block when
+        "pallas" was given by name, under "auto" the blocks too large for the
+        einsum tail) and none for the others."""
+        if self.device["platform"] != "tpu":
+            return
+        if compiled["tpu_custom_call"] != compiled["blocks_pallas"]:
+            raise PhaseFailed(
+                f"{phase}: solver {compiled['solver']} puts {compiled['blocks_pallas']}"
+                f" block(s) on the fused kernel, the compiled iteration holds"
+                f" {compiled['tpu_custom_call']} tpu_custom_call: {compiled}")
 
     def phase_ingest(self) -> None:
         import numpy as np
@@ -404,6 +419,13 @@ class Smoke:
                 f"ALS learned nothing: rmse {check['rmse']} vs global mean"
                 f" {check['rmse_global_mean']} (needs 10% under)"
             )
+        # the template's defaults (one bucket, no cap, f32) make an item block
+        # whose gathered rows cannot fit the chip: at full size this train is
+        # where "auto" keeps the fused kernel
+        if (self.device["platform"] == "tpu" and not self.rehearsal
+                and (not facts.get("blocks_pallas")
+                     or facts["device"]["kernels"].get("als_gram_rhs") != "compiled")):
+            raise PhaseFailed(f"train_als: no block of the template-default train ran the fused kernel: {facts}")
         self.line("train_als", t0, **facts, **check)
 
     def phase_als_full_width(self) -> None:
@@ -415,8 +437,7 @@ class Smoke:
         for run_ in res["runs"]:
             if not run_["agrees"]:
                 raise PhaseFailed(f"als_full_width: chip and NumPy float64 half-step disagree: {run_}")
-            if run_["solver"] == "pallas" and res["device"]["platform"] == "tpu" and not run_["tpu_custom_call"]:
-                raise PhaseFailed(f"als_full_width: no tpu_custom_call in the pallas iteration: {run_}")
+            self.kernel_where_asked("als_full_width", run_)
         self.line("als_full_width", t0, **res)
 
     def phase_serve_als(self) -> None:
@@ -662,15 +683,17 @@ def _iteration_shapes(data, config, mesh):
 
 def _compile_iteration(data, config, mesh) -> dict:
     """AOT-compile the jitted ALS iteration; what the compiler made of it."""
-    from predictionio_tpu.parallel.als import make_iteration, resolve_solver
+    from predictionio_tpu.parallel.als import block_paths, make_iteration
 
     t0 = time.perf_counter()
     compiled = make_iteration(mesh, config).lower(
         *_iteration_shapes(data, config, mesh)).compile()
     compile_s = time.perf_counter() - t0
     mem = compiled.memory_analysis()
+    paths = block_paths(data, config, mesh)
     return {
-        "solver": resolve_solver(config.solver, mesh.devices.flat[0].platform),
+        "solver": config.solver,
+        "blocks_xla": paths["xla"], "blocks_pallas": paths["pallas"],
         "compile_s": round(compile_s, 2),
         "tpu_custom_call": compiled.as_text().count("tpu_custom_call"),
         "device_memory_bytes": int(
@@ -870,10 +893,12 @@ def child_als_full_width(params: dict) -> dict:
     run_["timed_blocks_agree"] = record["valid"]
     runs = [run_]
     del data
-    # once more with f32 factors at a 2M-edge sample of the same stream
+    # once more with f32 factors at a 2M-edge sample of the same stream, on
+    # the fused kernel by name: "auto" above left every block to the einsum
+    # tail, and the kernel that stays in the tree meets the chip here
     cut = n_edges // 10
     # (2 buckets: the compile of 8 bucket programs is the long part here)
-    config = dataclasses.replace(base, dtype="float32", buckets=2)
+    config = dataclasses.replace(base, dtype="float32", buckets=2, solver="pallas")
     run_, _ = _fit_and_check(users[:cut], items[:cut], ratings[:cut], n_users,
                              n_items, config, mesh, tol=1e-4)
     runs.append(run_)

@@ -237,7 +237,7 @@ class RuleS003(PackageRule):
     jitted under the 2x2 mesh without shard_map routing;
     ``parallel/als.py`` now wraps BOTH factor layouts in an explicit
     ``shard_map`` (``_sharded_block_body`` / the replicated-path
-    ``smapped``), which is this rule's negative fixture."""
+    step of ``_half_steps``), which is this rule's negative fixture."""
 
     rule_id = "S003"
     severity = "error"

@@ -20,6 +20,7 @@ from predictionio_tpu.parallel.als import (
     ALSModel,
     als_fit,
     als_fit_streamed,
+    block_paths,
     build_als_data,
 )
 
@@ -558,9 +559,14 @@ def fit_with_checkpoint(
     fit = (
         als_fit_streamed if isinstance(als_data, StreamedALSData) else als_fit
     )
+    # which half-step path each block of the program about to be built takes
+    paths = block_paths(als_data, config, mesh)
     try:
         with global_tracer().span(
-            "als.fit", attrs={"name": name, "iterations": config.iterations}
+            "als.fit",
+            attrs={"name": name, "iterations": config.iterations,
+                   "blocks_xla": paths["xla"],
+                   "blocks_pallas": paths["pallas"]},
         ):
             model = fit(
                 als_data,
@@ -594,30 +600,30 @@ def _build_telemetry(ctx, als_data, config: ALSConfig, mesh, name: str):
         from predictionio_tpu.parallel.als import (
             modeled_bytes_per_iteration,
             real_edges,
-            resolve_solver,
         )
 
-        try:
-            platform = mesh.devices.flat[0].platform if mesh is not None else "cpu"
-        except Exception:
-            platform = "cpu"
-        solver = resolve_solver(config.solver, platform)
+        from predictionio_tpu.parallel.mesh import local_mesh
+
+        mesh = mesh or local_mesh(1, 1)  # as als_fit defaults it
+        paths = block_paths(als_data, config, mesh)
         itemsize = 2 if config.dtype == "bfloat16" else 4
         return TrainTelemetry(
             os.path.join(str(profile_dir), f"{name}-telemetry.jsonl"),
             edges=real_edges(als_data),
             # the model counts padded slots through the XLA tail's gathered
             # intermediate; it does not describe the fused kernel, so the
-            # journal writes no achieved_gbps there
-            modeled_bytes_per_iter=None if solver == "pallas" else
+            # journal writes no achieved_gbps where a block runs it
+            modeled_bytes_per_iter=None if paths["pallas"] else
             modeled_bytes_per_iteration(
                 als_data, config.rank, itemsize, fused=False
             ),
             meta={
                 "name": name,
                 "rank": config.rank,
-                "solver": solver,
-                "platform": platform,
+                "solver": config.solver,
+                "blocks_xla": paths["xla"],
+                "blocks_pallas": paths["pallas"],
+                "platform": mesh.devices.flat[0].platform,
                 "dtype": config.dtype,
                 "iterations": config.iterations,
             },

@@ -336,8 +336,9 @@ class ECommAlgorithm(TPUAlgorithm):
             dtype=p.get_or("factorDtype", "float32"),
             # "auto": ALX model-sharded factors on a model-axis mesh
             factor_sharding=p.get_or("factorSharding", "auto"),
-            # "auto": fused Pallas gather->Gram half-step on accelerator
-            # meshes, XLA einsums on CPU; `pio train --als-solver` overrides
+            # "auto": XLA einsums, and on a TPU the fused Pallas gather->Gram
+            # kernel for just the blocks whose gathered rows cannot fit the
+            # chip; `pio train --als-solver` overrides
             solver=p.get_or("alsSolver", "auto"),
         )
 

@@ -364,8 +364,9 @@ class ALSAlgorithm(TPUAlgorithm):
             # "auto": ALX model-sharded factors whenever pio.mesh_shape
             # configures a model axis > 1 (resolve_factor_sharding)
             factor_sharding=p.get_or("factorSharding", "auto"),
-            # "auto": fused Pallas gather->Gram half-step on accelerator
-            # meshes, XLA einsums on CPU; `pio train --als-solver` overrides
+            # "auto": XLA einsums, and on a TPU the fused Pallas gather->Gram
+            # kernel for just the blocks whose gathered rows cannot fit the
+            # chip; `pio train --als-solver` overrides
             solver=p.get_or("alsSolver", "auto"),
         )
 

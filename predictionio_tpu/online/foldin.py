@@ -215,8 +215,9 @@ def fold_in_users(
     local row order (``rows`` in ``[0, num_rows)``) and MODEL item space
     (``cols`` indexing ``item_factors``). Returns ``[num_rows, K]`` f32 --
     the exact ridge/implicit solution per row, via the same half-step tail
-    ``als_fit`` runs (``config.solver`` resolves "auto" like training:
-    the fused Pallas kernel on accelerators, XLA einsums on CPU).
+    ``als_fit`` runs (``config.solver`` resolves "auto" like training, from
+    the packed block's shape: the XLA einsums, or on a TPU the fused Pallas
+    kernel for a block whose gathered rows would not fit the chip).
 
     Shapes are padded to a pow2 ladder (rows AND history length) so a
     long-running loop compiles a handful of programs, not one per delta.
@@ -224,12 +225,11 @@ def fold_in_users(
     import jax
 
     from predictionio_tpu.ops.ragged import pack_padded_csr
-    from predictionio_tpu.parallel.als import resolve_solver
+    from predictionio_tpu.parallel.als import block_solver
 
     if num_rows == 0:
         return np.zeros((0, item_factors.shape[1]), np.float32)
     platform = jax.devices()[0].platform  # where the jitted step will run
-    solver = resolve_solver(config.solver, platform)
     counts = np.bincount(np.asarray(rows, np.int64), minlength=num_rows)
     longest = int(counts.max()) if counts.size else 1
     if config.max_len:
@@ -244,7 +244,10 @@ def fold_in_users(
         times=times,
         pad_len=_pow2_ceil(max(longest, 1)),
     )
-    step = _build_solver(solver, bool(config.implicit), item_factors.shape[1], platform)
+    rank = item_factors.shape[1]
+    # the table ships as float32 (_device_factors)
+    solver = block_solver(config.solver, platform, *csr.indices.shape, rank, 4)
+    step = _build_solver(solver, bool(config.implicit), rank, platform)
     out = step(
         csr.indices,
         csr.values,

@@ -1,17 +1,25 @@
 """Fused Pallas gather->Gram half-step kernels for ALS.
 
-The ALS half-step tail (``parallel/als.py``) is gather- and bandwidth-
-bound: the XLA path materializes the gathered opposite-side factors as a
-``[rows, L, K]`` HBM intermediate (one write + two einsum read passes)
-before reducing it to a ``[K, K]`` Gram and ``[K]`` rhs per row -- the
-ragged-data bottleneck the ALX paper (arxiv 2112.02194, PAPERS.md) names
-as THE TPU engineering problem for matrix factorization. This kernel
-streams padded-CSR row blocks through VMEM and performs the gather with
+The XLA path of the ALS half-step tail (``parallel/als.py``) materializes
+the gathered opposite-side factors as a ``[rows, L, K]`` HBM intermediate
+before reducing it to a ``[K, K]`` Gram and ``[K]`` rhs per row, and on a
+TPU that intermediate is lane-padded: a gathered row of K < 128 values takes
+a whole 128-lane row, 8 times its bytes at rank 16. This kernel streams
+padded-CSR row blocks through VMEM and performs the gather with
 double-buffered row DMAs from the HBM-resident factor table, accumulating
-each row's Gram/rhs in f32 on-chip; the ``[rows, L, K]`` intermediate
-never exists in HBM, so the half-step's HBM traffic drops from
-``~3 * rows * L * K * itemsize`` (write + 2 reads) to ONE random-gather
-read pass of ``rows * L * K * itemsize``.
+each row's Gram/rhs in f32 on-chip; the ``[rows, L, K]`` intermediate never
+exists in HBM.
+
+What that buys is MEMORY, not time. The gather is one row DMA a slot,
+started and waited for on the scalar core: 31 ns a slot on a v5e whatever
+the rank or the bytes (PERF.md, PR 24), which made an ML-20M iteration 14
+times slower than the einsums (PERF.md, PR 25: 882.8 against 63.5 ms). So
+"auto" (``parallel.als.block_solver``) runs the einsum tail, and keeps this kernel
+for the blocks whose intermediate cannot fit the chip: the recommendation
+template's default packing (one bucket, no cap) makes a ``[3712, 23832]``
+item block at MovieLens-1M, 45.3 GB of gathered rows, which the TPU
+compiler refuses and this kernel runs in under 1.5 GB. ``alsSolver:
+"pallas"`` still forces it for every block.
 
 Contract (shared with the XLA path -- ``parallel.als`` padding invariant):
 

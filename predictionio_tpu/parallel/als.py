@@ -59,9 +59,9 @@ logger = logging.getLogger("pio.als")
 #: iteration)``, ``shard_map``) may sit between these.
 SCOPE_HALF_STEP = {"user": "als.user_half_step", "item": "als.item_half_step"}
 SCOPE_BUCKET = "bucket{}"
-#: the fused kernel, or the gather (with its model-axis exchange) and the two
-#: einsums; on the Pallas path also the kernel's lane-padded f32 copy of the
-#: gather table, which ``gram_rhs`` makes for every bucket
+#: the gather (with its model-axis exchange) and the two einsums, or the fused
+#: kernel; for a bucket on the kernel also its lane-padded f32 copy of the
+#: gather table, which ``gram_rhs`` makes for every such bucket
 SCOPE_GRAM = "gram"
 #: ridge, ``ops.linalg.batched_spd_solve``, the cast back to the factor dtype
 SCOPE_SOLVE = "solve"
@@ -89,13 +89,14 @@ class ALSConfig:
     #: memory drops to total_slots/model_axis rows (see docs/parallelism.md
     #: for the max-catalog math). Requires build_als_data(model_shards=m).
     factor_sharding: str = "replicated"
-    #: half-step tail implementation, chosen per TARGET platform like the
-    #: unrolled-vs-LAPACK ``batched_spd_solve`` split: "pallas" runs the
-    #: fused gather->Gram kernel (``ops.als_gram``) that never writes the
-    #: [rows, L, K] gathered intermediate to HBM; "xla" is the einsum path.
-    #: "auto" = pallas on accelerators, xla on CPU meshes (where the fused
-    #: kernel runs in interpret mode -- a correctness vehicle, not a fast
-    #: path). Tiny ranks on CPU stay fastest on "xla".
+    #: half-step tail implementation: "xla" is the einsum path, which
+    #: writes the [rows, L, K] gathered intermediate to HBM; "pallas" runs
+    #: the fused gather->Gram kernel (``ops.als_gram``), which never does
+    #: and pays one row DMA a gather slot for it (14x slower on a v5e where
+    #: both fit). Either name forces every block. "auto" = the einsum path,
+    #: and on a TPU mesh the kernel for just the blocks whose intermediate
+    #: cannot fit the chip (``block_solver``). On CPU meshes the kernel runs
+    #: interpreted -- a correctness vehicle, not a fast path.
     solver: str = "auto"
 
 
@@ -600,18 +601,172 @@ def _append_zero_row(factors: jnp.ndarray) -> jnp.ndarray:
 
 
 def resolve_solver(solver: str, platform: str) -> str:
-    """Resolve ``ALSConfig.solver`` against a target platform -- ONE
-    definition of the "auto" rule (make_iteration and bench.py must agree
-    on which path a run measured): pallas on a TPU mesh, xla elsewhere,
-    where the fused kernel would only run interpreted."""
+    """Resolve ``ALSConfig.solver`` against a target platform: the path a
+    block takes unless ``block_solver`` finds it too large for the einsum
+    tail. "auto" is the einsum tail ("xla") everywhere: on CPU the fused
+    kernel would only run interpreted, and on a v5e it is 14x slower than
+    the einsums wherever both fit (PERF.md, PR 25). A solver given by name
+    stands, for every block."""
+    del platform  # the platform matters only to a block too large: block_solver
     if solver not in ("auto", "xla", "pallas"):
         raise ValueError(
             "ALSConfig.solver must be 'auto', 'xla' or 'pallas', "
             f"got {solver!r}"
         )
-    if solver == "auto":
-        return "pallas" if platform == "tpu" else "xla"
-    return solver
+    return "xla" if solver == "auto" else solver
+
+
+#: lanes of a TPU vector register row. The TPU compiler lays the einsum
+#: tail's gathered factors out one row a lane row, whatever the rank
+#: (``bf16[R*L,16]{1,0:T(8,128)(2,1)}`` at rank 16): a gather slot costs 128
+#: lanes x itemsize in HBM, 8 times the factors' own bytes at rank 16.
+_LANES = 128
+
+#: Most bytes a block's gathered intermediate may take on one device for
+#: "auto" to leave the block on the einsum tail: a quarter of a v5e's 16 GiB,
+#: the smallest HBM this runs on. Compiled for a described v5e
+#: (``memory_analysis``, PR 25): the einsum program's temporaries are 1.07 to
+#: 1.27 times its largest block's intermediate (f32 explicit to bf16
+#: implicit; 2.68 GB for the 2.31 GB of the ML-20M cell's largest block,
+#: [35312, 256] bf16), its block streams another 1/32, and the compiler
+#: refuses the program only past the whole chip (12 GiB of intermediate
+#: still compiles) -- so a quarter leaves the rest of the chip to the state,
+#: to the other programs a process has loaded and to what the compile of one
+#: program does not count. The recommendation template's default packing (one
+#: bucket, no cap, f32) makes a [3712, 23832] item block at MovieLens-1M:
+#: 45.3 GB of intermediate, which the compiler refuses outright
+#: (RESOURCE_EXHAUSTED); with the fused kernel on that block the program's
+#: temporaries are 0.75 GB.
+EINSUM_GATHER_BUDGET_BYTES = 4 << 30
+
+
+def gathered_bytes(rows: int, pad_len: int, rank: int, itemsize: int) -> int:
+    """HBM bytes of the einsum tail's ``[rows, pad_len, rank]`` gathered
+    intermediate as a TPU holds it: every gathered row padded to whole lane
+    rows. ``rows`` are one device's."""
+    return rows * pad_len * round_up(rank, _LANES) * itemsize
+
+
+def block_solver(solver: str, platform: str, rows: int, pad_len: int,
+                 rank: int, itemsize: int) -> str:
+    """The half-step path of ONE block -- the one statement of the "auto"
+    rule, asked at trace time by everything that has a block's static shape
+    (``rows`` on one device, ``pad_len``, the factors' ``rank`` and
+    ``itemsize``). "xla" / "pallas" by name force every block. "auto" takes
+    the einsum tail, except on a TPU for a block whose gathered intermediate
+    is over ``EINSUM_GATHER_BUDGET_BYTES``: that one takes the fused kernel,
+    which never materializes it."""
+    too_large = (
+        gathered_bytes(rows, pad_len, rank, itemsize)
+        > EINSUM_GATHER_BUDGET_BYTES
+    )
+    if solver == "auto" and platform == "tpu" and too_large:
+        return "pallas"
+    return resolve_solver(solver, platform)
+
+
+def _program_solver(solver: str, platform: str) -> str:
+    """What keys a built program: "auto" stays "auto" only on a TPU mesh,
+    where the rule can differ from block to block; elsewhere it is its
+    resolution, so "auto" and "xla" share one compiled program."""
+    if solver == "auto" and platform == "tpu":
+        return "auto"
+    return resolve_solver(solver, platform)
+
+
+def block_paths(data, config: ALSConfig, mesh) -> dict[str, int]:
+    """How many of ``data``'s blocks (both sides; resident or streamed) take
+    each half-step path in the program built for (mesh, config):
+    ``{"xla": n, "pallas": m}``. The same ``block_solver`` the program asks
+    at trace time, on the same shapes."""
+    platform = mesh.devices.flat[0].platform
+    d = mesh.shape["data"]
+    itemsize = jnp.dtype(config.dtype).itemsize
+    paths = {"xla": 0, "pallas": 0}
+    for side in (data.by_row, data.by_col):
+        specs = getattr(side, "specs", None)  # a streamed side's blocks
+        if specs is not None:
+            shapes = [(s.rows, s.pad_len) for s in specs]
+        else:
+            rows = side.global_rows or [b.indices.shape[0] for b in side.blocks]
+            shapes = [(r, b.indices.shape[1]) for r, b in zip(rows, side.blocks)]
+        for rows_b, pad_len in shapes:
+            paths[block_solver(config.solver, platform, rows_b // d, pad_len,
+                               config.rank, itemsize)] += 1
+    return paths
+
+
+def _half_steps(mesh, solver: str, implicit: bool, rank: int,
+                factor_axis: str):
+    """One bucket's half-step for (mesh, solver, factor layout), chosen for
+    each block as the program that holds it is traced.
+
+    Returns ``pick(idx, factors) -> step``, with ``step(idx, values, n_obs,
+    factors, yty, reg, alpha) -> rows``. ``solver`` is "xla" or "pallas" for
+    every block, or "auto" (a TPU mesh: ``_program_solver``), under which
+    ``block_solver`` decides from the block's shape on one device (rows
+    split over the data axis in both layouts); a path no block takes is
+    never traced. The einsum tail with replicated factors is left to GSPMD;
+    the fused kernel is opaque to it, and the model-sharded body exchanges
+    over ``model``, so those go through an explicit shard_map. The explicit
+    einsum tail drops ``yty`` and ``alpha`` (a dummy and a scalar).
+    """
+    P = PartitionSpec
+    platform = mesh.devices.flat[0].platform
+    # solve-path choice is per TARGET platform, not default backend: the
+    # benchmark compiles a CPU mesh while a TPU backend is live (and vice
+    # versa), and the unrolled solver is ~5x faster on TPU / ~8x slower on
+    # CPU than LAPACK's batched Cholesky (ops.linalg.batched_spd_solve).
+    unroll = platform == "tpu"
+    interpret = not unroll
+    paths = ("xla", "pallas") if solver == "auto" else (solver,)
+
+    def einsum_step(idx, val, n_obs, table, yty, reg, alpha):
+        if implicit:
+            return _half_step_implicit(
+                idx, val, n_obs, table, yty, reg, alpha, rank, unroll
+            )
+        return _half_step_explicit(idx, val, n_obs, table, reg, rank, unroll)
+
+    if factor_axis == "model":
+        steps = {
+            path: shard_map(
+                functools.partial(
+                    _sharded_block_body, implicit=implicit, rank=rank,
+                    unroll=unroll, solver=path, interpret=interpret,
+                ),
+                mesh=mesh,
+                in_specs=(P("data", None), P("data", None), P("data"),
+                          P("model", None), P(), P(), P()),
+                out_specs=P(("data", "model"), None),
+                # the pallas body has no replication/vma rule; the xla body
+                # keeps the checker on
+                check_vma=path != "pallas",
+            )
+            for path in paths
+        }
+    else:
+        steps = {"xla": einsum_step}
+        if "pallas" in paths:
+            steps["pallas"] = shard_map(
+                functools.partial(
+                    _half_step_pallas, implicit=implicit, rank=rank,
+                    unroll=unroll, interpret=interpret,
+                ),
+                mesh=mesh,
+                in_specs=(P("data", None), P("data", None), P("data"),
+                          P(), P(), P(), P()),
+                out_specs=P("data", None),
+                check_vma=False,
+            )
+
+    def pick(idx, factors):
+        return steps[block_solver(
+            solver, platform, idx.shape[0] // mesh.shape["data"],
+            idx.shape[1], rank, factors.dtype.itemsize,
+        )]
+
+    return pick
 
 
 def make_iteration(mesh, config: ALSConfig):
@@ -628,13 +783,13 @@ def make_iteration(mesh, config: ALSConfig):
             "ALSConfig.factor_sharding must be 'replicated' or 'model', "
             f"got {config.factor_sharding!r}"
         )
-    # per TARGET platform, like the unrolled-vs-LAPACK solve split: the
-    # fused kernel is built for the MXU+DMA engines; on CPU it would run
-    # interpreted (a correctness vehicle), so auto keeps the CPU default
-    # on the einsum path
-    solver = resolve_solver(config.solver, mesh.devices.flat[0].platform)
+    # "auto" is decided block by block as the program is traced
+    # (block_solver, from each block's static shape); a solver given by name
+    # forces every block
+    platform = mesh.devices.flat[0].platform
     return _build_iteration(
-        mesh, config.rank, config.implicit, config.factor_sharding, solver
+        mesh, config.rank, config.implicit, config.factor_sharding,
+        _program_solver(config.solver, platform),
     )
 
 
@@ -658,13 +813,15 @@ def _build_iteration(mesh, rank: int, implicit: bool,
       what lifts the catalog-size ceiling from one device's HBM to the
       model axis's aggregate (docs/parallelism.md has the sizing math).
 
-    ``solver`` (already resolved, "xla" or "pallas") picks the half-step
-    tail: the einsum path GSPMD partitions on its own; the fused Pallas
-    kernel (``ops.als_gram``) is opaque to GSPMD, so both factor layouts
-    route it through an explicit shard_map (interpret mode on CPU meshes,
-    the ``ops/flash_attention`` precedent -- tier-1 CPU tests run the same
-    kernel code). Implicit mode's ``yty`` is computed ONCE per half-step
-    here (bucket-invariant) and fed to every bucket's solve.
+    ``solver`` ("xla" or "pallas" for every block, or "auto" on a TPU mesh:
+    ``block_solver`` then picks for each block from its static shape as the
+    program is traced) is the half-step tail: the einsum path GSPMD
+    partitions on its own; the fused Pallas kernel (``ops.als_gram``) is
+    opaque to GSPMD, so both factor layouts route it through an explicit
+    shard_map (interpret mode on CPU meshes, the ``ops/flash_attention``
+    precedent -- tier-1 CPU tests run the same kernel code). Implicit
+    mode's ``yty`` is computed ONCE per half-step here (bucket-invariant)
+    and fed to every bucket's solve.
 
     Factor buffers are donated: each iteration updates in place instead
     of reallocating.
@@ -680,12 +837,7 @@ def _build_iteration(mesh, rank: int, implicit: bool,
     row = NamedSharding(mesh, P("data"))
     rep = NamedSharding(mesh, P())
 
-    # solve-path choice is per TARGET platform, not default backend: the
-    # benchmark compiles a CPU mesh while a TPU backend is live (and vice
-    # versa), and the unrolled solver is ~5x faster on TPU / ~8x slower on
-    # CPU than LAPACK's batched Cholesky (ops.linalg.batched_spd_solve).
-    unroll = mesh.devices.flat[0].platform == "tpu"
-    interpret = not unroll
+    pick = _half_steps(mesh, solver, implicit, rank, factor_axis)
 
     def side_yty(opp_real):
         """Global factor Gram of one side (implicit mode), hoisted out of
@@ -696,20 +848,6 @@ def _build_iteration(mesh, rank: int, implicit: bool,
 
     if factor_axis == "model":
         fsh = NamedSharding(mesh, P("model"))
-        body = functools.partial(
-            _sharded_block_body, implicit=implicit, rank=rank,
-            unroll=unroll, solver=solver, interpret=interpret,
-        )
-        smapped = shard_map(
-            body,
-            mesh=mesh,
-            in_specs=(P("data", None), P("data", None), P("data"),
-                      P("model", None), P(), P(), P()),
-            out_specs=P(("data", "model"), None),
-            # the pallas body has no replication/vma rule; the xla body
-            # keeps the checker on
-            check_vma=solver != "pallas",
-        )
 
         def iteration(u_blocks, i_blocks, users, items, reg, alpha):
             def solve_side(blocks, opp):
@@ -721,9 +859,8 @@ def _build_iteration(mesh, rank: int, implicit: bool,
                 outs = []
                 for b, (idx, val, n_obs) in enumerate(blocks):
                     with jax.named_scope(SCOPE_BUCKET.format(b)):
-                        outs.append(
-                            smapped(idx, val, n_obs, opp, yty, reg, alpha)
-                        )
+                        step = pick(idx, opp)
+                        outs.append(step(idx, val, n_obs, opp, yty, reg, alpha))
                 with jax.named_scope(SCOPE_ASSEMBLE):
                     if len(outs) == 1:
                         # reshard P(("data","model")) -> P("model"): the
@@ -766,50 +903,20 @@ def _build_iteration(mesh, rank: int, implicit: bool,
             donate_argnums=(2, 3),
         )
 
-    if solver == "pallas":
-        pallas_step = functools.partial(
-            _half_step_pallas, implicit=implicit, rank=rank, unroll=unroll,
-            interpret=interpret,
-        )
-        smapped = shard_map(
-            pallas_step,
-            mesh=mesh,
-            in_specs=(P("data", None), P("data", None), P("data"),
-                      P(), P(), P(), P()),
-            out_specs=P("data", None),
-            check_vma=False,
-        )
-
     def iteration(u_blocks, i_blocks, users, items, reg, alpha):
-        if solver == "pallas":
-            step = smapped
-        elif implicit:
-            step = functools.partial(
-                _half_step_implicit, reg=reg, alpha=alpha, rank=rank,
-                unroll=unroll,
-            )
-        else:
-            step = functools.partial(
-                _half_step_explicit, reg=reg, rank=rank, unroll=unroll
-            )
-
         def solve_side(blocks, opp):
             with jax.named_scope(SCOPE_ASSEMBLE):
                 opp_full = jax.lax.with_sharding_constraint(
                     _append_zero_row(opp), rep
                 )
-                # what each step takes after the table: the explicit XLA
-                # step nothing (and no YtY is computed for it)
-                if solver == "pallas":
-                    rest = (side_yty(opp_full[:-1]), reg, alpha)
-                elif implicit:
-                    rest = (side_yty(opp_full[:-1]),)
-                else:
-                    rest = ()
+                yty = side_yty(opp_full[:-1])
             outs = []
             for b, (idx, val, n_obs) in enumerate(blocks):
                 with jax.named_scope(SCOPE_BUCKET.format(b)):
-                    outs.append(step(idx, val, n_obs, opp_full, *rest))
+                    step = pick(idx, opp_full)
+                    outs.append(
+                        step(idx, val, n_obs, opp_full, yty, reg, alpha)
+                    )
             with jax.named_scope(SCOPE_ASSEMBLE):
                 out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
                 return jax.lax.with_sharding_constraint(out, row)
@@ -1081,12 +1188,13 @@ def als_fit(
             # the first call returned once the program was traced and
             # compiled (or read from the persistent cache); running it is
             # asynchronous, so this is the compile share of the fit
-            device = mesh.devices.flat[0]
+            paths = block_paths(data, config, mesh)
             logger.info(
-                "als_fit: platform=%s devices=%d solver=%s"
-                " first_call_s=%.2f (trace + compile, or cache load)",
-                device.platform, mesh.devices.size,
-                resolve_solver(config.solver, device.platform),
+                "als_fit: platform=%s devices=%d solver=%s blocks_xla=%d"
+                " blocks_pallas=%d first_call_s=%.2f (trace + compile, or"
+                " cache load)",
+                mesh.devices.flat[0].platform, mesh.devices.size,
+                config.solver, paths["xla"], paths["pallas"],
                 time.perf_counter() - first_call_t0,
             )
         if telemetry is not None:
@@ -1158,8 +1266,7 @@ class _StreamPrograms:
         P = PartitionSpec
         row = NamedSharding(mesh, P("data"))
         rep = NamedSharding(mesh, P())
-        unroll = mesh.devices.flat[0].platform == "tpu"
-        interpret = not unroll
+        pick = _half_steps(mesh, solver, implicit, rank, factor_axis)
 
         def side_yty(opp):
             if implicit:
@@ -1168,62 +1275,21 @@ class _StreamPrograms:
 
         if factor_axis == "model":
             fsh = NamedSharding(mesh, P("model"))
-            body = functools.partial(
-                _sharded_block_body, implicit=implicit, rank=rank,
-                unroll=unroll, solver=solver, interpret=interpret,
-            )
-            smapped = shard_map(
-                body,
-                mesh=mesh,
-                in_specs=(P("data", None), P("data", None), P("data"),
-                          P("model", None), P(), P(), P()),
-                out_specs=P(("data", "model"), None),
-                check_vma=solver != "pallas",
-            )
             self.prep = jax.jit(
                 lambda opp: (opp, side_yty(opp)),
                 in_shardings=(fsh,), out_shardings=(fsh, rep),
             )
-
-            def solve_rows(idx, val, n_obs, opp, yty, reg, alpha):
-                piece = smapped(idx, val, n_obs, opp, yty, reg, alpha)
-                # single-array reshard P(("data","model")) -> P("model"):
-                # the J005-safe assembly (no concat ever feeds a reshard)
-                return jax.lax.with_sharding_constraint(piece, fsh)
-
+            # single-array reshard P(("data","model")) -> P("model"): the
+            # J005-safe assembly (no concat ever feeds a reshard)
+            placed = lambda piece: jax.lax.with_sharding_constraint(piece, fsh)
             buf_sh = fsh
         else:
             fsh = row
-            if solver == "pallas":
-                pallas_step = functools.partial(
-                    _half_step_pallas, implicit=implicit, rank=rank,
-                    unroll=unroll, interpret=interpret,
-                )
-                smapped = shard_map(
-                    pallas_step,
-                    mesh=mesh,
-                    in_specs=(P("data", None), P("data", None), P("data"),
-                              P(), P(), P(), P()),
-                    out_specs=P("data", None),
-                    check_vma=False,
-                )
-
-            def solve_rows(idx, val, n_obs, opp_full, yty, reg, alpha):
-                if solver == "pallas":
-                    return smapped(idx, val, n_obs, opp_full, yty, reg, alpha)
-                if implicit:
-                    return _half_step_implicit(
-                        idx, val, n_obs, opp_full, yty, reg, alpha, rank,
-                        unroll,
-                    )
-                return _half_step_explicit(
-                    idx, val, n_obs, opp_full, reg, rank, unroll
-                )
-
             self.prep = jax.jit(
                 lambda f: (_append_zero_row(f), side_yty(f)),
                 in_shardings=(row,), out_shardings=(rep, rep),
             )
+            placed = lambda piece: piece
             buf_sh = row
 
         self.factor_sharding = buf_sh
@@ -1243,7 +1309,8 @@ class _StreamPrograms:
                     # ridge): the driver ships a scalar placeholder and the
                     # [rows] vector materializes on device
                     n_obs = jnp.zeros((idx.shape[0],), jnp.float32)
-                rows = solve_rows(idx, val, n_obs, opp, yty, reg, alpha)
+                step = pick(idx, opp)
+                rows = placed(step(idx, val, n_obs, opp, yty, reg, alpha))
                 return jax.lax.dynamic_update_slice(buf, rows, (off, 0))
 
             val_sh = row if has_values else rep
@@ -1339,7 +1406,7 @@ def als_fit_streamed(
             "local devices); multi-host training uses the sharded-reader "
             "resident path"
         )
-    solver = resolve_solver(config.solver, mesh.devices.flat[0].platform)
+    solver = _program_solver(config.solver, mesh.devices.flat[0].platform)
     dtype = jnp.dtype(config.dtype)
     implicit = bool(config.implicit)
     stats = stats if stats is not None else StreamStats()

@@ -45,10 +45,11 @@ def register(sub: argparse._SubParsersAction) -> None:
         "--als-solver",
         choices=("auto", "xla", "pallas"),
         default=None,
-        help="ALS half-step tail: 'pallas' = fused gather->Gram TPU kernel"
-        " (no [rows, L, K] HBM intermediate), 'xla' = einsum path; default"
-        " auto (pallas on accelerators, xla on CPU). Overrides the"
-        " engine.json alsSolver param for this run",
+        help="ALS half-step tail: 'xla' = einsum path, 'pallas' = fused"
+        " gather->Gram TPU kernel (no [rows, L, K] HBM intermediate), either"
+        " for every block; default auto (xla, and on a TPU pallas for just"
+        " the blocks whose gathered rows cannot fit the chip). Overrides"
+        " the engine.json alsSolver param for this run",
     )
     train.add_argument(
         "--als-feed",

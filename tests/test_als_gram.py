@@ -9,10 +9,15 @@ import pytest
 
 from predictionio_tpu.ops.als_gram import _pick_chunk, gram_rhs, half_step_bytes
 from predictionio_tpu.parallel.als import (
+    EINSUM_GATHER_BUDGET_BYTES,
     ALSConfig,
     als_fit,
+    block_paths,
+    block_solver,
     build_als_data,
+    gathered_bytes,
     make_iteration,
+    resolve_solver,
 )
 from predictionio_tpu.parallel.mesh import local_mesh
 
@@ -167,15 +172,95 @@ class TestSolverSelection:
         with pytest.raises(ValueError, match="solver"):
             make_iteration(local_mesh(1, 1), cfg)
 
-    def test_auto_resolves_to_xla_on_cpu(self):
-        """CPU meshes keep the einsum path (the kernel would interpret);
-        the cached program proves the resolution."""
+    @pytest.mark.parametrize("platform", ["cpu", "tpu"])
+    def test_auto_resolves_to_xla(self, platform):
+        """"auto" is the einsum path on both: on CPU the kernel would
+        interpret, on the chip it is 15x slower wherever both fit. On a CPU
+        mesh the cached program proves the resolution."""
+        assert resolve_solver("auto", platform) == "xla"
+        assert block_solver("auto", platform, 512, 64, 6, 4) == "xla"
+        if platform == "cpu":
+            mesh = local_mesh(1, 1)
+            auto = make_iteration(mesh, ALSConfig(rank=6, solver="auto"))
+            xla = make_iteration(mesh, ALSConfig(rank=6, solver="xla"))
+            pallas = make_iteration(mesh, ALSConfig(rank=6, solver="pallas"))
+            assert auto is xla
+            assert pallas is not xla
+
+
+#: the blocks of ``als-ml20m-r16.train-steady`` (PERF.md section 4: bf16,
+#: rank 16, cap 256, 4 buckets a side; the largest is 2.31 GB of gathered
+#: rows) and of the recommendation template's default packing at
+#: MovieLens-1M (one bucket, no cap, f32; 45.3 GB for the item side)
+CELL_BLOCKS = [
+    (35_312, 256), (22_872, 152), (28_696, 88), (51_632, 48),
+    (7_648, 256), (2_224, 144), (3_840, 64), (13_048, 16),
+]
+ML1M_ITEM_BLOCK = (3_712, 23_832)
+
+
+class TestBlockRule:
+    """``block_solver``: the one statement of what "auto" runs, per block."""
+
+    @pytest.mark.parametrize("rows,pad_len", CELL_BLOCKS)
+    def test_cell_blocks_take_the_einsum_tail(self, rows, pad_len):
+        assert gathered_bytes(rows, pad_len, 16, 2) == rows * pad_len * 256
+        assert block_solver("auto", "tpu", rows, pad_len, 16, 2) == "xla"
+
+    def test_template_default_item_block_takes_the_kernel(self):
+        rows, pad_len = ML1M_ITEM_BLOCK
+        assert gathered_bytes(rows, pad_len, 16, 4) == 45_293_764_608
+        assert block_solver("auto", "tpu", rows, pad_len, 16, 4) == "pallas"
+        # the same block on a CPU mesh: the kernel would only interpret
+        assert block_solver("auto", "cpu", rows, pad_len, 16, 4) == "xla"
+
+    def test_the_budget_is_the_line(self):
+        """A full lane row (rank 128) is not padded; the budget is compared
+        on one device's rows."""
+        fit = EINSUM_GATHER_BUDGET_BYTES // (256 * 128 * 2)
+        assert block_solver("auto", "tpu", fit, 256, 128, 2) == "xla"
+        assert block_solver("auto", "tpu", fit + 8, 256, 128, 2) == "pallas"
+        assert gathered_bytes(8, 8, 129, 4) == 8 * 8 * 256 * 4
+
+    @pytest.mark.parametrize("solver", ["xla", "pallas"])
+    @pytest.mark.parametrize("platform", ["cpu", "tpu"])
+    @pytest.mark.parametrize(
+        "rows,pad_len", [CELL_BLOCKS[0], ML1M_ITEM_BLOCK], ids=["cell", "ml1m"]
+    )
+    def test_a_solver_given_by_name_forces_every_block(
+        self, solver, platform, rows, pad_len
+    ):
+        assert resolve_solver(solver, platform) == solver
+        assert block_solver(solver, platform, rows, pad_len, 16, 4) == solver
+
+    def test_invalid_solver_rejected_by_the_rule(self):
+        with pytest.raises(ValueError, match="solver"):
+            block_solver("cuda", "tpu", 8, 8, 16, 4)
+
+    def test_block_paths_counts_both_sides(self, synthetic):
+        """On a CPU mesh every block is on the einsum tail under "auto" and
+        on the kernel under "pallas"; the count is over both sides."""
+        n_u, n_i, uu, ii, rr = synthetic
+        cfg = ALSConfig(rank=6, buckets=2)
+        data = build_als_data(uu, ii, rr, n_u, n_i, cfg)
+        n = len(data.by_row.blocks) + len(data.by_col.blocks)
         mesh = local_mesh(1, 1)
-        auto = make_iteration(mesh, ALSConfig(rank=6, solver="auto"))
-        xla = make_iteration(mesh, ALSConfig(rank=6, solver="xla"))
-        pallas = make_iteration(mesh, ALSConfig(rank=6, solver="pallas"))
-        assert auto is xla
-        assert pallas is not xla
+        assert block_paths(data, cfg, mesh) == {"xla": n, "pallas": 0}
+        forced = ALSConfig(rank=6, buckets=2, solver="pallas")
+        assert block_paths(data, forced, mesh) == {"xla": 0, "pallas": n}
+
+    def test_als_fit_logs_the_count_of_blocks_on_each_path(
+        self, synthetic, caplog
+    ):
+        n_u, n_i, uu, ii, rr = synthetic
+        cfg = ALSConfig(rank=6, iterations=2, buckets=2)
+        data = build_als_data(uu, ii, rr, n_u, n_i, cfg)
+        n = len(data.by_row.blocks) + len(data.by_col.blocks)
+        with caplog.at_level("INFO", logger="pio.als"):
+            als_fit(data, cfg, local_mesh(1, 1))
+        (line,) = [r.getMessage() for r in caplog.records
+                   if r.getMessage().startswith("als_fit:")]
+        assert f"solver=auto blocks_xla={n} blocks_pallas=0" in line
 
 
 class TestSolverPlumbing:

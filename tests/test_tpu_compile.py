@@ -190,14 +190,16 @@ def test_ncf_scorer_compiles(one_chip, no_persistent_cache):
     assert _kernel_instructions(text, "ncf_score_all_items")
 
 
-def test_model_sharded_als_compiles_on_four_chips(topo, no_persistent_cache):
+@pytest.mark.parametrize("solver", ["pallas", "auto"])
+def test_model_sharded_als_compiles_on_four_chips(topo, no_persistent_cache, solver):
     """The ALX block body (``factor_sharding="model"``) on ``Mesh(topo.devices)``
-    as data=2 x model=2: the fused kernel per device and a reduce-scatter of
-    its partial Gram/rhs over the model axis."""
+    as data=2 x model=2. By name, the fused kernel per device and a
+    reduce-scatter of its partial Gram/rhs over the model axis; under "auto"
+    a block this small gathers its local hits and reduce-scatters those."""
     from predictionio_tpu.parallel.als import ALSConfig, make_iteration
 
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
-    config = ALSConfig(rank=8, factor_sharding="model", solver="auto")
+    config = ALSConfig(rank=8, factor_sharding="model", solver=solver)
     row, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
     fsh = NamedSharding(mesh, P("model"))
     rows, length = 1024, 64
@@ -215,7 +217,79 @@ def test_model_sharded_als_compiles_on_four_chips(topo, no_persistent_cache):
         block(), block(), factors, factors, scalar, scalar
     ).compile().as_text()
     assert "reduce-scatter" in text
-    assert "tpu_custom_call" in text  # auto resolved to pallas: the mesh is TPU
+    assert ("tpu_custom_call" in text) == (solver == "pallas")
+
+
+def _one_chip_iteration(topo, config, user_blocks, item_blocks):
+    """``make_iteration`` for one described chip, compiled at the given
+    block shapes (users and items sized by their blocks' rows)."""
+    from predictionio_tpu.parallel.als import make_iteration
+
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    row, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    dtype = jnp.dtype(config.dtype)
+
+    def blocks(shapes):
+        return tuple((
+            jax.ShapeDtypeStruct((rows, length), jnp.int32, sharding=row),
+            jax.ShapeDtypeStruct((rows, length), jnp.float32, sharding=row),
+            jax.ShapeDtypeStruct((rows,), jnp.float32, sharding=row),
+        ) for rows, length in shapes)
+
+    def factors(shapes):
+        return jax.ShapeDtypeStruct(
+            (sum(rows for rows, _ in shapes), config.rank), dtype, sharding=row)
+
+    scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=rep)
+    return make_iteration(mesh, config).lower(
+        blocks(user_blocks), blocks(item_blocks), factors(user_blocks),
+        factors(item_blocks), scalar, scalar,
+    ).compile()
+
+
+def test_auto_takes_the_einsum_tail_for_every_block_of_the_train_cell(
+    topo, no_persistent_cache
+):
+    """``als-ml20m-r16.train-steady``'s eight blocks (PERF.md section 4):
+    under "auto" none runs the fused kernel, and the program's temporaries
+    (the lane-padded gathered rows of the largest block, 2.31 GB, and what
+    the einsums and the solve add) fit the chip several times over."""
+    from predictionio_tpu.parallel.als import ALSConfig
+
+    config = ALSConfig(rank=16, dtype="bfloat16", solver="auto")
+    compiled = _one_chip_iteration(
+        topo, config,
+        [(35_312, 256), (22_872, 152), (28_696, 88), (51_632, 48)],
+        [(7_648, 256), (2_224, 144), (3_840, 64), (13_048, 16)],
+    )
+    assert "tpu_custom_call" not in compiled.as_text()
+    temp_size = compiled.memory_analysis().temp_size_in_bytes
+    print(f"train cell, auto: temp_size {temp_size} bytes")
+    assert 35_312 * 256 * 128 * 2 <= temp_size < 4 << 30
+
+
+def test_auto_keeps_the_kernel_for_the_template_default_block(
+    topo, no_persistent_cache
+):
+    """The recommendation template's default packing (one bucket, no cap,
+    f32) at MovieLens-1M: users [6040, 216], items [3712, 23832]. The einsum
+    tail's gathered rows for the item block are 45.3 GB: forced by name, the
+    compiler refuses the program (the control). "auto" compiles: the kernel
+    for that block alone, the einsums for the user block."""
+    from predictionio_tpu.parallel.als import ALSConfig
+
+    users, items = [(6_040, 216)], [(3_712, 23_832)]
+    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
+        _one_chip_iteration(topo, ALSConfig(rank=16, solver="xla"), users, items)
+    compiled = _one_chip_iteration(
+        topo, ALSConfig(rank=16, solver="auto"), users, items)
+    text = compiled.as_text()
+    (kernel,) = _kernel_instructions(text, "als_gram_rhs")
+    line = next(l for l in text.splitlines() if f"%{kernel} = " in l)
+    assert "als.item_half_step" in line  # the oversized block's, not the users'
+    mem = compiled.memory_analysis()
+    print(f"ML-1M template defaults, auto: temp_size {mem.temp_size_in_bytes} bytes")
+    assert mem.temp_size_in_bytes < 4 << 30
 
 
 #: instructions of an entry computation that move or name data and do no
@@ -240,24 +314,10 @@ def test_als_iteration_names_its_kernel_and_scopes_its_work(
 
     from predictionio_tpu.parallel import als
 
-    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
     config = als.ALSConfig(rank=8, solver=solver, dtype="bfloat16")
-    row, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
-
-    def blocks(*shapes):
-        return tuple((
-            jax.ShapeDtypeStruct((rows, length), jnp.int32, sharding=row),
-            jax.ShapeDtypeStruct((rows, length), jnp.float32, sharding=row),
-            jax.ShapeDtypeStruct((rows,), jnp.float32, sharding=row),
-        ) for rows, length in shapes)
-
-    users = jax.ShapeDtypeStruct((768, 8), jnp.bfloat16, sharding=row)
-    items = jax.ShapeDtypeStruct((384, 8), jnp.bfloat16, sharding=row)
-    scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=rep)
-    text = als.make_iteration(mesh, config).lower(
-        blocks((256, 64), (512, 16)), blocks((128, 128), (256, 32)),
-        users, items, scalar, scalar,
-    ).compile().as_text()
+    text = _one_chip_iteration(
+        topo, config, [(256, 64), (512, 16)], [(128, 128), (256, 32)]
+    ).as_text()
 
     kernels = _kernel_instructions(text, "als_gram_rhs")
     assert len(kernels) == (4 if solver == "pallas" else 0)
