@@ -73,6 +73,8 @@ def prepare_als_data(
         # counts the packer has already: no pass over the data for them
         edges = len(users)
         span.set_attr("edges", edges)
+        span.set_attr("data_shards", num_shards)
+        span.set_attr("model_shards", model_shards)
         for name, side in (("by_row", data.by_row), ("by_col", data.by_col)):
             span.set_attr(name, {
                 "retained_edges": edges - side.truncated,
@@ -565,8 +567,7 @@ def fit_with_checkpoint(
         with global_tracer().span(
             "als.fit",
             attrs={"name": name, "iterations": config.iterations,
-                   "blocks_xla": paths["xla"],
-                   "blocks_pallas": paths["pallas"]},
+                   **_layout_attrs(paths, config, mesh)},
         ):
             model = fit(
                 als_data,
@@ -584,6 +585,21 @@ def fit_with_checkpoint(
     if checkpoint is not None:
         checkpoint.close()
     return model
+
+
+def _layout_attrs(paths: dict, config: ALSConfig, mesh) -> dict:
+    """What the span ``als.fit`` and the ``--profile`` journal's meta line say
+    of the program's layout: the mesh, where the factors live, and how the
+    blocks are worked (``block_paths``)."""
+    return {
+        "mesh_data": mesh.shape["data"],
+        "mesh_model": mesh.shape.get("model", 1),
+        "factor_sharding": config.factor_sharding,
+        "blocks_xla": paths["xla"],
+        "blocks_pallas": paths["pallas"],
+        "blocks_chunked": paths["chunked"],
+        "max_chunks": paths["max_chunks"],
+    }
 
 
 def _build_telemetry(ctx, als_data, config: ALSConfig, mesh, name: str):
@@ -621,8 +637,7 @@ def _build_telemetry(ctx, als_data, config: ALSConfig, mesh, name: str):
                 "name": name,
                 "rank": config.rank,
                 "solver": config.solver,
-                "blocks_xla": paths["xla"],
-                "blocks_pallas": paths["pallas"],
+                **_layout_attrs(paths, config, mesh),
                 "platform": mesh.devices.flat[0].platform,
                 "dtype": config.dtype,
                 "iterations": config.iterations,

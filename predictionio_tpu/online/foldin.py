@@ -155,10 +155,11 @@ def _device_factors(item_factors: np.ndarray):
 
 
 @functools.lru_cache(maxsize=16)
-def _build_solver(solver: str, implicit: bool, rank: int, platform: str):
-    """One jitted delta half-step per (solver, mode, rank, platform) --
-    repeated fold-ins reuse the compiled program (shapes are padded to a
-    pow2 ladder below for the same reason)."""
+def _build_solver(solver: str, implicit: bool, rank: int, platform: str,
+                  chunks: int = 1):
+    """One jitted delta half-step per (solver, mode, rank, platform, row
+    chunks) -- repeated fold-ins reuse the compiled program (shapes are
+    padded to a pow2 ladder below for the same reason)."""
     import jax
     import jax.numpy as jnp
 
@@ -170,13 +171,13 @@ def _build_solver(solver: str, implicit: bool, rank: int, platform: str):
         _finish_implicit,
         _half_step_explicit,
         _half_step_implicit,
+        _in_row_chunks,
     )
 
     unroll = platform == "tpu"
     interpret = not unroll
 
-    def step(indices, values, n_obs, factors, reg, alpha):
-        full = _append_zero_row(factors)
+    def block(indices, values, n_obs, full, yty, reg, alpha):
         if solver == "pallas":
             gram, rhs = gram_rhs(
                 indices.astype(jnp.int32), values, full, alpha,
@@ -184,18 +185,24 @@ def _build_solver(solver: str, implicit: bool, rank: int, platform: str):
             )
             if implicit:
                 return _finish_implicit(
-                    gram, rhs, _factors_yty(factors), reg, rank, unroll,
-                    factors.dtype,
+                    gram, rhs, yty, reg, rank, unroll, full.dtype
                 )
             return _finish_explicit(
-                gram, rhs, n_obs, reg, rank, unroll, factors.dtype
+                gram, rhs, n_obs, reg, rank, unroll, full.dtype
             )
         if implicit:
             return _half_step_implicit(
-                indices, values, n_obs, full, _factors_yty(factors), reg,
-                alpha, rank, unroll,
+                indices, values, n_obs, full, yty, reg, alpha, rank, unroll
             )
         return _half_step_explicit(indices, values, n_obs, full, reg, rank, unroll)
+
+    if chunks > 1:  # parallel.als.block_plan: too many rows for one piece
+        block = _in_row_chunks(block, chunks)
+
+    def step(indices, values, n_obs, factors, reg, alpha):
+        yty = _factors_yty(factors) if implicit else None
+        return block(indices, values, n_obs, _append_zero_row(factors), yty,
+                     reg, alpha)
 
     return jax.jit(step)
 
@@ -216,8 +223,9 @@ def fold_in_users(
     (``cols`` indexing ``item_factors``). Returns ``[num_rows, K]`` f32 --
     the exact ridge/implicit solution per row, via the same half-step tail
     ``als_fit`` runs (``config.solver`` resolves "auto" like training, from
-    the packed block's shape: the XLA einsums, or on a TPU the fused Pallas
-    kernel for a block whose gathered rows would not fit the chip).
+    the packed block's shape, ``parallel.als.block_plan``: the XLA einsums,
+    or on a TPU the fused Pallas kernel for a block whose gathered rows would
+    not fit the chip; in row chunks where its normal equations would not).
 
     Shapes are padded to a pow2 ladder (rows AND history length) so a
     long-running loop compiles a handful of programs, not one per delta.
@@ -225,7 +233,7 @@ def fold_in_users(
     import jax
 
     from predictionio_tpu.ops.ragged import pack_padded_csr
-    from predictionio_tpu.parallel.als import block_solver
+    from predictionio_tpu.parallel.als import block_plan
 
     if num_rows == 0:
         return np.zeros((0, item_factors.shape[1]), np.float32)
@@ -246,8 +254,10 @@ def fold_in_users(
     )
     rank = item_factors.shape[1]
     # the table ships as float32 (_device_factors)
-    solver = block_solver(config.solver, platform, *csr.indices.shape, rank, 4)
-    step = _build_solver(solver, bool(config.implicit), rank, platform)
+    solver, chunks = block_plan(
+        config.solver, platform, *csr.indices.shape, rank, 4
+    )
+    step = _build_solver(solver, bool(config.implicit), rank, platform, chunks)
     out = step(
         csr.indices,
         csr.values,

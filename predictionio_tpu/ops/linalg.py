@@ -12,6 +12,14 @@ from jax.scipy.linalg import cho_solve
 _UNROLL_MAX_K = 32
 
 
+def solve_unrolls(k: int, unroll: bool) -> bool:
+    """Whether ``batched_spd_solve`` takes the unrolled path for K x K systems
+    (``unroll`` as resolved for the target platform). The other path writes a
+    ``[batch, K, K]`` Cholesky factor beside the Gram: callers that size their
+    batches (``parallel.als.block_plan``) ask here."""
+    return bool(unroll) and k <= _UNROLL_MAX_K
+
+
 def batched_spd_solve(
     gram: jnp.ndarray,
     rhs: jnp.ndarray,
@@ -40,7 +48,7 @@ def batched_spd_solve(
     gram = gram + jitter * eye
     if unroll is None:
         unroll = jax.default_backend() == "tpu"
-    if not unroll or k > _UNROLL_MAX_K or gram.ndim != 3:
+    if not solve_unrolls(k, unroll) or gram.ndim != 3:
         chol = cholesky(gram)
         return cho_solve((chol, True), rhs[..., None])[..., 0]
     return _unrolled_chol_solve(gram, rhs)

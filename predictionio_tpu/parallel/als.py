@@ -43,7 +43,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from predictionio_tpu.ops.als_gram import gram_rhs
-from predictionio_tpu.ops.linalg import batched_spd_solve
+from predictionio_tpu.ops.linalg import batched_spd_solve, solve_unrolls
 from predictionio_tpu.ops.ragged import PaddedCSR, pack_padded_csr, round_up
 from predictionio_tpu.parallel.mesh import cached_by_mesh, one_step_in_flight
 from predictionio_tpu.utils.jax_compat import axis_size, shard_map
@@ -68,6 +68,14 @@ SCOPE_SOLVE = "solve"
 #: the zero row, the replicated constraint, YtY, and putting the buckets'
 #: rows together
 SCOPE_ASSEMBLE = "assemble"
+#: nested where the work is, under ``gram`` or ``assemble``: what crosses the
+#: chips in the model layout -- the exchange over ``model`` that completes a
+#: bucket's gathered rows (or, on the kernel, its partial Grams), and the
+#: re-layout of the solved rows from ``P(("data", "model"))`` back to
+#: ``P("model")``
+SCOPE_EXCHANGE = "exchange"
+#: nested under ``assemble``: the side's global K x K Gram (implicit only)
+SCOPE_YTY = "yty"
 
 
 @dataclass
@@ -531,9 +539,13 @@ def _sharded_block_body(idx, values, n_obs, opp_local, yty, reg, alpha,
 
     1. gather local hits only (out-of-shard indices -- including the
        padding sentinel, which is out of EVERY shard -- contribute zeros);
-    2. psum_scatter over "model" completes the sum while handing each
-       device only its 1/m slice of the rows (half the traffic of a psum,
-       and the [rows, L, K] gathered intermediate shrinks by m);
+    2. an all_to_all over "model" hands each device the m partial copies
+       of its 1/m slice of the rows, and their sum completes it (a
+       reduce-scatter by hand: half the traffic of a psum, and the
+       [rows, L, K] gathered intermediate shrinks by m. The TPU compiler
+       turns ``psum_scatter`` of this array into pad + all-reduce + slice,
+       twice the bytes and no ``op_name`` left for a trace: seen compiling
+       the MSD rank-128 blocks for a described v5e 2x2, PR 26);
     3. each device solves its rows' normal equations -- compute scales
        with the full d*m device count, not just d.
 
@@ -562,12 +574,13 @@ def _sharded_block_body(idx, values, n_obs, opp_local, yty, reg, alpha,
                 safe, values, _append_zero_row(opp_local), alpha,
                 implicit=implicit, interpret=interpret,
             )
-            gram = jax.lax.psum_scatter(
-                gram, "model", scatter_dimension=0, tiled=True
-            )
-            rhs = jax.lax.psum_scatter(
-                rhs, "model", scatter_dimension=0, tiled=True
-            )
+            with jax.named_scope(SCOPE_EXCHANGE):
+                gram = jax.lax.psum_scatter(
+                    gram, "model", scatter_dimension=0, tiled=True
+                )
+                rhs = jax.lax.psum_scatter(
+                    rhs, "model", scatter_dimension=0, tiled=True
+                )
         if implicit:
             return _finish_implicit(
                 gram, rhs, yty, reg, rank, unroll, opp_local.dtype
@@ -581,7 +594,13 @@ def _sharded_block_body(idx, values, n_obs, opp_local, yty, reg, alpha,
         hit = (loc >= 0) & (loc < s_m)
         g = opp_local[jnp.clip(loc, 0, s_m - 1)]
         g = g * hit[..., None].astype(g.dtype)
-        g = jax.lax.psum_scatter(g, "model", scatter_dimension=0, tiled=True)
+        with jax.named_scope(SCOPE_EXCHANGE):
+            # run j of the device's rows goes to device j of the model axis,
+            # which adds up the m runs it receives (each slot hits one shard,
+            # the others sent zeros: exact in any dtype)
+            g = jax.lax.all_to_all(
+                g.reshape((m, rows) + g.shape[1:]), "model", 0, 0
+            ).sum(axis=0)
         val_s = jax.lax.dynamic_slice_in_dim(values, mi * rows, rows, 0)
     if implicit:
         return _gram_solve_implicit(
@@ -622,9 +641,12 @@ def resolve_solver(solver: str, platform: str) -> str:
 #: lanes x itemsize in HBM, 8 times the factors' own bytes at rank 16.
 _LANES = 128
 
-#: Most bytes a block's gathered intermediate may take on one device for
-#: "auto" to leave the block on the einsum tail: a quarter of a v5e's 16 GiB,
-#: the smallest HBM this runs on. Compiled for a described v5e
+#: Most bytes one block may allocate on one device at a time under "auto":
+#: a quarter of a v5e's 16 GiB, the smallest HBM this runs on. Two things are
+#: held to it (``block_plan``): a block whose gathered intermediate alone is
+#: over it leaves the einsum tail for the fused kernel, and a block whose
+#: whole allocation (gathered rows, Grams, Cholesky factors) is over it is
+#: worked in row chunks, each under it. Compiled for a described v5e
 #: (``memory_analysis``, PR 25): the einsum program's temporaries are 1.07 to
 #: 1.27 times its largest block's intermediate (f32 explicit to bf16
 #: implicit; 2.68 GB for the 2.31 GB of the ML-20M cell's largest block,
@@ -647,15 +669,23 @@ def gathered_bytes(rows: int, pad_len: int, rank: int, itemsize: int) -> int:
     return rows * pad_len * round_up(rank, _LANES) * itemsize
 
 
+def normal_equation_bytes(rows: int, rank: int, unrolled: bool) -> int:
+    """HBM bytes of ``rows`` rows' normal equations: the ``[rows, K, K]``
+    float32 Gram and, where the solve is not the unrolled one
+    (``ops.linalg.solve_unrolls``), the Cholesky factor of the same size that
+    ``lax.linalg.cholesky`` writes beside it. 1 KiB a row at rank 16; 64 KiB
+    and 64 KiB again at rank 128, more than a gathered bf16 row wherever
+    ``pad_len`` < 256."""
+    return rows * rank * rank * 4 * (1 if unrolled else 2)
+
+
 def block_solver(solver: str, platform: str, rows: int, pad_len: int,
                  rank: int, itemsize: int) -> str:
-    """The half-step path of ONE block -- the one statement of the "auto"
-    rule, asked at trace time by everything that has a block's static shape
-    (``rows`` on one device, ``pad_len``, the factors' ``rank`` and
-    ``itemsize``). "xla" / "pallas" by name force every block. "auto" takes
-    the einsum tail, except on a TPU for a block whose gathered intermediate
-    is over ``EINSUM_GATHER_BUDGET_BYTES``: that one takes the fused kernel,
-    which never materializes it."""
+    """The half-step path of ONE block: the first half of ``block_plan``.
+    "xla" / "pallas" by name force every block. "auto" takes the einsum
+    tail, except on a TPU for a block whose gathered intermediate is over
+    ``EINSUM_GATHER_BUDGET_BYTES``: that one takes the fused kernel, which
+    never materializes it."""
     too_large = (
         gathered_bytes(rows, pad_len, rank, itemsize)
         > EINSUM_GATHER_BUDGET_BYTES
@@ -663,6 +693,69 @@ def block_solver(solver: str, platform: str, rows: int, pad_len: int,
     if solver == "auto" and platform == "tpu" and too_large:
         return "pallas"
     return resolve_solver(solver, platform)
+
+
+def block_plan(solver: str, platform: str, rows: int, pad_len: int,
+               rank: int, itemsize: int, model_shards: int = 1
+               ) -> tuple[str, int]:
+    """How ONE block is worked, ``(path, chunks)`` -- the one statement of
+    the rule, asked at trace time by everything that has a block's static
+    shape: ``rows`` on one device of the data axis, ``pad_len``, the factors'
+    ``rank`` and ``itemsize``, and ``model_shards`` (the model layout solves
+    ``rows / model_shards`` of them on each device; 1 otherwise).
+
+    ``path`` is ``block_solver``'s. ``chunks`` counts what the block
+    allocates on that path -- the gathered rows (the einsum tail only), the
+    float32 Grams and, where the rank leaves the unrolled solve, the Cholesky
+    factors -- and is the number of equal row chunks that brings one chunk's
+    share under ``EINSUM_GATHER_BUDGET_BYTES``; 1 is the block whole. Rows
+    are independent, so a chunk's rows come out as they would from the whole
+    block. A solver given by name is chunked by the same count: the name
+    picks the arithmetic, not what fits."""
+    path = block_solver(solver, platform, rows, pad_len, rank, itemsize)
+    allocated = normal_equation_bytes(
+        rows // model_shards, rank, solve_unrolls(rank, platform == "tpu")
+    )
+    if path == "xla":
+        allocated += gathered_bytes(rows, pad_len, rank, itemsize)
+    return path, max(1, -(-allocated // EINSUM_GATHER_BUDGET_BYTES))
+
+
+def _in_row_chunks(step, chunks: int, slices: int = 1, sharded: bool = False):
+    """``step`` over ``chunks`` equal row chunks of one device's block, one
+    after another (``lax.map``: one chunk's temporaries at a time), same
+    signature and same rows out.
+
+    The device's rows are ``slices`` contiguous runs (the model layout hands
+    each device of the model axis one run of the solved rows; 1 otherwise):
+    chunk ``c`` takes the ``c``-th piece of every run, so that what a device
+    keeps over the chunks is its own run, in order. Runs are padded to a
+    multiple of 8 * chunks rows with empty rows, dropped again on the way
+    out. An empty row's slots all hold an index that gathers zeros (the
+    padding invariant): the zero row the caller appended to a replicated
+    table, or with ``sharded`` tables the first index past the last shard's
+    rows, which is out of EVERY shard."""
+    def chunked(idx, val, n_obs, table, yty, reg, alpha):
+        run = idx.shape[0] // slices
+        size = round_up(-(-run // chunks), 8)
+        zero = slices * table.shape[0] if sharded else table.shape[0] - 1
+
+        def split(x, fill):
+            x = x.reshape((slices, run) + x.shape[1:])
+            widths = [(0, 0), (0, chunks * size - run)] + [(0, 0)] * (x.ndim - 2)
+            x = jnp.pad(x, widths, constant_values=fill)
+            x = x.reshape((slices, chunks, size) + x.shape[2:])
+            return jnp.moveaxis(x, 1, 0).reshape(
+                (chunks, slices * size) + x.shape[3:]
+            )
+
+        out = jax.lax.map(
+            lambda chunk: step(*chunk, table, yty, reg, alpha),
+            (split(idx, zero), split(val, 0), split(n_obs, 0)),
+        )
+        return out.reshape((chunks * size, out.shape[-1]))[:run]
+
+    return chunked
 
 
 def _program_solver(solver: str, platform: str) -> str:
@@ -675,14 +768,16 @@ def _program_solver(solver: str, platform: str) -> str:
 
 
 def block_paths(data, config: ALSConfig, mesh) -> dict[str, int]:
-    """How many of ``data``'s blocks (both sides; resident or streamed) take
-    each half-step path in the program built for (mesh, config):
-    ``{"xla": n, "pallas": m}``. The same ``block_solver`` the program asks
-    at trace time, on the same shapes."""
+    """How ``data``'s blocks (both sides; resident or streamed) are worked in
+    the program built for (mesh, config): ``{"xla": n, "pallas": m}`` blocks
+    on each half-step path, of which ``"chunked"`` in row chunks, the most
+    chunks of any under ``"max_chunks"`` (1: every block whole). The same
+    ``block_plan`` the program asks at trace time, on the same shapes."""
     platform = mesh.devices.flat[0].platform
     d = mesh.shape["data"]
+    m = mesh.shape.get("model", 1) if config.factor_sharding == "model" else 1
     itemsize = jnp.dtype(config.dtype).itemsize
-    paths = {"xla": 0, "pallas": 0}
+    paths = {"xla": 0, "pallas": 0, "chunked": 0, "max_chunks": 1}
     for side in (data.by_row, data.by_col):
         specs = getattr(side, "specs", None)  # a streamed side's blocks
         if specs is not None:
@@ -691,8 +786,11 @@ def block_paths(data, config: ALSConfig, mesh) -> dict[str, int]:
             rows = side.global_rows or [b.indices.shape[0] for b in side.blocks]
             shapes = [(r, b.indices.shape[1]) for r, b in zip(rows, side.blocks)]
         for rows_b, pad_len in shapes:
-            paths[block_solver(config.solver, platform, rows_b // d, pad_len,
-                               config.rank, itemsize)] += 1
+            path, chunks = block_plan(config.solver, platform, rows_b // d,
+                                      pad_len, config.rank, itemsize, m)
+            paths[path] += 1
+            paths["chunked"] += chunks > 1
+            paths["max_chunks"] = max(paths["max_chunks"], chunks)
     return paths
 
 
@@ -703,13 +801,14 @@ def _half_steps(mesh, solver: str, implicit: bool, rank: int,
 
     Returns ``pick(idx, factors) -> step``, with ``step(idx, values, n_obs,
     factors, yty, reg, alpha) -> rows``. ``solver`` is "xla" or "pallas" for
-    every block, or "auto" (a TPU mesh: ``_program_solver``), under which
-    ``block_solver`` decides from the block's shape on one device (rows
+    every block, or "auto" (a TPU mesh: ``_program_solver``); ``block_plan``
+    decides path and row chunks from the block's shape on one device (rows
     split over the data axis in both layouts); a path no block takes is
-    never traced. The einsum tail with replicated factors is left to GSPMD;
-    the fused kernel is opaque to it, and the model-sharded body exchanges
-    over ``model``, so those go through an explicit shard_map. The explicit
-    einsum tail drops ``yty`` and ``alpha`` (a dummy and a scalar).
+    never traced. The einsum tail with replicated factors, whole, is left to
+    GSPMD; the fused kernel is opaque to it, the model-sharded body exchanges
+    over ``model`` and a chunked block loops over its device's own rows, so
+    those go through an explicit shard_map. The explicit einsum tail drops
+    ``yty`` and ``alpha`` (a dummy and a scalar).
     """
     P = PartitionSpec
     platform = mesh.devices.flat[0].platform
@@ -719,7 +818,8 @@ def _half_steps(mesh, solver: str, implicit: bool, rank: int,
     # CPU than LAPACK's batched Cholesky (ops.linalg.batched_spd_solve).
     unroll = platform == "tpu"
     interpret = not unroll
-    paths = ("xla", "pallas") if solver == "auto" else (solver,)
+    model = factor_axis == "model"
+    slices = mesh.shape["model"] if model else 1
 
     def einsum_step(idx, val, n_obs, table, yty, reg, alpha):
         if implicit:
@@ -728,43 +828,40 @@ def _half_steps(mesh, solver: str, implicit: bool, rank: int,
             )
         return _half_step_explicit(idx, val, n_obs, table, reg, rank, unroll)
 
-    if factor_axis == "model":
-        steps = {
-            path: shard_map(
-                functools.partial(
-                    _sharded_block_body, implicit=implicit, rank=rank,
-                    unroll=unroll, solver=path, interpret=interpret,
-                ),
-                mesh=mesh,
-                in_specs=(P("data", None), P("data", None), P("data"),
-                          P("model", None), P(), P(), P()),
-                out_specs=P(("data", "model"), None),
-                # the pallas body has no replication/vma rule; the xla body
-                # keeps the checker on
-                check_vma=path != "pallas",
+    @functools.cache
+    def build(path: str, chunks: int):
+        if not model and path == "xla" and chunks == 1:
+            return einsum_step  # left to GSPMD
+        if model:
+            body = functools.partial(
+                _sharded_block_body, implicit=implicit, rank=rank,
+                unroll=unroll, solver=path, interpret=interpret,
             )
-            for path in paths
-        }
-    else:
-        steps = {"xla": einsum_step}
-        if "pallas" in paths:
-            steps["pallas"] = shard_map(
-                functools.partial(
-                    _half_step_pallas, implicit=implicit, rank=rank,
-                    unroll=unroll, interpret=interpret,
-                ),
-                mesh=mesh,
-                in_specs=(P("data", None), P("data", None), P("data"),
-                          P(), P(), P(), P()),
-                out_specs=P("data", None),
-                check_vma=False,
+        elif path == "pallas":
+            body = functools.partial(
+                _half_step_pallas, implicit=implicit, rank=rank,
+                unroll=unroll, interpret=interpret,
             )
+        else:
+            body = einsum_step
+        if chunks > 1:
+            body = _in_row_chunks(body, chunks, slices, sharded=model)
+        return shard_map(
+            body,
+            mesh=mesh,
+            in_specs=(P("data", None), P("data", None), P("data"),
+                      P("model", None) if model else P(), P(), P(), P()),
+            out_specs=P(("data", "model") if model else "data", None),
+            # the pallas body has no replication/vma rule; the model
+            # layout's xla body keeps the checker on
+            check_vma=model and path != "pallas",
+        )
 
     def pick(idx, factors):
-        return steps[block_solver(
+        return build(*block_plan(
             solver, platform, idx.shape[0] // mesh.shape["data"],
-            idx.shape[1], rank, factors.dtype.itemsize,
-        )]
+            idx.shape[1], rank, factors.dtype.itemsize, slices,
+        ))
 
     return pick
 
@@ -843,7 +940,8 @@ def _build_iteration(mesh, rank: int, implicit: bool,
         """Global factor Gram of one side (implicit mode), hoisted out of
         the per-bucket loop; explicit mode feeds a dummy the steps drop."""
         if implicit:
-            return _factors_yty(opp_real)
+            with jax.named_scope(SCOPE_YTY):
+                return _factors_yty(opp_real)
         return jnp.zeros((rank, rank), jnp.float32)
 
     if factor_axis == "model":
@@ -866,7 +964,10 @@ def _build_iteration(mesh, rank: int, implicit: bool,
                         # reshard P(("data","model")) -> P("model"): the
                         # all-gather over 'data' that readies this side
                         # for the next gather
-                        return jax.lax.with_sharding_constraint(outs[0], fsh)
+                        with jax.named_scope(SCOPE_EXCHANGE):
+                            return jax.lax.with_sharding_constraint(
+                                outs[0], fsh
+                            )
                     # multi-bucket assembly resharded PIECEWISE via
                     # dynamic_update_slice: jnp.concatenate of differently
                     # tuple-sharded bucket outputs followed by a reshard
@@ -883,7 +984,8 @@ def _build_iteration(mesh, rank: int, implicit: bool,
                     )
                     off = 0
                     for o in outs:
-                        piece = jax.lax.with_sharding_constraint(o, fsh)
+                        with jax.named_scope(SCOPE_EXCHANGE):
+                            piece = jax.lax.with_sharding_constraint(o, fsh)
                         buf = jax.lax.dynamic_update_slice(
                             buf, piece, (off, 0)
                         )
@@ -1190,11 +1292,14 @@ def als_fit(
             # asynchronous, so this is the compile share of the fit
             paths = block_paths(data, config, mesh)
             logger.info(
-                "als_fit: platform=%s devices=%d solver=%s blocks_xla=%d"
-                " blocks_pallas=%d first_call_s=%.2f (trace + compile, or"
-                " cache load)",
+                "als_fit: platform=%s devices=%d mesh_data=%d mesh_model=%d"
+                " factor_sharding=%s solver=%s blocks_xla=%d blocks_pallas=%d"
+                " blocks_chunked=%d max_chunks=%d first_call_s=%.2f (trace +"
+                " compile, or cache load)",
                 mesh.devices.flat[0].platform, mesh.devices.size,
-                config.solver, paths["xla"], paths["pallas"],
+                mesh.shape["data"], mesh.shape.get("model", 1),
+                config.factor_sharding, config.solver, paths["xla"],
+                paths["pallas"], paths["chunked"], paths["max_chunks"],
                 time.perf_counter() - first_call_t0,
             )
         if telemetry is not None:
@@ -1270,7 +1375,8 @@ class _StreamPrograms:
 
         def side_yty(opp):
             if implicit:
-                return _factors_yty(opp)
+                with jax.named_scope(SCOPE_YTY):
+                    return _factors_yty(opp)
             return jnp.zeros((rank, rank), jnp.float32)
 
         if factor_axis == "model":

@@ -346,11 +346,21 @@ class TestDeviceScopes:
         program, args = self._program_and_args(synthetic, solver, sharding, implicit)
         stacks = set(self._name_stacks(program.trace(*args).jaxpr.jaxpr))
         assert len(args[0]) == 2  # the user side keeps both its buckets
+        def under(scope):  # the scope itself, or one nested in it
+            return [s for s in stacks if s == scope or s.startswith(scope + "/")]
+
         for side, blocks in zip(als.SCOPE_HALF_STEP.values(), args):
-            assert f"{side}/{als.SCOPE_ASSEMBLE}" in stacks
+            assemble = f"{side}/{als.SCOPE_ASSEMBLE}"
+            assert under(assemble)
+            # what is nested where the work is: YtY (implicit only), and what
+            # crosses the chips in the model layout
+            assert bool(under(f"{assemble}/{als.SCOPE_YTY}")) == implicit
+            assert bool(under(f"{assemble}/{als.SCOPE_EXCHANGE}")) == (sharding == "model")
             for bucket in range(len(blocks)):
                 for stage in (als.SCOPE_GRAM, als.SCOPE_SOLVE):
                     assert f"{side}/{als.SCOPE_BUCKET.format(bucket)}/{stage}" in stacks
+                gram = f"{side}/{als.SCOPE_BUCKET.format(bucket)}/{als.SCOPE_GRAM}"
+                assert bool(under(f"{gram}/{als.SCOPE_EXCHANGE}")) == (sharding == "model")
         # and nothing of the iteration lies outside them
         assert all(stack.startswith("als.") for stack in stacks), sorted(stacks)[:5]
 

@@ -3212,7 +3212,9 @@ class TestMeshReport:
         # the canonical mesh factory and the ALS shard_map routing
         assert "predictionio_tpu/parallel/mesh.py" in out
         assert "[mesh]" in out and "axes=['data', 'model']" in out
-        assert "[shard_map]" in out and "_sharded_block_body" in out
+        # (one site since PR 26: _half_steps.build wraps whichever body the
+        # block's plan names, in row chunks where the plan says so)
+        assert "[shard_map]" in out and "_half_steps.build" in out
         assert "mesh-report:" in out
 
     def test_json_inventory_complete_against_ast_scan(self, capsys):

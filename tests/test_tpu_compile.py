@@ -195,7 +195,9 @@ def test_model_sharded_als_compiles_on_four_chips(topo, no_persistent_cache, sol
     """The ALX block body (``factor_sharding="model"``) on ``Mesh(topo.devices)``
     as data=2 x model=2. By name, the fused kernel per device and a
     reduce-scatter of its partial Gram/rhs over the model axis; under "auto"
-    a block this small gathers its local hits and reduce-scatters those."""
+    a block this small gathers its local hits and hands each chip of the
+    model pair its rows' share through an all-to-all (a ``psum_scatter`` of
+    the gathered rows the compiler turns into pad + all-reduce + slice)."""
     from predictionio_tpu.parallel.als import ALSConfig, make_iteration
 
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
@@ -216,7 +218,7 @@ def test_model_sharded_als_compiles_on_four_chips(topo, no_persistent_cache, sol
     text = make_iteration(mesh, config).lower(
         block(), block(), factors, factors, scalar, scalar
     ).compile().as_text()
-    assert "reduce-scatter" in text
+    assert ("reduce-scatter" if solver == "pallas" else "all-to-all") in text
     assert ("tpu_custom_call" in text) == (solver == "pallas")
 
 
@@ -269,18 +271,23 @@ def test_auto_takes_the_einsum_tail_for_every_block_of_the_train_cell(
 
 
 def test_auto_keeps_the_kernel_for_the_template_default_block(
-    topo, no_persistent_cache
+    topo, no_persistent_cache, monkeypatch
 ):
     """The recommendation template's default packing (one bucket, no cap,
     f32) at MovieLens-1M: users [6040, 216], items [3712, 23832]. The einsum
-    tail's gathered rows for the item block are 45.3 GB: forced by name, the
-    compiler refuses the program (the control). "auto" compiles: the kernel
-    for that block alone, the einsums for the user block."""
+    tail's gathered rows for the item block are 45.3 GB: worked whole, the
+    compiler refuses the program (the control: "xla" by name with the chunk
+    rule taken out). "auto" compiles: the kernel for that block alone, the
+    einsums for the user block."""
+    from predictionio_tpu.parallel import als
     from predictionio_tpu.parallel.als import ALSConfig
 
     users, items = [(6_040, 216)], [(3_712, 23_832)]
-    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
-        _one_chip_iteration(topo, ALSConfig(rank=16, solver="xla"), users, items)
+    with monkeypatch.context() as patch:
+        patch.setattr(als, "block_plan", lambda *shape, **kw: ("xla", 1))
+        with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
+            _one_chip_iteration(topo, ALSConfig(rank=16, solver="xla"), users, items)
+    als._build_iteration.cache_clear()  # the program built without the rule
     compiled = _one_chip_iteration(
         topo, ALSConfig(rank=16, solver="auto"), users, items)
     text = compiled.as_text()
