@@ -296,12 +296,13 @@ class Smoke:
         if not m or not inst:
             raise PhaseFailed(f"{name}: no Device/instance line: ...{text[-800:]}")
         facts = {"instance": inst.group(1), "device": self.saw(name, json.loads(m.group(1)))}
-        als = re.search(r"als_fit: platform=(\S+) devices=(\d+) solver=(\S+) blocks_xla=(\d+)"
-                        r" blocks_pallas=(\d+) first_call_s=([\d.]+)", text)
+        als = re.search(r"^.*als_fit: (platform=.*)$", text, re.M)
         if als:
-            # which half-step path the program's blocks took (parallel/als.py:block_solver)
-            facts.update(solver=als.group(3), blocks_xla=int(als.group(4)),
-                         blocks_pallas=int(als.group(5)), first_call_s=float(als.group(6)))
+            # how the program's blocks were worked (parallel/als.py:block_paths)
+            said = dict(re.findall(r"(\w+)=(\S+)", als.group(1)))
+            facts.update(
+                solver=said["solver"], first_call_s=float(said["first_call_s"]),
+                **{k: int(said[k]) for k in ("blocks_xla", "blocks_pallas", "blocked_solve")})
         timings = re.search(r"stage timings: (.*)$", text, re.M)
         if timings:
             facts["stage_timings"] = timings.group(1).strip()
@@ -369,6 +370,7 @@ class Smoke:
             cold_was_a_miss=missed, entries_before=cold["entries_before"],
             entries_after=warm["entries_after"], solver=cold["solver"],
             blocks_xla=cold["blocks_xla"], blocks_pallas=cold["blocks_pallas"],
+            blocked_solve=cold["blocked_solve"],
             tpu_custom_call=cold["tpu_custom_call"],
             device_memory_bytes=cold["device_memory_bytes"],
         )
@@ -377,7 +379,9 @@ class Smoke:
         """On the chip the compiled iteration holds one ``tpu_custom_call`` for
         each block the solver asked to run the fused kernel (every block when
         "pallas" was given by name, under "auto" the blocks too large for the
-        einsum tail) and none for the others."""
+        einsum tail) and none for the others; and above rank 32 every block's
+        rows take the blocked solve, which leaves none of ``lax.linalg.
+        cholesky`` + ``cho_solve``'s custom calls in the program."""
         if self.device["platform"] != "tpu":
             return
         if compiled["tpu_custom_call"] != compiled["blocks_pallas"]:
@@ -385,6 +389,15 @@ class Smoke:
                 f"{phase}: solver {compiled['solver']} puts {compiled['blocks_pallas']}"
                 f" block(s) on the fused kernel, the compiled iteration holds"
                 f" {compiled['tpu_custom_call']} tpu_custom_call: {compiled}")
+        blocks = compiled["blocks_xla"] + compiled["blocks_pallas"]
+        if compiled["blocked_solve"] != (blocks if compiled["rank"] > 32 else 0):
+            raise PhaseFailed(
+                f"{phase}: rank {compiled['rank']}, {blocks} block(s), of which"
+                f" {compiled['blocked_solve']} on the blocked solve: {compiled}")
+        if compiled["cholesky_custom_call"]:
+            raise PhaseFailed(
+                f"{phase}: the compiled iteration holds {compiled['cholesky_custom_call']}"
+                f" Cholesky / InvertDiagBlocksLowerTriangular custom call(s): {compiled}")
 
     def phase_ingest(self) -> None:
         import numpy as np
@@ -691,11 +704,15 @@ def _compile_iteration(data, config, mesh) -> dict:
     compile_s = time.perf_counter() - t0
     mem = compiled.memory_analysis()
     paths = block_paths(data, config, mesh)
+    text = compiled.as_text()
     return {
-        "solver": config.solver,
+        "solver": config.solver, "rank": config.rank,
         "blocks_xla": paths["xla"], "blocks_pallas": paths["pallas"],
+        "blocked_solve": paths["blocked_solve"],
         "compile_s": round(compile_s, 2),
-        "tpu_custom_call": compiled.as_text().count("tpu_custom_call"),
+        "tpu_custom_call": text.count("tpu_custom_call"),
+        "cholesky_custom_call": (text.count('custom_call_target="Cholesky"')
+                                 + text.count("InvertDiagBlocksLowerTriangular")),
         "device_memory_bytes": int(
             mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes),
@@ -901,6 +918,12 @@ def child_als_full_width(params: dict) -> dict:
     config = dataclasses.replace(base, dtype="float32", buckets=2, solver="pallas")
     run_, _ = _fit_and_check(users[:cut], items[:cut], ratings[:cut], n_users,
                              n_items, config, mesh, tol=1e-4)
+    runs.append(run_)
+    # and at rank 128, bf16, "auto": above rank 32 the rows of every block
+    # take the blocked Cholesky solve (ops/linalg.py)
+    config = dataclasses.replace(base, rank=128, buckets=2)
+    run_, _ = _fit_and_check(users[:cut], items[:cut], ratings[:cut], n_users,
+                             n_items, config, mesh, tol=2e-2)
     runs.append(run_)
     stats = jax.devices()[0].memory_stats() or {}
     return {"device": _backend(), "users": n_users, "items": n_items,

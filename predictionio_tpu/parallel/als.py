@@ -43,7 +43,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from predictionio_tpu.ops.als_gram import gram_rhs
-from predictionio_tpu.ops.linalg import batched_spd_solve, solve_unrolls
+from predictionio_tpu.ops.linalg import (
+    BLOCKED_SOLVE_ROWS, LANES, batched_spd_solve, solve_gram_arrays, solve_path)
 from predictionio_tpu.ops.ragged import PaddedCSR, pack_padded_csr, round_up
 from predictionio_tpu.parallel.mesh import cached_by_mesh, one_step_in_flight
 from predictionio_tpu.utils.jax_compat import axis_size, shard_map
@@ -635,18 +636,13 @@ def resolve_solver(solver: str, platform: str) -> str:
     return "xla" if solver == "auto" else solver
 
 
-#: lanes of a TPU vector register row. The TPU compiler lays the einsum
-#: tail's gathered factors out one row a lane row, whatever the rank
-#: (``bf16[R*L,16]{1,0:T(8,128)(2,1)}`` at rank 16): a gather slot costs 128
-#: lanes x itemsize in HBM, 8 times the factors' own bytes at rank 16.
-_LANES = 128
-
 #: Most bytes one block may allocate on one device at a time under "auto":
 #: a quarter of a v5e's 16 GiB, the smallest HBM this runs on. Two things are
 #: held to it (``block_plan``): a block whose gathered intermediate alone is
 #: over it leaves the einsum tail for the fused kernel, and a block whose
-#: whole allocation (gathered rows, Grams, Cholesky factors) is over it is
-#: worked in row chunks, each under it. Compiled for a described v5e
+#: whole allocation (gathered rows, Grams, what the solve holds beside them)
+#: is over it is worked in row chunks, each under it. Compiled for a
+#: described v5e
 #: (``memory_analysis``, PR 25): the einsum program's temporaries are 1.07 to
 #: 1.27 times its largest block's intermediate (f32 explicit to bf16
 #: implicit; 2.68 GB for the 2.31 GB of the ML-20M cell's largest block,
@@ -664,19 +660,21 @@ EINSUM_GATHER_BUDGET_BYTES = 4 << 30
 
 def gathered_bytes(rows: int, pad_len: int, rank: int, itemsize: int) -> int:
     """HBM bytes of the einsum tail's ``[rows, pad_len, rank]`` gathered
-    intermediate as a TPU holds it: every gathered row padded to whole lane
-    rows. ``rows`` are one device's."""
-    return rows * pad_len * round_up(rank, _LANES) * itemsize
+    intermediate as a TPU holds it: the compiler lays the gathered factors
+    out one row a lane row, whatever the rank (``bf16[R*L,16]{1,0:T(8,128)
+    (2,1)}`` at rank 16), so a gather slot costs 128 lanes x itemsize, 8
+    times the factors' own bytes at rank 16. ``rows`` are one device's."""
+    return rows * pad_len * round_up(rank, LANES) * itemsize
 
 
-def normal_equation_bytes(rows: int, rank: int, unrolled: bool) -> int:
-    """HBM bytes of ``rows`` rows' normal equations: the ``[rows, K, K]``
-    float32 Gram and, where the solve is not the unrolled one
-    (``ops.linalg.solve_unrolls``), the Cholesky factor of the same size that
-    ``lax.linalg.cholesky`` writes beside it. 1 KiB a row at rank 16; 64 KiB
-    and 64 KiB again at rank 128, more than a gathered bf16 row wherever
-    ``pad_len`` < 256."""
-    return rows * rank * rank * 4 * (1 if unrolled else 2)
+def normal_equation_bytes(rows: int, rank: int, unroll: bool) -> int:
+    """HBM bytes of ``rows`` rows' normal equations at their peak: the
+    ``[rows, K, K]`` float32 Gram and what the solve holds beside it on the
+    path it takes for (rank, ``unroll``: a TPU mesh), as
+    ``ops.linalg.solve_gram_arrays`` counts it. 1 KiB a row and its copy,
+    both lane-padded, at rank 16; 64 KiB a row and as much again and a
+    quarter at rank 128."""
+    return int(rows * rank * rank * 4 * solve_gram_arrays(rank, unroll))
 
 
 def block_solver(solver: str, platform: str, rows: int, pad_len: int,
@@ -706,19 +704,24 @@ def block_plan(solver: str, platform: str, rows: int, pad_len: int,
 
     ``path`` is ``block_solver``'s. ``chunks`` counts what the block
     allocates on that path -- the gathered rows (the einsum tail only), the
-    float32 Grams and, where the rank leaves the unrolled solve, the Cholesky
-    factors -- and is the number of equal row chunks that brings one chunk's
-    share under ``EINSUM_GATHER_BUDGET_BYTES``; 1 is the block whole. Rows
-    are independent, so a chunk's rows come out as they would from the whole
-    block. A solver given by name is chunked by the same count: the name
-    picks the arithmetic, not what fits."""
+    float32 Grams and what the solve holds beside them
+    (``normal_equation_bytes``) -- and is the number of equal row chunks that
+    brings one chunk's share under ``EINSUM_GATHER_BUDGET_BYTES``, and, where
+    the rows take the blocked solve (a TPU mesh above rank 32), the rows a
+    device solves in one chunk under ``ops.linalg.BLOCKED_SOLVE_ROWS``; 1 is
+    the block whole. Rows are independent, so a chunk's rows come out as they
+    would from the whole block. A solver given by name is chunked by the same
+    count: the name picks the arithmetic, not what fits."""
+    unroll = platform == "tpu"
     path = block_solver(solver, platform, rows, pad_len, rank, itemsize)
-    allocated = normal_equation_bytes(
-        rows // model_shards, rank, solve_unrolls(rank, platform == "tpu")
-    )
+    solved = rows // model_shards
+    allocated = normal_equation_bytes(solved, rank, unroll)
     if path == "xla":
         allocated += gathered_bytes(rows, pad_len, rank, itemsize)
-    return path, max(1, -(-allocated // EINSUM_GATHER_BUDGET_BYTES))
+    chunks = -(-allocated // EINSUM_GATHER_BUDGET_BYTES)
+    if solve_path(rank, unroll) == "blocked":
+        chunks = max(chunks, -(-solved // BLOCKED_SOLVE_ROWS))
+    return path, max(1, chunks)
 
 
 def _in_row_chunks(step, chunks: int, slices: int = 1, sharded: bool = False):
@@ -771,13 +774,18 @@ def block_paths(data, config: ALSConfig, mesh) -> dict[str, int]:
     """How ``data``'s blocks (both sides; resident or streamed) are worked in
     the program built for (mesh, config): ``{"xla": n, "pallas": m}`` blocks
     on each half-step path, of which ``"chunked"`` in row chunks, the most
-    chunks of any under ``"max_chunks"`` (1: every block whole). The same
-    ``block_plan`` the program asks at trace time, on the same shapes."""
+    chunks of any under ``"max_chunks"`` (1: every block whole), and
+    ``"blocked_solve"`` blocks whose rows take the blocked Cholesky solve
+    (``ops.linalg.solve_path``: every block of a TPU mesh above rank 32, else
+    none). The same ``block_plan`` the program asks at trace time, on the
+    same shapes."""
     platform = mesh.devices.flat[0].platform
     d = mesh.shape["data"]
     m = mesh.shape.get("model", 1) if config.factor_sharding == "model" else 1
     itemsize = jnp.dtype(config.dtype).itemsize
-    paths = {"xla": 0, "pallas": 0, "chunked": 0, "max_chunks": 1}
+    paths = {"xla": 0, "pallas": 0, "chunked": 0, "max_chunks": 1,
+             "blocked_solve": 0}
+    blocked = solve_path(config.rank, platform == "tpu") == "blocked"
     for side in (data.by_row, data.by_col):
         specs = getattr(side, "specs", None)  # a streamed side's blocks
         if specs is not None:
@@ -791,6 +799,7 @@ def block_paths(data, config: ALSConfig, mesh) -> dict[str, int]:
             paths[path] += 1
             paths["chunked"] += chunks > 1
             paths["max_chunks"] = max(paths["max_chunks"], chunks)
+            paths["blocked_solve"] += blocked
     return paths
 
 
@@ -814,8 +823,8 @@ def _half_steps(mesh, solver: str, implicit: bool, rank: int,
     platform = mesh.devices.flat[0].platform
     # solve-path choice is per TARGET platform, not default backend: the
     # benchmark compiles a CPU mesh while a TPU backend is live (and vice
-    # versa), and the unrolled solver is ~5x faster on TPU / ~8x slower on
-    # CPU than LAPACK's batched Cholesky (ops.linalg.batched_spd_solve).
+    # versa), and the unrolled and blocked solves that win on a TPU lose to
+    # LAPACK's batched Cholesky on a CPU (ops.linalg.batched_spd_solve).
     unroll = platform == "tpu"
     interpret = not unroll
     model = factor_axis == "model"
@@ -1294,13 +1303,13 @@ def als_fit(
             logger.info(
                 "als_fit: platform=%s devices=%d mesh_data=%d mesh_model=%d"
                 " factor_sharding=%s solver=%s blocks_xla=%d blocks_pallas=%d"
-                " blocks_chunked=%d max_chunks=%d first_call_s=%.2f (trace +"
-                " compile, or cache load)",
+                " blocks_chunked=%d max_chunks=%d blocked_solve=%d"
+                " first_call_s=%.2f (trace + compile, or cache load)",
                 mesh.devices.flat[0].platform, mesh.devices.size,
                 mesh.shape["data"], mesh.shape.get("model", 1),
                 config.factor_sharding, config.solver, paths["xla"],
                 paths["pallas"], paths["chunked"], paths["max_chunks"],
-                time.perf_counter() - first_call_t0,
+                paths["blocked_solve"], time.perf_counter() - first_call_t0,
             )
         if telemetry is not None:
             # per-half-step resolution lives inside one jitted program;

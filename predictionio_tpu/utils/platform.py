@@ -178,11 +178,22 @@ def note_kernel(name: str, interpret: bool) -> None:
     _KERNELS[name] = "interpreted" if interpret else "compiled"
 
 
+#: blocked Cholesky solves (``ops/linalg.py``) traced by this process: one for
+#: each block of an ALS program above rank 32 on a TPU mesh, none elsewhere
+_SOLVES = {"blocked_solve": 0}
+
+
+def note_blocked_solve() -> None:
+    """Record that a program traced the blocked Cholesky solve."""
+    _SOLVES["blocked_solve"] += 1
+
+
 def device_report() -> dict:
-    """What this process runs on: the device as JAX reports it and the
-    Pallas kernels built so far. ``pio train`` prints it, the query server
-    serves it under ``GET /``, and ``chip_smoke.py`` takes its verdict from
-    it -- a run on the wrong platform cannot pass for a chip run."""
+    """What this process runs on: the device as JAX reports it, the Pallas
+    kernels built and the blocked solves traced so far. ``pio train`` prints
+    it, the query server serves it under ``GET /``, and ``chip_smoke.py``
+    takes its verdict from it -- a run on the wrong platform cannot pass for
+    a chip run."""
     import jax
 
     devices = jax.devices()
@@ -191,4 +202,5 @@ def device_report() -> dict:
         "kind": devices[0].device_kind,
         "count": len(devices),
         "kernels": dict(_KERNELS),
+        **_SOLVES,
     }

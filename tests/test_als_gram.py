@@ -245,7 +245,8 @@ class TestBlockRule:
         data = build_als_data(uu, ii, rr, n_u, n_i, cfg)
         n = len(data.by_row.blocks) + len(data.by_col.blocks)
         mesh = local_mesh(1, 1)
-        whole = {"chunked": 0, "max_chunks": 1}  # every block in one piece
+        whole = {"chunked": 0, "max_chunks": 1,  # every block in one piece
+                 "blocked_solve": 0}              # a CPU mesh solves by LAPACK
         assert block_paths(data, cfg, mesh) == {"xla": n, "pallas": 0, **whole}
         forced = ALSConfig(rank=6, buckets=2, solver="pallas")
         assert block_paths(data, forced, mesh) == {"xla": 0, "pallas": n, **whole}

@@ -169,17 +169,20 @@ RULE = {
     **{f"ml20m_r16_{rows}x{length}": ((rows, length, 16, 2, 1), ("xla", 1))
        for rows, length in [(35_312, 256), (22_872, 152), (28_696, 88), (51_632, 48),
                             (7_648, 256), (2_224, 144), (3_840, 64), (13_048, 16)]},
-    # the same blocks at rank 128: 64 KiB of Gram and 64 KiB of factor a row
-    "ml20m_r128_35312x256": ((35_312, 256, 128, 2, 1), ("xla", 2)),
-    "ml20m_r128_51632x48": ((51_632, 48, 128, 2, 1), ("xla", 2)),
-    # 125,000 rows of rank 128: 15.3 GiB of Grams and factors beside 0.7 GiB of rows
-    "r128_125000x24": ((125_000, 24, 128, 2, 1), ("xla", 4)),
-    # als-msd-r128.train-sharded's largest blocks, a data shard's rows, two model shards
-    "msd_users_231168x24": ((231_168, 24, 128, 2, 2), ("xla", 4)),
-    "msd_users_39680x256": ((39_680, 256, 128, 2, 2), ("xla", 2)),
-    "msd_songs_14512x128": ((14_512, 128, 128, 2, 2), ("xla", 1)),
-    # the unrolled solve (rank <= 32) writes no factor beside the Gram
-    "r32_unrolled": ((1_000_000, 8, 32, 4, 1), ("xla", 2)),
+    # the same blocks at rank 128: the rows take the blocked solve, at most
+    # 4,096 of them in a chunk (64 KiB of Gram a row: the bytes alone ask 2)
+    "ml20m_r128_35312x256": ((35_312, 256, 128, 2, 1), ("xla", 9)),
+    "ml20m_r128_51632x48": ((51_632, 48, 128, 2, 1), ("xla", 13)),
+    "r128_125000x24": ((125_000, 24, 128, 2, 1), ("xla", 31)),
+    # als-msd-r128.train-sharded's largest blocks, a data shard's rows, two
+    # model shards: each device solves half of them
+    "msd_users_231168x24": ((231_168, 24, 128, 2, 2), ("xla", 29)),
+    "msd_users_39680x256": ((39_680, 256, 128, 2, 2), ("xla", 5)),
+    "msd_songs_14512x128": ((14_512, 128, 128, 2, 2), ("xla", 2)),
+    # the unrolled solve (rank <= 32) holds a copy of the Gram with the rows
+    # on the lanes, and the chip pads a 32-wide row of either to 128 lanes;
+    # its rows are not capped
+    "r32_unrolled": ((1_000_000, 8, 32, 4, 1), ("xla", 7)),
     # the template's default ML-1M item block: the kernel, as before, whole
     "ml1m_template_default": ((3_712, 23_832, 16, 4, 1), ("pallas", 1)),
 }
@@ -192,15 +195,29 @@ def test_the_rule(case):
 
 
 def test_the_rule_counts_gathered_rows_grams_and_factors():
+    """... and, since PR 27, what each solve path holds beside the Gram
+    (``ops.linalg.solve_gram_arrays``, read from a compile for a described
+    v5e): the blocked solve as much again and a quarter, the unrolled one a
+    lane-padded copy, LAPACK's the factor. Rows on the blocked solve are cut
+    to ``ops.linalg.BLOCKED_SOLVE_ROWS`` a chunk as well."""
+    from predictionio_tpu.ops.linalg import BLOCKED_SOLVE_ROWS
+
     rows, pad_len = 125_000, 24
     gathered = als.gathered_bytes(rows, pad_len, 128, 2)
     assert gathered == rows * pad_len * 256
-    assert als.normal_equation_bytes(rows, 128, unrolled=False) == rows * 2 * 65_536
-    assert als.normal_equation_bytes(rows, 16, unrolled=True) == rows * 1_024
+    assert als.normal_equation_bytes(rows, 128, unroll=True) == int(rows * 2.25 * 65_536)
+    assert als.normal_equation_bytes(rows, 16, unroll=True) == int(rows * 1_024 * 1.35 * 8)
+    assert als.normal_equation_bytes(rows, 128, unroll=False) == rows * 2 * 65_536
+    # off the TPU the bytes decide: LAPACK's batches are not capped
     total = gathered + rows * 2 * 65_536
-    assert block_plan("auto", "tpu", rows, pad_len, 128, 2)[1] == -(-total // (4 * GIB))
-    # a name picks the arithmetic, not what fits; a CPU mesh has no unrolled solve
-    assert block_plan("pallas", "tpu", rows, pad_len, 128, 2) == ("pallas", 4)
+    assert block_plan("auto", "cpu", rows, pad_len, 128, 2)[1] == -(-total // (4 * GIB)) == 4
+    # on it, above rank 32, the rows a device solves in a chunk are
+    assert block_plan("auto", "tpu", rows, pad_len, 128, 2)[1] == -(-rows // BLOCKED_SOLVE_ROWS)
+    assert block_plan("auto", "tpu", rows, pad_len, 128, 2, model_shards=2)[1] == 16
+    # ... unless the bytes ask for more: 4,096 rows of 4,096 slots are 4 GiB gathered
+    assert block_plan("auto", "tpu", 4_096, 4_096, 128, 2) == ("xla", 2)
+    # a name picks the arithmetic, not what fits
+    assert block_plan("pallas", "tpu", rows, pad_len, 128, 2) == ("pallas", 31)
     assert block_plan("xla", "cpu", 35_312, 256, 16, 2) == ("xla", 1)
 
 

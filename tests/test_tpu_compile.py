@@ -222,13 +222,18 @@ def test_model_sharded_als_compiles_on_four_chips(topo, no_persistent_cache, sol
     assert ("tpu_custom_call" in text) == (solver == "pallas")
 
 
-def _one_chip_iteration(topo, config, user_blocks, item_blocks):
-    """``make_iteration`` for one described chip, compiled at the given
-    block shapes (users and items sized by their blocks' rows)."""
+def _one_chip_iteration(topo, config, user_blocks, item_blocks, mesh_shape=(1, 1)):
+    """``make_iteration`` for one described chip (or ``mesh_shape`` = data x
+    model of them, tables sharded as ``config.factor_sharding`` says),
+    compiled at the given block shapes (users and items sized by their
+    blocks' rows)."""
     from predictionio_tpu.parallel.als import make_iteration
 
-    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    d, m = mesh_shape
+    mesh = Mesh(np.array(topo.devices[:d * m]).reshape(d, m), ("data", "model"))
     row, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    table = NamedSharding(
+        mesh, P("model" if config.factor_sharding == "model" else "data"))
     dtype = jnp.dtype(config.dtype)
 
     def blocks(shapes):
@@ -240,13 +245,43 @@ def _one_chip_iteration(topo, config, user_blocks, item_blocks):
 
     def factors(shapes):
         return jax.ShapeDtypeStruct(
-            (sum(rows for rows, _ in shapes), config.rank), dtype, sharding=row)
+            (sum(rows for rows, _ in shapes), config.rank), dtype, sharding=table)
 
     scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=rep)
     return make_iteration(mesh, config).lower(
         blocks(user_blocks), blocks(item_blocks), factors(user_blocks),
         factors(item_blocks), scalar, scalar,
     ).compile()
+
+
+def test_the_rank_128_solve_is_blocked_and_holds_what_the_rule_counts(
+    topo, no_persistent_cache
+):
+    """``als-msd-r128.train-sharded``'s largest user block (79,360 x 256,
+    implicit, rank 128, bf16 tables over ``model``) on data=2 x model=2:
+    neither of ``lax.linalg.cholesky`` + ``cho_solve``'s custom calls is left
+    in the program, and its temporaries stay within what ``block_plan``
+    counted for one of the block's 5 chunks (3,968 rows a device to solve:
+    its gathered rows, its Grams, what the blocked solve holds beside them),
+    and a third more: the exchange over ``model`` holds the gathered rows
+    twice (PERF.md section 7)."""
+    from predictionio_tpu.parallel import als
+
+    config = als.ALSConfig(rank=128, implicit=True, alpha=40.0, reg=0.1,
+                           dtype="bfloat16", factor_sharding="model", solver="auto")
+    rows, length = 79_360, 256
+    path, chunks = als.block_plan("auto", "tpu", rows // 2, length, 128, 2, 2)
+    counted = (als.gathered_bytes(rows // 2, length, 128, 2)
+               + als.normal_equation_bytes(rows // 4, 128, unroll=True)) / chunks
+    assert (path, chunks) == ("xla", 5)
+    compiled = _one_chip_iteration(
+        topo, config, [(rows, length)], [(1_024, 16)], mesh_shape=(2, 2))
+    text = compiled.as_text()
+    assert "Cholesky" not in text and "InvertDiagBlocksLowerTriangular" not in text
+    assert "all-to-all" in text
+    temp_size = compiled.memory_analysis().temp_size_in_bytes
+    print(f"msd r128 largest user block: temp_size {temp_size} bytes, counted {counted:.0f}")
+    assert 0.8 * counted <= temp_size < 1.35 * counted < als.EINSUM_GATHER_BUDGET_BYTES
 
 
 def test_auto_takes_the_einsum_tail_for_every_block_of_the_train_cell(
