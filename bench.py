@@ -229,10 +229,6 @@ def run_als_streamed(platform: str, config, n_edges, n_users, n_items,
         float(model.user_factors[0, 0])  # host sync (host model already)
         sec = (time.time() - t0) / iters_to_time
         itemsize = 2 if config.dtype == "bfloat16" else 4
-        from predictionio_tpu.ops.als_gram import half_step_bytes
-        from predictionio_tpu.parallel.als import resolve_solver
-
-        fused = resolve_solver(config.solver, platform) == "pallas"
         specs = [
             s for side in (data.by_row, data.by_col) for s in side.specs
         ]
@@ -242,10 +238,8 @@ def run_als_streamed(platform: str, config, n_edges, n_users, n_items,
                 _half_step_flops(s.rows, s.pad_len, config.rank)
                 for s in specs
             ),
-            "bytes_per_iter_model": sum(
-                half_step_bytes(s.rows, s.pad_len, config.rank, itemsize,
-                                fused)
-                for s in specs
+            "bytes_per_iter_model": als_bytes_per_iteration(
+                data, config.rank, itemsize
             ),
             "build_seconds": round(build_s, 2),
             "compile_and_first_iter_s": round(compile_s, 2),
@@ -293,7 +287,7 @@ def als_flops_per_iteration(data, rank: int) -> float:
     )
 
 
-def als_bytes_per_iteration(data, rank: int, itemsize: int, fused: bool) -> float:
+def als_bytes_per_iteration(data, rank: int, itemsize: int) -> float:
     """HBM bytes one full ALS iteration moves through its half-step tails:
     the half-step is gather/bandwidth-bound, so achieved GB/s against this
     model -- NOT the MFU number, which an einsum-heavy but bandwidth-
@@ -302,7 +296,7 @@ def als_bytes_per_iteration(data, rank: int, itemsize: int, fused: bool) -> floa
     telemetry journal (``parallel.als.modeled_bytes_per_iteration``)."""
     from predictionio_tpu.parallel.als import modeled_bytes_per_iteration
 
-    return modeled_bytes_per_iteration(data, rank, itemsize, fused)
+    return modeled_bytes_per_iteration(data, rank, itemsize)
 
 
 def full_scale_flops_estimate(scale: float) -> float:
@@ -554,72 +548,14 @@ def secondary_main(result_path: str) -> None:
             "config": "#8 train_data_eps (120k events, sqlite, 2-pass read)",
         }
 
-    def als_half_step_gbps():
-        """#9: achieved HBM GB/s of the ALS half-step tail, fused Pallas
-        kernel vs unfused XLA einsum path, against the bytes-moved model
-        (``ops.als_gram.half_step_bytes``). On TPU both paths are timed at
-        a reduced ml20m shape (same generator as the primary metric); the
-        CPU child reports the einsum path's GB/s plus the model's byte
-        ratio only -- the interpret-mode kernel is a correctness vehicle,
-        and timing it would benchmark the Pallas interpreter, not the
-        half-step."""
-        import dataclasses
-
-        from predictionio_tpu.parallel.als import ALSConfig, build_als_data
-
-        scale = 4.0 if tpu else 400.0
-        n_users = int(N_USERS_FULL / scale ** 0.5)
-        n_items = int(N_ITEMS_FULL / scale ** 0.5)
-        n_edges = int(N_EDGES_FULL / scale)
-        users, items, ratings = make_dataset(n_edges, n_users, n_items)
-        config = ALSConfig(
-            rank=RANK, reg=0.05, max_len=256,
-            dtype="bfloat16" if tpu else "float32",
-            buckets=4 if tpu else 1,
-        )
-        data = build_als_data(users, items, ratings, n_users, n_items, config)
-        itemsize = 2 if tpu else 4
-        fused_b = als_bytes_per_iteration(data, RANK, itemsize, fused=True)
-        unfused_b = als_bytes_per_iteration(data, RANK, itemsize, fused=False)
-        res = {
-            "edges": n_edges,
-            "bytes_per_iter_fused": fused_b,
-            "bytes_per_iter_unfused": unfused_b,
-            "model_bytes_ratio": round(unfused_b / fused_b, 2),
-            "config": "#9 als_half_step_gbps (bytes model: ops.als_gram)",
-        }
-        if not tpu:
-            sec = run_als(
-                "cpu", data, dataclasses.replace(config, solver="xla"), 2
-            )
-            res["sec_per_iter_xla"] = round(sec, 5)
-            res["gbps_xla"] = round(unfused_b / sec / 1e9, 2)
-            res["fused"] = (
-                "skipped on CPU (interpret-mode kernel times the "
-                "interpreter, not the half-step)"
-            )
-            return res
-        for solver in ("xla", "pallas"):
-            sec = run_als(
-                platform, data,
-                dataclasses.replace(config, solver=solver), 10,
-            )
-            bytes_iter = fused_b if solver == "pallas" else unfused_b
-            res[f"sec_per_iter_{solver}"] = round(sec, 5)
-            res[f"gbps_{solver}"] = round(bytes_iter / sec / 1e9, 2)
-        res["fused_speedup"] = round(
-            res["sec_per_iter_xla"] / res["sec_per_iter_pallas"], 3
-        )
-        return res
-
     def mips_topk():
         """#15: two-stage quantized MIPS retrieval (ops/mips) vs the full
         scan over a 1M-item synthetic catalog. TPU: times
         RetrievalIndex.search end-to-end (shortlisted items/sec +
         achieved GB/s against the packed-table bytes model). CPU child:
         the kernel only runs under the Pallas interpreter, and timing it
-        at catalog scale would measure the interpreter (the
-        als_half_step_gbps precedent) -- so recall@10 is measured through
+        at catalog scale would measure the interpreter -- so recall@10 is
+        measured through
         the numpy REFERENCE of the same quantized stage-1 math
         (ops.mips.reference_shortlist) and the bytes-model ratio is
         reported; the kernel-timing rerun rides the ROADMAP
@@ -1035,7 +971,6 @@ def secondary_main(result_path: str) -> None:
     phase("serving_qps", serving_qps)
     phase("ingest_eps", ingest_eps)
     phase("train_data_eps", train_data_eps)
-    phase("als_half_step_gbps", als_half_step_gbps)
     phase("mips_topk", mips_topk)
     phase("trace_overhead_pct", trace_overhead_pct)
     phase("serving_qps_multiproc", serving_qps_multiproc)
@@ -1109,14 +1044,7 @@ def child_main(mode: str, result_path: str) -> None:
         dtype="bfloat16" if mode == "tpu" else "float32",
         buckets=int(os.environ.get("PIO_BENCH_BUCKETS", "4"))
         if mode == "tpu" else 1,
-        # "auto": the XLA einsums (at this shape every block fits the
-        # chip, so none takes the fused Pallas kernel);
-        # PIO_BENCH_ALS_SOLVER pins either path for A/B runs
-        solver=os.environ.get("PIO_BENCH_ALS_SOLVER", "auto"),
     )
-    from predictionio_tpu.parallel.als import resolve_solver
-
-    solver_used = resolve_solver(config.solver, platform)
     itemsize = 2 if config.dtype == "bfloat16" else 4
     # fast TPU iterations need more reps per timed block so the one
     # scalar-fetch sync amortizes out; CPU iterations are seconds each
@@ -1134,16 +1062,13 @@ def child_main(mode: str, result_path: str) -> None:
         data = build_als_data(users, items, ratings, n_users, n_items, config)
         sec = run_als(platform, data, config, iters_to_time)
         flops = als_flops_per_iteration(data, config.rank)
-        bytes_iter = als_bytes_per_iteration(
-            data, config.rank, itemsize, fused=solver_used == "pallas"
-        )
+        bytes_iter = als_bytes_per_iteration(data, config.rank, itemsize)
     out = {
         "mode": mode,
         "scale": scale,
         "edges": n_edges,
         "sec_per_iter": sec,
         "flops_per_iter": flops,
-        "solver": solver_used,
         "bytes_per_iter": bytes_iter,
         **extras,
         "run_record": EVIDENCE["runs"].get(platform),
@@ -1439,7 +1364,6 @@ def _run_phases(bench: _Bench) -> None:
             "mfu_vs_bf16_peak": round(achieved / peaks["bf16_flops_per_s"], 4),
         }
         if full.get("bytes_per_iter"):
-            EVIDENCE["mfu"]["als_solver"] = full.get("solver")
             EVIDENCE["mfu"]["hbm_bytes_per_iteration"] = full["bytes_per_iter"]
             EVIDENCE["mfu"]["achieved_hbm_gbps"] = round(
                 full["bytes_per_iter"] / tpu_sec / 1e9, 2
@@ -1448,7 +1372,6 @@ def _run_phases(bench: _Bench) -> None:
         bench.edges = full["edges"]
         gbps_tail = (
             f"; hbm ~{EVIDENCE['mfu']['achieved_hbm_gbps']:.0f} GB/s"
-            f" ({full.get('solver')} half-step)"
             if "achieved_hbm_gbps" in EVIDENCE["mfu"]
             else ""
         )

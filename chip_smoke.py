@@ -301,8 +301,8 @@ class Smoke:
             # how the program's blocks were worked (parallel/als.py:block_paths)
             said = dict(re.findall(r"(\w+)=(\S+)", als.group(1)))
             facts.update(
-                solver=said["solver"], first_call_s=float(said["first_call_s"]),
-                **{k: int(said[k]) for k in ("blocks_xla", "blocks_pallas", "blocked_solve")})
+                first_call_s=float(said["first_call_s"]),
+                **{k: int(said[k]) for k in ("blocks", "blocks_chunked", "max_chunks", "blocked_solve")})
         timings = re.search(r"stage timings: (.*)$", text, re.M)
         if timings:
             facts["stage_timings"] = timings.group(1).strip()
@@ -363,33 +363,29 @@ class Smoke:
             raise PhaseFailed(f"the second process missed the cache: entries {cold['entries_after']} -> {warm['entries_after']}")
         if self.device["platform"] == "tpu" and missed and not warm["compile_s"] < cold["compile_s"]:
             raise PhaseFailed(f"warm compile {warm['compile_s']}s not under cold {cold['compile_s']}s")
-        self.kernel_where_asked("compile_cache", cold)
+        self.einsums_and_blocked_solve("compile_cache", cold)
         self.line(
             "compile_cache", t0, cache_dir=cold["cache_dir"],
             cold_compile_s=cold["compile_s"], warm_compile_s=warm["compile_s"],
             cold_was_a_miss=missed, entries_before=cold["entries_before"],
-            entries_after=warm["entries_after"], solver=cold["solver"],
-            blocks_xla=cold["blocks_xla"], blocks_pallas=cold["blocks_pallas"],
-            blocked_solve=cold["blocked_solve"],
+            entries_after=warm["entries_after"], blocks=cold["blocks"],
+            max_chunks=cold["max_chunks"], blocked_solve=cold["blocked_solve"],
             tpu_custom_call=cold["tpu_custom_call"],
             device_memory_bytes=cold["device_memory_bytes"],
         )
 
-    def kernel_where_asked(self, phase: str, compiled: dict) -> None:
-        """On the chip the compiled iteration holds one ``tpu_custom_call`` for
-        each block the solver asked to run the fused kernel (every block when
-        "pallas" was given by name, under "auto" the blocks too large for the
-        einsum tail) and none for the others; and above rank 32 every block's
+    def einsums_and_blocked_solve(self, phase: str, compiled: dict) -> None:
+        """On the chip the compiled ALS iteration holds no ``tpu_custom_call``
+        (every block takes the einsum tail); and above rank 32 every block's
         rows take the blocked solve, which leaves none of ``lax.linalg.
         cholesky`` + ``cho_solve``'s custom calls in the program."""
         if self.device["platform"] != "tpu":
             return
-        if compiled["tpu_custom_call"] != compiled["blocks_pallas"]:
+        if compiled["tpu_custom_call"]:
             raise PhaseFailed(
-                f"{phase}: solver {compiled['solver']} puts {compiled['blocks_pallas']}"
-                f" block(s) on the fused kernel, the compiled iteration holds"
+                f"{phase}: the compiled iteration holds"
                 f" {compiled['tpu_custom_call']} tpu_custom_call: {compiled}")
-        blocks = compiled["blocks_xla"] + compiled["blocks_pallas"]
+        blocks = compiled["blocks"]
         if compiled["blocked_solve"] != (blocks if compiled["rank"] > 32 else 0):
             raise PhaseFailed(
                 f"{phase}: rank {compiled['rank']}, {blocks} block(s), of which"
@@ -433,12 +429,11 @@ class Smoke:
                 f" {check['rmse_global_mean']} (needs 10% under)"
             )
         # the template's defaults (one bucket, no cap, f32) make an item block
-        # whose gathered rows cannot fit the chip: at full size this train is
-        # where "auto" keeps the fused kernel
+        # whose gathered rows cannot fit the chip whole: at full size this
+        # train is where a block is worked in row chunks (``max_chunks``)
         if (self.device["platform"] == "tpu" and not self.rehearsal
-                and (not facts.get("blocks_pallas")
-                     or facts["device"]["kernels"].get("als_gram_rhs") != "compiled")):
-            raise PhaseFailed(f"train_als: no block of the template-default train ran the fused kernel: {facts}")
+                and not facts.get("blocks_chunked")):
+            raise PhaseFailed(f"train_als: no block of the template-default train was worked in row chunks: {facts}")
         self.line("train_als", t0, **facts, **check)
 
     def phase_als_full_width(self) -> None:
@@ -450,7 +445,7 @@ class Smoke:
         for run_ in res["runs"]:
             if not run_["agrees"]:
                 raise PhaseFailed(f"als_full_width: chip and NumPy float64 half-step disagree: {run_}")
-            self.kernel_where_asked("als_full_width", run_)
+            self.einsums_and_blocked_solve("als_full_width", run_)
         self.line("als_full_width", t0, **res)
 
     def phase_serve_als(self) -> None:
@@ -706,8 +701,8 @@ def _compile_iteration(data, config, mesh) -> dict:
     paths = block_paths(data, config, mesh)
     text = compiled.as_text()
     return {
-        "solver": config.solver, "rank": config.rank,
-        "blocks_xla": paths["xla"], "blocks_pallas": paths["pallas"],
+        "rank": config.rank, "blocks": paths["blocks"],
+        "max_chunks": paths["max_chunks"],
         "blocked_solve": paths["blocked_solve"],
         "compile_s": round(compile_s, 2),
         "tpu_custom_call": text.count("tpu_custom_call"),
@@ -893,7 +888,7 @@ def child_als_full_width(params: dict) -> dict:
     users, items, ratings = bench.make_dataset(n_edges, n_users, n_items, seed=SEED)
     mesh = local_mesh(1, 1)
     base = ALSConfig(rank=16, iterations=3, reg=0.05, max_len=256,
-                     dtype="bfloat16", buckets=4, solver="auto")
+                     dtype="bfloat16", buckets=4)
     run_, data = _fit_and_check(users, items, ratings, n_users, n_items,
                                 base, mesh, tol=2e-2)
     # warm-up, then 3 timed iterations twice, each block ending in a device
@@ -910,17 +905,11 @@ def child_als_full_width(params: dict) -> dict:
     run_["timed_blocks_agree"] = record["valid"]
     runs = [run_]
     del data
-    # once more with f32 factors at a 2M-edge sample of the same stream, on
-    # the fused kernel by name: "auto" above left every block to the einsum
-    # tail, and the kernel that stays in the tree meets the chip here
+    # once more at rank 128 on a 2M-edge sample of the same stream: above
+    # rank 32 the rows of every block take the blocked Cholesky solve
+    # (ops/linalg.py). 2 buckets: the compile of 8 bucket programs is the
+    # long part here
     cut = n_edges // 10
-    # (2 buckets: the compile of 8 bucket programs is the long part here)
-    config = dataclasses.replace(base, dtype="float32", buckets=2, solver="pallas")
-    run_, _ = _fit_and_check(users[:cut], items[:cut], ratings[:cut], n_users,
-                             n_items, config, mesh, tol=1e-4)
-    runs.append(run_)
-    # and at rank 128, bf16, "auto": above rank 32 the rows of every block
-    # take the blocked Cholesky solve (ops/linalg.py)
     config = dataclasses.replace(base, rank=128, buckets=2)
     run_, _ = _fit_and_check(users[:cut], items[:cut], ratings[:cut], n_users,
                              n_items, config, mesh, tol=2e-2)

@@ -34,8 +34,8 @@ What it tracks, package-wide:
 Values (mesh axes, spec axes) propagate interprocedurally: when a call
 passes a known mesh or spec into a resolved callee, the callee's
 parameter binds the value WITH the hand-off hop recorded, so a
-``P("model")`` minted in ``parallel/als.py`` and consumed three frames
-down in ``ops/als_gram.py`` is joined against the mesh it actually lands
+``P("model")`` minted in one module and consumed three frames down in
+another is joined against the mesh it actually lands
 on, and the finding renders the mint->consume chain.
 
 Execution contexts propagate the same way: each ``shard_map`` site seeds

@@ -232,12 +232,11 @@ class RuleS003(PackageRule):
     path) is required; single-device jit of a kernel stays silent, and
     reaching the kernel through a shard_map body is the blessed route.
 
-    Incident: the "pallas_call is opaque to GSPMD" class --
-    ``ops/als_gram``'s fused kernel gave wrong sums the moment it was
-    jitted under the 2x2 mesh without shard_map routing;
-    ``parallel/als.py`` now wraps BOTH factor layouts in an explicit
-    ``shard_map`` (``_sharded_block_body`` / the replicated-path
-    step of ``_half_steps``), which is this rule's negative fixture."""
+    Incident: the "pallas_call is opaque to GSPMD" class -- a fused ALS
+    Gram kernel (removed in PR 28) gave wrong sums the moment it was
+    jitted under the 2x2 mesh without shard_map routing. The package's
+    remaining ``pallas_call``s (``ops/mips``, ``ops/flash_attention``,
+    ``models/ncf/kernel``) are what the rule guards now."""
 
     rule_id = "S003"
     severity = "error"
@@ -248,10 +247,10 @@ class RuleS003(PackageRule):
             fi = flow.graph.functions.get(fkey)
             if fi is None:
                 continue
-            # per-context, not per-kernel: ops/als_gram's kernel is
-            # reached BOTH through the blessed ALS shard_map route and
-            # directly from the fold-in solver's jit -- a shard_map
-            # path elsewhere must not amnesty an unwrapped jit path
+            # per-context, not per-kernel: a kernel can be reached BOTH
+            # through a shard_map route and directly from another jit --
+            # a shard_map path elsewhere must not amnesty an unwrapped
+            # jit path
             jit_ctxs = flow.contexts_of(fkey, "jit")
             for ctx in jit_ctxs:
                 mesh = self._multi_axis_evidence(flow, fkey, ctx)

@@ -104,24 +104,6 @@ def warn_misplaced_packing_params(algo_params, template: str) -> None:
         )
 
 
-def resolve_solver_override(config: ALSConfig, ctx) -> ALSConfig:
-    """Apply the run-scoped ``pio.als_solver`` conf (``pio train
-    --als-solver``) over the engine.json ``alsSolver`` param.
-
-    The CLI flag is an operator override -- benchmarking the fused Pallas
-    half-step against the XLA einsum path, or pinning "xla" if a jax/Mosaic
-    upgrade regresses the kernel -- so it wins over the variant file.
-    ``make_iteration`` validates the value.
-    """
-    import dataclasses
-
-    solver = getattr(ctx, "runtime_conf", None) or {}
-    solver = solver.get("pio.als_solver")
-    if not solver:
-        return config
-    return dataclasses.replace(config, solver=str(solver))
-
-
 def resolve_factor_sharding(config: ALSConfig, mesh) -> ALSConfig:
     """Resolve ``factor_sharding="auto"`` against the actual mesh.
 
@@ -148,7 +130,7 @@ def build_seen(users: np.ndarray, items: np.ndarray) -> dict[int, set[int]]:
     """user index -> set of interacted item indices (serving-time filter).
 
     Sorted-split construction: one stable argsort + one ``np.unique``
-    boundary scan, so interpreter time is O(distinct users), not O(events)
+    boundary scan, so Python time is O(distinct users), not O(events)
     -- this runs on EVERY model build and the per-event Python loop it
     replaces was a measurable slice of large builds. The dict-of-sets
     return type is the serving contract (``_seen_indices`` and fold-in
@@ -508,7 +490,6 @@ def fit_with_checkpoint(
     workflow captures -- the cheap always-parseable view vs the deep one.
     """
     config = resolve_factor_sharding(config, mesh)
-    config = resolve_solver_override(config, ctx)
     telemetry = _build_telemetry(ctx, als_data, config, mesh, name)
     checkpoint = ctx.checkpoint_manager(name) if interval > 0 else None
     init, start_iteration, callback = None, 0, None
@@ -595,8 +576,7 @@ def _layout_attrs(paths: dict, config: ALSConfig, mesh) -> dict:
         "mesh_data": mesh.shape["data"],
         "mesh_model": mesh.shape.get("model", 1),
         "factor_sharding": config.factor_sharding,
-        "blocks_xla": paths["xla"],
-        "blocks_pallas": paths["pallas"],
+        "blocks": paths["blocks"],
         "blocks_chunked": paths["chunked"],
         "max_chunks": paths["max_chunks"],
         "blocked_solve": paths["blocked_solve"],
@@ -627,17 +607,12 @@ def _build_telemetry(ctx, als_data, config: ALSConfig, mesh, name: str):
         return TrainTelemetry(
             os.path.join(str(profile_dir), f"{name}-telemetry.jsonl"),
             edges=real_edges(als_data),
-            # the model counts padded slots through the XLA tail's gathered
-            # intermediate; it does not describe the fused kernel, so the
-            # journal writes no achieved_gbps where a block runs it
-            modeled_bytes_per_iter=None if paths["pallas"] else
-            modeled_bytes_per_iteration(
-                als_data, config.rank, itemsize, fused=False
+            modeled_bytes_per_iter=modeled_bytes_per_iteration(
+                als_data, config.rank, itemsize
             ),
             meta={
                 "name": name,
                 "rank": config.rank,
-                "solver": config.solver,
                 **_layout_attrs(paths, config, mesh),
                 "platform": mesh.devices.flat[0].platform,
                 "dtype": config.dtype,

@@ -336,9 +336,9 @@ class ECommAlgorithm(TPUAlgorithm):
             dtype=p.get_or("factorDtype", "float32"),
             # "auto": ALX model-sharded factors on a model-axis mesh
             factor_sharding=p.get_or("factorSharding", "auto"),
-            # "auto": XLA einsums, and on a TPU the fused Pallas gather->Gram
-            # kernel for just the blocks whose gathered rows cannot fit the
-            # chip; `pio train --als-solver` overrides
+            # a vestige (``ALSConfig.solver``): an engine.json that still
+            # says "pallas" fails loudly; goes with benchmarks/drivers/
+            # als_train.py:92 and als_train_sharded.py:125 (ROADMAP.md)
             solver=p.get_or("alsSolver", "auto"),
         )
 

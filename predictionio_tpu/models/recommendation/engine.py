@@ -364,9 +364,9 @@ class ALSAlgorithm(TPUAlgorithm):
             # "auto": ALX model-sharded factors whenever pio.mesh_shape
             # configures a model axis > 1 (resolve_factor_sharding)
             factor_sharding=p.get_or("factorSharding", "auto"),
-            # "auto": XLA einsums, and on a TPU the fused Pallas gather->Gram
-            # kernel for just the blocks whose gathered rows cannot fit the
-            # chip; `pio train --als-solver` overrides
+            # a vestige (``ALSConfig.solver``): an engine.json that still
+            # says "pallas" fails loudly; goes with benchmarks/drivers/
+            # als_train.py:92 and als_train_sharded.py:125 (ROADMAP.md)
             solver=p.get_or("alsSolver", "auto"),
         )
 

@@ -5,11 +5,10 @@ the primary training metric; the ``jax.profiler`` trace gives the deep
 view but needs tensorboard/xprof to open. This journal is the cheap,
 always-parseable companion: one JSON line per training step with wall
 time, edges/sec, and the achieved HBM GB/s implied by the bytes-moved
-model (``ops.als_gram.half_step_bytes``; not written for the fused Pallas
-path, which that model does not describe), plus the process's count of
-compilations (``pio_jit_compiles_total``) so a shape-instability regression
-(recompiling every step) is visible as a climbing integer instead of a
-mysteriously slow run.
+model (``parallel.als.modeled_bytes_per_iteration``), plus the process's
+count of compilations (``pio_jit_compiles_total``) so a shape-instability
+regression (recompiling every step) is visible as a climbing integer
+instead of a mysteriously slow run.
 
 Lines are flushed as written: a crashed or preempted run keeps every
 completed step's record.

@@ -1,8 +1,8 @@
 """Continuous learning: WAL tail -> snapshot refresh -> fold-in -> hot swap.
 
 The batch stack can ingest durably (``data/ingest``), replay training data
-at memmap speed (``data/snapshot``), solve ALS half-steps with fused
-kernels (``ops/als_gram``), and serve through a supervised process tier
+at memmap speed (``data/snapshot``), solve ALS half-steps on the device
+(``parallel/als``), and serve through a supervised process tier
 (``serving/``) -- but an event ingested now is invisible to queries until
 someone reruns ``pio train`` and redeploys. This package closes that loop
 as ``pio retrain --follow``:

@@ -4,8 +4,8 @@ ALX (arxiv 2112.02194) makes the point that the per-row ALS solve is
 cheap: one K x K normal-equation system per row. Between full retrains,
 that is exactly enough to keep a deployed factor model fresh -- a user who
 just rated something gets their row re-solved against the CURRENT item
-factors (one fused gather->Gram half-step over a delta CSR block, the
-``ops/als_gram`` kernel), while every untouched row keeps its trained
+factors (one half-step over a delta CSR block, the tail ``parallel.als``
+trains with), while every untouched row keeps its trained
 factors bit-for-bit. New users append rows; new items append zero factors
 (they score 0 until the next full retrain -- which the staleness budget
 triggers once item-vocab growth makes zero rows matter).
@@ -155,41 +155,23 @@ def _device_factors(item_factors: np.ndarray):
 
 
 @functools.lru_cache(maxsize=16)
-def _build_solver(solver: str, implicit: bool, rank: int, platform: str,
-                  chunks: int = 1):
-    """One jitted delta half-step per (solver, mode, rank, platform, row
-    chunks) -- repeated fold-ins reuse the compiled program (shapes are
-    padded to a pow2 ladder below for the same reason)."""
+def _build_solver(implicit: bool, rank: int, platform: str, chunks: int = 1):
+    """One jitted delta half-step per (mode, rank, platform, row chunks) --
+    repeated fold-ins reuse the compiled program (shapes are padded to a
+    pow2 ladder below for the same reason)."""
     import jax
-    import jax.numpy as jnp
 
-    from predictionio_tpu.ops.als_gram import gram_rhs
     from predictionio_tpu.parallel.als import (
         _append_zero_row,
         _factors_yty,
-        _finish_explicit,
-        _finish_implicit,
         _half_step_explicit,
         _half_step_implicit,
         _in_row_chunks,
     )
 
     unroll = platform == "tpu"
-    interpret = not unroll
 
     def block(indices, values, n_obs, full, yty, reg, alpha):
-        if solver == "pallas":
-            gram, rhs = gram_rhs(
-                indices.astype(jnp.int32), values, full, alpha,
-                implicit=implicit, interpret=interpret,
-            )
-            if implicit:
-                return _finish_implicit(
-                    gram, rhs, yty, reg, rank, unroll, full.dtype
-                )
-            return _finish_explicit(
-                gram, rhs, n_obs, reg, rank, unroll, full.dtype
-            )
         if implicit:
             return _half_step_implicit(
                 indices, values, n_obs, full, yty, reg, alpha, rank, unroll
@@ -222,10 +204,8 @@ def fold_in_users(
     local row order (``rows`` in ``[0, num_rows)``) and MODEL item space
     (``cols`` indexing ``item_factors``). Returns ``[num_rows, K]`` f32 --
     the exact ridge/implicit solution per row, via the same half-step tail
-    ``als_fit`` runs (``config.solver`` resolves "auto" like training, from
-    the packed block's shape, ``parallel.als.block_plan``: the XLA einsums,
-    or on a TPU the fused Pallas kernel for a block whose gathered rows would
-    not fit the chip; in row chunks where its normal equations would not).
+    ``als_fit`` runs, in row chunks where the packed block is too large to
+    work whole (``parallel.als.block_plan``, from its shape, like training).
 
     Shapes are padded to a pow2 ladder (rows AND history length) so a
     long-running loop compiles a handful of programs, not one per delta.
@@ -254,10 +234,8 @@ def fold_in_users(
     )
     rank = item_factors.shape[1]
     # the table ships as float32 (_device_factors)
-    solver, chunks = block_plan(
-        config.solver, platform, *csr.indices.shape, rank, 4
-    )
-    step = _build_solver(solver, bool(config.implicit), rank, platform, chunks)
+    chunks = block_plan(platform, *csr.indices.shape, rank, 4)
+    step = _build_solver(bool(config.implicit), rank, platform, chunks)
     out = step(
         csr.indices,
         csr.values,

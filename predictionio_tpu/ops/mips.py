@@ -7,7 +7,7 @@ below production scale. This module is the scale tentpole that replaces
 the scan with the ALX-style device-resident layout (arxiv 2112.02194):
 
 - **Stage 1** (``mips_block_topk``, a Pallas kernel in the
-  ``ops/als_gram`` / ``ops/flash_attention`` house style): scan the int8
+  ``ops/flash_attention`` house style): scan the int8
   block-quantized item table (``ops/quantize``) tile by tile, fusing the
   dequantize, the query dot-product, and a per-tile top-R selection. The
   ``[B, items]`` score matrix lives only as one ``[BB, block_items]``
@@ -33,7 +33,7 @@ bounded only by quantization reorderings inside the
 against -- measured >= 0.99 recall@10 at 1M items with the defaults
 (bench ``mips_topk``).
 
-Layout/VMEM budget (mirrors ``ops/als_gram``):
+Layout/VMEM budget:
 
 - Query block ``[BB, K]`` f32 and item tile ``[BI, K]`` int8 are
   exact-dim blocks (K is far below a lane and pads internally); the
@@ -45,7 +45,7 @@ Layout/VMEM budget (mirrors ``ops/als_gram``):
   stream tiles ahead of the VPU selection.
 - The top-R selection is R unrolled max/first-match-argmin passes over
   the VMEM score tile (pure VPU ops: Mosaic has no in-kernel sort);
-  R is static so the loop unrolls like ``als_gram``'s chunk loop.
+  R is static so the loop unrolls.
 - On CPU meshes the kernel runs in interpret mode (the
   ``ops/flash_attention`` precedent), so tier-1 CPU tests exercise this
   exact kernel code.
@@ -391,8 +391,8 @@ def reference_shortlist(
     quantized stage-1 arithmetic and merge the kernel fuses, as plain
     host math. This is the recall oracle -- the bench's off-hardware
     recall@k measurement runs through it (timing the interpret-mode
-    kernel at catalog scale would benchmark the Pallas interpreter, the
-    ``als_half_step_gbps`` precedent) and the slow tier-2 test checks the
+    kernel at catalog scale would benchmark the Pallas interpreter) and
+    the slow tier-2 test checks the
     1M-item recall contract against it. Returns ``[B, shortlist]``
     ascending candidate catalog indices (padding slots carry
     ``padded_items`` sentinels past tiny catalogs)."""

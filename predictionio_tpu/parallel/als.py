@@ -42,7 +42,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
-from predictionio_tpu.ops.als_gram import gram_rhs
 from predictionio_tpu.ops.linalg import (
     BLOCKED_SOLVE_ROWS, LANES, batched_spd_solve, solve_gram_arrays, solve_path)
 from predictionio_tpu.ops.ragged import PaddedCSR, pack_padded_csr, round_up
@@ -60,9 +59,7 @@ logger = logging.getLogger("pio.als")
 #: iteration)``, ``shard_map``) may sit between these.
 SCOPE_HALF_STEP = {"user": "als.user_half_step", "item": "als.item_half_step"}
 SCOPE_BUCKET = "bucket{}"
-#: the gather (with its model-axis exchange) and the two einsums, or the fused
-#: kernel; for a bucket on the kernel also its lane-padded f32 copy of the
-#: gather table, which ``gram_rhs`` makes for every such bucket
+#: the gather (with its model-axis exchange) and the two einsums
 SCOPE_GRAM = "gram"
 #: ridge, ``ops.linalg.batched_spd_solve``, the cast back to the factor dtype
 SCOPE_SOLVE = "solve"
@@ -71,9 +68,8 @@ SCOPE_SOLVE = "solve"
 SCOPE_ASSEMBLE = "assemble"
 #: nested where the work is, under ``gram`` or ``assemble``: what crosses the
 #: chips in the model layout -- the exchange over ``model`` that completes a
-#: bucket's gathered rows (or, on the kernel, its partial Grams), and the
-#: re-layout of the solved rows from ``P(("data", "model"))`` back to
-#: ``P("model")``
+#: bucket's gathered rows, and the re-layout of the solved rows from
+#: ``P(("data", "model"))`` back to ``P("model")``
 SCOPE_EXCHANGE = "exchange"
 #: nested under ``assemble``: the side's global K x K Gram (implicit only)
 SCOPE_YTY = "yty"
@@ -98,14 +94,11 @@ class ALSConfig:
     #: memory drops to total_slots/model_axis rows (see docs/parallelism.md
     #: for the max-catalog math). Requires build_als_data(model_shards=m).
     factor_sharding: str = "replicated"
-    #: half-step tail implementation: "xla" is the einsum path, which
-    #: writes the [rows, L, K] gathered intermediate to HBM; "pallas" runs
-    #: the fused gather->Gram kernel (``ops.als_gram``), which never does
-    #: and pays one row DMA a gather slot for it (14x slower on a v5e where
-    #: both fit). Either name forces every block. "auto" = the einsum path,
-    #: and on a TPU mesh the kernel for just the blocks whose intermediate
-    #: cannot fit the chip (``block_solver``). On CPU meshes the kernel runs
-    #: interpreted -- a correctness vehicle, not a fast path.
+    #: a vestige: nothing in the package branches on it. There is one
+    #: half-step, the einsum tail (``resolve_solver`` refuses any other name).
+    #: Kept because benchmarks/drivers/als_train.py:92 calls
+    #: ``resolve_solver(config.solver, platform)`` and als_train_sharded.py:125
+    #: prints ``config.solver``; goes with those two reads (ROADMAP.md).
     solver: str = "auto"
 
 
@@ -393,9 +386,7 @@ def _factor_precision(dtype):
 
 
 def _finish_explicit(gram, rhs, n_obs, reg, rank, unroll, out_dtype):
-    """ALS-WR ridge + batched solve over precomputed Gram/rhs -- the tail
-    both the XLA einsum path and the fused Pallas kernel share bit-for-bit
-    (so solver parity reduces to Gram/rhs parity)."""
+    """ALS-WR ridge + batched solve over precomputed Gram/rhs."""
     # MLlib-style weighted regularization: lambda * n_obs (ALS-WR); constant
     # lambda would also be defensible -- n_obs matches the reference template
     with jax.named_scope(SCOPE_SOLVE):
@@ -405,7 +396,7 @@ def _finish_explicit(gram, rhs, n_obs, reg, rank, unroll, out_dtype):
 
 
 def _finish_implicit(gram_fix, rhs, yty, reg, rank, unroll, out_dtype):
-    """YtY + correction + constant ridge + solve (shared tail, see above).
+    """YtY + correction + constant ridge + solve.
 
     ``gram_fix`` holds only the per-row observed-entry corrections
     sum_obs (c-1) y y^T; the replicated global Gram lands here."""
@@ -505,30 +496,8 @@ def _half_step_implicit(indices, values, n_obs, factors, yty, reg, alpha,
     )
 
 
-def _half_step_pallas(idx, values, n_obs, factors, yty, reg, alpha,
-                      implicit, rank, unroll, interpret):
-    """Replicated-factor half-step through the fused gather->Gram kernel.
-
-    Runs inside shard_map over the mesh (a pallas_call is opaque to GSPMD,
-    so the data-axis row split is explicit here): each device streams its
-    CSR row shard through ``ops.als_gram.gram_rhs`` against the replicated
-    factor table and solves its rows locally -- no collectives; the
-    [rows, L, K] gathered intermediate never exists in HBM.
-    """
-    with jax.named_scope(SCOPE_GRAM):
-        gram, rhs = gram_rhs(
-            idx, values, factors, alpha, implicit=implicit, interpret=interpret
-        )
-    if implicit:
-        return _finish_implicit(
-            gram, rhs, yty, reg, rank, unroll, factors.dtype
-        )
-    return _finish_explicit(gram, rhs, n_obs, reg, rank, unroll, factors.dtype)
-
-
 def _sharded_block_body(idx, values, n_obs, opp_local, yty, reg, alpha,
-                        implicit, rank, unroll, solver="xla",
-                        interpret=False):
+                        implicit, rank, unroll):
     """Per-device half-step for one bucket with MODEL-SHARDED factors.
 
     Runs inside shard_map over the full ("data", "model") mesh. Each
@@ -550,14 +519,6 @@ def _sharded_block_body(idx, values, n_obs, opp_local, yty, reg, alpha,
     3. each device solves its rows' normal equations -- compute scales
        with the full d*m device count, not just d.
 
-    solver="pallas" replaces steps 1-2's [rows, L, K] exchange with the
-    fused kernel: out-of-shard indices remap to a LOCAL trailing zero row
-    (the same padding invariant, applied to the shard), each device
-    accumulates its partial [rows, K, K]/[rows, K] Gram/rhs on-chip, and
-    the psum_scatter runs over those -- (K^2 + K)/(L * K) of the XLA
-    path's ICI traffic (~15x less at L=256, K=16) and no HBM gathered
-    intermediate.
-
     Output rows per device: the model-axis slice of the local data shard,
     i.e. global layout P(("data", "model")).
     """
@@ -567,31 +528,6 @@ def _sharded_block_body(idx, values, n_obs, opp_local, yty, reg, alpha,
         s_m = opp_local.shape[0]
         loc = idx - mi * s_m
         rows = idx.shape[0] // m
-    if solver == "pallas":
-        with jax.named_scope(SCOPE_GRAM):
-            hit = (loc >= 0) & (loc < s_m)
-            safe = jnp.where(hit, loc, s_m).astype(jnp.int32)
-            gram, rhs = gram_rhs(
-                safe, values, _append_zero_row(opp_local), alpha,
-                implicit=implicit, interpret=interpret,
-            )
-            with jax.named_scope(SCOPE_EXCHANGE):
-                gram = jax.lax.psum_scatter(
-                    gram, "model", scatter_dimension=0, tiled=True
-                )
-                rhs = jax.lax.psum_scatter(
-                    rhs, "model", scatter_dimension=0, tiled=True
-                )
-        if implicit:
-            return _finish_implicit(
-                gram, rhs, yty, reg, rank, unroll, opp_local.dtype
-            )
-        with jax.named_scope(SCOPE_SOLVE):
-            n_s = jax.lax.dynamic_slice_in_dim(n_obs, mi * rows, rows, 0)
-        return _finish_explicit(
-            gram, rhs, n_s, reg, rank, unroll, opp_local.dtype
-        )
-    with jax.named_scope(SCOPE_GRAM):
         hit = (loc >= 0) & (loc < s_m)
         g = opp_local[jnp.clip(loc, 0, s_m - 1)]
         g = g * hit[..., None].astype(g.dtype)
@@ -621,40 +557,38 @@ def _append_zero_row(factors: jnp.ndarray) -> jnp.ndarray:
 
 
 def resolve_solver(solver: str, platform: str) -> str:
-    """Resolve ``ALSConfig.solver`` against a target platform: the path a
-    block takes unless ``block_solver`` finds it too large for the einsum
-    tail. "auto" is the einsum tail ("xla") everywhere: on CPU the fused
-    kernel would only run interpreted, and on a v5e it is 14x slower than
-    the einsums wherever both fit (PERF.md, PR 25). A solver given by name
-    stands, for every block."""
-    del platform  # the platform matters only to a block too large: block_solver
-    if solver not in ("auto", "xla", "pallas"):
+    """Validate ``ALSConfig.solver``, the vestige of a selector: "auto" and
+    "xla" are the einsum tail, the one half-step there is; any other name
+    raises. Kept with the field for benchmarks/drivers/als_train.py:92, which
+    calls it (ROADMAP.md)."""
+    del platform
+    if solver not in ("auto", "xla"):
         raise ValueError(
-            "ALSConfig.solver must be 'auto', 'xla' or 'pallas', "
-            f"got {solver!r}"
+            f"ALSConfig.solver={solver!r}: the fused Gram kernel and the "
+            "alsSolver selector were removed in PR 28; every block takes the "
+            "einsum tail, in row chunks where it must (leave it at 'auto')"
         )
-    return "xla" if solver == "auto" else solver
+    return "xla"
 
 
-#: Most bytes one block may allocate on one device at a time under "auto":
-#: a quarter of a v5e's 16 GiB, the smallest HBM this runs on. Two things are
-#: held to it (``block_plan``): a block whose gathered intermediate alone is
-#: over it leaves the einsum tail for the fused kernel, and a block whose
-#: whole allocation (gathered rows, Grams, what the solve holds beside them)
-#: is over it is worked in row chunks, each under it. Compiled for a
-#: described v5e
-#: (``memory_analysis``, PR 25): the einsum program's temporaries are 1.07 to
-#: 1.27 times its largest block's intermediate (f32 explicit to bf16
-#: implicit; 2.68 GB for the 2.31 GB of the ML-20M cell's largest block,
-#: [35312, 256] bf16), its block streams another 1/32, and the compiler
-#: refuses the program only past the whole chip (12 GiB of intermediate
-#: still compiles) -- so a quarter leaves the rest of the chip to the state,
-#: to the other programs a process has loaded and to what the compile of one
-#: program does not count. The recommendation template's default packing (one
-#: bucket, no cap, f32) makes a [3712, 23832] item block at MovieLens-1M:
-#: 45.3 GB of intermediate, which the compiler refuses outright
-#: (RESOURCE_EXHAUSTED); with the fused kernel on that block the program's
-#: temporaries are 0.75 GB.
+#: Most bytes one row chunk of a block may allocate on one device
+#: (``block_plan``): its lane-padded gathered rows, its float32 Grams and what
+#: the solve holds beside them. A block over it is worked in equal row chunks,
+#: each under it. A quarter of a v5e's 16 GiB, the smallest HBM this runs on.
+#: Compiled for a described v5e (``memory_analysis``, PR 25): the einsum
+#: program's temporaries are 1.07 to 1.27 times its largest block's gathered
+#: rows (f32 explicit to bf16 implicit; 2.68 GB for the 2.31 GB of the ML-20M
+#: cell's largest block, [35312, 256] bf16), its block streams another 1/32,
+#: and the compiler refuses the program only past the whole chip (12 GiB of
+#: gathered rows still compiles) -- so a quarter leaves the rest of the chip
+#: to the state, to the other programs a process has loaded and to what the
+#: compile of one program does not count. The recommendation template's
+#: default packing (one bucket, no cap, f32) makes a [3712, 23832] item block
+#: at MovieLens-1M: 45.3 GB of gathered rows, which the compiler refuses
+#: whole (RESOURCE_EXHAUSTED) and the rule works in 11 chunks of 344 rows.
+#: The floor of the rule: a chunk is at least 8 rows, so the budget holds for
+#: rows of up to 1,048,576 slots at f32 and rank <= 128 (nine times MSD's
+#: most-played song uncapped, 110,479); there is no code for more.
 EINSUM_GATHER_BUDGET_BYTES = 4 << 30
 
 
@@ -677,51 +611,31 @@ def normal_equation_bytes(rows: int, rank: int, unroll: bool) -> int:
     return int(rows * rank * rank * 4 * solve_gram_arrays(rank, unroll))
 
 
-def block_solver(solver: str, platform: str, rows: int, pad_len: int,
-                 rank: int, itemsize: int) -> str:
-    """The half-step path of ONE block: the first half of ``block_plan``.
-    "xla" / "pallas" by name force every block. "auto" takes the einsum
-    tail, except on a TPU for a block whose gathered intermediate is over
-    ``EINSUM_GATHER_BUDGET_BYTES``: that one takes the fused kernel, which
-    never materializes it."""
-    too_large = (
-        gathered_bytes(rows, pad_len, rank, itemsize)
-        > EINSUM_GATHER_BUDGET_BYTES
-    )
-    if solver == "auto" and platform == "tpu" and too_large:
-        return "pallas"
-    return resolve_solver(solver, platform)
+def block_plan(platform: str, rows: int, pad_len: int, rank: int,
+               itemsize: int, model_shards: int = 1) -> int:
+    """In how many equal row chunks ONE block is worked (1: whole) -- the one
+    statement of the rule, asked at trace time by everything that has a
+    block's static shape: ``rows`` on one device of the data axis,
+    ``pad_len``, the factors' ``rank`` and ``itemsize``, and ``model_shards``
+    (the model layout solves ``rows / model_shards`` of them on each device; 1
+    otherwise).
 
-
-def block_plan(solver: str, platform: str, rows: int, pad_len: int,
-               rank: int, itemsize: int, model_shards: int = 1
-               ) -> tuple[str, int]:
-    """How ONE block is worked, ``(path, chunks)`` -- the one statement of
-    the rule, asked at trace time by everything that has a block's static
-    shape: ``rows`` on one device of the data axis, ``pad_len``, the factors'
-    ``rank`` and ``itemsize``, and ``model_shards`` (the model layout solves
-    ``rows / model_shards`` of them on each device; 1 otherwise).
-
-    ``path`` is ``block_solver``'s. ``chunks`` counts what the block
-    allocates on that path -- the gathered rows (the einsum tail only), the
-    float32 Grams and what the solve holds beside them
-    (``normal_equation_bytes``) -- and is the number of equal row chunks that
+    It counts what the block allocates -- the gathered rows
+    (``gathered_bytes``), the float32 Grams and what the solve holds beside
+    them (``normal_equation_bytes``) -- and answers the number of chunks that
     brings one chunk's share under ``EINSUM_GATHER_BUDGET_BYTES``, and, where
     the rows take the blocked solve (a TPU mesh above rank 32), the rows a
-    device solves in one chunk under ``ops.linalg.BLOCKED_SOLVE_ROWS``; 1 is
-    the block whole. Rows are independent, so a chunk's rows come out as they
-    would from the whole block. A solver given by name is chunked by the same
-    count: the name picks the arithmetic, not what fits."""
+    device solves in one chunk under ``ops.linalg.BLOCKED_SOLVE_ROWS``. Rows
+    are independent, so a chunk's rows come out as they would from the whole
+    block."""
     unroll = platform == "tpu"
-    path = block_solver(solver, platform, rows, pad_len, rank, itemsize)
     solved = rows // model_shards
-    allocated = normal_equation_bytes(solved, rank, unroll)
-    if path == "xla":
-        allocated += gathered_bytes(rows, pad_len, rank, itemsize)
+    allocated = (gathered_bytes(rows, pad_len, rank, itemsize)
+                 + normal_equation_bytes(solved, rank, unroll))
     chunks = -(-allocated // EINSUM_GATHER_BUDGET_BYTES)
     if solve_path(rank, unroll) == "blocked":
         chunks = max(chunks, -(-solved // BLOCKED_SOLVE_ROWS))
-    return path, max(1, chunks)
+    return max(1, chunks)
 
 
 def _in_row_chunks(step, chunks: int, slices: int = 1, sharded: bool = False):
@@ -761,30 +675,19 @@ def _in_row_chunks(step, chunks: int, slices: int = 1, sharded: bool = False):
     return chunked
 
 
-def _program_solver(solver: str, platform: str) -> str:
-    """What keys a built program: "auto" stays "auto" only on a TPU mesh,
-    where the rule can differ from block to block; elsewhere it is its
-    resolution, so "auto" and "xla" share one compiled program."""
-    if solver == "auto" and platform == "tpu":
-        return "auto"
-    return resolve_solver(solver, platform)
-
-
 def block_paths(data, config: ALSConfig, mesh) -> dict[str, int]:
     """How ``data``'s blocks (both sides; resident or streamed) are worked in
-    the program built for (mesh, config): ``{"xla": n, "pallas": m}`` blocks
-    on each half-step path, of which ``"chunked"`` in row chunks, the most
-    chunks of any under ``"max_chunks"`` (1: every block whole), and
-    ``"blocked_solve"`` blocks whose rows take the blocked Cholesky solve
-    (``ops.linalg.solve_path``: every block of a TPU mesh above rank 32, else
-    none). The same ``block_plan`` the program asks at trace time, on the
-    same shapes."""
+    the program built for (mesh, config): ``"blocks"`` of them, of which
+    ``"chunked"`` in row chunks, the most chunks of any under
+    ``"max_chunks"`` (1: every block whole), and ``"blocked_solve"`` blocks
+    whose rows take the blocked Cholesky solve (``ops.linalg.solve_path``:
+    every block of a TPU mesh above rank 32, else none). The same
+    ``block_plan`` the program asks at trace time, on the same shapes."""
     platform = mesh.devices.flat[0].platform
     d = mesh.shape["data"]
     m = mesh.shape.get("model", 1) if config.factor_sharding == "model" else 1
     itemsize = jnp.dtype(config.dtype).itemsize
-    paths = {"xla": 0, "pallas": 0, "chunked": 0, "max_chunks": 1,
-             "blocked_solve": 0}
+    paths = {"blocks": 0, "chunked": 0, "max_chunks": 1, "blocked_solve": 0}
     blocked = solve_path(config.rank, platform == "tpu") == "blocked"
     for side in (data.by_row, data.by_col):
         specs = getattr(side, "specs", None)  # a streamed side's blocks
@@ -794,30 +697,27 @@ def block_paths(data, config: ALSConfig, mesh) -> dict[str, int]:
             rows = side.global_rows or [b.indices.shape[0] for b in side.blocks]
             shapes = [(r, b.indices.shape[1]) for r, b in zip(rows, side.blocks)]
         for rows_b, pad_len in shapes:
-            path, chunks = block_plan(config.solver, platform, rows_b // d,
-                                      pad_len, config.rank, itemsize, m)
-            paths[path] += 1
+            chunks = block_plan(platform, rows_b // d, pad_len, config.rank,
+                                itemsize, m)
+            paths["blocks"] += 1
             paths["chunked"] += chunks > 1
             paths["max_chunks"] = max(paths["max_chunks"], chunks)
             paths["blocked_solve"] += blocked
     return paths
 
 
-def _half_steps(mesh, solver: str, implicit: bool, rank: int,
-                factor_axis: str):
-    """One bucket's half-step for (mesh, solver, factor layout), chosen for
-    each block as the program that holds it is traced.
+def _half_steps(mesh, implicit: bool, rank: int, factor_axis: str):
+    """One bucket's half-step for (mesh, factor layout), chosen for each
+    block as the program that holds it is traced.
 
     Returns ``pick(idx, factors) -> step``, with ``step(idx, values, n_obs,
-    factors, yty, reg, alpha) -> rows``. ``solver`` is "xla" or "pallas" for
-    every block, or "auto" (a TPU mesh: ``_program_solver``); ``block_plan``
-    decides path and row chunks from the block's shape on one device (rows
-    split over the data axis in both layouts); a path no block takes is
-    never traced. The einsum tail with replicated factors, whole, is left to
-    GSPMD; the fused kernel is opaque to it, the model-sharded body exchanges
-    over ``model`` and a chunked block loops over its device's own rows, so
-    those go through an explicit shard_map. The explicit einsum tail drops
-    ``yty`` and ``alpha`` (a dummy and a scalar).
+    factors, yty, reg, alpha) -> rows``. ``block_plan`` decides the row chunks
+    from the block's shape on one device (rows split over the data axis in
+    both layouts). With replicated factors a block worked whole is left to
+    GSPMD; the model-sharded body exchanges over ``model`` and a chunked block
+    loops over its device's own rows, so those go through an explicit
+    shard_map. The explicit tail drops ``yty`` and ``alpha`` (a dummy and a
+    scalar).
     """
     P = PartitionSpec
     platform = mesh.devices.flat[0].platform
@@ -826,7 +726,6 @@ def _half_steps(mesh, solver: str, implicit: bool, rank: int,
     # versa), and the unrolled and blocked solves that win on a TPU lose to
     # LAPACK's batched Cholesky on a CPU (ops.linalg.batched_spd_solve).
     unroll = platform == "tpu"
-    interpret = not unroll
     model = factor_axis == "model"
     slices = mesh.shape["model"] if model else 1
 
@@ -838,21 +737,14 @@ def _half_steps(mesh, solver: str, implicit: bool, rank: int,
         return _half_step_explicit(idx, val, n_obs, table, reg, rank, unroll)
 
     @functools.cache
-    def build(path: str, chunks: int):
-        if not model and path == "xla" and chunks == 1:
+    def build(chunks: int):
+        if not model and chunks == 1:
             return einsum_step  # left to GSPMD
+        body = einsum_step
         if model:
             body = functools.partial(
-                _sharded_block_body, implicit=implicit, rank=rank,
-                unroll=unroll, solver=path, interpret=interpret,
+                _sharded_block_body, implicit=implicit, rank=rank, unroll=unroll
             )
-        elif path == "pallas":
-            body = functools.partial(
-                _half_step_pallas, implicit=implicit, rank=rank,
-                unroll=unroll, interpret=interpret,
-            )
-        else:
-            body = einsum_step
         if chunks > 1:
             body = _in_row_chunks(body, chunks, slices, sharded=model)
         return shard_map(
@@ -861,15 +753,13 @@ def _half_steps(mesh, solver: str, implicit: bool, rank: int,
             in_specs=(P("data", None), P("data", None), P("data"),
                       P("model", None) if model else P(), P(), P(), P()),
             out_specs=P(("data", "model") if model else "data", None),
-            # the pallas body has no replication/vma rule; the model
-            # layout's xla body keeps the checker on
-            check_vma=model and path != "pallas",
+            check_vma=model,
         )
 
     def pick(idx, factors):
-        return build(*block_plan(
-            solver, platform, idx.shape[0] // mesh.shape["data"],
-            idx.shape[1], rank, factors.dtype.itemsize, slices,
+        return build(block_plan(
+            platform, idx.shape[0] // mesh.shape["data"], idx.shape[1], rank,
+            factors.dtype.itemsize, slices,
         ))
 
     return pick
@@ -889,19 +779,15 @@ def make_iteration(mesh, config: ALSConfig):
             "ALSConfig.factor_sharding must be 'replicated' or 'model', "
             f"got {config.factor_sharding!r}"
         )
-    # "auto" is decided block by block as the program is traced
-    # (block_solver, from each block's static shape); a solver given by name
-    # forces every block
-    platform = mesh.devices.flat[0].platform
+    resolve_solver(config.solver, mesh.devices.flat[0].platform)
     return _build_iteration(
-        mesh, config.rank, config.implicit, config.factor_sharding,
-        _program_solver(config.solver, platform),
+        mesh, config.rank, config.implicit, config.factor_sharding
     )
 
 
 @cached_by_mesh(maxsize=32)
 def _build_iteration(mesh, rank: int, implicit: bool,
-                     factor_axis: str = "replicated", solver: str = "xla"):
+                     factor_axis: str = "replicated"):
     """Build the jitted full ALS iteration (both half-steps fused).
 
     CSR rows (every bucket) shard over the 'data' mesh axis. Factor
@@ -919,15 +805,10 @@ def _build_iteration(mesh, rank: int, implicit: bool,
       what lifts the catalog-size ceiling from one device's HBM to the
       model axis's aggregate (docs/parallelism.md has the sizing math).
 
-    ``solver`` ("xla" or "pallas" for every block, or "auto" on a TPU mesh:
-    ``block_solver`` then picks for each block from its static shape as the
-    program is traced) is the half-step tail: the einsum path GSPMD
-    partitions on its own; the fused Pallas kernel (``ops.als_gram``) is
-    opaque to GSPMD, so both factor layouts route it through an explicit
-    shard_map (interpret mode on CPU meshes, the ``ops/flash_attention``
-    precedent -- tier-1 CPU tests run the same kernel code). Implicit
-    mode's ``yty`` is computed ONCE per half-step here (bucket-invariant)
-    and fed to every bucket's solve.
+    Each bucket's half-step is the einsum tail, whole or in row chunks as
+    ``block_plan`` says from its static shape as the program is traced
+    (``_half_steps``). Implicit mode's ``yty`` is computed ONCE per half-step
+    here (bucket-invariant) and fed to every bucket's solve.
 
     Factor buffers are donated: each iteration updates in place instead
     of reallocating.
@@ -943,7 +824,7 @@ def _build_iteration(mesh, rank: int, implicit: bool,
     row = NamedSharding(mesh, P("data"))
     rep = NamedSharding(mesh, P())
 
-    pick = _half_steps(mesh, solver, implicit, rank, factor_axis)
+    pick = _half_steps(mesh, implicit, rank, factor_axis)
 
     def side_yty(opp_real):
         """Global factor Gram of one side (implicit mode), hoisted out of
@@ -1107,21 +988,31 @@ def device_put_blocks(side: BucketedCSR, put) -> tuple:
     )
 
 
-def modeled_bytes_per_iteration(
-    data: ALSData, rank: int, itemsize: int, fused: bool
-) -> float:
-    """HBM bytes one full ALS iteration moves through its half-step tails
-    (``ops.als_gram.half_step_bytes`` summed over both sides' buckets).
-    The half-step is bandwidth-bound, so achieved GB/s against this model
-    is the training-efficiency axis -- the number the ``--profile``
-    telemetry journal and the bench secondary both report."""
-    from predictionio_tpu.ops.als_gram import half_step_bytes
+def modeled_bytes_per_iteration(data, rank: int, itemsize: int) -> float:
+    """HBM bytes one full ALS iteration moves through its half-step tails,
+    summed over both sides' buckets (resident or streamed). The half-step is
+    bandwidth-bound, so achieved GB/s against this model is the
+    training-efficiency axis the ``--profile`` telemetry journal reports.
 
-    return sum(
-        half_step_bytes(*block.indices.shape, rank, itemsize, fused)
-        for side in (data.by_row, data.by_col)
-        for block in side.blocks
-    )
+    A [rows, L] block: indices (i32) and values (f32) read once; Gram and rhs
+    (f32) written once; the gather's random read of the factor table
+    (rows*L*K*itemsize in expectation; the table's cold first touch is not
+    modeled per block), the gathered [rows, L, K] intermediate written to HBM
+    once and read back by the Gram and rhs einsums: 4 gather-sized passes."""
+    total = 0.0
+    for side in (data.by_row, data.by_col):
+        specs = getattr(side, "specs", None)  # a streamed side's blocks
+        if specs is not None:
+            shapes = [(s.rows, s.pad_len) for s in specs]
+        else:
+            shapes = [b.indices.shape for b in side.blocks]
+        for rows, pad_len in shapes:
+            total += (
+                rows * pad_len * (4 + 4)             # indices + values
+                + rows * (rank * rank + rank) * 4    # gram + rhs, f32
+                + 4 * rows * pad_len * rank * itemsize
+            )
+    return total
 
 
 def real_edges(data: ALSData) -> int:
@@ -1302,13 +1193,13 @@ def als_fit(
             paths = block_paths(data, config, mesh)
             logger.info(
                 "als_fit: platform=%s devices=%d mesh_data=%d mesh_model=%d"
-                " factor_sharding=%s solver=%s blocks_xla=%d blocks_pallas=%d"
+                " factor_sharding=%s blocks=%d"
                 " blocks_chunked=%d max_chunks=%d blocked_solve=%d"
                 " first_call_s=%.2f (trace + compile, or cache load)",
                 mesh.devices.flat[0].platform, mesh.devices.size,
                 mesh.shape["data"], mesh.shape.get("model", 1),
-                config.factor_sharding, config.solver, paths["xla"],
-                paths["pallas"], paths["chunked"], paths["max_chunks"],
+                config.factor_sharding, paths["blocks"],
+                paths["chunked"], paths["max_chunks"],
                 paths["blocked_solve"], time.perf_counter() - first_call_t0,
             )
         if telemetry is not None:
@@ -1373,14 +1264,13 @@ class _StreamPrograms:
     block updates are exact, not approximate.
     """
 
-    def __init__(self, mesh, rank: int, implicit: bool, factor_axis: str,
-                 solver: str):
+    def __init__(self, mesh, rank: int, implicit: bool, factor_axis: str):
         self.implicit = implicit
         self.factor_axis = factor_axis
         P = PartitionSpec
         row = NamedSharding(mesh, P("data"))
         rep = NamedSharding(mesh, P())
-        pick = _half_steps(mesh, solver, implicit, rank, factor_axis)
+        pick = _half_steps(mesh, implicit, rank, factor_axis)
 
         def side_yty(opp):
             if implicit:
@@ -1447,8 +1337,8 @@ class _StreamPrograms:
 
 @cached_by_mesh(maxsize=32)
 def _build_stream_programs(mesh, rank: int, implicit: bool,
-                           factor_axis: str, solver: str) -> _StreamPrograms:
-    return _StreamPrograms(mesh, rank, implicit, factor_axis, solver)
+                           factor_axis: str) -> _StreamPrograms:
+    return _StreamPrograms(mesh, rank, implicit, factor_axis)
 
 
 def als_fit_streamed(
@@ -1476,8 +1366,8 @@ def als_fit_streamed(
     edge ceiling from "fits in RAM twice" to "fits on disk".
 
     Bit-identical to ``als_fit`` over ``build_als_data`` at equal shapes
-    (same plans, same per-row packing, same kernels, same update order);
-    the parity tests in ``tests/test_als_stream.py`` pin all solver x
+    (same plans, same per-row packing, same half-step, same update order);
+    the parity tests in ``tests/test_als_stream.py`` pin all whole/chunked x
     mode x dtype x sharding combinations.
 
     ``device_budget_bytes`` > 0 pins streamed blocks device-resident (in
@@ -1521,7 +1411,7 @@ def als_fit_streamed(
             "local devices); multi-host training uses the sharded-reader "
             "resident path"
         )
-    solver = _program_solver(config.solver, mesh.devices.flat[0].platform)
+    resolve_solver(config.solver, mesh.devices.flat[0].platform)
     dtype = jnp.dtype(config.dtype)
     implicit = bool(config.implicit)
     stats = stats if stats is not None else StreamStats()
@@ -1573,7 +1463,7 @@ def als_fit_streamed(
     alpha = put_global(np.float32(config.alpha), rep)
 
     programs = _build_stream_programs(
-        mesh, config.rank, implicit, config.factor_sharding, solver
+        mesh, config.rank, implicit, config.factor_sharding
     )
     accounting = FeedAccounting()
     pinned: dict = {}

@@ -89,7 +89,7 @@ def _config(rank: int, iterations: int, implicit: bool, buckets: int,
 
     return ALSConfig(
         rank=rank, iterations=iterations, reg=0.05, alpha=10.0,
-        implicit=implicit, max_len=max_len, buckets=buckets, solver="auto",
+        implicit=implicit, max_len=max_len, buckets=buckets,
     )
 
 

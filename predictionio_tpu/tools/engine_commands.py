@@ -42,16 +42,6 @@ def register(sub: argparse._SubParsersAction) -> None:
         help="snapshot root (default $PIO_FS_BASEDIR/snapshots)",
     )
     train.add_argument(
-        "--als-solver",
-        choices=("auto", "xla", "pallas"),
-        default=None,
-        help="ALS half-step tail: 'xla' = einsum path, 'pallas' = fused"
-        " gather->Gram TPU kernel (no [rows, L, K] HBM intermediate), either"
-        " for every block; default auto (xla, and on a TPU pallas for just"
-        " the blocks whose gathered rows cannot fit the chip). Overrides"
-        " the engine.json alsSolver param for this run",
-    )
-    train.add_argument(
         "--als-feed",
         choices=("resident", "streamed"),
         default=None,
@@ -371,8 +361,6 @@ def cmd_train(args: argparse.Namespace) -> int:
     if args.snapshot_dir:
         variant.runtime_conf["pio.snapshot_dir"] = args.snapshot_dir
         os.environ["PIO_SNAPSHOT_DIR"] = args.snapshot_dir
-    if args.als_solver:
-        variant.runtime_conf["pio.als_solver"] = args.als_solver
     if args.als_feed:
         variant.runtime_conf["pio.als_feed"] = args.als_feed
     params = WorkflowParams(

@@ -125,3 +125,30 @@ def storage_env(tmp_path, monkeypatch):
     storage_registry.reset()
     yield storage_registry
     storage_registry.reset()
+
+
+@pytest.fixture
+def a_small_als_budget(monkeypatch):
+    """``parallel.als.EINSUM_GATHER_BUDGET_BYTES`` at 64 KiB: every ALS block
+    of a test's size is worked in row chunks. The rule is asked as a program
+    is traced, so built programs are forgotten on the way in and out."""
+    from predictionio_tpu.parallel import als
+
+    def forget():
+        als._build_iteration.cache_clear()
+        als._build_stream_programs.cache_clear()
+
+    forget()
+    monkeypatch.setattr(als, "EINSUM_GATHER_BUDGET_BYTES", 1 << 16)
+    yield
+    forget()
+
+
+@pytest.fixture(params=["whole", "chunked"])
+def worked(request):
+    """ALS blocks worked whole (the budget as it is) or in row chunks
+    (``a_small_als_budget``); a test may name one with
+    ``parametrize("worked", [...], indirect=True)``."""
+    if request.param == "chunked":
+        request.getfixturevalue("a_small_als_budget")
+    return request.param

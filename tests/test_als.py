@@ -308,10 +308,11 @@ class TestImplicitALS:
 
 
 class TestDeviceScopes:
-    """``jax.named_scope`` inside the iteration: names only, same program."""
+    """``jax.named_scope`` inside the iteration: names only, same program,
+    whether a block is worked whole or in row chunks."""
 
     @staticmethod
-    def _program_and_args(synthetic, solver, sharding, implicit, build=None):
+    def _program_and_args(synthetic, worked, sharding, implicit):
         import jax.numpy as jnp
 
         from predictionio_tpu.parallel import als
@@ -319,10 +320,12 @@ class TestDeviceScopes:
         n_u, n_i, uu, ii, rr, _ = synthetic
         model = 2 if sharding == "model" else 1
         mesh = local_mesh(2, model)
-        cfg = ALSConfig(rank=6, buckets=2, implicit=implicit, solver=solver,
+        cfg = ALSConfig(rank=6, buckets=2, implicit=implicit,
                         factor_sharding=sharding)
         data = build_als_data(uu, ii, rr, n_u, n_i, cfg, num_shards=2,
                               model_shards=model)
+        paths = als.block_paths(data, cfg, mesh)
+        assert paths["chunked"] == (paths["blocks"] if worked == "chunked" else 0)
         blocks = [
             tuple((jnp.asarray(b.indices), jnp.asarray(b.values),
                    jnp.asarray(b.mask.sum(axis=1))) for b in side.blocks)
@@ -332,18 +335,17 @@ class TestDeviceScopes:
             jnp.asarray(als._initial_side_factors(side, 6, seed), jnp.float32)
             for side, seed in ((data.by_row, 1), (data.by_col, 2))
         ]
-        build = build or als._build_iteration
-        program = build(mesh, 6, implicit, sharding, solver)
+        # past the per-mesh cache: the rule is asked as a program is traced
+        program = als._build_iteration.__wrapped__(mesh, 6, implicit, sharding)
         return program, (*blocks, *factors, jnp.float32(0.05), jnp.float32(2.0))
 
     @pytest.mark.parametrize("implicit", [False, True], ids=["explicit", "implicit"])
     @pytest.mark.parametrize("sharding", ["replicated", "model"])
-    @pytest.mark.parametrize("solver", ["xla", "pallas"])
-    def test_every_scope_is_in_the_traced_program(self, synthetic, solver,
+    def test_every_scope_is_in_the_traced_program(self, synthetic, worked,
                                                    sharding, implicit):
         from predictionio_tpu.parallel import als
 
-        program, args = self._program_and_args(synthetic, solver, sharding, implicit)
+        program, args = self._program_and_args(synthetic, worked, sharding, implicit)
         stacks = set(self._name_stacks(program.trace(*args).jaxpr.jaxpr))
         assert len(args[0]) == 2  # the user side keeps both its buckets
         def under(scope):  # the scope itself, or one nested in it
@@ -377,9 +379,8 @@ class TestDeviceScopes:
                     yield from cls._name_stacks(inner, here)
 
     @pytest.mark.parametrize("sharding", ["replicated", "model"])
-    @pytest.mark.parametrize("solver", ["xla", "pallas"])
     def test_factors_equal_the_unscoped_programs_bit_for_bit(
-            self, synthetic, monkeypatch, solver, sharding):
+            self, synthetic, monkeypatch, worked, sharding):
         """The same builder with every ``named_scope`` taken out is the
         program this one replaced."""
         import contextlib
@@ -388,13 +389,12 @@ class TestDeviceScopes:
 
         from predictionio_tpu.parallel import als
 
-        fresh = als._build_iteration.__wrapped__  # past the per-mesh cache
-        scoped, args = self._program_and_args(synthetic, solver, sharding, False, fresh)
+        scoped, args = self._program_and_args(synthetic, worked, sharding, False)
         scopes = lambda f: set(self._name_stacks(f.trace(*args).jaxpr.jaxpr))
         copy = lambda tree: jax.tree_util.tree_map(lambda a: a + 0, tree)  # donated
         with monkeypatch.context() as patch:  # traced and run with the scopes out
             patch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
-            bare, _ = self._program_and_args(synthetic, solver, sharding, False, fresh)
+            bare, _ = self._program_and_args(synthetic, worked, sharding, False)
             assert not any("als." in stack for stack in scopes(bare))
             want = bare(*copy(args))
         assert all(stack.startswith("als.") for stack in scopes(scoped))

@@ -55,14 +55,14 @@ def plays():
     return n_users, n_items, users, items, counts, tables
 
 
-def _one_iteration(plays, dtype, mesh_shape, sharding, budget=None, solver="auto"):
+def _one_iteration(plays, dtype, mesh_shape, sharding, budget=None):
     """One call of ``make_iteration``'s program on the fixture's state:
     ``(users out, items out)`` in original order, float32."""
     n_users, n_items, users, items, counts, (u0, v0) = plays
     d, m = mesh_shape
     mesh = local_mesh(d, m)
     config = ALSConfig(rank=RANK, implicit=True, alpha=ALPHA, reg=REG, dtype=dtype,
-                       buckets=2, factor_sharding=sharding, solver=solver)
+                       buckets=2, factor_sharding=sharding)
     data = build_als_data(users, items, counts, n_users, n_items, config,
                           num_shards=d, model_shards=m)
     if budget is not None:
@@ -107,7 +107,7 @@ TOLERANCE = {"float32": 1e-3, "bfloat16": 4e-3}
 def test_sharded_implicit_iteration_against_the_reference(plays, dtype):
     n_users, n_items, users, items, counts, (u0, v0) = plays
     got_u, got_v, paths = _one_iteration(plays, dtype, (2, 2), "model")
-    assert paths["xla"] and not paths["pallas"]
+    assert paths["blocks"] == 4
     stored = lambda a: np.asarray(jnp.asarray(a, jnp.dtype(dtype)), np.float32)  # noqa: E731
     want_u = reference_half_step(users, items, counts, stored(v0), n_users)
     want_v = reference_half_step(items, users, counts, got_u, n_items)
@@ -127,20 +127,18 @@ def test_fp8_storage_is_outside_the_tolerance(plays, dtype):
     assert relative_error(low, want) > 5 * TOLERANCE[dtype]
 
 
-@pytest.mark.parametrize("solver", ["xla", "pallas"])
 @pytest.mark.parametrize("mesh_shape,sharding", [
     ((1, 1), "replicated"), ((2, 1), "replicated"), ((2, 2), "replicated"),
     ((2, 2), "model"),
 ], ids=["one_device", "data2", "data2_model2_replicated", "data2_model2_sharded"])
-def test_a_chunked_block_equals_the_block_worked_whole(plays, mesh_shape, sharding, solver):
+def test_a_chunked_block_equals_the_block_worked_whole(plays, mesh_shape, sharding):
     """Rows are independent: under a budget so small that the blocks are
     worked in several row chunks, each row comes out bit for bit as from the
-    block whole. float32 tables, both half-step paths, every layout."""
-    whole_u, whole_v, whole = _one_iteration(plays, "float32", mesh_shape, sharding,
-                                             solver=solver)
+    block whole. float32 tables, every layout."""
+    whole_u, whole_v, whole = _one_iteration(plays, "float32", mesh_shape, sharding)
     assert whole["chunked"] == 0 and whole["max_chunks"] == 1
     cut_u, cut_v, cut = _one_iteration(plays, "float32", mesh_shape, sharding,
-                                       budget=1 << 20, solver=solver)
+                                       budget=1 << 20)
     assert cut["chunked"] >= 3 and cut["max_chunks"] >= 3  # of 4 blocks
     assert np.array_equal(cut_u, whole_u) and np.array_equal(cut_v, whole_v)
 
@@ -163,35 +161,39 @@ def test_two_by_two_equals_one_device(plays):
 
 
 GIB = 1 << 30
-#: (rows on one data shard, pad_len, rank, itemsize, model shards) -> (path, chunks)
+#: (rows on one data shard, pad_len, rank, itemsize, model shards) -> row chunks
 RULE = {
-    # als-ml20m-r16.train-steady's eight blocks (PERF.md section 4): whole, on the einsums
-    **{f"ml20m_r16_{rows}x{length}": ((rows, length, 16, 2, 1), ("xla", 1))
+    # als-ml20m-r16.train-steady's eight blocks (PERF.md section 4): whole
+    **{f"ml20m_r16_{rows}x{length}": ((rows, length, 16, 2, 1), 1)
        for rows, length in [(35_312, 256), (22_872, 152), (28_696, 88), (51_632, 48),
                             (7_648, 256), (2_224, 144), (3_840, 64), (13_048, 16)]},
     # the same blocks at rank 128: the rows take the blocked solve, at most
     # 4,096 of them in a chunk (64 KiB of Gram a row: the bytes alone ask 2)
-    "ml20m_r128_35312x256": ((35_312, 256, 128, 2, 1), ("xla", 9)),
-    "ml20m_r128_51632x48": ((51_632, 48, 128, 2, 1), ("xla", 13)),
-    "r128_125000x24": ((125_000, 24, 128, 2, 1), ("xla", 31)),
-    # als-msd-r128.train-sharded's largest blocks, a data shard's rows, two
-    # model shards: each device solves half of them
-    "msd_users_231168x24": ((231_168, 24, 128, 2, 2), ("xla", 29)),
-    "msd_users_39680x256": ((39_680, 256, 128, 2, 2), ("xla", 5)),
-    "msd_songs_14512x128": ((14_512, 128, 128, 2, 2), ("xla", 2)),
+    "ml20m_r128_35312x256": ((35_312, 256, 128, 2, 1), 9),
+    "ml20m_r128_51632x48": ((51_632, 48, 128, 2, 1), 13),
+    "r128_125000x24": ((125_000, 24, 128, 2, 1), 31),
+    # als-msd-r128.train-sharded's eight blocks (PERF.md section 4), a data
+    # shard's rows, two model shards: each device solves half of them
+    **{f"msd_{side}_{rows}x{length}": ((rows, length, 128, 2, 2), chunks)
+       for side, rows, length, chunks in [
+           ("users", 39_680, 256, 5), ("users", 85_152, 136, 11),
+           ("users", 153_696, 56, 19), ("users", 231_168, 24, 29),
+           ("songs", 34_656, 256, 5), ("songs", 14_512, 128, 2),
+           ("songs", 38_496, 48, 5), ("songs", 104_640, 16, 13)]},
     # the unrolled solve (rank <= 32) holds a copy of the Gram with the rows
     # on the lanes, and the chip pads a 32-wide row of either to 128 lanes;
     # its rows are not capped
-    "r32_unrolled": ((1_000_000, 8, 32, 4, 1), ("xla", 7)),
-    # the template's default ML-1M item block: the kernel, as before, whole
-    "ml1m_template_default": ((3_712, 23_832, 16, 4, 1), ("pallas", 1)),
+    "r32_unrolled": ((1_000_000, 8, 32, 4, 1), 7),
+    # the template's default ML-1M item block, 45.3 GB of gathered rows:
+    # 344 rows a chunk, 3.91 GiB of them
+    "ml1m_template_default": ((3_712, 23_832, 16, 4, 1), 11),
 }
 
 
 @pytest.mark.parametrize("case", sorted(RULE))
 def test_the_rule(case):
     (rows, pad_len, rank, itemsize, model_shards), want = RULE[case]
-    assert block_plan("auto", "tpu", rows, pad_len, rank, itemsize, model_shards) == want
+    assert block_plan("tpu", rows, pad_len, rank, itemsize, model_shards) == want
 
 
 def test_the_rule_counts_gathered_rows_grams_and_factors():
@@ -210,15 +212,13 @@ def test_the_rule_counts_gathered_rows_grams_and_factors():
     assert als.normal_equation_bytes(rows, 128, unroll=False) == rows * 2 * 65_536
     # off the TPU the bytes decide: LAPACK's batches are not capped
     total = gathered + rows * 2 * 65_536
-    assert block_plan("auto", "cpu", rows, pad_len, 128, 2)[1] == -(-total // (4 * GIB)) == 4
+    assert block_plan("cpu", rows, pad_len, 128, 2) == -(-total // (4 * GIB)) == 4
     # on it, above rank 32, the rows a device solves in a chunk are
-    assert block_plan("auto", "tpu", rows, pad_len, 128, 2)[1] == -(-rows // BLOCKED_SOLVE_ROWS)
-    assert block_plan("auto", "tpu", rows, pad_len, 128, 2, model_shards=2)[1] == 16
+    assert block_plan("tpu", rows, pad_len, 128, 2) == -(-rows // BLOCKED_SOLVE_ROWS)
+    assert block_plan("tpu", rows, pad_len, 128, 2, model_shards=2) == 16
     # ... unless the bytes ask for more: 4,096 rows of 4,096 slots are 4 GiB gathered
-    assert block_plan("auto", "tpu", 4_096, 4_096, 128, 2) == ("xla", 2)
-    # a name picks the arithmetic, not what fits
-    assert block_plan("pallas", "tpu", rows, pad_len, 128, 2) == ("pallas", 31)
-    assert block_plan("xla", "cpu", 35_312, 256, 16, 2) == ("xla", 1)
+    assert block_plan("tpu", 4_096, 4_096, 128, 2) == 2
+    assert block_plan("cpu", 35_312, 256, 16, 2) == 1
 
 
 @pytest.mark.parametrize("implicit", [False, True], ids=["explicit", "implicit"])
@@ -233,5 +233,5 @@ def test_the_fold_in_asks_the_same_rule(plays, implicit):
     whole = foldin.fold_in_users(*args)
     als.EINSUM_GATHER_BUDGET_BYTES = 1 << 20
     rows, pad_len = 512, 32  # the pow2 ladder over 300 users with at most 28 songs
-    assert block_plan("auto", "cpu", rows, pad_len, RANK, 4)[1] > 3
+    assert block_plan("cpu", rows, pad_len, RANK, 4) > 3
     assert np.array_equal(foldin.fold_in_users(*args), whole)

@@ -3,7 +3,7 @@
 The contract under test: ``als_fit_streamed`` over a ``parallel.stream``
 block store is BIT-IDENTICAL to ``als_fit`` over ``build_als_data`` when
 block shapes equal the resident bucket shapes (same plans, same packing,
-same kernels, same update order), and ulp-equivalent when a bucket is cut
+same half-step, same update order), and ulp-equivalent when a bucket is cut
 into smaller blocks (XLA tiles some batch sizes differently -- the PR-1
 micro-batching precedent); peak host memory stays O(block), with at most
 two blocks in flight through the feeder.
@@ -35,9 +35,7 @@ from predictionio_tpu.parallel.stream import (
 
 @pytest.fixture(scope="module")
 def synthetic():
-    # small on purpose: the pallas parity combos run the kernel in
-    # interpret mode, whose cost scales with edges x iterations — this
-    # shape keeps the whole matrix inside the tier-1 budget
+    # small on purpose: keeps the whole parity matrix inside the tier-1 budget
     rng = np.random.default_rng(42)
     n_u, n_i = 96, 64
     mask = rng.random((n_u, n_i)) < 0.22
@@ -80,36 +78,46 @@ def _assert_bit_identical(resident, streamed):
     np.testing.assert_array_equal(resident.item_factors, streamed.item_factors)
 
 
+def _assert_worked(worked, data, cfg, shards):
+    from predictionio_tpu.parallel.als import block_paths
+
+    paths = block_paths(data, cfg, local_mesh(*shards))
+    assert paths["chunked"] == (paths["blocks"] if worked == "chunked" else 0)
+
+
 class TestStreamedResidentParity:
-    """Bit-parity at equal shapes across the solver x mode x dtype matrix."""
+    """Bit-parity at equal shapes across the whole/chunked x mode x dtype
+    matrix."""
 
     @pytest.mark.parametrize(
-        "implicit,dtype,solver",
+        "implicit,dtype,worked",
         [
-            (False, "float32", "xla"),
-            (True, "float32", "xla"),
-            (False, "float32", "pallas"),
-            (True, "float32", "pallas"),
-            (False, "bfloat16", "xla"),
-            (True, "bfloat16", "pallas"),
+            (False, "float32", "whole"),
+            (True, "float32", "whole"),
+            (False, "float32", "chunked"),
+            (True, "float32", "chunked"),
+            (False, "bfloat16", "whole"),
+            (True, "bfloat16", "chunked"),
         ],
+        indirect=["worked"],
     )
-    def test_equal_shapes_bit_identical(self, synthetic, implicit, dtype, solver):
+    def test_equal_shapes_bit_identical(self, synthetic, implicit, dtype, worked):
         cfg = ALSConfig(
             rank=8, iterations=2, reg=0.01, seed=1, buckets=2,
-            implicit=implicit, alpha=5.0, dtype=dtype, solver=solver,
+            implicit=implicit, alpha=5.0, dtype=dtype,
         )
-        resident, streamed, _, _ = _fit_both(synthetic, cfg)
+        resident, streamed, data, _ = _fit_both(synthetic, cfg)
+        _assert_worked(worked, data, cfg, (1, 1))
         _assert_bit_identical(resident, streamed)
 
-    @pytest.mark.parametrize("solver", ["xla", "pallas"])
-    def test_model_sharded_bit_identical(self, synthetic, solver):
+    @pytest.mark.parametrize("worked", ["whole", "chunked"], indirect=True)
+    def test_model_sharded_bit_identical(self, synthetic, worked):
         cfg = ALSConfig(
             rank=8, iterations=2, reg=0.01, seed=1, buckets=2,
-            implicit=True, alpha=5.0, solver=solver,
-            factor_sharding="model",
+            implicit=True, alpha=5.0, factor_sharding="model",
         )
-        resident, streamed, _, _ = _fit_both(synthetic, cfg, shards=(2, 2))
+        resident, streamed, data, _ = _fit_both(synthetic, cfg, shards=(2, 2))
+        _assert_worked(worked, data, cfg, (2, 2))
         _assert_bit_identical(resident, streamed)
 
     def test_data_sharded_replicated_bit_identical(self, synthetic):

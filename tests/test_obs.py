@@ -955,12 +955,12 @@ class TestTrainTelemetry:
 
         path = str(tmp_path / "t.jsonl")
         with TrainTelemetry(
-            path, edges=1000, modeled_bytes_per_iter=2e9, meta={"solver": "xla"}
+            path, edges=1000, modeled_bytes_per_iter=2e9, meta={"rank": 16}
         ) as tel:
             tel.record_step(0, 0.5, recompile_count=1)
             tel.record_step(1, 0.25, recompile_count=1)
         lines = [json.loads(l) for l in open(path)]
-        assert lines[0]["event"] == "meta" and lines[0]["solver"] == "xla"
+        assert lines[0]["event"] == "meta" and lines[0]["rank"] == 16
         assert lines[1]["edges_per_sec"] == 2000.0
         assert lines[1]["achieved_gbps"] == 4.0
         assert lines[2]["step"] == 1 and lines[2]["recompile_count"] == 1
@@ -987,9 +987,7 @@ class TestTrainTelemetry:
         tel = TrainTelemetry(
             path,
             edges=real_edges(data),
-            modeled_bytes_per_iter=modeled_bytes_per_iteration(
-                data, 4, 4, fused=False
-            ),
+            modeled_bytes_per_iter=modeled_bytes_per_iteration(data, 4, 4),
         )
         model = als_fit(data, config, telemetry=tel)
         tel.close()
@@ -1013,13 +1011,10 @@ class TestTrainTelemetry:
             "pio_jit_compiles_total"
         )
 
-    @pytest.mark.parametrize("solver,has_gbps", [("xla", True), ("pallas", False)])
-    def test_journal_models_bytes_for_the_xla_tail_only(
-        self, tmp_path, solver, has_gbps
-    ):
-        """``modeled_bytes_per_iteration`` counts padded slots through the
-        gathered intermediate: it does not describe the fused kernel, so a
-        run that resolves to ``pallas`` writes no ``achieved_gbps``."""
+    def test_the_profiled_train_journals_achieved_gbps(self, tmp_path):
+        """``_build_telemetry`` always hands the journal the bytes model
+        (``modeled_bytes_per_iteration``: padded slots through the gathered
+        intermediate), so every step line carries ``achieved_gbps``."""
         import numpy as np
 
         from predictionio_tpu.models._als_common import _build_telemetry
@@ -1028,13 +1023,13 @@ class TestTrainTelemetry:
         class Ctx:
             runtime_conf = {"pio.profile": str(tmp_path)}
 
-        config = ALSConfig(rank=4, solver=solver)
+        config = ALSConfig(rank=4)
         data = build_als_data(
             np.arange(12) % 5, np.arange(12) % 3, np.ones(12, np.float32), 5, 3, config
         )
         with _build_telemetry(Ctx(), data, config, None, "als") as tel:
             step = tel.record_step(0, 0.5)
-        assert ("achieved_gbps" in step) is has_gbps
+        assert "achieved_gbps" in step  # rounded to 0.0 at this toy size
         assert step["edges_per_sec"] > 0
 
     def test_train_profile_cli_flag(self):

@@ -474,7 +474,7 @@ class TestFoldinParity:
 
         users, items, vals, U, I, K = self._data()
         cfg = ALSConfig(rank=K, iterations=2, reg=0.1, alpha=5.0,
-                        implicit=implicit, solver="xla")
+                        implicit=implicit)
         data = build_als_data(users, items, vals, U, I, cfg)
         model = als_fit(data, cfg)
         touched = [0, 5, 11]
@@ -494,25 +494,26 @@ class TestFoldinParity:
             ref = np.linalg.solve(G, r)
             assert np.abs(ref - out[t]).max() < 1e-4
 
-    def test_pallas_solver_matches_xla(self):
-        """The fused gather->Gram kernel path (interpret mode on the CPU
-        mesh, the tier-1 precedent) produces the same folded rows."""
-        import dataclasses
-
+    def test_rows_folded_in_chunks_equal_the_rows_folded_whole(self, monkeypatch):
+        """More touched rows than one chunk may hold (a budget of 64 KiB: the
+        pow2 ladder's 16 rows go in two chunks of 8): the same folded rows,
+        bit for bit, as under the budget that takes them whole."""
         from predictionio_tpu.online.foldin import fold_in_users
+        from predictionio_tpu.parallel import als
         from predictionio_tpu.parallel.als import ALSConfig, als_fit, build_als_data
 
         users, items, vals, U, I, K = self._data(seed=2, U=20, I=10, E=200)
-        cfg = ALSConfig(rank=K, iterations=2, solver="xla")
+        cfg = ALSConfig(rank=K, iterations=2)
         data = build_als_data(users, items, vals, U, I, cfg)
         model = als_fit(data, cfg)
-        rows, cols, vv = self._touched_coo(users, items, vals, [1, 3, 7])
-        a = fold_in_users(model.item_factors, rows, cols, vv, 3, cfg)
-        b = fold_in_users(
-            model.item_factors, rows, cols, vv, 3,
-            dataclasses.replace(cfg, solver="pallas"),
-        )
-        assert np.abs(a - b).max() < 1e-5
+        touched = list(range(11))
+        rows, cols, vv = self._touched_coo(users, items, vals, touched)
+        whole = fold_in_users(model.item_factors, rows, cols, vv, len(touched), cfg)
+        monkeypatch.setattr(als, "EINSUM_GATHER_BUDGET_BYTES", 1 << 16)
+        pad_len = 1 << int(np.ceil(np.log2(max(np.bincount(rows).max(), 8))))
+        assert als.block_plan("cpu", 16, pad_len, K, 4) > 1
+        cut = fold_in_users(model.item_factors, rows, cols, vv, len(touched), cfg)
+        np.testing.assert_array_equal(cut, whole)
 
     def test_replay_idempotence(self):
         """Folding the same window twice converges to the same factors --
@@ -521,7 +522,7 @@ class TestFoldinParity:
         from predictionio_tpu.parallel.als import ALSConfig, als_fit, build_als_data
 
         users, items, vals, U, I, K = self._data(seed=4)
-        cfg = ALSConfig(rank=K, iterations=2, solver="xla")
+        cfg = ALSConfig(rank=K, iterations=2)
         data = build_als_data(users, items, vals, U, I, cfg)
         model = als_fit(data, cfg)
         rows, cols, vv = self._touched_coo(users, items, vals, [2, 9])
@@ -590,7 +591,7 @@ class TestAlgorithmFoldIn:
         users = rng.integers(0, U, E)
         items = rng.integers(0, I, E)
         vals = rng.integers(1, 6, E).astype(np.float32)
-        cfg = ALSConfig(rank=4, iterations=2, solver="xla")
+        cfg = ALSConfig(rank=4, iterations=2)
         model = als_fit(build_als_data(users, items, vals, U, I, cfg), cfg)
         uid = [f"u{k}" for k in range(U)]
         iid = [f"i{k}" for k in range(I)]
@@ -712,7 +713,7 @@ class TestECommerceCategoryRefresh:
         U, I, E = 8, 5, 60
         users = rng.integers(0, U, E)
         items = rng.integers(0, I, E)
-        cfg = ALSConfig(rank=4, iterations=2, implicit=True, solver="xla")
+        cfg = ALSConfig(rank=4, iterations=2, implicit=True)
         als = als_fit(
             build_als_data(users, items, np.ones(E, np.float32), U, I, cfg),
             cfg,

@@ -70,45 +70,6 @@ def _kernel_instructions(text: str, name: str) -> list[str]:
 
 
 @pytest.mark.parametrize("rank", [16, 128])
-@pytest.mark.parametrize("implicit", [False, True], ids=["explicit", "implicit"])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
-def test_gram_rhs_compiles(one_chip, no_persistent_cache, dtype, implicit, rank):
-    """The fused gather->Gram half-step at the bench block shape, against a
-    27k-row factor table. The seed kernel was refused here in every mode (a
-    one-row DMA out of a [rows, K] table: sublane tiling for bf16, lane
-    tiling for K < 128)."""
-    from predictionio_tpu.ops.als_gram import gram_rhs
-
-    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    text = _compiled_text(
-        functools.partial(gram_rhs, implicit=implicit, interpret=False),
-        sds((4096, 256), jnp.int32),
-        sds((4096, 256), jnp.float32),
-        sds((27_001, rank), dtype),
-        sds((), jnp.float32),
-    )
-    assert "tpu_custom_call" in text
-    assert _kernel_instructions(text, "als_gram_rhs")
-
-
-def test_gram_rhs_compiles_for_a_block_longer_than_smem(one_chip, no_persistent_cache):
-    """The recommendation template's single-bucket item side at the
-    MovieLens-1M shape: [3712, 23832]. One grid step over the whole length
-    wants a 1.5 MB index window in 1 MB of SMEM; tiled over L it fits."""
-    from predictionio_tpu.ops.als_gram import gram_rhs
-
-    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    text = _compiled_text(
-        functools.partial(gram_rhs, implicit=False, interpret=False),
-        sds((3712, 23_832), jnp.int32),
-        sds((3712, 23_832), jnp.float32),
-        sds((6_041, 16), jnp.float32),
-        sds((), jnp.float32),
-    )
-    assert "tpu_custom_call" in text
-
-
-@pytest.mark.parametrize("rank", [16, 128])
 def test_mips_block_topk_compiles(one_chip, no_persistent_cache, rank):
     """Stage 1 of device retrieval at 1M items, default tile and top-R."""
     from predictionio_tpu.ops.mips import BLOCK_QUERIES, mips_block_topk
@@ -190,21 +151,20 @@ def test_ncf_scorer_compiles(one_chip, no_persistent_cache):
     assert _kernel_instructions(text, "ncf_score_all_items")
 
 
-@pytest.mark.parametrize("solver", ["pallas", "auto"])
-def test_model_sharded_als_compiles_on_four_chips(topo, no_persistent_cache, solver):
+def test_model_sharded_als_compiles_on_four_chips(topo, no_persistent_cache, worked):
     """The ALX block body (``factor_sharding="model"``) on ``Mesh(topo.devices)``
-    as data=2 x model=2. By name, the fused kernel per device and a
-    reduce-scatter of its partial Gram/rhs over the model axis; under "auto"
-    a block this small gathers its local hits and hands each chip of the
-    model pair its rows' share through an all-to-all (a ``psum_scatter`` of
-    the gathered rows the compiler turns into pad + all-reduce + slice)."""
-    from predictionio_tpu.parallel.als import ALSConfig, make_iteration
+    as data=2 x model=2: a block gathers its local hits and hands each chip of
+    the model pair its rows' share through an all-to-all (a ``psum_scatter`` of
+    the gathered rows the compiler turns into pad + all-reduce + slice), whole
+    or, under a small budget, inside the loop over its row chunks."""
+    from predictionio_tpu.parallel.als import ALSConfig, block_plan, make_iteration
 
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
-    config = ALSConfig(rank=8, factor_sharding="model", solver=solver)
+    config = ALSConfig(rank=8, factor_sharding="model")
     row, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
     fsh = NamedSharding(mesh, P("model"))
     rows, length = 1024, 64
+    assert (block_plan("tpu", rows // 2, length, 8, 4, 2) > 1) == (worked == "chunked")
 
     def block():
         return ((
@@ -218,8 +178,8 @@ def test_model_sharded_als_compiles_on_four_chips(topo, no_persistent_cache, sol
     text = make_iteration(mesh, config).lower(
         block(), block(), factors, factors, scalar, scalar
     ).compile().as_text()
-    assert ("reduce-scatter" if solver == "pallas" else "all-to-all") in text
-    assert ("tpu_custom_call" in text) == (solver == "pallas")
+    assert "all-to-all" in text and "tpu_custom_call" not in text
+    assert (" while(" in text) == (worked == "chunked")
 
 
 def _one_chip_iteration(topo, config, user_blocks, item_blocks, mesh_shape=(1, 1)):
@@ -268,12 +228,12 @@ def test_the_rank_128_solve_is_blocked_and_holds_what_the_rule_counts(
     from predictionio_tpu.parallel import als
 
     config = als.ALSConfig(rank=128, implicit=True, alpha=40.0, reg=0.1,
-                           dtype="bfloat16", factor_sharding="model", solver="auto")
+                           dtype="bfloat16", factor_sharding="model")
     rows, length = 79_360, 256
-    path, chunks = als.block_plan("auto", "tpu", rows // 2, length, 128, 2, 2)
+    chunks = als.block_plan("tpu", rows // 2, length, 128, 2, 2)
     counted = (als.gathered_bytes(rows // 2, length, 128, 2)
                + als.normal_equation_bytes(rows // 4, 128, unroll=True)) / chunks
-    assert (path, chunks) == ("xla", 5)
+    assert chunks == 5
     compiled = _one_chip_iteration(
         topo, config, [(rows, length)], [(1_024, 16)], mesh_shape=(2, 2))
     text = compiled.as_text()
@@ -284,16 +244,16 @@ def test_the_rank_128_solve_is_blocked_and_holds_what_the_rule_counts(
     assert 0.8 * counted <= temp_size < 1.35 * counted < als.EINSUM_GATHER_BUDGET_BYTES
 
 
-def test_auto_takes_the_einsum_tail_for_every_block_of_the_train_cell(
+def test_the_train_cell_compiles_whole_and_fits_the_chip_several_times_over(
     topo, no_persistent_cache
 ):
-    """``als-ml20m-r16.train-steady``'s eight blocks (PERF.md section 4):
-    under "auto" none runs the fused kernel, and the program's temporaries
+    """``als-ml20m-r16.train-steady``'s eight blocks (PERF.md section 4): no
+    custom call in the program, and its temporaries
     (the lane-padded gathered rows of the largest block, 2.31 GB, and what
     the einsums and the solve add) fit the chip several times over."""
     from predictionio_tpu.parallel.als import ALSConfig
 
-    config = ALSConfig(rank=16, dtype="bfloat16", solver="auto")
+    config = ALSConfig(rank=16, dtype="bfloat16")
     compiled = _one_chip_iteration(
         topo, config,
         [(35_312, 256), (22_872, 152), (28_696, 88), (51_632, 48)],
@@ -301,37 +261,74 @@ def test_auto_takes_the_einsum_tail_for_every_block_of_the_train_cell(
     )
     assert "tpu_custom_call" not in compiled.as_text()
     temp_size = compiled.memory_analysis().temp_size_in_bytes
-    print(f"train cell, auto: temp_size {temp_size} bytes")
+    print(f"train cell: temp_size {temp_size} bytes")
     assert 35_312 * 256 * 128 * 2 <= temp_size < 4 << 30
 
 
-def test_auto_keeps_the_kernel_for_the_template_default_block(
+def test_the_template_default_block_compiles_in_11_chunks(
     topo, no_persistent_cache, monkeypatch
 ):
     """The recommendation template's default packing (one bucket, no cap,
-    f32) at MovieLens-1M: users [6040, 216], items [3712, 23832]. The einsum
-    tail's gathered rows for the item block are 45.3 GB: worked whole, the
-    compiler refuses the program (the control: "xla" by name with the chunk
-    rule taken out). "auto" compiles: the kernel for that block alone, the
-    einsums for the user block."""
+    f32) at MovieLens-1M: users [6040, 216], items [3712, 23832]. The gathered
+    rows of the item block are 45.3 GB: worked whole, the compiler refuses the
+    program (the control: the chunk rule taken out). With the rule it compiles
+    as a loop over 11 chunks of 344 rows with no custom call, and its
+    temporaries stay within 1.3x of the 3.91 GiB the rule counted for one."""
     from predictionio_tpu.parallel import als
     from predictionio_tpu.parallel.als import ALSConfig
 
     users, items = [(6_040, 216)], [(3_712, 23_832)]
+    als._build_iteration.cache_clear()
     with monkeypatch.context() as patch:
-        patch.setattr(als, "block_plan", lambda *shape, **kw: ("xla", 1))
+        patch.setattr(als, "block_plan", lambda *shape, **kw: 1)
         with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
-            _one_chip_iteration(topo, ALSConfig(rank=16, solver="xla"), users, items)
+            _one_chip_iteration(topo, ALSConfig(rank=16), users, items)
     als._build_iteration.cache_clear()  # the program built without the rule
-    compiled = _one_chip_iteration(
-        topo, ALSConfig(rank=16, solver="auto"), users, items)
+    assert als.block_plan("tpu", *items[0], 16, 4) == 11
+    compiled = _one_chip_iteration(topo, ALSConfig(rank=16), users, items)
     text = compiled.as_text()
-    (kernel,) = _kernel_instructions(text, "als_gram_rhs")
-    line = next(l for l in text.splitlines() if f"%{kernel} = " in l)
-    assert "als.item_half_step" in line  # the oversized block's, not the users'
-    mem = compiled.memory_analysis()
-    print(f"ML-1M template defaults, auto: temp_size {mem.temp_size_in_bytes} bytes")
-    assert mem.temp_size_in_bytes < 4 << 30
+    assert "tpu_custom_call" not in text and text.count(" while(") == 1
+    counted = (als.gathered_bytes(344, 23_832, 16, 4)
+               + als.normal_equation_bytes(344, 16, unroll=True))
+    temp_size = compiled.memory_analysis().temp_size_in_bytes
+    print(f"ML-1M template defaults: temp_size {temp_size} bytes, counted {counted}")
+    assert counted <= temp_size < 1.3 * counted
+
+
+@pytest.mark.parametrize("rank,pad_len", [(16, 256), (128, 4_096)], ids=["rank16", "rank128"])
+@pytest.mark.parametrize("implicit", [False, True], ids=["explicit", "implicit"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_a_budget_sized_chunk_holds_what_the_rule_counted(
+    topo, one_chip, no_persistent_cache, dtype, implicit, rank, pad_len
+):
+    """The most rows of ``pad_len`` slots that ``block_plan`` still takes in
+    one piece (at rank 128 a length at which the bytes bind before the blocked
+    solve's 4,096 rows do), through the half-step it picks: the compiled
+    program's temporaries are what the rule counted for them (gathered rows,
+    Grams, what the solve holds beside them) and no more than 1.3 times that
+    (PERF.md section 7)."""
+    from predictionio_tpu.parallel import als
+
+    itemsize = jnp.dtype(dtype).itemsize
+    per_row = (als.gathered_bytes(1, pad_len, rank, itemsize)
+               + als.normal_equation_bytes(8, rank, unroll=True) // 8)
+    rows = als.EINSUM_GATHER_BUDGET_BYTES // per_row // 8 * 8
+    assert als.block_plan("tpu", rows, pad_len, rank, itemsize) == 1
+    assert als.block_plan("tpu", rows + 64, pad_len, rank, itemsize) == 2
+    counted = (als.gathered_bytes(rows, pad_len, rank, itemsize)
+               + als.normal_equation_bytes(rows, rank, unroll=True))
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    idx, table = sds((rows, pad_len), jnp.int32), sds((27_001, rank), dtype)
+    step = als._half_steps(mesh, implicit, rank, "replicated")(idx, table)
+    compiled = jax.jit(step).lower(
+        idx, sds((rows, pad_len), jnp.float32), sds((rows,), jnp.float32), table,
+        sds((rank, rank), jnp.float32), sds((), jnp.float32), sds((), jnp.float32),
+    ).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    temp_size = compiled.memory_analysis().temp_size_in_bytes
+    print(f"rank {rank} {rows} x {pad_len}: temp_size {temp_size} bytes, counted {counted}")
+    assert 0.8 * counted <= temp_size <= 1.3 * counted
 
 
 #: instructions of an entry computation that move or name data and do no
@@ -343,26 +340,24 @@ _NO_WORK = {
 }
 
 
-@pytest.mark.parametrize("solver", ["pallas", "xla"])
-def test_als_iteration_names_its_kernel_and_scopes_its_work(
-    topo, no_persistent_cache, solver
-):
-    """One chip, two buckets a side, bf16 factors: the kernel once per bucket
-    under its name, and every instruction of the entry computation that does
-    work under an ``als.`` scope. The compiler leaves the ``op_name`` off some
-    fusions it forms itself; those are held to their fused instructions."""
+def test_als_iteration_scopes_its_work(topo, no_persistent_cache, worked):
+    """One chip, two buckets a side, bf16 factors, each bucket whole or in
+    row chunks: no custom call, and every instruction of the entry
+    computation that does work under an ``als.`` scope. The compiler leaves
+    the ``op_name`` off some fusions it forms itself; those are held to their
+    fused instructions."""
     import re
     from collections import Counter
 
     from predictionio_tpu.parallel import als
 
-    config = als.ALSConfig(rank=8, solver=solver, dtype="bfloat16")
-    text = _one_chip_iteration(
-        topo, config, [(256, 64), (512, 16)], [(128, 128), (256, 32)]
-    ).as_text()
+    config = als.ALSConfig(rank=8, dtype="bfloat16")
+    shapes = [(256, 64), (512, 16), (128, 128), (256, 32)]
+    for rows, length in shapes:
+        assert (als.block_plan("tpu", rows, length, 8, 2) > 1) == (worked == "chunked")
+    text = _one_chip_iteration(topo, config, shapes[:2], shapes[2:]).as_text()
 
-    kernels = _kernel_instructions(text, "als_gram_rhs")
-    assert len(kernels) == (4 if solver == "pallas" else 0)
+    assert "tpu_custom_call" not in text
     assert "%iteration" not in text and "_unknown_" not in text
 
     def scope(line: str):
@@ -376,19 +371,23 @@ def test_als_iteration_names_its_kernel_and_scopes_its_work(
     entry = re.search(r"\nENTRY %[^\n]*\{\n(.*?)\n\}", text, re.S).group(1)
     seen = Counter()
     for line in entry.splitlines():
-        opcode = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = .*? ([\w\-]+)\(", line).group(1)
-        if opcode in _NO_WORK:
+        name, opcode = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = .*? ([\w\-]+)\(", line).groups()
+        # (the compiler's own placing of a chunked bucket's rows is a fusion
+        # named for the dynamic-update-slice it holds: no arithmetic either)
+        if opcode in _NO_WORK or "dynamic-update-slice_fusion" in name:
             continue
         found = scope(line)
         if found is None and opcode == "fusion":
             inner = computations[re.search(r"calls=%([\w.\-]+)", line).group(1)]
             votes = Counter(filter(None, map(scope, inner.splitlines())))
             found = votes.most_common(1)[0][0] if votes else None
-        assert found is not None and found[1] is not None, line[:200]
+        # a chunked bucket's splitting and stitching (pad, reshape, the loop)
+        # lie under its ``bucket<i>`` and under no stage
+        assert found is not None and (found[1] is not None or worked == "chunked"), line[:200]
         seen[found] += 1
     for side in als.SCOPE_HALF_STEP.values():
         for stage in (als.SCOPE_GRAM, als.SCOPE_SOLVE):
-            assert seen[(side, stage)] > 0, (side, stage, seen)
-    for line in text.splitlines():
-        if any(f"%{kernel} = " in line for kernel in kernels):
-            assert scope(line)[1] == als.SCOPE_GRAM
+            if worked == "whole":
+                assert seen[(side, stage)] > 0, (side, stage, seen)
+            else:  # the stages are in the chunk loops' bodies, not in the entry
+                assert re.search(rf'op_name="[^"]*{re.escape(side)}/bucket\d/while/body/[^"]*{stage}/', text)
