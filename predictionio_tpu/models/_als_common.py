@@ -580,6 +580,7 @@ def _layout_attrs(paths: dict, config: ALSConfig, mesh) -> dict:
         "blocks_chunked": paths["chunked"],
         "max_chunks": paths["max_chunks"],
         "blocked_solve": paths["blocked_solve"],
+        "dual_solve": paths["dual_solve"],
     }
 
 

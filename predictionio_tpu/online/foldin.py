@@ -163,18 +163,18 @@ def _build_solver(implicit: bool, rank: int, platform: str, chunks: int = 1):
 
     from predictionio_tpu.parallel.als import (
         _append_zero_row,
-        _factors_yty,
         _half_step_explicit,
         _half_step_implicit,
         _in_row_chunks,
+        _shared_gram,
     )
 
     unroll = platform == "tpu"
 
-    def block(indices, values, n_obs, full, yty, reg, alpha):
+    def block(indices, values, n_obs, full, shared, reg, alpha):
         if implicit:
             return _half_step_implicit(
-                indices, values, n_obs, full, yty, reg, alpha, rank, unroll
+                indices, values, n_obs, full, shared, reg, alpha, rank, unroll
             )
         return _half_step_explicit(indices, values, n_obs, full, reg, rank, unroll)
 
@@ -182,9 +182,8 @@ def _build_solver(implicit: bool, rank: int, platform: str, chunks: int = 1):
         block = _in_row_chunks(block, chunks)
 
     def step(indices, values, n_obs, factors, reg, alpha):
-        yty = _factors_yty(factors) if implicit else None
-        return block(indices, values, n_obs, _append_zero_row(factors), yty,
-                     reg, alpha)
+        return block(indices, values, n_obs, _append_zero_row(factors),
+                     _shared_gram(factors, reg, implicit), reg, alpha)
 
     return jax.jit(step)
 
@@ -234,8 +233,9 @@ def fold_in_users(
     )
     rank = item_factors.shape[1]
     # the table ships as float32 (_device_factors)
-    chunks = block_plan(platform, *csr.indices.shape, rank, 4)
-    step = _build_solver(bool(config.implicit), rank, platform, chunks)
+    implicit = bool(config.implicit)
+    chunks = block_plan(platform, *csr.indices.shape, rank, 4, implicit=implicit)
+    step = _build_solver(implicit, rank, platform, chunks)
     out = step(
         csr.indices,
         csr.values,

@@ -40,6 +40,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.scipy.linalg import solve_triangular
 from jax.sharding import NamedSharding, PartitionSpec
 
 from predictionio_tpu.ops.linalg import (
@@ -59,9 +60,11 @@ logger = logging.getLogger("pio.als")
 #: iteration)``, ``shard_map``) may sit between these.
 SCOPE_HALF_STEP = {"user": "als.user_half_step", "item": "als.item_half_step"}
 SCOPE_BUCKET = "bucket{}"
-#: the gather (with its model-axis exchange) and the two einsums
+#: forming a row's system: the gather (with its model-axis exchange) and the
+#: two einsums or, in the dual form, the whitening and the ``[L, L]`` system
 SCOPE_GRAM = "gram"
 #: ridge, ``ops.linalg.batched_spd_solve``, the cast back to the factor dtype
+#: (and the dual form's projection back to ``[K]``)
 SCOPE_SOLVE = "solve"
 #: the zero row, the replicated constraint, YtY, and putting the buckets'
 #: rows together
@@ -71,7 +74,8 @@ SCOPE_ASSEMBLE = "assemble"
 #: bucket's gathered rows, and the re-layout of the solved rows from
 #: ``P(("data", "model"))`` back to ``P("model")``
 SCOPE_EXCHANGE = "exchange"
-#: nested under ``assemble``: the side's global K x K Gram (implicit only)
+#: nested under ``assemble``: the side's global K x K Gram and the whitening
+#: matrix made from it (implicit only; ``_shared_gram``)
 SCOPE_YTY = "yty"
 
 
@@ -438,7 +442,29 @@ def _gram_solve_explicit(gathered, values, n_obs, reg, rank, unroll, out_dtype):
     return _finish_explicit(gram, rhs, n_obs, reg, rank, unroll, out_dtype)
 
 
-def _gram_solve_implicit(gathered, values, yty, reg, alpha, rank, unroll, out_dtype):
+#: An implicit block whose padded length L is at most the rank over this takes
+#: the dual form of its rows' systems (``_dual_solve_implicit``): ``2 * L <=
+#: K``. One v5e, rank 128, bf16 table, one block through the half-step in
+#: chunks of 4,096 rows, ms a call primal against dual (PERF.md section 6, PR
+#: 29): 16 slots x 52,320 rows 76.7 / 5.6; 24 x 115,584 169.4 / 23.1; 48 x
+#: 19,248 27.1 / 12.4; 56 x 76,848 114.0 / 56.8; 64 x 16,384 33.3 / 23.3; 96 x
+#: 16,384 42.1 / 37.5; 128 x 8,192 25.0 / 25.9 to 29.0. At the line the dual
+#: takes 70% of the primal's time; past it the margin thins (89% at three
+#: quarters of the rank) and at L = K it is gone, and no listed cell has a
+#: block between 64 and 128 slots at rank 128 to judge a later line by.
+DUAL_RANK_OVER_LEN = 2
+
+
+def takes_dual(implicit: bool, pad_len: int, rank: int) -> bool:
+    """Whether a block of ``pad_len`` slots a row is solved in the dual form:
+    a static test on the block's shape, made as the program is traced, by the
+    tail, by ``block_plan`` and by ``block_paths`` alike. Explicit blocks never
+    are (ALS-WR's ridge differs by row, so nothing is shared to whiten with).
+    """
+    return implicit and DUAL_RANK_OVER_LEN * pad_len <= rank
+
+
+def _gram_solve_implicit(gathered, values, shared, reg, alpha, rank, unroll, out_dtype):
     """Hu-Koren-Volinsky implicit tail with the YtY trick.
 
     G = YtY + sum_obs (c-1) y y^T + lam*I ; rhs = sum_obs c * y
@@ -447,7 +473,15 @@ def _gram_solve_implicit(gathered, values, yty, reg, alpha, rank, unroll, out_dt
     padding term dies without a mask (``(1 + c-1) * y`` at a padding slot
     multiplies the gathered zero row). Implicit mode uses constant lambda
     (MLlib trainImplicit parity), so no n_obs.
+
+    ``shared`` is ``_shared_gram``'s pair, made once a half-step: ``yty`` for
+    this, the primal form, and ``whiten`` for the dual form, which a block
+    short against the rank takes (``takes_dual``): the same equation by a
+    matrix identity, an ``[L, L]`` system a row in place of a ``[K, K]`` one.
     """
+    yty, whiten = shared
+    if takes_dual(True, gathered.shape[1], rank):
+        return _dual_solve_implicit(gathered, values, whiten, alpha, unroll, out_dtype)
     with jax.named_scope(SCOPE_GRAM):
         conf_minus_1 = alpha * values
         gram_fix = jnp.einsum(
@@ -461,13 +495,85 @@ def _gram_solve_implicit(gathered, values, yty, reg, alpha, rank, unroll, out_dt
     return _finish_implicit(gram_fix, rhs, yty, reg, rank, unroll, out_dtype)
 
 
-def _factors_yty(factors):
-    """f32 K x K Gram of a factor matrix (implicit mode's global term)."""
-    return jnp.einsum(
-        "nk,nj->kj", factors, factors,
-        precision=_factor_precision(factors.dtype),
-        preferred_element_type=jnp.float32,
-    )
+def _dual_solve_implicit(gathered, values, whiten, alpha, unroll, out_dtype):
+    """The implicit tail in its dual (Woodbury) form, for rows of few slots.
+
+    A row's system is ``(A + U C U') x = U w``: ``A = YtY + lam I``, shared by
+    every row of the half-step; ``U`` the row's ``L`` gathered factors as
+    columns; ``C = diag(alpha r)``; ``w = 1 + alpha r``. By the push-through
+    identity ``(A + U C U')^-1 U = A^-1 U (I + C T)^-1`` with ``T = U' A^-1
+    U``, so ``x = A^-1 U t`` with ``t = (I + C T)^-1 w``: one ``[L, L]`` system
+    a row, no ``[K, K]`` Gram. Nothing is approximated.
+
+    - ``whiten`` is ``inv(chol(A))`` (``_shared_gram``), so ``A^-1 = whiten'
+      whiten``: the gathered rows are whitened by one large matmul, ``Ut = G
+      whiten'``, and ``T = Ut Ut'`` is symmetric positive semi-definite as
+      computed.
+    - ``I + C T`` is made symmetric by ``D = sqrt(C)``: ``S = I + D T D``, SPD
+      with every eigenvalue >= 1, solved by ``batched_spd_solve`` with no
+      jitter (up to 32 slots unrolled with the rows on the lanes, above it
+      blocked, off the TPU by LAPACK).
+    - ``t = 1 + D S^-1 D (1 - T 1)``: multiply out ``(I + C T) t`` with ``D S
+      D = C + C T C`` to get ``w``. No division by ``D``, so a slot of value
+      zero (``t = 1`` there, as the primal form has it) and a padding slot
+      (zero row and column of ``T``, ``S`` the identity there: the padding
+      invariant, no mask) need nothing of their own. Values are confidences
+      and not negative.
+    - ``x = whiten' (Ut' t)``.
+
+    Of three ways to take ``t`` this one was kept. Float32 against NumPy
+    float64 at rank 128, 16 to 56 slots, play counts up to 9,667 at alpha 40
+    (``alpha r`` 3.9e5), relative error of the solved rows (CPU, PR 29): this
+    form 2.7e-7 to 3.2e-7; ``t = D S^-1 D^-1 w`` with ``D`` set to 1 where
+    ``C`` is 0 the same, but a slot of value zero that is not padding then
+    solves as if its confidence were 2; the subtractive ``t = w - D S^-1 D T
+    w`` 7e-5 to 2.6e-4 (``w`` reaches 3.9e5 where ``t`` stays near ``T^-1
+    1``, so it cancels). The primal form reads 1e-4 to 3e-4 on the same
+    rows: its ``[K, K]`` system carries the condition number that ``S`` sheds.
+    """
+    pad_len = gathered.shape[1]
+    with jax.named_scope(SCOPE_GRAM):
+        white = jnp.einsum(
+            "rlk,jk->rlj", gathered, whiten,
+            precision="highest", preferred_element_type=jnp.float32,
+        )
+        cross = jnp.einsum("rlk,rmk->rlm", white, white, precision="highest")
+        root = jnp.sqrt(alpha * values)
+        system = (root[:, :, None] * cross * root[:, None, :]
+                  + jnp.eye(pad_len, dtype=cross.dtype))
+        rhs = root * (1.0 - cross.sum(axis=2))
+    with jax.named_scope(SCOPE_SOLVE):
+        weights = 1.0 + root * batched_spd_solve(system, rhs, jitter=0.0, unroll=unroll)
+        back = jnp.einsum("rl,rlk->rk", weights, white, precision="highest")
+        return jnp.einsum(
+            "rk,kj->rj", back, whiten, precision="highest"
+        ).astype(out_dtype)
+
+
+def _shared_gram(factors, reg, implicit: bool = True):
+    """What every row of one implicit half-step shares, computed ONCE per
+    half-step by the caller (bucket-invariant: not once a bucket, not once a
+    chunk) and handed to every block's tail: ``(yty, whiten)``.
+
+    ``yty`` is the side's global factor Gram, the primal form's term.
+    ``whiten`` is ``inv(L)`` for ``A = yty + lam I = L L'`` (float32 Cholesky
+    of one K x K matrix), the dual form's (``_dual_solve_implicit``); ``A``
+    carries ``batched_spd_solve``'s jitter, so that both forms solve the same
+    system to the letter. A program none of whose blocks is dual drops it
+    as dead code. Explicit mode feeds a dummy the steps drop.
+    """
+    k = factors.shape[1]
+    if not implicit:
+        return jnp.zeros((k, k), jnp.float32)
+    with jax.named_scope(SCOPE_YTY):
+        yty = jnp.einsum(
+            "nk,nj->kj", factors, factors,
+            precision=_factor_precision(factors.dtype),
+            preferred_element_type=jnp.float32,
+        )
+        eye = jnp.eye(k, dtype=yty.dtype)
+        chol = jnp.linalg.cholesky(yty + (reg + 1e-6) * eye)
+        return yty, solve_triangular(chol, eye, lower=True)
 
 
 def _half_step_explicit(indices, values, n_obs, factors, reg, rank, unroll):
@@ -479,33 +585,34 @@ def _half_step_explicit(indices, values, n_obs, factors, reg, rank, unroll):
     )
 
 
-def _half_step_implicit(indices, values, n_obs, factors, yty, reg, alpha,
+def _half_step_implicit(indices, values, n_obs, factors, shared, reg, alpha,
                         rank, unroll):
     """Replicated-factor implicit half-step.
 
     ``n_obs`` is unused (constant lambda) but kept so both modes share one
-    block layout. ``yty`` is the side's global factor Gram, computed ONCE
-    per half-step by the caller (it is bucket-invariant; computing it here
-    would redo the [S, K] reduction for every bucket).
+    block layout. ``shared`` is ``_shared_gram``'s pair for the side,
+    computed ONCE per half-step by the caller (it is bucket-invariant;
+    computing it here would redo the [S, K] reduction for every bucket).
     """
     del n_obs
     with jax.named_scope(SCOPE_GRAM):
         gathered = factors[indices]
     return _gram_solve_implicit(
-        gathered, values, yty, reg, alpha, rank, unroll, factors.dtype
+        gathered, values, shared, reg, alpha, rank, unroll, factors.dtype
     )
 
 
-def _sharded_block_body(idx, values, n_obs, opp_local, yty, reg, alpha,
+def _sharded_block_body(idx, values, n_obs, opp_local, shared, reg, alpha,
                         implicit, rank, unroll):
     """Per-device half-step for one bucket with MODEL-SHARDED factors.
 
     Runs inside shard_map over the full ("data", "model") mesh. Each
     device holds opp_local = its model-axis shard of the opposite factor
     matrix ([S/m, K], replicated across the data axis) and the full local
-    data-shard of the bucket's CSR rows. ``yty`` (implicit mode) arrives
-    replicated from the caller -- it is bucket-invariant and was formerly
-    re-psum'd here per bucket. The ALX block exchange:
+    data-shard of the bucket's CSR rows. ``shared`` (implicit mode:
+    ``_shared_gram``'s pair) arrives replicated from the caller -- it is
+    bucket-invariant and was formerly re-psum'd here per bucket. The ALX
+    block exchange:
 
     1. gather local hits only (out-of-shard indices -- including the
        padding sentinel, which is out of EVERY shard -- contribute zeros);
@@ -541,7 +648,7 @@ def _sharded_block_body(idx, values, n_obs, opp_local, yty, reg, alpha,
         val_s = jax.lax.dynamic_slice_in_dim(values, mi * rows, rows, 0)
     if implicit:
         return _gram_solve_implicit(
-            g, val_s, yty, reg, alpha, rank, unroll, opp_local.dtype
+            g, val_s, shared, reg, alpha, rank, unroll, opp_local.dtype
         )
     with jax.named_scope(SCOPE_SOLVE):
         n_s = jax.lax.dynamic_slice_in_dim(n_obs, mi * rows, rows, 0)
@@ -611,30 +718,70 @@ def normal_equation_bytes(rows: int, rank: int, unroll: bool) -> int:
     return int(rows * rank * rank * 4 * solve_gram_arrays(rank, unroll))
 
 
+def dual_block_bytes(rows: int, solved: int, pad_len: int, rank: int,
+                     itemsize: int, unroll: bool) -> int:
+    """HBM bytes of a block in the dual form (``_dual_solve_implicit``) at its
+    peak, ``rows`` gathered and ``solved`` of them solved on one device: the
+    float32 whitened rows, ``[solved, L, K]``, beside either the gathered
+    rows they are made from or the ``[solved, L, L]`` systems made from them,
+    whichever is more (the gathered rows are dead once whitened). A TPU pads
+    ``L`` to whole rows of 128 lanes, so a system costs what a whitened row
+    does; the unrolled solve works out of the one array, the blocked solve
+    and LAPACK's hold a second. Compiled for a described v5e the temporaries
+    are 0.92 to 1.12 times this (tests/test_tpu_compile.py, PR 29)."""
+    whitened = gathered_bytes(solved, pad_len, rank, 4)
+    held = 1 if solve_path(pad_len, unroll) == "unrolled" else 2
+    systems = held * solved * pad_len * round_up(pad_len, LANES) * 4
+    return whitened + max(gathered_bytes(rows, pad_len, rank, itemsize), systems)
+
+
+#: Most rows of a dual block a TPU solves in one chunk: the blocked solve's
+#: own 4,096, whichever solve the ``[L, L]`` systems take. One v5e, a dual
+#: block of the MSD rank-128 cell alone (a device's solved rows of each), ms a
+#: call by rows a chunk (PERF.md section 6, PR 29): 76,848 x 56 slots 1,011
+#: 59.8, 2,022 54.7, 4,045 56.8, 7,685 77.3, 15,370 87.7; 115,584 x 24 1,022
+#: 25.2, 2,027 20.4, 3,986 23.1, 7,705 20.7, 28,896 27.5, whole 37.8; 52,320 x
+#: 16 2,012 5.1, 4,025 5.6, 7,474 7.4, 13,080 10.5, whole 7.3: a chunk's
+#: whitened rows and systems stay in the chip's fast memory up to a few
+#: thousand rows, and a block worked whole also compiles three times as long
+#: (44.9 s against 16.7 at 24 slots).
+DUAL_CHUNK_ROWS = BLOCKED_SOLVE_ROWS
+
+
 def block_plan(platform: str, rows: int, pad_len: int, rank: int,
-               itemsize: int, model_shards: int = 1) -> int:
+               itemsize: int, model_shards: int = 1, implicit: bool = False) -> int:
     """In how many equal row chunks ONE block is worked (1: whole) -- the one
     statement of the rule, asked at trace time by everything that has a
     block's static shape: ``rows`` on one device of the data axis,
-    ``pad_len``, the factors' ``rank`` and ``itemsize``, and ``model_shards``
+    ``pad_len``, the factors' ``rank`` and ``itemsize``, ``model_shards``
     (the model layout solves ``rows / model_shards`` of them on each device; 1
-    otherwise).
+    otherwise) and whether the half-step is ``implicit``.
 
     It counts what the block allocates -- the gathered rows
     (``gathered_bytes``), the float32 Grams and what the solve holds beside
-    them (``normal_equation_bytes``) -- and answers the number of chunks that
-    brings one chunk's share under ``EINSUM_GATHER_BUDGET_BYTES``, and, where
-    the rows take the blocked solve (a TPU mesh above rank 32), the rows a
-    device solves in one chunk under ``ops.linalg.BLOCKED_SOLVE_ROWS``. Rows
-    are independent, so a chunk's rows come out as they would from the whole
-    block."""
+    them (``normal_equation_bytes``) or, where the block ``takes_dual``, what
+    that form holds (``dual_block_bytes``) -- and answers the number of chunks
+    that brings one chunk's share under ``EINSUM_GATHER_BUDGET_BYTES``, and,
+    on a TPU mesh, the rows a device solves in one chunk under
+    ``ops.linalg.BLOCKED_SOLVE_ROWS`` where they take the blocked solve
+    (above rank 32) and under ``DUAL_CHUNK_ROWS`` where the block is dual.
+    Rows are independent, so a chunk's rows come out as they would from the
+    whole block."""
     unroll = platform == "tpu"
     solved = rows // model_shards
-    allocated = (gathered_bytes(rows, pad_len, rank, itemsize)
-                 + normal_equation_bytes(solved, rank, unroll))
+    most_rows = None
+    if takes_dual(implicit, pad_len, rank):
+        allocated = dual_block_bytes(rows, solved, pad_len, rank, itemsize, unroll)
+        if unroll:
+            most_rows = DUAL_CHUNK_ROWS
+    else:
+        allocated = (gathered_bytes(rows, pad_len, rank, itemsize)
+                     + normal_equation_bytes(solved, rank, unroll))
+        if solve_path(rank, unroll) == "blocked":
+            most_rows = BLOCKED_SOLVE_ROWS
     chunks = -(-allocated // EINSUM_GATHER_BUDGET_BYTES)
-    if solve_path(rank, unroll) == "blocked":
-        chunks = max(chunks, -(-solved // BLOCKED_SOLVE_ROWS))
+    if most_rows:
+        chunks = max(chunks, -(-solved // most_rows))
     return max(1, chunks)
 
 
@@ -652,7 +799,7 @@ def _in_row_chunks(step, chunks: int, slices: int = 1, sharded: bool = False):
     padding invariant): the zero row the caller appended to a replicated
     table, or with ``sharded`` tables the first index past the last shard's
     rows, which is out of EVERY shard."""
-    def chunked(idx, val, n_obs, table, yty, reg, alpha):
+    def chunked(idx, val, n_obs, table, shared, reg, alpha):
         run = idx.shape[0] // slices
         size = round_up(-(-run // chunks), 8)
         zero = slices * table.shape[0] if sharded else table.shape[0] - 1
@@ -667,7 +814,7 @@ def _in_row_chunks(step, chunks: int, slices: int = 1, sharded: bool = False):
             )
 
         out = jax.lax.map(
-            lambda chunk: step(*chunk, table, yty, reg, alpha),
+            lambda chunk: step(*chunk, table, shared, reg, alpha),
             (split(idx, zero), split(val, 0), split(n_obs, 0)),
         )
         return out.reshape((chunks * size, out.shape[-1]))[:run]
@@ -679,16 +826,18 @@ def block_paths(data, config: ALSConfig, mesh) -> dict[str, int]:
     """How ``data``'s blocks (both sides; resident or streamed) are worked in
     the program built for (mesh, config): ``"blocks"`` of them, of which
     ``"chunked"`` in row chunks, the most chunks of any under
-    ``"max_chunks"`` (1: every block whole), and ``"blocked_solve"`` blocks
-    whose rows take the blocked Cholesky solve (``ops.linalg.solve_path``:
-    every block of a TPU mesh above rank 32, else none). The same
-    ``block_plan`` the program asks at trace time, on the same shapes."""
+    ``"max_chunks"`` (1: every block whole), ``"dual_solve"`` blocks whose
+    rows are solved in the dual form (``takes_dual``: implicit blocks short
+    against the rank) and ``"blocked_solve"`` blocks whose systems take the
+    blocked Cholesky solve (``ops.linalg.solve_path``: on a TPU mesh, those
+    wider than 32, be they ``[K, K]`` or a dual block's ``[L, L]``). The same
+    rules the program asks at trace time, on the same shapes."""
     platform = mesh.devices.flat[0].platform
     d = mesh.shape["data"]
     m = mesh.shape.get("model", 1) if config.factor_sharding == "model" else 1
     itemsize = jnp.dtype(config.dtype).itemsize
-    paths = {"blocks": 0, "chunked": 0, "max_chunks": 1, "blocked_solve": 0}
-    blocked = solve_path(config.rank, platform == "tpu") == "blocked"
+    paths = {"blocks": 0, "chunked": 0, "max_chunks": 1, "blocked_solve": 0,
+             "dual_solve": 0}
     for side in (data.by_row, data.by_col):
         specs = getattr(side, "specs", None)  # a streamed side's blocks
         if specs is not None:
@@ -698,11 +847,15 @@ def block_paths(data, config: ALSConfig, mesh) -> dict[str, int]:
             shapes = [(r, b.indices.shape[1]) for r, b in zip(rows, side.blocks)]
         for rows_b, pad_len in shapes:
             chunks = block_plan(platform, rows_b // d, pad_len, config.rank,
-                                itemsize, m)
+                                itemsize, m, config.implicit)
+            dual = takes_dual(config.implicit, pad_len, config.rank)
+            width = pad_len if dual else config.rank
             paths["blocks"] += 1
             paths["chunked"] += chunks > 1
             paths["max_chunks"] = max(paths["max_chunks"], chunks)
-            paths["blocked_solve"] += blocked
+            paths["dual_solve"] += dual
+            paths["blocked_solve"] += (
+                solve_path(width, platform == "tpu") == "blocked")
     return paths
 
 
@@ -711,13 +864,14 @@ def _half_steps(mesh, implicit: bool, rank: int, factor_axis: str):
     block as the program that holds it is traced.
 
     Returns ``pick(idx, factors) -> step``, with ``step(idx, values, n_obs,
-    factors, yty, reg, alpha) -> rows``. ``block_plan`` decides the row chunks
+    factors, shared, reg, alpha) -> rows`` (``shared``: ``_shared_gram``'s
+    pair). ``block_plan`` decides the row chunks
     from the block's shape on one device (rows split over the data axis in
     both layouts). With replicated factors a block worked whole is left to
     GSPMD; the model-sharded body exchanges over ``model`` and a chunked block
     loops over its device's own rows, so those go through an explicit
-    shard_map. The explicit tail drops ``yty`` and ``alpha`` (a dummy and a
-    scalar).
+    shard_map. The explicit tail drops ``shared`` and ``alpha`` (dummies and
+    a scalar).
     """
     P = PartitionSpec
     platform = mesh.devices.flat[0].platform
@@ -729,10 +883,10 @@ def _half_steps(mesh, implicit: bool, rank: int, factor_axis: str):
     model = factor_axis == "model"
     slices = mesh.shape["model"] if model else 1
 
-    def einsum_step(idx, val, n_obs, table, yty, reg, alpha):
+    def einsum_step(idx, val, n_obs, table, shared, reg, alpha):
         if implicit:
             return _half_step_implicit(
-                idx, val, n_obs, table, yty, reg, alpha, rank, unroll
+                idx, val, n_obs, table, shared, reg, alpha, rank, unroll
             )
         return _half_step_explicit(idx, val, n_obs, table, reg, rank, unroll)
 
@@ -759,7 +913,7 @@ def _half_steps(mesh, implicit: bool, rank: int, factor_axis: str):
     def pick(idx, factors):
         return build(block_plan(
             platform, idx.shape[0] // mesh.shape["data"], idx.shape[1], rank,
-            factors.dtype.itemsize, slices,
+            factors.dtype.itemsize, slices, implicit,
         ))
 
     return pick
@@ -807,8 +961,9 @@ def _build_iteration(mesh, rank: int, implicit: bool,
 
     Each bucket's half-step is the einsum tail, whole or in row chunks as
     ``block_plan`` says from its static shape as the program is traced
-    (``_half_steps``). Implicit mode's ``yty`` is computed ONCE per half-step
-    here (bucket-invariant) and fed to every bucket's solve.
+    (``_half_steps``). Implicit mode's ``yty`` and the dual form's whitening
+    matrix (``_shared_gram``) are computed ONCE per half-step here
+    (bucket-invariant) and fed to every bucket's solve.
 
     Factor buffers are donated: each iteration updates in place instead
     of reallocating.
@@ -826,14 +981,6 @@ def _build_iteration(mesh, rank: int, implicit: bool,
 
     pick = _half_steps(mesh, implicit, rank, factor_axis)
 
-    def side_yty(opp_real):
-        """Global factor Gram of one side (implicit mode), hoisted out of
-        the per-bucket loop; explicit mode feeds a dummy the steps drop."""
-        if implicit:
-            with jax.named_scope(SCOPE_YTY):
-                return _factors_yty(opp_real)
-        return jnp.zeros((rank, rank), jnp.float32)
-
     if factor_axis == "model":
         fsh = NamedSharding(mesh, P("model"))
 
@@ -843,12 +990,12 @@ def _build_iteration(mesh, rank: int, implicit: bool,
                 # out of every shard, so the full sharded [S, K] Gram is
                 # the implicit global term (GSPMD psums it once per side)
                 with jax.named_scope(SCOPE_ASSEMBLE):
-                    yty = side_yty(opp)
+                    shared = _shared_gram(opp, reg, implicit)
                 outs = []
                 for b, (idx, val, n_obs) in enumerate(blocks):
                     with jax.named_scope(SCOPE_BUCKET.format(b)):
                         step = pick(idx, opp)
-                        outs.append(step(idx, val, n_obs, opp, yty, reg, alpha))
+                        outs.append(step(idx, val, n_obs, opp, shared, reg, alpha))
                 with jax.named_scope(SCOPE_ASSEMBLE):
                     if len(outs) == 1:
                         # reshard P(("data","model")) -> P("model"): the
@@ -901,13 +1048,13 @@ def _build_iteration(mesh, rank: int, implicit: bool,
                 opp_full = jax.lax.with_sharding_constraint(
                     _append_zero_row(opp), rep
                 )
-                yty = side_yty(opp_full[:-1])
+                shared = _shared_gram(opp_full[:-1], reg, implicit)
             outs = []
             for b, (idx, val, n_obs) in enumerate(blocks):
                 with jax.named_scope(SCOPE_BUCKET.format(b)):
                     step = pick(idx, opp_full)
                     outs.append(
-                        step(idx, val, n_obs, opp_full, yty, reg, alpha)
+                        step(idx, val, n_obs, opp_full, shared, reg, alpha)
                     )
             with jax.named_scope(SCOPE_ASSEMBLE):
                 out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
@@ -1195,12 +1342,14 @@ def als_fit(
                 "als_fit: platform=%s devices=%d mesh_data=%d mesh_model=%d"
                 " factor_sharding=%s blocks=%d"
                 " blocks_chunked=%d max_chunks=%d blocked_solve=%d"
+                " dual_solve=%d"
                 " first_call_s=%.2f (trace + compile, or cache load)",
                 mesh.devices.flat[0].platform, mesh.devices.size,
                 mesh.shape["data"], mesh.shape.get("model", 1),
                 config.factor_sharding, paths["blocks"],
                 paths["chunked"], paths["max_chunks"],
-                paths["blocked_solve"], time.perf_counter() - first_call_t0,
+                paths["blocked_solve"], paths["dual_solve"],
+                time.perf_counter() - first_call_t0,
             )
         if telemetry is not None:
             # per-half-step resolution lives inside one jitted program;
@@ -1254,7 +1403,7 @@ class _StreamPrograms:
 
     ``prep`` runs ONCE per half-step (the loop-invariant hoist the J006
     lint encodes): it materializes the opposite side's replicated
-    ``[S+1, K]`` gather table and the implicit-mode YtY Gram, so the
+    ``[S+1, K]`` gather table and implicit mode's ``_shared_gram``, so the
     per-block python loop re-ships NOTHING invariant -- each block step
     moves only that block's streams plus two 4-byte scalars (offset,
     uniform value). ``step(has_values)`` solves one block's rows and
@@ -1272,17 +1421,11 @@ class _StreamPrograms:
         rep = NamedSharding(mesh, P())
         pick = _half_steps(mesh, implicit, rank, factor_axis)
 
-        def side_yty(opp):
-            if implicit:
-                with jax.named_scope(SCOPE_YTY):
-                    return _factors_yty(opp)
-            return jnp.zeros((rank, rank), jnp.float32)
-
         if factor_axis == "model":
             fsh = NamedSharding(mesh, P("model"))
             self.prep = jax.jit(
-                lambda opp: (opp, side_yty(opp)),
-                in_shardings=(fsh,), out_shardings=(fsh, rep),
+                lambda opp, reg: (opp, _shared_gram(opp, reg, implicit)),
+                in_shardings=(fsh, rep), out_shardings=(fsh, rep),
             )
             # single-array reshard P(("data","model")) -> P("model"): the
             # J005-safe assembly (no concat ever feeds a reshard)
@@ -1291,8 +1434,8 @@ class _StreamPrograms:
         else:
             fsh = row
             self.prep = jax.jit(
-                lambda f: (_append_zero_row(f), side_yty(f)),
-                in_shardings=(row,), out_shardings=(rep, rep),
+                lambda f, reg: (_append_zero_row(f), _shared_gram(f, reg, implicit)),
+                in_shardings=(row, rep), out_shardings=(rep, rep),
             )
             placed = lambda piece: piece
             buf_sh = row
@@ -1300,7 +1443,7 @@ class _StreamPrograms:
         self.factor_sharding = buf_sh
 
         def make_step(has_values: bool):
-            def block_update(buf, idx, val_in, n_obs, opp, yty, reg, alpha, off):
+            def block_update(buf, idx, val_in, n_obs, opp, shared, reg, alpha, off):
                 if has_values:
                     val = val_in
                 else:
@@ -1315,7 +1458,7 @@ class _StreamPrograms:
                     # [rows] vector materializes on device
                     n_obs = jnp.zeros((idx.shape[0],), jnp.float32)
                 step = pick(idx, opp)
-                rows = placed(step(idx, val, n_obs, opp, yty, reg, alpha))
+                rows = placed(step(idx, val, n_obs, opp, shared, reg, alpha))
                 return jax.lax.dynamic_update_slice(buf, rows, (off, 0))
 
             val_sh = row if has_values else rep
@@ -1514,11 +1657,11 @@ def als_fit_streamed(
         return prefetch_blocks(side.specs, produce, consumed)
 
     def solve_side(side, side_name, opp, buf):
-        opp_arg, yty = programs.prep(opp)
+        opp_arg, shared = programs.prep(opp, reg)
         for spec, (idx_d, val_d, nobs_d) in feed(side, side_name):
             step = programs.step(spec.const is None)
             buf = step(
-                buf, idx_d, val_d, nobs_d, opp_arg, yty, reg, alpha,
+                buf, idx_d, val_d, nobs_d, opp_arg, shared, reg, alpha,
                 np.int32(spec.offset),
             )
             stats.h2d_scalar_bytes += 4  # the block offset scalar

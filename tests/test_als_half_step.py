@@ -79,10 +79,10 @@ def _half_step_rows(indices, values, table, implicit):
     rank = table.shape[1]
     pick = als._half_steps(local_mesh(1, 1), implicit, rank, "replicated")
     n_obs = jnp.asarray((indices != table.shape[0] - 1).sum(axis=1), jnp.float32)
-    yty = als._factors_yty(table[:-1]) if implicit else jnp.zeros((rank, rank))
+    shared = als._shared_gram(table[:-1], jnp.float32(REG), implicit)
     idx = jnp.asarray(indices)
     out = jax.jit(pick(idx, table))(
-        idx, jnp.asarray(values), n_obs, table, yty, jnp.float32(REG), jnp.float32(ALPHA))
+        idx, jnp.asarray(values), n_obs, table, shared, jnp.float32(REG), jnp.float32(ALPHA))
     return np.asarray(out, np.float32)
 
 
@@ -142,8 +142,8 @@ class TestAgainstFloat64:
         idx = jnp.asarray(rng.integers(0, s + 1, size=(20, l)).astype(np.int32))
         val = jnp.asarray(rng.random((20, l)).astype(np.float32))
         n_obs = jnp.asarray((np.asarray(idx) != s).sum(axis=1), jnp.float32)
-        yty = als._factors_yty(table[:-1])
-        args = (idx, val, n_obs, table, yty, jnp.float32(REG), jnp.float32(ALPHA))
+        shared = als._shared_gram(table[:-1], jnp.float32(REG))
+        args = (idx, val, n_obs, table, shared, jnp.float32(REG), jnp.float32(ALPHA))
         step = als._half_steps(local_mesh(1, 1), implicit, k, "replicated")(idx, table)
         whole = jax.jit(step)(*args)
         cut = jax.jit(als._in_row_chunks(step, 3))(*args)
@@ -193,7 +193,8 @@ class TestFitInChunks:
         cfg = ALSConfig(rank=6, iterations=2, reg=0.01, seed=1, implicit=implicit,
                         alpha=10.0, dtype=dtype)
         whole, paths, _ = self._fit(skewed, cfg, shards)
-        assert paths == {"blocks": 2, "chunked": 0, "max_chunks": 1, "blocked_solve": 0}
+        assert paths == {"blocks": 2, "chunked": 0, "max_chunks": 1, "blocked_solve": 0,
+                         "dual_solve": 0}  # rank 6: no block is short against it
         monkeypatch.setattr(als, "EINSUM_GATHER_BUDGET_BYTES", 1 << 16)
         cut, paths, _ = self._fit(skewed, cfg, shards)
         assert paths["chunked"] == 2 and paths["max_chunks"] >= 3
@@ -266,7 +267,8 @@ class TestBlockRule:
         n = len(data.by_row.blocks) + len(data.by_col.blocks)
         assert block_paths(data, cfg, local_mesh(1, 1)) == {
             "blocks": n, "chunked": 0, "max_chunks": 1,  # every block in one piece
-            "blocked_solve": 0}                           # a CPU mesh solves by LAPACK
+            "blocked_solve": 0,                           # a CPU mesh solves by LAPACK
+            "dual_solve": 0}                              # explicit
 
     def test_als_fit_logs_how_the_blocks_are_worked(self, synthetic, caplog):
         n_u, n_i, uu, ii, rr = synthetic

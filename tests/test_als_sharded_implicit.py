@@ -44,12 +44,20 @@ def relative_error(got, want) -> float:
 @pytest.fixture(scope="module")
 def plays():
     """300 users x 200 songs, every pair at most once, play counts >= 1 with
-    a heavy tail, and seeded N(0, 1/sqrt(K)) tables for both sides."""
+    a heavy tail, and seeded N(0, 1/sqrt(K)) tables for both sides. Eight
+    users have 80 songs or more and six songs 90 listeners or more, the
+    others at most 32: packed in two buckets a side, the long one is solved
+    in the primal form and the short one (24 and 32 slots against rank 128)
+    in the dual form, so every program here holds both."""
     rng = np.random.default_rng(26)
-    n_users, n_items, n_edges = 300, 200, 4000
-    pairs = rng.choice(n_users * n_items, size=n_edges, replace=False)
+    n_users, n_items = 300, 200
+    pairs = np.unique(np.concatenate(
+        [rng.choice(n_users * n_items, size=4000, replace=False)]
+        + [u * n_items + rng.choice(n_items, size=80, replace=False) for u in range(8)]
+        + [rng.choice(n_users, size=90, replace=False) * n_items + i for i in range(6)]))
+    rng.shuffle(pairs)
     users, items = pairs // n_items, pairs % n_items
-    counts = np.minimum(rng.zipf(2.25, size=n_edges), 500).astype(np.float32)
+    counts = np.minimum(rng.zipf(2.25, size=pairs.size), 500).astype(np.float32)
     tables = [rng.standard_normal((n, RANK)).astype(np.float32) / np.sqrt(RANK)
               for n in (n_users, n_items)]
     return n_users, n_items, users, items, counts, tables
@@ -107,7 +115,7 @@ TOLERANCE = {"float32": 1e-3, "bfloat16": 4e-3}
 def test_sharded_implicit_iteration_against_the_reference(plays, dtype):
     n_users, n_items, users, items, counts, (u0, v0) = plays
     got_u, got_v, paths = _one_iteration(plays, dtype, (2, 2), "model")
-    assert paths["blocks"] == 4
+    assert paths["blocks"] == 4 and paths["dual_solve"] == 2
     stored = lambda a: np.asarray(jnp.asarray(a, jnp.dtype(dtype)), np.float32)  # noqa: E731
     want_u = reference_half_step(users, items, counts, stored(v0), n_users)
     want_v = reference_half_step(items, users, counts, got_u, n_items)
@@ -136,7 +144,7 @@ def test_a_chunked_block_equals_the_block_worked_whole(plays, mesh_shape, shardi
     worked in several row chunks, each row comes out bit for bit as from the
     block whole. float32 tables, every layout."""
     whole_u, whole_v, whole = _one_iteration(plays, "float32", mesh_shape, sharding)
-    assert whole["chunked"] == 0 and whole["max_chunks"] == 1
+    assert whole["chunked"] == 0 and whole["max_chunks"] == 1 and whole["dual_solve"] == 2
     cut_u, cut_v, cut = _one_iteration(plays, "float32", mesh_shape, sharding,
                                        budget=1 << 20)
     assert cut["chunked"] >= 3 and cut["max_chunks"] >= 3  # of 4 blocks
@@ -161,7 +169,8 @@ def test_two_by_two_equals_one_device(plays):
 
 
 GIB = 1 << 30
-#: (rows on one data shard, pad_len, rank, itemsize, model shards) -> row chunks
+#: (rows on one data shard, pad_len, rank, itemsize, model shards[, implicit])
+#: -> row chunks
 RULE = {
     # als-ml20m-r16.train-steady's eight blocks (PERF.md section 4): whole
     **{f"ml20m_r16_{rows}x{length}": ((rows, length, 16, 2, 1), 1)
@@ -173,13 +182,17 @@ RULE = {
     "ml20m_r128_51632x48": ((51_632, 48, 128, 2, 1), 13),
     "r128_125000x24": ((125_000, 24, 128, 2, 1), 31),
     # als-msd-r128.train-sharded's eight blocks (PERF.md section 4), a data
-    # shard's rows, two model shards: each device solves half of them
-    **{f"msd_{side}_{rows}x{length}": ((rows, length, 128, 2, 2), chunks)
+    # shard's rows, two model shards: each device solves half of them. The
+    # cell is implicit, so the four short blocks are dual (PR 29): on a TPU a
+    # dual block goes 4,096 solved rows a chunk too, so the chunks are the same
+    **{f"msd_{side}_{rows}x{length}": ((rows, length, 128, 2, 2, True), chunks)
        for side, rows, length, chunks in [
            ("users", 39_680, 256, 5), ("users", 85_152, 136, 11),
            ("users", 153_696, 56, 19), ("users", 231_168, 24, 29),
            ("songs", 34_656, 256, 5), ("songs", 14_512, 128, 2),
            ("songs", 38_496, 48, 5), ("songs", 104_640, 16, 13)]},
+    # a dual block at rank 16 (8 slots: a fold-in's short histories)
+    "r16_dual_100000x8": ((100_000, 8, 16, 4, 1, True), 25),
     # the unrolled solve (rank <= 32) holds a copy of the Gram with the rows
     # on the lanes, and the chip pads a 32-wide row of either to 128 lanes;
     # its rows are not capped
@@ -192,8 +205,8 @@ RULE = {
 
 @pytest.mark.parametrize("case", sorted(RULE))
 def test_the_rule(case):
-    (rows, pad_len, rank, itemsize, model_shards), want = RULE[case]
-    assert block_plan("tpu", rows, pad_len, rank, itemsize, model_shards) == want
+    shape, want = RULE[case]
+    assert block_plan("tpu", *shape) == want
 
 
 def test_the_rule_counts_gathered_rows_grams_and_factors():
@@ -232,6 +245,6 @@ def test_the_fold_in_asks_the_same_rule(plays, implicit):
     args = (v0, users, items, counts, n_users, config)
     whole = foldin.fold_in_users(*args)
     als.EINSUM_GATHER_BUDGET_BYTES = 1 << 20
-    rows, pad_len = 512, 32  # the pow2 ladder over 300 users with at most 28 songs
-    assert block_plan("cpu", rows, pad_len, RANK, 4) > 3
+    rows, pad_len = 512, 128  # the pow2 ladder over 300 users with at most 93 songs
+    assert block_plan("cpu", rows, pad_len, RANK, 4, implicit=implicit) > 3
     assert np.array_equal(foldin.fold_in_users(*args), whole)

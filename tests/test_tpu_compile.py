@@ -220,7 +220,10 @@ def test_the_rank_128_solve_is_blocked_and_holds_what_the_rule_counts(
     """``als-msd-r128.train-sharded``'s largest user block (79,360 x 256,
     implicit, rank 128, bf16 tables over ``model``) on data=2 x model=2:
     neither of ``lax.linalg.cholesky`` + ``cho_solve``'s custom calls is left
-    in the program, and its temporaries stay within what ``block_plan``
+    in the rows' solves (the one 128 x 128 factorisation under ``assemble/
+    yty`` is the whitening matrix of the item half-step, whose 16-slot block
+    is dual; the user half-step's block is primal and drops its own as dead
+    code), and its temporaries stay within what ``block_plan``
     counted for one of the block's 5 chunks (3,968 rows a device to solve:
     its gathered rows, its Grams, what the blocked solve holds beside them),
     and a third more: the exchange over ``model`` holds the gathered rows
@@ -230,14 +233,18 @@ def test_the_rank_128_solve_is_blocked_and_holds_what_the_rule_counts(
     config = als.ALSConfig(rank=128, implicit=True, alpha=40.0, reg=0.1,
                            dtype="bfloat16", factor_sharding="model")
     rows, length = 79_360, 256
-    chunks = als.block_plan("tpu", rows // 2, length, 128, 2, 2)
+    chunks = als.block_plan("tpu", rows // 2, length, 128, 2, 2, implicit=True)
     counted = (als.gathered_bytes(rows // 2, length, 128, 2)
                + als.normal_equation_bytes(rows // 4, 128, unroll=True)) / chunks
     assert chunks == 5
     compiled = _one_chip_iteration(
         topo, config, [(rows, length)], [(1_024, 16)], mesh_shape=(2, 2))
     text = compiled.as_text()
-    assert "Cholesky" not in text and "InvertDiagBlocksLowerTriangular" not in text
+    factorisations = [line for line in text.splitlines()
+                      if 'custom_call_target="Cholesky"' in line
+                      or "InvertDiagBlocksLowerTriangular" in line]
+    assert all(f"/{als.SCOPE_ASSEMBLE}/{als.SCOPE_YTY}/" in line for line in factorisations)
+    assert text.count('custom_call_target="Cholesky"') == 1
     assert "all-to-all" in text
     temp_size = compiled.memory_analysis().temp_size_in_bytes
     print(f"msd r128 largest user block: temp_size {temp_size} bytes, counted {counted:.0f}")
@@ -321,14 +328,57 @@ def test_a_budget_sized_chunk_holds_what_the_rule_counted(
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     idx, table = sds((rows, pad_len), jnp.int32), sds((27_001, rank), dtype)
     step = als._half_steps(mesh, implicit, rank, "replicated")(idx, table)
+    gram = sds((rank, rank), jnp.float32)
     compiled = jax.jit(step).lower(
         idx, sds((rows, pad_len), jnp.float32), sds((rows,), jnp.float32), table,
-        sds((rank, rank), jnp.float32), sds((), jnp.float32), sds((), jnp.float32),
+        (gram, gram) if implicit else gram, sds((), jnp.float32), sds((), jnp.float32),
     ).compile()
     assert "tpu_custom_call" not in compiled.as_text()
     temp_size = compiled.memory_analysis().temp_size_in_bytes
     print(f"rank {rank} {rows} x {pad_len}: temp_size {temp_size} bytes, counted {counted}")
     assert 0.8 * counted <= temp_size <= 1.3 * counted
+
+
+@pytest.mark.parametrize("pad_len", [24, 56])
+def test_a_dual_chunk_holds_what_the_rule_counted(
+    topo, one_chip, no_persistent_cache, monkeypatch, pad_len
+):
+    """One chunk of a dual block (implicit, rank 128, bf16 tables) against
+    ``dual_block_bytes``. 56 slots (systems the blocked solve takes) at the
+    4,096 rows ``block_plan`` cuts a dual block to on a TPU. 24 slots (systems
+    the unrolled solve takes) with that cap taken out, so that the bytes bind
+    as they do off the TPU: 24 KiB a row, 174,760 rows to the budget. The
+    compiled program's temporaries are what was counted (the float32 whitened
+    rows beside the lane-padded ``[L, L]`` systems, two of them or one),
+    within the band the primal chunks were read in (PERF.md section 7): 0.92
+    and 1.10 times it."""
+    from predictionio_tpu.parallel import als
+
+    rank = 128
+    assert als.takes_dual(True, pad_len, rank)
+    if pad_len == 24:
+        monkeypatch.setattr(als, "DUAL_CHUNK_ROWS", 1 << 30)
+        rows = als.EINSUM_GATHER_BUDGET_BYTES // (24 * 1_024) // 8 * 8
+    else:
+        rows = als.DUAL_CHUNK_ROWS
+    assert als.block_plan("tpu", rows, pad_len, rank, 2, implicit=True) == 1
+    assert als.block_plan("tpu", rows + 8, pad_len, rank, 2, implicit=True) == 2
+    counted = als.dual_block_bytes(rows, rows, pad_len, rank, 2, unroll=True)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    idx, table = sds((rows, pad_len), jnp.int32), sds((192_289, rank), jnp.bfloat16)
+    gram = sds((rank, rank), jnp.float32)
+    step = als._half_steps(mesh, True, rank, "replicated")(idx, table)
+    compiled = jax.jit(step).lower(
+        idx, sds((rows, pad_len), jnp.float32), sds((rows,), jnp.float32), table,
+        (gram, gram), sds((), jnp.float32), sds((), jnp.float32),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text and "Cholesky" not in text
+    assert f"f32[{rows},{rank},{rank}]" not in text  # no [K, K] Gram of a row
+    temp_size = compiled.memory_analysis().temp_size_in_bytes
+    print(f"dual {rows} x {pad_len}: temp_size {temp_size} bytes, counted {counted}")
+    assert 0.9 * counted <= temp_size <= 1.2 * counted
 
 
 #: instructions of an entry computation that move or name data and do no
