@@ -303,6 +303,12 @@ class Smoke:
             facts.update(
                 first_call_s=float(said["first_call_s"]),
                 **{k: int(said[k]) for k in ("blocks", "blocks_chunked", "max_chunks", "blocked_solve")})
+        seq = re.search(r"^.*seq_fit: (platform=.*)$", text, re.M)
+        if seq:
+            said = dict(re.findall(r"(\w+)=(\S+)", seq.group(1)))
+            facts.update(backbone=said["backbone"], steps=int(said["steps"]),
+                         first_loss=float(said["first_loss"]),
+                         last_loss=float(said["last_loss"]))
         timings = re.search(r"stage timings: (.*)$", text, re.M)
         if timings:
             facts["stage_timings"] = timings.group(1).strip()
@@ -537,6 +543,45 @@ class Smoke:
                   p50_ms_smoke_reading=round(p50, 2), server=info["device"],
                   kernel=kernel, **check)
 
+    def phase_train_sequence_looped(self) -> None:
+        """The sequence template's looped backbone through ``pio train`` at the
+        published widths (2 layers of them; a rehearsal cuts the widths): a
+        few steps on one batch of users, seen again every epoch."""
+        import numpy as np
+
+        t0 = time.time()
+        out = pio("app_new_seq", ["app", "new", "SmokeSeqApp"], self.env, 120)
+        app_id = int(re.search(r"ID: (\d+)", out).group(1))
+        rng = np.random.default_rng(SEED)
+        lengths = rng.integers(20, 300, size=32)
+        users = np.repeat(np.arange(32), lengths)
+        items = (np.minimum(rng.random(users.size) ** 2.2, 0.999999) * 2_000).astype(np.int64)
+        events = os.path.join(self.basedir, "seq_events.jsonl")
+        write_events(events, users, items, np.ones(users.size, np.float32))
+        pio("import_seq", ["import", "--appid", str(app_id), "--input", events], self.env, 300)
+        os.unlink(events)
+        widths = ({"hiddenSize": 64, "numHeads": 4, "headDim": 16, "ffnDim": 176}
+                  if self.rehearsal else
+                  {"hiddenSize": 2048, "numHeads": 16, "headDim": 128, "ffnDim": 5632})
+
+        def edit(v):
+            v["datasource"]["params"]["appName"] = "SmokeSeqApp"
+            v["preparator"]["params"]["maxLen"] = 256
+            v["algorithms"][0]["params"].update(
+                backbone="looped", numLayers=2, utSteps=4, batchSize=32, epochs=6,
+                learningRate=3e-4, **widths)
+            v["sparkConf"] = {"pio.mesh_shape": [1, 1], "pio.mesh_axes": ["data", "seq"]}
+
+        seq_dir = self.engine_dir("sequence_looped", "sequence", edit)
+        facts = self.train("train_sequence_looped", seq_dir, 900)
+        if facts.get("backbone") != "looped" or facts.get("steps") != 6:
+            raise PhaseFailed(f"train_sequence_looped: not six steps of the looped backbone: {facts}")
+        first, last = facts["first_loss"], facts["last_loss"]
+        if not (first == first and last == last and last < first < float("inf")):
+            raise PhaseFailed(f"train_sequence_looped: loss not finite and falling: {first} -> {last}")
+        self.line("train_sequence_looped", t0, **facts, users=32, events=int(users.size),
+                  **widths)
+
     def phase_sharded(self) -> None:
         self.phase_device(with_status=False)
         if self.device["count"] != 4:
@@ -596,6 +641,7 @@ def main(argv=None) -> int:
                 smoke.phase_device, smoke.phase_compile_cache, smoke.phase_ingest,
                 smoke.phase_train_als, smoke.phase_als_full_width,
                 smoke.phase_serve_als, smoke.phase_train_serve_ncf,
+                smoke.phase_train_sequence_looped,
             ]
         for phase in phases:
             try:

@@ -28,6 +28,7 @@ from predictionio_tpu.controller import (
 from predictionio_tpu.controller.base import SanityCheck
 from predictionio_tpu.data.store import PEventStore
 from predictionio_tpu.models._als_common import score_buffer_rows, topk_item_scores
+from predictionio_tpu.models.sequence.looped import LoopedConfig
 from predictionio_tpu.models.sequence.model import (
     SASRecConfig,
     score_next_items,
@@ -157,18 +158,24 @@ class SequencePreparator(Preparator):
     """
 
     def prepare(self, ctx, data: SequencesData) -> PackedSequences:
+        from predictionio_tpu.obs.trace import global_tracer
+
         max_len = self.params.get_or("maxLen", 64)
-        matrix = np.zeros((len(data.sequences), max_len), np.int32)
-        for row, seq in enumerate(data.sequences):
-            tail = seq[-max_len:] + 1
-            matrix[row, : len(tail)] = tail
+        with global_tracer().span("seq.pack") as span:
+            matrix = np.zeros((len(data.sequences), max_len), np.int32)
+            for row, seq in enumerate(data.sequences):
+                tail = seq[-max_len:] + 1
+                matrix[row, : len(tail)] = tail
+            span.set_attr("users", matrix.shape[0])
+            span.set_attr("slots", matrix.size)
+            span.set_attr("filled_slots", int(np.count_nonzero(matrix)))
         return PackedSequences(matrix=matrix, data=data)
 
 
 @dataclass
 class SASRecModel:
     params: dict
-    config: SASRecConfig
+    config: SASRecConfig | LoopedConfig   # the backbone it was trained with
     item_ids: list[str]
     item_index: dict[str, int]
     histories: dict[str, np.ndarray]   # user id -> shifted (+1) id sequence
@@ -184,10 +191,55 @@ class SASRecModel:
 
 
 class SASRecAlgorithm(TPUAlgorithm):
-    """Params: embedDim, numHeads, numBlocks, ffnDim, dropout, learningRate,
-    batchSize, epochs, seed, maxLen (must match the preparator's), and
-    seqParallel ("ring" | "ulysses") selecting the sequence-parallel
-    attention strategy when the mesh has a >1 ``seq`` axis."""
+    """Params: ``backbone`` ("sasrec", the default, or "looped"); learningRate,
+    batchSize, epochs, seed, maxLen (must match the preparator's), attention
+    ("auto" | "flash" | "plain") and seqParallel ("ring" | "ulysses", the
+    sequence-parallel attention strategy when the mesh has a >1 ``seq`` axis)
+    for both. "sasrec" reads embedDim, numHeads, numBlocks, ffnDim, dropout;
+    "looped" (``models/sequence/looped.py``) reads hiddenSize, numHeads,
+    headDim, ffnDim, numLayers, utSteps, ropeTheta, rmsNormEps, exitBeta,
+    earlyExitThreshold."""
+
+    def _config(self, num_items: int, max_len: int):
+        p = self.params
+        backbone = p.get_or("backbone", "sasrec")
+        if backbone not in ("sasrec", "looped"):
+            raise ValueError(f"backbone={backbone!r}: want 'sasrec' or 'looped'")
+        shared = dict(
+            num_items=num_items,
+            max_len=max_len,
+            batch_size=p.get_or("batchSize", 256),
+            epochs=p.get_or("epochs", 10),
+            seed=p.get_or("seed", 0),
+            seq_parallel=p.get_or("seqParallel", "ring"),
+            attention=p.get_or("attention", "auto"),
+        )
+        if backbone == "looped":
+            d = LoopedConfig(num_items=num_items)  # the defaults, in one place
+            return LoopedConfig(
+                hidden_size=p.get_or("hiddenSize", d.hidden_size),
+                num_heads=p.get_or("numHeads", d.num_heads),
+                head_dim=p.get_or("headDim", d.head_dim),
+                ffn_dim=p.get_or("ffnDim", d.ffn_dim),
+                num_layers=p.get_or("numLayers", d.num_layers),
+                ut_steps=p.get_or("utSteps", d.ut_steps),
+                rope_theta=float(p.get_or("ropeTheta", d.rope_theta)),
+                rms_eps=float(p.get_or("rmsNormEps", d.rms_eps)),
+                exit_beta=float(p.get_or("exitBeta", d.exit_beta)),
+                early_exit_threshold=float(
+                    p.get_or("earlyExitThreshold", d.early_exit_threshold)),
+                learning_rate=p.get_or("learningRate", d.learning_rate),
+                **shared,
+            )
+        return SASRecConfig(
+            embed_dim=p.get_or("embedDim", 32),
+            num_heads=p.get_or("numHeads", 2),
+            num_blocks=p.get_or("numBlocks", 2),
+            ffn_dim=p.get_or("ffnDim", 64),
+            dropout=p.get_or("dropout", 0.0),
+            learning_rate=p.get_or("learningRate", 1e-3),
+            **shared,
+        )
 
     def train(self, ctx, prepared: PackedSequences) -> SASRecModel:
         p = self.params
@@ -199,21 +251,7 @@ class SASRecAlgorithm(TPUAlgorithm):
                 f"{prepared.matrix.shape[1]}; set both to the same value "
                 "(or drop the algorithm's)"
             )
-        config = SASRecConfig(
-            num_items=data.num_items,
-            max_len=prepared.matrix.shape[1],
-            embed_dim=p.get_or("embedDim", 32),
-            num_heads=p.get_or("numHeads", 2),
-            num_blocks=p.get_or("numBlocks", 2),
-            ffn_dim=p.get_or("ffnDim", 64),
-            dropout=p.get_or("dropout", 0.0),
-            learning_rate=p.get_or("learningRate", 1e-3),
-            batch_size=p.get_or("batchSize", 256),
-            epochs=p.get_or("epochs", 10),
-            seed=p.get_or("seed", 0),
-            seq_parallel=p.get_or("seqParallel", "ring"),
-            attention=p.get_or("attention", "auto"),
-        )
+        config = self._config(data.num_items, prepared.matrix.shape[1])
         history_mode = self.params.get_or("historyMode", "model")
         if history_mode not in ("model", "live"):
             # before the (expensive) training run, not after

@@ -17,6 +17,7 @@ design:
 
 from __future__ import annotations
 
+import logging
 from dataclasses import dataclass
 
 import flax.linen as nn
@@ -32,9 +33,13 @@ from predictionio_tpu.parallel.mesh import (
     one_step_in_flight,
     put_global,
 )
+from predictionio_tpu.models.sequence import looped
+from predictionio_tpu.models.sequence.looped import LoopedConfig
 from predictionio_tpu.ops.flash_attention import flash_attention
 from predictionio_tpu.parallel.ring_attention import plain_attention, ring_attention
 from predictionio_tpu.parallel.ulysses import ulysses_attention
+
+logger = logging.getLogger("pio.sequence")
 
 
 @dataclass(frozen=True)
@@ -76,9 +81,40 @@ class SASRecConfig:
         return self.num_items + 1  # +1 for the padding id 0
 
 
+def attend(q, k, v, pad_mask, mesh, attention: str, seq_parallel: str):
+    """Causal attention with the padded keys masked, q, k, v [B, T, H, D],
+    mesh-aware: ring or Ulysses attention when the mesh has a >1 ``seq``
+    axis, else the Pallas flash kernel or the materialized-score reference
+    (``attention``: "auto" | "flash" | "plain"). Both backbones call it."""
+    # the platform the program is built for: the mesh's, when there is one
+    backend = (
+        mesh.devices.flat[0].platform if mesh is not None
+        else jax.default_backend()
+    )
+    use_flash = attention == "flash" or (attention == "auto" and backend == "tpu")
+    if mesh is not None and mesh.shape.get("seq", 1) > 1:
+        if seq_parallel == "ulysses":
+            # ulysses gathers full sequences per chip, so the flash
+            # kernel slots in as its local attention
+            return ulysses_attention(q, k, v, mesh, axis_name="seq",
+                                     causal=True, mask=pad_mask,
+                                     use_flash=use_flash)
+        # ring attention IS the online softmax across shards; its
+        # per-step scores are already [Tl, Tl] blocks, so "flash"
+        # asks for nothing it does not already do
+        return ring_attention(q, k, v, mesh, axis_name="seq",
+                              causal=True, mask=pad_mask)
+    if use_flash:
+        # O(T*D) memory: scores never materialize (ops/flash_attention)
+        return flash_attention(
+            q, k, v, pad_mask, causal=True,
+            interpret=backend != "tpu",
+        )
+    return plain_attention(q, k, v, causal=True, mask=pad_mask)
+
+
 class _MultiHeadSelfAttention(nn.Module):
-    """Causal MHA whose score computation is mesh-aware: ring attention when
-    the mesh has a >1 ``seq`` axis, plain attention otherwise."""
+    """Causal MHA whose score computation is mesh-aware (:func:`attend`)."""
 
     config: SASRecConfig
     mesh: object = None
@@ -93,36 +129,7 @@ class _MultiHeadSelfAttention(nn.Module):
         q, k, v = jnp.split(qkv, 3, axis=-1)
         reshape = lambda a: a.reshape(b, t, h, head_dim)
         q, k, v = reshape(q), reshape(k), reshape(v)
-        mesh = self.mesh
-        # the platform the program is built for: the mesh's, when there is one
-        backend = (
-            mesh.devices.flat[0].platform if mesh is not None
-            else jax.default_backend()
-        )
-        use_flash = c.attention == "flash" or (
-            c.attention == "auto" and backend == "tpu"
-        )
-        if mesh is not None and mesh.shape.get("seq", 1) > 1:
-            if c.seq_parallel == "ulysses":
-                # ulysses gathers full sequences per chip, so the flash
-                # kernel slots in as its local attention
-                out = ulysses_attention(q, k, v, mesh, axis_name="seq",
-                                        causal=True, mask=pad_mask,
-                                        use_flash=use_flash)
-            else:
-                # ring attention IS the online softmax across shards; its
-                # per-step scores are already [Tl, Tl] blocks, so "flash"
-                # asks for nothing it does not already do
-                out = ring_attention(q, k, v, mesh, axis_name="seq",
-                                     causal=True, mask=pad_mask)
-        elif use_flash:
-            # O(T*D) memory: scores never materialize (ops/flash_attention)
-            out = flash_attention(
-                q, k, v, pad_mask, causal=True,
-                interpret=backend != "tpu",
-            )
-        else:
-            out = plain_attention(q, k, v, causal=True, mask=pad_mask)
+        out = attend(q, k, v, pad_mask, self.mesh, c.attention, c.seq_parallel)
         return nn.Dense(d, use_bias=False, name="proj")(out.reshape(b, t, d))
 
 
@@ -158,7 +165,7 @@ def _logits(params, hidden):
     return jnp.einsum("btd,vd->btv", hidden, table)
 
 
-def make_train_step(model: SASRec, optimizer):
+def _sasrec_loss(model: SASRec):
     def loss_fn(params, batch, rng):
         hidden = model.apply(
             {"params": params}, batch["seq"], deterministic=False,
@@ -168,18 +175,85 @@ def make_train_step(model: SASRec, optimizer):
         targets = batch["target"]                     # [B, T], 0 = no target
         mask = (targets > 0).astype(jnp.float32)
         ce = optax.softmax_cross_entropy_with_integer_labels(logits, targets)
-        return (ce * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+        return (ce * mask).sum() / jnp.maximum(mask.sum(), 1.0), {}
 
+    return loss_fn
+
+
+def make_train_step(loss_fn, optimizer):
+    """One optimizer step of ``loss_fn(params, batch, rng) -> (loss, aux)``."""
     def train_step(params, opt_state, batch, rng):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch, rng)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state, loss
+        (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            params, batch, rng)
+        with jax.named_scope(looped.SCOPE_OPTIMIZER):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+        return params, opt_state, loss, aux
 
     return train_step
 
 
+def _attention_of(config, mesh):
+    """``attend`` with the configuration's choices and the mesh bound."""
+    def attention(q, k, v, pad_mask):
+        return attend(q, k, v, pad_mask, mesh, config.attention, config.seq_parallel)
+
+    return attention
+
+
+def backbone_of(config, mesh):
+    """``(init, loss_fn)`` of the backbone ``config`` names: ``init(rng, t)``
+    gives the parameter tree, ``loss_fn(params, batch, rng)`` the objective
+    and what the fit's span reports of it."""
+    if isinstance(config, LoopedConfig):
+        return (lambda rng, t: looped.init_params(config, rng),
+                looped.make_loss(config, _attention_of(config, mesh)))
+    model = SASRec(config, mesh)
+    # dummy batch = one row per data-shard: shard_map needs divisibility
+    dp0 = max(mesh.shape.get("data", 1), 1)
+    return (lambda rng, t: model.init(rng, jnp.zeros((dp0, t), jnp.int32))["params"],
+            _sasrec_loss(model))
+
+
+def _tree_bytes(tree) -> int:
+    return sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
+
+
+def make_fit(config, mesh):
+    """What the fit runs, for ``train_sasrec`` and for a caller that steps it
+    itself: ``(init, place, step_fn, seq_shard)``. ``init(rng, t)`` draws the
+    parameters; ``place(params)`` puts them on the mesh and makes Adam's
+    state; ``step_fn(params, opt_state, batch, rng)`` is one jitted optimizer
+    step (both donated) returning ``(params, opt_state, loss, aux)``;
+    ``seq_shard`` is the sharding of a batch's ``seq`` and ``target``."""
+    init, loss_fn = backbone_of(config, mesh)
+    rep = NamedSharding(mesh, P())
+    dp_axis = "data" if "data" in mesh.axis_names else None
+    sp_axis = "seq" if "seq" in mesh.axis_names else None
+    seq_shard = NamedSharding(mesh, P(dp_axis, sp_axis))
+    optimizer = optax.adam(config.learning_rate)
+
+    def place(params):
+        # put_global/jitted-init: on multi-process meshes every rank holds
+        # identical params (same PRNGKey); placement and Adam-state creation
+        # must not touch non-addressable shards eagerly
+        params = jax.tree_util.tree_map(lambda a: put_global(a, rep), params)
+        # Adam's state replicated on the mesh like the parameters, its step
+        # count too: a count left off the mesh is another input type than the
+        # one a step returns, and the second step of every fit compiled again
+        return params, jax.jit(optimizer.init, out_shardings=rep)(params)
+
+    step_fn = jax.jit(
+        make_train_step(loss_fn, optimizer),
+        in_shardings=(rep, rep, {"seq": seq_shard, "target": seq_shard}, None),
+        out_shardings=(rep, rep, rep, rep),
+        donate_argnums=(0, 1),
+    )
+    return init, place, step_fn, seq_shard
+
+
 def train_sasrec(
-    config: SASRecConfig,
+    config,                  # SASRecConfig | LoopedConfig: picks the backbone
     sequences: np.ndarray,   # [N, T] int32 padded item ids (0 = pad)
     mesh,
     log_every: int = 0,
@@ -189,6 +263,8 @@ def train_sasrec(
     Inputs/targets are the sequence and its left-shift: position t predicts
     the item at t+1. The [N, T] matrix shards over (data, seq).
     """
+    from predictionio_tpu.obs.trace import global_tracer
+
     t = sequences.shape[1]
     if t != config.max_len:
         raise ValueError(f"sequences padded to {t}, config.max_len={config.max_len}")
@@ -196,28 +272,9 @@ def train_sasrec(
     if t % sp:
         raise ValueError(f"max_len={t} must divide over seq axis size {sp}")
 
-    model = SASRec(config, mesh)
+    init, place, step_fn, seq_shard = make_fit(config, mesh)
     rng = jax.random.PRNGKey(config.seed)
-    # dummy batch = one row per data-shard: shard_map needs divisibility
-    dp0 = max(mesh.shape.get("data", 1), 1)
-    params = model.init(rng, jnp.zeros((dp0, t), jnp.int32))["params"]
-    rep = NamedSharding(mesh, P())
-    dp_axis = "data" if "data" in mesh.axis_names else None
-    sp_axis = "seq" if "seq" in mesh.axis_names else None
-    seq_shard = NamedSharding(mesh, P(dp_axis, sp_axis))
-    # put_global/jitted-init: on multi-process meshes every rank holds
-    # identical params (same PRNGKey); placement and Adam-state creation
-    # must not touch non-addressable shards eagerly
-    params = jax.tree_util.tree_map(lambda a: put_global(a, rep), params)
-    optimizer = optax.adam(config.learning_rate)
-    opt_state = jax.jit(optimizer.init)(params)
-
-    step_fn = jax.jit(
-        make_train_step(model, optimizer),
-        in_shardings=(rep, None, {"seq": seq_shard, "target": seq_shard}, None),
-        out_shardings=(rep, None, rep),
-        donate_argnums=(0, 1),
-    )
+    params, opt_state = place(init(rng, t))
 
     inputs = sequences.astype(np.int32)
     targets = np.zeros_like(inputs)
@@ -228,38 +285,82 @@ def train_sasrec(
     dp = mesh.shape.get("data", 1)
     losses = []
     step = 0
-    for _ in range(config.epochs):
-        order = np_rng.permutation(n)
-        for start in range(0, n, config.batch_size):
-            take = order[start : start + config.batch_size]
-            usable = (take.size // dp) * dp
-            if not usable:
-                continue
-            take = take[:usable]
-            # identical permutation on every rank (same seed): put_global
-            # hands each process exactly its addressable (data, seq) shards
-            batch = {
-                "seq": put_global(inputs[take], seq_shard),
-                "target": put_global(targets[take], seq_shard),
-            }
-            params, opt_state, loss = step_fn(
-                params, opt_state, batch, jax.random.fold_in(rng, step)
-            )
-            one_step_in_flight(mesh, loss)
-            step += 1
-            if log_every and step % log_every == 0:
-                losses.append(float(loss))
-    check_steps_ran(step, n, dp, "sequence")
+    aux = {}
+    first_loss = loss = None
+    span_attrs = fit_attrs(config, _tree_bytes(params), _tree_bytes(opt_state))
+    with global_tracer().span("seq.fit", attrs=span_attrs) as span:
+        for _ in range(config.epochs):
+            order = np_rng.permutation(n)
+            for start in range(0, n, config.batch_size):
+                take = order[start : start + config.batch_size]
+                usable = (take.size // dp) * dp
+                if not usable:
+                    continue
+                take = take[:usable]
+                # identical permutation on every rank (same seed): put_global
+                # hands each process exactly its addressable (data, seq) shards
+                batch = {
+                    "seq": put_global(inputs[take], seq_shard),
+                    "target": put_global(targets[take], seq_shard),
+                }
+                params, opt_state, loss, aux = step_fn(
+                    params, opt_state, batch, jax.random.fold_in(rng, step)
+                )
+                one_step_in_flight(mesh, loss)
+                if first_loss is None:
+                    first_loss = loss
+                step += 1
+                if log_every and step % log_every == 0:
+                    losses.append(float(loss))
+        check_steps_ran(step, n, dp, "sequence")
+        span.set_attr("steps", step)
+        logger.info(
+            "seq_fit: platform=%s devices=%d backbone=%s steps=%d"
+            " first_loss=%.5f last_loss=%.5f",
+            mesh.devices.flat[0].platform, mesh.devices.size,
+            span_attrs["backbone"], step, float(first_loss), float(loss),
+        )
+        for name, value in aux.items():  # of the last step: the means
+            if value.ndim <= 1:
+                span.set_attr(name, np.asarray(fetch_global(value)).tolist())
     return jax.tree_util.tree_map(fetch_global, params), losses
 
 
-def _score_fn(config: SASRecConfig):
+def fit_attrs(config, param_bytes: int, opt_state_bytes: int) -> dict:
+    """What the fit's span says of the model it trains."""
+    attrs = {
+        "backbone": "looped" if isinstance(config, LoopedConfig) else "sasrec",
+        "param_bytes": param_bytes,
+        # weights, their gradients and the optimizer's moments
+        "state_bytes": 2 * param_bytes + opt_state_bytes,
+    }
+    if isinstance(config, LoopedConfig):
+        chunk = looped.head_chunk_of(config)
+        attrs.update(
+            layers=config.num_layers, passes=config.ut_steps,
+            rematerialised="layer" if config.remat else "nothing",
+            head=(f"chunks of {chunk} positions, recomputed" if chunk else
+                  "whole pass, recomputed"),
+        )
+    else:
+        attrs.update(layers=config.num_blocks, passes=1,
+                     rematerialised="nothing", head="whole")
+    return attrs
+
+
+def _score_fn(config):
     """Jitted forward + vocab projection in ONE program, cached per config.
 
     The old path dispatched the transformer forward and the [D] x [V, D]
     einsum as separate eager calls, paying a dispatch each, per query.
     """
     if config not in _SCORE_CACHE:
+        if isinstance(config, LoopedConfig):
+            attention = _attention_of(config, None)
+            _SCORE_CACHE[config] = jax.jit(
+                lambda params, seqs, last: looped.score_last(
+                    config, attention, params, seqs, last))
+            return _SCORE_CACHE[config]
         model = SASRec(config, None)
 
         @jax.jit
@@ -277,7 +378,7 @@ def _score_fn(config: SASRecConfig):
 _SCORE_CACHE: dict = {}
 
 
-def score_next_items_batch(params, config: SASRecConfig, prefixes) -> np.ndarray:
+def score_next_items_batch(params, config, prefixes) -> np.ndarray:
     """Scores over the item vocab for the next item after each prefix.
 
     ``prefixes``: list of 1-D id arrays (no padding); each uses its last
@@ -302,6 +403,6 @@ def score_next_items_batch(params, config: SASRecConfig, prefixes) -> np.ndarray
     return scores[:b, 1:]
 
 
-def score_next_items(params, config: SASRecConfig, prefix: np.ndarray) -> np.ndarray:
+def score_next_items(params, config, prefix: np.ndarray) -> np.ndarray:
     """Single-prefix convenience over :func:`score_next_items_batch`."""
     return score_next_items_batch(params, config, [prefix])[0]

@@ -14,7 +14,7 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PHASES = ["device", "compile_cache", "ingest", "train_als", "als_full_width",
-          "serve_als", "train_serve_ncf"]
+          "serve_als", "train_serve_ncf", "train_sequence_looped"]
 
 
 def _run(args, tmp_path, timeout, **env_overrides):
@@ -47,6 +47,8 @@ def test_rehearsal_reaches_every_phase_then_refuses_the_cpu(tmp_path):
     assert by_phase["train_serve_ncf"]["kernel"] == "interpreted"
     assert by_phase["compile_cache"]["cache_dir"] == str(tmp_path / "jax_cache")
     assert all(r["agrees"] for r in by_phase["als_full_width"]["runs"])
+    looped = by_phase["train_sequence_looped"]
+    assert looped["backbone"] == "looped" and looped["last_loss"] < looped["first_loss"]
 
 
 def test_without_a_chip_the_default_run_stops_at_the_device_phase(tmp_path):

@@ -441,3 +441,68 @@ def test_als_iteration_scopes_its_work(topo, no_persistent_cache, worked):
                 assert seen[(side, stage)] > 0, (side, stage, seen)
             else:  # the stages are in the chunk loops' bodies, not in the entry
                 assert re.search(rf'op_name="[^"]*{re.escape(side)}/bucket\d/while/body/[^"]*{stage}/', text)
+
+
+def _lowered_looped_step(topo, layers: int, rows: int, **how):
+    """One optimizer step of the looped backbone at Ouro-2.6B's widths on
+    ``rows`` rows of 256, lowered for one described chip."""
+    import optax
+
+    from predictionio_tpu.models.sequence import looped, model as seq_model
+
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "seq"))
+    config = looped.LoopedConfig(
+        num_items=49_151, max_len=256, hidden_size=2048, num_heads=16, head_dim=128,
+        ffn_dim=5632, num_layers=layers, ut_steps=4, batch_size=rows, **how)
+    _, _, step_fn, seq_shard = seq_model.make_fit(config, mesh)
+    rep = NamedSharding(mesh, P())
+    sds = lambda shape, dtype, sh: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)  # noqa: E731
+    params = jax.tree_util.tree_map(
+        lambda shape: sds(shape, jnp.float32, rep), looped.param_shapes(config),
+        is_leaf=lambda x: isinstance(x, tuple))
+    opt_state = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype, rep),
+        jax.eval_shape(optax.adam(config.learning_rate).init, params))
+    batch = {k: sds((rows, 256), jnp.int32, seq_shard) for k in ("seq", "target")}
+    return config, step_fn.lower(params, opt_state, batch, sds((2,), jnp.uint32, rep))
+
+
+def test_the_looped_step_compiles_at_the_published_widths_and_scopes_its_work(
+        topo, no_persistent_cache):
+    """One optimizer step of the sequence template's looped backbone at
+    Ouro-2.6B's widths (two layers, eight rows of 256): the flash kernel at
+    heads of 128 is there four times a pass (forward, the recomputed forward,
+    ``dq`` and ``dkv``), each call under its pass's ``attention`` scope as the
+    benchmark's reader takes an ``op_name`` apart, and the step fits the chip."""
+    import re
+
+    from benchmarks import scopes_seq
+
+    compiled = _lowered_looped_step(topo, 2, 8, head_chunk=1024)[1].compile()
+    assert compiled.memory_analysis().peak_memory_in_bytes < 8e9
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"',
+                       compiled.as_text())
+    assert len(calls) == 16
+    kinds = [(scopes_seq.parse_scope(c), scopes_seq.kernel_kind(c)) for c in calls]
+    for t in range(1, 5):
+        mine = sorted(kind for scope, kind in kinds if scope == (f"pass{t}", "attention"))
+        assert mine == ["backward", "backward", "forward", "forward"]
+
+
+@pytest.mark.parametrize("layers,fits", [(6, True), (8, False)], ids=["6-layers", "8-layers"])
+def test_the_sequence_cells_step_fits_the_chip_at_6_layers_and_not_at_8(
+        topo, no_persistent_cache, layers, fits):
+    """The step of ``ouro-2.6b-d8.train-histories`` (32 rows of 256 at the
+    published widths): at 6 layers it compiles at a peak under the chip's
+    15.75 GB, at the 8 the issue asked for the compiler refuses it for memory
+    (16.35 GB), which is why ``benchmarks/configs/ouro-2.6b-d8.json`` holds 6."""
+    from predictionio_tpu.models.sequence import looped
+
+    config, lowered = _lowered_looped_step(topo, layers, 32)
+    assert looped.head_chunk_of(config) == 2048
+    if fits:
+        peak = lowered.compile().memory_analysis().peak_memory_in_bytes
+        assert 13.5e9 < peak < 15.0e9, peak    # 14.23 GB
+    else:
+        with pytest.raises(Exception, match=r"RESOURCE_EXHAUSTED(.|\n)*hbm"):
+            lowered.compile()
