@@ -29,6 +29,7 @@ from predictionio_tpu.controller.base import SanityCheck
 from predictionio_tpu.data.store import PEventStore
 from predictionio_tpu.models._als_common import score_buffer_rows, topk_item_scores
 from predictionio_tpu.models.sequence.looped import LoopedConfig
+from predictionio_tpu.ops.flash_attention import tiles_worked
 from predictionio_tpu.models.sequence.model import (
     SASRecConfig,
     score_next_items,
@@ -169,6 +170,10 @@ class SequencePreparator(Preparator):
             span.set_attr("users", matrix.shape[0])
             span.set_attr("slots", matrix.size)
             span.set_attr("filled_slots", int(np.count_nonzero(matrix)))
+            # what the flash kernel walks of these rows (causal, its block)
+            worked, tiles = tiles_worked(matrix > 0, causal=True)
+            span.set_attr("attention_tiles", tiles)
+            span.set_attr("attention_tiles_worked", worked)
         return PackedSequences(matrix=matrix, data=data)
 
 

@@ -14,10 +14,51 @@ softmax runs at the ring level (``parallel.ring_attention``); within a
 shard, this kernel keeps the memory footprint O(T * D) so per-chip
 sequences can grow until HBM, not VMEM-score-matrix, is the limit.
 
-Shapes follow plain_attention: q, k, v [B, T, H, D]; optional key-validity
-``mask`` [B, T]; causal masking over absolute positions. On CPU test
-backends the kernels run in interpret mode (tests pin fwd+grad against
-plain_attention).
+Shapes follow plain_attention: q, k, v [B, T, H, D]; optional ``mask``
+[B, T]; causal masking over absolute positions. On CPU test backends the
+kernels run in interpret mode (tests pin fwd+grad against plain_attention
+at the valid positions).
+
+The contract of ``mask``. It marks the positions of a self-attention row
+that hold something. An invalid position is neither key nor query: a
+(query, key) pair counts when the key is valid, the query is valid and,
+under ``causal``, the query is at or after the key. The output row of an
+invalid position is exactly 0 and no gradient passes through it, as query
+or as key, wherever a block edge falls (the in-tile mask takes the query's
+validity as well as the key's). ``plain_attention`` gives an invalid query
+the average of the row's valid keys instead; nothing reads that: both
+backbones pass ``pad_mask = seq > 0``, mask the loss at those positions
+and mask them as keys of the next layer, and ``parallel/ulysses.py`` hands
+the kernel whole rows with the same mask. ``mask=None`` is every position.
+
+Only the tiles that can hold a counting pair are worked. From the mask (a
+traced value: a new batch compiles nothing) plain XLA operations reduce,
+per row, the first and the last BLOCK-wide block that holds a valid
+position (:func:`_block_bounds`), and scalar prefetch hands both to the
+three programs in SMEM, where they are loop bounds:
+
+- forward and ``dq``, program (b, h, qi): key blocks ``first ..
+  min(qi, last)`` under ``causal`` (``first .. last`` without), in place
+  of every key block; a query block outside ``first .. last`` runs no loop
+  and writes zeros (and the logsumexp a row with no key gets);
+- ``dkv``, program (b, h, ki): query blocks ``max(ki, first) .. last``
+  (``first .. last`` without ``causal``); a key block outside
+  ``first .. last`` runs no loop and writes zeros.
+
+A program works 4, 2 or 1 heads of its row (:func:`_heads_per_program`,
+from the shape: what divides the heads and keeps the blocks within a VMEM
+budget). The bounds are the row's, so its heads walk the same tiles, and a
+loop body holds one tile of each head: where the skipping leaves a program
+a single tile, the heads are the independent work the scheduler overlaps
+(alone, one tile's chain of three matmuls waits on itself).
+
+A tile outside those bounds holds no counting pair, so it adds exactly
+nothing to the output at a valid position, to the logsumexp there or to
+any gradient: leaving it out is the same result, not an approximation.
+The bounds drop whole tiles only; the in-tile ``where(valid, ...)`` stays,
+so a mask with holes is still right (a wholly invalid block between two
+valid ones is walked and contributes zeros). :func:`tiles_worked` counts
+the tiles by the same rule on the host.
 
 Real-hardware layout constraints (learned the hard way -- interpret mode
 checks none of this):
@@ -29,6 +70,11 @@ checks none of this):
 - Row operands (mask, lse, delta) carry a singleton middle axis --
   [B, 1, T] / [B*H, 1, T] -- so their (1, T)-shaped blocks match the
   array's own last-two dims.
+- Mosaic has no cheap turn of a lane vector into a column, so the query
+  side gets its validity as an operand of its own in column form,
+  [B, T, 1] blocked (1, BQ, 1), beside the key side's [B, 1, T]; both stay
+  two-dimensional up to the tile's mask (a one-dimensional vector re-expanded
+  a tile cost a fifth of the three programs' time on the chip).
 - Mosaic cannot do dynamic SUBLANE (row) indexing inside a kernel
   ("dynamic load with unaligned indices"): all row selection lives in the
   BlockSpec index maps (per-program DMA), and in-kernel dynamic slices are
@@ -41,8 +87,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from predictionio_tpu.utils.jax_compat import pallas as pl, shape_struct
+from predictionio_tpu.utils.jax_compat import (
+    pallas as pl, pallas_tpu as pltpu, shape_struct,
+)
 
 _NEG = -1e30  # matches plain_attention's finite masked-score constant
 
@@ -50,150 +99,184 @@ BLOCK_Q = 128
 BLOCK_K = 128
 
 
-def _pos(n: int, offset):
-    # 2D iota (1D iota fails on TPU), squeezed after
-    return offset + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)[:, 0]
+def _block_bounds(maskp, block: int, xp=jnp):
+    """Per row of ``maskp`` [B, T] (T a multiple of ``block``): the first and
+    the last block that holds a valid position, [B] each; a row with none
+    gets (T // block, -1), under which every loop below is empty. ``xp``:
+    ``jnp`` for the programs' scalars, ``np`` for the host's count."""
+    b, t = maskp.shape
+    n = t // block
+    held = maskp.reshape(b, n, block).any(axis=2)
+    index = xp.arange(n, dtype=xp.int32)
+    first = xp.where(held, index, n).min(axis=1)
+    last = xp.where(held, index, -1).max(axis=1)
+    return first, last
+
+
+def tiles_worked(valid, causal: bool = True) -> tuple[int, int]:
+    """``(worked, tiles)`` of the rows ``valid`` [N, T] (NumPy, on the host):
+    the BLOCK x BLOCK tiles the three programs walk, by their own rule (a
+    tile whose query block and key block both lie between the row's first
+    and last block with a valid position and, under ``causal``, whose key
+    block is not after its query block), and every tile of the rows."""
+    valid = np.asarray(valid, bool)
+    n = -(-valid.shape[1] // BLOCK_Q)
+    padded = np.zeros((valid.shape[0], n * BLOCK_Q), bool)
+    padded[:, : valid.shape[1]] = valid
+    first, last = _block_bounds(padded, BLOCK_Q, np)
+    width = np.maximum(last - first + 1, 0).astype(np.int64)      # 0: no event
+    worked = width * (width + 1) // 2 if causal else width * width
+    return int(worked.sum()), valid.shape[0] * n * n
+
+
+def _valid(q_valid, q_off, k_valid, k_off, causal: bool):
+    """The pairs of a tile that count, [BQ, BK], from the query side's
+    validity as a column [BQ, 1], the key side's as a row [1, BK] and the
+    two blocks' first positions. Columns and rows stay two-dimensional from
+    the operand to here: a turn through a one-dimensional vector costs a
+    relayout a tile (2D iota too: a 1D iota fails on TPU)."""
+    valid = k_valid & q_valid
+    if causal:
+        bq, bk = q_valid.shape[0], k_valid.shape[1]
+        q_pos = q_off + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+        k_pos = k_off + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        valid = valid & (q_pos >= k_pos)
+    return valid
+
+
+def _dot(a, b, contract_a: int, contract_b: int):
+    return jax.lax.dot_general(
+        a, b, (((contract_a,), (contract_b,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _key_blocks(qi, first, last, causal: bool):
+    """``[lo, hi)``: the key blocks query block ``qi`` walks; empty where the
+    query block itself lies outside ``first .. last``."""
+    hi = jnp.minimum(qi, last) if causal else last
+    return first, jnp.where((qi >= first) & (qi <= last), hi + 1, first)
 
 
 def _fwd_kernel(
-    q_ref,      # [1, 1, BQ, D]
-    k_ref,      # [1, 1, T, D]
-    v_ref,      # [1, 1, T, D]
+    first_ref,  # [B] SMEM: the row's first block with a valid position
+    last_ref,   # [B] SMEM: its last
+    q_ref,      # [1, HB, BQ, D]
+    k_ref,      # [1, HB, T, D]
+    v_ref,      # [1, HB, T, D]
     mask_ref,   # [1, 1, T]
-    out_ref,    # [1, 1, BQ, D]
-    lse_ref,    # [1, 1, BQ]
+    qmask_ref,  # [1, BQ, 1]
+    out_ref,    # [1, HB, BQ, D]
+    lse_ref,    # [HB, 1, BQ]
     *, causal: bool, sm_scale: float, block_k: int,
 ):
-    qi = pl.program_id(2)
-    bq, d = q_ref.shape[2], q_ref.shape[3]
-    t = k_ref.shape[2]
-    q = q_ref[0, 0, :, :].astype(jnp.float32)
-    q_pos = _pos(bq, qi * bq)
+    b, qi = pl.program_id(0), pl.program_id(2)
+    hb, bq, d = q_ref.shape[1:]
+    q_valid = qmask_ref[0, :, :]
+    qs = [q_ref[0, hh, :, :].astype(jnp.float32) for hh in range(hb)]
 
     def body(kb, carry):
-        acc, m, l = carry
-        k_blk = k_ref[0, 0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, 0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        msk = mask_ref[0, 0, pl.ds(kb * block_k, block_k)]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale                                        # [BQ, BK]
-        k_pos = _pos(block_k, kb * block_k)
-        valid = msk[None, :]
-        if causal:
-            valid = valid & (q_pos[:, None] >= k_pos[None, :])
-        s = jnp.where(valid, s, _NEG)
-        m_new = jnp.maximum(m, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None]) * valid             # [BQ, BK]
-        corr = jnp.exp(m - m_new)
-        l = l * corr + p.sum(axis=1)
-        acc = acc * corr[:, None] + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return acc, m_new, l
+        keys = pl.ds(kb * block_k, block_k)
+        valid = _valid(q_valid, qi * bq, mask_ref[0, :, keys], kb * block_k, causal)
+        out = []
+        for hh, (acc, m, l) in enumerate(carry):
+            k_blk = k_ref[0, hh, keys, :].astype(jnp.float32)
+            v_blk = v_ref[0, hh, keys, :].astype(jnp.float32)
+            s = jnp.where(valid, _dot(qs[hh], k_blk, 1, 1) * sm_scale, _NEG)
+            m_new = jnp.maximum(m, s.max(axis=1))
+            p = jnp.exp(s - m_new[:, None]) * valid             # [BQ, BK]
+            corr = jnp.exp(m - m_new)
+            l = l * corr + p.sum(axis=1)
+            acc = acc * corr[:, None] + _dot(p, v_blk, 1, 0)
+            out.append((acc, m_new, l))
+        return tuple(out)
 
-    acc0 = jnp.zeros((bq, d), jnp.float32)
-    m0 = jnp.full((bq,), _NEG, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, t // block_k, body, (acc0, m0, l0))
-
-    out_ref[0, 0, :, :] = (acc / jnp.maximum(l, 1e-20)[:, None]).astype(out_ref.dtype)
-    lse_ref[0, 0, :] = m + jnp.log(jnp.maximum(l, 1e-20))
+    init = (jnp.zeros((bq, d), jnp.float32), jnp.full((bq,), _NEG, jnp.float32),
+            jnp.zeros((bq,), jnp.float32))
+    lo, hi = _key_blocks(qi, first_ref[b], last_ref[b], causal)
+    for hh, (acc, m, l) in enumerate(
+            jax.lax.fori_loop(lo, hi, body, (init,) * hb)):
+        out_ref[0, hh, :, :] = (
+            acc / jnp.maximum(l, 1e-20)[:, None]).astype(out_ref.dtype)
+        lse_ref[hh, 0, :] = m + jnp.log(jnp.maximum(l, 1e-20))
 
 
 def _dq_kernel(
-    q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
+    first_ref, last_ref,
+    q_ref, k_ref, v_ref, mask_ref, qmask_ref, do_ref, lse_ref, delta_ref,
     dq_ref,
     *, causal: bool, sm_scale: float, block_k: int,
 ):
     """dQ for one query block: dq = sum_kb (P o (dP - delta)) K * scale."""
-    qi = pl.program_id(2)
-    bq, d = q_ref.shape[2], q_ref.shape[3]
-    t = k_ref.shape[2]
-    q = q_ref[0, 0, :, :].astype(jnp.float32)
-    do = do_ref[0, 0, :, :].astype(jnp.float32)
-    lse = lse_ref[0, 0, :]
-    delta = delta_ref[0, 0, :]
-    q_pos = _pos(bq, qi * bq)
+    b, qi = pl.program_id(0), pl.program_id(2)
+    hb, bq, d = q_ref.shape[1:]
+    q_valid = qmask_ref[0, :, :]
+    heads = [
+        (q_ref[0, hh, :, :].astype(jnp.float32),
+         do_ref[0, hh, :, :].astype(jnp.float32),
+         lse_ref[hh, 0, :][:, None], delta_ref[hh, 0, :][:, None])
+        for hh in range(hb)
+    ]
 
-    def body(kb, dq):
-        k_blk = k_ref[0, 0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, 0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        msk = mask_ref[0, 0, pl.ds(kb * block_k, block_k)]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
-        k_pos = _pos(block_k, kb * block_k)
-        valid = msk[None, :]
-        if causal:
-            valid = valid & (q_pos[:, None] >= k_pos[None, :])
-        p = jnp.where(valid, jnp.exp(s - lse[:, None]), 0.0)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta[:, None]) * sm_scale
-        return dq + jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    def body(kb, dqs):
+        keys = pl.ds(kb * block_k, block_k)
+        valid = _valid(q_valid, qi * bq, mask_ref[0, :, keys], kb * block_k, causal)
+        out = []
+        for hh, (q, do, lse, delta) in enumerate(heads):
+            k_blk = k_ref[0, hh, keys, :].astype(jnp.float32)
+            v_blk = v_ref[0, hh, keys, :].astype(jnp.float32)
+            s = _dot(q, k_blk, 1, 1) * sm_scale
+            p = jnp.where(valid, jnp.exp(s - lse), 0.0)
+            ds = p * (_dot(do, v_blk, 1, 1) - delta) * sm_scale
+            out.append(dqs[hh] + _dot(ds, k_blk, 1, 0))
+        return tuple(out)
 
-    dq = jax.lax.fori_loop(0, t // block_k, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0, 0, :, :] = dq.astype(dq_ref.dtype)
+    lo, hi = _key_blocks(qi, first_ref[b], last_ref[b], causal)
+    dqs = jax.lax.fori_loop(
+        lo, hi, body, (jnp.zeros((bq, d), jnp.float32),) * hb)
+    for hh, dq in enumerate(dqs):
+        dq_ref[0, hh, :, :] = dq.astype(dq_ref.dtype)
 
 
 def _dkv_kernel(
-    q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
+    first_ref, last_ref,
+    q_ref, k_ref, v_ref, mask_ref, qmask_ref, do_ref, lse_ref, delta_ref,
     dk_ref, dv_ref,
     *, causal: bool, sm_scale: float, block_q: int,
 ):
-    """dK/dV for one key block: loop over query blocks."""
-    ki = pl.program_id(2)
-    bk, d = k_ref.shape[2], k_ref.shape[3]
-    t = q_ref.shape[2]
-    k_blk = k_ref[0, 0, :, :].astype(jnp.float32)
-    v_blk = v_ref[0, 0, :, :].astype(jnp.float32)
-    msk = mask_ref[0, 0, pl.ds(ki * bk, bk)]
-    k_pos = _pos(bk, ki * bk)
+    """dK/dV for one key block: loop over the query blocks that can hold a
+    counting pair with it."""
+    b, ki = pl.program_id(0), pl.program_id(2)
+    first, last = first_ref[b], last_ref[b]
+    hb, bk, d = k_ref.shape[1:]
+    k_valid = mask_ref[0, :, pl.ds(ki * bk, bk)]
+    heads = [(k_ref[0, hh, :, :].astype(jnp.float32),
+              v_ref[0, hh, :, :].astype(jnp.float32)) for hh in range(hb)]
 
     def body(qb, carry):
-        dk, dv = carry
-        q = q_ref[0, 0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, 0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.ds(qb * block_q, block_q)]
-        delta = delta_ref[0, 0, pl.ds(qb * block_q, block_q)]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
-        q_pos = _pos(block_q, qb * block_q)
-        valid = msk[None, :]
-        if causal:
-            valid = valid & (q_pos[:, None] >= k_pos[None, :])
-        p = jnp.where(valid, jnp.exp(s - lse[:, None]), 0.0)   # [BQ, BK]
-        dv = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta[:, None]) * sm_scale
-        dk = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return dk, dv
+        rows = pl.ds(qb * block_q, block_q)
+        valid = _valid(qmask_ref[0, rows, :], qb * block_q, k_valid, ki * bk, causal)
+        out = []
+        for hh, ((k_blk, v_blk), (dk, dv)) in enumerate(zip(heads, carry)):
+            q = q_ref[0, hh, rows, :].astype(jnp.float32)
+            do = do_ref[0, hh, rows, :].astype(jnp.float32)
+            lse = lse_ref[hh, 0, rows][:, None]
+            delta = delta_ref[hh, 0, rows][:, None]
+            s = _dot(q, k_blk, 1, 1) * sm_scale
+            p = jnp.where(valid, jnp.exp(s - lse), 0.0)         # [BQ, BK]
+            dv = dv + _dot(p, do, 0, 0)
+            ds = p * (_dot(do, v_blk, 1, 1) - delta) * sm_scale
+            dk = dk + _dot(ds, q, 0, 0)
+            out.append((dk, dv))
+        return tuple(out)
 
-    dk0 = jnp.zeros((bk, d), jnp.float32)
-    dv0 = jnp.zeros((bk, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(0, t // block_q, body, (dk0, dv0))
-    dk_ref[0, 0, :, :] = dk.astype(dk_ref.dtype)
-    dv_ref[0, 0, :, :] = dv.astype(dv_ref.dtype)
+    zero = jnp.zeros((bk, d), jnp.float32)
+    lo = jnp.maximum(ki, first) if causal else first
+    hi = jnp.where((ki >= first) & (ki <= last), last + 1, lo)
+    for hh, (dk, dv) in enumerate(
+            jax.lax.fori_loop(lo, hi, body, ((zero, zero),) * hb)):
+        dk_ref[0, hh, :, :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, hh, :, :] = dv.astype(dv_ref.dtype)
 
 
 def _pad_t(x, t_padded):
@@ -205,20 +288,77 @@ def _pad_t(x, t_padded):
     return jnp.pad(x, widths)
 
 
-def _specs(b_dim, t, h_dim, d, bq):
-    """(index-mapped) block specs shared by the three kernels.
+#: what a program's double-buffered blocks may take of VMEM when heads are
+#: put together (the dkv program's, the largest of the three)
+HEADS_VMEM_BYTES = 4 << 20
+
+
+def _heads_per_program(h_dim: int, t: int, d: int, itemsize: int, block: int) -> int:
+    """Heads a program works: 4, 2 or 1, the most that divide the heads and
+    keep the dkv program's blocks (q and dO whole; k, v, dk, dv a block)
+    within HEADS_VMEM_BYTES. The bounds are a row's, so its heads walk the
+    same tiles: a loop body holds one tile of each, independent work for the
+    scheduler to overlap where the skipping leaves a program one tile."""
+    for hb in (4, 2):
+        blocks = 2 * hb * d * itemsize * (2 * t + 4 * block)
+        if h_dim % hb == 0 and blocks <= HEADS_VMEM_BYTES:
+            return hb
+    return 1
+
+
+def _specs(t, h_dim, d, bq, hb):
+    """(index-mapped) block specs shared by the three kernels; the grid is
+    (B, H // hb, T // bq), ``hb`` heads a program.
 
     Device tensors are [B, H, T, D]; row operands are [B, 1, T] (mask) and
-    [B*H, 1, T] (lse/delta), with all row selection in the index maps.
+    [B*H, 1, T] (lse/delta), the query side's validity a column [B, T, 1];
+    all row selection is in the index maps, which also receive (and ignore)
+    the two scalar-prefetch operands.
     """
-    q_spec = pl.BlockSpec((1, 1, bq, d), lambda b, h, i: (b, h, i, 0))
-    kv_spec = pl.BlockSpec((1, 1, t, d), lambda b, h, i: (b, h, 0, 0))
-    mask_spec = pl.BlockSpec((1, 1, t), lambda b, h, i: (b, 0, 0))
-    #: one query block of this (b, h)'s lse/delta row
-    row_blk_spec = pl.BlockSpec((1, 1, bq), lambda b, h, i: (b * h_dim + h, 0, i))
-    #: the full lse/delta row (dkv loops over all query blocks)
-    row_full_spec = pl.BlockSpec((1, 1, t), lambda b, h, i: (b * h_dim + h, 0, 0))
-    return q_spec, kv_spec, mask_spec, row_blk_spec, row_full_spec
+    groups = h_dim // hb
+    spec = lambda block, index: pl.BlockSpec(
+        block, lambda b, g, i, *_: index(b, g, i))
+    return {
+        "blk": spec((1, hb, bq, d), lambda b, g, i: (b, g, i, 0)),
+        "full": spec((1, hb, t, d), lambda b, g, i: (b, g, 0, 0)),
+        "mask": spec((1, 1, t), lambda b, g, i: (b, 0, 0)),
+        "qmask_blk": spec((1, bq, 1), lambda b, g, i: (b, i, 0)),
+        "qmask_full": spec((1, t, 1), lambda b, g, i: (b, 0, 0)),
+        #: one query block of these heads' lse/delta rows
+        "row_blk": spec((hb, 1, bq), lambda b, g, i: (b * groups + g, 0, i)),
+        #: their full lse/delta rows (dkv loops over the query blocks)
+        "row_full": spec((hb, 1, t), lambda b, g, i: (b * groups + g, 0, 0)),
+    }
+
+
+def _plan(q, mask, sm_scale):
+    """What the three calls of one attention share, from q [B, T, H, D] and
+    ``mask`` [B, T] (None: every position): the score scale, T padded to
+    whole blocks, the operands taken of the mask (the two block bounds, the
+    key side's row form, the query side's column form, padding invalid), the
+    block specs and the grid (BLOCK_Q == BLOCK_K: one grid for all three)."""
+    b, t, h, d = q.shape
+    scale = sm_scale if sm_scale is not None else d**-0.5
+    t_padded = -(-t // BLOCK_Q) * BLOCK_Q
+    if mask is None:
+        mask = jnp.ones((b, t), bool)
+    maskp = _pad_t(mask.astype(bool), t_padded)
+    masks = (*_block_bounds(maskp, BLOCK_Q), maskp[:, None, :], maskp[:, :, None])
+    hb = _heads_per_program(h, t_padded, d, q.dtype.itemsize, BLOCK_Q)
+    specs = _specs(t_padded, h, d, BLOCK_Q, hb)
+    return scale, t_padded, masks, specs, (b, h // hb, t_padded // BLOCK_Q)
+
+
+def _call(kernel, grid, in_specs, out_specs, out_shape, interpret):
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=grid,
+            in_specs=in_specs, out_specs=out_specs,
+        ),
+        out_shape=out_shape,
+        interpret=interpret,
+    )
 
 
 def _to_bhtd(x):
@@ -229,10 +369,12 @@ def _to_bhtd(x):
 def flash_attention(q, k, v, mask, causal=True, sm_scale=None, interpret=False):
     """Flash attention. q,k,v [B, T, H, D] -> [B, T, H, D].
 
-    ``mask``: [B, T] key-validity mask, or None for all-valid. Note rows
-    whose every key is masked come back ~0 (the flash/ring convention),
-    where ``plain_attention`` would return a uniform average -- such rows
-    are padding and must be loss-masked by the caller either way.
+    ``mask``: [B, T], the positions of each row that hold something, or
+    None for all of them. An invalid position is neither key nor query: its
+    output row is exactly 0 and no gradient passes through it (the module
+    docstring has the contract), where ``plain_attention`` would return an
+    average -- such positions are padding and must be loss-masked by the
+    caller either way.
     """
     out, _ = _flash_fwd(q, k, v, mask, causal, sm_scale, interpret)
     return out
@@ -240,29 +382,21 @@ def flash_attention(q, k, v, mask, causal=True, sm_scale=None, interpret=False):
 
 def _flash_forward(q, k, v, mask, causal, sm_scale, interpret):
     b, t, h, d = q.shape
-    scale = sm_scale if sm_scale is not None else d**-0.5
-    bq = bk = BLOCK_Q
-    t_padded = -(-t // bq) * bq
-    if mask is None:
-        mask = jnp.ones((b, t), bool)
+    scale, t_padded, (first, last, maskp, qmaskp), sp, grid = _plan(q, mask, sm_scale)
     qp, kp, vp = (_pad_t(x, t_padded) for x in (q, k, v))
-    maskp = _pad_t(mask.astype(bool), t_padded)[:, None, :]  # pad -> invalid
-
-    nq = t_padded // bq
-    q_spec, kv_spec, mask_spec, row_blk_spec, _ = _specs(b, t_padded, h, d, bq)
-    out, lse = pl.pallas_call(
+    out, lse = _call(
         functools.partial(
-            _fwd_kernel, causal=causal, sm_scale=scale, block_k=bk
+            _fwd_kernel, causal=causal, sm_scale=scale, block_k=BLOCK_K
         ),
-        grid=(b, h, nq),
-        in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
-        out_specs=[q_spec, row_blk_spec],
-        out_shape=[
+        grid,
+        [sp["blk"], sp["full"], sp["full"], sp["mask"], sp["qmask_blk"]],
+        [sp["blk"], sp["row_blk"]],
+        [
             _struct((b, h, t_padded, d), q.dtype, q),
             _struct((b * h, 1, t_padded), jnp.float32, q),
         ],
-        interpret=interpret,
-    )(_to_bhtd(qp), _to_bhtd(kp), _to_bhtd(vp), maskp)
+        interpret,
+    )(first, last, _to_bhtd(qp), _to_bhtd(kp), _to_bhtd(vp), maskp, qmaskp)
     return _to_bhtd(out)[:, :t], lse
 
 
@@ -282,61 +416,46 @@ def _flash_fwd(q, k, v, mask, causal, sm_scale, interpret):
 def _flash_bwd(causal, sm_scale, interpret, res, g):
     q, k, v, mask, out, lse = res
     b, t, h, d = q.shape
-    scale = sm_scale if sm_scale is not None else d**-0.5
-    bq = bk = BLOCK_Q
-    t_padded = -(-t // bq) * bq
-    if mask is None:
-        mask = jnp.ones((b, t), bool)
-        mask_grad = None
-    else:
-        import numpy as np
-
-        mask_grad = np.zeros(mask.shape, jax.dtypes.float0)
+    scale, t_padded, (first, last, maskp, qmaskp), sp, grid = _plan(q, mask, sm_scale)
+    mask_grad = (
+        None if mask is None else np.zeros(mask.shape, jax.dtypes.float0)
+    )
 
     # delta[b,h,i] = rowsum(dO o O): the softmax-jacobian correction term
     delta = jnp.einsum("bthd,bthd->bht", g.astype(jnp.float32),
                        out.astype(jnp.float32)).reshape(b * h, 1, t)
 
     qp, kp, vp, gp = (_pad_t(x, t_padded) for x in (q, k, v, g))
-    maskp = _pad_t(mask.astype(bool), t_padded)[:, None, :]
     lsep = lse  # already t_padded long: it never left the padded domain
     deltap = jnp.pad(delta, ((0, 0), (0, 0), (0, t_padded - t)))
 
-    nq = t_padded // bq
-    nk = t_padded // bk
-    q_spec, kv_spec, mask_spec, row_blk_spec, row_full_spec = _specs(
-        b, t_padded, h, d, bq
-    )
-    full_q = pl.BlockSpec((1, 1, t_padded, d), lambda b_, h_, i: (b_, h_, 0, 0))
-
     qt, kt, vt, gt = (_to_bhtd(x) for x in (qp, kp, vp, gp))
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal, sm_scale=scale, block_k=bk),
-        grid=(b, h, nq),
-        in_specs=[
-            q_spec, kv_spec, kv_spec, mask_spec, q_spec,
-            row_blk_spec, row_blk_spec,
+    dq = _call(
+        functools.partial(_dq_kernel, causal=causal, sm_scale=scale, block_k=BLOCK_K),
+        grid,
+        [
+            sp["blk"], sp["full"], sp["full"], sp["mask"], sp["qmask_blk"],
+            sp["blk"], sp["row_blk"], sp["row_blk"],
         ],
-        out_specs=q_spec,
-        out_shape=_struct((b, h, t_padded, d), q.dtype, q),
-        interpret=interpret,
-    )(qt, kt, vt, maskp, gt, lsep, deltap)
+        sp["blk"],
+        _struct((b, h, t_padded, d), q.dtype, q),
+        interpret,
+    )(first, last, qt, kt, vt, maskp, qmaskp, gt, lsep, deltap)
 
-    k_spec = pl.BlockSpec((1, 1, bk, d), lambda b_, h_, i: (b_, h_, i, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, sm_scale=scale, block_q=bq),
-        grid=(b, h, nk),
-        in_specs=[
-            full_q, k_spec, k_spec, mask_spec, full_q,
-            row_full_spec, row_full_spec,
+    dk, dv = _call(
+        functools.partial(_dkv_kernel, causal=causal, sm_scale=scale, block_q=BLOCK_Q),
+        grid,
+        [
+            sp["full"], sp["blk"], sp["blk"], sp["mask"], sp["qmask_full"],
+            sp["full"], sp["row_full"], sp["row_full"],
         ],
-        out_specs=[k_spec, k_spec],
-        out_shape=[
+        [sp["blk"], sp["blk"]],
+        [
             _struct((b, h, t_padded, d), k.dtype, k),
             _struct((b, h, t_padded, d), v.dtype, v),
         ],
-        interpret=interpret,
-    )(qt, kt, vt, maskp, gt, lsep, deltap)
+        interpret,
+    )(first, last, qt, kt, vt, maskp, qmaskp, gt, lsep, deltap)
 
     return (
         _to_bhtd(dq)[:, :t],

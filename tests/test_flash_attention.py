@@ -5,7 +5,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from predictionio_tpu.ops.flash_attention import flash_attention
+from predictionio_tpu.ops.flash_attention import (
+    BLOCK_Q, flash_attention, tiles_worked,
+)
 from predictionio_tpu.parallel.ring_attention import plain_attention
 
 
@@ -24,12 +26,14 @@ def _inputs(b=2, t=50, h=2, d=8, seed=0, masked=True):
 
 
 def _rows_with_valid_keys(mask, t, causal=True):
-    """Query rows that have >=1 valid causal key (defined output rows)."""
+    """Valid query rows that have >=1 valid causal key (the rows where the
+    kernel and plain_attention are held to each other: an invalid position
+    is no query to the kernel, and comes back 0)."""
     if mask is None:
         return np.ones(t, bool)
     m = np.asarray(mask)
     tri = np.tril(np.ones((t, t), bool)) if causal else np.ones((t, t), bool)
-    return (tri & m[:, None, :]).any(axis=-1)  # [B, T]
+    return (tri & m[:, None, :]).any(axis=-1) & m  # [B, T]
 
 
 class TestForwardParity:
@@ -124,3 +128,158 @@ class TestBackwardParity:
         g_plain = jax.grad(loss_plain, argnums=(0, 1, 2))(q, k, v)
         for gf, gp in zip(g_flash, g_plain):
             np.testing.assert_allclose(np.asarray(gf), np.asarray(gp), atol=1e-4)
+
+
+# -- tiles: the programs work only those that can hold a counting pair ------
+
+def _layout(name: str, t: int) -> np.ndarray:
+    """One row's validity [t] by name (t is two or three 128-wide blocks)."""
+    pos = np.arange(t)
+    return {
+        "events_first": pos < 150,            # the preparator's layout
+        "events_first_short": pos < 70,       # under half a two-block row
+        "padding_first": pos >= t - 150,      # the layout of the tests above
+        "block_edge": pos < 128,              # ends exactly on a block edge
+        "one_event": pos == 0,
+        "one_event_last": pos == t - 1,
+        "none": np.zeros(t, bool),
+        "full": np.ones(t, bool),
+        "hole": (pos < 200) & ~((pos >= 40) & (pos < 90)),
+        "block_hole": (pos < 100) | (pos >= 2 * 128 + 10) if t > 256
+                      else (pos < 100) | (pos >= 128 + 60),
+    }[name]
+
+
+LAYOUTS = ("events_first", "events_first_short", "padding_first", "block_edge",
+           "one_event", "one_event_last", "none", "full", "hole", "block_hole")
+
+
+def _counting_pairs(valid: np.ndarray, causal: bool) -> np.ndarray:
+    """[T, T] (query, key): both valid and, under causal, key not after."""
+    pairs = valid[:, None] & valid[None, :]
+    return np.tril(pairs) if causal else pairs
+
+
+def _assert_matches_plain_at_valid(valid, h, causal, seed):
+    """Forward and dq, dk, dv of ``valid`` [B, T] rows of ``h`` heads against
+    plain_attention at the valid positions; exactly 0, output and gradient,
+    at the invalid ones. The cotangent at invalid positions is NOT zeroed for
+    the kernel: it has to stop it itself."""
+    rng = np.random.default_rng(seed)
+    mask = jnp.asarray(valid)
+    q, k, v, w = (jnp.asarray(rng.normal(size=(*valid.shape, h, 8)), jnp.float32)
+                  for _ in range(4))
+    out, vjp = jax.vjp(
+        lambda q, k, v: flash_attention(q, k, v, mask, causal=causal,
+                                        interpret=True), q, k, v)
+    want, want_vjp = jax.vjp(
+        lambda q, k, v: plain_attention(q, k, v, causal=causal, mask=mask),
+        q, k, v)
+    np.testing.assert_allclose(np.asarray(out)[valid], np.asarray(want)[valid],
+                               atol=3e-5)
+    assert not np.asarray(out)[~valid].any()
+    for got, ref, name in zip(vjp(w), want_vjp(w * mask[:, :, None, None]), "qkv"):
+        got, ref = np.asarray(got), np.asarray(ref)
+        np.testing.assert_allclose(got[valid], ref[valid], atol=1e-4,
+                                   err_msg=f"d{name}")
+        assert not got[~valid].any(), f"d{name} at an invalid position"
+
+
+class TestTilesSkipped:
+    @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+    @pytest.mark.parametrize("t", [256, 300], ids=["two_blocks", "three_blocks"])
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    def test_forward_and_grads_match_plain_at_valid(self, layout, t, causal):
+        """A row of ``layout`` beside a full row (so the bounds differ by row)."""
+        valid = np.stack([_layout(layout, t), np.ones(t, bool)])
+        _assert_matches_plain_at_valid(valid, 2, causal, LAYOUTS.index(layout) + t)
+
+    def test_no_mask_is_every_position(self):
+        q, k, v, _ = _inputs(b=1, t=300, h=1, d=8, masked=False)
+        got = flash_attention(q, k, v, None, causal=True, interpret=True)
+        want = plain_attention(q, k, v, causal=True)
+        np.testing.assert_allclose(got, want, atol=3e-5)
+
+    def test_a_new_batch_compiles_nothing(self):
+        """The bounds are traced values: another mask, the same program."""
+        fn = jax.jit(lambda q, k, v, m: flash_attention(
+            q, k, v, m, causal=True, interpret=True))
+        q, k, v, _ = _inputs(b=2, t=256, h=1, d=8, masked=False)
+        for layout in ("events_first", "full", "none"):
+            fn(q, k, v, jnp.asarray(np.stack([_layout(layout, 256)] * 2)))
+        assert fn._cache_size() == 1
+
+    @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+    @pytest.mark.parametrize("t", [256, 300, 64], ids=["t256", "t300", "t64"])
+    def test_tiles_worked_is_the_brute_force_count(self, t, causal):
+        """Over the layouts whose valid blocks are contiguous the kernel's
+        rule is the count of tiles that hold a counting pair; a wholly invalid
+        block between two valid ones is walked (its tiles contribute zeros)."""
+        n = -(-t // BLOCK_Q)
+        for layout in LAYOUTS:
+            valid = np.zeros(n * BLOCK_Q, bool)
+            valid[:t] = _layout(layout, max(t, 256))[:t]
+            pairs = _counting_pairs(valid, causal)
+            brute = int(pairs.reshape(n, BLOCK_Q, n, BLOCK_Q).any(axis=(1, 3)).sum())
+            worked, tiles = tiles_worked(valid[None, :t], causal)
+            assert tiles == n * n
+            if layout == "block_hole" and t == 300:
+                assert worked > brute
+            else:
+                assert worked == brute, layout
+
+    def test_bounds_are_the_rule_tiles_worked_counts(self):
+        """The device's bounds and the host's count are one rule."""
+        from predictionio_tpu.ops.flash_attention import _block_bounds
+
+        t = 3 * BLOCK_Q
+        valid = np.stack([_layout(name, 300) for name in LAYOUTS])
+        valid = np.pad(valid, ((0, 0), (0, t - 300)))
+        first, last = (np.asarray(x) for x in _block_bounds(jnp.asarray(valid), BLOCK_Q))
+        for row, (lo, hi) in enumerate(zip(first, last)):
+            width = max(hi - lo + 1, 0)
+            assert tiles_worked(valid[row:row + 1], True)[0] == width * (width + 1) // 2
+            assert tiles_worked(valid[row:row + 1], False)[0] == width * width
+            held = valid[row].reshape(3, BLOCK_Q).any(axis=1)
+            assert (lo, hi) == ((3, -1) if not held.any() else
+                                (held.argmax(), 2 - held[::-1].argmax()))
+
+    def test_cell_length_law_works_two_tiles_in_five(self):
+        """``ouro-2.6b-d8``: MovieLens-20M's history lengths, events first,
+        rows of 256: 40.3% of the tiles at block 128."""
+        import json
+        import os
+
+        from benchmarks import seeded
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "benchmarks/configs/ouro-2.6b-d8.json")) as f:
+            data = json.load(f)["data"]
+        lengths = np.minimum(
+            seeded.degree_sequence(data["users"], data["events"],
+                                   data["user_degrees"]), 256)
+        valid = np.arange(256)[None, :] < lengths[:, None]
+        worked, tiles = tiles_worked(valid, True)
+        assert tiles == 4 * data["users"]
+        assert BLOCK_Q == 128 and abs(100.0 * worked / tiles - 40.3) <= 0.1
+
+
+class TestHeadsAProgram:
+    """A program works 4, 2 or 1 heads of a row (they share the row's bounds)."""
+
+    @pytest.mark.parametrize("h,want", [(1, 1), (2, 2), (3, 1), (4, 4), (6, 2), (8, 4)])
+    def test_forward_and_grads_whatever_the_grouping(self, h, want):
+        from predictionio_tpu.ops.flash_attention import _heads_per_program
+
+        assert _heads_per_program(h, 256, 8, 4, BLOCK_Q) == want
+        valid = np.stack([_layout("events_first_short", 256), _layout("hole", 256)])
+        _assert_matches_plain_at_valid(valid, h, True, h)
+
+    def test_long_rows_keep_a_head_a_program(self):
+        """The blocks of the dkv program stay within the budget: at 2,048
+        positions of 64 a head works alone, at the cell's shape four."""
+        from predictionio_tpu.ops.flash_attention import _heads_per_program
+
+        assert _heads_per_program(4, 2048, 64, 4, BLOCK_Q) == 1
+        assert _heads_per_program(16, 256, 128, 4, BLOCK_Q) == 4
+        assert _heads_per_program(2, 512, 32, 4, BLOCK_Q) == 2

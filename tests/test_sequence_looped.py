@@ -181,15 +181,53 @@ def test_bfloat16_matmul_inputs_stay_close_to_the_reference(params, batch, refer
                        _leaf(reference["grads"], "layers.wq")) < 5e-2
 
 
-def test_flash_attention_in_the_looped_layer_matches_plain(params, batch, system):
-    def flash(q, k, v, mask):
-        return attend(q, k, v, mask, None, "flash", "ring")
+def _flash(q, k, v, mask):
+    return attend(q, k, v, mask, None, "flash", "ring")
 
-    loss_fn = looped.make_loss(_config(), flash)
+
+def test_flash_attention_in_the_looped_layer_matches_plain(params, batch, system):
+    loss_fn = looped.make_loss(_config(), _flash)
     (loss, _), grads = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))(
         params, batch, None)
     assert abs(float(loss) - system["loss"]) < 1e-5
     assert _rel(np.asarray(grads["layers"]["wq"]), system["grads"]["layers"]["wq"]) < 1e-4
+
+
+@pytest.fixture(scope="module")
+def two_block_rows(params):
+    """Events-first rows of 256 slots, two of the kernel's blocks: one under
+    half of ``max_len`` (its second block holds nothing: three of its four
+    tiles are skipped), one that ends on the block edge, one past it, one
+    full. The looped loss and its gradients under both attentions."""
+    rng = np.random.default_rng(5)
+    seq = np.zeros((4, 256), np.int32)
+    for row, n in enumerate((100, 128, 200, 256)):
+        seq[row, :n] = rng.integers(1, VOCAB, n)
+    target = np.zeros_like(seq)
+    target[:, :-1] = seq[:, 1:]
+    rows = {"seq": seq, "target": target}
+    out = {}
+    for name, attention in (("plain", _plain), ("flash", _flash)):
+        loss_fn = looped.make_loss(_config(max_len=256), attention)
+        (loss, aux), grads = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))(
+            params, rows, None)
+        out[name] = {"loss": float(loss), "exit_ce": np.asarray(aux["exit_ce"]),
+                     "grads": jax.tree_util.tree_map(np.asarray, grads)}
+    return out
+
+
+def test_flash_over_two_blocks_gives_the_plain_loss(two_block_rows):
+    flash, plain = two_block_rows["flash"], two_block_rows["plain"]
+    assert abs(flash["loss"] - plain["loss"]) < 1e-5
+    np.testing.assert_allclose(flash["exit_ce"], plain["exit_ce"], atol=1e-5)
+
+
+@pytest.mark.parametrize("name", PARAM_NAMES)
+def test_flash_over_two_blocks_gives_every_plain_gradient(two_block_rows, name):
+    got = _leaf(two_block_rows["flash"]["grads"], name)
+    want = _leaf(two_block_rows["plain"]["grads"], name)
+    assert np.linalg.norm(want) > 0
+    assert _rel(got, want) < 1e-4
 
 
 # ---- wrong loops must fail the same comparison ----------------------------

@@ -272,3 +272,37 @@ class TestLiveHistory:
         )
         fresh = a.predict(model, {"user": "brand_new", "num": 3})
         assert fresh["itemScores"], "fresh session did not serve"
+
+
+class TestPreparatorSpan:
+    """``seq.pack`` carries what the benchmark's readers divide: slots and
+    filled slots, and the attention tiles the flash kernel works of the rows
+    (``ops/flash_attention.tiles_worked``: all users, ``maxLen``, causal)."""
+
+    @pytest.mark.parametrize("max_len,lengths,tiles,worked", [
+        (64, [3, 64, 200], 3, 3),             # one block a row: nothing to skip
+        (256, [20, 128, 129, 256, 400], 20, 1 + 1 + 3 + 3 + 3),
+        (300, [10, 130, 290], 27, 1 + 3 + 6),  # three blocks a row
+    ])
+    def test_counts(self, max_len, lengths, tiles, worked):
+        from predictionio_tpu.controller import Params
+        from predictionio_tpu.models.sequence.engine import (
+            SequencePreparator, SequencesData,
+        )
+        from predictionio_tpu.obs.trace import global_tracer
+
+        data = SequencesData(
+            [np.arange(n, dtype=np.int64) % 7 for n in lengths],
+            [f"u{i}" for i in range(len(lengths))], [f"i{i}" for i in range(7)])
+        packed = SequencePreparator(Params({"maxLen": max_len})).prepare(None, data)
+        # events first, the last maxLen of them, ids shifted by one
+        for row, n in enumerate(lengths):
+            assert np.count_nonzero(packed.matrix[row]) == min(n, max_len)
+            assert packed.matrix[row, : min(n, max_len)].all()
+        attrs = next(s for tr in global_tracer().snapshot(limit=50)["recent"]
+                     for s in tr["spans"] if s["op"] == "seq.pack")["attrs"]
+        assert attrs["users"] == len(lengths)
+        assert attrs["slots"] == len(lengths) * max_len
+        assert attrs["filled_slots"] == sum(min(n, max_len) for n in lengths)
+        assert attrs["attention_tiles"] == tiles
+        assert attrs["attention_tiles_worked"] == worked

@@ -117,20 +117,34 @@ def test_search_program_names_its_kernel_and_its_stages(one_chip, no_persistent_
         assert f"/{scope}/" in text
 
 
-@pytest.mark.parametrize("shape", [(8, 512, 2, 32), (4, 2048, 4, 64)],
-                         ids=["b8_t512_h2_d32", "b4_t2048_h4_d64"])
+@pytest.mark.parametrize(
+    "shape,masked",
+    [((8, 512, 2, 32), False), ((4, 2048, 4, 64), False),
+     ((32, 256, 16, 128), True)],
+    ids=["b8_t512_h2_d32", "b4_t2048_h4_d64", "b32_t256_h16_d128_masked"])
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_flash_attention_compiles(one_chip, no_persistent_cache, direction, shape):
+def test_flash_attention_compiles(one_chip, no_persistent_cache, direction,
+                                  shape, masked):
+    """The last case is the sequence cell's call (``ouro-2.6b-d8``: 32 rows of
+    256, 16 heads of 128) with a mask: the block bounds reach the three
+    programs by scalar prefetch and bound their loops from SMEM."""
     from predictionio_tpu.ops.flash_attention import flash_attention
 
-    def fwd(q, k, v):
-        return flash_attention(q, k, v, None, True, None, False)
+    def fwd(q, k, v, mask=None):
+        return flash_attention(q, k, v, mask, True, None, False)
 
     fn = fwd
     if direction == "bwd":
-        fn = jax.grad(lambda q, k, v: fwd(q, k, v).sum(), argnums=(0, 1, 2))
+        fn = jax.grad(lambda q, k, v, mask=None: fwd(q, k, v, mask).sum(),
+                      argnums=(0, 1, 2))
     qkv = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
-    assert "tpu_custom_call" in _compiled_text(fn, qkv, qkv, qkv)
+    args = (qkv, qkv, qkv)
+    if masked:
+        args += (jax.ShapeDtypeStruct(shape[:2], jnp.bool_, sharding=one_chip),)
+    text = _compiled_text(fn, *args)
+    # three programs an attention and no fourth: forward; dq; dkv
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        1 if direction == "fwd" else 3)
 
 
 def test_ncf_scorer_compiles(one_chip, no_persistent_cache):
