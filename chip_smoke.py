@@ -50,6 +50,10 @@ OUT = os.path.join(ROOT, "chiprun_out", "chip_smoke")  # --out moves it
 ML1M = {"users": 6_040, "items": 3_706, "events": 1_000_209}
 ML20M = {"users": 138_000, "items": 27_000, "edges": 20_000_000}
 SEED = 0
+#: what the sparse backbone's ``seq_fit:`` line says beside the losses
+SPARSE_FIT_FACTS = ("experts_total", "experts_held", "experts_per_token", "index_topk",
+                    "moe_assignments", "moe_held_assignments", "moe_held_load_max",
+                    "moe_dropped", "selected_pairs", "causal_pairs")
 
 
 # ---------------------------------------------------------------------------
@@ -309,6 +313,8 @@ class Smoke:
             facts.update(backbone=said["backbone"], steps=int(said["steps"]),
                          first_loss=float(said["first_loss"]),
                          last_loss=float(said["last_loss"]))
+            # what the sparse backbone adds to the line: its share and its counts
+            facts.update({k: float(said[k]) for k in SPARSE_FIT_FACTS if k in said})
         timings = re.search(r"stage timings: (.*)$", text, re.M)
         if timings:
             facts["stage_timings"] = timings.group(1).strip()
@@ -582,6 +588,64 @@ class Smoke:
         self.line("train_sequence_looped", t0, **facts, users=32, events=int(users.size),
                   **widths)
 
+    def phase_train_sequence_sparse_moe(self) -> None:
+        """The sequence template's sparse backbone through ``pio train`` at the
+        published widths (2 layers, 16 of 128 experts held; a rehearsal cuts
+        the widths): a few steps on one batch of users whose histories are
+        several times ``indexTopk`` long, so that the selection bites. The
+        ``seq_fit:`` line has to name the backbone, the held experts, a
+        selection smaller than the causal triangle and no dropped token: a
+        silent fall to a dense path or to the whole layer would show."""
+        import numpy as np
+
+        t0 = time.time()
+        out = pio("app_new_sparse", ["app", "new", "SmokeSparseApp"], self.env, 120)
+        app_id = int(re.search(r"ID: (\d+)", out).group(1))
+        rng = np.random.default_rng(SEED + 1)
+        max_len, topk = (128, 32) if self.rehearsal else (2048, 512)
+        lengths = rng.integers(max_len, max_len + 64, size=8)
+        users = np.repeat(np.arange(8), lengths)
+        items = (np.minimum(rng.random(users.size) ** 2.2, 0.999999) * 2_000).astype(np.int64)
+        events = os.path.join(self.basedir, "sparse_events.jsonl")
+        write_events(events, users, items, np.ones(users.size, np.float32))
+        pio("import_sparse", ["import", "--appid", str(app_id), "--input", events], self.env, 300)
+        os.unlink(events)
+        widths = ({"hiddenSize": 64, "numHeads": 4, "numKvHeads": 2, "headDim": 16,
+                   "expertDim": 32, "numExperts": 16, "expertsPerToken": 4,
+                   "expertsHeld": [0, 4], "indexHeads": 2, "indexDim": 8}
+                  if self.rehearsal else
+                  {"hiddenSize": 2048, "numHeads": 32, "numKvHeads": 4, "headDim": 128,
+                   "expertDim": 768, "numExperts": 128, "expertsPerToken": 8,
+                   "expertsHeld": [0, 16], "indexHeads": 16, "indexDim": 64})
+
+        def edit(v):
+            v["datasource"]["params"]["appName"] = "SmokeSparseApp"
+            v["preparator"]["params"]["maxLen"] = max_len
+            v["algorithms"][0]["params"].update(
+                backbone="sparse_moe", numLayers=2, indexTopk=topk, batchSize=8, epochs=6,
+                learningRate=3e-4, **widths)
+            v["sparkConf"] = {"pio.mesh_shape": [1, 1], "pio.mesh_axes": ["data", "seq"]}
+
+        seq_dir = self.engine_dir("sequence_sparse_moe", "sequence", edit)
+        facts = self.train("train_sequence_sparse_moe", seq_dir, 900)
+        held = widths["expertsHeld"][1] - widths["expertsHeld"][0]
+        if (facts.get("backbone") != "sparse_moe" or facts.get("steps") != 6
+                or facts.get("experts_held") != held
+                or facts.get("experts_total") != widths["numExperts"]):
+            raise PhaseFailed(
+                f"train_sequence_sparse_moe: not six steps of the sparse backbone with"
+                f" {held} of {widths['numExperts']} experts held: {facts}")
+        if facts.get("moe_dropped") != 0 or not (
+                0 < facts.get("moe_held_assignments", 0) < facts["moe_assignments"]):
+            raise PhaseFailed(f"train_sequence_sparse_moe: tokens dropped, or no share: {facts}")
+        if not 0 < facts.get("selected_pairs", 0) < facts["causal_pairs"]:
+            raise PhaseFailed(f"train_sequence_sparse_moe: no selection (a dense path?): {facts}")
+        first, last = facts["first_loss"], facts["last_loss"]
+        if not (first == first and last == last and last < first < float("inf")):
+            raise PhaseFailed(f"train_sequence_sparse_moe: loss not finite and falling: {first} -> {last}")
+        self.line("train_sequence_sparse_moe", t0, **facts, users=8, events=int(users.size),
+                  max_len=max_len, **widths)
+
     def phase_sharded(self) -> None:
         self.phase_device(with_status=False)
         if self.device["count"] != 4:
@@ -642,6 +706,7 @@ def main(argv=None) -> int:
                 smoke.phase_train_als, smoke.phase_als_full_width,
                 smoke.phase_serve_als, smoke.phase_train_serve_ncf,
                 smoke.phase_train_sequence_looped,
+                smoke.phase_train_sequence_sparse_moe,
             ]
         for phase in phases:
             try:

@@ -29,6 +29,7 @@ from predictionio_tpu.controller.base import SanityCheck
 from predictionio_tpu.data.store import PEventStore
 from predictionio_tpu.models._als_common import score_buffer_rows, topk_item_scores
 from predictionio_tpu.models.sequence.looped import LoopedConfig
+from predictionio_tpu.models.sequence.sparse_moe import SparseMoEConfig
 from predictionio_tpu.ops.flash_attention import tiles_worked
 from predictionio_tpu.models.sequence.model import (
     SASRecConfig,
@@ -180,7 +181,7 @@ class SequencePreparator(Preparator):
 @dataclass
 class SASRecModel:
     params: dict
-    config: SASRecConfig | LoopedConfig   # the backbone it was trained with
+    config: SASRecConfig | LoopedConfig | SparseMoEConfig   # the backbone it was trained with
     item_ids: list[str]
     item_index: dict[str, int]
     histories: dict[str, np.ndarray]   # user id -> shifted (+1) id sequence
@@ -196,20 +197,29 @@ class SASRecModel:
 
 
 class SASRecAlgorithm(TPUAlgorithm):
-    """Params: ``backbone`` ("sasrec", the default, or "looped"); learningRate,
-    batchSize, epochs, seed, maxLen (must match the preparator's), attention
-    ("auto" | "flash" | "plain") and seqParallel ("ring" | "ulysses", the
-    sequence-parallel attention strategy when the mesh has a >1 ``seq`` axis)
-    for both. "sasrec" reads embedDim, numHeads, numBlocks, ffnDim, dropout;
-    "looped" (``models/sequence/looped.py``) reads hiddenSize, numHeads,
-    headDim, ffnDim, numLayers, utSteps, ropeTheta, rmsNormEps, exitBeta,
-    earlyExitThreshold."""
+    """Params: ``backbone`` ("sasrec", the default, "looped" or "sparse_moe");
+    learningRate, batchSize, epochs, seed, maxLen (must match the
+    preparator's), attention ("auto" | "flash" | "plain") and seqParallel
+    ("ring" | "ulysses", the sequence-parallel attention strategy when the
+    mesh has a >1 ``seq`` axis) for all. "sasrec" reads embedDim, numHeads,
+    numBlocks, ffnDim, dropout; "looped" (``models/sequence/looped.py``) reads
+    hiddenSize, numHeads, headDim, ffnDim, numLayers, utSteps, ropeTheta,
+    rmsNormEps, exitBeta, earlyExitThreshold; "sparse_moe"
+    (``models/sequence/sparse_moe.py``) reads hiddenSize, numHeads,
+    numKvHeads, headDim, expertDim, numExperts, expertsPerToken, expertsHeld
+    (``[lo, hi]``: the experts this program holds of the ``numExperts``; the
+    default is all), numLayers, indexHeads, indexDim, indexTopk, ropeTheta,
+    rmsNormEps, auxLossCoef."""
+
+    BACKBONES = ("sasrec", "looped", "sparse_moe")
 
     def _config(self, num_items: int, max_len: int):
         p = self.params
         backbone = p.get_or("backbone", "sasrec")
-        if backbone not in ("sasrec", "looped"):
-            raise ValueError(f"backbone={backbone!r}: want 'sasrec' or 'looped'")
+        if backbone not in self.BACKBONES:
+            raise ValueError(
+                f"backbone={backbone!r}: want one of "
+                + ", ".join(repr(b) for b in self.BACKBONES))
         shared = dict(
             num_items=num_items,
             max_len=max_len,
@@ -233,6 +243,28 @@ class SASRecAlgorithm(TPUAlgorithm):
                 exit_beta=float(p.get_or("exitBeta", d.exit_beta)),
                 early_exit_threshold=float(
                     p.get_or("earlyExitThreshold", d.early_exit_threshold)),
+                learning_rate=p.get_or("learningRate", d.learning_rate),
+                **shared,
+            )
+        if backbone == "sparse_moe":
+            d = SparseMoEConfig(num_items=num_items)  # the defaults, in one place
+            experts = p.get_or("numExperts", d.num_experts)
+            return SparseMoEConfig(
+                hidden_size=p.get_or("hiddenSize", d.hidden_size),
+                num_heads=p.get_or("numHeads", d.num_heads),
+                num_kv_heads=p.get_or("numKvHeads", d.num_kv_heads),
+                head_dim=p.get_or("headDim", d.head_dim),
+                expert_dim=p.get_or("expertDim", d.expert_dim),
+                num_experts=experts,
+                experts_per_token=p.get_or("expertsPerToken", d.experts_per_token),
+                experts_held=tuple(p.get_or("expertsHeld", (0, experts))),
+                num_layers=p.get_or("numLayers", d.num_layers),
+                index_heads=p.get_or("indexHeads", d.index_heads),
+                index_dim=p.get_or("indexDim", d.index_dim),
+                index_topk=p.get_or("indexTopk", d.index_topk),
+                rope_theta=float(p.get_or("ropeTheta", d.rope_theta)),
+                rms_eps=float(p.get_or("rmsNormEps", d.rms_eps)),
+                aux_coef=float(p.get_or("auxLossCoef", d.aux_coef)),
                 learning_rate=p.get_or("learningRate", d.learning_rate),
                 **shared,
             )

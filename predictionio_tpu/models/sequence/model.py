@@ -17,6 +17,7 @@ design:
 
 from __future__ import annotations
 
+import functools
 import logging
 from dataclasses import dataclass
 
@@ -33,8 +34,9 @@ from predictionio_tpu.parallel.mesh import (
     one_step_in_flight,
     put_global,
 )
-from predictionio_tpu.models.sequence import looped
+from predictionio_tpu.models.sequence import looped, sparse_moe
 from predictionio_tpu.models.sequence.looped import LoopedConfig
+from predictionio_tpu.models.sequence.sparse_moe import SparseMoEConfig
 from predictionio_tpu.ops.flash_attention import flash_attention
 from predictionio_tpu.parallel.ring_attention import plain_attention, ring_attention
 from predictionio_tpu.parallel.ulysses import ulysses_attention
@@ -208,11 +210,24 @@ def backbone_of(config, mesh):
     if isinstance(config, LoopedConfig):
         return (lambda rng, t: looped.init_params(config, rng),
                 looped.make_loss(config, _attention_of(config, mesh)))
+    if isinstance(config, SparseMoEConfig):
+        return (lambda rng, t: sparse_moe.init_params(config, rng),
+                sparse_moe.make_loss(config, mesh))
     model = SASRec(config, mesh)
     # dummy batch = one row per data-shard: shard_map needs divisibility
     dp0 = max(mesh.shape.get("data", 1), 1)
     return (lambda rng, t: model.init(rng, jnp.zeros((dp0, t), jnp.int32))["params"],
             _sasrec_loss(model))
+
+
+def optimizer_of(config):
+    """Adam at the configuration's learning rate. The sparse backbone's
+    indexer is not trained by this loss: it gets no update and no moments."""
+    adam = optax.adam(config.learning_rate)
+    if isinstance(config, SparseMoEConfig):
+        return optax.multi_transform(
+            {"train": adam, "fixed": optax.set_to_zero()}, sparse_moe.trained_labels)
+    return adam
 
 
 def _tree_bytes(tree) -> int:
@@ -231,7 +246,7 @@ def make_fit(config, mesh):
     dp_axis = "data" if "data" in mesh.axis_names else None
     sp_axis = "seq" if "seq" in mesh.axis_names else None
     seq_shard = NamedSharding(mesh, P(dp_axis, sp_axis))
-    optimizer = optax.adam(config.learning_rate)
+    optimizer = optimizer_of(config)
 
     def place(params):
         # put_global/jitted-init: on multi-process meshes every rank holds
@@ -253,7 +268,7 @@ def make_fit(config, mesh):
 
 
 def train_sasrec(
-    config,                  # SASRecConfig | LoopedConfig: picks the backbone
+    config,                  # SASRecConfig | LoopedConfig | SparseMoEConfig: the backbone
     sequences: np.ndarray,   # [N, T] int32 padded item ids (0 = pad)
     mesh,
     log_every: int = 0,
@@ -314,34 +329,48 @@ def train_sasrec(
                     losses.append(float(loss))
         check_steps_ran(step, n, dp, "sequence")
         span.set_attr("steps", step)
+        last = {name: np.asarray(fetch_global(value)).tolist()
+                for name, value in aux.items() if value.ndim <= 1}  # of the last step
         logger.info(
             "seq_fit: platform=%s devices=%d backbone=%s steps=%d"
-            " first_loss=%.5f last_loss=%.5f",
+            " first_loss=%.5f last_loss=%.5f%s",
             mesh.devices.flat[0].platform, mesh.devices.size,
             span_attrs["backbone"], step, float(first_loss), float(loss),
+            "".join(f" {k}={span_attrs[k]}" for k in _FIT_LINE_ATTRS if k in span_attrs)
+            + "".join(f" {k}={v:.6g}" for k, v in last.items() if np.ndim(v) == 0),
         )
-        for name, value in aux.items():  # of the last step: the means
-            if value.ndim <= 1:
-                span.set_attr(name, np.asarray(fetch_global(value)).tolist())
+        for name, value in last.items():
+            span.set_attr(name, value)
     return jax.tree_util.tree_map(fetch_global, params), losses
+
+
+#: the span's attributes the ``seq_fit:`` line repeats (a backbone that has them)
+_FIT_LINE_ATTRS = ("experts_total", "experts_held", "experts_per_token", "index_topk",
+                   "kv_heads")
+_BACKBONES = {LoopedConfig: "looped", SparseMoEConfig: "sparse_moe"}
 
 
 def fit_attrs(config, param_bytes: int, opt_state_bytes: int) -> dict:
     """What the fit's span says of the model it trains."""
     attrs = {
-        "backbone": "looped" if isinstance(config, LoopedConfig) else "sasrec",
+        "backbone": _BACKBONES.get(type(config), "sasrec"),
         "param_bytes": param_bytes,
         # weights, their gradients and the optimizer's moments
         "state_bytes": 2 * param_bytes + opt_state_bytes,
     }
-    if isinstance(config, LoopedConfig):
+    if isinstance(config, (LoopedConfig, SparseMoEConfig)):
         chunk = looped.head_chunk_of(config)
         attrs.update(
-            layers=config.num_layers, passes=config.ut_steps,
+            layers=config.num_layers, passes=getattr(config, "ut_steps", 1),
             rematerialised="layer" if config.remat else "nothing",
             head=(f"chunks of {chunk} positions, recomputed" if chunk else
                   "whole pass, recomputed"),
         )
+        if isinstance(config, SparseMoEConfig):
+            attrs.update(
+                experts_total=config.num_experts, experts_held=config.held,
+                experts_per_token=config.experts_per_token,
+                index_topk=config.index_topk, kv_heads=config.num_kv_heads)
     else:
         attrs.update(layers=config.num_blocks, passes=1,
                      rematerialised="nothing", head="whole")
@@ -360,6 +389,9 @@ def _score_fn(config):
             _SCORE_CACHE[config] = jax.jit(
                 lambda params, seqs, last: looped.score_last(
                     config, attention, params, seqs, last))
+            return _SCORE_CACHE[config]
+        if isinstance(config, SparseMoEConfig):
+            _SCORE_CACHE[config] = jax.jit(functools.partial(sparse_moe.score_last, config))
             return _SCORE_CACHE[config]
         model = SASRec(config, None)
 

@@ -1,0 +1,445 @@
+"""The sparse backbone of the sequence template: a decoder whose attention
+reads, for every query, the ``index_topk`` earlier positions a learned indexer
+scores highest, and whose feed-forward is a routed mixture of experts of which
+this program holds a share.
+
+The block is that of ``Keye-VL-2.0-30B-A3B``'s language model (``model_type
+KeyeVL2``: grouped key-value heads, a DeepSeek-Sparse-Attention indexer, 128
+experts, 8 a token, no shared expert) with the item catalog as its
+vocabulary. For one row ``x`` ``[T, D]`` (``n1``, ``n2`` RMSNorm):
+
+- ``h = n1(x)``; ``q = h Wq`` ``[T, H, hd]``, ``k = h Wk``, ``v = h Wv``
+  ``[T, KV, hd]``, rotary positions on ``q`` and ``k``;
+- indexer: ``qI = h WqI`` ``[T, HI, dI]``, ``kI = rms(h WkI)`` ``[T, dI]`` (no
+  learned scale), ``w = h Ww`` ``[T, HI]``;
+  ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])`` for ``s <= t``; ``S_t`` is
+  the ``index_topk`` positions ``s <= t`` with the largest ``I[t, s]`` (all of
+  them while ``t < index_topk``; ties to the earlier position);
+- ``a[t] = softmax over s in S_t of (q[t, g] . k[s, g // (H / KV)] / sqrt(hd))``,
+  ``x = x + concat_g(a v) Wo``;
+- ``u = n2(x)``; ``p = softmax(u Wr)`` over all ``num_experts`` in float32;
+  ``E_t`` the ``experts_per_token`` largest, ``g[t, e] = p[t, e] / sum_{E_t} p``;
+  ``x = x + sum_{e in E_t, e held here} g[t, e] W2_e (silu(W1_e u) * (W3_e u))``;
+- after the last layer ``h = n_f(x)``, ``logits = W_head h`` (not tied), and
+  the loss of a position with a target is its cross-entropy, the mean over
+  such positions, plus ``aux_coef`` times the mean over the layers of
+  ``num_experts sum_e f_e P_e`` (``f_e`` the assignments to expert ``e`` a
+  real token, ``P_e`` the mean of ``p[., e]``; the load-balancing loss of the
+  family).
+
+``experts_held = (lo, hi)`` names the experts this program holds, as one chip
+of an expert-parallel deployment does: the router is whole (every chip routes
+its tokens over all the experts), the expert weights are ``hi - lo`` of them,
+and a layer adds the held experts' part of the sum. The other chips' parts
+are theirs to add: nothing here stands in for them, and with every expert
+held the layer is the whole layer.
+
+The indexer decides by a hard top-k, which passes no gradient, so the
+next-item loss cannot train it: its three matrices (``params["indexer"]``)
+are inputs of the fit that stay as drawn, and the optimizer keeps no state
+for them (``model.py:optimizer_of``). DeepSeek trains its indexer by a
+separate alignment loss; that recipe is not part of this backbone.
+
+How it is worked (``benchmarks/reference_keye.py`` is the same mathematics
+with none of this):
+
+- layer parameters are stacked ``[L, ...]`` and the stack is one ``lax.scan``,
+  each layer rematerialised from its input (``remat``);
+- matmul inputs are ``compute_dtype`` (bfloat16) with float32 accumulation;
+  the router's matmul, softmax and top-k, the residual stream, norms, rotary
+  positions, attention softmax, loss, master weights and Adam's moments are
+  float32; the index scores are bfloat16 products accumulated in float32;
+- on a TPU (``attention`` "auto") index scores, selection and attention are
+  the three programs of ``ops/sparse_attention.py``: scores in tiles over the
+  causal triangle, the k-th largest by bisection with a block of queries'
+  scores in VMEM, attention with K and V streamed a block at a time; elsewhere
+  their ``jax.numpy`` twins;
+- experts: a token's assignments to held experts are sorted by expert and
+  worked as grouped matmuls (``jax.lax.ragged_dot``) over exactly those rows:
+  no capacity, no token dropped. Tokens go through in chunks whose worst case
+  (every assignment of the chunk held) fits ``MOE_CHUNK_BYTES``, each chunk
+  recomputed in the backward pass; the permutations in and out are gathers in
+  both directions (``_dispatch``, ``_permute``: on the chip a scatter of rows
+  cost more than the whole of this, PERF.md PR 33);
+- the head and loss are ``looped._exit_ce``'s chunks of positions.
+"""
+
+from __future__ import annotations
+
+import functools
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from predictionio_tpu.models.sequence import looped
+from predictionio_tpu.ops import sparse_attention as sa
+
+#: Device scopes of a training step beside ``looped``'s (``seq.embed``,
+#: ``seq.pass1/layers/attention``, ``seq.pass1/exit``, ``seq.optimizer``):
+#: under ``attention`` the indexer's projections and scores, the k-th
+#: largest, and the attention over the selection; under ``layers/moe`` the
+#: router and the held experts.
+SCOPE_INDEX = "index"
+SCOPE_SELECT = "select"
+SCOPE_KERNEL = "kernel"
+SCOPE_MOE = "moe"
+SCOPE_ROUTE = "route"
+SCOPE_EXPERTS = "experts"
+
+#: the most float32 bytes the held experts' output rows of one chunk of
+#: tokens may take, were every assignment of the chunk to a held expert
+MOE_CHUNK_BYTES = 256 << 20
+
+
+@dataclass(frozen=True)
+class SparseMoEConfig:
+    num_items: int              # real item vocab; id 0 is reserved for padding
+    max_len: int = 64
+    hidden_size: int = 64
+    num_heads: int = 4
+    num_kv_heads: int = 2
+    head_dim: int = 16
+    expert_dim: int = 32
+    num_experts: int = 8
+    experts_per_token: int = 2
+    experts_held: tuple = (0, 8)    # [lo, hi) of the experts: this program's share
+    num_layers: int = 2
+    index_heads: int = 2
+    index_dim: int = 16
+    index_topk: int = 16
+    rope_theta: float = 1e7
+    rms_eps: float = 1e-6
+    aux_coef: float = 0.001
+    learning_rate: float = 3e-4
+    batch_size: int = 256
+    epochs: int = 10
+    seed: int = 0
+    seq_parallel: str = "ring"
+    attention: str = "auto"
+    # how the step is worked: what the tests vary, and no engine parameter
+    compute_dtype: str = "bfloat16"   # matmul inputs; accumulation is float32
+    remat: bool = True
+    head_chunk: int | None = None     # None: from looped.HEAD_CHUNK_BYTES; 0: whole
+    moe_chunk: int | None = None      # None: from MOE_CHUNK_BYTES; tokens a chunk
+
+    def __post_init__(self):
+        object.__setattr__(self, "experts_held", tuple(int(e) for e in self.experts_held))
+        lo, hi = self.experts_held
+        if not 0 <= lo < hi <= self.num_experts:
+            raise ValueError(
+                f"experts_held={self.experts_held}: want 0 <= lo < hi <= num_experts="
+                f"{self.num_experts}")
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(
+                f"num_heads={self.num_heads} must be a multiple of num_kv_heads="
+                f"{self.num_kv_heads}")
+        if not 1 <= self.experts_per_token <= self.num_experts:
+            raise ValueError(
+                f"experts_per_token={self.experts_per_token}: want 1 .. num_experts")
+        if self.attention not in ("auto", "flash", "plain"):
+            raise ValueError(
+                f"attention={self.attention!r} must be one of 'auto' | 'flash' | 'plain'")
+        if self.compute_dtype not in ("bfloat16", "float32"):
+            raise ValueError(
+                f"compute_dtype={self.compute_dtype!r}: want 'bfloat16' or 'float32'")
+        if self.head_dim % 2:
+            raise ValueError(f"head_dim={self.head_dim} must be even (rotary pairs)")
+        if self.index_topk < 1 or self.num_layers < 1:
+            raise ValueError("index_topk and num_layers must be at least 1")
+
+    @property
+    def vocab(self) -> int:
+        return self.num_items + 1  # +1 for the padding id 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held[1] - self.experts_held[0]
+
+
+def moe_chunk_of(c: SparseMoEConfig) -> int:
+    """Tokens of a layer's experts worked at once: a multiple of 128 whose
+    worst case of held rows keeps within ``MOE_CHUNK_BYTES``."""
+    if c.moe_chunk is not None:
+        return c.moe_chunk
+    slots = min(c.experts_per_token, c.held)
+    return max(128, MOE_CHUNK_BYTES // (4 * slots * c.hidden_size) // 128 * 128)
+
+
+def param_shapes(c: SparseMoEConfig) -> dict:
+    """The parameter tree as shapes. The layers' arrays lead with ``[L]``;
+    ``indexer`` is held fixed by the fit."""
+    d, n, hd = c.hidden_size, c.num_layers, c.head_dim
+    return {
+        "embed": (c.vocab, d),
+        "layers": {
+            "n1": (n, d), "wq": (n, d, c.num_heads * hd), "wk": (n, d, c.num_kv_heads * hd),
+            "wv": (n, d, c.num_kv_heads * hd), "wo": (n, c.num_heads * hd, d),
+            "n2": (n, d), "router": (n, d, c.num_experts),
+            "w_gate": (n, c.held, d, c.expert_dim), "w_up": (n, c.held, d, c.expert_dim),
+            "w_down": (n, c.held, c.expert_dim, d),
+        },
+        "indexer": {
+            "wq": (n, d, c.index_heads * c.index_dim), "wk": (n, d, c.index_dim),
+            "ww": (n, d, c.index_heads),
+        },
+        "final_norm": (d,),
+        "head": (c.vocab, d),
+    }
+
+
+_NORMS = ("n1", "n2", "final_norm")
+_is_shape = lambda x: isinstance(x, tuple)  # noqa: E731
+
+
+def init_params(c: SparseMoEConfig, rng) -> dict:
+    """Norm weights 1, the embedding N(0, 1), matrices N(0, 0.02) and the
+    projections that write into the residual stream (``wo``, ``w_down``)
+    N(0, 0.02 / sqrt(2 L)), GPT-2's scaling. With everything at 0.02 the
+    near-uniform attention of an untrained model adds the same mean of values
+    to every position, and a router that sees one state in every position
+    sends a layer's tokens to the same few experts."""
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(param_shapes(c), is_leaf=_is_shape)
+    stds = {"embed": 1.0, "wo": 0.02 / np.sqrt(2 * c.num_layers),
+            "w_down": 0.02 / np.sqrt(2 * c.num_layers)}
+    out = []
+    for n, (path, shape) in enumerate(leaves):
+        name = path[-1].key
+        if name in _NORMS:
+            out.append(jnp.ones(shape, jnp.float32))
+            continue
+        out.append(stds.get(name, 0.02) * jax.random.normal(
+            jax.random.fold_in(rng, n), shape, jnp.float32))
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def count_params(c: SparseMoEConfig) -> int:
+    return sum(int(np.prod(s)) for s in jax.tree_util.tree_leaves(
+        param_shapes(c), is_leaf=_is_shape))
+
+
+def trained_labels(params) -> dict:
+    """``"train"`` or ``"fixed"`` for every leaf: the indexer is fixed."""
+    return {name: jax.tree_util.tree_map(
+        lambda _: "fixed" if name == "indexer" else "train", sub)
+        for name, sub in params.items()}
+
+
+def uses_kernels(c: SparseMoEConfig, backend: str) -> bool:
+    return c.attention == "flash" or (c.attention == "auto" and backend == "tpu")
+
+
+# ---- attention over the indexer's selection ----------------------------------
+
+def _attention(c: SparseMoEConfig, backend: str, rope, h, p, ip, real, probe=None):
+    """``(o, counts)``: the attention output before ``Wo`` ``[B, T, H x hd]``
+    on the normed input ``h``, and what it selected. ``probe``: query
+    positions whose index scores and selection are returned too."""
+    dtype = jnp.dtype(c.compute_dtype)
+    b, t, _ = h.shape
+    kernels, interpret = uses_kernels(c, backend), backend != "tpu"
+    heads = lambda a, n: a.reshape(b, t, n, c.head_dim)  # noqa: E731
+    q = looped._rotate(heads(looped._matmul(h, p["wq"], dtype), c.num_heads), *rope)
+    k = looped._rotate(heads(looped._matmul(h, p["wk"], dtype), c.num_kv_heads), *rope)
+    v = heads(looped._matmul(h, p["wv"], dtype), c.num_kv_heads)
+    with jax.named_scope(SCOPE_INDEX):
+        # the selection is a hard top-k: no gradient, to the input or the indexer
+        hs, ip = jax.lax.stop_gradient((h, ip))
+        q_idx = looped._matmul(hs, ip["wq"], dtype).reshape(b, t, c.index_heads, c.index_dim)
+        k_idx = looped._matmul(hs, ip["wk"], dtype)
+        k_idx = k_idx * jax.lax.rsqrt(jnp.mean(k_idx * k_idx, axis=-1, keepdims=True)
+                                      + c.rms_eps)
+        w = looped._matmul(hs, ip["ww"], dtype)
+        q_idx, k_idx = q_idx.astype(dtype), k_idx.astype(dtype)
+        scores = (sa.index_scores(q_idx, k_idx, w, interpret=interpret) if kernels
+                  else sa.index_scores_plain(q_idx, k_idx, w))
+    with jax.named_scope(SCOPE_SELECT):
+        mask = (sa.select_topk(scores, c.index_topk, interpret=interpret) if kernels
+                else sa.select_topk_plain(scores, c.index_topk))
+        per_query = mask.astype(jnp.int32).sum(axis=-1)
+        counts = {"selected_pairs": jnp.where(real, per_query, 0).sum(),
+                  "causal_pairs": jnp.where(real, jnp.arange(1, t + 1)[None, :], 0).sum()}
+        if probe is not None:
+            counts["probe_scores"] = scores[:, probe, :]
+            counts["probe_mask"] = mask[:, probe, :]
+    with jax.named_scope(SCOPE_KERNEL):
+        q, k, v = q.astype(dtype), k.astype(dtype), v.astype(dtype)
+        if kernels:
+            out = sa.sparse_attention(q, k, v, mask, sa.BLOCK_Q, sa.BLOCK_K, interpret)
+        else:
+            out = sa.sparse_attention_plain(q, k, v, mask)
+    return out.reshape(b, t, -1), counts
+
+
+# ---- routed experts ---------------------------------------------------------
+
+@jax.custom_vjp
+def _permute(a, index, back):
+    """``a[index]`` for a permutation ``index`` whose inverse is ``back``: the
+    transpose is the gather ``g[back]``, never a scatter."""
+    return a[index]
+
+
+_permute.defvjp(lambda a, index, back: (a[index], (index, back)),
+                lambda res, g: (g[res[1]], None, None))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(u, order, back, slots: int):
+    """Row ``r`` of the sorted assignments takes its token: ``u[order // slots]``.
+    The transpose sums a token's ``slots`` rows, found by ``back``."""
+    return u[order // slots]
+
+
+_dispatch.defvjp(
+    lambda u, order, back, slots: (u[order // slots], (back,)),
+    lambda slots, res, g: (g[res[0]].reshape(-1, slots, g.shape[-1]).sum(axis=1).astype(g.dtype),
+                           None, None))
+
+
+def _experts_chunk(c: SparseMoEConfig, w_gate, w_up, w_down, u, experts, gates, real):
+    """The held experts' part of the layer for a chunk of tokens: ``u`` [n, D]
+    bfloat16, ``experts``, ``gates`` [n, K], ``real`` [n] -> ``(y [n, D]
+    float32, rows worked)``. The chunk's ``n K`` assignments are sorted, those
+    to held experts first and by expert; grouped matmuls work exactly the held
+    rows, and the rows go in and come out by permutation."""
+    lo, hi = c.experts_held
+    n, slots = experts.shape
+    held = (experts >= lo) & (experts < hi) & real[:, None]
+    local = jnp.where(held, experts - lo, c.held).reshape(-1)      # not held: last
+    order = jnp.argsort(local, stable=True)
+    back = jnp.argsort(order)
+    sizes = (local[:, None] == jnp.arange(c.held)[None, :]).sum(axis=0).astype(jnp.int32)
+    dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
+                            preferred_element_type=jnp.float32)
+    # rows past the held assignments belong to no group: whatever a grouped
+    # matmul leaves there goes no further, forward or backward
+    grouped = (jnp.arange(n * slots) < sizes.sum())[:, None]
+    x = jnp.where(grouped, _dispatch(u, order, back, slots), 0)    # [n K, D]
+    inner = jax.nn.silu(dot(x, w_gate)) * dot(x, w_up)
+    out = jnp.where(grouped, dot(inner.astype(u.dtype), w_down), 0.0)
+    parts = _permute(out, back, order).reshape(n, slots, -1)
+    return jnp.einsum("nkd,nk->nd", parts, jnp.where(held, gates, 0.0)), sizes.sum()
+
+
+def _moe(c: SparseMoEConfig, u, p, real):
+    """``(y, stats)``: the held experts' part of the routed sum for the normed
+    tokens ``u`` [N, D], and the layer's counts. ``real`` [N]: a padded slot
+    is routed nowhere and counts nowhere."""
+    dtype = jnp.dtype(c.compute_dtype)
+    lo, hi = c.experts_held
+    n, slots = u.shape[0], c.experts_per_token
+    with jax.named_scope(SCOPE_ROUTE):
+        probs = jax.nn.softmax(jnp.matmul(
+            u, p["router"], precision=jax.lax.Precision.HIGHEST), axis=-1)
+        top_p, experts = jax.lax.top_k(probs, slots)
+        gates = top_p / top_p.sum(axis=-1, keepdims=True)
+        count = jnp.maximum(real.sum(), 1).astype(jnp.float32)
+        chosen = (experts[..., None] == jnp.arange(c.num_experts)) & real[:, None, None]
+        load = chosen.sum(axis=(0, 1))                             # [E] assignments
+        mean_p = jnp.where(real[:, None], probs, 0.0).sum(axis=0) / count
+        aux = c.num_experts * jnp.sum(load.astype(jnp.float32) / count * mean_p)
+        held_load = load[lo:hi]
+        stats = {"aux": aux, "assignments": load.sum(), "held_assignments": held_load.sum(),
+                 "held_load_max": held_load.max()}
+    with jax.named_scope(SCOPE_EXPERTS):
+        chunk = min(moe_chunk_of(c), n)
+        pad = -n % chunk
+        cut = lambda a: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)).reshape(  # noqa: E731
+            -1, chunk, *a.shape[1:])
+        work = jax.checkpoint(functools.partial(
+            _experts_chunk, c, p["w_gate"].astype(dtype), p["w_up"].astype(dtype),
+            p["w_down"].astype(dtype)))
+        y, rows = jax.lax.map(lambda args: work(*args),
+                              (cut(u.astype(dtype)), cut(experts), cut(gates), cut(real)))
+        stats["dropped"] = stats["held_assignments"] - rows.sum()
+    return y.reshape(-1, y.shape[-1])[:n], stats
+
+
+# ---- the stack ---------------------------------------------------------------
+
+def _layer(c: SparseMoEConfig, backend: str, rope, real, x, p, ip, probe=None):
+    """One decoder layer on ``x`` [B, T, D]: ``(x', stats)``."""
+    dtype = jnp.dtype(c.compute_dtype)
+    with jax.named_scope(looped.SCOPE_ATTENTION):
+        h = looped._rms_norm(x, p["n1"], c.rms_eps)
+        out, stats = _attention(c, backend, rope, h, p, ip, real, probe)
+        x = x + looped._matmul(out, p["wo"], dtype)
+    with jax.named_scope(SCOPE_MOE):
+        u = looped._rms_norm(x, p["n2"], c.rms_eps)
+        y, routed = _moe(c, u.reshape(-1, u.shape[-1]), p, real.reshape(-1))
+        return x + y.reshape(x.shape), {**stats, **routed}
+
+
+def _backend_of(mesh) -> str:
+    if mesh is not None and mesh.shape.get("seq", 1) > 1:
+        raise ValueError(
+            "the sparse_moe backbone selects keys over a whole row: it does not run"
+            " on a mesh whose 'seq' axis is larger than 1")
+    return mesh.devices.flat[0].platform if mesh is not None else jax.default_backend()
+
+
+def hidden_states(c: SparseMoEConfig, backend: str, params, seq, probe=None):
+    """``(x, stats)``: the residual stream after the last layer ``[B, T, D]``
+    and every layer's counts ``[L, ...]``, under the pass's scope."""
+    with jax.named_scope(looped.SCOPE_EMBED):
+        real = seq > 0
+        rope = looped._rope_tables(seq.shape[1], c.head_dim, c.rope_theta)
+        x = jnp.take(params["embed"], seq, axis=0)
+
+    def body(carry, layer):
+        return _layer(c, backend, rope, real, carry, *layer, probe)
+
+    if c.remat:
+        body = jax.checkpoint(body)
+    with jax.named_scope(looped.SCOPE_PASS.format(1)), jax.named_scope(looped.SCOPE_LAYERS):
+        return jax.lax.scan(body, x, (params["layers"], params["indexer"]))
+
+
+def make_loss(c: SparseMoEConfig, mesh):
+    """``loss_fn(params, batch, rng) -> (loss, aux)`` for the trainer's step;
+    ``aux`` is scalars: the two terms of the loss and the step's counts."""
+    backend = _backend_of(mesh)
+
+    def loss_fn(params, batch, rng):
+        del rng  # no dropout in this block
+        seq, targets = batch["seq"], batch["target"]
+        x, stats = hidden_states(c, backend, params, seq)
+        with jax.named_scope(looped.SCOPE_PASS.format(1)), jax.named_scope(looped.SCOPE_EXIT):
+            h = looped._rms_norm(x, params["final_norm"], c.rms_eps)
+            ce = looped._exit_ce(c, h.reshape(-1, h.shape[-1]), params["head"],
+                                 targets.reshape(-1))
+            mask = (targets.reshape(-1) > 0).astype(jnp.float32)
+            ce = (ce * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+            aux_loss = stats["aux"].mean()
+            held = stats["held_assignments"].sum()
+            out = {
+                "ce": ce, "aux_loss": aux_loss,
+                "moe_assignments": stats["assignments"].sum(),
+                "moe_held_assignments": held,
+                "moe_held_load_max": stats["held_load_max"].max(),
+                "moe_held_load_mean": held / (c.num_layers * c.held),
+                "moe_dropped": stats["dropped"].sum(),
+                "selected_pairs": stats["selected_pairs"].sum(),
+                "causal_pairs": stats["causal_pairs"].sum(),
+            }
+            return ce + c.aux_coef * aux_loss, out
+
+    return loss_fn
+
+
+def probe_selection(c: SparseMoEConfig, mesh, params, seq, queries):
+    """What the step's own index and select programs give for the query
+    positions ``queries`` in every layer: ``(scores, mask)``, each
+    ``[L, B, len(queries), T]`` (a score above the diagonal is undefined)."""
+    _, stats = hidden_states(c, _backend_of(mesh), params, seq, probe=queries)
+    return stats["probe_scores"], stats["probe_mask"]
+
+
+def score_last(c: SparseMoEConfig, params, seqs, last):
+    """Next-item scores [B, V] at position ``last`` of each row."""
+    x, _ = hidden_states(c, _backend_of(None), params, seqs)
+    h = looped._rms_norm(x, params["final_norm"], c.rms_eps)
+    h = jnp.take_along_axis(h, last[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+    return looped._matmul(h, params["head"].T, jnp.dtype(c.compute_dtype))

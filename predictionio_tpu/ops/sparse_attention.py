@@ -1,0 +1,466 @@
+"""Learned sparse attention for TPU: an indexer's scores, the k-th largest of
+every query's scores, and attention over the selected keys with grouped
+key-value heads, K and V streamed a block at a time.
+
+Three pieces, each a Pallas program with a plain ``jax.numpy`` twin (the twin
+is what runs off the TPU and what the tests hold the programs to):
+
+- :func:`index_scores`: ``I[b, t, s] = sum_j w[b, t, j] relu(qI[b, t, j] . kI[b, s])``
+  over the causal triangle, a ``[BQ, BK]`` tile a grid step, tiles above the
+  diagonal neither fetched nor computed (their values are never read);
+- :func:`select_topk`: per query the ``topk`` causal keys with the largest
+  score, as an int8 mask ``[B, T, T]`` (all of them while a query has at most
+  ``topk``; ties go to the earlier position, as ``jax.lax.top_k`` breaks
+  them). A program holds one block of queries' scores in VMEM as integers in
+  the scores' order and finds each query's k-th largest by bisection on the
+  integer's 32 bits (a count of ``>=`` a pass, no sort); ties at the threshold
+  are cut at a position found by a second bisection, run only where a query
+  of the block has more ties than places;
+- :func:`sparse_attention`: softmax attention of ``q`` ``[B, T, H, D]`` on
+  ``k``, ``v`` ``[B, T, KV, D]`` (``H // KV`` query heads a key-value head)
+  over the pairs the mask selects, with the matching custom VJP. The grid is
+  ``(B, KV, query blocks, key blocks)``: a step holds one block of K and V and
+  one tile of the mask for all the query heads of the group, the running
+  (max, sum, acc) live in VMEM scratch across the key blocks, and blocks
+  above the diagonal are neither fetched nor worked. Matmul inputs are
+  bfloat16 with float32 accumulation; the softmax is float32.
+
+The mask is the whole contract of which pairs count: causality is in it (the
+selection takes causal keys only) and nothing else masks a pair. Rows are
+left-aligned, so a padded position follows every event of its row and no
+real query can select it; a padded query's output is never read.
+
+Layout (see ``flash_attention.py`` for what the hardware asks): tensors are
+``[B, H, T, D]`` at the Pallas boundary; per-query scalars (logsumexp, delta)
+are ``[B, KV, T, G]`` with the group's heads on the lanes, so a head's column
+is a static lane slice.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from predictionio_tpu.utils.jax_compat import pallas as pl, pallas_tpu as pltpu
+
+_NEG = -1e30
+_INT_MIN = np.int32(-(2 ** 31))
+
+BLOCK_Q = 256          # queries a tile
+BLOCK_K = 512          # keys a tile
+SELECT_ROWS = 128      # queries a select program holds
+SELECT_CHUNK = 512     # keys a pass of its loops takes
+VMEM_LIMIT_BYTES = 64 << 20
+
+
+def _block(block: int, t: int) -> int:
+    """The tile edge for a length ``t``: ``block``, or ``t`` where shorter."""
+    if t <= block:
+        return t
+    if t % block:
+        raise ValueError(f"length {t} is not a multiple of the block {block}")
+    return block
+
+
+def _dot(a, b, contract_a: int, contract_b: int):
+    return jax.lax.dot_general(
+        a, b, (((contract_a,), (contract_b,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _params(*semantics):
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def _last_key_block(qi, bq: int, bk: int):
+    """The last key block that holds a causal pair with query block ``qi``."""
+    return (qi * bq + bq - 1) // bk
+
+
+def _first_query_block(ki, bq: int, bk: int):
+    """The first query block that holds a causal pair with key block ``ki``."""
+    return (ki * bk) // bq
+
+
+# ---- index scores -----------------------------------------------------------
+
+def index_scores_plain(q_idx, k_idx, w):
+    """``[B, T, T]`` float32: q_idx [B, T, HI, DI], k_idx [B, T, DI], w
+    [B, T, HI]; the matmul in the inputs' dtype, accumulated in float32.
+    Every pair is scored; the selection reads the causal ones."""
+    s = jnp.einsum("bthd,bsd->bhts", q_idx, k_idx, preferred_element_type=jnp.float32)
+    return jnp.einsum("bhts,bth->bts", jnp.maximum(s, 0.0), w.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def _index_kernel(q_ref, k_ref, w_ref, out_ref, *, bq: int, bk: int):
+    qi, ki = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ki <= _last_key_block(qi, bq, bk))
+    def _():
+        k = k_ref[0]
+        acc = jnp.zeros(out_ref.shape[1:], jnp.float32)
+        for j in range(q_ref.shape[1]):
+            acc = acc + w_ref[0, j] * jnp.maximum(_dot(q_ref[0, j], k, 1, 1), 0.0)
+        out_ref[0] = acc
+
+
+def index_scores(q_idx, k_idx, w, *, block_q=BLOCK_Q, block_k=BLOCK_K, interpret=False):
+    """The Pallas form of :func:`index_scores_plain` on the causal tiles;
+    what a tile above the diagonal holds is undefined."""
+    b, t, hi, di = q_idx.shape
+    bq, bk = _block(block_q, t), _block(block_k, t)
+    q = jnp.transpose(q_idx, (0, 2, 1, 3))                               # [B, HI, T, DI]
+    wt = jnp.transpose(w.astype(jnp.float32), (0, 2, 1))[..., None]      # [B, HI, T, 1]
+    key = lambda bb, qi, ki: (bb, jnp.minimum(ki, _last_key_block(qi, bq, bk)), 0)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_index_kernel, bq=bq, bk=bk),
+        grid=(b, t // bq, t // bk),
+        in_specs=[
+            pl.BlockSpec((1, hi, bq, di), lambda bb, qi, ki: (bb, 0, qi, 0)),
+            pl.BlockSpec((1, bk, di), key),
+            pl.BlockSpec((1, hi, bq, 1), lambda bb, qi, ki: (bb, 0, qi, 0)),
+        ],
+        out_specs=pl.BlockSpec(
+            (1, bq, bk),
+            lambda bb, qi, ki: (bb, qi, jnp.minimum(ki, _last_key_block(qi, bq, bk)))),
+        out_shape=jax.ShapeDtypeStruct((b, t, t), jnp.float32),
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
+        interpret=interpret,
+    )(q, k_idx, wt)
+
+
+# ---- the k-th largest -------------------------------------------------------
+
+def select_topk_plain(scores, topk: int):
+    """int8 ``[B, T, T]``: 1 where key ``s <= t`` is among query ``t``'s
+    ``topk`` largest scores (every causal key while ``t < topk``); of equal
+    scores at the threshold the earlier positions are taken."""
+    t = scores.shape[-1]
+    pos = jnp.arange(t)
+    causal = pos[None, :] <= pos[:, None]
+    masked = jnp.where(causal, scores, -jnp.inf)
+    if topk >= t:
+        return jnp.broadcast_to(causal, scores.shape).astype(jnp.int8)
+    kth = jax.lax.top_k(masked, topk)[0][..., -1:]
+    above = masked > kth
+    ties = (masked == kth) & causal
+    places = topk - above.sum(axis=-1, keepdims=True)
+    taken = ties & (jnp.cumsum(ties, axis=-1) <= places)
+    return ((above | taken) & causal).astype(jnp.int8)
+
+
+def _ordered(x):
+    """float32 -> int32 with the same order (finite values and infinities)."""
+    bits = jax.lax.bitcast_convert_type(jnp.where(x == 0.0, 0.0, x), jnp.int32)  # -0.0 is 0.0
+    return bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+
+
+def _select_kernel(scores_ref, mask_ref, keys_ref, *, topk: int, rows: int, chunk: int):
+    qi = pl.program_id(1)
+    t = scores_ref.shape[2]
+    chunks = t // chunk
+    used = jnp.minimum((qi * rows + rows - 1) // chunk + 1, chunks)   # hold causal keys
+    q_pos = qi * rows + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+
+    def k_pos(c):
+        return c * chunk + jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+
+    def fill(c, carry):
+        at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        keys_ref[:, at] = jnp.where(k_pos(c) <= q_pos, _ordered(scores_ref[0, :, at]),
+                                    _INT_MIN)
+        return carry
+
+    jax.lax.fori_loop(0, used, fill, 0)
+
+    def count(pred):
+        """Per query, how many of its held keys satisfy ``pred(keys, c)``."""
+        def body(c, n):
+            at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+            hit = pred(keys_ref[:, at], c)
+            return n + jnp.sum(hit.astype(jnp.int32), axis=1, keepdims=True)
+
+        return jax.lax.fori_loop(0, used, body, jnp.zeros((rows, 1), jnp.int32))
+
+    # the largest integer v with at least topk keys >= v, a bit at a time from
+    # the top; u is v's offset from INT_MIN, so v = u ^ INT_MIN as bits. With
+    # fewer than topk causal keys no bit is ever set and v stays INT_MIN
+    def bit(i, u):
+        trial = u | (jnp.int32(1) << (31 - i))
+        v = trial ^ _INT_MIN
+        enough = count(lambda keys, c: keys >= v) >= topk
+        return jnp.where(enough, trial, u)
+
+    kth = jax.lax.fori_loop(0, 32, bit, jnp.zeros((rows, 1), jnp.int32)) ^ _INT_MIN
+    def tie(keys, c):
+        return (keys == kth) & (k_pos(c) <= q_pos)
+
+    places = topk - count(lambda keys, c: keys > kth)       # for the keys == kth
+    tied = count(tie)
+
+    # where a query has more ties than places, the ties up to a position: the
+    # largest p with fewer than ``places`` ties before p, again bit by bit
+    def cut_of():
+        bits = t.bit_length()
+
+        def bit_p(i, p):
+            trial = p | (jnp.int32(1) << (bits - 1 - i))
+            before = count(lambda keys, c: tie(keys, c) & (k_pos(c) < trial))
+            return jnp.where(before < places, trial, p)
+
+        return jax.lax.fori_loop(0, bits, bit_p, jnp.zeros((rows, 1), jnp.int32))
+
+    cut = jax.lax.cond(jnp.max(tied - places) > 0, cut_of,
+                       lambda: jnp.full((rows, 1), t, jnp.int32))
+
+    def emit(c, carry):
+        at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        keys, pos = keys_ref[:, at], k_pos(c)
+        taken = ((keys > kth) | ((keys == kth) & (pos <= cut))) & (pos <= q_pos)
+        mask_ref[0, :, at] = taken.astype(jnp.int32).astype(jnp.int8)
+        return carry
+
+    def blank(c, carry):
+        at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        mask_ref[0, :, at] = jnp.zeros((rows, chunk), jnp.int8)
+        return carry
+
+    jax.lax.fori_loop(0, used, emit, 0)
+    jax.lax.fori_loop(used, chunks, blank, 0)
+
+
+def select_topk(scores, topk: int, *, rows=SELECT_ROWS, chunk=SELECT_CHUNK,
+                interpret=False):
+    """The Pallas form of :func:`select_topk_plain`; reads the causal part of
+    ``scores`` only."""
+    b, t, _ = scores.shape
+    rows, chunk = _block(rows, t), _block(chunk, t)
+    return pl.pallas_call(
+        functools.partial(_select_kernel, topk=topk, rows=rows, chunk=chunk),
+        grid=(b, t // rows),
+        in_specs=[pl.BlockSpec((1, rows, t), lambda bb, qi: (bb, qi, 0))],
+        out_specs=pl.BlockSpec((1, rows, t), lambda bb, qi: (bb, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, t, t), jnp.int8),
+        scratch_shapes=[pltpu.VMEM((rows, t), jnp.int32)],
+        compiler_params=_params("parallel", "parallel"),
+        interpret=interpret,
+    )(scores)
+
+
+# ---- attention over the selected pairs --------------------------------------
+
+def sparse_attention_plain(q, k, v, mask):
+    """Softmax attention over the pairs ``mask`` [B, T, T] selects: q
+    [B, T, H, D], k, v [B, T, KV, D] -> [B, T, H, D]; float32 softmax, the
+    matmuls in the inputs' dtype accumulated in float32."""
+    b, t, h, d = q.shape
+    kv = k.shape[2]
+    qg = q.reshape(b, t, kv, h // kv, d)
+    s = jnp.einsum("btkgd,bskd->bkgts", qg, k,
+                   preferred_element_type=jnp.float32) * d ** -0.5
+    on = (mask != 0)[:, None, None]
+    s = jnp.where(on, s, _NEG)
+    p = jnp.where(on, jnp.exp(s - s.max(axis=-1, keepdims=True)), 0.0)
+    p = p / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-20)
+    out = jnp.einsum("bkgts,bskd->btkgd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, t, h, d).astype(q.dtype)
+
+
+def _tile(mask_ref):
+    return mask_ref[0].astype(jnp.int32) != 0
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref, m_scr, l_scr, acc_scr,
+                *, bq: int, bk: int):
+    qi, ki = pl.program_id(2), pl.program_id(3)
+    heads = q_ref.shape[1]
+
+    @pl.when(ki == 0)
+    def _():
+        m_scr[...] = jnp.full(m_scr.shape, _NEG, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    @pl.when(ki <= _last_key_block(qi, bq, bk))
+    def _():
+        k, v, on = k_ref[0, 0], v_ref[0, 0], _tile(mask_ref)
+        for h in range(heads):
+            s = jnp.where(on, _dot(q_ref[0, h], k, 1, 1), _NEG)
+            m_old = m_scr[h]
+            m_new = jnp.maximum(m_old, s.max(axis=1, keepdims=True))
+            p = jnp.where(on, jnp.exp(s - m_new), 0.0)
+            scale = jnp.exp(m_old - m_new)
+            l_scr[h] = l_scr[h] * scale + p.sum(axis=1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * scale + _dot(p.astype(v.dtype), v, 1, 0)
+            m_scr[h] = m_new
+
+    @pl.when(ki == pl.num_programs(3) - 1)
+    def _():
+        for h in range(heads):
+            l = jnp.maximum(l_scr[h], 1e-20)
+            out_ref[0, h] = (acc_scr[h] / l).astype(out_ref.dtype)
+            lse_ref[0, 0, :, h:h + 1] = m_scr[h] + jnp.log(l)
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
+               *, bq: int, bk: int):
+    qi, ki = pl.program_id(2), pl.program_id(3)
+    heads = q_ref.shape[1]
+
+    @pl.when(ki == 0)
+    def _():
+        dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
+
+    @pl.when(ki <= _last_key_block(qi, bq, bk))
+    def _():
+        k, v, on = k_ref[0, 0], v_ref[0, 0], _tile(mask_ref)
+        for h in range(heads):
+            s = _dot(q_ref[0, h], k, 1, 1)
+            p = jnp.where(on, jnp.exp(s - lse_ref[0, 0, :, h:h + 1]), 0.0)
+            ds = p * (_dot(do_ref[0, h], v, 1, 1) - delta_ref[0, 0, :, h:h + 1])
+            dq_scr[h] = dq_scr[h] + _dot(ds.astype(k.dtype), k, 1, 0)
+
+    @pl.when(ki == pl.num_programs(3) - 1)
+    def _():
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+
+
+def _dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
+                dk_ref, dv_ref, dk_scr, dv_scr, *, bq: int, bk: int):
+    ki, qi = pl.program_id(2), pl.program_id(3)
+    heads = q_ref.shape[1]
+
+    @pl.when(qi == 0)
+    def _():
+        dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
+        dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
+
+    @pl.when(qi >= _first_query_block(ki, bq, bk))
+    def _():
+        k, v, on = k_ref[0, 0], v_ref[0, 0], _tile(mask_ref)
+        dk, dv = dk_scr[...], dv_scr[...]
+        for h in range(heads):
+            q, do = q_ref[0, h], do_ref[0, h]
+            s = _dot(q, k, 1, 1)
+            p = jnp.where(on, jnp.exp(s - lse_ref[0, 0, :, h:h + 1]), 0.0)
+            dv = dv + _dot(p.astype(do.dtype), do, 0, 0)
+            ds = p * (_dot(do, v, 1, 1) - delta_ref[0, 0, :, h:h + 1])
+            dk = dk + _dot(ds.astype(q.dtype), q, 0, 0)
+        dk_scr[...], dv_scr[...] = dk, dv
+
+    @pl.when(qi == pl.num_programs(3) - 1)
+    def _():
+        dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _specs(g: int, d: int, bq: int, bk: int, by_key: bool):
+    """Block specs of one call. The forward and ``dq`` walk (b, kv, qi, ki)
+    with the key block clamped to the last one under the diagonal; ``dkv``
+    walks (b, kv, ki, qi) with the query block clamped to the first at it. A
+    clamped step names the block the step before it held: nothing is fetched."""
+    if by_key:
+        at = lambda ki, qi: (jnp.maximum(qi, _first_query_block(ki, bq, bk)), ki)  # noqa: E731
+    else:
+        at = lambda qi, ki: (qi, jnp.minimum(ki, _last_key_block(qi, bq, bk)))  # noqa: E731
+
+    def spec(block, index):
+        return pl.BlockSpec(block, lambda b, kv, i, j: index(b, kv, *at(i, j)))
+
+    return {
+        "q": spec((1, g, bq, d), lambda b, kv, qi, ki: (b, kv, qi, 0)),
+        "k": spec((1, 1, bk, d), lambda b, kv, qi, ki: (b, kv, ki, 0)),
+        "mask": spec((1, bq, bk), lambda b, kv, qi, ki: (b, qi, ki)),
+        "row": spec((1, 1, bq, g), lambda b, kv, qi, ki: (b, kv, qi, 0)),
+    }
+
+
+def _heads_first(x):
+    return jnp.transpose(x, (0, 2, 1, 3))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def sparse_attention(q, k, v, mask, block_q=BLOCK_Q, block_k=BLOCK_K, interpret=False):
+    """q [B, T, H, D], k, v [B, T, KV, D], ``mask`` int8 [B, T, T] (the
+    selection: the pairs that count, causal) -> [B, T, H, D]."""
+    return _forward(q, k, v, mask, block_q, block_k, interpret)[0]
+
+
+def _forward(q, k, v, mask, block_q, block_k, interpret):
+    b, t, h, d = q.shape
+    kv = k.shape[2]
+    g = h // kv
+    bq, bk = _block(block_q, t), _block(block_k, t)
+    sp = _specs(g, d, bq, bk, by_key=False)
+    scaled = (q.astype(jnp.float32) * d ** -0.5).astype(q.dtype)
+    out, lse = pl.pallas_call(
+        functools.partial(_fwd_kernel, bq=bq, bk=bk),
+        grid=(b, kv, t // bq, t // bk),
+        in_specs=[sp["q"], sp["k"], sp["k"], sp["mask"]],
+        out_specs=[sp["q"], sp["row"]],
+        out_shape=[jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
+                   jax.ShapeDtypeStruct((b, kv, t, g), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((g, bq, 1), jnp.float32),
+                        pltpu.VMEM((g, bq, 1), jnp.float32),
+                        pltpu.VMEM((g, bq, d), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "parallel", "arbitrary"),
+        interpret=interpret,
+    )(_heads_first(scaled), _heads_first(k), _heads_first(v), mask)
+    return _heads_first(out), lse
+
+
+def _fwd(q, k, v, mask, block_q, block_k, interpret):
+    out, lse = _forward(q, k, v, mask, block_q, block_k, interpret)
+    return out, (q, k, v, mask, out, lse)
+
+
+def _bwd(block_q, block_k, interpret, res, g_out):
+    q, k, v, mask, out, lse = res
+    b, t, h, d = q.shape
+    kv = k.shape[2]
+    g = h // kv
+    bq, bk = _block(block_q, t), _block(block_k, t)
+    scale = d ** -0.5
+    # delta[b, t, h] = rowsum(dO o O), laid out like the logsumexp
+    delta = jnp.einsum("bthd,bthd->bth", g_out.astype(jnp.float32),
+                       out.astype(jnp.float32))
+    delta = jnp.transpose(delta.reshape(b, t, kv, g), (0, 2, 1, 3))
+    qs = _heads_first((q.astype(jnp.float32) * scale).astype(q.dtype))
+    kt, vt, do = _heads_first(k), _heads_first(v), _heads_first(g_out.astype(q.dtype))
+
+    sp = _specs(g, d, bq, bk, by_key=False)
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, bq=bq, bk=bk),
+        grid=(b, kv, t // bq, t // bk),
+        in_specs=[sp["q"], sp["k"], sp["k"], sp["mask"], sp["q"], sp["row"], sp["row"]],
+        out_specs=sp["q"],
+        out_shape=jax.ShapeDtypeStruct((b, h, t, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((g, bq, d), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "parallel", "arbitrary"),
+        interpret=interpret,
+    )(qs, kt, vt, mask, do, lse, delta)
+
+    sp = _specs(g, d, bq, bk, by_key=True)
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, bq=bq, bk=bk),
+        grid=(b, kv, t // bk, t // bq),
+        in_specs=[sp["q"], sp["k"], sp["k"], sp["mask"], sp["q"], sp["row"], sp["row"]],
+        out_specs=[sp["k"], sp["k"]],
+        out_shape=[jax.ShapeDtypeStruct((b, kv, t, d), jnp.float32)] * 2,
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32)] * 2,
+        compiler_params=_params("parallel", "parallel", "parallel", "arbitrary"),
+        interpret=interpret,
+    )(qs, kt, vt, mask, do, lse, delta)
+
+    # dq was taken against the scaled q; dk already carries the scale
+    return ((_heads_first(dq) * scale).astype(q.dtype), _heads_first(dk).astype(k.dtype),
+            _heads_first(dv).astype(v.dtype), np.zeros(mask.shape, jax.dtypes.float0))
+
+
+sparse_attention.defvjp(_fwd, _bwd)

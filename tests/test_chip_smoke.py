@@ -14,7 +14,8 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PHASES = ["device", "compile_cache", "ingest", "train_als", "als_full_width",
-          "serve_als", "train_serve_ncf", "train_sequence_looped"]
+          "serve_als", "train_serve_ncf", "train_sequence_looped",
+          "train_sequence_sparse_moe"]
 
 
 def _run(args, tmp_path, timeout, **env_overrides):
@@ -49,6 +50,10 @@ def test_rehearsal_reaches_every_phase_then_refuses_the_cpu(tmp_path):
     assert all(r["agrees"] for r in by_phase["als_full_width"]["runs"])
     looped = by_phase["train_sequence_looped"]
     assert looped["backbone"] == "looped" and looped["last_loss"] < looped["first_loss"]
+    sparse = by_phase["train_sequence_sparse_moe"]
+    assert sparse["backbone"] == "sparse_moe" and sparse["last_loss"] < sparse["first_loss"]
+    assert (sparse["experts_held"], sparse["experts_total"], sparse["moe_dropped"]) == (4, 16, 0)
+    assert 0 < sparse["selected_pairs"] < sparse["causal_pairs"]
 
 
 def test_without_a_chip_the_default_run_stops_at_the_device_phase(tmp_path):

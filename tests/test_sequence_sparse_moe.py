@@ -1,0 +1,345 @@
+"""The sparse backbone of the sequence template (learned sparse attention, a
+routed mixture of experts of which the program holds a share) against its
+plain reference (``benchmarks/reference_keye.py``), at a small size with
+seeded weights: loss, auxiliary loss and every gradient with a history
+several times ``index_topk`` long; the three programs of
+``ops/sparse_attention.py`` in interpret mode against their plain twins; the
+k-th largest against ``jax.lax.top_k``, ties included; the eight shares of a
+layer add up to the whole layer; nothing is dropped under a skewed router;
+the engine takes the backbone by name."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks import reference_keye as ref
+from benchmarks import seeded_histories
+from predictionio_tpu.models.sequence import sparse_moe
+from predictionio_tpu.models.sequence.model import (
+    make_fit, score_next_items_batch, train_sasrec,
+)
+from predictionio_tpu.models.sequence.sparse_moe import SparseMoEConfig
+from predictionio_tpu.ops import sparse_attention as sa
+
+VOCAB, T, ROWS, TOPK = 256, 64, 3, 16
+DIMS = dict(num_heads=4, num_kv_heads=2, head_dim=16, index_heads=2, index_dim=8,
+            index_topk=TOPK, experts_per_token=2, experts_held=(2, 6),
+            rope_theta=1e7, rms_eps=1e-6, query_block=16)
+AUX = 0.01
+
+
+def _config(**kw) -> SparseMoEConfig:
+    base = dict(num_items=VOCAB - 1, max_len=T, hidden_size=32, num_heads=4,
+                num_kv_heads=2, head_dim=16, expert_dim=24, num_experts=8,
+                experts_per_token=2, experts_held=(2, 6), num_layers=2,
+                index_heads=2, index_dim=8, index_topk=TOPK, aux_coef=AUX,
+                compute_dtype="float32", attention="plain", head_chunk=64, moe_chunk=64)
+    base.update(kw)
+    return SparseMoEConfig(**base)
+
+
+def _mesh():
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "seq"))
+
+
+@pytest.fixture(scope="module")
+def params():
+    drawn = seeded_histories.make_params(sparse_moe.param_shapes(_config()), seed=5)
+    # an indexer and a router wide enough that neither choice is near a tie
+    for name in drawn["indexer"]:
+        drawn["indexer"][name] = drawn["indexer"][name] * 10
+    drawn["layers"]["router"] = drawn["layers"]["router"] * 10
+    return drawn
+
+
+@pytest.fixture(scope="module")
+def batch():
+    rng = np.random.default_rng(11)
+    seq = rng.integers(1, VOCAB, (ROWS, T)).astype(np.int32)
+    seq[1, 40:] = 0  # a padded tail: routed nowhere, counted nowhere
+    targets = np.zeros_like(seq)
+    targets[:, :-1] = seq[:, 1:]
+    return seq, targets
+
+
+def _reference(params, batch, dims=DIMS, how=ref.SOUND):
+    seq, targets = (jnp.asarray(a) for a in batch)
+    return jax.jit(lambda p: ref.loss_and_grads(p, seq, targets, dims, AUX, how))(params)
+
+
+@pytest.fixture(scope="module")
+def sound(params, batch):
+    return _reference(params, batch)
+
+
+def _flat(tree):
+    leaves = jax.tree_util.tree_flatten_with_path(tree)[0]
+    return {".".join(k.key for k in path): np.asarray(a) for path, a in leaves}
+
+
+@pytest.mark.parametrize("attention", ["plain", "flash"])
+def test_loss_auxiliary_loss_and_every_gradient_match_the_reference(
+        params, batch, sound, attention):
+    """``T`` is four times ``index_topk``: three quarters of the queries read a
+    selection. "flash" is the three Pallas programs, interpreted."""
+    config = _config(attention=attention)
+    loss_fn = sparse_moe.make_loss(config, _mesh())
+    (loss, aux), grads = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))(
+        params, {"seq": jnp.asarray(batch[0]), "target": jnp.asarray(batch[1])}, None)
+    want, want_aux, want_grads = sound
+    assert abs(float(loss) - float(want)) < 2e-5
+    assert abs(float(aux["ce"]) - float(want_aux["ce"])) < 2e-5
+    assert abs(float(aux["aux_loss"]) - float(want_aux["aux_loss"])) < 2e-5
+    assert float(aux["aux_loss"]) > 1.0       # E sum f P is K when balanced
+    have, want_flat = _flat(grads), _flat(want_grads)
+    assert sorted(have) == sorted(want_flat)
+    for name, g in want_flat.items():
+        if name.startswith("indexer."):
+            assert not have[name].any() and not g.any(), name
+            continue
+        scale = np.abs(g).max()
+        assert scale > 0, name
+        assert np.abs(have[name] - g).max() < 2e-3 * scale, name
+    real = int((batch[0] > 0).sum())
+    assert int(aux["moe_assignments"]) == 2 * 2 * real     # layers x K x real tokens
+    assert 0 < int(aux["moe_held_assignments"]) < int(aux["moe_assignments"])
+    assert int(aux["moe_dropped"]) == 0
+    lengths = (batch[0] > 0).sum(axis=1)
+    causal = int(sum(n * (n + 1) // 2 for n in lengths))
+    selected = int(sum(min(t + 1, TOPK) for n in lengths for t in range(n)))
+    assert int(aux["causal_pairs"]) == 2 * causal
+    assert int(aux["selected_pairs"]) == 2 * selected
+
+
+@pytest.mark.parametrize("control,tensor", [
+    ({"selection": "window"}, "layers.wq"), ({"renormalise": False}, "layers.w_down"),
+    ({"precision": "bfloat16"}, "layers.router")])
+def test_each_control_of_the_reference_reads_other_gradients(
+        params, batch, sound, control, tensor):
+    """What the benchmark's ``--control 1`` plants, at this size: each moves a
+    gradient of the path it touches by far more than the program differs."""
+    sound = _flat(sound[2])[tensor]
+    wrong = _flat(_reference(params, batch, how={**ref.SOUND, **control})[2])[tensor]
+    assert np.linalg.norm(wrong - sound) > 1e-2 * np.linalg.norm(sound)
+
+
+def test_remat_and_chunks_change_nothing(params, batch):
+    feed = {"seq": jnp.asarray(batch[0]), "target": jnp.asarray(batch[1])}
+    values = []
+    for how in ({}, {"remat": False, "head_chunk": 0, "moe_chunk": 4 * T}):
+        fn = sparse_moe.make_loss(_config(**how), _mesh())
+        (loss, _), grads = jax.jit(jax.value_and_grad(fn, has_aux=True))(params, feed, None)
+        values.append((float(loss), _flat(grads)))
+    assert abs(values[0][0] - values[1][0]) < 1e-5
+    for name, g in values[0][1].items():
+        assert np.abs(g - values[1][1][name]).max() <= 1e-4 * max(np.abs(g).max(), 1e-12), name
+
+
+# ---- the three programs ------------------------------------------------------
+
+def _scores(rng, b=2, t=256, quantum=None):
+    q = jnp.asarray(rng.standard_normal((b, t, 4, 16)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, t, 16)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((b, t, 4)), jnp.float32)
+    scores = sa.index_scores_plain(q, k, w)
+    if quantum:
+        scores = jnp.round(scores / quantum) * quantum + 0.0     # no -0.0: top_k orders it
+    return q, k, w, scores
+
+
+def test_index_scores_program_matches_its_twin_on_the_causal_tiles():
+    q, k, w, want = _scores(np.random.default_rng(0))
+    have = sa.index_scores(q, k, w, block_q=64, block_k=128, interpret=True)
+    causal = np.tril(np.ones((256, 256), bool))
+    assert np.abs(np.asarray(have) - np.asarray(want))[:, causal].max() < 1e-4
+
+
+@pytest.mark.parametrize("quantum", [None, 0.5, 4.0], ids=["distinct", "ties", "mostly-ties"])
+@pytest.mark.parametrize("topk", [48, 200])
+def test_the_kth_largest_matches_top_k_ties_included(quantum, topk):
+    """Against ``jax.lax.top_k`` over the causal scores, which takes the lower
+    index of equal values: the program's bisection and its cut of the ties,
+    and the plain twin's threshold, select exactly top_k's positions."""
+    _, _, _, scores = _scores(np.random.default_rng(1), quantum=quantum)
+    t = scores.shape[-1]
+    causal = np.tril(np.ones((t, t), bool))
+    masked = jnp.where(causal, scores, -jnp.inf)
+    _, index = jax.lax.top_k(masked, topk)
+    want = np.zeros(scores.shape, bool)
+    np.put_along_axis(want, np.asarray(index), True, axis=-1)
+    want &= causal
+    if quantum:
+        threshold = np.sort(np.where(causal, scores, -np.inf), axis=-1)[..., -topk]
+        assert ((np.asarray(scores) == threshold[..., None]) & causal).sum(-1).max() > 1
+    twin = np.asarray(sa.select_topk_plain(scores, topk)).astype(bool)
+    program = np.asarray(sa.select_topk(scores, topk, rows=32, chunk=128,
+                                        interpret=True)).astype(bool)
+    assert (twin == want).all()
+    assert (program == want).all()
+    assert (program.sum(-1)[:, topk:] == topk).all()
+    assert (program.sum(-1)[:, :topk] == np.arange(1, topk + 1)).all()
+
+
+def test_attention_program_matches_masked_plain_attention_forward_and_gradients():
+    """Grouped heads (4 query heads a key head), a selection, ``T`` of four
+    query blocks and two key blocks; bfloat16 inputs as the backbone hands
+    them, the twin on the same inputs."""
+    rng = np.random.default_rng(2)
+    b, t, h, kv, d = 2, 256, 8, 2, 32
+    _, _, _, scores = _scores(rng)
+    mask = sa.select_topk_plain(scores, 64)
+    q, k, v = (jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+               for shape in ((b, t, h, d), (b, t, kv, d), (b, t, kv, d)))
+    weight = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
+
+    def total(fn):
+        return lambda q, k, v: (fn(q, k, v).astype(jnp.float32) * weight).sum()
+
+    program = lambda q, k, v: sa.sparse_attention(q, k, v, mask, 64, 128, True)  # noqa: E731
+    twin = lambda q, k, v: sa.sparse_attention_plain(q, k, v, mask)  # noqa: E731
+    have, want = program(q, k, v), twin(q, k, v)
+    assert np.abs(np.asarray(have, np.float32) - np.asarray(want, np.float32)).max() < 2e-2
+    have_g = jax.grad(total(program), (0, 1, 2))(q, k, v)
+    want_g = jax.grad(total(twin), (0, 1, 2))(q, k, v)
+    for a, g in zip(have_g, want_g):
+        a, g = np.asarray(a, np.float32), np.asarray(g, np.float32)
+        assert np.linalg.norm(a - g) < 2e-2 * np.linalg.norm(g)
+    # float32 inputs: the same arithmetic to rounding
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    assert np.abs(np.asarray(program(*f32)) - np.asarray(twin(*f32))).max() < 1e-5
+    for a, g in zip(jax.grad(total(program), (0, 1, 2))(*f32),
+                    jax.grad(total(twin), (0, 1, 2))(*f32)):
+        assert np.abs(np.asarray(a) - np.asarray(g)).max() < 1e-4 * np.abs(np.asarray(g)).max()
+
+
+# ---- the experts -------------------------------------------------------------
+
+def test_the_eight_shares_add_up_to_the_whole_layer(params):
+    """Eight programs, each holding one of 8 experts with the same router,
+    add up to the reference's uncut layer (every expert held)."""
+    rng = np.random.default_rng(4)
+    u = jnp.asarray(rng.standard_normal((96, 32)), jnp.float32)
+    real = jnp.asarray(np.arange(96) < 90)
+    whole_shapes = sparse_moe.param_shapes(_config(experts_held=(0, 8)))["layers"]
+    drawn = seeded_histories.make_params(
+        {k: whole_shapes[k][1:] for k in ("router", "w_gate", "w_up", "w_down")}, seed=9)
+    drawn["router"] = drawn["router"] * 10
+    dims = {**DIMS, "experts_held": (0, 8)}
+    with jax.default_matmul_precision("highest"):
+        _, experts, gates = ref.routing(drawn, u, dims, ref.SOUND)
+        want = ref.experts_part(drawn, u, experts, gates, real, dims)
+    total, held = 0.0, 0
+    for e in range(8):
+        config = _config(experts_held=(e, e + 1))
+        share = {"router": drawn["router"],
+                 **{k: drawn[k][e:e + 1] for k in ("w_gate", "w_up", "w_down")}}
+        y, stats = sparse_moe._moe(config, u, share, real)
+        assert int(stats["dropped"]) == 0
+        held += int(stats["held_assignments"])
+        total = total + y
+    assert held == int(stats["assignments"]) == 2 * 90
+    assert np.abs(np.asarray(total) - np.asarray(want)).max() < 1e-5
+    assert not np.asarray(total)[90:].any()      # a padded slot gets nothing
+
+
+def test_nothing_is_dropped_under_a_router_skewed_onto_one_expert(params):
+    """Every token's first choice is expert 2, one of the two held here: it
+    alone takes a row a token, four times the even share, and every row is
+    worked. The tokens are positive and expert 2's column of the router
+    outweighs every other."""
+    config = _config(experts_held=(2, 4))
+    dims = {**DIMS, "experts_held": (2, 4)}
+    u = jnp.abs(jnp.asarray(np.random.default_rng(6).standard_normal((3 * T, 32)), jnp.float32))
+    layer = {k: jnp.asarray(v[0]) for k, v in params["layers"].items()}
+    layer.update({k: layer[k][:2] for k in ("w_gate", "w_up", "w_down")})
+    real = jnp.ones((3 * T,), bool)
+    even, _ = sparse_moe._moe(config, u, layer, real)
+    layer["router"] = layer["router"].at[:, 2].set(jnp.abs(layer["router"]).sum(axis=1) + 1.0)
+    y, stats = sparse_moe._moe(config, u, layer, real)
+    assert int(stats["held_load_max"]) == 3 * T
+    assert int(stats["held_assignments"]) > 3 * T and int(stats["dropped"]) == 0
+    with jax.default_matmul_precision("highest"):
+        _, experts, gates = ref.routing(layer, u, dims, ref.SOUND)
+        want = ref.experts_part(layer, u, experts, gates, real, dims)
+    assert (np.asarray(experts)[:, 0] == 2).all()
+    assert np.abs(np.asarray(y) - np.asarray(want)).max() < 1e-5
+    assert np.abs(np.asarray(even)).max() > 0
+
+
+# ---- the template ------------------------------------------------------------
+
+def test_the_engine_takes_the_backbone_and_names_all_three_when_it_refuses():
+    from predictionio_tpu.controller.base import Params
+    from predictionio_tpu.models.sequence.engine import SASRecAlgorithm
+
+    config = SASRecAlgorithm(Params({
+        "backbone": "sparse_moe", "hiddenSize": 2048, "numHeads": 32, "numKvHeads": 4,
+        "headDim": 128, "expertDim": 768, "numExperts": 128, "expertsPerToken": 8,
+        "expertsHeld": [0, 16], "numLayers": 6, "indexHeads": 16, "indexDim": 64,
+        "indexTopk": 2048, "ropeTheta": 10000000, "batchSize": 2}))._config(18991, 8192)
+    assert isinstance(config, SparseMoEConfig) and config.held == 16
+    assert sparse_moe.count_params(config) == 659_187_712
+    assert sparse_moe.moe_chunk_of(config) == 4096
+    whole = SASRecAlgorithm(Params({"backbone": "sparse_moe", "numExperts": 16}))._config(12, 64)
+    assert whole.experts_held == (0, 16)
+    with pytest.raises(ValueError, match="'sasrec', 'looped', 'sparse_moe'"):
+        SASRecAlgorithm(Params({"backbone": "mamba"}))._config(12, 64)
+    with pytest.raises(ValueError, match="experts_held"):
+        SASRecAlgorithm(Params({"backbone": "sparse_moe", "expertsHeld": [4, 12]}))._config(12, 64)
+
+
+def test_the_indexer_is_fixed_and_carries_no_optimizer_state(params, batch):
+    config = _config()
+    _, place, step_fn, _ = make_fit(config, _mesh())
+    placed, opt_state = place(params)
+    leaves = jax.tree_util.tree_leaves(opt_state)
+    trained = sparse_moe.count_params(config) - sum(
+        int(np.prod(s)) for s in sparse_moe.param_shapes(config)["indexer"].values())
+    assert sum(a.size for a in leaves if a.ndim) == 2 * trained      # Adam's two moments
+    feed = {"seq": jnp.asarray(batch[0]), "target": jnp.asarray(batch[1])}
+    after, _, loss, aux = step_fn(placed, opt_state, feed, jax.random.PRNGKey(0))
+    assert np.isfinite(float(loss)) and int(aux["moe_dropped"]) == 0
+    for name, before in params["indexer"].items():
+        assert (np.asarray(after["indexer"][name]) == before).all()
+    assert (np.asarray(after["layers"]["router"]) != params["layers"]["router"]).any()
+
+
+def _cyclic(n_items=12, t=8, rows=96, seed=0):
+    starts = np.random.default_rng(seed).integers(0, n_items, rows)
+    return ((starts[:, None] + np.arange(t)[None, :]) % n_items + 1).astype(np.int32)
+
+
+def test_the_backbone_learns_a_cycle_and_reports_its_fit(caplog):
+    import logging
+
+    from predictionio_tpu.obs.trace import global_tracer
+
+    config = SparseMoEConfig(
+        num_items=12, max_len=8, hidden_size=32, num_heads=4, num_kv_heads=2, head_dim=8,
+        expert_dim=32, num_experts=4, experts_per_token=2, experts_held=(0, 4),
+        num_layers=1, index_heads=2, index_dim=8, index_topk=4, learning_rate=0.01,
+        batch_size=32, epochs=12, attention="plain")
+    with caplog.at_level(logging.INFO, logger="pio.sequence"):
+        trained, losses = train_sasrec(config, _cyclic(), _mesh(), log_every=1)
+    assert losses[-1] < 0.6 * losses[0]
+    hits = 0
+    for start in range(12):
+        prefix = (start + np.arange(4)) % 12 + 1
+        scores = score_next_items_batch(trained, config, [prefix])[0]
+        hits += int(np.argmax(scores) == (start + 4) % 12)
+    assert hits >= 10
+    attrs = next(s for tr in global_tracer().snapshot(limit=50)["recent"]
+                 for s in tr["spans"] if s["op"] == "seq.fit")["attrs"]
+    assert attrs["backbone"] == "sparse_moe" and attrs["passes"] == 1
+    assert (attrs["experts_total"], attrs["experts_held"], attrs["experts_per_token"],
+            attrs["index_topk"], attrs["kv_heads"]) == (4, 4, 2, 4, 2)
+    assert attrs["moe_dropped"] == 0 and attrs["moe_held_assignments"] == attrs["moe_assignments"]
+    assert attrs["selected_pairs"] == 32 * (1 + 2 + 3 + 4 * 5)
+    assert attrs["causal_pairs"] == 32 * 36
+    line = next(r.getMessage() for r in caplog.records if "seq_fit:" in r.getMessage())
+    for word in ("backbone=sparse_moe", "experts_held=4", "experts_total=4", "index_topk=4",
+                 "moe_dropped=0", "moe_held_load_max=", "selected_pairs="):
+        assert word in line, (word, line)

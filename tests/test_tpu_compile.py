@@ -520,3 +520,53 @@ def test_the_sequence_cells_step_fits_the_chip_at_6_layers_and_not_at_8(
     else:
         with pytest.raises(Exception, match=r"RESOURCE_EXHAUSTED(.|\n)*hbm"):
             lowered.compile()
+
+
+def test_the_lifelong_histories_cells_step_fits_the_chip_at_6_layers_and_scopes_its_work(
+        topo, no_persistent_cache):
+    """The step of ``keye-vl2-30b-a3b-ep8.train-lifelong-histories`` (2 rows of
+    8,192 at the published widths, 16 of 128 experts held, an eighth of the
+    vocabulary) at the 6 layers ``benchmarks/configs/keye-vl2-30b-a3b-ep8.json``
+    holds: Mosaic takes the three programs of ``ops/sparse_attention.py`` at
+    that size, the grouped matmuls lower to the chip's own ragged dot, the
+    peak is under the chip's 15.75 GB, and every program sits under the scope
+    the benchmark's reader looks for, forward, recomputed and backward."""
+    import re
+
+    from benchmarks import scopes_sparse
+    from predictionio_tpu.models.sequence import model as seq_model, sparse_moe
+
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "seq"))
+    config = sparse_moe.SparseMoEConfig(
+        num_items=18_991, max_len=8192, hidden_size=2048, num_heads=32, num_kv_heads=4,
+        head_dim=128, expert_dim=768, num_experts=128, experts_per_token=8,
+        experts_held=(0, 16), num_layers=6, index_heads=16, index_dim=64, index_topk=2048,
+        batch_size=2)
+    assert sparse_moe.count_params(config) == 659_187_712
+    _, _, step_fn, seq_shard = seq_model.make_fit(config, mesh)
+    rep = NamedSharding(mesh, P())
+    sds = lambda shape, dtype, sh: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)  # noqa: E731
+    params = jax.tree_util.tree_map(
+        lambda shape: sds(shape, jnp.float32, rep), sparse_moe.param_shapes(config),
+        is_leaf=lambda x: isinstance(x, tuple))
+    opt_state = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype, rep),
+        jax.eval_shape(seq_model.optimizer_of(config).init, params))
+    batch = {k: sds((2, 8192), jnp.int32, seq_shard) for k in ("seq", "target")}
+    compiled = step_fn.lower(params, opt_state, batch, sds((2,), jnp.uint32, rep)).compile()
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert 13.5e9 < peak < 15.5e9, peak       # 14.77 GB of the 16.9 the chip gives
+    text = compiled.as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
+    stages = [scopes_sparse.parse_stage(c) for c in calls]
+    kinds = [scopes_sparse.kernel_kind(c) for c in calls]
+    # forward and recomputed: one index, one select and one attention program
+    # each; backward: dq and dkv. The experts' grouped matmuls are custom calls too
+    assert stages.count("index") == 2 and stages.count("select") == 2
+    assert kinds.count("forward") == 2 and kinds.count("backward") == 2
+    # the held experts: three grouped matmuls forward, three a recomputation and
+    # six backward, XLA's own ragged dot, which keeps its own name and no scope
+    grouped = re.findall(
+        r"(%ragged-dot-none(?:\.\d+)?) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    assert len(grouped) == 12, grouped
+    assert all(scopes_sparse.stage_of(g, "ragged-dot-none") == "experts" for g in grouped)
