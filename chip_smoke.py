@@ -53,7 +53,8 @@ SEED = 0
 #: what the sparse backbone's ``seq_fit:`` line says beside the losses
 SPARSE_FIT_FACTS = ("experts_total", "experts_held", "experts_per_token", "index_topk",
                     "moe_assignments", "moe_held_assignments", "moe_held_load_max",
-                    "moe_dropped", "selected_pairs", "causal_pairs")
+                    "moe_dropped", "moe_passes", "moe_passes_run", "selected_pairs",
+                    "causal_pairs")
 
 
 # ---------------------------------------------------------------------------
@@ -638,6 +639,10 @@ class Smoke:
         if facts.get("moe_dropped") != 0 or not (
                 0 < facts.get("moe_held_assignments", 0) < facts["moe_assignments"]):
             raise PhaseFailed(f"train_sequence_sparse_moe: tokens dropped, or no share: {facts}")
+        # the experts' rows go through in passes: at least one a layer ran, and
+        # no more than the worst case would take
+        if not 2 <= facts.get("moe_passes_run", 0) <= facts.get("moe_passes", 0):
+            raise PhaseFailed(f"train_sequence_sparse_moe: the experts' passes: {facts}")
         if not 0 < facts.get("selected_pairs", 0) < facts["causal_pairs"]:
             raise PhaseFailed(f"train_sequence_sparse_moe: no selection (a dense path?): {facts}")
         first, last = facts["first_loss"], facts["last_loss"]

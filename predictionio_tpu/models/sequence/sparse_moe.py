@@ -56,11 +56,18 @@ with none of this):
   their ``jax.numpy`` twins;
 - experts: a token's assignments to held experts are sorted by expert and
   worked as grouped matmuls (``jax.lax.ragged_dot``) over exactly those rows:
-  no capacity, no token dropped. Tokens go through in chunks whose worst case
-  (every assignment of the chunk held) fits ``MOE_CHUNK_BYTES``, each chunk
-  recomputed in the backward pass; the permutations in and out are gathers in
-  both directions (``_dispatch``, ``_permute``: on the chip a scatter of rows
-  cost more than the whole of this, PERF.md PR 33);
+  no capacity, no token dropped. Every row array is as long as a static bound
+  ``R`` (``pass_plan``: twice the held experts' even share of the tokens
+  worked at once, within ``MOE_CHUNK_BYTES``), not as the worst case: pass
+  ``p`` works the sorted rows ``[p R, (p + 1) R)``, and a pass past the last
+  held row is skipped at run time (``lax.cond``), forward and backward. An
+  even router takes one pass a layer, a skewed one as many as it needs, and
+  with every expert held the bound is the worst case. Rows come from their
+  tokens by a gather and go back by a gather too: a token sums, by the
+  position the sort gave each of its assignments, its rows of the pass's
+  short array (``_sum_by_position``; a scatter of rows cost the chip more
+  than the whole of this, PERF.md PR 33). The experts' part keeps its
+  operands alone and is worked again in the backward pass;
 - the head and loss are ``looped._exit_ce``'s chunks of positions.
 """
 
@@ -88,9 +95,13 @@ SCOPE_MOE = "moe"
 SCOPE_ROUTE = "route"
 SCOPE_EXPERTS = "experts"
 
-#: the most float32 bytes the held experts' output rows of one chunk of
-#: tokens may take, were every assignment of the chunk to a held expert
+#: the most float32 bytes the held experts' output rows of one pass may take
 MOE_CHUNK_BYTES = 256 << 20
+#: a pass's rows over the held experts' even share of the tokens worked at
+#: once: PERF.md PR 33 read 0.99 to 1.02 of that share a step over 14 seeds
+#: (single experts up to 1.58 of theirs, PR 32; the sum over those held is
+#: steadier than any one); a router that sends more takes further passes
+MOE_ROWS_OVER_EVEN = 2
 
 
 @dataclass(frozen=True)
@@ -156,15 +167,6 @@ class SparseMoEConfig:
     @property
     def held(self) -> int:
         return self.experts_held[1] - self.experts_held[0]
-
-
-def moe_chunk_of(c: SparseMoEConfig) -> int:
-    """Tokens of a layer's experts worked at once: a multiple of 128 whose
-    worst case of held rows keeps within ``MOE_CHUNK_BYTES``."""
-    if c.moe_chunk is not None:
-        return c.moe_chunk
-    slots = min(c.experts_per_token, c.held)
-    return max(128, MOE_CHUNK_BYTES // (4 * slots * c.hidden_size) // 128 * 128)
 
 
 def param_shapes(c: SparseMoEConfig) -> dict:
@@ -274,53 +276,173 @@ def _attention(c: SparseMoEConfig, backend: str, rope, h, p, ip, real, probe=Non
 
 # ---- routed experts ---------------------------------------------------------
 
+def pass_plan(c: SparseMoEConfig, n: int) -> tuple[int, int]:
+    """``(R, passes)`` for ``n`` tokens worked at once, from static shapes: a
+    pass works ``R`` sorted rows, ``MOE_ROWS_OVER_EVEN`` times the held experts'
+    even share in whole 128s and no more than the worst case (every token's
+    ``min(K, held)`` slots held); the passes cover that worst case."""
+    worst = n * min(c.experts_per_token, c.held)
+    share = -(-MOE_ROWS_OVER_EVEN * n * c.experts_per_token * c.held // c.num_experts)
+    bound = min(-(-share // 128) * 128, worst)
+    return bound, -(-worst // bound)
+
+
+def moe_chunk_of(c: SparseMoEConfig) -> int:
+    """Tokens of a layer's experts worked at once: the most, in whole 128s,
+    whose pass of rows (``pass_plan``) keeps within ``MOE_CHUNK_BYTES``."""
+    if c.moe_chunk is not None:
+        return c.moe_chunk
+    rows = MOE_CHUNK_BYTES // (4 * c.hidden_size)
+    by_worst = rows // min(c.experts_per_token, c.held)
+    by_share = (rows // 128 * 128 * c.num_experts
+                // (MOE_ROWS_OVER_EVEN * c.experts_per_token * c.held))
+    return max(128, max(by_worst, by_share) // 128 * 128)
+
+
+def _cut(a, chunk: int):
+    """``a`` as ``[chunks, chunk, ...]``, its leading axis padded with zeros."""
+    pad = -a.shape[0] % chunk
+    return jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)).reshape(
+        -1, chunk, *a.shape[1:])
+
+
+def _sum_by_position(rows, pos, weight):
+    """``y[t] = sum_k weight[t, k] rows[pos[t, k]]`` over the ``k`` whose
+    weight is not 0, in float32: a token's rows of a pass, found where the sort
+    put them. Gathers from the short array ``rows`` [R, D], in chunks of tokens
+    whose gathered ``[tokens, K, D]`` block keeps within ``MOE_CHUNK_BYTES``."""
+    n, slots = pos.shape
+
+    def block(at):
+        pos, weight = at
+        return jnp.where(weight[..., None] != 0, rows[pos] * weight[..., None], 0.0).sum(axis=1)
+
+    chunk = max(1, MOE_CHUNK_BYTES // (4 * slots * rows.shape[-1]))
+    if chunk >= n:
+        return block((pos, weight))
+    y = jax.lax.map(block, (_cut(pos, chunk), _cut(weight, chunk)))
+    return y.reshape(-1, y.shape[-1])[:n]
+
+
 @jax.custom_vjp
-def _permute(a, index, back):
-    """``a[index]`` for a permutation ``index`` whose inverse is ``back``: the
-    transpose is the gather ``g[back]``, never a scatter."""
-    return a[index]
+def _take_rows(u, token, live, pos, mine):
+    """Row ``r`` of a pass takes its token, ``u[token[r]]``; rows past the
+    pass's ``live`` ones are 0. The transpose sums a token's rows by position."""
+    return jnp.where(live, u[token], 0)
 
 
-_permute.defvjp(lambda a, index, back: (a[index], (index, back)),
-                lambda res, g: (g[res[1]], None, None))
+_take_rows.defvjp(
+    lambda u, token, live, pos, mine: (_take_rows(u, token, live, pos, mine), (pos, mine)),
+    lambda res, g: (_sum_by_position(g, res[0], res[1].astype(jnp.float32)).astype(g.dtype),
+                    None, None, None, None))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch(u, order, back, slots: int):
-    """Row ``r`` of the sorted assignments takes its token: ``u[order // slots]``.
-    The transpose sums a token's ``slots`` rows, found by ``back``."""
-    return u[order // slots]
+@jax.custom_vjp
+def _give_back(out, gates, row, live, pos, mine):
+    """A token's sum of its rows of the pass, ``out`` [R, D], each times the
+    gate of its assignment: ``[n, D]`` float32. ``row`` [R] is a row's
+    assignment, an index into ``gates`` [n, K]. The transpose gathers ``R``
+    rows of ``dy`` and, for the gates, a row dot: no scatter either way."""
+    return _sum_by_position(out, pos, jnp.where(mine, gates, 0.0))
 
 
-_dispatch.defvjp(
-    lambda u, order, back, slots: (u[order // slots], (back,)),
-    lambda slots, res, g: (g[res[0]].reshape(-1, slots, g.shape[-1]).sum(axis=1).astype(g.dtype),
-                           None, None))
+def _give_back_bwd(res, dy):
+    out, gates, row, live, pos, mine = res
+    sent = dy[row // mine.shape[1]]                                # [R, D]
+    d_gate = jnp.where(live[:, 0], (out * sent).sum(axis=-1), 0.0)
+    return (jnp.where(live, sent * gates.reshape(-1)[row][:, None], 0.0),
+            jnp.where(mine, d_gate[pos], 0.0), None, None, None, None)
+
+
+_give_back.defvjp(lambda *args: (_give_back(*args), args), _give_back_bwd)
+
+
+def _one_pass(u, gates, w_gate, w_up, w_down, back, row, sizes, start):
+    """What one pass adds to the tokens ``[n, D]``: the sorted rows ``[start,
+    start + R)``, the assignments ``row`` [R], of which the first
+    ``sizes.sum()`` are held (``sizes`` [held]: the pass's share of each
+    expert's rows), through the three grouped matmuls and back."""
+    worked = sizes.sum()
+    # rows past the held ones belong to no group: whatever a grouped matmul
+    # leaves there goes no further, forward or backward
+    live = (jnp.arange(row.shape[0]) < worked)[:, None]
+    pos = back - start
+    mine = (pos >= 0) & (pos < worked)
+    pos = jnp.where(mine, pos, 0)
+    dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
+                            preferred_element_type=jnp.float32)
+    x = _take_rows(u, row // gates.shape[1], live, pos, mine)     # [R, D]
+    inner = jax.nn.silu(dot(x, w_gate)) * dot(x, w_up)
+    return _give_back(dot(inner.astype(u.dtype), w_down), gates, row, live, pos, mine)
+
+
+def _over_passes(plans, run, zeros):
+    """``run(plan)`` summed over the passes that hold a row (``plans``: every
+    pass's ``(rows, sizes, start)``, stacked). A pass without rows is not run:
+    the sum is carried past it, and nothing is written for it. The first pass
+    starts the sum (``zeros()`` stands for it where no row is held at all)."""
+    holds_rows = lambda plan: plan[1].sum() > 0  # noqa: E731
+    at = lambda i: jax.tree_util.tree_map(lambda a: a[i], plans)  # noqa: E731
+    total = jax.lax.cond(holds_rows(at(0)), lambda: run(at(0)), zeros)
+    if plans[0].shape[0] == 1:
+        return total
+
+    def one(total, plan):
+        return jax.lax.cond(
+            holds_rows(plan),
+            lambda total: jax.tree_util.tree_map(jnp.add, total, run(plan)),
+            lambda total: total, total), None
+
+    return jax.lax.scan(one, total, at(slice(1, None)))[0]
+
+
+@jax.custom_vjp
+def _passes(operands, back, plans):
+    """``_one_pass`` over the passes, for ``operands = (u, gates, w_gate, w_up,
+    w_down)``: ``[n, D]`` float32. Only the operands are kept: the backward
+    pass works each pass it runs again, and skips the same passes."""
+    return _over_passes(plans, lambda plan: _one_pass(*operands, back, *plan),
+                        lambda: jnp.zeros(operands[0].shape, jnp.float32))
+
+
+def _passes_bwd(res, dy):
+    operands, back, plans = res
+
+    def pulled(plan):
+        return jax.vjp(lambda *a: _one_pass(*a, back, *plan), *operands)[1](dy)
+
+    return (_over_passes(plans, pulled, lambda: tuple(jnp.zeros_like(a) for a in operands)),
+            None, None)
+
+
+_passes.defvjp(lambda *args: (_passes(*args), args), _passes_bwd)
 
 
 def _experts_chunk(c: SparseMoEConfig, w_gate, w_up, w_down, u, experts, gates, real):
     """The held experts' part of the layer for a chunk of tokens: ``u`` [n, D]
     bfloat16, ``experts``, ``gates`` [n, K], ``real`` [n] -> ``(y [n, D]
-    float32, rows worked)``. The chunk's ``n K`` assignments are sorted, those
-    to held experts first and by expert; grouped matmuls work exactly the held
-    rows, and the rows go in and come out by permutation."""
+    float32, rows worked, passes run)``. The chunk's ``n K`` assignments are
+    sorted once, those to held experts first and by expert; a pass works ``R``
+    of the sorted rows (``pass_plan``), and the passes past the last held row
+    are skipped at run time."""
     lo, hi = c.experts_held
     n, slots = experts.shape
+    bound, passes = pass_plan(c, n)
     held = (experts >= lo) & (experts < hi) & real[:, None]
     local = jnp.where(held, experts - lo, c.held).reshape(-1)      # not held: last
     order = jnp.argsort(local, stable=True)
-    back = jnp.argsort(order)
+    back = jnp.argsort(order).reshape(n, slots)
     sizes = (local[:, None] == jnp.arange(c.held)[None, :]).sum(axis=0).astype(jnp.int32)
-    dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
-                            preferred_element_type=jnp.float32)
-    # rows past the held assignments belong to no group: whatever a grouped
-    # matmul leaves there goes no further, forward or backward
-    grouped = (jnp.arange(n * slots) < sizes.sum())[:, None]
-    x = jnp.where(grouped, _dispatch(u, order, back, slots), 0)    # [n K, D]
-    inner = jax.nn.silu(dot(x, w_gate)) * dot(x, w_up)
-    out = jnp.where(grouped, dot(inner.astype(u.dtype), w_down), 0.0)
-    parts = _permute(out, back, order).reshape(n, slots, -1)
-    return jnp.einsum("nkd,nk->nd", parts, jnp.where(held, gates, 0.0)), sizes.sum()
+    # a pass's share of each expert's rows: its group sizes clipped to the range
+    starts = bound * jnp.arange(passes, dtype=jnp.int32)
+    ends = jnp.cumsum(sizes)
+    clip = lambda edge: jnp.clip(edge[None, :], starts[:, None], starts[:, None] + bound)  # noqa: E731
+    pass_sizes = clip(ends) - clip(ends - sizes)                   # [passes, held]
+    rows = jnp.pad(order, (0, max(0, passes * bound - order.size)))[:passes * bound]
+    y = _passes((u, gates, w_gate, w_up, w_down), back,
+                (rows.reshape(passes, bound), pass_sizes, starts))
+    worked = pass_sizes.sum(axis=1)
+    return y, worked.sum(), (worked > 0).sum()
 
 
 def _moe(c: SparseMoEConfig, u, p, real):
@@ -345,15 +467,14 @@ def _moe(c: SparseMoEConfig, u, p, real):
                  "held_load_max": held_load.max()}
     with jax.named_scope(SCOPE_EXPERTS):
         chunk = min(moe_chunk_of(c), n)
-        pad = -n % chunk
-        cut = lambda a: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)).reshape(  # noqa: E731
-            -1, chunk, *a.shape[1:])
         work = jax.checkpoint(functools.partial(
             _experts_chunk, c, p["w_gate"].astype(dtype), p["w_up"].astype(dtype),
             p["w_down"].astype(dtype)))
-        y, rows = jax.lax.map(lambda args: work(*args),
-                              (cut(u.astype(dtype)), cut(experts), cut(gates), cut(real)))
+        y, rows, ran = jax.lax.map(lambda args: work(*args), tuple(
+            _cut(a, chunk) for a in (u.astype(dtype), experts, gates, real)))
         stats["dropped"] = stats["held_assignments"] - rows.sum()
+        stats["passes"] = jnp.int32(len(rows) * pass_plan(c, chunk)[1])   # chunks x passes
+        stats["passes_run"] = ran.sum()
     return y.reshape(-1, y.shape[-1])[:n], stats
 
 
@@ -421,6 +542,8 @@ def make_loss(c: SparseMoEConfig, mesh):
                 "moe_held_load_max": stats["held_load_max"].max(),
                 "moe_held_load_mean": held / (c.num_layers * c.held),
                 "moe_dropped": stats["dropped"].sum(),
+                "moe_passes": stats["passes"].sum(),
+                "moe_passes_run": stats["passes_run"].sum(),
                 "selected_pairs": stats["selected_pairs"].sum(),
                 "causal_pairs": stats["causal_pairs"].sum(),
             }

@@ -5,7 +5,8 @@ seeded weights: loss, auxiliary loss and every gradient with a history
 several times ``index_topk`` long; the three programs of
 ``ops/sparse_attention.py`` in interpret mode against their plain twins; the
 k-th largest against ``jax.lax.top_k``, ties included; the eight shares of a
-layer add up to the whole layer; nothing is dropped under a skewed router;
+layer add up to the whole layer; the experts' passes under an even, a skewed,
+a whole and an empty routing;
 the engine takes the backbone by name."""
 
 import jax
@@ -245,28 +246,75 @@ def test_the_eight_shares_add_up_to_the_whole_layer(params):
     assert not np.asarray(total)[90:].any()      # a padded slot gets nothing
 
 
-def test_nothing_is_dropped_under_a_router_skewed_onto_one_expert(params):
-    """Every token's first choice is expert 2, one of the two held here: it
-    alone takes a row a token, four times the even share, and every row is
-    worked. The tokens are positive and expert 2's column of the router
-    outweighs every other."""
-    config = _config(experts_held=(2, 4))
-    dims = {**DIMS, "experts_held": (2, 4)}
-    u = jnp.abs(jnp.asarray(np.random.default_rng(6).standard_normal((3 * T, 32)), jnp.float32))
-    layer = {k: jnp.asarray(v[0]) for k, v in params["layers"].items()}
-    layer.update({k: layer[k][:2] for k in ("w_gate", "w_up", "w_down")})
-    real = jnp.ones((3 * T,), bool)
-    even, _ = sparse_moe._moe(config, u, layer, real)
-    layer["router"] = layer["router"].at[:, 2].set(jnp.abs(layer["router"]).sum(axis=1) + 1.0)
-    y, stats = sparse_moe._moe(config, u, layer, real)
-    assert int(stats["held_load_max"]) == 3 * T
-    assert int(stats["held_assignments"]) > 3 * T and int(stats["dropped"]) == 0
-    with jax.default_matmul_precision("highest"):
+#: routing -> (experts held, real tokens of the 192, passes run of pass_plan's)
+ROUTINGS = {"even": ((2, 4), 186, 1), "skewed": ((2, 4), 192, 3),
+            "all-held": ((0, 16), 186, 1), "none-held": ((2, 4), 186, 0)}
+
+
+@pytest.mark.parametrize("routing", list(ROUTINGS))
+def test_the_passes_give_the_references_output_and_gradients_whatever_the_router_sends(routing):
+    """192 tokens, 2 of 16 experts a token, experts 2 and 3 held: a pass works
+    128 sorted rows (twice the even share of 48, in whole 128s) and three
+    passes cover the worst case of 384. **even**: a seeded router, one pass
+    runs. **skewed**: every token's first choice is expert 2 and its second
+    expert 3 (the tokens are positive and those columns of the router outweigh
+    every other), eight times the even share: every pass runs, the second
+    holds rows of both experts, and every row is worked. **all-held**: the
+    bound is the worst case, one pass. **none-held**: no token is sent to a
+    held expert: no pass runs, zeros out, finite gradients. Output and the
+    gradients to the tokens, the router and the three expert matrices against
+    ``reference_keye.experts_part``."""
+    held, n_real, run = ROUTINGS[routing]
+    n = 3 * T
+    config = _config(num_experts=16, experts_held=held, moe_chunk=None)
+    dims = {**DIMS, "experts_held": held}
+    assert sparse_moe.moe_chunk_of(config) >= n      # one chunk of tokens
+    assert sparse_moe.pass_plan(config, n) == ((384, 1) if routing == "all-held" else (128, 3))
+    shapes = sparse_moe.param_shapes(config)["layers"]
+    layer = seeded_histories.make_params(
+        {k: shapes[k][1:] for k in ("router", "w_gate", "w_up", "w_down")}, seed=13)
+    layer["router"] = layer["router"] * 10
+    heavy = np.abs(layer["router"]).sum(axis=1)
+    if routing == "skewed":
+        layer["router"][:, 2], layer["router"][:, 3] = heavy + 1.0, heavy + 0.5
+    if routing == "none-held":
+        layer["router"][:, 2:4] = -heavy[:, None] - 1.0
+    rng = np.random.default_rng(6)
+    u = jnp.abs(jnp.asarray(rng.standard_normal((n, 32)), jnp.float32))
+    weight = jnp.asarray(rng.standard_normal((n, 32)), jnp.float32)
+    real = jnp.asarray(np.arange(n) < n_real)
+
+    def program(u, layer):
+        y, stats = sparse_moe._moe(config, u, layer, real)
+        return (y * weight).sum(), (y, stats)
+
+    def reference(u, layer):
         _, experts, gates = ref.routing(layer, u, dims, ref.SOUND)
-        want = ref.experts_part(layer, u, experts, gates, real, dims)
-    assert (np.asarray(experts)[:, 0] == 2).all()
-    assert np.abs(np.asarray(y) - np.asarray(want)).max() < 1e-5
-    assert np.abs(np.asarray(even)).max() > 0
+        y = ref.experts_part(layer, u, experts, gates, real, dims)
+        return (y * weight).sum(), (y, experts)
+
+    (_, (y, stats)), have = jax.jit(jax.value_and_grad(program, (0, 1), has_aux=True))(u, layer)
+    with jax.default_matmul_precision("highest"):
+        (_, (want_y, experts)), want = jax.jit(
+            jax.value_and_grad(reference, (0, 1), has_aux=True))(u, layer)
+    assert int(stats["dropped"]) == 0
+    assert (int(stats["passes"]), int(stats["passes_run"])) == (1 if routing == "all-held" else 3, run)
+    sent = np.isin(np.asarray(experts)[:n_real], np.arange(*held)).sum()
+    assert int(stats["held_assignments"]) == sent
+    if routing == "skewed":
+        assert (np.asarray(experts) == [2, 3]).all()
+        assert int(stats["held_load_max"]) == n and sent == 2 * n
+    if routing == "none-held":
+        assert sent == 0 and not np.asarray(y).any()
+    else:
+        assert np.abs(np.asarray(want_y)).max() > 1e-3
+    assert np.abs(np.asarray(y) - np.asarray(want_y)).max() < 1e-5
+    assert not np.asarray(y)[n_real:].any()          # a padded slot gets nothing
+    have, want = _flat({"u": have[0], **have[1]}), _flat({"u": want[0], **want[1]})
+    for name, g in want.items():
+        assert np.isfinite(have[name]).all(), name
+        assert np.abs(have[name] - g).max() <= 1e-4 * max(np.abs(g).max(), 1e-6), name
+        assert routing == "none-held" or np.abs(g).max() > 0, name
 
 
 # ---- the template ------------------------------------------------------------
@@ -282,7 +330,9 @@ def test_the_engine_takes_the_backbone_and_names_all_three_when_it_refuses():
         "indexTopk": 2048, "ropeTheta": 10000000, "batchSize": 2}))._config(18991, 8192)
     assert isinstance(config, SparseMoEConfig) and config.held == 16
     assert sparse_moe.count_params(config) == 659_187_712
-    assert sparse_moe.moe_chunk_of(config) == 4096
+    # a whole layer's tokens: a pass of 32,768 rows is twice their even share
+    assert sparse_moe.moe_chunk_of(config) == 16384
+    assert sparse_moe.pass_plan(config, 16384) == (32768, 4)
     whole = SASRecAlgorithm(Params({"backbone": "sparse_moe", "numExperts": 16}))._config(12, 64)
     assert whole.experts_held == (0, 16)
     with pytest.raises(ValueError, match="'sasrec', 'looped', 'sparse_moe'"):
