@@ -55,6 +55,14 @@ SPARSE_FIT_FACTS = ("experts_total", "experts_held", "experts_per_token", "index
                     "moe_assignments", "moe_held_assignments", "moe_held_load_max",
                     "moe_dropped", "moe_passes", "moe_passes_run", "selected_pairs",
                     "causal_pairs")
+#: the leaf scopes a compiled sequence step has to carry under each stage
+#: (``jax.named_scope``; the strings are ``looped``'s and ``sparse_moe``'s), and
+#: of them those whose backward pass is work of its own
+LAYER_LEAVES = {"attention": ("norm", "qkv", "rope", "kernel", "out")}
+LOOPED_LEAVES = {**LAYER_LEAVES, "mlp": ("norm",)}
+SPARSE_LEAVES = {**LAYER_LEAVES, "moe": ("norm",),
+                 "experts": ("sort", "take", "grouped", "give", "sum")}
+BACKWARD_LEAVES = {"norm", "qkv", "rope", "kernel", "out", "grouped", "give", "sum"}
 
 
 # ---------------------------------------------------------------------------
@@ -321,6 +329,24 @@ class Smoke:
             facts["stage_timings"] = timings.group(1).strip()
         return facts
 
+    def step_leaves(self, name: str, algorithm: dict, max_len: int, want: dict) -> dict:
+        """The step ``pio train`` ran for ``algorithm``, compiled once more in a
+        child: every leaf scope of ``want`` has to be in the compiled text under
+        its stage, forward and, for the leaves that have one, backward."""
+        res = child(name, "sequence_step_leaves",
+                    {"algorithm": algorithm, "max_len": max_len,
+                     "want": {stage: list(leaves) for stage, leaves in want.items()}},
+                    self.env, 900)
+        self.saw(name, res["device"])
+        missing = [f"{stage}/{leaf} {phase}"
+                   for stage, leaves in want.items() for leaf in leaves
+                   for phase in ("forward", "backward")
+                   if phase not in res["leaves"][f"{stage}/{leaf}"]
+                   and (phase == "forward" or leaf in BACKWARD_LEAVES)]
+        if missing:
+            raise PhaseFailed(f"{name}: the compiled step lacks the leaf scopes {missing}")
+        return res
+
     def query_all(self, url: str, queries: list[dict]) -> tuple[list, float]:
         answers, lat = [], []
         for q in queries:
@@ -571,12 +597,13 @@ class Smoke:
                   if self.rehearsal else
                   {"hiddenSize": 2048, "numHeads": 16, "headDim": 128, "ffnDim": 5632})
 
+        algorithm = dict(backbone="looped", numLayers=2, utSteps=4, batchSize=32, epochs=6,
+                         learningRate=3e-4, **widths)
+
         def edit(v):
             v["datasource"]["params"]["appName"] = "SmokeSeqApp"
             v["preparator"]["params"]["maxLen"] = 256
-            v["algorithms"][0]["params"].update(
-                backbone="looped", numLayers=2, utSteps=4, batchSize=32, epochs=6,
-                learningRate=3e-4, **widths)
+            v["algorithms"][0]["params"].update(algorithm)
             v["sparkConf"] = {"pio.mesh_shape": [1, 1], "pio.mesh_axes": ["data", "seq"]}
 
         seq_dir = self.engine_dir("sequence_looped", "sequence", edit)
@@ -586,8 +613,9 @@ class Smoke:
         first, last = facts["first_loss"], facts["last_loss"]
         if not (first == first and last == last and last < first < float("inf")):
             raise PhaseFailed(f"train_sequence_looped: loss not finite and falling: {first} -> {last}")
+        leaves = self.step_leaves("sequence_looped_leaves", algorithm, 256, LOOPED_LEAVES)
         self.line("train_sequence_looped", t0, **facts, users=32, events=int(users.size),
-                  **widths)
+                  leaf_scopes=len(leaves["leaves"]), **widths)
 
     def phase_train_sequence_sparse_moe(self) -> None:
         """The sequence template's sparse backbone through ``pio train`` at the
@@ -619,12 +647,13 @@ class Smoke:
                    "expertDim": 768, "numExperts": 128, "expertsPerToken": 8,
                    "expertsHeld": [0, 16], "indexHeads": 16, "indexDim": 64})
 
+        algorithm = dict(backbone="sparse_moe", numLayers=2, indexTopk=topk, batchSize=8,
+                         epochs=6, learningRate=3e-4, **widths)
+
         def edit(v):
             v["datasource"]["params"]["appName"] = "SmokeSparseApp"
             v["preparator"]["params"]["maxLen"] = max_len
-            v["algorithms"][0]["params"].update(
-                backbone="sparse_moe", numLayers=2, indexTopk=topk, batchSize=8, epochs=6,
-                learningRate=3e-4, **widths)
+            v["algorithms"][0]["params"].update(algorithm)
             v["sparkConf"] = {"pio.mesh_shape": [1, 1], "pio.mesh_axes": ["data", "seq"]}
 
         seq_dir = self.engine_dir("sequence_sparse_moe", "sequence", edit)
@@ -648,8 +677,14 @@ class Smoke:
         first, last = facts["first_loss"], facts["last_loss"]
         if not (first == first and last == last and last < first < float("inf")):
             raise PhaseFailed(f"train_sequence_sparse_moe: loss not finite and falling: {first} -> {last}")
+        leaves = self.step_leaves("sequence_sparse_moe_leaves", algorithm, max_len, SPARSE_LEAVES)
+        # the experts' backward rule says which of its work is the forward again
+        if not leaves["again_backward"] or leaves["again_forward"]:
+            raise PhaseFailed(f"train_sequence_sparse_moe: `again` not in the backward pass"
+                              f" alone: {leaves}")
         self.line("train_sequence_sparse_moe", t0, **facts, users=8, events=int(users.size),
-                  max_len=max_len, **widths)
+                  max_len=max_len, leaf_scopes=len(leaves["leaves"]),
+                  again_in_backward=leaves["again_backward"], **widths)
 
     def phase_sharded(self) -> None:
         self.phase_device(with_status=False)
@@ -847,6 +882,46 @@ def child_als_compile(params: dict) -> dict:
     out.update(device=rep, cache_dir=cache_dir, entries_before=before,
                entries_after=_cache_entries(cache_dir))
     return out
+
+
+def child_sequence_step_leaves(params: dict) -> dict:
+    """One optimizer step of the sequence template at the engine parameters
+    ``algorithm`` (2,000 items), compiled for this device: for every
+    ``stage/leaf`` of ``want`` the phases (``forward``, ``backward``) in which
+    the compiled text carries the leaf scope under its stage, and how often
+    ``again`` shows in either. A program served from the compile cache is read
+    as it was served: the cache's key has to cover the names
+    (``utils/platform.configure_compile_cache``)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from predictionio_tpu.controller import Params
+    from predictionio_tpu.models.sequence import model as seq_model
+    from predictionio_tpu.models.sequence.engine import SASRecAlgorithm
+
+    rep = _backend()
+    max_len = params["max_len"]
+    config = SASRecAlgorithm(Params(params["algorithm"]))._config(2_000, max_len)
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "seq"))
+    init, _, step_fn, _ = seq_model.make_fit(config, mesh)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    weights = jax.eval_shape(lambda key: init(key, max_len), key)
+    moments = jax.eval_shape(seq_model.optimizer_of(config).init, weights)
+    rows = jax.ShapeDtypeStruct((config.batch_size, max_len), jnp.int32)
+    text = step_fn.lower(weights, moments, {"seq": rows, "target": rows},
+                         key).compile().as_text()
+    names = [(name, re.split(r"[/():]", name.rpartition("/")[0]))  # less the primitive
+             for name in set(re.findall(r'op_name="([^"]*seq\.[^"]*)"', text))]
+    phase = lambda name: "backward" if "transpose(" in name else "forward"  # noqa: E731
+    leaves = {f"{stage}/{leaf}": sorted({
+        phase(name) for name, parts in names
+        if stage in parts and leaf in parts[parts.index(stage):]})
+        for stage, wanted in params["want"].items() for leaf in wanted}
+    again = [phase(name) for name, parts in names if "again" in parts]
+    return {"device": rep, "leaves": leaves, "again_forward": again.count("forward"),
+            "again_backward": again.count("backward")}
 
 
 def _load_model(engine_dir: str, instance_id: str):
@@ -1101,6 +1176,7 @@ CHILDREN = {
     "check_ncf_scores": child_check_ncf_scores,
     "als_full_width": child_als_full_width,
     "sharded_als": child_sharded_als,
+    "sequence_step_leaves": child_sequence_step_leaves,
 }
 
 

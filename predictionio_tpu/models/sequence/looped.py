@@ -65,6 +65,17 @@ SCOPE_ATTENTION = "attention"
 SCOPE_MLP = "mlp"
 SCOPE_EXIT = "exit"
 SCOPE_OPTIMIZER = "seq.optimizer"
+#: Leaves under the stages, by class of operation (``sparse_moe`` uses the
+#: same names): ``norm`` at a layer's RMSNorm call sites (the exit's final norm
+#: stays the exit's), ``qkv`` the three projections and the reshape to heads,
+#: ``rope`` the rotary positions of ``q`` and ``k``, ``kernel`` the call of
+#: ``attend`` (the Pallas programs and the transposes and casts around them),
+#: ``out`` the ``wo`` projection and the residual add.
+SCOPE_NORM = "norm"
+SCOPE_QKV = "qkv"
+SCOPE_ROPE = "rope"
+SCOPE_KERNEL = "kernel"
+SCOPE_OUT = "out"
 
 #: the most float32 logits one chunk of an exit's head holds at once
 HEAD_CHUNK_BYTES = 384 << 20
@@ -210,17 +221,29 @@ def _layer(c: LoopedConfig, attend, rope, pad_mask, h, p):
     dtype = jnp.dtype(c.compute_dtype)
     b, t, _ = h.shape
     with jax.named_scope(SCOPE_ATTENTION):
-        z = _rms_norm(h, p["n1"], c.rms_eps)
-        heads = lambda a: a.reshape(b, t, c.num_heads, c.head_dim)  # noqa: E731
-        q = _rotate(heads(_matmul(z, p["wq"], dtype)), *rope)
-        k = _rotate(heads(_matmul(z, p["wk"], dtype)), *rope)
-        v = heads(_matmul(z, p["wv"], dtype))
-        out = attend(q, k, v, pad_mask).reshape(b, t, -1)
-        a = h + _rms_norm(_matmul(out, p["wo"], dtype), p["n2"], c.rms_eps)
+        with jax.named_scope(SCOPE_NORM):
+            z = _rms_norm(h, p["n1"], c.rms_eps)
+        with jax.named_scope(SCOPE_QKV):
+            q, k, v = (_matmul(z, p[w], dtype).reshape(b, t, c.num_heads, c.head_dim)
+                       for w in ("wq", "wk", "wv"))
+        with jax.named_scope(SCOPE_ROPE):
+            q, k = _rotate(q, *rope), _rotate(k, *rope)
+        with jax.named_scope(SCOPE_KERNEL):
+            out = attend(q, k, v, pad_mask).reshape(b, t, -1)
+        with jax.named_scope(SCOPE_OUT):
+            out = _matmul(out, p["wo"], dtype)
+        with jax.named_scope(SCOPE_NORM):
+            out = _rms_norm(out, p["n2"], c.rms_eps)
+        with jax.named_scope(SCOPE_OUT):
+            a = h + out
     with jax.named_scope(SCOPE_MLP):
-        z = _rms_norm(a, p["n3"], c.rms_eps)
+        with jax.named_scope(SCOPE_NORM):
+            z = _rms_norm(a, p["n3"], c.rms_eps)
         inner = jax.nn.silu(_matmul(z, p["w_gate"], dtype)) * _matmul(z, p["w_up"], dtype)
-        return a + _rms_norm(_matmul(inner, p["w_down"], dtype), p["n4"], c.rms_eps)
+        down = _matmul(inner, p["w_down"], dtype)
+        with jax.named_scope(SCOPE_NORM):
+            down = _rms_norm(down, p["n4"], c.rms_eps)
+        return a + down
 
 
 def _stack(c: LoopedConfig, attend, rope, pad_mask, h, layers):

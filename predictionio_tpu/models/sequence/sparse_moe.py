@@ -90,10 +90,25 @@ from predictionio_tpu.ops import sparse_attention as sa
 #: router and the held experts.
 SCOPE_INDEX = "index"
 SCOPE_SELECT = "select"
-SCOPE_KERNEL = "kernel"
+SCOPE_KERNEL = looped.SCOPE_KERNEL
 SCOPE_MOE = "moe"
 SCOPE_ROUTE = "route"
 SCOPE_EXPERTS = "experts"
+#: Leaves under ``moe/experts``, by class of operation (a layer's ``norm``,
+#: ``qkv``, ``rope``, ``out`` are ``looped``'s): ``sort`` the held test, the two
+#: argsorts, the group sizes and the passes' plan; ``take`` the gather of a
+#: pass's rows from their tokens and its transpose; ``grouped`` the three
+#: grouped matmuls and the gated product between them; ``give`` the rows back
+#: onto their tokens and its transpose; ``sum`` the sum by position, inside
+#: ``give`` forward and inside ``take`` backward. ``again`` marks the forward
+#: work a backward rule runs again: a ``custom_vjp`` rule's recomputation
+#: carries no ``rematted_computation``, so the program says it.
+SCOPE_SORT = "sort"
+SCOPE_TAKE = "take"
+SCOPE_GROUPED = "grouped"
+SCOPE_GIVE = "give"
+SCOPE_SUM = "sum"
+SCOPE_AGAIN = "again"
 
 #: the most float32 bytes the held experts' output rows of one pass may take
 MOE_CHUNK_BYTES = 256 << 20
@@ -241,10 +256,12 @@ def _attention(c: SparseMoEConfig, backend: str, rope, h, p, ip, real, probe=Non
     dtype = jnp.dtype(c.compute_dtype)
     b, t, _ = h.shape
     kernels, interpret = uses_kernels(c, backend), backend != "tpu"
-    heads = lambda a, n: a.reshape(b, t, n, c.head_dim)  # noqa: E731
-    q = looped._rotate(heads(looped._matmul(h, p["wq"], dtype), c.num_heads), *rope)
-    k = looped._rotate(heads(looped._matmul(h, p["wk"], dtype), c.num_kv_heads), *rope)
-    v = heads(looped._matmul(h, p["wv"], dtype), c.num_kv_heads)
+    with jax.named_scope(looped.SCOPE_QKV):
+        q, k, v = (looped._matmul(h, p[w], dtype).reshape(b, t, n, c.head_dim)
+                   for w, n in (("wq", c.num_heads), ("wk", c.num_kv_heads),
+                                ("wv", c.num_kv_heads)))
+    with jax.named_scope(looped.SCOPE_ROPE):
+        q, k = looped._rotate(q, *rope), looped._rotate(k, *rope)
     with jax.named_scope(SCOPE_INDEX):
         # the selection is a hard top-k: no gradient, to the input or the indexer
         hs, ip = jax.lax.stop_gradient((h, ip))
@@ -318,10 +335,11 @@ def _sum_by_position(rows, pos, weight):
         return jnp.where(weight[..., None] != 0, rows[pos] * weight[..., None], 0.0).sum(axis=1)
 
     chunk = max(1, MOE_CHUNK_BYTES // (4 * slots * rows.shape[-1]))
-    if chunk >= n:
-        return block((pos, weight))
-    y = jax.lax.map(block, (_cut(pos, chunk), _cut(weight, chunk)))
-    return y.reshape(-1, y.shape[-1])[:n]
+    with jax.named_scope(SCOPE_SUM):
+        if chunk >= n:
+            return block((pos, weight))
+        y = jax.lax.map(block, (_cut(pos, chunk), _cut(weight, chunk)))
+        return y.reshape(-1, y.shape[-1])[:n]
 
 
 @jax.custom_vjp
@@ -371,9 +389,13 @@ def _one_pass(u, gates, w_gate, w_up, w_down, back, row, sizes, start):
     pos = jnp.where(mine, pos, 0)
     dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
                             preferred_element_type=jnp.float32)
-    x = _take_rows(u, row // gates.shape[1], live, pos, mine)     # [R, D]
-    inner = jax.nn.silu(dot(x, w_gate)) * dot(x, w_up)
-    return _give_back(dot(inner.astype(u.dtype), w_down), gates, row, live, pos, mine)
+    with jax.named_scope(SCOPE_TAKE):
+        x = _take_rows(u, row // gates.shape[1], live, pos, mine)     # [R, D]
+    with jax.named_scope(SCOPE_GROUPED):
+        inner = jax.nn.silu(dot(x, w_gate)) * dot(x, w_up)
+        out = dot(inner.astype(u.dtype), w_down)
+    with jax.named_scope(SCOPE_GIVE):
+        return _give_back(out, gates, row, live, pos, mine)
 
 
 def _over_passes(plans, run, zeros):
@@ -409,7 +431,9 @@ def _passes_bwd(res, dy):
     operands, back, plans = res
 
     def pulled(plan):
-        return jax.vjp(lambda *a: _one_pass(*a, back, *plan), *operands)[1](dy)
+        with jax.named_scope(SCOPE_AGAIN):
+            pull = jax.vjp(lambda *a: _one_pass(*a, back, *plan), *operands)[1]
+        return pull(dy)
 
     return (_over_passes(plans, pulled, lambda: tuple(jnp.zeros_like(a) for a in operands)),
             None, None)
@@ -428,17 +452,18 @@ def _experts_chunk(c: SparseMoEConfig, w_gate, w_up, w_down, u, experts, gates, 
     lo, hi = c.experts_held
     n, slots = experts.shape
     bound, passes = pass_plan(c, n)
-    held = (experts >= lo) & (experts < hi) & real[:, None]
-    local = jnp.where(held, experts - lo, c.held).reshape(-1)      # not held: last
-    order = jnp.argsort(local, stable=True)
-    back = jnp.argsort(order).reshape(n, slots)
-    sizes = (local[:, None] == jnp.arange(c.held)[None, :]).sum(axis=0).astype(jnp.int32)
-    # a pass's share of each expert's rows: its group sizes clipped to the range
-    starts = bound * jnp.arange(passes, dtype=jnp.int32)
-    ends = jnp.cumsum(sizes)
-    clip = lambda edge: jnp.clip(edge[None, :], starts[:, None], starts[:, None] + bound)  # noqa: E731
-    pass_sizes = clip(ends) - clip(ends - sizes)                   # [passes, held]
-    rows = jnp.pad(order, (0, max(0, passes * bound - order.size)))[:passes * bound]
+    with jax.named_scope(SCOPE_SORT):
+        held = (experts >= lo) & (experts < hi) & real[:, None]
+        local = jnp.where(held, experts - lo, c.held).reshape(-1)      # not held: last
+        order = jnp.argsort(local, stable=True)
+        back = jnp.argsort(order).reshape(n, slots)
+        sizes = (local[:, None] == jnp.arange(c.held)[None, :]).sum(axis=0).astype(jnp.int32)
+        # a pass's share of each expert's rows: its group sizes clipped to the range
+        starts = bound * jnp.arange(passes, dtype=jnp.int32)
+        ends = jnp.cumsum(sizes)
+        clip = lambda edge: jnp.clip(edge[None, :], starts[:, None], starts[:, None] + bound)  # noqa: E731
+        pass_sizes = clip(ends) - clip(ends - sizes)                   # [passes, held]
+        rows = jnp.pad(order, (0, max(0, passes * bound - order.size)))[:passes * bound]
     y = _passes((u, gates, w_gate, w_up, w_down), back,
                 (rows.reshape(passes, bound), pass_sizes, starts))
     worked = pass_sizes.sum(axis=1)
@@ -484,11 +509,14 @@ def _layer(c: SparseMoEConfig, backend: str, rope, real, x, p, ip, probe=None):
     """One decoder layer on ``x`` [B, T, D]: ``(x', stats)``."""
     dtype = jnp.dtype(c.compute_dtype)
     with jax.named_scope(looped.SCOPE_ATTENTION):
-        h = looped._rms_norm(x, p["n1"], c.rms_eps)
+        with jax.named_scope(looped.SCOPE_NORM):
+            h = looped._rms_norm(x, p["n1"], c.rms_eps)
         out, stats = _attention(c, backend, rope, h, p, ip, real, probe)
-        x = x + looped._matmul(out, p["wo"], dtype)
+        with jax.named_scope(looped.SCOPE_OUT):
+            x = x + looped._matmul(out, p["wo"], dtype)
     with jax.named_scope(SCOPE_MOE):
-        u = looped._rms_norm(x, p["n2"], c.rms_eps)
+        with jax.named_scope(looped.SCOPE_NORM):
+            u = looped._rms_norm(x, p["n2"], c.rms_eps)
         y, routed = _moe(c, u.reshape(-1, u.shape[-1]), p, real.reshape(-1))
         return x + y.reshape(x.shape), {**stats, **routed}
 

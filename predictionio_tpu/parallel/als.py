@@ -63,6 +63,13 @@ SCOPE_BUCKET = "bucket{}"
 #: forming a row's system: the gather (with its model-axis exchange) and the
 #: two einsums or, in the dual form, the whitening and the ``[L, L]`` system
 SCOPE_GRAM = "gram"
+#: leaves under ``gram``, by class of operation: the gather of the opposite
+#: side's rows from the table (in the model layout the local hits and their
+#: mask; the ``exchange`` that completes them is ``gram``'s own child, beside
+#: this), and the matmuls that make a row's system from the gathered rows (the
+#: Gram and right-hand-side einsums; in a dual block the whitening, ``T``, ``S``)
+SCOPE_GATHER = "gather"
+SCOPE_PRODUCTS = "products"
 #: ridge, ``ops.linalg.batched_spd_solve``, the cast back to the factor dtype
 #: (and the dual form's projection back to ``[K]``)
 SCOPE_SOLVE = "solve"
@@ -429,7 +436,7 @@ def _gram_solve_explicit(gathered, values, n_obs, reg, rank, unroll, out_dtype):
     back to ``out_dtype`` on return. ``reg`` may be a traced scalar (the
     iteration program is shared across regularization values).
     """
-    with jax.named_scope(SCOPE_GRAM):
+    with jax.named_scope(SCOPE_GRAM), jax.named_scope(SCOPE_PRODUCTS):
         gram = jnp.einsum(
             "rlk,rlj->rkj", gathered, gathered,
             precision=_factor_precision(gathered.dtype),
@@ -482,7 +489,7 @@ def _gram_solve_implicit(gathered, values, shared, reg, alpha, rank, unroll, out
     yty, whiten = shared
     if takes_dual(True, gathered.shape[1], rank):
         return _dual_solve_implicit(gathered, values, whiten, alpha, unroll, out_dtype)
-    with jax.named_scope(SCOPE_GRAM):
+    with jax.named_scope(SCOPE_GRAM), jax.named_scope(SCOPE_PRODUCTS):
         conf_minus_1 = alpha * values
         gram_fix = jnp.einsum(
             "rlk,rl,rlj->rkj", gathered, conf_minus_1, gathered,
@@ -532,7 +539,7 @@ def _dual_solve_implicit(gathered, values, whiten, alpha, unroll, out_dtype):
     rows: its ``[K, K]`` system carries the condition number that ``S`` sheds.
     """
     pad_len = gathered.shape[1]
-    with jax.named_scope(SCOPE_GRAM):
+    with jax.named_scope(SCOPE_GRAM), jax.named_scope(SCOPE_PRODUCTS):
         white = jnp.einsum(
             "rlk,jk->rlj", gathered, whiten,
             precision="highest", preferred_element_type=jnp.float32,
@@ -578,7 +585,7 @@ def _shared_gram(factors, reg, implicit: bool = True):
 
 def _half_step_explicit(indices, values, n_obs, factors, reg, rank, unroll):
     """Replicated-factor explicit half-step (gather + shared tail)."""
-    with jax.named_scope(SCOPE_GRAM):
+    with jax.named_scope(SCOPE_GRAM), jax.named_scope(SCOPE_GATHER):
         gathered = factors[indices]                   # [R, L, K]
     return _gram_solve_explicit(
         gathered, values, n_obs, reg, rank, unroll, factors.dtype
@@ -595,7 +602,7 @@ def _half_step_implicit(indices, values, n_obs, factors, shared, reg, alpha,
     computing it here would redo the [S, K] reduction for every bucket).
     """
     del n_obs
-    with jax.named_scope(SCOPE_GRAM):
+    with jax.named_scope(SCOPE_GRAM), jax.named_scope(SCOPE_GATHER):
         gathered = factors[indices]
     return _gram_solve_implicit(
         gathered, values, shared, reg, alpha, rank, unroll, factors.dtype
@@ -635,9 +642,10 @@ def _sharded_block_body(idx, values, n_obs, opp_local, shared, reg, alpha,
         s_m = opp_local.shape[0]
         loc = idx - mi * s_m
         rows = idx.shape[0] // m
-        hit = (loc >= 0) & (loc < s_m)
-        g = opp_local[jnp.clip(loc, 0, s_m - 1)]
-        g = g * hit[..., None].astype(g.dtype)
+        with jax.named_scope(SCOPE_GATHER):
+            hit = (loc >= 0) & (loc < s_m)
+            g = opp_local[jnp.clip(loc, 0, s_m - 1)]
+            g = g * hit[..., None].astype(g.dtype)
         with jax.named_scope(SCOPE_EXCHANGE):
             # run j of the device's rows goes to device j of the model axis,
             # which adds up the m runs it receives (each slot hits one shard,

@@ -51,6 +51,12 @@ def configure_compile_cache() -> str:
     jax.config.update(
         "jax_persistent_cache_min_compile_time_secs", CACHE_MIN_COMPILE_SECS
     )
+    # JAX's key leaves an instruction's names out (``op_name``, source lines), so
+    # a cache written before a ``jax.named_scope`` was added serves a program
+    # whose trace still reads under the old names: the one-chip ALS cell's
+    # iteration came back without this PR's leaves on a machine whose cache an
+    # earlier commit had filled (PERF.md section 6, PR 35). The key covers them
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path
 
 

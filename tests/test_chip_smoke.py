@@ -54,6 +54,10 @@ def test_rehearsal_reaches_every_phase_then_refuses_the_cpu(tmp_path):
     assert sparse["backbone"] == "sparse_moe" and sparse["last_loss"] < sparse["first_loss"]
     assert (sparse["experts_held"], sparse["experts_total"], sparse["moe_dropped"]) == (4, 16, 0)
     assert 0 < sparse["selected_pairs"] < sparse["causal_pairs"]
+    # the compiled steps carry the leaf scopes: five a layer's attention and the
+    # mlp's norm; those, the moe's norm and the experts' five
+    assert (looped["leaf_scopes"], sparse["leaf_scopes"]) == (6, 11)
+    assert sparse["again_in_backward"] > 0
 
 
 def test_without_a_chip_the_default_run_stops_at_the_device_phase(tmp_path):

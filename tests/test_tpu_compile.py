@@ -69,6 +69,41 @@ def _kernel_instructions(text: str, name: str) -> list[str]:
     )
 
 
+def _without_names(text: str) -> str:
+    """The optimised HLO ``text`` with everything that only names things taken
+    out, so that two programs compare instruction for instruction: each
+    instruction's ``metadata``, the tables of files, functions and stack frames
+    it points into, the instructions' own names (the compiler names a Pallas
+    call after the innermost scope around it: ``%attention.124`` becomes
+    ``%kernel.124``), numbered here by first appearance, and the source
+    locations inside a Pallas program's serialised body, which is printed
+    without them."""
+    import base64
+    import json
+    import re
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    def body_without_locations(found) -> str:
+        context = mlir.make_ir_context()
+        context.allow_unregistered_dialects = True   # the serialised Mosaic dialect
+        with context:
+            try:
+                module = ir.Module.parse(base64.b64decode(found.group(1)))
+            except ir.MLIRError:      # the compiler's own kernel (a ragged dot): as it is
+                return found.group(0)
+            printed = module.operation.get_asm(enable_debug_info=False)
+        return '"body":' + json.dumps(printed)
+
+    text = re.sub(r",? ?metadata=\{[^{}]*\}", "", text)
+    text = re.sub(r"\n(FileNames|FunctionNames|FileLocations|StackFrames)\n(?:[^\n]+\n)*",
+                  "\n", text)
+    text = re.sub(r'"body":"([A-Za-z0-9+/=]+)"', body_without_locations, text)
+    seen: dict = {}
+    return re.sub(r"%[\w.\-]+", lambda m: seen.setdefault(m.group(0), f"%{len(seen)}"), text)
+
+
 @pytest.mark.parametrize("rank", [16, 128])
 def test_mips_block_topk_compiles(one_chip, no_persistent_cache, rank):
     """Stage 1 of device retrieval at 1M items, default tile and top-R."""
@@ -455,6 +490,13 @@ def test_als_iteration_scopes_its_work(topo, no_persistent_cache, worked):
                 assert seen[(side, stage)] > 0, (side, stage, seen)
             else:  # the stages are in the chunk loops' bodies, not in the entry
                 assert re.search(rf'op_name="[^"]*{re.escape(side)}/bucket\d/while/body/[^"]*{stage}/', text)
+    # under ``gram`` every operation is the gather's or the products', and
+    # under no other stage is there a leaf
+    places = _places(text)
+    for side in als.SCOPE_HALF_STEP.values():
+        assert {p.leaf for p in places if p.top == side and p.stage == als.SCOPE_GRAM} == {
+            als.SCOPE_GATHER, als.SCOPE_PRODUCTS}
+    assert not [p for p in places if p.leaf and p.stage != als.SCOPE_GRAM]
 
 
 def _lowered_looped_step(topo, layers: int, rows: int, **how):
@@ -481,26 +523,70 @@ def _lowered_looped_step(topo, layers: int, rows: int, **how):
     return config, step_fn.lower(params, opt_state, batch, sds((2,), jnp.uint32, rep))
 
 
+@pytest.fixture(scope="module")
+def small_looped_step(topo, no_persistent_cache):
+    """The looped step at Ouro-2.6B's widths, two layers, eight rows of 256."""
+    return _lowered_looped_step(topo, 2, 8, head_chunk=1024)[1].compile()
+
+
+def _places(text: str) -> set:
+    """Where the instructions of a compiled program lie, as
+    ``benchmarks/scopes_leaf.py`` takes an ``op_name`` apart."""
+    import re
+
+    from benchmarks import scopes_leaf
+
+    return {scopes_leaf.place_of(name) for name in re.findall(r'op_name="([^"]*)"', text)} - {None}
+
+
 def test_the_looped_step_compiles_at_the_published_widths_and_scopes_its_work(
-        topo, no_persistent_cache):
+        small_looped_step):
     """One optimizer step of the sequence template's looped backbone at
     Ouro-2.6B's widths (two layers, eight rows of 256): the flash kernel at
     heads of 128 is there four times a pass (forward, the recomputed forward,
     ``dq`` and ``dkv``), each call under its pass's ``attention`` scope as the
-    benchmark's reader takes an ``op_name`` apart, and the step fits the chip."""
+    benchmark's reader takes an ``op_name`` apart, and the step fits the chip.
+    Every leaf of a layer is in the compiled program in every pass, forward,
+    recomputed and backward, and each flash call lies under ``attention/kernel``."""
     import re
 
-    from benchmarks import scopes_seq
+    from benchmarks import scopes_leaf, scopes_seq
 
-    compiled = _lowered_looped_step(topo, 2, 8, head_chunk=1024)[1].compile()
+    compiled = small_looped_step
     assert compiled.memory_analysis().peak_memory_in_bytes < 8e9
-    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"',
-                       compiled.as_text())
+    text = compiled.as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
     assert len(calls) == 16
     kinds = [(scopes_seq.parse_scope(c), scopes_seq.kernel_kind(c)) for c in calls]
     for t in range(1, 5):
         mine = sorted(kind for scope, kind in kinds if scope == (f"pass{t}", "attention"))
         assert mine == ["backward", "backward", "forward", "forward"]
+    placed = [scopes_leaf.place_of(c) for c in calls]
+    assert {(p.stage, p.leaf) for p in placed} == {("attention", "kernel")}
+    assert sorted(p.phase for p in placed if p.top == "pass3") == [
+        "backward", "backward", "forward", "recomputed"]
+    seen = {(p.top, p.stage, p.leaf, p.phase) for p in _places(text)}
+    for t in range(1, 5):
+        for phase in ("forward", "recomputed", "backward"):
+            for leaf in ("norm", "qkv", "rope", "kernel", "out"):
+                assert (f"pass{t}", "attention", leaf, phase) in seen, (t, leaf, phase)
+            assert (f"pass{t}", "mlp", "norm", phase) in seen, (t, phase)
+    assert {leaf for _, stage, leaf, _ in seen if stage == "exit"} == {None}
+
+
+def test_the_leaf_scopes_leave_the_looped_step_instruction_for_instruction(
+        topo, small_looped_step, monkeypatch):
+    """The same step compiled with every ``jax.named_scope`` patched out here
+    in the test (the program has no switch): with the names taken out of both,
+    the two optimised programs for the described v5e are one text."""
+    import contextlib
+
+    with monkeypatch.context() as patch:
+        patch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        bare = _lowered_looped_step(topo, 2, 8, head_chunk=1024)[1].compile().as_text()
+    scoped = small_looped_step.as_text()
+    assert "seq.pass" not in bare and "/attention/qkv/" in scoped
+    assert _without_names(bare) == _without_names(scoped)
 
 
 @pytest.mark.parametrize("layers,fits", [(6, True), (8, False)], ids=["6-layers", "8-layers"])
@@ -534,7 +620,7 @@ def test_the_lifelong_histories_cells_step_fits_the_chip_at_6_layers_and_scopes_
     forward, recomputed and backward."""
     import re
 
-    from benchmarks import scopes_sparse
+    from benchmarks import scopes_leaf, scopes_sparse
     from predictionio_tpu.models.sequence import model as seq_model, sparse_moe
 
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "seq"))
@@ -582,3 +668,23 @@ def test_the_lifelong_histories_cells_step_fits_the_chip_at_6_layers_and_scopes_
     scatters = re.findall(r'= (\S+?)\{\S* scatter\(([^\n]*)', text)
     assert sorted(shape for shape, _ in scatters) == ["f32[18992,2048]", "f32[2097152]"]
     assert not [rest for _, rest in scatters if "moe/experts" in rest]
+    # the leaves, as ``benchmarks/scopes_leaf.py`` takes an ``op_name`` apart: a
+    # layer's in every phase (the indexer and the selection pass no gradient);
+    # the experts' rows and grouped matmuls forward and again, the sum back onto
+    # the tokens not again (the backward of ``take`` is its ``sum``: the cast
+    # after it is fused away); every attention program under its leaf
+    seen = {(p.stage, p.leaf, p.phase) for p in _places(text)}
+    every = ("forward", "recomputed", "backward")
+    want = {("attention", leaf): every for leaf in ("norm", "qkv", "rope", "kernel", "out")}
+    want |= {("attention", "index"): every[:2], ("attention", "select"): every[:2],
+             ("moe", "norm"): every, ("experts", "sort"): every[:2],
+             ("experts", "take"): every[:2], ("experts", "grouped"): every,
+             ("experts", "give"): every[::2], ("experts", "sum"): every[::2]}
+    for (stage, leaf), phases in want.items():
+        assert tuple(p for p in every if (stage, leaf, p) in seen) == phases, (stage, leaf)
+    assert {(stage, leaf) for stage, leaf, _ in seen if leaf} == set(want)
+    again = re.findall(r'op_name="([^"]*seq\.[^"]*/again/[^"]*)"', text)
+    assert again and all("transpose(jvp(seq.pass1))" in name and "/moe/experts/" in name
+                         for name in again)
+    assert {scopes_leaf.place_of(c).leaf for c in calls if "seq." in c} == {
+        "index", "select", "kernel"}

@@ -479,4 +479,17 @@ class TestCompileCache:
         assert plat.configure_compile_cache() == str(tmp_path)
         assert "jax_compilation_cache_dir" not in updates
 
+    def test_the_key_covers_the_names_a_trace_is_read_by(self, monkeypatch, tmp_path):
+        """A cache written before a ``jax.named_scope`` was added must not serve
+        the program under its old names: JAX's key leaves them out unless told."""
+        import jax
+
+        import predictionio_tpu.utils.platform as plat
+
+        updates = {}
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        monkeypatch.setattr(jax.config, "update", updates.__setitem__)
+        plat.configure_compile_cache()
+        assert updates["jax_compilation_cache_include_metadata_in_key"] is True
+
 
