@@ -54,7 +54,7 @@ SEED = 0
 SPARSE_FIT_FACTS = ("experts_total", "experts_held", "experts_per_token", "index_topk",
                     "moe_assignments", "moe_held_assignments", "moe_held_load_max",
                     "moe_dropped", "moe_passes", "moe_passes_run", "selected_pairs",
-                    "causal_pairs")
+                    "causal_pairs", "selection_kept_bytes")
 #: the leaf scopes a compiled sequence step has to carry under each stage
 #: (``jax.named_scope``; the strings are ``looped``'s and ``sparse_moe``'s), and
 #: of them those whose backward pass is work of its own
@@ -623,8 +623,10 @@ class Smoke:
         the widths): a few steps on one batch of users whose histories are
         several times ``indexTopk`` long, so that the selection bites. The
         ``seq_fit:`` line has to name the backbone, the held experts, a
-        selection smaller than the causal triangle and no dropped token: a
-        silent fall to a dense path or to the whole layer would show."""
+        selection smaller than the causal triangle, the bytes of it a step
+        keeps for its backward pass and no dropped token: a silent fall to a
+        dense path, to the whole layer or to a selection worked twice would
+        show."""
         import numpy as np
 
         t0 = time.time()
@@ -674,6 +676,11 @@ class Smoke:
             raise PhaseFailed(f"train_sequence_sparse_moe: the experts' passes: {facts}")
         if not 0 < facts.get("selected_pairs", 0) < facts["causal_pairs"]:
             raise PhaseFailed(f"train_sequence_sparse_moe: no selection (a dense path?): {facts}")
+        # the rematerialised layers start from the forward pass's selection, a bit
+        # a pair: layers x rows x maxLen^2 / 8 bytes kept for the backward pass
+        kept = algorithm["numLayers"] * algorithm["batchSize"] * max_len * max_len // 8
+        if facts.get("selection_kept_bytes") != kept:
+            raise PhaseFailed(f"train_sequence_sparse_moe: the selection is not kept: {facts}")
         first, last = facts["first_loss"], facts["last_loss"]
         if not (first == first and last == last and last < first < float("inf")):
             raise PhaseFailed(f"train_sequence_sparse_moe: loss not finite and falling: {first} -> {last}")

@@ -302,7 +302,8 @@ def train_sasrec(
     step = 0
     aux = {}
     first_loss = loss = None
-    span_attrs = fit_attrs(config, _tree_bytes(params), _tree_bytes(opt_state))
+    span_attrs = fit_attrs(config, _tree_bytes(params), _tree_bytes(opt_state),
+                           min(config.batch_size, n) // dp * dp)
     with global_tracer().span("seq.fit", attrs=span_attrs) as span:
         for _ in range(config.epochs):
             order = np_rng.permutation(n)
@@ -346,17 +347,21 @@ def train_sasrec(
 
 #: the span's attributes the ``seq_fit:`` line repeats (a backbone that has them)
 _FIT_LINE_ATTRS = ("experts_total", "experts_held", "experts_per_token", "index_topk",
-                   "kv_heads")
+                   "kv_heads", "selection_kept_bytes")
 _BACKBONES = {LoopedConfig: "looped", SparseMoEConfig: "sparse_moe"}
 
 
-def fit_attrs(config, param_bytes: int, opt_state_bytes: int) -> dict:
-    """What the fit's span says of the model it trains."""
+def fit_attrs(config, param_bytes: int, opt_state_bytes: int, rows: int) -> dict:
+    """What the fit's span says of the model it trains and of how a step on
+    ``rows`` rows is worked."""
     attrs = {
         "backbone": _BACKBONES.get(type(config), "sasrec"),
         "param_bytes": param_bytes,
         # weights, their gradients and the optimizer's moments
         "state_bytes": 2 * param_bytes + opt_state_bytes,
+        # what a rematerialised layer keeps beside its input (the sparse
+        # backbone: its selection, one bit a pair)
+        "selection_kept_bytes": 0,
     }
     if isinstance(config, (LoopedConfig, SparseMoEConfig)):
         chunk = looped.head_chunk_of(config)
@@ -370,7 +375,8 @@ def fit_attrs(config, param_bytes: int, opt_state_bytes: int) -> dict:
             attrs.update(
                 experts_total=config.num_experts, experts_held=config.held,
                 experts_per_token=config.experts_per_token,
-                index_topk=config.index_topk, kv_heads=config.num_kv_heads)
+                index_topk=config.index_topk, kv_heads=config.num_kv_heads,
+                selection_kept_bytes=sparse_moe.selection_kept_bytes(config, rows))
     else:
         attrs.update(layers=config.num_blocks, passes=1,
                      rematerialised="nothing", head="whole")

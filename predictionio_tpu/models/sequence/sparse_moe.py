@@ -44,7 +44,12 @@ How it is worked (``benchmarks/reference_keye.py`` is the same mathematics
 with none of this):
 
 - layer parameters are stacked ``[L, ...]`` and the stack is one ``lax.scan``,
-  each layer rematerialised from its input (``remat``);
+  each layer rematerialised from its input (``remat``) and from its selection:
+  the forward pass keeps the mask of selected pairs, one bit a pair
+  (``_pack_rows``; ``L B T T / 8`` bytes, 100.7 MB at 6 layers of 2 rows of
+  8,192), and the backward pass unpacks it where the layer is worked again,
+  so the indexer's projections, the index scores and the k-th largest are
+  worked once a step. Everything else of a layer is recomputed;
 - matmul inputs are ``compute_dtype`` (bfloat16) with float32 accumulation;
   the router's matmul, softmax and top-k, the residual stream, norms, rotary
   positions, attention softmax, loss, master weights and Adam's moments are
@@ -79,6 +84,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from predictionio_tpu.models.sequence import looped
 from predictionio_tpu.ops import sparse_attention as sa
@@ -109,6 +115,9 @@ SCOPE_GROUPED = "grouped"
 SCOPE_GIVE = "give"
 SCOPE_SUM = "sum"
 SCOPE_AGAIN = "again"
+#: what a rematerialised layer keeps from the forward pass beside its input
+#: (``jax.ad_checkpoint.checkpoint_name``): the selection, one bit a pair
+KEPT_SELECTION = "selection"
 
 #: the most float32 bytes the held experts' output rows of one pass may take
 MOE_CHUNK_BYTES = 256 << 20
@@ -249,6 +258,31 @@ def uses_kernels(c: SparseMoEConfig, backend: str) -> bool:
 
 # ---- attention over the indexer's selection ----------------------------------
 
+def _pack_rows(mask):
+    """The 0/1 mask ``[B, T, T]`` as bits, eight query rows a byte with the
+    keys left where they are: ``byte[b, r, s] = sum_i mask[b, 8 r + i, s] << i``,
+    uint8 ``[B, ceil(T / 8), T]``. Both directions are elementwise over whole
+    rows of keys; bits along the key axis would be gathered within a row."""
+    b, t, keys = mask.shape
+    rows = jnp.pad(mask.astype(jnp.uint8), ((0, 0), (0, -t % 8), (0, 0)))
+    rows = rows.reshape(b, -1, 8, keys) << jnp.arange(8, dtype=jnp.uint8)[:, None]
+    return rows.sum(axis=2, dtype=jnp.uint8)
+
+
+def _unpack_rows(packed, t: int):
+    """``_pack_rows`` undone: int8 ``[B, t, T]``."""
+    b, _, keys = packed.shape
+    bits = (packed[:, :, None, :] >> jnp.arange(8, dtype=jnp.uint8)[:, None]) & 1
+    return bits.reshape(b, -1, keys)[:, :t].astype(jnp.int8)
+
+
+def selection_kept_bytes(c: SparseMoEConfig, rows: int) -> int:
+    """What a step on ``rows`` rows keeps of its selections from the forward
+    pass to the backward pass: every layer's packed mask, where the layers are
+    rematerialised (otherwise the backward pass has the mask itself)."""
+    return c.num_layers * rows * -(-c.max_len // 8) * c.max_len if c.remat else 0
+
+
 def _attention(c: SparseMoEConfig, backend: str, rope, h, p, ip, real, probe=None):
     """``(o, counts)``: the attention output before ``Wo`` ``[B, T, H x hd]``
     on the normed input ``h``, and what it selected. ``probe``: query
@@ -282,7 +316,10 @@ def _attention(c: SparseMoEConfig, backend: str, rope, h, p, ip, real, probe=Non
         if probe is not None:
             counts["probe_scores"] = scores[:, probe, :]
             counts["probe_mask"] = mask[:, probe, :]
+        # a rematerialised layer starts from these bits (``hidden_states``)
+        kept = checkpoint_name(_pack_rows(mask), KEPT_SELECTION)
     with jax.named_scope(SCOPE_KERNEL):
+        mask = _unpack_rows(kept, t)
         q, k, v = q.astype(dtype), k.astype(dtype), v.astype(dtype)
         if kernels:
             out = sa.sparse_attention(q, k, v, mask, sa.BLOCK_Q, sa.BLOCK_K, interpret)
@@ -541,7 +578,8 @@ def hidden_states(c: SparseMoEConfig, backend: str, params, seq, probe=None):
         return _layer(c, backend, rope, real, carry, *layer, probe)
 
     if c.remat:
-        body = jax.checkpoint(body)
+        body = jax.checkpoint(
+            body, policy=jax.checkpoint_policies.save_only_these_names(KEPT_SELECTION))
     with jax.named_scope(looped.SCOPE_PASS.format(1)), jax.named_scope(looped.SCOPE_LAYERS):
         return jax.lax.scan(body, x, (params["layers"], params["indexer"]))
 

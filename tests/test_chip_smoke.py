@@ -54,6 +54,7 @@ def test_rehearsal_reaches_every_phase_then_refuses_the_cpu(tmp_path):
     assert sparse["backbone"] == "sparse_moe" and sparse["last_loss"] < sparse["first_loss"]
     assert (sparse["experts_held"], sparse["experts_total"], sparse["moe_dropped"]) == (4, 16, 0)
     assert 0 < sparse["selected_pairs"] < sparse["causal_pairs"]
+    assert sparse["selection_kept_bytes"] == 2 * 8 * 128 * 128 // 8   # layers x rows x T x T bits
     # the compiled steps carry the leaf scopes: five a layer's attention and the
     # mlp's norm; those, the moe's norm and the experts' five
     assert (looped["leaf_scopes"], sparse["leaf_scopes"]) == (6, 11)

@@ -31,14 +31,15 @@ CONFIGS = {
         index_topk=8, moe_chunk=32, attention="plain"),
 }
 #: (stage, leaf) -> the phases that hold it. The indexer and the selection pass
-#: no gradient and the sort is integers; a pass's sum back onto its tokens is
+#: no gradient and are not worked again (the rematerialised layer starts from the
+#: kept bits); the sort is integers; a pass's sum back onto its tokens is
 #: not needed again, its rows and grouped matmuls are
 ALL = ("forward", "recomputed", "backward")
 _ATTENTION = {("attention", leaf): ALL for leaf in ("norm", "qkv", "rope", "kernel", "out")}
 LEAVES = {
     "looped": _ATTENTION | {("mlp", "norm"): ALL},
     "sparse_moe": _ATTENTION | {
-        ("attention", "index"): ALL[:2], ("attention", "select"): ALL[:2],
+        ("attention", "index"): ALL[:1], ("attention", "select"): ALL[:1],
         ("moe", "norm"): ALL, ("experts", "sort"): ALL[:2], ("experts", "take"): ALL,
         ("experts", "grouped"): ALL, ("experts", "give"): ALL[::2], ("experts", "sum"): ALL[::2]},
 }

@@ -373,6 +373,7 @@ def test_the_looped_backbone_learns_a_cycle_and_reports_its_fit():
     attrs = span["attrs"]
     assert attrs["backbone"] == "looped" and attrs["passes"] == 4
     assert attrs["layers"] == 1 and attrs["rematerialised"] == "layer"
+    assert attrs["selection_kept_bytes"] == 0      # the sparse backbone's alone
     assert attrs["param_bytes"] == 4 * looped.count_params(config)
     assert attrs["state_bytes"] >= 4 * attrs["param_bytes"]
     assert len(attrs["exit_p"]) == 4 and abs(sum(attrs["exit_p"]) - 1) < 1e-5

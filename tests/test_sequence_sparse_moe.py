@@ -6,7 +6,8 @@ several times ``index_topk`` long; the three programs of
 ``ops/sparse_attention.py`` in interpret mode against their plain twins; the
 k-th largest against ``jax.lax.top_k``, ties included; the eight shares of a
 layer add up to the whole layer; the experts' passes under an even, a skewed,
-a whole and an empty routing;
+a whole and an empty routing; the selection kept from the forward pass, one bit
+a pair, and read by the rematerialised layer in place of working it again;
 the engine takes the backbone by name."""
 
 import jax
@@ -127,16 +128,83 @@ def test_each_control_of_the_reference_reads_other_gradients(
     assert np.linalg.norm(wrong - sound) > 1e-2 * np.linalg.norm(sound)
 
 
-def test_remat_and_chunks_change_nothing(params, batch):
+#: case -> (how the second step is worked, the most its loss may differ from
+#: the default step's, the most a gradient may, as a share of its largest entry)
+REWORKED = {"chunks": ({"remat": False, "head_chunk": 0, "moe_chunk": 4 * T}, 1e-5, 1e-4),
+            # the rematerialised layer reads the forward pass's own selection, bit
+            # for bit (the packing test below): the same loss, and gradients that
+            # differ by how XLA fuses a layer worked again, float32 rounding
+            # (1.3e-7 read here), where one query's selection changed by one key
+            # moves every trained gradient by 1.5e-2 or more
+            "selection-kept": ({"remat": False}, 0.0, 2e-6)}
+
+
+@pytest.mark.parametrize("case", list(REWORKED))
+def test_remat_and_chunks_change_nothing(params, batch, case):
+    reworked, loss_tolerance, tolerance = REWORKED[case]
     feed = {"seq": jnp.asarray(batch[0]), "target": jnp.asarray(batch[1])}
     values = []
-    for how in ({}, {"remat": False, "head_chunk": 0, "moe_chunk": 4 * T}):
-        fn = sparse_moe.make_loss(_config(**how), _mesh())
+    for how in ({}, reworked):
+        config = _config(**how)
+        assert (sparse_moe.selection_kept_bytes(config, ROWS) > 0) == config.remat
+        fn = sparse_moe.make_loss(config, _mesh())
         (loss, _), grads = jax.jit(jax.value_and_grad(fn, has_aux=True))(params, feed, None)
         values.append((float(loss), _flat(grads)))
-    assert abs(values[0][0] - values[1][0]) < 1e-5
+    assert abs(values[0][0] - values[1][0]) <= loss_tolerance
     for name, g in values[0][1].items():
-        assert np.abs(g - values[1][1][name]).max() <= 1e-4 * max(np.abs(g).max(), 1e-12), name
+        assert np.abs(g - values[1][1][name]).max() <= tolerance * max(np.abs(g).max(), 1e-12), name
+
+
+def _top_ks(jaxpr, layers: int, scan=None):
+    """``(scan, k)`` of every ``top_k`` of ``jaxpr`` and of the programs nested
+    in it; ``scan``: which scan over the layers holds it, ``"forward"``,
+    ``"backward"`` (a reversed one) or ``None``."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "top_k":
+            yield scan, eqn.params["k"]
+        inside = scan
+        if scan is None and eqn.primitive.name == "scan" and eqn.params["length"] == layers:
+            inside = "backward" if eqn.params["reverse"] else "forward"
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _top_ks(sub, layers, inside)
+
+
+def test_the_selection_is_worked_in_the_forward_scan_and_not_in_the_backward_scan(params, batch):
+    """The gradient of a two-layer step as JAX hands it to XLA: the forward
+    scan's body holds the selection's top-k (``index_topk`` wide, the plain
+    path's primitive) and keeps the packed mask; the backward scan's body
+    starts from the kept bits and holds the router's top-k alone."""
+    feed = {"seq": jnp.asarray(batch[0]), "target": jnp.asarray(batch[1])}
+    fn = sparse_moe.make_loss(_config(), _mesh())
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: fn(p, feed, None)[0]))(params).jaxpr
+    assert sorted(_top_ks(jaxpr, 2)) == [("backward", 2), ("forward", 2), ("forward", TOPK)]
+    kept = [v.aval for eqn in jaxpr.eqns if eqn.primitive.name == "scan"
+            and not eqn.params["reverse"] for v in eqn.outvars if v.aval.dtype == jnp.uint8]
+    assert [(a.shape, a.size) for a in kept] == [
+        ((2, ROWS, T // 8, T), sparse_moe.selection_kept_bytes(_config(), ROWS))]
+
+
+@pytest.mark.parametrize("t,topk", [(64, 16), (256, 48), (512, 200), (1024, 128), (20, 6)],
+                         ids=["64", "256-a-query-tile", "512-a-key-tile", "1024", "20-not-in-eights"])
+def test_packing_the_selection_and_unpacking_it_returns_the_mask(t, topk):
+    """Eight query rows a byte, the keys left where they are; a selection with
+    ties at the threshold, at the attention programs' tile widths and at a
+    length that is no multiple of eight (the last byte's spare rows are 0)."""
+    rng = np.random.default_rng(t)
+    scores = jnp.asarray(np.round(rng.standard_normal((2, t, t)) / 0.5) * 0.5 + 0.0, jnp.float32)
+    mask = sa.select_topk_plain(scores, topk)
+    assert mask.dtype == jnp.int8 and int(mask.sum(-1).max()) == topk
+    packed = sparse_moe._pack_rows(mask)
+    assert packed.dtype == jnp.uint8 and packed.shape == (2, -(-t // 8), t)
+    want = np.zeros((2, -(-t // 8) * 8, t), np.uint8)
+    want[:, :t] = np.asarray(mask)
+    assert (np.asarray(packed) == np.packbits(
+        want.reshape(2, -1, 8, t), axis=2, bitorder="little")[:, :, 0]).all()
+    back = sparse_moe._unpack_rows(packed, t)
+    assert back.dtype == jnp.int8 and (np.asarray(back) == np.asarray(mask)).all()
 
 
 # ---- the three programs ------------------------------------------------------
@@ -389,7 +457,10 @@ def test_the_backbone_learns_a_cycle_and_reports_its_fit(caplog):
     assert attrs["moe_dropped"] == 0 and attrs["moe_held_assignments"] == attrs["moe_assignments"]
     assert attrs["selected_pairs"] == 32 * (1 + 2 + 3 + 4 * 5)
     assert attrs["causal_pairs"] == 32 * 36
+    # one layer of 32 rows of 8 positions, a bit a pair, kept for the backward pass
+    assert (attrs["rematerialised"], attrs["selection_kept_bytes"]) == ("layer", 32 * 8)
     line = next(r.getMessage() for r in caplog.records if "seq_fit:" in r.getMessage())
     for word in ("backbone=sparse_moe", "experts_held=4", "experts_total=4", "index_topk=4",
-                 "moe_dropped=0", "moe_held_load_max=", "selected_pairs="):
+                 "selection_kept_bytes=256", "moe_dropped=0", "moe_held_load_max=",
+                 "selected_pairs="):
         assert word in line, (word, line)
