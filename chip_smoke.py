@@ -55,6 +55,9 @@ SPARSE_FIT_FACTS = ("experts_total", "experts_held", "experts_per_token", "index
                     "moe_assignments", "moe_held_assignments", "moe_held_load_max",
                     "moe_dropped", "moe_passes", "moe_passes_run", "selected_pairs",
                     "causal_pairs", "selection_kept_bytes")
+#: and the hybrid backbone's: its layers by kind and what the delta rule carries
+HYBRID_FIT_FACTS = ("experts_shared", "linear_layers", "full_layers", "delta_chunk",
+                    "delta_state_bytes", "delta_kept_bytes")
 #: the leaf scopes a compiled sequence step has to carry under each stage
 #: (``jax.named_scope``; the strings are ``looped``'s and ``sparse_moe``'s), and
 #: of them those whose backward pass is work of its own
@@ -323,7 +326,8 @@ class Smoke:
                          first_loss=float(said["first_loss"]),
                          last_loss=float(said["last_loss"]))
             # what the sparse backbone adds to the line: its share and its counts
-            facts.update({k: float(said[k]) for k in SPARSE_FIT_FACTS if k in said})
+            facts.update({k: float(said[k]) for k in SPARSE_FIT_FACTS + HYBRID_FIT_FACTS
+                          if k in said})
         timings = re.search(r"stage timings: (.*)$", text, re.M)
         if timings:
             facts["stage_timings"] = timings.group(1).strip()
@@ -693,6 +697,74 @@ class Smoke:
                   max_len=max_len, leaf_scopes=len(leaves["leaves"]),
                   again_in_backward=leaves["again_backward"], **widths)
 
+    def phase_train_sequence_hybrid_linear(self) -> None:
+        """The sequence template's hybrid backbone through ``pio train`` at the
+        published widths (one period: three gated-delta-rule layers and a gated
+        full-attention layer, 32 of 512 experts held beside the shared one; a
+        rehearsal cuts the widths): a few steps on one batch of users whose
+        histories are many chunks long. The ``seq_fit:`` line has to name the
+        backbone, its layers by kind, the chunk the rule is worked in, the
+        states a row carries and a layer's backward pass holds, the held
+        experts and no dropped token: a silent fall to another backbone, to the
+        whole layer or to a rule without its state would show."""
+        import numpy as np
+
+        t0 = time.time()
+        out = pio("app_new_hybrid", ["app", "new", "SmokeHybridApp"], self.env, 120)
+        app_id = int(re.search(r"ID: (\d+)", out).group(1))
+        rng = np.random.default_rng(SEED + 2)
+        max_len = 128 if self.rehearsal else 2048
+        lengths = rng.integers(max_len, max_len + 64, size=4)
+        users = np.repeat(np.arange(4), lengths)
+        items = (np.minimum(rng.random(users.size) ** 2.2, 0.999999) * 2_000).astype(np.int64)
+        events = os.path.join(self.basedir, "hybrid_events.jsonl")
+        write_events(events, users, items, np.ones(users.size, np.float32))
+        pio("import_hybrid", ["import", "--appid", str(app_id), "--input", events], self.env, 300)
+        os.unlink(events)
+        widths = ({"hiddenSize": 64, "linearKeyHeads": 2, "linearValueHeads": 4,
+                   "linearKeyDim": 16, "linearValueDim": 16, "numHeads": 4, "numKvHeads": 2,
+                   "headDim": 32, "expertDim": 32, "numExperts": 16, "expertsPerToken": 4,
+                   "expertsHeld": [0, 4], "sharedExpertDim": 32}
+                  if self.rehearsal else
+                  {"hiddenSize": 2048, "linearKeyHeads": 16, "linearValueHeads": 32,
+                   "linearKeyDim": 128, "linearValueDim": 128, "numHeads": 16, "numKvHeads": 2,
+                   "headDim": 256, "expertDim": 512, "numExperts": 512, "expertsPerToken": 10,
+                   "expertsHeld": [0, 32], "sharedExpertDim": 512})
+        algorithm = dict(backbone="hybrid_linear", numLayers=4, fullAttentionInterval=4,
+                         batchSize=4, epochs=6, learningRate=3e-4, **widths)
+
+        def edit(v):
+            v["datasource"]["params"]["appName"] = "SmokeHybridApp"
+            v["preparator"]["params"]["maxLen"] = max_len
+            v["algorithms"][0]["params"].update(algorithm)
+            v["sparkConf"] = {"pio.mesh_shape": [1, 1], "pio.mesh_axes": ["data", "seq"]}
+
+        seq_dir = self.engine_dir("sequence_hybrid_linear", "sequence", edit)
+        facts = self.train("train_sequence_hybrid_linear", seq_dir, 900)
+        held = widths["expertsHeld"][1] - widths["expertsHeld"][0]
+        if (facts.get("backbone") != "hybrid_linear" or facts.get("steps") != 6
+                or (facts.get("linear_layers"), facts.get("full_layers")) != (3, 1)
+                or facts.get("experts_held") != held or facts.get("experts_shared") != 1
+                or facts.get("experts_total") != widths["numExperts"]):
+            raise PhaseFailed(
+                f"train_sequence_hybrid_linear: not six steps of three linear layers and a"
+                f" full one with {held} of {widths['numExperts']} experts held: {facts}")
+        if facts.get("moe_dropped") != 0 or not (
+                0 < facts.get("moe_held_assignments", 0) < facts["moe_assignments"]):
+            raise PhaseFailed(f"train_sequence_hybrid_linear: tokens dropped, or no share: {facts}")
+        # a value head's state is linearKeyDim x linearValueDim float32; a row
+        # carries three layers' and a layer's backward pass holds every chunk's
+        state = widths["linearValueHeads"] * widths["linearKeyDim"] * widths["linearValueDim"] * 4
+        chunks = -(-max_len // int(facts.get("delta_chunk", 1)))
+        if (facts.get("delta_state_bytes") != 3 * state
+                or facts.get("delta_kept_bytes") != algorithm["batchSize"] * chunks * state):
+            raise PhaseFailed(f"train_sequence_hybrid_linear: the rule's states: {facts}")
+        first, last = facts["first_loss"], facts["last_loss"]
+        if not (first == first and last == last and last < first < float("inf")):
+            raise PhaseFailed(f"train_sequence_hybrid_linear: loss not finite and falling: {first} -> {last}")
+        self.line("train_sequence_hybrid_linear", t0, **facts, users=4, events=int(users.size),
+                  max_len=max_len, **widths)
+
     def phase_sharded(self) -> None:
         self.phase_device(with_status=False)
         if self.device["count"] != 4:
@@ -754,6 +826,7 @@ def main(argv=None) -> int:
                 smoke.phase_serve_als, smoke.phase_train_serve_ncf,
                 smoke.phase_train_sequence_looped,
                 smoke.phase_train_sequence_sparse_moe,
+                smoke.phase_train_sequence_hybrid_linear,
             ]
         for phase in phases:
             try:

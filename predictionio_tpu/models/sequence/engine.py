@@ -29,6 +29,7 @@ from predictionio_tpu.controller.base import SanityCheck
 from predictionio_tpu.data.store import PEventStore
 from predictionio_tpu.models._als_common import score_buffer_rows, topk_item_scores
 from predictionio_tpu.models.sequence.looped import LoopedConfig
+from predictionio_tpu.models.sequence.hybrid import HybridConfig
 from predictionio_tpu.models.sequence.sparse_moe import SparseMoEConfig
 from predictionio_tpu.ops.flash_attention import tiles_worked
 from predictionio_tpu.models.sequence.model import (
@@ -181,7 +182,8 @@ class SequencePreparator(Preparator):
 @dataclass
 class SASRecModel:
     params: dict
-    config: SASRecConfig | LoopedConfig | SparseMoEConfig   # the backbone it was trained with
+    # the backbone it was trained with
+    config: SASRecConfig | LoopedConfig | SparseMoEConfig | HybridConfig
     item_ids: list[str]
     item_index: dict[str, int]
     histories: dict[str, np.ndarray]   # user id -> shifted (+1) id sequence
@@ -197,7 +199,8 @@ class SASRecModel:
 
 
 class SASRecAlgorithm(TPUAlgorithm):
-    """Params: ``backbone`` ("sasrec", the default, "looped" or "sparse_moe");
+    """Params: ``backbone`` ("sasrec", the default, "looped", "sparse_moe" or
+    "hybrid_linear");
     learningRate, batchSize, epochs, seed, maxLen (must match the
     preparator's), attention ("auto" | "flash" | "plain") and seqParallel
     ("ring" | "ulysses", the sequence-parallel attention strategy when the
@@ -209,9 +212,16 @@ class SASRecAlgorithm(TPUAlgorithm):
     numKvHeads, headDim, expertDim, numExperts, expertsPerToken, expertsHeld
     (``[lo, hi]``: the experts this program holds of the ``numExperts``; the
     default is all), numLayers, indexHeads, indexDim, indexTopk, ropeTheta,
-    rmsNormEps, auxLossCoef."""
+    rmsNormEps, auxLossCoef; "hybrid_linear" (``models/sequence/hybrid.py``:
+    periods of fullAttentionInterval layers, the last a gated full-attention
+    layer, the others gated-delta-rule linear-attention layers) reads
+    hiddenSize, numLayers, fullAttentionInterval, linearKeyHeads,
+    linearValueHeads, linearKeyDim, linearValueDim, convKernel, numHeads,
+    numKvHeads, headDim, partialRotaryFactor, expertDim, numExperts,
+    expertsPerToken, expertsHeld, sharedExpertDim, ropeTheta, rmsNormEps,
+    auxLossCoef."""
 
-    BACKBONES = ("sasrec", "looped", "sparse_moe")
+    BACKBONES = ("sasrec", "looped", "sparse_moe", "hybrid_linear")
 
     def _config(self, num_items: int, max_len: int):
         p = self.params
@@ -262,6 +272,34 @@ class SASRecAlgorithm(TPUAlgorithm):
                 index_heads=p.get_or("indexHeads", d.index_heads),
                 index_dim=p.get_or("indexDim", d.index_dim),
                 index_topk=p.get_or("indexTopk", d.index_topk),
+                rope_theta=float(p.get_or("ropeTheta", d.rope_theta)),
+                rms_eps=float(p.get_or("rmsNormEps", d.rms_eps)),
+                aux_coef=float(p.get_or("auxLossCoef", d.aux_coef)),
+                learning_rate=p.get_or("learningRate", d.learning_rate),
+                **shared,
+            )
+        if backbone == "hybrid_linear":
+            d = HybridConfig(num_items=num_items)  # the defaults, in one place
+            experts = p.get_or("numExperts", d.num_experts)
+            return HybridConfig(
+                hidden_size=p.get_or("hiddenSize", d.hidden_size),
+                num_layers=p.get_or("numLayers", d.num_layers),
+                full_attention_interval=p.get_or(
+                    "fullAttentionInterval", d.full_attention_interval),
+                linear_key_heads=p.get_or("linearKeyHeads", d.linear_key_heads),
+                linear_value_heads=p.get_or("linearValueHeads", d.linear_value_heads),
+                linear_key_dim=p.get_or("linearKeyDim", d.linear_key_dim),
+                linear_value_dim=p.get_or("linearValueDim", d.linear_value_dim),
+                conv_kernel=p.get_or("convKernel", d.conv_kernel),
+                num_heads=p.get_or("numHeads", d.num_heads),
+                num_kv_heads=p.get_or("numKvHeads", d.num_kv_heads),
+                head_dim=p.get_or("headDim", d.head_dim),
+                rotary_fraction=float(p.get_or("partialRotaryFactor", d.rotary_fraction)),
+                expert_dim=p.get_or("expertDim", d.expert_dim),
+                num_experts=experts,
+                experts_per_token=p.get_or("expertsPerToken", d.experts_per_token),
+                experts_held=tuple(p.get_or("expertsHeld", (0, experts))),
+                shared_expert_dim=p.get_or("sharedExpertDim", d.shared_expert_dim),
                 rope_theta=float(p.get_or("ropeTheta", d.rope_theta)),
                 rms_eps=float(p.get_or("rmsNormEps", d.rms_eps)),
                 aux_coef=float(p.get_or("auxLossCoef", d.aux_coef)),

@@ -1,0 +1,464 @@
+"""The hybrid backbone of the sequence template: a decoder whose layers come
+in periods of ``full_attention_interval``, all but the last of a period
+linear-attention layers (a gated delta rule: a state a row carries along, no
+score square) and the last a gated full-attention layer, each followed by a
+routed mixture of experts of which this program holds a share, beside a
+shared expert every token takes.
+
+The block is that of ``Qwen3-Next-80B-A3B`` (``model_type qwen3_next``: 3
+Gated DeltaNet layers to 1 gated attention layer, 512 experts, 10 a token, one
+shared expert behind a sigmoid gate) with the item catalog as its vocabulary.
+For one row ``x`` ``[T, D]``, ``n(.)`` the zero-centred RMSNorm
+``x / rms(x) (1 + w)``:
+
+- **linear layer** (``HV`` value heads of ``dv``, ``HK`` key heads of ``dk``, a
+  key head serving ``HV / HK`` value heads): ``h = n1(x)``;
+  ``[q, k, v, z] = h W_qkvz``, ``[b, a] = h W_ba``;
+  ``[q, k, v] <- silu(conv([q, k, v]))``, ``conv`` depthwise, causal, over the
+  last ``conv_kernel`` positions, no bias; ``beta = sigmoid(b)``,
+  ``g = -exp(A_log) softplus(a + dt_bias)`` a value head;
+  ``q <- q / |q| / sqrt(dk)``, ``k <- k / |k|``; the gated delta rule
+  (``ops/delta_rule.py`` has the recurrence) gives ``o`` ``[T, HV, dv]``;
+  ``y = o / rms(o) w_n silu(z)``; ``x <- x + concat_heads(y) W_out``. A padded
+  slot (``seq == 0``) has ``beta = 0``, ``g = 0`` and a zero conv input: it
+  neither moves the state nor is read;
+- **full layer**: ``[q, gate] = h W_q`` (``H`` heads of ``hd + hd``),
+  ``k = h W_k``, ``v = h W_v`` (``KV`` heads); ``q <- n_q(q)``, ``k <- n_k(k)``
+  over the head; rotary positions on the first ``rotary_dim`` dimensions of a
+  head, the rest pass; causal ``softmax(q k^T / sqrt(hd)) v``;
+  ``x <- x + (attn sigmoid(gate)) W_o``;
+- **experts, every layer**: ``u = n2(x)``; the router, its top
+  ``experts_per_token``, the renormalised gates, the held experts' part of the
+  sum and the load-balancing loss are ``sparse_moe._moe``'s, as they stand
+  (``experts_held = (lo, hi)``: the router is whole, the other chips' parts are
+  theirs to add, nothing stands in for them); beside it the shared expert,
+  worked on every token: ``sigmoid(u . w_sg) W2_s(silu(W1_s u) W3_s u)``. Of an
+  expert-parallel deployment's shares each adds the shared expert's part; it
+  is counted once when shares are added up;
+- final norm, an untied head, cross-entropy at the positions with a target
+  plus ``aux_coef`` times the mean over the layers of the load-balancing loss.
+
+How it is worked (``benchmarks/reference_qwen3next.py`` is the same
+mathematics with none of this):
+
+- the parameters of a period are stacked by kind of layer, a layer's mixer
+  and experts together: ``periods/linear`` ``[P, I - 1, ...]``, ``periods/full``
+  ``[P, ...]`` (one array of all layers' experts would be sliced, and so
+  copied, for each kind); the stack is a ``lax.scan`` over the periods, inside
+  it a ``lax.scan`` over the period's linear layers and then its full layer.
+  Each half of a layer, the mixer and the experts, is rematerialised from its
+  own input (``remat``): the forward pass keeps the residual stream before
+  each, ``2 L`` states ``[B, T, D]``, and nothing else, and a backward pass
+  holds one half's intermediates at a time (a linear mixer's and its experts'
+  together did not leave the chip room). Inside a linear mixer the conv keeps
+  its input and works its products and silu again;
+- the delta rule is worked ``delta_chunk`` positions at a time
+  (``ops/delta_rule.gated_delta_rule``): the triangular system and the
+  products inside the chunks for all chunks at once, the state by a pass over
+  the chunks, on a TPU two Pallas programs (the pass and its transpose). While
+  a layer's backward pass runs, the state every chunk starts from is held
+  (``delta_kept_bytes``); between the passes nothing of the rule is;
+- the full layer's attention is ``ops/sparse_attention.causal_attention``: the
+  sparse backbone's three programs (8 query heads a key-value head, K and V
+  streamed) with no mask operand; off the TPU its plain twin;
+- matmul inputs are ``compute_dtype`` (bfloat16) with float32 accumulation;
+  the state, ``g``, ``beta``, the l2 norms, the triangular system, the router,
+  norms, rotary positions, softmax, residual stream, loss, master weights and
+  Adam's moments are float32;
+- the head and loss are ``looped._exit_ce``'s chunks of positions.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from predictionio_tpu.models.sequence import looped, sparse_moe
+from predictionio_tpu.ops import delta_rule, sparse_attention as sa
+
+#: Device scopes of a training step beside ``looped``'s and ``sparse_moe``'s: a
+#: linear layer's mixer under ``seq.pass1/layers/linear_attention`` (one
+#: component, so a reader that looks for ``attention`` does not take it), the
+#: full layer's under ``attention`` with ``looped``'s leaves, the shared expert
+#: under ``moe/shared``.
+SCOPE_LINEAR = "linear_attention"
+SCOPE_SHARED = "shared"
+#: Leaves under ``linear_attention`` beside ``norm``, ``qkv`` (the two input
+#: projections) and ``out``: ``conv`` (the depthwise convolution and its silu),
+#: ``gates`` (beta, g, the l2 norms), ``delta`` (the chunked rule and its
+#: programs), ``gated_norm`` (the output's norm and gate).
+SCOPE_CONV = "conv"
+SCOPE_GATES = "gates"
+SCOPE_DELTA = "delta"
+SCOPE_GATED_NORM = "gated_norm"
+
+
+@dataclass(frozen=True)
+class HybridConfig:
+    num_items: int              # real item vocab; id 0 is reserved for padding
+    max_len: int = 64
+    hidden_size: int = 64
+    num_layers: int = 4
+    full_attention_interval: int = 4   # the last layer of every so many is the full one
+    linear_key_heads: int = 2
+    linear_value_heads: int = 4
+    linear_key_dim: int = 16
+    linear_value_dim: int = 16
+    conv_kernel: int = 4
+    num_heads: int = 4
+    num_kv_heads: int = 2
+    head_dim: int = 32
+    rotary_fraction: float = 0.25      # of a head's dimensions, the first, are rotated
+    expert_dim: int = 32
+    num_experts: int = 8
+    experts_per_token: int = 2
+    experts_held: tuple = (0, 8)       # [lo, hi) of the experts: this program's share
+    shared_expert_dim: int = 32
+    rope_theta: float = 1e7
+    rms_eps: float = 1e-6
+    aux_coef: float = 0.001
+    learning_rate: float = 3e-4
+    batch_size: int = 256
+    epochs: int = 10
+    seed: int = 0
+    seq_parallel: str = "ring"
+    attention: str = "auto"
+    # how the step is worked: what the tests vary, and no engine parameter
+    compute_dtype: str = "bfloat16"   # matmul inputs; accumulation is float32
+    remat: bool = True
+    head_chunk: int | None = None     # None: from looped.HEAD_CHUNK_BYTES; 0: whole
+    moe_chunk: int | None = None      # None: from sparse_moe.MOE_CHUNK_BYTES
+    delta_chunk: int = delta_rule.CHUNK
+
+    def __post_init__(self):
+        object.__setattr__(self, "experts_held", tuple(int(e) for e in self.experts_held))
+        lo, hi = self.experts_held
+        if not 0 <= lo < hi <= self.num_experts:
+            raise ValueError(
+                f"experts_held={self.experts_held}: want 0 <= lo < hi <= num_experts="
+                f"{self.num_experts}")
+        if self.full_attention_interval < 2 or self.num_layers % self.full_attention_interval:
+            raise ValueError(
+                f"num_layers={self.num_layers} must be whole periods of"
+                f" full_attention_interval={self.full_attention_interval} (at least 2)")
+        for many, few, what in ((self.num_heads, self.num_kv_heads, "num_kv_heads"),
+                                (self.linear_value_heads, self.linear_key_heads,
+                                 "linear_key_heads")):
+            if many % few:
+                raise ValueError(f"{what}={few} must divide the {many} heads it serves")
+        if not 1 <= self.experts_per_token <= self.num_experts:
+            raise ValueError(
+                f"experts_per_token={self.experts_per_token}: want 1 .. num_experts")
+        if self.attention not in ("auto", "flash", "plain"):
+            raise ValueError(
+                f"attention={self.attention!r} must be one of 'auto' | 'flash' | 'plain'")
+        if self.compute_dtype not in ("bfloat16", "float32"):
+            raise ValueError(
+                f"compute_dtype={self.compute_dtype!r}: want 'bfloat16' or 'float32'")
+        if self.rotary_dim % 2 or not 0 < self.rotary_dim <= self.head_dim:
+            raise ValueError(
+                f"rotary_fraction={self.rotary_fraction} of head_dim={self.head_dim} must"
+                " be an even count of dimensions")
+
+    @property
+    def vocab(self) -> int:
+        return self.num_items + 1  # +1 for the padding id 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held[1] - self.experts_held[0]
+
+    @property
+    def periods(self) -> int:
+        return self.num_layers // self.full_attention_interval
+
+    @property
+    def linear_layers(self) -> int:
+        return self.num_layers - self.periods
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.rotary_fraction)
+
+
+def param_shapes(c: HybridConfig) -> dict:
+    """The parameter tree as shapes. ``periods/linear`` leads with ``[P, I - 1]``,
+    ``periods/full`` with ``[P]``; each holds its layers' mixer and experts."""
+    d, p, i = c.hidden_size, c.periods, c.full_attention_interval
+    keys, values = c.linear_key_heads * c.linear_key_dim, c.linear_value_heads * c.linear_value_dim
+    hd, shared = c.head_dim, c.shared_expert_dim
+    lin = (p, i - 1)
+
+    def experts(lead):
+        return {
+            "n2": lead + (d,), "router": lead + (d, c.num_experts),
+            "w_gate": lead + (c.held, d, c.expert_dim), "w_up": lead + (c.held, d, c.expert_dim),
+            "w_down": lead + (c.held, c.expert_dim, d),
+            "s_gate": lead + (d, shared), "s_up": lead + (d, shared),
+            "s_down": lead + (shared, d), "s_g": lead + (d,),
+        }
+
+    return {
+        "embed": (c.vocab, d),
+        "periods": {
+            "linear": {
+                "n1": lin + (d,), "w_qkvz": lin + (d, 2 * keys + 2 * values),
+                "w_ba": lin + (d, 2 * c.linear_value_heads),
+                "conv": lin + (2 * keys + values, c.conv_kernel),
+                "a_log": lin + (c.linear_value_heads,), "dt_bias": lin + (c.linear_value_heads,),
+                "norm": lin + (c.linear_value_dim,), "w_out": lin + (values, d),
+                **experts(lin),
+            },
+            "full": {
+                "n1": (p, d), "wq": (p, d, 2 * c.num_heads * hd),
+                "wk": (p, d, c.num_kv_heads * hd), "wv": (p, d, c.num_kv_heads * hd),
+                "wo": (p, c.num_heads * hd, d), "q_norm": (p, hd), "k_norm": (p, hd),
+                **experts((p,)),
+            },
+        },
+        "final_norm": (d,),
+        "head": (c.vocab, d),
+    }
+
+
+#: zero-centred norm weights start at 0, the gated norm's plain weight at 1
+_ZEROS = ("n1", "n2", "q_norm", "k_norm", "final_norm")
+#: the projections that write into the residual stream
+_WRITERS = ("w_out", "wo", "w_down", "s_down")
+_is_shape = lambda x: isinstance(x, tuple)  # noqa: E731
+
+
+def init_params(c: HybridConfig, rng) -> dict:
+    """As ``sparse_moe.init_params`` (the embedding N(0, 1), matrices
+    N(0, 0.02), those that write into the residual stream N(0, 0.02 / sqrt(2 L)))
+    with the family's own: ``A_log = log U(0, 16)``, ``dt_bias = 1``, the conv
+    weights U(-1/2, 1/2) (``torch.nn.Conv1d``'s default at a fan-in of 4)."""
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(param_shapes(c), is_leaf=_is_shape)
+    out = []
+    for n, (path, shape) in enumerate(leaves):
+        name, key = path[-1].key, jax.random.fold_in(rng, n)
+        if name in _ZEROS:
+            out.append(jnp.zeros(shape, jnp.float32))
+        elif name in ("norm", "dt_bias"):
+            out.append(jnp.ones(shape, jnp.float32))
+        elif name == "a_log":
+            out.append(jnp.log(jax.random.uniform(key, shape, jnp.float32, 1e-3, 16.0)))
+        elif name == "conv":
+            out.append(jax.random.uniform(key, shape, jnp.float32, -0.5, 0.5))
+        else:
+            std = (1.0 if name == "embed" else
+                   0.02 / np.sqrt(2 * c.num_layers) if name in _WRITERS else 0.02)
+            out.append(std * jax.random.normal(key, shape, jnp.float32))
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def count_params(c: HybridConfig) -> int:
+    return sum(int(np.prod(s)) for s in jax.tree_util.tree_leaves(
+        param_shapes(c), is_leaf=_is_shape))
+
+
+def delta_state_bytes(c: HybridConfig) -> int:
+    """A row's recurrent states, all linear layers: what serving one user
+    would carry from one event to the next."""
+    return c.linear_layers * c.linear_value_heads * c.linear_key_dim * c.linear_value_dim * 4
+
+
+def delta_kept_bytes(c: HybridConfig, rows: int) -> int:
+    """The states every chunk of ``rows`` rows starts from, float32: what a
+    step holds of the rule for a backward pass. A rematerialised layer keeps
+    nothing between the passes and holds its own chunks' states while its
+    backward pass runs, one layer at a time; with ``remat`` off every linear
+    layer's are kept from the forward pass."""
+    chunks = -(-c.max_len // c.delta_chunk)
+    layers = 1 if c.remat else c.linear_layers
+    return rows * chunks * layers * delta_state_bytes(c) // c.linear_layers
+
+
+def _norm0(x, weight, eps):
+    return looped._rms_norm(x, 1.0 + weight, eps)
+
+
+# ---- the mixers --------------------------------------------------------------
+
+def _causal_conv(x, weight):
+    """``y[b, t, c] = sum_i weight[c, i] x[b, t - (K - 1) + i, c]``, zero before
+    the row: ``x`` [B, T, C], ``weight`` [C, K]."""
+    t, width = x.shape[1], weight.shape[1]
+    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    return sum(padded[:, i:i + t] * weight[:, i] for i in range(width))
+
+
+def _l2_normalised(x):
+    return x * jax.lax.rsqrt((x * x).sum(axis=-1, keepdims=True) + 1e-6)
+
+
+def _linear_attention(c: HybridConfig, backend: str, h, p, real):
+    """The linear mixer's output before ``W_out`` ``[B, T, HV x dv]`` on the
+    normed input ``h``."""
+    dtype = jnp.dtype(c.compute_dtype)
+    b, t, _ = h.shape
+    hk, hv, dk, dv = (c.linear_key_heads, c.linear_value_heads, c.linear_key_dim,
+                      c.linear_value_dim)
+    on = real[..., None]
+    with jax.named_scope(looped.SCOPE_QKV):
+        # two products, so that the gate z is not a view into one array that
+        # holds q, k and v until the backward pass is done with z
+        w_mixed, w_z = jnp.split(p["w_qkvz"], [2 * hk * dk + hv * dv], axis=-1)
+        mixed, z = looped._matmul(h, w_mixed, dtype), looped._matmul(h, w_z, dtype)
+        beta, a = jnp.split(looped._matmul(h, p["w_ba"], dtype), 2, axis=-1)
+    with jax.named_scope(SCOPE_CONV):
+        # kept: the input alone; the products and the silu are worked again
+        mixed = jax.checkpoint(lambda x, w: jax.nn.silu(_causal_conv(x, w)))(
+            jnp.where(on, mixed, 0.0), p["conv"])
+        q, k, v = jnp.split(mixed, [hk * dk, 2 * hk * dk], axis=-1)
+    with jax.named_scope(SCOPE_GATES):
+        beta = jnp.where(on, jax.nn.sigmoid(beta), 0.0)
+        g = jnp.where(on, -jnp.exp(p["a_log"]) * jax.nn.softplus(a + p["dt_bias"]), 0.0)
+        q = _l2_normalised(q.reshape(b, t, hk, dk)) * dk ** -0.5
+        k = _l2_normalised(k.reshape(b, t, hk, dk))
+    with jax.named_scope(SCOPE_DELTA):
+        o = delta_rule.gated_delta_rule(
+            q, k, v.reshape(b, t, hv, dv), g, beta, chunk=c.delta_chunk, dtype=dtype,
+            kernels=sparse_moe.uses_kernels(c, backend), interpret=backend != "tpu")
+    with jax.named_scope(SCOPE_GATED_NORM):
+        y = looped._rms_norm(o, p["norm"], c.rms_eps) * jax.nn.silu(z.reshape(b, t, hv, dv))
+    return y.reshape(b, t, hv * dv)
+
+
+def _full_attention(c: HybridConfig, backend: str, rope, h, p):
+    """The full mixer's gated output before ``W_o`` ``[B, T, H x hd]``."""
+    dtype = jnp.dtype(c.compute_dtype)
+    b, t, _ = h.shape
+    hd, rd = c.head_dim, c.rotary_dim
+    with jax.named_scope(looped.SCOPE_QKV):
+        q, gate = jnp.split(looped._matmul(h, p["wq"], dtype).reshape(b, t, c.num_heads, 2 * hd),
+                            2, axis=-1)
+        k, v = (looped._matmul(h, p[w], dtype).reshape(b, t, c.num_kv_heads, hd)
+                for w in ("wk", "wv"))
+    with jax.named_scope(looped.SCOPE_NORM):
+        q, k = _norm0(q, p["q_norm"], c.rms_eps), _norm0(k, p["k_norm"], c.rms_eps)
+    with jax.named_scope(looped.SCOPE_ROPE):
+        q, k = (jnp.concatenate([looped._rotate(x[..., :rd], *rope), x[..., rd:]], axis=-1)
+                for x in (q, k))
+    with jax.named_scope(looped.SCOPE_KERNEL):
+        q, k, v = q.astype(dtype), k.astype(dtype), v.astype(dtype)
+        if sparse_moe.uses_kernels(c, backend):
+            out = sa.causal_attention(q, k, v, sa.BLOCK_Q, sa.BLOCK_K, backend != "tpu")
+        else:
+            out = sa.causal_attention_plain(q, k, v)
+        out = out.astype(jnp.float32) * jax.nn.sigmoid(gate)
+    return out.reshape(b, t, -1)
+
+
+def _experts(c: HybridConfig, x, p, real):
+    """``(x', stats)``: the held routed experts' part and the shared expert
+    added to the residual stream ``x`` [B, T, D]."""
+    dtype = jnp.dtype(c.compute_dtype)
+    with jax.named_scope(sparse_moe.SCOPE_MOE):
+        with jax.named_scope(looped.SCOPE_NORM):
+            u = _norm0(x, p["n2"], c.rms_eps)
+        flat = u.reshape(-1, u.shape[-1])
+        y, stats = sparse_moe._moe(c, flat, p, real.reshape(-1))
+        with jax.named_scope(SCOPE_SHARED):
+            inner = (jax.nn.silu(looped._matmul(flat, p["s_gate"], dtype))
+                     * looped._matmul(flat, p["s_up"], dtype))
+            gate = jax.nn.sigmoid(jnp.matmul(flat, p["s_g"], precision=jax.lax.Precision.HIGHEST))
+            y = y + gate[:, None] * looped._matmul(inner, p["s_down"], dtype)
+        return x + y.reshape(x.shape), stats
+
+
+def _linear_mixer(c: HybridConfig, backend: str, real, x, p):
+    dtype = jnp.dtype(c.compute_dtype)
+    with jax.named_scope(SCOPE_LINEAR):
+        with jax.named_scope(looped.SCOPE_NORM):
+            h = _norm0(x, p["n1"], c.rms_eps)
+        y = _linear_attention(c, backend, h, p, real)
+        with jax.named_scope(looped.SCOPE_OUT):
+            return x + looped._matmul(y, p["w_out"], dtype)
+
+
+def _full_mixer(c: HybridConfig, backend: str, rope, x, p):
+    dtype = jnp.dtype(c.compute_dtype)
+    with jax.named_scope(looped.SCOPE_ATTENTION):
+        with jax.named_scope(looped.SCOPE_NORM):
+            h = _norm0(x, p["n1"], c.rms_eps)
+        out = _full_attention(c, backend, rope, h, p)
+        with jax.named_scope(looped.SCOPE_OUT):
+            return x + looped._matmul(out, p["wo"], dtype)
+
+
+# ---- the stack ---------------------------------------------------------------
+
+def hidden_states(c: HybridConfig, backend: str, params, seq):
+    """``(x, stats)``: the residual stream after the last layer ``[B, T, D]``
+    and every layer's counts ``[layers, ...]``, under the pass's scope."""
+    with jax.named_scope(looped.SCOPE_EMBED):
+        real = seq > 0
+        rope = looped._rope_tables(seq.shape[1], c.rotary_dim, c.rope_theta)
+        x = jnp.take(params["embed"], seq, axis=0)
+
+    kept = jax.checkpoint if c.remat else (lambda half: half)
+    experts = kept(lambda x, p: _experts(c, x, p, real))
+    linear_mixer = kept(lambda x, p: _linear_mixer(c, backend, real, x, p))
+    full_mixer = kept(lambda x, p: _full_mixer(c, backend, rope, x, p))
+
+    def linear(carry, layer):
+        return experts(linear_mixer(carry, layer), layer)
+
+    def full(carry, layer):
+        return experts(full_mixer(carry, layer), layer)
+
+    def period(carry, p):
+        carry, stats = jax.lax.scan(linear, carry, p["linear"])
+        carry, last = full(carry, p["full"])
+        return carry, jax.tree_util.tree_map(
+            lambda a, b: jnp.concatenate([a, b[None]]), stats, last)
+
+    with jax.named_scope(looped.SCOPE_PASS.format(1)), jax.named_scope(looped.SCOPE_LAYERS):
+        x, stats = jax.lax.scan(period, x, params["periods"])
+    return x, jax.tree_util.tree_map(lambda a: a.reshape(-1, *a.shape[2:]), stats)
+
+
+def make_loss(c: HybridConfig, mesh):
+    """``loss_fn(params, batch, rng) -> (loss, aux)`` for the trainer's step;
+    ``aux`` is scalars: the two terms of the loss and the step's counts, under
+    ``sparse_moe.make_loss``'s names."""
+    backend = sparse_moe._backend_of(mesh)
+
+    def loss_fn(params, batch, rng):
+        del rng  # no dropout in this block
+        seq, targets = batch["seq"], batch["target"]
+        x, stats = hidden_states(c, backend, params, seq)
+        with jax.named_scope(looped.SCOPE_PASS.format(1)), jax.named_scope(looped.SCOPE_EXIT):
+            h = _norm0(x, params["final_norm"], c.rms_eps)
+            ce = looped._exit_ce(c, h.reshape(-1, h.shape[-1]), params["head"],
+                                 targets.reshape(-1))
+            mask = (targets.reshape(-1) > 0).astype(jnp.float32)
+            ce = (ce * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+            aux_loss = stats["aux"].mean()
+            held = stats["held_assignments"].sum()
+            out = {
+                "ce": ce, "aux_loss": aux_loss,
+                "moe_assignments": stats["assignments"].sum(),
+                "moe_held_assignments": held,
+                "moe_held_load_max": stats["held_load_max"].max(),
+                "moe_held_load_mean": held / (c.num_layers * c.held),
+                "moe_dropped": stats["dropped"].sum(),
+                "moe_passes": stats["passes"].sum(),
+                "moe_passes_run": stats["passes_run"].sum(),
+            }
+            return ce + c.aux_coef * aux_loss, out
+
+    return loss_fn
+
+
+def score_last(c: HybridConfig, params, seqs, last):
+    """Next-item scores [B, V] at position ``last`` of each row: the whole
+    history a query (no state is carried from one query to the next)."""
+    x, _ = hidden_states(c, sparse_moe._backend_of(None), params, seqs)
+    h = _norm0(x, params["final_norm"], c.rms_eps)
+    h = jnp.take_along_axis(h, last[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+    return looped._matmul(h, params["head"].T, jnp.dtype(c.compute_dtype))

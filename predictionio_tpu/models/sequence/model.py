@@ -34,7 +34,8 @@ from predictionio_tpu.parallel.mesh import (
     one_step_in_flight,
     put_global,
 )
-from predictionio_tpu.models.sequence import looped, sparse_moe
+from predictionio_tpu.models.sequence import hybrid, looped, sparse_moe
+from predictionio_tpu.models.sequence.hybrid import HybridConfig
 from predictionio_tpu.models.sequence.looped import LoopedConfig
 from predictionio_tpu.models.sequence.sparse_moe import SparseMoEConfig
 from predictionio_tpu.ops.flash_attention import flash_attention
@@ -213,6 +214,9 @@ def backbone_of(config, mesh):
     if isinstance(config, SparseMoEConfig):
         return (lambda rng, t: sparse_moe.init_params(config, rng),
                 sparse_moe.make_loss(config, mesh))
+    if isinstance(config, HybridConfig):
+        return (lambda rng, t: hybrid.init_params(config, rng),
+                hybrid.make_loss(config, mesh))
     model = SASRec(config, mesh)
     # dummy batch = one row per data-shard: shard_map needs divisibility
     dp0 = max(mesh.shape.get("data", 1), 1)
@@ -268,7 +272,7 @@ def make_fit(config, mesh):
 
 
 def train_sasrec(
-    config,                  # SASRecConfig | LoopedConfig | SparseMoEConfig: the backbone
+    config,                  # SASRecConfig | LoopedConfig | SparseMoEConfig | HybridConfig
     sequences: np.ndarray,   # [N, T] int32 padded item ids (0 = pad)
     mesh,
     log_every: int = 0,
@@ -346,9 +350,11 @@ def train_sasrec(
 
 
 #: the span's attributes the ``seq_fit:`` line repeats (a backbone that has them)
-_FIT_LINE_ATTRS = ("experts_total", "experts_held", "experts_per_token", "index_topk",
-                   "kv_heads", "selection_kept_bytes")
-_BACKBONES = {LoopedConfig: "looped", SparseMoEConfig: "sparse_moe"}
+_FIT_LINE_ATTRS = ("experts_total", "experts_held", "experts_per_token", "experts_shared",
+                   "index_topk", "kv_heads", "selection_kept_bytes", "linear_layers",
+                   "full_layers", "delta_chunk", "delta_state_bytes", "delta_kept_bytes")
+_BACKBONES = {LoopedConfig: "looped", SparseMoEConfig: "sparse_moe",
+              HybridConfig: "hybrid_linear"}
 
 
 def fit_attrs(config, param_bytes: int, opt_state_bytes: int, rows: int) -> dict:
@@ -363,20 +369,29 @@ def fit_attrs(config, param_bytes: int, opt_state_bytes: int, rows: int) -> dict
         # backbone: its selection, one bit a pair)
         "selection_kept_bytes": 0,
     }
-    if isinstance(config, (LoopedConfig, SparseMoEConfig)):
+    if isinstance(config, (LoopedConfig, SparseMoEConfig, HybridConfig)):
         chunk = looped.head_chunk_of(config)
         attrs.update(
             layers=config.num_layers, passes=getattr(config, "ut_steps", 1),
-            rematerialised="layer" if config.remat else "nothing",
+            rematerialised=("nothing" if not config.remat else
+                            "mixer and experts" if isinstance(config, HybridConfig) else "layer"),
             head=(f"chunks of {chunk} positions, recomputed" if chunk else
                   "whole pass, recomputed"),
         )
-        if isinstance(config, SparseMoEConfig):
+        if isinstance(config, (SparseMoEConfig, HybridConfig)):
             attrs.update(
                 experts_total=config.num_experts, experts_held=config.held,
-                experts_per_token=config.experts_per_token,
-                index_topk=config.index_topk, kv_heads=config.num_kv_heads,
+                experts_per_token=config.experts_per_token, kv_heads=config.num_kv_heads)
+        if isinstance(config, SparseMoEConfig):
+            attrs.update(
+                index_topk=config.index_topk,
                 selection_kept_bytes=sparse_moe.selection_kept_bytes(config, rows))
+        if isinstance(config, HybridConfig):
+            attrs.update(
+                experts_shared=1, linear_layers=config.linear_layers,
+                full_layers=config.periods, delta_chunk=config.delta_chunk,
+                delta_state_bytes=hybrid.delta_state_bytes(config),
+                delta_kept_bytes=hybrid.delta_kept_bytes(config, rows))
     else:
         attrs.update(layers=config.num_blocks, passes=1,
                      rematerialised="nothing", head="whole")
@@ -396,8 +411,9 @@ def _score_fn(config):
                 lambda params, seqs, last: looped.score_last(
                     config, attention, params, seqs, last))
             return _SCORE_CACHE[config]
-        if isinstance(config, SparseMoEConfig):
-            _SCORE_CACHE[config] = jax.jit(functools.partial(sparse_moe.score_last, config))
+        if isinstance(config, (SparseMoEConfig, HybridConfig)):
+            module = sparse_moe if isinstance(config, SparseMoEConfig) else hybrid
+            _SCORE_CACHE[config] = jax.jit(functools.partial(module.score_last, config))
             return _SCORE_CACHE[config]
         model = SASRec(config, None)
 

@@ -561,8 +561,8 @@ def _layer(c: SparseMoEConfig, backend: str, rope, real, x, p, ip, probe=None):
 def _backend_of(mesh) -> str:
     if mesh is not None and mesh.shape.get("seq", 1) > 1:
         raise ValueError(
-            "the sparse_moe backbone selects keys over a whole row: it does not run"
-            " on a mesh whose 'seq' axis is larger than 1")
+            "this backbone works a row whole (its selection, its recurrent state): it"
+            " does not run on a mesh whose 'seq' axis is larger than 1")
     return mesh.devices.flat[0].platform if mesh is not None else jax.default_backend()
 
 

@@ -26,7 +26,9 @@ is what runs off the TPU and what the tests hold the programs to):
   bfloat16 with float32 accumulation; the softmax is float32.
 
 The mask is the whole contract of which pairs count: causality is in it (the
-selection takes causal keys only) and nothing else masks a pair. Rows are
+selection takes causal keys only) and nothing else masks a pair.
+:func:`causal_attention` is the same three programs with no mask operand:
+every causal pair counts, and a tile's mask is made from its positions. Rows are
 left-aligned, so a padded position follows every event of its row and no
 real query can select it; a padded query's output is never read.
 
@@ -272,8 +274,21 @@ def sparse_attention_plain(q, k, v, mask):
     return out.reshape(b, t, h, d).astype(q.dtype)
 
 
-def _tile(mask_ref):
-    return mask_ref[0].astype(jnp.int32) != 0
+def _tile(mask_ref, qi, ki, bq: int, bk: int):
+    """The pairs of tile ``(qi, ki)`` that count: the mask's, or with no mask
+    operand the causal ones."""
+    if mask_ref is not None:
+        return mask_ref[0].astype(jnp.int32) != 0
+    return (ki * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+            <= qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0))
+
+
+def _kernel(kernel, masked, **static):
+    """``kernel`` with its static sizes bound; for a call with no mask operand
+    (``masked`` empty) the mask's place, after q, k and v, holds None."""
+    if masked:
+        return functools.partial(kernel, **static)
+    return lambda q_ref, k_ref, v_ref, *rest: kernel(q_ref, k_ref, v_ref, None, *rest, **static)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref, m_scr, l_scr, acc_scr,
@@ -289,7 +304,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref, m_scr, l_scr, a
 
     @pl.when(ki <= _last_key_block(qi, bq, bk))
     def _():
-        k, v, on = k_ref[0, 0], v_ref[0, 0], _tile(mask_ref)
+        k, v, on = k_ref[0, 0], v_ref[0, 0], _tile(mask_ref, qi, ki, bq, bk)
         for h in range(heads):
             s = jnp.where(on, _dot(q_ref[0, h], k, 1, 1), _NEG)
             m_old = m_scr[h]
@@ -319,7 +334,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref, dq_ref
 
     @pl.when(ki <= _last_key_block(qi, bq, bk))
     def _():
-        k, v, on = k_ref[0, 0], v_ref[0, 0], _tile(mask_ref)
+        k, v, on = k_ref[0, 0], v_ref[0, 0], _tile(mask_ref, qi, ki, bq, bk)
         for h in range(heads):
             s = _dot(q_ref[0, h], k, 1, 1)
             p = jnp.where(on, jnp.exp(s - lse_ref[0, 0, :, h:h + 1]), 0.0)
@@ -343,7 +358,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(qi >= _first_query_block(ki, bq, bk))
     def _():
-        k, v, on = k_ref[0, 0], v_ref[0, 0], _tile(mask_ref)
+        k, v, on = k_ref[0, 0], v_ref[0, 0], _tile(mask_ref, qi, ki, bq, bk)
         dk, dv = dk_scr[...], dv_scr[...]
         for h in range(heads):
             q, do = q_ref[0, h], do_ref[0, h]
@@ -398,11 +413,12 @@ def _forward(q, k, v, mask, block_q, block_k, interpret):
     g = h // kv
     bq, bk = _block(block_q, t), _block(block_k, t)
     sp = _specs(g, d, bq, bk, by_key=False)
+    masked = (mask,) if mask is not None else ()
     scaled = (q.astype(jnp.float32) * d ** -0.5).astype(q.dtype)
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, bq=bq, bk=bk),
+        _kernel(_fwd_kernel, masked, bq=bq, bk=bk),
         grid=(b, kv, t // bq, t // bk),
-        in_specs=[sp["q"], sp["k"], sp["k"], sp["mask"]],
+        in_specs=[sp["q"], sp["k"], sp["k"]] + [sp["mask"]] * len(masked),
         out_specs=[sp["q"], sp["row"]],
         out_shape=[jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
                    jax.ShapeDtypeStruct((b, kv, t, g), jnp.float32)],
@@ -411,7 +427,7 @@ def _forward(q, k, v, mask, block_q, block_k, interpret):
                         pltpu.VMEM((g, bq, d), jnp.float32)],
         compiler_params=_params("parallel", "parallel", "parallel", "arbitrary"),
         interpret=interpret,
-    )(_heads_first(scaled), _heads_first(k), _heads_first(v), mask)
+    )(_heads_first(scaled), _heads_first(k), _heads_first(v), *masked)
     return _heads_first(out), lse
 
 
@@ -433,34 +449,61 @@ def _bwd(block_q, block_k, interpret, res, g_out):
     delta = jnp.transpose(delta.reshape(b, t, kv, g), (0, 2, 1, 3))
     qs = _heads_first((q.astype(jnp.float32) * scale).astype(q.dtype))
     kt, vt, do = _heads_first(k), _heads_first(v), _heads_first(g_out.astype(q.dtype))
+    masked = (mask,) if mask is not None else ()
+    ins = lambda sp: ([sp["q"], sp["k"], sp["k"]] + [sp["mask"]] * len(masked)  # noqa: E731
+                      + [sp["q"], sp["row"], sp["row"]])
 
     sp = _specs(g, d, bq, bk, by_key=False)
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, bq=bq, bk=bk),
+        _kernel(_dq_kernel, masked, bq=bq, bk=bk),
         grid=(b, kv, t // bq, t // bk),
-        in_specs=[sp["q"], sp["k"], sp["k"], sp["mask"], sp["q"], sp["row"], sp["row"]],
+        in_specs=ins(sp),
         out_specs=sp["q"],
         out_shape=jax.ShapeDtypeStruct((b, h, t, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((g, bq, d), jnp.float32)],
         compiler_params=_params("parallel", "parallel", "parallel", "arbitrary"),
         interpret=interpret,
-    )(qs, kt, vt, mask, do, lse, delta)
+    )(qs, kt, vt, *masked, do, lse, delta)
 
     sp = _specs(g, d, bq, bk, by_key=True)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, bq=bq, bk=bk),
+        _kernel(_dkv_kernel, masked, bq=bq, bk=bk),
         grid=(b, kv, t // bk, t // bq),
-        in_specs=[sp["q"], sp["k"], sp["k"], sp["mask"], sp["q"], sp["row"], sp["row"]],
+        in_specs=ins(sp),
         out_specs=[sp["k"], sp["k"]],
         out_shape=[jax.ShapeDtypeStruct((b, kv, t, d), jnp.float32)] * 2,
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32)] * 2,
         compiler_params=_params("parallel", "parallel", "parallel", "arbitrary"),
         interpret=interpret,
-    )(qs, kt, vt, mask, do, lse, delta)
+    )(qs, kt, vt, *masked, do, lse, delta)
 
     # dq was taken against the scaled q; dk already carries the scale
-    return ((_heads_first(dq) * scale).astype(q.dtype), _heads_first(dk).astype(k.dtype),
-            _heads_first(dv).astype(v.dtype), np.zeros(mask.shape, jax.dtypes.float0))
+    grads = ((_heads_first(dq) * scale).astype(q.dtype), _heads_first(dk).astype(k.dtype),
+             _heads_first(dv).astype(v.dtype))
+    return grads + tuple(np.zeros(m.shape, jax.dtypes.float0) for m in masked)
 
 
 sparse_attention.defvjp(_fwd, _bwd)
+
+
+def causal_attention_plain(q, k, v):
+    """:func:`sparse_attention_plain` over every causal pair."""
+    at = jnp.arange(q.shape[1])
+    causal = jnp.broadcast_to(at[None, :] <= at[:, None], (q.shape[0],) + (q.shape[1],) * 2)
+    return sparse_attention_plain(q, k, v, causal)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def causal_attention(q, k, v, block_q=BLOCK_Q, block_k=BLOCK_K, interpret=False):
+    """:func:`sparse_attention` over every causal pair, with no mask operand:
+    q [B, T, H, D], k, v [B, T, KV, D] -> [B, T, H, D]. A padded position
+    follows its row's events, so no real query reads it."""
+    return _forward(q, k, v, None, block_q, block_k, interpret)[0]
+
+
+def _causal_fwd(q, k, v, block_q, block_k, interpret):
+    out, lse = _forward(q, k, v, None, block_q, block_k, interpret)
+    return out, (q, k, v, None, out, lse)
+
+
+causal_attention.defvjp(_causal_fwd, _bwd)

@@ -15,7 +15,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PHASES = ["device", "compile_cache", "ingest", "train_als", "als_full_width",
           "serve_als", "train_serve_ncf", "train_sequence_looped",
-          "train_sequence_sparse_moe"]
+          "train_sequence_sparse_moe", "train_sequence_hybrid_linear"]
 
 
 def _run(args, tmp_path, timeout, **env_overrides):
@@ -59,6 +59,12 @@ def test_rehearsal_reaches_every_phase_then_refuses_the_cpu(tmp_path):
     # mlp's norm; those, the moe's norm and the experts' five
     assert (looped["leaf_scopes"], sparse["leaf_scopes"]) == (6, 11)
     assert sparse["again_in_backward"] > 0
+    hybrid = by_phase["train_sequence_hybrid_linear"]
+    assert hybrid["backbone"] == "hybrid_linear" and hybrid["last_loss"] < hybrid["first_loss"]
+    assert (hybrid["linear_layers"], hybrid["full_layers"], hybrid["experts_shared"]) == (3, 1, 1)
+    assert (hybrid["experts_held"], hybrid["experts_total"], hybrid["moe_dropped"]) == (4, 16, 0)
+    assert hybrid["delta_state_bytes"] == 3 * 4 * 16 * 16 * 4       # layers x heads x dk x dv
+    assert hybrid["delta_kept_bytes"] == 4 * 2 * 4 * 16 * 16 * 4    # rows x chunks x a layer's
 
 
 def test_without_a_chip_the_default_run_stops_at_the_device_phase(tmp_path):

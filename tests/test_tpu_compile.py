@@ -69,6 +69,26 @@ def _kernel_instructions(text: str, name: str) -> list[str]:
     )
 
 
+#: sha256 of ``_without_names`` of the two accepted sequence cells' compiled
+#: steps, as PR 36's tree compiled them for the described v5e (jax 0.9.0,
+#: libtpu 0.0.34). A change that shares code with them (PR 37: the experts'
+#: passes, the attention programs that gained a mode without a mask) leaves
+#: both programs as they were, instruction for instruction; one that means to
+#: change a step re-measures its cell and writes the new digest here
+ACCEPTED_STEPS = {
+    "ouro-2.6b-d8.train-histories":
+        "7849910ae58d05dc248f996c7c3e71238090eae70f3d76ad3486b9b4d5c342ef",
+    "keye-vl2-30b-a3b-ep8.train-lifelong-histories":
+        "e60f828da966cc0e25a6890def91fc059bb85c8b791fbb37e5477956d0559e2e",
+}
+
+
+def _digest(text: str) -> str:
+    import hashlib
+
+    return hashlib.sha256(_without_names(text).encode()).hexdigest()
+
+
 def _without_names(text: str) -> str:
     """The optimised HLO ``text`` with everything that only names things taken
     out, so that two programs compare instruction for instruction: each
@@ -601,8 +621,10 @@ def test_the_sequence_cells_step_fits_the_chip_at_6_layers_and_not_at_8(
     config, lowered = _lowered_looped_step(topo, layers, 32)
     assert looped.head_chunk_of(config) == 2048
     if fits:
-        peak = lowered.compile().memory_analysis().peak_memory_in_bytes
+        compiled = lowered.compile()
+        peak = compiled.memory_analysis().peak_memory_in_bytes
         assert 13.5e9 < peak < 15.0e9, peak    # 14.23 GB
+        assert _digest(compiled.as_text()) == ACCEPTED_STEPS["ouro-2.6b-d8.train-histories"]
     else:
         with pytest.raises(Exception, match=r"RESOURCE_EXHAUSTED(.|\n)*hbm"):
             lowered.compile()
@@ -647,6 +669,7 @@ def test_the_lifelong_histories_cells_step_fits_the_chip_at_6_layers_and_scopes_
     peak = compiled.memory_analysis().peak_memory_in_bytes
     assert 13.5e9 < peak < 15.5e9, peak       # 15.04 GB (14.96 before the selection was kept)
     text = compiled.as_text()
+    assert _digest(text) == ACCEPTED_STEPS["keye-vl2-30b-a3b-ep8.train-lifelong-histories"]
     calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
     stages = [scopes_sparse.parse_stage(c) for c in calls]
     kinds = [scopes_sparse.kernel_kind(c) for c in calls]
@@ -696,3 +719,58 @@ def test_the_lifelong_histories_cells_step_fits_the_chip_at_6_layers_and_scopes_
                          for name in again)
     assert {scopes_leaf.place_of(c).leaf for c in calls if "seq." in c} == {
         "index", "select", "kernel"}
+
+
+def test_the_hybrid_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persistent_cache):
+    """The step of ``qwen3-next-80b-a3b-ep16.train-lifelong-histories`` (2 rows
+    of 8,192 at the published widths, one period of three linear layers and a
+    full one, 32 of 512 experts held, an eighth of the vocabulary): Mosaic takes
+    the delta rule's state pass and its transpose at 128 chunks of 64 and the
+    attention programs with no mask operand at head width 256, the peak is
+    under the chip's 15.75 GB, and every program and every leaf sits under the
+    scope the benchmark's readers look for. A linear mixer's state pass stands
+    forward, recomputed and (its transpose) backward; the full layer's
+    attention forward, recomputed and as ``dq`` and ``dkv``."""
+    import re
+
+    from benchmarks import scopes_hybrid, scopes_seq, scopes_sparse
+    from predictionio_tpu.models.sequence import hybrid, model as seq_model
+
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "seq"))
+    config = hybrid.HybridConfig(
+        num_items=18_991, max_len=8192, hidden_size=2048, num_layers=4,
+        full_attention_interval=4, linear_key_heads=16, linear_value_heads=32,
+        linear_key_dim=128, linear_value_dim=128, conv_kernel=4, num_heads=16,
+        num_kv_heads=2, head_dim=256, rotary_fraction=0.25, expert_dim=512, num_experts=512,
+        experts_per_token=10, experts_held=(0, 32), shared_expert_dim=512, batch_size=2)
+    assert hybrid.count_params(config) == 625_667_136
+    _, _, step_fn, seq_shard = seq_model.make_fit(config, mesh)
+    rep = NamedSharding(mesh, P())
+    sds = lambda shape, dtype, sh: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)  # noqa: E731
+    params = jax.tree_util.tree_map(
+        lambda shape: sds(shape, jnp.float32, rep), hybrid.param_shapes(config),
+        is_leaf=lambda x: isinstance(x, tuple))
+    opt_state = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype, rep),
+        jax.eval_shape(seq_model.optimizer_of(config).init, params))
+    batch = {k: sds((2, 8192), jnp.int32, seq_shard) for k in ("seq", "target")}
+    compiled = step_fn.lower(params, opt_state, batch, sds((2,), jnp.uint32, rep)).compile()
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert 14.0e9 < peak < 15.6e9, peak       # 15.25 GB
+    text = compiled.as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
+    rule = [c for c in calls if scopes_hybrid.place_of(c) == ("linear", "delta")]
+    phases = lambda names: sorted(  # noqa: E731
+        "recomputed" if "rematted_computation" in c else
+        "backward" if "transpose(" in c else "forward" for c in names)
+    assert phases(rule) == ["backward", "forward", "recomputed"]
+    kinds = [scopes_seq.kernel_kind(c) for c in calls]
+    assert kinds.count("forward") == 2 and kinds.count("backward") == 2
+    assert not [c for c in calls if scopes_sparse.parse_stage(c) in ("index", "select")]
+    # the held experts: the sparse backbone's passes, XLA's own ragged dot
+    assert re.search(r"%ragged-dot-none(?:\.\d+)? = [^\n]*tpu_custom_call", text)
+    places = {scopes_hybrid.place_of(name) for name in re.findall(r'op_name="([^"]*)"', text)}
+    assert {leaf for kind, leaf in places - {None} if kind == "linear"} >= set(
+        scopes_hybrid.LEAVES)
+    assert ("shared", None) in places
+
