@@ -57,7 +57,7 @@ SPARSE_FIT_FACTS = ("experts_total", "experts_held", "experts_per_token", "index
                     "causal_pairs", "selection_kept_bytes")
 #: and the hybrid backbone's: its layers by kind and what the delta rule carries
 HYBRID_FIT_FACTS = ("experts_shared", "linear_layers", "full_layers", "delta_chunk",
-                    "delta_state_bytes", "delta_kept_bytes")
+                    "delta_heads_per_step", "delta_state_bytes", "delta_kept_bytes")
 #: the leaf scopes a compiled sequence step has to carry under each stage
 #: (``jax.named_scope``; the strings are ``looped``'s and ``sparse_moe``'s), and
 #: of them those whose backward pass is work of its own
@@ -703,10 +703,11 @@ class Smoke:
         full-attention layer, 32 of 512 experts held beside the shared one; a
         rehearsal cuts the widths): a few steps on one batch of users whose
         histories are many chunks long. The ``seq_fit:`` line has to name the
-        backbone, its layers by kind, the chunk the rule is worked in, the
-        states a row carries and a layer's backward pass holds, the held
-        experts and no dropped token: a silent fall to another backbone, to the
-        whole layer or to a rule without its state would show."""
+        backbone, its layers by kind, the chunk the rule is worked in and the
+        row-heads a grid step of its state pass takes, the states a row carries
+        and a layer's backward pass holds, the held experts and no dropped
+        token: a silent fall to another backbone, to the whole layer or to a
+        rule without its state would show."""
         import numpy as np
 
         t0 = time.time()
@@ -759,6 +760,12 @@ class Smoke:
         if (facts.get("delta_state_bytes") != 3 * state
                 or facts.get("delta_kept_bytes") != algorithm["batchSize"] * chunks * state):
             raise PhaseFailed(f"train_sequence_hybrid_linear: the rule's states: {facts}")
+        # a grid step of the state pass: the most of 8, 4, 2, 1 that divide the
+        # batch's row-heads (at these widths eight heads' blocks are within the budget)
+        row_heads = algorithm["batchSize"] * widths["linearValueHeads"]
+        if facts.get("delta_heads_per_step") != next(g for g in (8, 4, 2, 1) if row_heads % g == 0):
+            raise PhaseFailed(f"train_sequence_hybrid_linear: {row_heads} row-heads, and a grid"
+                              f" step of the state pass takes {facts.get('delta_heads_per_step')}")
         first, last = facts["first_loss"], facts["last_loss"]
         if not (first == first and last == last and last < first < float("inf")):
             raise PhaseFailed(f"train_sequence_hybrid_linear: loss not finite and falling: {first} -> {last}")

@@ -55,9 +55,12 @@ mathematics with none of this):
 - the delta rule is worked ``delta_chunk`` positions at a time
   (``ops/delta_rule.gated_delta_rule``): the triangular system and the
   products inside the chunks for all chunks at once, the state by a pass over
-  the chunks, on a TPU two Pallas programs (the pass and its transpose). While
-  a layer's backward pass runs, the state every chunk starts from is held
-  (``delta_kept_bytes``); between the passes nothing of the rule is;
+  the chunks, on a TPU two Pallas programs (the pass and its transpose), a
+  grid step of which works one chunk of a block of row-heads, a row's value
+  heads and the batch's rows alike (``delta_heads_per_step``: 8 where 8 divide
+  ``B x HV``, from the shapes alone). While a layer's backward pass runs, the
+  state every chunk starts from is held (``delta_kept_bytes``); between the
+  passes nothing of the rule is;
 - the full layer's attention is ``ops/sparse_attention.causal_attention``: the
   sparse backbone's three programs (8 query heads a key-value head, K and V
   streamed) with no mask operand; off the TPU its plain twin;
@@ -275,6 +278,14 @@ def delta_kept_bytes(c: HybridConfig, rows: int) -> int:
     chunks = -(-c.max_len // c.delta_chunk)
     layers = 1 if c.remat else c.linear_layers
     return rows * chunks * layers * delta_state_bytes(c) // c.linear_layers
+
+
+def delta_heads_per_step(c: HybridConfig, rows: int) -> int:
+    """The row-heads a grid step of the rule's state pass works on ``rows``
+    rows (``ops/delta_rule.heads_per_step``, from the shapes alone)."""
+    return delta_rule.heads_per_step(
+        rows * c.linear_value_heads, c.delta_chunk, c.linear_key_dim, c.linear_value_dim,
+        jnp.dtype(c.compute_dtype).itemsize)
 
 
 def _norm0(x, weight, eps):

@@ -352,7 +352,8 @@ def train_sasrec(
 #: the span's attributes the ``seq_fit:`` line repeats (a backbone that has them)
 _FIT_LINE_ATTRS = ("experts_total", "experts_held", "experts_per_token", "experts_shared",
                    "index_topk", "kv_heads", "selection_kept_bytes", "linear_layers",
-                   "full_layers", "delta_chunk", "delta_state_bytes", "delta_kept_bytes")
+                   "full_layers", "delta_chunk", "delta_heads_per_step", "delta_state_bytes",
+                   "delta_kept_bytes")
 _BACKBONES = {LoopedConfig: "looped", SparseMoEConfig: "sparse_moe",
               HybridConfig: "hybrid_linear"}
 
@@ -390,6 +391,7 @@ def fit_attrs(config, param_bytes: int, opt_state_bytes: int, rows: int) -> dict
             attrs.update(
                 experts_shared=1, linear_layers=config.linear_layers,
                 full_layers=config.periods, delta_chunk=config.delta_chunk,
+                delta_heads_per_step=hybrid.delta_heads_per_step(config, rows),
                 delta_state_bytes=hybrid.delta_state_bytes(config),
                 delta_kept_bytes=hybrid.delta_kept_bytes(config, rows))
     else:

@@ -23,10 +23,22 @@ chunk starts from:
 Everything but the third and fifth line's ``S`` is worked for all chunks at
 once, as batched matmuls. What walks a row is the **state pass**: ``V'`` and
 the state each chunk starts from, chunk after chunk. On a TPU it is a Pallas
-program (:func:`state_pass`; grid ``(row-heads, chunks)``, the state in VMEM
-scratch across a row-head's chunks) with a second program for its transpose,
-which walks the chunks backwards from the kept starting states; elsewhere
+program (:func:`state_pass`) with a second program for its transpose, which
+walks the chunks backwards from the kept starting states; elsewhere
 :func:`state_pass_plain`, a ``lax.scan`` that JAX differentiates itself.
+
+A grid step of either program works one chunk of a **block of row-heads**:
+grid ``(row-heads / G, chunks)``, the ``G`` states (or carried cotangents) in
+VMEM scratch across the block's chunks. A TensorCore runs its grid steps one
+after another, and a row-head's chunk is a chain (``V'`` needs ``W S``, the
+next state needs ``V'``; the transpose is four products in a chain of three),
+so a step of one row-head leaves the matrix units waiting on a single chain
+and pays a step's fixed cost for two small products. The ``G`` row-heads of a
+block are independent: the body is a static loop over them, each head's dots,
+dtypes and order of chunks what they are alone, for the scheduler to overlap.
+``G`` is 8, 4, 2 or 1 by :func:`heads_per_step`, from the shapes alone: the
+most that divide the row-heads and keep the transpose program's blocks,
+double-buffered, within ``PASS_VMEM_BYTES``.
 
 Matmul inputs are ``dtype`` (bfloat16) with float32 accumulation; ``g``, its
 sums and exponentials, ``beta``, ``L``, ``T`` and the state are float32. Every
@@ -113,41 +125,68 @@ def _dot(a, b, contract_a: int, contract_b: int):
 
 
 def _pass_kernel(w_ref, u_ref, kd_ref, decay_ref, v_ref, start_ref, state):
+    """One chunk of a block of row-heads, each an independent chain."""
     @pl.when(pl.program_id(1) == 0)
     def _():
         state[...] = jnp.zeros(state.shape, jnp.float32)
 
-    w, kd, s = w_ref[0, 0], kd_ref[0, 0], state[...]
-    low = s.astype(w.dtype)
-    start_ref[0, 0] = low
-    v_new = (u_ref[0, 0] - _dot(w, low, 1, 0)).astype(w.dtype)
-    v_ref[0, 0] = v_new
-    state[...] = decay_ref[0, 0] * s + _dot(kd, v_new, 0, 0)
+    for h in range(w_ref.shape[0]):
+        w, kd, s = w_ref[h, 0], kd_ref[h, 0], state[h]
+        low = s.astype(w.dtype)
+        start_ref[h, 0] = low
+        v_new = (u_ref[h, 0] - _dot(w, low, 1, 0)).astype(w.dtype)
+        v_ref[h, 0] = v_new
+        state[h] = decay_ref[h, 0] * s + _dot(kd, v_new, 0, 0)
 
 
 def _pass_bwd_kernel(w_ref, kd_ref, decay_ref, v_ref, start_ref, dv_ref, dstart_ref,
                      dw_ref, du_ref, dkd_ref, ddecay_ref, carried):
     """The transpose, chunks last to first; ``carried`` is the cotangent of
-    the state a chunk leaves."""
+    the state a chunk leaves, a row-head of the block each."""
     @pl.when(pl.program_id(1) == 0)
     def _():
         carried[...] = jnp.zeros(carried.shape, jnp.float32)
 
-    w, kd, s, d_after = w_ref[0, 0], kd_ref[0, 0], start_ref[0, 0], carried[...]
-    low = w.dtype
-    d_v = dv_ref[0, 0].astype(jnp.float32) + _dot(kd, d_after.astype(low), 1, 0)  # of V'_c, in all
-    du_ref[0, 0] = d_v
-    dkd_ref[0, 0] = _dot(v_ref[0, 0], d_after.astype(low), 1, 1).astype(low)
-    dw_ref[0, 0] = (-_dot(d_v.astype(low), s, 1, 1)).astype(low)
-    ddecay_ref[0, 0] = jnp.full(ddecay_ref.shape[2:], jnp.sum(d_after * s), jnp.float32)
-    carried[...] = (dstart_ref[0, 0].astype(jnp.float32) + decay_ref[0, 0] * d_after
-                    - _dot(w, d_v.astype(low), 0, 0))
+    for h in range(w_ref.shape[0]):
+        w, kd, s, d_after = w_ref[h, 0], kd_ref[h, 0], start_ref[h, 0], carried[h]
+        low = w.dtype
+        d_v = dv_ref[h, 0].astype(jnp.float32) + _dot(kd, d_after.astype(low), 1, 0)  # of V'_c, in all
+        du_ref[h, 0] = d_v
+        dkd_ref[h, 0] = _dot(v_ref[h, 0], d_after.astype(low), 1, 1).astype(low)
+        dw_ref[h, 0] = (-_dot(d_v.astype(low), s, 1, 1)).astype(low)
+        ddecay_ref[h, 0] = jnp.full(ddecay_ref.shape[2:], jnp.sum(d_after * s), jnp.float32)
+        carried[h] = (dstart_ref[h, 0].astype(jnp.float32) + decay_ref[h, 0] * d_after
+                      - _dot(w, d_v.astype(low), 0, 0))
 
 
-def _pass_specs(chunks: int, c: int, dk: int, dv: int, backwards: bool):
+#: what the transpose program's blocks, double-buffered, may take of VMEM
+PASS_VMEM_BYTES = 4 * 2 ** 20
+
+
+def heads_per_step(rows: int, c: int, dk: int, dv: int, itemsize: int) -> int:
+    """Row-heads a grid step works: 8, 4, 2 or 1, the most that divide the
+    ``rows`` row-heads and keep the transpose program's blocks (the larger of
+    the two programs': ``w``, ``kd``, ``dw``, ``dkd``; ``V'``, its cotangent
+    and ``dU`` in float32; the starting state and its cotangent; two scalars'
+    lanes), double-buffered, within ``PASS_VMEM_BYTES``."""
+    a_head = (4 * c * dk + 2 * c * dv + 2 * dk * dv) * itemsize + (c * dv + 2 * dv) * 4
+    for heads in (8, 4, 2):
+        if rows % heads == 0 and 2 * heads * a_head <= PASS_VMEM_BYTES:
+            return heads
+    return 1
+
+
+def _pass_specs(w, dv: int, backwards: bool):
+    """``(specs, grid, scratch)`` of either program for ``w`` ``[R, N, C, dk]``:
+    blocks of :func:`heads_per_step` row-heads and one chunk, the chunks in
+    order or last to first, a float32 ``[dk, dv]`` of scratch a head."""
+    rows, chunks, c, dk = w.shape
+    heads = heads_per_step(rows, c, dk, dv, w.dtype.itemsize)
     at = (lambda r, n: (r, chunks - 1 - n, 0, 0)) if backwards else (lambda r, n: (r, n, 0, 0))
-    return {"k": pl.BlockSpec((1, 1, c, dk), at), "v": pl.BlockSpec((1, 1, c, dv), at),
-            "state": pl.BlockSpec((1, 1, dk, dv), at), "scalar": pl.BlockSpec((1, 1, 1, dv), at)}
+    specs = {"k": pl.BlockSpec((heads, 1, c, dk), at), "v": pl.BlockSpec((heads, 1, c, dv), at),
+             "state": pl.BlockSpec((heads, 1, dk, dv), at),
+             "scalar": pl.BlockSpec((heads, 1, 1, dv), at)}
+    return specs, (rows // heads, chunks), [pltpu.VMEM((heads, dk, dv), jnp.float32)]
 
 
 _PASS_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
@@ -165,17 +204,17 @@ def _lanes(decay, dv: int):
 
 
 def _pass_fwd(w, u, kd, decay, interpret):
-    rows, chunks, c, dk = w.shape
+    rows, chunks, _, dk = w.shape
     dv = u.shape[-1]
-    sp = _pass_specs(chunks, c, dk, dv, backwards=False)
+    sp, grid, scratch = _pass_specs(w, dv, backwards=False)
     v_new, starts = pl.pallas_call(
         _pass_kernel,
-        grid=(rows, chunks),
+        grid=grid,
         in_specs=[sp["k"], sp["v"], sp["k"], sp["scalar"]],
         out_specs=[sp["v"], sp["state"]],
         out_shape=[jax.ShapeDtypeStruct(u.shape, w.dtype),
                    jax.ShapeDtypeStruct((rows, chunks, dk, dv), w.dtype)],
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        scratch_shapes=scratch,
         compiler_params=_PASS_PARAMS,
         interpret=interpret,
     )(w, u.astype(jnp.float32), kd, _lanes(decay.astype(jnp.float32), dv))
@@ -185,19 +224,19 @@ def _pass_fwd(w, u, kd, decay, interpret):
 def _pass_bwd(interpret, res, cotangents):
     w, kd, decay, v_new, starts = res
     d_v, d_starts = cotangents
-    rows, chunks, c, dk = w.shape
+    rows, chunks = w.shape[:2]
     dv = v_new.shape[-1]
-    sp = _pass_specs(chunks, c, dk, dv, backwards=True)
+    sp, grid, scratch = _pass_specs(w, dv, backwards=True)
     d_w, d_u, d_kd, d_decay = pl.pallas_call(
         _pass_bwd_kernel,
-        grid=(rows, chunks),
+        grid=grid,
         in_specs=[sp["k"], sp["k"], sp["scalar"], sp["v"], sp["state"], sp["v"], sp["state"]],
         out_specs=[sp["k"], sp["v"], sp["k"], sp["scalar"]],
         out_shape=[jax.ShapeDtypeStruct(w.shape, w.dtype),
                    jax.ShapeDtypeStruct(v_new.shape, jnp.float32),
                    jax.ShapeDtypeStruct(kd.shape, kd.dtype),
                    jax.ShapeDtypeStruct((rows, chunks, 1, dv), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        scratch_shapes=scratch,
         compiler_params=_PASS_PARAMS,
         interpret=interpret,
     )(w, kd, _lanes(decay.astype(jnp.float32), dv), v_new, starts, d_v, d_starts)

@@ -174,9 +174,10 @@ def test_remat_and_chunks_change_nothing(params, batch, plain_step, case):
 
 # ---- the delta rule ----------------------------------------------------------
 
-def _rule_inputs(t: int, seed: int = 0):
+def _rule_inputs(t: int, seed: int = 0, heads=(2, 2, 4)):
+    """``heads``: rows, key heads and value heads; 2 x 4 row-heads unless said."""
     rng = np.random.default_rng(seed)
-    b, hk, hv, dk, dv = 2, 2, 4, 16, 32
+    (b, hk, hv), dk, dv = heads, 16, 32
     draw = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)  # noqa: E731
     q = ref.l2_normalised(draw(b, t, hk, dk)) / 4.0
     k = ref.l2_normalised(draw(b, t, hk, dk))
@@ -192,12 +193,23 @@ def _token_by_token(q, k, v, g, beta):
                       for b in range(q.shape[0])])
 
 
-@pytest.mark.parametrize("kernels", [False, True], ids=["scan", "programs"])
-@pytest.mark.parametrize("chunk", [16, 32, 64])
-def test_the_chunked_rule_matches_the_recurrence_forward_and_gradient(chunk, kernels):
+#: rows, key heads and value heads whose row-heads pick each block of the state
+#: pass's programs: 8, 4, 2 and 1 row-heads a grid step
+BLOCKS = {8: (2, 2, 4), 4: (1, 2, 4), 2: (1, 2, 6), 1: (1, 1, 3)}
+RULE_CASES = ([pytest.param(chunk, kernels, 8, id=f"{how}-{chunk}")
+               for chunk in (16, 32, 64) for kernels, how in ((False, "scan"), (True, "programs"))]
+              + [pytest.param(32, True, g, id=f"programs-32-block-of-{g}") for g in (4, 2, 1)])
+
+
+@pytest.mark.parametrize("chunk,kernels,block", RULE_CASES)
+def test_the_chunked_rule_matches_the_recurrence_forward_and_gradient(chunk, kernels, block):
     """A length of 100 is no multiple of any of the chunks: the tail is padded
-    with positions that neither move nor read the state."""
-    inputs = _rule_inputs(100)
+    with positions that neither move nor read the state. The programs work 8
+    row-heads a grid step at 2 rows of 4 value heads, and 4, 2 and 1 at the
+    row-heads that 8, then 4, then 2 do not divide."""
+    b, _, hv = BLOCKS[block]
+    assert delta_rule.heads_per_step(b * hv, chunk, 16, 32, 4) == block
+    inputs = _rule_inputs(100, heads=BLOCKS[block])
     with jax.default_matmul_precision("highest"):
         want = _token_by_token(*inputs)
         weight = jnp.asarray(np.random.default_rng(1).standard_normal(want.shape), jnp.float32)
@@ -207,10 +219,73 @@ def test_the_chunked_rule_matches_the_recurrence_forward_and_gradient(chunk, ker
             *a, chunk=chunk, dtype=jnp.float32, kernels=kernels, interpret=True)
         have = rule(*inputs)
         grads = jax.grad(lambda *a: (rule(*a) * weight).sum(), argnums=(0, 1, 2, 3, 4))(*inputs)
-    assert have.shape == want.shape == (2, 100, 4, 32)
+    assert have.shape == want.shape == (b, 100, hv, 32)
     assert np.abs(np.asarray(have - want)).max() < 1e-5
     for name, a, b in zip("qkvgb", grads, want_grads):
         assert np.abs(np.asarray(a - b)).max() < 1e-4 * np.abs(np.asarray(b)).max(), name
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rows,block", [(8, 8), (4, 4), (6, 2), (3, 1)])
+def test_the_state_pass_programs_match_the_scan_forward_and_every_gradient(rows, block, dtype):
+    """The two Pallas programs, interpreted, against ``state_pass_plain`` and
+    what JAX differentiates of it, at row-heads that pick each block. A head's
+    arithmetic is the same in every block: with bfloat16 inputs, as the cell
+    has them, the programs' results are the scan's to the last bit."""
+    dtype = jnp.dtype(dtype)
+    chunks, c, dk, dv = 5, 16, 16, 32
+    assert delta_rule.heads_per_step(rows, c, dk, dv, dtype.itemsize) == block
+    rng = np.random.default_rng(rows)
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape) * 0.3, jnp.float32)  # noqa: E731
+    w, kd = draw(rows, chunks, c, dk).astype(dtype), draw(rows, chunks, c, dk).astype(dtype)
+    u = draw(rows, chunks, c, dv)
+    decay = jnp.asarray(0.5 + 0.5 * rng.random((rows, chunks)), jnp.float32)
+    weights = draw(rows, chunks, c, dv), draw(rows, chunks, dk, dv)
+
+    def loss(pass_fn):
+        def of(w, u, kd, decay):
+            v_new, starts = pass_fn(w, u, kd, decay)
+            return ((v_new * weights[0]).sum() + (starts * weights[1]).sum(),
+                    (v_new, starts))
+        return jax.value_and_grad(of, argnums=(0, 1, 2, 3), has_aux=True)
+
+    with jax.default_matmul_precision("highest"):
+        (_, have), grads = loss(lambda *a: delta_rule.state_pass(*a, True))(w, u, kd, decay)
+        (_, want), want_grads = loss(delta_rule.state_pass_plain)(w, u, kd, decay)
+    for name, a, b in zip(("v_new", "starts"), have, want):
+        assert a.shape == b.shape and a.dtype == b.dtype == dtype, name
+        a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))
+        if dtype == jnp.bfloat16:
+            assert np.array_equal(a, b), name
+        else:
+            assert np.abs(a - b).max() < 1e-5, name
+    for name, a, b in zip(("w", "u", "kd", "decay"), grads, want_grads):
+        a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))
+        assert a.shape == b.shape and np.abs(b).max() > 0, name
+        assert np.abs(a - b).max() < (3e-2 if dtype == jnp.bfloat16 else 1e-4) * np.abs(b).max(), name
+
+
+def test_a_grid_step_takes_the_row_heads_that_divide_and_fit():
+    """The chooser alone: the most of 8, 4, 2, 1 that divide the row-heads, and
+    of those the most whose blocks of the transpose program, double-buffered,
+    stay within the module's budget."""
+    cell = (64, 128, 128, 2)                      # chunk, dk, dv, bfloat16
+    a_head = (4 * 64 * 128 + 2 * 64 * 128 + 2 * 128 * 128) * 2 + (64 * 128 + 2 * 128) * 4
+    assert a_head == 197_632 and 2 * 8 * a_head <= delta_rule.PASS_VMEM_BYTES < 2 * 16 * a_head
+    assert delta_rule.heads_per_step(2 * 32, *cell) == 8     # the cell: 2 rows of 32 value heads
+    assert [delta_rule.heads_per_step(rows, *cell) for rows in (128, 12, 6, 3, 1, 7)] == [
+        8, 4, 2, 1, 1, 1]
+    # float32 inputs nearly double a head's blocks, a key and value width of
+    # 256 more than triples them, one of 512 takes eight times as much: the
+    # budget refuses 8, then 4, then 2
+    assert delta_rule.heads_per_step(64, 64, 128, 128, 4) == 4
+    assert delta_rule.heads_per_step(64, 64, 256, 256, 2) == 2
+    assert delta_rule.heads_per_step(64, 64, 512, 512, 2) == 1
+    config = _config(max_len=8192, linear_value_heads=32, linear_key_dim=128,
+                     linear_value_dim=128, compute_dtype="bfloat16", delta_chunk=64)
+    assert hybrid.delta_heads_per_step(config, 2) == 8
+    assert fit_attrs(config, 4, 8, 2)["delta_heads_per_step"] == 8
+    assert fit_attrs(_config(), 4, 8, 3)["delta_heads_per_step"] == 4     # 3 rows x 4 heads
 
 
 def test_a_position_without_beta_or_decay_leaves_the_state():
@@ -317,8 +392,9 @@ def test_the_engine_takes_the_backbone_at_the_cells_sizes():
     assert hybrid.delta_state_bytes(config) == 3 * 32 * 128 * 128 * 4
     assert hybrid.delta_kept_bytes(config, 2) == 2 * 128 * 32 * 128 * 128 * 4
     attrs = fit_attrs(config, 4, 8, 2)
-    assert (attrs["backbone"], attrs["linear_layers"], attrs["full_layers"],
-            attrs["delta_chunk"], attrs["experts_shared"]) == ("hybrid_linear", 3, 1, 64, 1)
+    assert (attrs["backbone"], attrs["linear_layers"], attrs["full_layers"], attrs["delta_chunk"],
+            attrs["delta_heads_per_step"], attrs["experts_shared"]) == (
+                "hybrid_linear", 3, 1, 64, 8, 1)
     whole = SASRecAlgorithm(Params({"backbone": "hybrid_linear", "numExperts": 16}))._config(12, 64)
     assert whole.experts_held == (0, 16)
     with pytest.raises(ValueError, match="'sparse_moe', 'hybrid_linear'"):
@@ -370,13 +446,14 @@ def test_the_backbone_learns_a_cycle_and_reports_its_fit(caplog):
     assert attrs["backbone"] == "hybrid_linear" and attrs["passes"] == 1
     assert (attrs["linear_layers"], attrs["full_layers"], attrs["delta_chunk"],
             attrs["experts_shared"], attrs["experts_held"]) == (1, 1, 4, 1, 4)
+    assert attrs["delta_heads_per_step"] == 8                      # 32 rows x 2 value heads
     assert attrs["delta_state_bytes"] == 2 * 8 * 8 * 4
     assert attrs["delta_kept_bytes"] == 32 * 2 * 2 * 8 * 8 * 4     # rows x chunks x a state
     assert attrs["moe_dropped"] == 0 and attrs["moe_held_assignments"] == attrs["moe_assignments"]
     line = next(r.getMessage() for r in caplog.records if "seq_fit:" in r.getMessage())
     for word in ("backbone=hybrid_linear", "linear_layers=1", "full_layers=1", "delta_chunk=4",
-                 "delta_state_bytes=512", "delta_kept_bytes=", "experts_shared=1",
-                 "moe_dropped=0"):
+                 "delta_heads_per_step=8", "delta_state_bytes=512", "delta_kept_bytes=",
+                 "experts_shared=1", "moe_dropped=0"):
         assert word in line, (word, line)
 
 

@@ -725,7 +725,8 @@ def test_the_hybrid_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
     """The step of ``qwen3-next-80b-a3b-ep16.train-lifelong-histories`` (2 rows
     of 8,192 at the published widths, one period of three linear layers and a
     full one, 32 of 512 experts held, an eighth of the vocabulary): Mosaic takes
-    the delta rule's state pass and its transpose at 128 chunks of 64 and the
+    the delta rule's state pass and its transpose at 128 chunks of 64, blocks
+    of 8 of the 64 row-heads a grid step (a grid of 8 x 128), and the
     attention programs with no mask operand at head width 256, the peak is
     under the chip's 15.75 GB, and every program and every leaf sits under the
     scope the benchmark's readers look for. A linear mixer's state pass stands
@@ -735,6 +736,7 @@ def test_the_hybrid_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
 
     from benchmarks import scopes_hybrid, scopes_seq, scopes_sparse
     from predictionio_tpu.models.sequence import hybrid, model as seq_model
+    from predictionio_tpu.ops import delta_rule
 
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "seq"))
     config = hybrid.HybridConfig(
@@ -744,9 +746,21 @@ def test_the_hybrid_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
         num_kv_heads=2, head_dim=256, rotary_fraction=0.25, expert_dim=512, num_experts=512,
         experts_per_token=10, experts_held=(0, 32), shared_expert_dim=512, batch_size=2)
     assert hybrid.count_params(config) == 625_667_136
+    assert hybrid.delta_heads_per_step(config, 2) == 8
     _, _, step_fn, seq_shard = seq_model.make_fit(config, mesh)
     rep = NamedSharding(mesh, P())
     sds = lambda shape, dtype, sh: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)  # noqa: E731
+    # the two programs as the step traces them at these shapes: 1,024 grid steps each
+    low = jax.ShapeDtypeStruct((64, 128, 64, 128), jnp.bfloat16)
+    state, scalar = (jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in (
+        ((64, 128, 128, 128), jnp.bfloat16), ((64, 128), jnp.float32)))
+    for traced in (
+            jax.make_jaxpr(lambda *a: delta_rule._pass_fwd(*a, False))(low, low, low, scalar),
+            jax.make_jaxpr(lambda *a: delta_rule._pass_bwd(False, a[:5], a[5:]))(
+                low, low, scalar, low, state, low, state)):
+        grids = [eqn.params["grid_mapping"].grid for eqn in traced.jaxpr.eqns
+                 if eqn.primitive.name == "pallas_call"]
+        assert grids == [(8, 128)], grids
     params = jax.tree_util.tree_map(
         lambda shape: sds(shape, jnp.float32, rep), hybrid.param_shapes(config),
         is_leaf=lambda x: isinstance(x, tuple))
