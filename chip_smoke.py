@@ -58,6 +58,11 @@ SPARSE_FIT_FACTS = ("experts_total", "experts_held", "experts_per_token", "index
 #: and the hybrid backbone's: its layers by kind and what the delta rule carries
 HYBRID_FIT_FACTS = ("experts_shared", "linear_layers", "full_layers", "delta_chunk",
                     "delta_heads_per_step", "delta_state_bytes", "delta_kept_bytes")
+#: and the latent backbone's: its stack, its latents and what the step moved
+#: that no gradient trains
+LATENT_FIT_FACTS = ("dense_layers", "mtp_depth", "latent_q_rank", "latent_kv_rank",
+                    "score_width", "value_width", "latent_bytes_per_token",
+                    "router_bias_leaves", "router_bias_abs_max", "mtp_ce", "balance")
 #: the leaf scopes a compiled sequence step has to carry under each stage
 #: (``jax.named_scope``; the strings are ``looped``'s and ``sparse_moe``'s), and
 #: of them those whose backward pass is work of its own
@@ -65,7 +70,13 @@ LAYER_LEAVES = {"attention": ("norm", "qkv", "rope", "kernel", "out")}
 LOOPED_LEAVES = {**LAYER_LEAVES, "mlp": ("norm",)}
 SPARSE_LEAVES = {**LAYER_LEAVES, "moe": ("norm",),
                  "experts": ("sort", "take", "grouped", "give", "sum")}
-BACKWARD_LEAVES = {"norm", "qkv", "rope", "kernel", "out", "grouped", "give", "sum"}
+#: the latent backbone's: the two latent paths inside ``qkv``, the dense layer's
+#: MLP, the shared expert, and all of a layer again under the prediction module
+LATENT_LEAVES = {**SPARSE_LEAVES, "qkv": ("q_latent", "kv_latent"), "mlp": ("norm",),
+                 "moe": ("norm", "shared"),
+                 "mtp": ("merge", "q_latent", "kv_latent", "kernel", "shared", "exit")}
+BACKWARD_LEAVES = {"norm", "qkv", "rope", "kernel", "out", "grouped", "give", "sum",
+                   "q_latent", "kv_latent", "shared", "merge", "exit"}
 
 
 # ---------------------------------------------------------------------------
@@ -326,7 +337,8 @@ class Smoke:
                          first_loss=float(said["first_loss"]),
                          last_loss=float(said["last_loss"]))
             # what the sparse backbone adds to the line: its share and its counts
-            facts.update({k: float(said[k]) for k in SPARSE_FIT_FACTS + HYBRID_FIT_FACTS
+            facts.update({k: float(said[k])
+                          for k in SPARSE_FIT_FACTS + HYBRID_FIT_FACTS + LATENT_FIT_FACTS
                           if k in said})
         timings = re.search(r"stage timings: (.*)$", text, re.M)
         if timings:
@@ -772,6 +784,89 @@ class Smoke:
         self.line("train_sequence_hybrid_linear", t0, **facts, users=4, events=int(users.size),
                   max_len=max_len, **widths)
 
+    def phase_train_sequence_latent_moe(self) -> None:
+        """The sequence template's latent backbone through ``pio train`` at the
+        published widths (32 heads that score over 192 and carry 128 through
+        latents of 1,536 and 512, a dense layer of 7,168, then an expert layer
+        with 8 of 256 experts held beside the shared one, and the prediction
+        module: 225.7 M parameters, 0.90 GB, under the 1e9 bytes sqlite takes
+        in a BLOB, which is the model store's limit; with 16 held the fit ran
+        and the store refused 1.2 GB; a rehearsal cuts the widths): a few steps on one
+        batch of users whose histories are several key blocks long. The
+        ``seq_fit:`` line has to name the backbone, its dense layers and its
+        module, the latents' ranks and the two head widths, the held experts,
+        no dropped token, a second cross-entropy and a bias that the steps
+        moved by no more than ``biasUpdateRate`` each: a silent fall to another
+        backbone, to attention with one width, to a stack without its module or
+        to a bias that Adam trains would show."""
+        import numpy as np
+
+        t0 = time.time()
+        out = pio("app_new_latent", ["app", "new", "SmokeLatentApp"], self.env, 120)
+        app_id = int(re.search(r"ID: (\d+)", out).group(1))
+        rng = np.random.default_rng(SEED + 3)
+        max_len = 128 if self.rehearsal else 2048
+        lengths = rng.integers(max_len, max_len + 64, size=4)
+        users = np.repeat(np.arange(4), lengths)
+        items = (np.minimum(rng.random(users.size) ** 2.2, 0.999999) * 2_000).astype(np.int64)
+        events = os.path.join(self.basedir, "latent_events.jsonl")
+        write_events(events, users, items, np.ones(users.size, np.float32))
+        pio("import_latent", ["import", "--appid", str(app_id), "--input", events], self.env, 300)
+        os.unlink(events)
+        widths = ({"hiddenSize": 64, "numHeads": 4, "qLoraRank": 48, "kvLoraRank": 32,
+                   "qkNopeHeadDim": 16, "qkRopeHeadDim": 8, "vHeadDim": 16, "ffnDim": 128,
+                   "expertDim": 32, "numExperts": 16, "expertsPerToken": 4,
+                   "expertsHeld": [0, 4], "sharedExpertDim": 32}
+                  if self.rehearsal else
+                  {"hiddenSize": 2048, "numHeads": 32, "qLoraRank": 1536, "kvLoraRank": 512,
+                   "qkNopeHeadDim": 128, "qkRopeHeadDim": 64, "vHeadDim": 128, "ffnDim": 7168,
+                   "expertDim": 768, "numExperts": 256, "expertsPerToken": 8,
+                   "expertsHeld": [0, 8], "sharedExpertDim": 768})
+        rate, steps = 1e-3, 6
+        algorithm = dict(backbone="latent_moe", numLayers=2, denseLayers=1, mtpDepth=1,
+                         biasUpdateRate=rate, batchSize=4, epochs=steps, learningRate=3e-4,
+                         **widths)
+
+        def edit(v):
+            v["datasource"]["params"]["appName"] = "SmokeLatentApp"
+            v["preparator"]["params"]["maxLen"] = max_len
+            v["algorithms"][0]["params"].update(algorithm)
+            v["sparkConf"] = {"pio.mesh_shape": [1, 1], "pio.mesh_axes": ["data", "seq"]}
+
+        seq_dir = self.engine_dir("sequence_latent_moe", "sequence", edit)
+        facts = self.train("train_sequence_latent_moe", seq_dir, 900)
+        held = widths["expertsHeld"][1] - widths["expertsHeld"][0]
+        if (facts.get("backbone") != "latent_moe" or facts.get("steps") != steps
+                or (facts.get("dense_layers"), facts.get("mtp_depth")) != (1, 1)
+                or facts.get("experts_held") != held or facts.get("experts_shared") != 1
+                or facts.get("experts_total") != widths["numExperts"]):
+            raise PhaseFailed(
+                f"train_sequence_latent_moe: not six steps of a dense layer, an expert layer"
+                f" with {held} of {widths['numExperts']} experts held and the module: {facts}")
+        score = widths["qkNopeHeadDim"] + widths["qkRopeHeadDim"]
+        latent = (widths["kvLoraRank"] + widths["qkRopeHeadDim"]) * 2
+        if ((facts.get("score_width"), facts.get("value_width")) != (score, widths["vHeadDim"])
+                or (facts.get("latent_q_rank"), facts.get("latent_kv_rank"))
+                != (widths["qLoraRank"], widths["kvLoraRank"])
+                or facts.get("latent_bytes_per_token") != latent):
+            raise PhaseFailed(f"train_sequence_latent_moe: the latents and the widths: {facts}")
+        if facts.get("moe_dropped") != 0 or not (
+                0 < facts.get("moe_held_assignments", 0) < facts["moe_assignments"]):
+            raise PhaseFailed(f"train_sequence_latent_moe: tokens dropped, or no share: {facts}")
+        # two routers (the expert layer's, the module's), each bias moved by the
+        # rate a step from zero: the largest lies within steps x rate, and is not 0
+        if (facts.get("router_bias_leaves") != 2
+                or not 0 < facts.get("router_bias_abs_max", 0) <= steps * rate * 1.001):
+            raise PhaseFailed(f"train_sequence_latent_moe: the routers' bias: {facts}")
+        if not facts.get("mtp_ce", 0) > 0:
+            raise PhaseFailed(f"train_sequence_latent_moe: no second prediction: {facts}")
+        first, last = facts["first_loss"], facts["last_loss"]
+        if not (first == first and last == last and last < first < float("inf")):
+            raise PhaseFailed(f"train_sequence_latent_moe: loss not finite and falling: {first} -> {last}")
+        leaves = self.step_leaves("sequence_latent_moe_leaves", algorithm, max_len, LATENT_LEAVES)
+        self.line("train_sequence_latent_moe", t0, **facts, users=4, events=int(users.size),
+                  max_len=max_len, leaf_scopes=len(leaves["leaves"]), **widths)
+
     def phase_sharded(self) -> None:
         self.phase_device(with_status=False)
         if self.device["count"] != 4:
@@ -834,6 +929,7 @@ def main(argv=None) -> int:
                 smoke.phase_train_sequence_looped,
                 smoke.phase_train_sequence_sparse_moe,
                 smoke.phase_train_sequence_hybrid_linear,
+                smoke.phase_train_sequence_latent_moe,
             ]
         for phase in phases:
             try:

@@ -30,6 +30,7 @@ from predictionio_tpu.data.store import PEventStore
 from predictionio_tpu.models._als_common import score_buffer_rows, topk_item_scores
 from predictionio_tpu.models.sequence.looped import LoopedConfig
 from predictionio_tpu.models.sequence.hybrid import HybridConfig
+from predictionio_tpu.models.sequence.latent_moe import LatentMoEConfig
 from predictionio_tpu.models.sequence.sparse_moe import SparseMoEConfig
 from predictionio_tpu.ops.flash_attention import tiles_worked
 from predictionio_tpu.models.sequence.model import (
@@ -183,7 +184,7 @@ class SequencePreparator(Preparator):
 class SASRecModel:
     params: dict
     # the backbone it was trained with
-    config: SASRecConfig | LoopedConfig | SparseMoEConfig | HybridConfig
+    config: SASRecConfig | LoopedConfig | SparseMoEConfig | HybridConfig | LatentMoEConfig
     item_ids: list[str]
     item_index: dict[str, int]
     histories: dict[str, np.ndarray]   # user id -> shifted (+1) id sequence
@@ -199,8 +200,8 @@ class SASRecModel:
 
 
 class SASRecAlgorithm(TPUAlgorithm):
-    """Params: ``backbone`` ("sasrec", the default, "looped", "sparse_moe" or
-    "hybrid_linear");
+    """Params: ``backbone`` ("sasrec", the default, "looped", "sparse_moe",
+    "hybrid_linear" or "latent_moe");
     learningRate, batchSize, epochs, seed, maxLen (must match the
     preparator's), attention ("auto" | "flash" | "plain") and seqParallel
     ("ring" | "ulysses", the sequence-parallel attention strategy when the
@@ -219,9 +220,16 @@ class SASRecAlgorithm(TPUAlgorithm):
     linearValueHeads, linearKeyDim, linearValueDim, convKernel, numHeads,
     numKvHeads, headDim, partialRotaryFactor, expertDim, numExperts,
     expertsPerToken, expertsHeld, sharedExpertDim, ropeTheta, rmsNormEps,
-    auxLossCoef."""
+    auxLossCoef; "latent_moe" (``models/sequence/latent_moe.py``: latent
+    attention, denseLayers leading dense layers, then experts chosen by sigmoid
+    scores plus a bias the step moves against the load, a shared expert, and a
+    module that predicts a second event ahead) reads hiddenSize, numLayers,
+    denseLayers, numHeads, qLoraRank, kvLoraRank, qkNopeHeadDim, qkRopeHeadDim,
+    vHeadDim, ffnDim, expertDim, numExperts, expertsPerToken, expertsHeld,
+    sharedExpertDim, routedScalingFactor, mtpDepth, mtpLossCoef,
+    balanceLossCoef, biasUpdateRate, ropeTheta, rmsNormEps."""
 
-    BACKBONES = ("sasrec", "looped", "sparse_moe", "hybrid_linear")
+    BACKBONES = ("sasrec", "looped", "sparse_moe", "hybrid_linear", "latent_moe")
 
     def _config(self, num_items: int, max_len: int):
         p = self.params
@@ -303,6 +311,35 @@ class SASRecAlgorithm(TPUAlgorithm):
                 rope_theta=float(p.get_or("ropeTheta", d.rope_theta)),
                 rms_eps=float(p.get_or("rmsNormEps", d.rms_eps)),
                 aux_coef=float(p.get_or("auxLossCoef", d.aux_coef)),
+                learning_rate=p.get_or("learningRate", d.learning_rate),
+                **shared,
+            )
+        if backbone == "latent_moe":
+            d = LatentMoEConfig(num_items=num_items)  # the defaults, in one place
+            experts = p.get_or("numExperts", d.num_experts)
+            return LatentMoEConfig(
+                hidden_size=p.get_or("hiddenSize", d.hidden_size),
+                num_layers=p.get_or("numLayers", d.num_layers),
+                dense_layers=p.get_or("denseLayers", d.dense_layers),
+                num_heads=p.get_or("numHeads", d.num_heads),
+                q_rank=p.get_or("qLoraRank", d.q_rank),
+                kv_rank=p.get_or("kvLoraRank", d.kv_rank),
+                nope_dim=p.get_or("qkNopeHeadDim", d.nope_dim),
+                rope_dim=p.get_or("qkRopeHeadDim", d.rope_dim),
+                value_dim=p.get_or("vHeadDim", d.value_dim),
+                ffn_dim=p.get_or("ffnDim", d.ffn_dim),
+                expert_dim=p.get_or("expertDim", d.expert_dim),
+                num_experts=experts,
+                experts_per_token=p.get_or("expertsPerToken", d.experts_per_token),
+                experts_held=tuple(p.get_or("expertsHeld", (0, experts))),
+                shared_expert_dim=p.get_or("sharedExpertDim", d.shared_expert_dim),
+                routed_scale=float(p.get_or("routedScalingFactor", d.routed_scale)),
+                mtp_depth=p.get_or("mtpDepth", d.mtp_depth),
+                mtp_coef=float(p.get_or("mtpLossCoef", d.mtp_coef)),
+                balance_coef=float(p.get_or("balanceLossCoef", d.balance_coef)),
+                bias_rate=float(p.get_or("biasUpdateRate", d.bias_rate)),
+                rope_theta=float(p.get_or("ropeTheta", d.rope_theta)),
+                rms_eps=float(p.get_or("rmsNormEps", d.rms_eps)),
                 learning_rate=p.get_or("learningRate", d.learning_rate),
                 **shared,
             )

@@ -34,8 +34,9 @@ from predictionio_tpu.parallel.mesh import (
     one_step_in_flight,
     put_global,
 )
-from predictionio_tpu.models.sequence import hybrid, looped, sparse_moe
+from predictionio_tpu.models.sequence import hybrid, latent_moe, looped, sparse_moe
 from predictionio_tpu.models.sequence.hybrid import HybridConfig
+from predictionio_tpu.models.sequence.latent_moe import LatentMoEConfig
 from predictionio_tpu.models.sequence.looped import LoopedConfig
 from predictionio_tpu.models.sequence.sparse_moe import SparseMoEConfig
 from predictionio_tpu.ops.flash_attention import flash_attention
@@ -183,14 +184,19 @@ def _sasrec_loss(model: SASRec):
     return loss_fn
 
 
-def make_train_step(loss_fn, optimizer):
-    """One optimizer step of ``loss_fn(params, batch, rng) -> (loss, aux)``."""
+def make_train_step(loss_fn, optimizer, move=None):
+    """One optimizer step of ``loss_fn(params, batch, rng) -> (loss, aux)``.
+    ``move(params, aux) -> (params, aux)`` is the rule of a backbone's leaves
+    that the optimizer leaves alone (``untrained_of``): it moves them from
+    what the step's own loss counted, inside the same program."""
     def train_step(params, opt_state, batch, rng):
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params, batch, rng)
         with jax.named_scope(looped.SCOPE_OPTIMIZER):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
+            if move is not None:
+                params, aux = move(params, aux)
         return params, opt_state, loss, aux
 
     return train_step
@@ -217,6 +223,9 @@ def backbone_of(config, mesh):
     if isinstance(config, HybridConfig):
         return (lambda rng, t: hybrid.init_params(config, rng),
                 hybrid.make_loss(config, mesh))
+    if isinstance(config, LatentMoEConfig):
+        return (lambda rng, t: latent_moe.init_params(config, rng),
+                latent_moe.make_loss(config, mesh))
     model = SASRec(config, mesh)
     # dummy batch = one row per data-shard: shard_map needs divisibility
     dp0 = max(mesh.shape.get("data", 1), 1)
@@ -224,13 +233,28 @@ def backbone_of(config, mesh):
             _sasrec_loss(model))
 
 
-def optimizer_of(config):
-    """Adam at the configuration's learning rate. The sparse backbone's
-    indexer is not trained by this loss: it gets no update and no moments."""
-    adam = optax.adam(config.learning_rate)
+def untrained_of(config):
+    """``(labels, move)`` of a backbone with leaves that the loss's gradient
+    does not train, else ``(None, None)``. ``labels(params)`` names every leaf
+    ``"train"`` or ``"fixed"``: a fixed leaf gets no update from the optimizer
+    and no moments. ``move(params, aux) -> (params, aux)``, where there is one,
+    moves the fixed leaves after the step from what its loss counted. The
+    sparse backbone's indexer stays as drawn (no rule); the latent backbone's
+    router biases move against the step's load."""
     if isinstance(config, SparseMoEConfig):
-        return optax.multi_transform(
-            {"train": adam, "fixed": optax.set_to_zero()}, sparse_moe.trained_labels)
+        return sparse_moe.trained_labels, None
+    if isinstance(config, LatentMoEConfig):
+        return latent_moe.trained_labels, functools.partial(latent_moe.move_bias, config)
+    return None, None
+
+
+def optimizer_of(config):
+    """Adam at the configuration's learning rate, over the leaves the loss
+    trains (``untrained_of`` names the others)."""
+    adam = optax.adam(config.learning_rate)
+    labels = untrained_of(config)[0]
+    if labels is not None:
+        return optax.multi_transform({"train": adam, "fixed": optax.set_to_zero()}, labels)
     return adam
 
 
@@ -263,7 +287,7 @@ def make_fit(config, mesh):
         return params, jax.jit(optimizer.init, out_shardings=rep)(params)
 
     step_fn = jax.jit(
-        make_train_step(loss_fn, optimizer),
+        make_train_step(loss_fn, optimizer, untrained_of(config)[1]),
         in_shardings=(rep, rep, {"seq": seq_shard, "target": seq_shard}, None),
         out_shardings=(rep, rep, rep, rep),
         donate_argnums=(0, 1),
@@ -272,7 +296,7 @@ def make_fit(config, mesh):
 
 
 def train_sasrec(
-    config,                  # SASRecConfig | LoopedConfig | SparseMoEConfig | HybridConfig
+    config,                  # SASRecConfig or a backbone's (``_BACKBONES``)
     sequences: np.ndarray,   # [N, T] int32 padded item ids (0 = pad)
     mesh,
     log_every: int = 0,
@@ -353,9 +377,13 @@ def train_sasrec(
 _FIT_LINE_ATTRS = ("experts_total", "experts_held", "experts_per_token", "experts_shared",
                    "index_topk", "kv_heads", "selection_kept_bytes", "linear_layers",
                    "full_layers", "delta_chunk", "delta_heads_per_step", "delta_state_bytes",
-                   "delta_kept_bytes")
+                   "delta_kept_bytes", "dense_layers", "mtp_depth", "latent_q_rank",
+                   "latent_kv_rank", "score_width", "value_width", "latent_bytes_per_token",
+                   "router_bias_leaves")
 _BACKBONES = {LoopedConfig: "looped", SparseMoEConfig: "sparse_moe",
-              HybridConfig: "hybrid_linear"}
+              HybridConfig: "hybrid_linear", LatentMoEConfig: "latent_moe"}
+#: the backbones with routed experts of which the program holds a share
+_EXPERTS = (SparseMoEConfig, HybridConfig, LatentMoEConfig)
 
 
 def fit_attrs(config, param_bytes: int, opt_state_bytes: int, rows: int) -> dict:
@@ -370,16 +398,17 @@ def fit_attrs(config, param_bytes: int, opt_state_bytes: int, rows: int) -> dict
         # backbone: its selection, one bit a pair)
         "selection_kept_bytes": 0,
     }
-    if isinstance(config, (LoopedConfig, SparseMoEConfig, HybridConfig)):
+    if type(config) in _BACKBONES:
         chunk = looped.head_chunk_of(config)
+        halves = isinstance(config, (HybridConfig, LatentMoEConfig))
         attrs.update(
             layers=config.num_layers, passes=getattr(config, "ut_steps", 1),
             rematerialised=("nothing" if not config.remat else
-                            "mixer and experts" if isinstance(config, HybridConfig) else "layer"),
+                            "mixer and experts" if halves else "layer"),
             head=(f"chunks of {chunk} positions, recomputed" if chunk else
                   "whole pass, recomputed"),
         )
-        if isinstance(config, (SparseMoEConfig, HybridConfig)):
+        if isinstance(config, _EXPERTS):
             attrs.update(
                 experts_total=config.num_experts, experts_held=config.held,
                 experts_per_token=config.experts_per_token, kv_heads=config.num_kv_heads)
@@ -394,6 +423,13 @@ def fit_attrs(config, param_bytes: int, opt_state_bytes: int, rows: int) -> dict
                 delta_heads_per_step=hybrid.delta_heads_per_step(config, rows),
                 delta_state_bytes=hybrid.delta_state_bytes(config),
                 delta_kept_bytes=hybrid.delta_kept_bytes(config, rows))
+        if isinstance(config, LatentMoEConfig):
+            attrs.update(
+                experts_shared=1, dense_layers=config.dense_layers, mtp_depth=config.mtp_depth,
+                latent_q_rank=config.q_rank, latent_kv_rank=config.kv_rank,
+                score_width=config.score_dim, value_width=config.value_dim,
+                latent_bytes_per_token=latent_moe.latent_bytes_per_token(config),
+                router_bias_leaves=config.routers)
     else:
         attrs.update(layers=config.num_blocks, passes=1,
                      rematerialised="nothing", head="whole")
@@ -413,8 +449,9 @@ def _score_fn(config):
                 lambda params, seqs, last: looped.score_last(
                     config, attention, params, seqs, last))
             return _SCORE_CACHE[config]
-        if isinstance(config, (SparseMoEConfig, HybridConfig)):
-            module = sparse_moe if isinstance(config, SparseMoEConfig) else hybrid
+        if isinstance(config, _EXPERTS):
+            module = {SparseMoEConfig: sparse_moe, HybridConfig: hybrid,
+                      LatentMoEConfig: latent_moe}[type(config)]
             _SCORE_CACHE[config] = jax.jit(functools.partial(module.score_last, config))
             return _SCORE_CACHE[config]
         model = SASRec(config, None)

@@ -507,37 +507,68 @@ def _experts_chunk(c: SparseMoEConfig, w_gate, w_up, w_down, u, experts, gates, 
     return y, worked.sum(), (worked > 0).sum()
 
 
-def _moe(c: SparseMoEConfig, u, p, real):
-    """``(y, stats)``: the held experts' part of the routed sum for the normed
-    tokens ``u`` [N, D], and the layer's counts. ``real`` [N]: a padded slot
-    is routed nowhere and counts nowhere."""
-    dtype = jnp.dtype(c.compute_dtype)
+def load_of(c, experts, real):
+    """The assignments of real tokens to every expert ``[E]`` under a router's
+    choice ``experts`` [N, K]."""
+    chosen = (experts[..., None] == jnp.arange(c.num_experts)) & real[:, None, None]
+    return chosen.sum(axis=(0, 1))
+
+
+def load_stats(c, load) -> dict:
+    """A layer's counts of its ``load`` [E]: every assignment, those to held
+    experts, the most one held expert takes."""
     lo, hi = c.experts_held
-    n, slots = u.shape[0], c.experts_per_token
-    with jax.named_scope(SCOPE_ROUTE):
-        probs = jax.nn.softmax(jnp.matmul(
-            u, p["router"], precision=jax.lax.Precision.HIGHEST), axis=-1)
-        top_p, experts = jax.lax.top_k(probs, slots)
-        gates = top_p / top_p.sum(axis=-1, keepdims=True)
-        count = jnp.maximum(real.sum(), 1).astype(jnp.float32)
-        chosen = (experts[..., None] == jnp.arange(c.num_experts)) & real[:, None, None]
-        load = chosen.sum(axis=(0, 1))                             # [E] assignments
-        mean_p = jnp.where(real[:, None], probs, 0.0).sum(axis=0) / count
-        aux = c.num_experts * jnp.sum(load.astype(jnp.float32) / count * mean_p)
-        held_load = load[lo:hi]
-        stats = {"aux": aux, "assignments": load.sum(), "held_assignments": held_load.sum(),
-                 "held_load_max": held_load.max()}
-    with jax.named_scope(SCOPE_EXPERTS):
-        chunk = min(moe_chunk_of(c), n)
-        work = jax.checkpoint(functools.partial(
-            _experts_chunk, c, p["w_gate"].astype(dtype), p["w_up"].astype(dtype),
-            p["w_down"].astype(dtype)))
-        y, rows, ran = jax.lax.map(lambda args: work(*args), tuple(
-            _cut(a, chunk) for a in (u.astype(dtype), experts, gates, real)))
-        stats["dropped"] = stats["held_assignments"] - rows.sum()
-        stats["passes"] = jnp.int32(len(rows) * pass_plan(c, chunk)[1])   # chunks x passes
-        stats["passes_run"] = ran.sum()
+    held_load = load[lo:hi]
+    return {"assignments": load.sum(), "held_assignments": held_load.sum(),
+            "held_load_max": held_load.max()}
+
+
+def _route(c: SparseMoEConfig, u, p, real):
+    """``(experts, gates, stats)``: the softmax router's ``experts_per_token``
+    largest of all ``num_experts`` for the normed tokens ``u`` [N, D], their
+    renormalised gates, and the layer's counts with its load-balancing loss
+    (``aux``). ``real`` [N]: a padded slot counts nowhere."""
+    probs = jax.nn.softmax(jnp.matmul(
+        u, p["router"], precision=jax.lax.Precision.HIGHEST), axis=-1)
+    top_p, experts = jax.lax.top_k(probs, c.experts_per_token)
+    gates = top_p / top_p.sum(axis=-1, keepdims=True)
+    count = jnp.maximum(real.sum(), 1).astype(jnp.float32)
+    load = load_of(c, experts, real)
+    mean_p = jnp.where(real[:, None], probs, 0.0).sum(axis=0) / count
+    aux = c.num_experts * jnp.sum(load.astype(jnp.float32) / count * mean_p)
+    return experts, gates, {"aux": aux, **load_stats(c, load)}
+
+
+def _held_experts(c: SparseMoEConfig, u, p, experts, gates, real, stats):
+    """``(y, stats)``: the held experts' part of the routed sum for the normed
+    tokens ``u`` [N, D] under a router's choice (``experts``, ``gates``
+    [N, K]), ``stats`` gaining what the passes did. A padded slot (``real``
+    [N]) is routed nowhere."""
+    dtype = jnp.dtype(c.compute_dtype)
+    n = u.shape[0]
+    chunk = min(moe_chunk_of(c), n)
+    work = jax.checkpoint(functools.partial(
+        _experts_chunk, c, p["w_gate"].astype(dtype), p["w_up"].astype(dtype),
+        p["w_down"].astype(dtype)))
+    y, rows, ran = jax.lax.map(lambda args: work(*args), tuple(
+        _cut(a, chunk) for a in (u.astype(dtype), experts, gates, real)))
+    stats["dropped"] = stats["held_assignments"] - rows.sum()
+    stats["passes"] = jnp.int32(len(rows) * pass_plan(c, chunk)[1])   # chunks x passes
+    stats["passes_run"] = ran.sum()
     return y.reshape(-1, y.shape[-1])[:n], stats
+
+
+def _moe(c: SparseMoEConfig, u, p, real, route=_route):
+    """``(y, stats)``: the held experts' part of the routed sum for the normed
+    tokens ``u`` [N, D], and the layer's counts. ``route(c, u, p, real)`` is
+    the layer's router, under ``moe/route`` (this backbone's and the hybrid's
+    is the softmax ``_route``; the latent backbone brings its own); the held
+    experts' work under ``moe/experts`` is the same for all. ``real`` [N]: a
+    padded slot is routed nowhere and counts nowhere."""
+    with jax.named_scope(SCOPE_ROUTE):
+        experts, gates, stats = route(c, u, p, real)
+    with jax.named_scope(SCOPE_EXPERTS):
+        return _held_experts(c, u, p, experts, gates, real, stats)
 
 
 # ---- the stack ---------------------------------------------------------------

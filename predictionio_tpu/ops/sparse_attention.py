@@ -32,10 +32,19 @@ every causal pair counts, and a tile's mask is made from its positions. Rows are
 left-aligned, so a padded position follows every event of its row and no
 real query can select it; a padded query's output is never read.
 
+``q`` and ``k`` share the width the scores are taken over, ``v`` and the output
+the width that is carried: the two may differ (latent attention scores over
+192 and carries 128). A grid step works ``heads_per_step`` key-value heads
+with their query heads: one where a key-value head serves eight query heads or
+more, several where each serves few (with a key and value of its own for every
+query head, a step of one head would be mostly its own overhead, and its
+per-query scalars one lane wide).
+
 Layout (see ``flash_attention.py`` for what the hardware asks): tensors are
 ``[B, H, T, D]`` at the Pallas boundary; per-query scalars (logsumexp, delta)
-are ``[B, KV, T, G]`` with the group's heads on the lanes, so a head's column
-is a static lane slice.
+are ``[B, KV / S, T, S G]`` for ``S`` key-value heads a step of ``G`` query
+heads each, the step's heads on the lanes, so a head's column is a static lane
+slice.
 """
 
 from __future__ import annotations
@@ -56,6 +65,7 @@ BLOCK_K = 512          # keys a tile
 SELECT_ROWS = 128      # queries a select program holds
 SELECT_CHUNK = 512     # keys a pass of its loops takes
 VMEM_LIMIT_BYTES = 64 << 20
+STEP_HEADS = 8         # query heads a grid step of the attention programs aims at
 
 
 def _block(block: int, t: int) -> int:
@@ -258,8 +268,8 @@ def select_topk(scores, topk: int, *, rows=SELECT_ROWS, chunk=SELECT_CHUNK,
 
 def sparse_attention_plain(q, k, v, mask):
     """Softmax attention over the pairs ``mask`` [B, T, T] selects: q
-    [B, T, H, D], k, v [B, T, KV, D] -> [B, T, H, D]; float32 softmax, the
-    matmuls in the inputs' dtype accumulated in float32."""
+    [B, T, H, D], k [B, T, KV, D], v [B, T, KV, DV] -> [B, T, H, DV]; float32
+    softmax, the matmuls in the inputs' dtype accumulated in float32."""
     b, t, h, d = q.shape
     kv = k.shape[2]
     qg = q.reshape(b, t, kv, h // kv, d)
@@ -271,7 +281,7 @@ def sparse_attention_plain(q, k, v, mask):
     p = p / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-20)
     out = jnp.einsum("bkgts,bskd->btkgd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(b, t, h, d).astype(q.dtype)
+    return out.reshape(b, t, h, -1).astype(q.dtype)
 
 
 def _tile(mask_ref, qi, ki, bq: int, bk: int):
@@ -294,7 +304,7 @@ def _kernel(kernel, masked, **static):
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, bq: int, bk: int):
     qi, ki = pl.program_id(2), pl.program_id(3)
-    heads = q_ref.shape[1]
+    heads, group = q_ref.shape[1], q_ref.shape[1] // k_ref.shape[1]
 
     @pl.when(ki == 0)
     def _():
@@ -304,16 +314,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref, m_scr, l_scr, a
 
     @pl.when(ki <= _last_key_block(qi, bq, bk))
     def _():
-        k, v, on = k_ref[0, 0], v_ref[0, 0], _tile(mask_ref, qi, ki, bq, bk)
-        for h in range(heads):
-            s = jnp.where(on, _dot(q_ref[0, h], k, 1, 1), _NEG)
-            m_old = m_scr[h]
-            m_new = jnp.maximum(m_old, s.max(axis=1, keepdims=True))
-            p = jnp.where(on, jnp.exp(s - m_new), 0.0)
-            scale = jnp.exp(m_old - m_new)
-            l_scr[h] = l_scr[h] * scale + p.sum(axis=1, keepdims=True)
-            acc_scr[h] = acc_scr[h] * scale + _dot(p.astype(v.dtype), v, 1, 0)
-            m_scr[h] = m_new
+        for j in range(k_ref.shape[1]):
+            k, v, on = k_ref[0, j], v_ref[0, j], _tile(mask_ref, qi, ki, bq, bk)
+            for h in range(j * group, (j + 1) * group):
+                s = jnp.where(on, _dot(q_ref[0, h], k, 1, 1), _NEG)
+                m_old = m_scr[h]
+                m_new = jnp.maximum(m_old, s.max(axis=1, keepdims=True))
+                p = jnp.where(on, jnp.exp(s - m_new), 0.0)
+                scale = jnp.exp(m_old - m_new)
+                l_scr[h] = l_scr[h] * scale + p.sum(axis=1, keepdims=True)
+                acc_scr[h] = acc_scr[h] * scale + _dot(p.astype(v.dtype), v, 1, 0)
+                m_scr[h] = m_new
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _():
@@ -326,7 +337,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref, m_scr, l_scr, a
 def _dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
                *, bq: int, bk: int):
     qi, ki = pl.program_id(2), pl.program_id(3)
-    heads = q_ref.shape[1]
+    group = q_ref.shape[1] // k_ref.shape[1]
 
     @pl.when(ki == 0)
     def _():
@@ -334,12 +345,13 @@ def _dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref, dq_ref
 
     @pl.when(ki <= _last_key_block(qi, bq, bk))
     def _():
-        k, v, on = k_ref[0, 0], v_ref[0, 0], _tile(mask_ref, qi, ki, bq, bk)
-        for h in range(heads):
-            s = _dot(q_ref[0, h], k, 1, 1)
-            p = jnp.where(on, jnp.exp(s - lse_ref[0, 0, :, h:h + 1]), 0.0)
-            ds = p * (_dot(do_ref[0, h], v, 1, 1) - delta_ref[0, 0, :, h:h + 1])
-            dq_scr[h] = dq_scr[h] + _dot(ds.astype(k.dtype), k, 1, 0)
+        for j in range(k_ref.shape[1]):
+            k, v, on = k_ref[0, j], v_ref[0, j], _tile(mask_ref, qi, ki, bq, bk)
+            for h in range(j * group, (j + 1) * group):
+                s = _dot(q_ref[0, h], k, 1, 1)
+                p = jnp.where(on, jnp.exp(s - lse_ref[0, 0, :, h:h + 1]), 0.0)
+                ds = p * (_dot(do_ref[0, h], v, 1, 1) - delta_ref[0, 0, :, h:h + 1])
+                dq_scr[h] = dq_scr[h] + _dot(ds.astype(k.dtype), k, 1, 0)
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _():
@@ -347,39 +359,52 @@ def _dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref, dq_ref
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, bq: int, bk: int):
+                dk_ref, dv_ref, *scratch, bq: int, bk: int):
+    """``scratch``: a key-value head of the step's ``dk``, then its ``dv``."""
     ki, qi = pl.program_id(2), pl.program_id(3)
-    heads = q_ref.shape[1]
+    group = q_ref.shape[1] // k_ref.shape[1]
+    sums = tuple(zip(scratch[0::2], scratch[1::2]))
 
     @pl.when(qi == 0)
     def _():
-        dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
-        dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
+        for dk_scr, dv_scr in sums:
+            dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
+            dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
 
     @pl.when(qi >= _first_query_block(ki, bq, bk))
     def _():
-        k, v, on = k_ref[0, 0], v_ref[0, 0], _tile(mask_ref, qi, ki, bq, bk)
-        dk, dv = dk_scr[...], dv_scr[...]
-        for h in range(heads):
-            q, do = q_ref[0, h], do_ref[0, h]
-            s = _dot(q, k, 1, 1)
-            p = jnp.where(on, jnp.exp(s - lse_ref[0, 0, :, h:h + 1]), 0.0)
-            dv = dv + _dot(p.astype(do.dtype), do, 0, 0)
-            ds = p * (_dot(do, v, 1, 1) - delta_ref[0, 0, :, h:h + 1])
-            dk = dk + _dot(ds.astype(q.dtype), q, 0, 0)
-        dk_scr[...], dv_scr[...] = dk, dv
+        for j, (dk_scr, dv_scr) in enumerate(sums):
+            k, v, on = k_ref[0, j], v_ref[0, j], _tile(mask_ref, qi, ki, bq, bk)
+            dk, dv = dk_scr[...], dv_scr[...]
+            for h in range(j * group, (j + 1) * group):
+                q, do = q_ref[0, h], do_ref[0, h]
+                s = _dot(q, k, 1, 1)
+                p = jnp.where(on, jnp.exp(s - lse_ref[0, 0, :, h:h + 1]), 0.0)
+                dv = dv + _dot(p.astype(do.dtype), do, 0, 0)
+                ds = p * (_dot(do, v, 1, 1) - delta_ref[0, 0, :, h:h + 1])
+                dk = dk + _dot(ds.astype(q.dtype), q, 0, 0)
+            dk_scr[...], dv_scr[...] = dk, dv
 
     @pl.when(qi == pl.num_programs(3) - 1)
     def _():
-        dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
+        for j, (dk_scr, dv_scr) in enumerate(sums):
+            dk_ref[0, j] = dk_scr[...].astype(dk_ref.dtype)
+            dv_ref[0, j] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _specs(g: int, d: int, bq: int, bk: int, by_key: bool):
-    """Block specs of one call. The forward and ``dq`` walk (b, kv, qi, ki)
-    with the key block clamped to the last one under the diagonal; ``dkv``
-    walks (b, kv, ki, qi) with the query block clamped to the first at it. A
-    clamped step names the block the step before it held: nothing is fetched."""
+def heads_per_step(kv: int, g: int) -> int:
+    """Key-value heads a grid step works, from the shapes alone: as many as
+    bring its query heads to ``STEP_HEADS``, of those that divide ``kv``."""
+    return next(s for s in range(min(kv, max(1, STEP_HEADS // g)), 0, -1) if kv % s == 0)
+
+
+def _specs(s: int, g: int, d: int, dv: int, bq: int, bk: int, by_key: bool):
+    """Block specs of one call for ``s`` key-value heads a step of ``g`` query
+    heads each, scores over ``d`` and values of ``dv``. The forward and ``dq``
+    walk (b, kv / s, qi, ki) with the key block clamped to the last one under
+    the diagonal; ``dkv`` walks (b, kv / s, ki, qi) with the query block
+    clamped to the first at it. A clamped step names the block the step before
+    it held: nothing is fetched."""
     if by_key:
         at = lambda ki, qi: (jnp.maximum(qi, _first_query_block(ki, bq, bk)), ki)  # noqa: E731
     else:
@@ -388,11 +413,13 @@ def _specs(g: int, d: int, bq: int, bk: int, by_key: bool):
     def spec(block, index):
         return pl.BlockSpec(block, lambda b, kv, i, j: index(b, kv, *at(i, j)))
 
+    by_query = lambda b, kv, qi, ki: (b, kv, qi, 0)  # noqa: E731
+    by_keys = lambda b, kv, qi, ki: (b, kv, ki, 0)  # noqa: E731
     return {
-        "q": spec((1, g, bq, d), lambda b, kv, qi, ki: (b, kv, qi, 0)),
-        "k": spec((1, 1, bk, d), lambda b, kv, qi, ki: (b, kv, ki, 0)),
+        "q": spec((1, s * g, bq, d), by_query), "o": spec((1, s * g, bq, dv), by_query),
+        "k": spec((1, s, bk, d), by_keys), "v": spec((1, s, bk, dv), by_keys),
         "mask": spec((1, bq, bk), lambda b, kv, qi, ki: (b, qi, ki)),
-        "row": spec((1, 1, bq, g), lambda b, kv, qi, ki: (b, kv, qi, 0)),
+        "row": spec((1, 1, bq, s * g), by_query),
     }
 
 
@@ -402,29 +429,30 @@ def _heads_first(x):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def sparse_attention(q, k, v, mask, block_q=BLOCK_Q, block_k=BLOCK_K, interpret=False):
-    """q [B, T, H, D], k, v [B, T, KV, D], ``mask`` int8 [B, T, T] (the
-    selection: the pairs that count, causal) -> [B, T, H, D]."""
+    """q [B, T, H, D], k [B, T, KV, D], v [B, T, KV, DV], ``mask`` int8
+    [B, T, T] (the selection: the pairs that count, causal) -> [B, T, H, DV]."""
     return _forward(q, k, v, mask, block_q, block_k, interpret)[0]
 
 
 def _forward(q, k, v, mask, block_q, block_k, interpret):
     b, t, h, d = q.shape
-    kv = k.shape[2]
+    kv, dv = k.shape[2], v.shape[3]
     g = h // kv
+    s = heads_per_step(kv, g)
     bq, bk = _block(block_q, t), _block(block_k, t)
-    sp = _specs(g, d, bq, bk, by_key=False)
+    sp = _specs(s, g, d, dv, bq, bk, by_key=False)
     masked = (mask,) if mask is not None else ()
     scaled = (q.astype(jnp.float32) * d ** -0.5).astype(q.dtype)
     out, lse = pl.pallas_call(
         _kernel(_fwd_kernel, masked, bq=bq, bk=bk),
-        grid=(b, kv, t // bq, t // bk),
-        in_specs=[sp["q"], sp["k"], sp["k"]] + [sp["mask"]] * len(masked),
-        out_specs=[sp["q"], sp["row"]],
-        out_shape=[jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
-                   jax.ShapeDtypeStruct((b, kv, t, g), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((g, bq, 1), jnp.float32),
-                        pltpu.VMEM((g, bq, 1), jnp.float32),
-                        pltpu.VMEM((g, bq, d), jnp.float32)],
+        grid=(b, kv // s, t // bq, t // bk),
+        in_specs=[sp["q"], sp["k"], sp["v"]] + [sp["mask"]] * len(masked),
+        out_specs=[sp["o"], sp["row"]],
+        out_shape=[jax.ShapeDtypeStruct((b, h, t, dv), q.dtype),
+                   jax.ShapeDtypeStruct((b, kv // s, t, s * g), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((s * g, bq, 1), jnp.float32),
+                        pltpu.VMEM((s * g, bq, 1), jnp.float32),
+                        pltpu.VMEM((s * g, bq, dv), jnp.float32)],
         compiler_params=_params("parallel", "parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(_heads_first(scaled), _heads_first(k), _heads_first(v), *masked)
@@ -439,47 +467,49 @@ def _fwd(q, k, v, mask, block_q, block_k, interpret):
 def _bwd(block_q, block_k, interpret, res, g_out):
     q, k, v, mask, out, lse = res
     b, t, h, d = q.shape
-    kv = k.shape[2]
+    kv, dv = k.shape[2], v.shape[3]
     g = h // kv
+    s = heads_per_step(kv, g)
     bq, bk = _block(block_q, t), _block(block_k, t)
     scale = d ** -0.5
     # delta[b, t, h] = rowsum(dO o O), laid out like the logsumexp
     delta = jnp.einsum("bthd,bthd->bth", g_out.astype(jnp.float32),
                        out.astype(jnp.float32))
-    delta = jnp.transpose(delta.reshape(b, t, kv, g), (0, 2, 1, 3))
+    delta = jnp.transpose(delta.reshape(b, t, kv // s, s * g), (0, 2, 1, 3))
     qs = _heads_first((q.astype(jnp.float32) * scale).astype(q.dtype))
     kt, vt, do = _heads_first(k), _heads_first(v), _heads_first(g_out.astype(q.dtype))
     masked = (mask,) if mask is not None else ()
-    ins = lambda sp: ([sp["q"], sp["k"], sp["k"]] + [sp["mask"]] * len(masked)  # noqa: E731
-                      + [sp["q"], sp["row"], sp["row"]])
+    ins = lambda sp: ([sp["q"], sp["k"], sp["v"]] + [sp["mask"]] * len(masked)  # noqa: E731
+                      + [sp["o"], sp["row"], sp["row"]])
 
-    sp = _specs(g, d, bq, bk, by_key=False)
+    sp = _specs(s, g, d, dv, bq, bk, by_key=False)
     dq = pl.pallas_call(
         _kernel(_dq_kernel, masked, bq=bq, bk=bk),
-        grid=(b, kv, t // bq, t // bk),
+        grid=(b, kv // s, t // bq, t // bk),
         in_specs=ins(sp),
         out_specs=sp["q"],
         out_shape=jax.ShapeDtypeStruct((b, h, t, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((g, bq, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((s * g, bq, d), jnp.float32)],
         compiler_params=_params("parallel", "parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(qs, kt, vt, *masked, do, lse, delta)
 
-    sp = _specs(g, d, bq, bk, by_key=True)
-    dk, dv = pl.pallas_call(
+    sp = _specs(s, g, d, dv, bq, bk, by_key=True)
+    dk, d_v = pl.pallas_call(
         _kernel(_dkv_kernel, masked, bq=bq, bk=bk),
-        grid=(b, kv, t // bk, t // bq),
+        grid=(b, kv // s, t // bk, t // bq),
         in_specs=ins(sp),
-        out_specs=[sp["k"], sp["k"]],
-        out_shape=[jax.ShapeDtypeStruct((b, kv, t, d), jnp.float32)] * 2,
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32)] * 2,
+        out_specs=[sp["k"], sp["v"]],
+        out_shape=[jax.ShapeDtypeStruct((b, kv, t, d), jnp.float32),
+                   jax.ShapeDtypeStruct((b, kv, t, dv), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32), pltpu.VMEM((bk, dv), jnp.float32)] * s,
         compiler_params=_params("parallel", "parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(qs, kt, vt, *masked, do, lse, delta)
 
     # dq was taken against the scaled q; dk already carries the scale
     grads = ((_heads_first(dq) * scale).astype(q.dtype), _heads_first(dk).astype(k.dtype),
-             _heads_first(dv).astype(v.dtype))
+             _heads_first(d_v).astype(v.dtype))
     return grads + tuple(np.zeros(m.shape, jax.dtypes.float0) for m in masked)
 
 
@@ -496,8 +526,8 @@ def causal_attention_plain(q, k, v):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def causal_attention(q, k, v, block_q=BLOCK_Q, block_k=BLOCK_K, interpret=False):
     """:func:`sparse_attention` over every causal pair, with no mask operand:
-    q [B, T, H, D], k, v [B, T, KV, D] -> [B, T, H, D]. A padded position
-    follows its row's events, so no real query reads it."""
+    q [B, T, H, D], k [B, T, KV, D], v [B, T, KV, DV] -> [B, T, H, DV]. A
+    padded position follows its row's events, so no real query reads it."""
     return _forward(q, k, v, None, block_q, block_k, interpret)[0]
 
 

@@ -15,7 +15,8 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PHASES = ["device", "compile_cache", "ingest", "train_als", "als_full_width",
           "serve_als", "train_serve_ncf", "train_sequence_looped",
-          "train_sequence_sparse_moe", "train_sequence_hybrid_linear"]
+          "train_sequence_sparse_moe", "train_sequence_hybrid_linear",
+          "train_sequence_latent_moe"]
 
 
 def _run(args, tmp_path, timeout, **env_overrides):
@@ -65,6 +66,18 @@ def test_rehearsal_reaches_every_phase_then_refuses_the_cpu(tmp_path):
     assert (hybrid["experts_held"], hybrid["experts_total"], hybrid["moe_dropped"]) == (4, 16, 0)
     assert hybrid["delta_state_bytes"] == 3 * 4 * 16 * 16 * 4       # layers x heads x dk x dv
     assert hybrid["delta_kept_bytes"] == 4 * 2 * 4 * 16 * 16 * 4    # rows x chunks x a layer's
+    latent = by_phase["train_sequence_latent_moe"]
+    assert latent["backbone"] == "latent_moe" and latent["last_loss"] < latent["first_loss"]
+    assert (latent["dense_layers"], latent["mtp_depth"], latent["experts_shared"]) == (1, 1, 1)
+    assert (latent["experts_held"], latent["experts_total"], latent["moe_dropped"]) == (4, 16, 0)
+    assert (latent["score_width"], latent["value_width"], latent["latent_q_rank"],
+            latent["latent_kv_rank"], latent["latent_bytes_per_token"]) == (24, 16, 48, 32, 80)
+    # two routers, six steps of 0.001 either way from zero
+    assert latent["router_bias_leaves"] == 2 and 0 < latent["router_bias_abs_max"] <= 0.006001
+    assert latent["mtp_ce"] > 0
+    # the sparse step's eleven, the dense layer's norm, the shared expert, the
+    # two latent paths, and six of them again under the prediction module
+    assert latent["leaf_scopes"] == 21
 
 
 def test_without_a_chip_the_default_run_stops_at_the_device_phase(tmp_path):

@@ -788,3 +788,74 @@ def test_the_hybrid_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
         scopes_hybrid.LEAVES)
     assert ("shared", None) in places
 
+
+def test_the_latent_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persistent_cache):
+    """The step of ``joyai-llm-flash-ep16.train-lifelong-histories`` (2 rows of
+    8,192 at the published widths: a dense layer, four expert layers with 16 of
+    256 experts held, the prediction module, an eighth of the vocabulary):
+    Mosaic takes the attention programs with no mask operand at a score width
+    of 192 (one and a half lane tiles) and a value width of 128, eight of the
+    32 heads a grid step (a grid of 2 x 4 x 32 x 16), the peak is under the
+    chip's 15.75 GB, and every program and every new scope sits where the
+    benchmark's readers look for it: the attention forward, recomputed and as
+    ``dq`` and ``dkv`` in the dense layer, in the scanned expert layers and
+    under ``mtp``; the two latent paths inside ``qkv``; the bias's move under
+    ``seq.optimizer``."""
+    import re
+
+    from benchmarks import scopes_latent, scopes_leaf, scopes_seq, scopes_sparse
+    from predictionio_tpu.models.sequence import latent_moe, model as seq_model
+    from predictionio_tpu.ops import sparse_attention as sa
+
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "seq"))
+    config = latent_moe.LatentMoEConfig(
+        num_items=16_159, max_len=8192, hidden_size=2048, num_layers=5, dense_layers=1,
+        num_heads=32, q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64, value_dim=128,
+        ffn_dim=7168, expert_dim=768, num_experts=256, experts_per_token=8,
+        experts_held=(0, 16), shared_expert_dim=768, batch_size=2)
+    assert latent_moe.count_params(config) == 680_439_808
+    # the forward program as the step traces it at these shapes: eight heads a step
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16)
+    traced = jax.make_jaxpr(lambda q, k, v: sa._forward(
+        q, k, v, None, sa.BLOCK_Q, sa.BLOCK_K, False))(q, q, v)
+    grids = [eqn.params["grid_mapping"].grid for eqn in traced.jaxpr.eqns
+             if eqn.primitive.name == "pallas_call"]
+    assert grids == [(2, 4, 8192 // sa.BLOCK_Q, 8192 // sa.BLOCK_K)] == [(2, 4, 32, 16)], grids
+    _, _, step_fn, seq_shard = seq_model.make_fit(config, mesh)
+    rep = NamedSharding(mesh, P())
+    sds = lambda shape, dtype, sh: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)  # noqa: E731
+    params = jax.tree_util.tree_map(
+        lambda shape: sds(shape, jnp.float32, rep), latent_moe.param_shapes(config),
+        is_leaf=lambda x: isinstance(x, tuple))
+    opt_state = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype, rep),
+        jax.eval_shape(seq_model.optimizer_of(config).init, params))
+    # Adam keeps two moments for every trained leaf and none for the biases
+    moments = [a for a in jax.tree_util.tree_leaves(opt_state) if a.ndim]
+    assert sum(int(np.prod(a.shape)) for a in moments) == 2 * 680_439_808
+    batch = {k: sds((2, 8192), jnp.int32, seq_shard) for k in ("seq", "target")}
+    compiled = step_fn.lower(params, opt_state, batch, sds((2,), jnp.uint32, rep)).compile()
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert 14.0e9 < peak < 15.6e9, peak       # 15.18 GB
+    text = compiled.as_text()
+    calls = [c for c in re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text) if "seq." in c]
+    # the dense layer, the scan's body and the module: each forward, again, dq and dkv
+    assert len(calls) == 12 and all(scopes_leaf.place_of(c).leaf == "kernel" for c in calls)
+    kinds = [scopes_seq.kernel_kind(c) for c in calls]
+    assert kinds.count("forward") == 6 and kinds.count("backward") == 6
+    assert sum("mtp" in scopes_latent.places_of(c) for c in calls) == 4
+    assert not [c for c in calls if scopes_sparse.parse_stage(c) in ("index", "select")]
+    # the held experts: the sparse backbone's passes, XLA's own ragged dot
+    assert re.search(r"%ragged-dot-none(?:\.\d+)? = [^\n]*tpu_custom_call", text)
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    places = {place for name in names for place in scopes_latent.places_of(name)}
+    assert places == {"q_latent", "kv_latent", "mtp"}
+    under_module = {scopes_seq.parse_scope(n)[1] for n in names
+                    if "mtp" in scopes_latent.places_of(n)}
+    assert {"attention", "exit", "layers"} <= under_module
+    assert any("/seq.optimizer/bias/" in n for n in names)
+    seen = {(p.stage, p.leaf) for p in map(scopes_leaf.place_of, names) if p and p.leaf}
+    assert {("attention", leaf) for leaf in ("norm", "qkv", "rope", "kernel", "out")} <= seen
+    assert {("mlp", "norm"), ("moe", "norm"), ("experts", "grouped")} <= seen
