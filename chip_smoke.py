@@ -53,8 +53,8 @@ SEED = 0
 #: what the sparse backbone's ``seq_fit:`` line says beside the losses
 SPARSE_FIT_FACTS = ("experts_total", "experts_held", "experts_per_token", "index_topk",
                     "moe_assignments", "moe_held_assignments", "moe_held_load_max",
-                    "moe_dropped", "moe_passes", "moe_passes_run", "selected_pairs",
-                    "causal_pairs", "selection_kept_bytes")
+                    "moe_dropped", "moe_passes", "moe_passes_run", "moe_sum_rows",
+                    "moe_sum_slots", "selected_pairs", "causal_pairs", "selection_kept_bytes")
 #: and the hybrid backbone's: its layers by kind and what the delta rule carries
 HYBRID_FIT_FACTS = ("experts_shared", "linear_layers", "full_layers", "delta_chunk",
                     "delta_heads_per_step", "delta_state_bytes", "delta_kept_bytes")
@@ -340,6 +340,8 @@ class Smoke:
             facts.update({k: float(said[k])
                           for k in SPARSE_FIT_FACTS + HYBRID_FIT_FACTS + LATENT_FIT_FACTS
                           if k in said})
+            if "moe_sum" in said:          # how the experts' rows come back: a word
+                facts["moe_sum"] = said["moe_sum"]
         timings = re.search(r"stage timings: (.*)$", text, re.M)
         if timings:
             facts["stage_timings"] = timings.group(1).strip()
@@ -362,6 +364,20 @@ class Smoke:
         if missing:
             raise PhaseFailed(f"{name}: the compiled step lacks the leaf scopes {missing}")
         return res
+
+    def rows_come_back(self, name: str, facts: dict, leaves: dict) -> None:
+        """The held experts' rows come back onto their tokens by runs where the
+        package's programs run (``ops/run_sum.py``: a pass's rows read once,
+        never more than the tokens' slots, the program under the leaf ``sum``
+        forward and backward) and by position elsewhere, and no assignment is
+        dropped either way."""
+        by_runs = self.device["platform"] == "tpu"
+        if (facts.get("moe_sum") != ("runs" if by_runs else "positions")
+                or facts.get("moe_dropped") != 0
+                or not 0 < facts.get("moe_sum_rows", 0) <= facts.get("moe_sum_slots", 0)):
+            raise PhaseFailed(f"{name}: how the experts' rows come back: {facts}")
+        if leaves["sum_programs"] != (["backward", "forward"] if by_runs else []):
+            raise PhaseFailed(f"{name}: the programs under experts/sum: {leaves['sum_programs']}")
 
     def query_all(self, url: str, queries: list[dict]) -> tuple[list, float]:
         answers, lat = [], []
@@ -705,6 +721,7 @@ class Smoke:
         if not leaves["again_backward"] or leaves["again_forward"]:
             raise PhaseFailed(f"train_sequence_sparse_moe: `again` not in the backward pass"
                               f" alone: {leaves}")
+        self.rows_come_back("train_sequence_sparse_moe", facts, leaves)
         self.line("train_sequence_sparse_moe", t0, **facts, users=8, events=int(users.size),
                   max_len=max_len, leaf_scopes=len(leaves["leaves"]),
                   again_in_backward=leaves["again_backward"], **widths)
@@ -781,6 +798,9 @@ class Smoke:
         first, last = facts["first_loss"], facts["last_loss"]
         if not (first == first and last == last and last < first < float("inf")):
             raise PhaseFailed(f"train_sequence_hybrid_linear: loss not finite and falling: {first} -> {last}")
+        leaves = self.step_leaves("sequence_hybrid_linear_leaves", algorithm, max_len,
+                                  {"experts": SPARSE_LEAVES["experts"]})
+        self.rows_come_back("train_sequence_hybrid_linear", facts, leaves)
         self.line("train_sequence_hybrid_linear", t0, **facts, users=4, events=int(users.size),
                   max_len=max_len, **widths)
 
@@ -864,6 +884,7 @@ class Smoke:
         if not (first == first and last == last and last < first < float("inf")):
             raise PhaseFailed(f"train_sequence_latent_moe: loss not finite and falling: {first} -> {last}")
         leaves = self.step_leaves("sequence_latent_moe_leaves", algorithm, max_len, LATENT_LEAVES)
+        self.rows_come_back("train_sequence_latent_moe", facts, leaves)
         self.line("train_sequence_latent_moe", t0, **facts, users=4, events=int(users.size),
                   max_len=max_len, leaf_scopes=len(leaves["leaves"]), **widths)
 
@@ -1071,10 +1092,11 @@ def child_sequence_step_leaves(params: dict) -> dict:
     """One optimizer step of the sequence template at the engine parameters
     ``algorithm`` (2,000 items), compiled for this device: for every
     ``stage/leaf`` of ``want`` the phases (``forward``, ``backward``) in which
-    the compiled text carries the leaf scope under its stage, and how often
-    ``again`` shows in either. A program served from the compile cache is read
-    as it was served: the cache's key has to cover the names
-    (``utils/platform.configure_compile_cache``)."""
+    the compiled text carries the leaf scope under its stage, how often
+    ``again`` shows in either, and the phases in which a device program
+    (``tpu_custom_call``) lies under ``experts/.../sum``. A program served
+    from the compile cache is read as it was served: the cache's key has to
+    cover the names (``utils/platform.configure_compile_cache``)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1103,8 +1125,11 @@ def child_sequence_step_leaves(params: dict) -> dict:
         if stage in parts and leaf in parts[parts.index(stage):]})
         for stage, wanted in params["want"].items() for leaf in wanted}
     again = [phase(name) for name, parts in names if "again" in parts]
+    programs = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
+    under_sum = sorted({phase(name) for name in programs
+                        if "/experts/" in name and "/sum/" in name})
     return {"device": rep, "leaves": leaves, "again_forward": again.count("forward"),
-            "again_backward": again.count("backward")}
+            "again_backward": again.count("backward"), "sum_programs": under_sum}
 
 
 def _load_model(engine_dir: str, instance_id: str):

@@ -364,7 +364,7 @@ def _full_attention(c: HybridConfig, backend: str, rope, h, p):
     return out.reshape(b, t, -1)
 
 
-def _experts(c: HybridConfig, x, p, real):
+def _experts(c: HybridConfig, backend: str, x, p, real):
     """``(x', stats)``: the held routed experts' part and the shared expert
     added to the residual stream ``x`` [B, T, D]."""
     dtype = jnp.dtype(c.compute_dtype)
@@ -372,7 +372,7 @@ def _experts(c: HybridConfig, x, p, real):
         with jax.named_scope(looped.SCOPE_NORM):
             u = _norm0(x, p["n2"], c.rms_eps)
         flat = u.reshape(-1, u.shape[-1])
-        y, stats = sparse_moe._moe(c, flat, p, real.reshape(-1))
+        y, stats = sparse_moe._moe(c, backend, flat, p, real.reshape(-1))
         with jax.named_scope(SCOPE_SHARED):
             inner = (jax.nn.silu(looped._matmul(flat, p["s_gate"], dtype))
                      * looped._matmul(flat, p["s_up"], dtype))
@@ -412,7 +412,7 @@ def hidden_states(c: HybridConfig, backend: str, params, seq):
         x = jnp.take(params["embed"], seq, axis=0)
 
     kept = jax.checkpoint if c.remat else (lambda half: half)
-    experts = kept(lambda x, p: _experts(c, x, p, real))
+    experts = kept(lambda x, p: _experts(c, backend, x, p, real))
     linear_mixer = kept(lambda x, p: _linear_mixer(c, backend, real, x, p))
     full_mixer = kept(lambda x, p: _full_mixer(c, backend, rope, x, p))
 
@@ -460,6 +460,8 @@ def make_loss(c: HybridConfig, mesh):
                 "moe_dropped": stats["dropped"].sum(),
                 "moe_passes": stats["passes"].sum(),
                 "moe_passes_run": stats["passes_run"].sum(),
+                "moe_sum_rows": stats["sum_rows"].sum(),
+                "moe_sum_slots": stats["sum_slots"].sum(),
             }
             return ce + c.aux_coef * aux_loss, out
 

@@ -356,7 +356,7 @@ def _route(c: LatentMoEConfig, u, p, real, rows: int):
                             **sparse_moe.load_stats(c, load), "load": load}
 
 
-def _experts(c: LatentMoEConfig, x, p, real):
+def _experts(c: LatentMoEConfig, backend: str, x, p, real):
     """``(x', stats)``: the held routed experts' part and the shared expert
     added to the residual stream ``x`` [B, T, D]."""
     dtype = jnp.dtype(c.compute_dtype)
@@ -364,7 +364,7 @@ def _experts(c: LatentMoEConfig, x, p, real):
         with jax.named_scope(looped.SCOPE_NORM):
             u = looped._rms_norm(x, p["n2"], c.rms_eps)
         flat = u.reshape(-1, u.shape[-1])
-        y, stats = sparse_moe._moe(c, flat, p, real.reshape(-1),
+        y, stats = sparse_moe._moe(c, backend, flat, p, real.reshape(-1),
                                    functools.partial(_route, rows=x.shape[0]))
         with jax.named_scope(SCOPE_SHARED):
             y = y + _swiglu(flat, p["s_gate"], p["s_up"], p["s_down"], dtype)
@@ -378,7 +378,7 @@ def _halves(c: LatentMoEConfig, backend: str, rope):
     kept = jax.checkpoint if c.remat else (lambda half: half)
     return (kept(lambda x, p: _mixer(c, backend, rope, x, p)),
             kept(lambda x, p: _dense_mlp(c, x, p)),
-            kept(lambda x, p, real: _experts(c, x, p, real)))
+            kept(lambda x, p, real: _experts(c, backend, x, p, real)))
 
 
 def hidden_states(c: LatentMoEConfig, backend: str, params, seq):
@@ -461,6 +461,8 @@ def make_loss(c: LatentMoEConfig, mesh):
                 "moe_dropped": stats["dropped"].sum(),
                 "moe_passes": stats["passes"].sum(),
                 "moe_passes_run": stats["passes_run"].sum(),
+                "moe_sum_rows": stats["sum_rows"].sum(),
+                "moe_sum_slots": stats["sum_slots"].sum(),
                 "router_load": stats["load"],
             }
             return ce + c.mtp_coef * mtp_ce + c.balance_coef * balance, out

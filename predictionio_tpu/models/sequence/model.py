@@ -331,7 +331,8 @@ def train_sasrec(
     aux = {}
     first_loss = loss = None
     span_attrs = fit_attrs(config, _tree_bytes(params), _tree_bytes(opt_state),
-                           min(config.batch_size, n) // dp * dp)
+                           min(config.batch_size, n) // dp * dp,
+                           mesh.devices.flat[0].platform)
     with global_tracer().span("seq.fit", attrs=span_attrs) as span:
         for _ in range(config.epochs):
             order = np_rng.permutation(n)
@@ -375,7 +376,7 @@ def train_sasrec(
 
 #: the span's attributes the ``seq_fit:`` line repeats (a backbone that has them)
 _FIT_LINE_ATTRS = ("experts_total", "experts_held", "experts_per_token", "experts_shared",
-                   "index_topk", "kv_heads", "selection_kept_bytes", "linear_layers",
+                   "moe_sum", "index_topk", "kv_heads", "selection_kept_bytes", "linear_layers",
                    "full_layers", "delta_chunk", "delta_heads_per_step", "delta_state_bytes",
                    "delta_kept_bytes", "dense_layers", "mtp_depth", "latent_q_rank",
                    "latent_kv_rank", "score_width", "value_width", "latent_bytes_per_token",
@@ -386,9 +387,10 @@ _BACKBONES = {LoopedConfig: "looped", SparseMoEConfig: "sparse_moe",
 _EXPERTS = (SparseMoEConfig, HybridConfig, LatentMoEConfig)
 
 
-def fit_attrs(config, param_bytes: int, opt_state_bytes: int, rows: int) -> dict:
+def fit_attrs(config, param_bytes: int, opt_state_bytes: int, rows: int,
+              platform: str) -> dict:
     """What the fit's span says of the model it trains and of how a step on
-    ``rows`` rows is worked."""
+    ``rows`` rows is worked on ``platform``."""
     attrs = {
         "backbone": _BACKBONES.get(type(config), "sasrec"),
         "param_bytes": param_bytes,
@@ -411,7 +413,8 @@ def fit_attrs(config, param_bytes: int, opt_state_bytes: int, rows: int) -> dict
         if isinstance(config, _EXPERTS):
             attrs.update(
                 experts_total=config.num_experts, experts_held=config.held,
-                experts_per_token=config.experts_per_token, kv_heads=config.num_kv_heads)
+                experts_per_token=config.experts_per_token, kv_heads=config.num_kv_heads,
+                moe_sum=sparse_moe.sum_path(config, platform))
         if isinstance(config, SparseMoEConfig):
             attrs.update(
                 index_topk=config.index_topk,

@@ -68,11 +68,16 @@ with none of this):
   held row is skipped at run time (``lax.cond``), forward and backward. An
   even router takes one pass a layer, a skewed one as many as it needs, and
   with every expert held the bound is the worst case. Rows come from their
-  tokens by a gather and go back by a gather too: a token sums, by the
-  position the sort gave each of its assignments, its rows of the pass's
-  short array (``_sum_by_position``; a scatter of rows cost the chip more
-  than the whole of this, PERF.md PR 33). The experts' part keeps its
-  operands alone and is worked again in the backward pass;
+  tokens by a gather and go back by a gather too (a scatter of rows cost the
+  chip more than the whole of this, PERF.md PR 33). Where the package's
+  programs run (``sum_path``: the platform decides, as for attention) a pass's
+  ``R`` rows are gathered once into token order and one program adds each
+  token's run (``ops/run_sum.py``, PERF.md PR 41): the same float32 sum of
+  float32 rows times float32 gates. Elsewhere a token sums, by the position
+  the sort gave each of its ``K`` assignments, its rows of the pass's short
+  array (``_sum_by_position``, which the tests hold the program to). The
+  experts' part keeps its operands alone and is worked again in the backward
+  pass;
 - the head and loss are ``looped._exit_ce``'s chunks of positions.
 """
 
@@ -87,6 +92,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from predictionio_tpu.models.sequence import looped
+from predictionio_tpu.ops import run_sum
 from predictionio_tpu.ops import sparse_attention as sa
 
 #: Device scopes of a training step beside ``looped``'s (``seq.embed``,
@@ -105,10 +111,12 @@ SCOPE_EXPERTS = "experts"
 #: argsorts, the group sizes and the passes' plan; ``take`` the gather of a
 #: pass's rows from their tokens and its transpose; ``grouped`` the three
 #: grouped matmuls and the gated product between them; ``give`` the rows back
-#: onto their tokens and its transpose; ``sum`` the sum by position, inside
-#: ``give`` forward and inside ``take`` backward. ``again`` marks the forward
-#: work a backward rule runs again: a ``custom_vjp`` rule's recomputation
-#: carries no ``rematted_computation``, so the program says it.
+#: onto their tokens and its transpose; ``sum`` a token's sum of its rows (by
+#: runs: the gather into token order and the program; else by position), inside
+#: ``give`` forward and inside ``take`` backward; the runs' plan is ``sort``'s.
+#: ``again`` marks the forward work a backward rule runs again: a
+#: ``custom_vjp`` rule's recomputation carries no ``rematted_computation``, so
+#: the program says it.
 SCOPE_SORT = "sort"
 SCOPE_TAKE = "take"
 SCOPE_GROUPED = "grouped"
@@ -256,6 +264,12 @@ def uses_kernels(c: SparseMoEConfig, backend: str) -> bool:
     return c.attention == "flash" or (c.attention == "auto" and backend == "tpu")
 
 
+def sum_path(c, backend: str) -> str:
+    """How a pass's rows come back onto their tokens: ``"runs"`` where the
+    package's programs run (``ops/run_sum.py``), else ``"positions"``."""
+    return "runs" if uses_kernels(c, backend) else "positions"
+
+
 # ---- attention over the indexer's selection ----------------------------------
 
 def _pack_rows(mask):
@@ -379,44 +393,71 @@ def _sum_by_position(rows, pos, weight):
         return y.reshape(-1, y.shape[-1])[:n]
 
 
-@jax.custom_vjp
-def _take_rows(u, token, live, pos, mine):
+def _sum_by_runs(rows, weight, runs, shape, interpret, *, unit, dtype=jnp.float32):
+    """``_sum_by_position``'s sum for ``shape = (n, K)`` with the weights by
+    row, ``weight`` [R] (0 past the live rows), over the pass's rows in token
+    order (``runs``, ``run_sum.plan``'s): one gather of ``R`` rows and one
+    program, under the same scope. ``unit``: the weights are 0 and 1 alone."""
+    n, slots = shape
+    with jax.named_scope(SCOPE_SUM):
+        return run_sum.sum_runs(rows, weight, runs, n, slots, unit=unit, out_dtype=dtype,
+                                interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _take_rows(interpret, u, token, live, pos, mine, runs):
     """Row ``r`` of a pass takes its token, ``u[token[r]]``; rows past the
-    pass's ``live`` ones are 0. The transpose sums a token's rows by position."""
+    pass's ``live`` ones are 0. The transpose sums a token's rows, by runs
+    where the pass brings them (``runs``), else by position."""
     return jnp.where(live, u[token], 0)
 
 
-_take_rows.defvjp(
-    lambda u, token, live, pos, mine: (_take_rows(u, token, live, pos, mine), (pos, mine)),
-    lambda res, g: (_sum_by_position(g, res[0], res[1].astype(jnp.float32)).astype(g.dtype),
-                    None, None, None, None))
+def _take_rows_bwd(interpret, res, g):
+    live, pos, mine, runs = res
+    if runs is None:
+        d_u = _sum_by_position(g, pos, mine.astype(jnp.float32)).astype(g.dtype)
+    else:
+        d_u = _sum_by_runs(g, live[:, 0], runs, pos.shape, interpret, unit=True, dtype=g.dtype)
+    return d_u, None, None, None, None, None
 
 
-@jax.custom_vjp
-def _give_back(out, gates, row, live, pos, mine):
+_take_rows.defvjp(lambda interpret, *args: (_take_rows(interpret, *args), args[2:]),
+                  _take_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _give_back(interpret, out, gates, row, live, pos, mine, runs):
     """A token's sum of its rows of the pass, ``out`` [R, D], each times the
     gate of its assignment: ``[n, D]`` float32. ``row`` [R] is a row's
     assignment, an index into ``gates`` [n, K]. The transpose gathers ``R``
     rows of ``dy`` and, for the gates, a row dot: no scatter either way."""
-    return _sum_by_position(out, pos, jnp.where(mine, gates, 0.0))
+    if runs is None:
+        return _sum_by_position(out, pos, jnp.where(mine, gates, 0.0))
+    by_row = jnp.where(live[:, 0], gates.reshape(-1)[row], 0.0)
+    return _sum_by_runs(out, by_row, runs, pos.shape, interpret, unit=False)
 
 
-def _give_back_bwd(res, dy):
-    out, gates, row, live, pos, mine = res
+def _give_back_bwd(interpret, res, dy):
+    out, gates, row, live, pos, mine, _ = res
     sent = dy[row // mine.shape[1]]                                # [R, D]
     d_gate = jnp.where(live[:, 0], (out * sent).sum(axis=-1), 0.0)
     return (jnp.where(live, sent * gates.reshape(-1)[row][:, None], 0.0),
-            jnp.where(mine, d_gate[pos], 0.0), None, None, None, None)
+            jnp.where(mine, d_gate[pos], 0.0), None, None, None, None, None)
 
 
-_give_back.defvjp(lambda *args: (_give_back(*args), args), _give_back_bwd)
+_give_back.defvjp(lambda interpret, *args: (_give_back(interpret, *args), args),
+                  _give_back_bwd)
 
 
-def _one_pass(u, gates, w_gate, w_up, w_down, back, row, sizes, start):
+def _one_pass(interpret, u, gates, w_gate, w_up, w_down, back, row, sizes, start):
     """What one pass adds to the tokens ``[n, D]``: the sorted rows ``[start,
     start + R)``, the assignments ``row`` [R], of which the first
     ``sizes.sum()`` are held (``sizes`` [held]: the pass's share of each
-    expert's rows), through the three grouped matmuls and back."""
+    expert's rows), through the three grouped matmuls and back. ``interpret``
+    None: the rows come back by position; else by runs (``ops/run_sum.py``, its
+    program interpreted or compiled), the pass's rows put in token order once
+    for both sums."""
+    n, slots = gates.shape
     worked = sizes.sum()
     # rows past the held ones belong to no group: whatever a grouped matmul
     # leaves there goes no further, forward or backward
@@ -424,15 +465,20 @@ def _one_pass(u, gates, w_gate, w_up, w_down, back, row, sizes, start):
     pos = back - start
     mine = (pos >= 0) & (pos < worked)
     pos = jnp.where(mine, pos, 0)
+    token = row // slots
+    runs = None
+    if interpret is not None:
+        with jax.named_scope(SCOPE_SORT):
+            runs = run_sum.plan(jnp.where(live[:, 0], token, run_sum.NO_TOKEN), n)
     dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
                             preferred_element_type=jnp.float32)
     with jax.named_scope(SCOPE_TAKE):
-        x = _take_rows(u, row // gates.shape[1], live, pos, mine)     # [R, D]
+        x = _take_rows(interpret, u, token, live, pos, mine, runs)             # [R, D]
     with jax.named_scope(SCOPE_GROUPED):
         inner = jax.nn.silu(dot(x, w_gate)) * dot(x, w_up)
         out = dot(inner.astype(u.dtype), w_down)
     with jax.named_scope(SCOPE_GIVE):
-        return _give_back(out, gates, row, live, pos, mine)
+        return _give_back(interpret, out, gates, row, live, pos, mine, runs)
 
 
 def _over_passes(plans, run, zeros):
@@ -455,31 +501,32 @@ def _over_passes(plans, run, zeros):
     return jax.lax.scan(one, total, at(slice(1, None)))[0]
 
 
-@jax.custom_vjp
-def _passes(operands, back, plans):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _passes(interpret, operands, back, plans):
     """``_one_pass`` over the passes, for ``operands = (u, gates, w_gate, w_up,
     w_down)``: ``[n, D]`` float32. Only the operands are kept: the backward
     pass works each pass it runs again, and skips the same passes."""
-    return _over_passes(plans, lambda plan: _one_pass(*operands, back, *plan),
+    return _over_passes(plans, lambda plan: _one_pass(interpret, *operands, back, *plan),
                         lambda: jnp.zeros(operands[0].shape, jnp.float32))
 
 
-def _passes_bwd(res, dy):
+def _passes_bwd(interpret, res, dy):
     operands, back, plans = res
 
     def pulled(plan):
         with jax.named_scope(SCOPE_AGAIN):
-            pull = jax.vjp(lambda *a: _one_pass(*a, back, *plan), *operands)[1]
+            pull = jax.vjp(lambda *a: _one_pass(interpret, *a, back, *plan), *operands)[1]
         return pull(dy)
 
     return (_over_passes(plans, pulled, lambda: tuple(jnp.zeros_like(a) for a in operands)),
             None, None)
 
 
-_passes.defvjp(lambda *args: (_passes(*args), args), _passes_bwd)
+_passes.defvjp(lambda interpret, *args: (_passes(interpret, *args), args), _passes_bwd)
 
 
-def _experts_chunk(c: SparseMoEConfig, w_gate, w_up, w_down, u, experts, gates, real):
+def _experts_chunk(c: SparseMoEConfig, interpret, w_gate, w_up, w_down, u, experts, gates,
+                   real):
     """The held experts' part of the layer for a chunk of tokens: ``u`` [n, D]
     bfloat16, ``experts``, ``gates`` [n, K], ``real`` [n] -> ``(y [n, D]
     float32, rows worked, passes run)``. The chunk's ``n K`` assignments are
@@ -501,7 +548,7 @@ def _experts_chunk(c: SparseMoEConfig, w_gate, w_up, w_down, u, experts, gates, 
         clip = lambda edge: jnp.clip(edge[None, :], starts[:, None], starts[:, None] + bound)  # noqa: E731
         pass_sizes = clip(ends) - clip(ends - sizes)                   # [passes, held]
         rows = jnp.pad(order, (0, max(0, passes * bound - order.size)))[:passes * bound]
-    y = _passes((u, gates, w_gate, w_up, w_down), back,
+    y = _passes(interpret, (u, gates, w_gate, w_up, w_down), back,
                 (rows.reshape(passes, bound), pass_sizes, starts))
     worked = pass_sizes.sum(axis=1)
     return y, worked.sum(), (worked > 0).sum()
@@ -539,28 +586,37 @@ def _route(c: SparseMoEConfig, u, p, real):
     return experts, gates, {"aux": aux, **load_stats(c, load)}
 
 
-def _held_experts(c: SparseMoEConfig, u, p, experts, gates, real, stats):
+def _held_experts(c: SparseMoEConfig, backend: str, u, p, experts, gates, real, stats):
     """``(y, stats)``: the held experts' part of the routed sum for the normed
     tokens ``u`` [N, D] under a router's choice (``experts``, ``gates``
-    [N, K]), ``stats`` gaining what the passes did. A padded slot (``real``
-    [N]) is routed nowhere."""
+    [N, K]), ``stats`` gaining what the passes did and what their forward sums
+    read. A padded slot (``real`` [N]) is routed nowhere."""
     dtype = jnp.dtype(c.compute_dtype)
     n = u.shape[0]
     chunk = min(moe_chunk_of(c), n)
+    by_runs = sum_path(c, backend) == "runs"
     work = jax.checkpoint(functools.partial(
-        _experts_chunk, c, p["w_gate"].astype(dtype), p["w_up"].astype(dtype),
-        p["w_down"].astype(dtype)))
+        _experts_chunk, c, (backend != "tpu") if by_runs else None,
+        p["w_gate"].astype(dtype), p["w_up"].astype(dtype), p["w_down"].astype(dtype)))
     y, rows, ran = jax.lax.map(lambda args: work(*args), tuple(
         _cut(a, chunk) for a in (u.astype(dtype), experts, gates, real)))
     stats["dropped"] = stats["held_assignments"] - rows.sum()
-    stats["passes"] = jnp.int32(len(rows) * pass_plan(c, chunk)[1])   # chunks x passes
+    bound, passes = pass_plan(c, chunk)
+    slots = chunk * c.experts_per_token
+    stats["passes"] = jnp.int32(len(rows) * passes)                   # chunks x passes
     stats["passes_run"] = ran.sum()
+    # a pass's forward sum reads its ``R`` rows once by runs, every token's
+    # ``K`` positions otherwise
+    stats["sum_rows"] = ran.sum() * (bound if by_runs else slots)
+    stats["sum_slots"] = ran.sum() * slots
     return y.reshape(-1, y.shape[-1])[:n], stats
 
 
-def _moe(c: SparseMoEConfig, u, p, real, route=_route):
+def _moe(c: SparseMoEConfig, backend: str, u, p, real, route=_route):
     """``(y, stats)``: the held experts' part of the routed sum for the normed
-    tokens ``u`` [N, D], and the layer's counts. ``route(c, u, p, real)`` is
+    tokens ``u`` [N, D], and the layer's counts. ``backend`` is the platform
+    the layer runs on, which decides how a pass's rows come back
+    (``sum_path``). ``route(c, u, p, real)`` is
     the layer's router, under ``moe/route`` (this backbone's and the hybrid's
     is the softmax ``_route``; the latent backbone brings its own); the held
     experts' work under ``moe/experts`` is the same for all. ``real`` [N]: a
@@ -568,7 +624,7 @@ def _moe(c: SparseMoEConfig, u, p, real, route=_route):
     with jax.named_scope(SCOPE_ROUTE):
         experts, gates, stats = route(c, u, p, real)
     with jax.named_scope(SCOPE_EXPERTS):
-        return _held_experts(c, u, p, experts, gates, real, stats)
+        return _held_experts(c, backend, u, p, experts, gates, real, stats)
 
 
 # ---- the stack ---------------------------------------------------------------
@@ -585,7 +641,7 @@ def _layer(c: SparseMoEConfig, backend: str, rope, real, x, p, ip, probe=None):
     with jax.named_scope(SCOPE_MOE):
         with jax.named_scope(looped.SCOPE_NORM):
             u = looped._rms_norm(x, p["n2"], c.rms_eps)
-        y, routed = _moe(c, u.reshape(-1, u.shape[-1]), p, real.reshape(-1))
+        y, routed = _moe(c, backend, u.reshape(-1, u.shape[-1]), p, real.reshape(-1))
         return x + y.reshape(x.shape), {**stats, **routed}
 
 
@@ -641,6 +697,8 @@ def make_loss(c: SparseMoEConfig, mesh):
                 "moe_dropped": stats["dropped"].sum(),
                 "moe_passes": stats["passes"].sum(),
                 "moe_passes_run": stats["passes_run"].sum(),
+                "moe_sum_rows": stats["sum_rows"].sum(),
+                "moe_sum_slots": stats["sum_slots"].sum(),
                 "selected_pairs": stats["selected_pairs"].sum(),
                 "causal_pairs": stats["causal_pairs"].sum(),
             }

@@ -2,11 +2,14 @@
 only. Every leaf is in the program under its stage, forward and (for the
 sequence steps) in the backward pass, as ``benchmarks/scopes_leaf.py`` takes an
 ``op_name`` apart; ``again`` marks the experts' forward half of the backward
-rule and nothing else; and the same builder with ``jax.named_scope`` patched out
+rule and nothing else; with the package's programs (interpreted here) a pass's
+rows come back by runs, under the same leaves, ``take``'s backward pass all
+``sum``; and the same builder with ``jax.named_scope`` patched out
 in the test (the program has no switch) gives the same loss, gradients and
 updated state bit for bit."""
 
 import contextlib
+import dataclasses
 import re
 
 import jax
@@ -30,6 +33,8 @@ CONFIGS = {
         num_items=50, max_len=T, num_layers=2, batch_size=ROWS, experts_held=(0, 4),
         index_topk=8, moe_chunk=32, attention="plain"),
 }
+#: the same step with the package's programs: the experts' rows back by runs
+CONFIGS["sparse_moe-programs"] = dataclasses.replace(CONFIGS["sparse_moe"], attention="flash")
 #: (stage, leaf) -> the phases that hold it. The indexer and the selection pass
 #: no gradient and are not worked again (the rematerialised layer starts from the
 #: kept bits); the sort is integers; a pass's sum back onto its tokens is
@@ -43,6 +48,8 @@ LEAVES = {
         ("moe", "norm"): ALL, ("experts", "sort"): ALL[:2], ("experts", "take"): ALL,
         ("experts", "grouped"): ALL, ("experts", "give"): ALL[::2], ("experts", "sum"): ALL[::2]},
 }
+#: by runs the transpose of ``take`` is one sum that writes the compute dtype
+LEAVES["sparse_moe-programs"] = LEAVES["sparse_moe"] | {("experts", "take"): ALL[:2]}
 
 
 def _mesh():
@@ -97,6 +104,9 @@ def test_again_marks_the_forward_half_of_the_experts_backward_rule(op_names):
     pulled = [n for n in names if f"transpose({sparse_moe.SCOPE_AGAIN})" in n]
     assert pulled and all(scopes_leaf.place_of(n).phase == "backward" for n in pulled)
     assert {scopes_leaf.place_of(n).leaf for n in pulled} == {"take", "give", "sum"}
+    by_runs = [scopes_leaf.place_of(n) for n in op_names["sparse_moe-programs"]
+               if f"transpose({sparse_moe.SCOPE_AGAIN})" in n]
+    assert {p.leaf for p in by_runs if p is not None} == {"give", "sum"}
     assert not [n for n in names if sparse_moe.SCOPE_AGAIN in n and "transpose(" not in n]
     assert not [n for n in op_names["looped"] if sparse_moe.SCOPE_AGAIN in n.split("/")]
 

@@ -284,8 +284,8 @@ def test_a_grid_step_takes_the_row_heads_that_divide_and_fit():
     config = _config(max_len=8192, linear_value_heads=32, linear_key_dim=128,
                      linear_value_dim=128, compute_dtype="bfloat16", delta_chunk=64)
     assert hybrid.delta_heads_per_step(config, 2) == 8
-    assert fit_attrs(config, 4, 8, 2)["delta_heads_per_step"] == 8
-    assert fit_attrs(_config(), 4, 8, 3)["delta_heads_per_step"] == 4     # 3 rows x 4 heads
+    assert fit_attrs(config, 4, 8, 2, "cpu")["delta_heads_per_step"] == 8
+    assert fit_attrs(_config(), 4, 8, 3, "cpu")["delta_heads_per_step"] == 4     # 3 rows x 4 heads
 
 
 def test_a_position_without_beta_or_decay_leaves_the_state():
@@ -355,9 +355,9 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_whole_layer():
         for lo in range(0, 8, 2):
             config = _config(experts_held=(lo, lo + 2))
             share = {**drawn, **{k: drawn[k][lo:lo + 2] for k in ("w_gate", "w_up", "w_down")}}
-            with_shared, stats = hybrid._experts(config, x, share, real)
+            with_shared, stats = hybrid._experts(config, "cpu", x, share, real)
             alone = {**share, "s_down": jnp.zeros_like(drawn["s_down"])}
-            without, _ = hybrid._experts(config, x, alone, real)
+            without, _ = hybrid._experts(config, "cpu", x, alone, real)
             assert int(stats["dropped"]) == 0
             held += int(stats["held_assignments"])
             routed = routed + (without - x)
@@ -391,7 +391,7 @@ def test_the_engine_takes_the_backbone_at_the_cells_sizes():
     # a row's states, 3 layers x 32 heads x 128 x 128 float32; a layer's chunks' for 2 rows
     assert hybrid.delta_state_bytes(config) == 3 * 32 * 128 * 128 * 4
     assert hybrid.delta_kept_bytes(config, 2) == 2 * 128 * 32 * 128 * 128 * 4
-    attrs = fit_attrs(config, 4, 8, 2)
+    attrs = fit_attrs(config, 4, 8, 2, "cpu")
     assert (attrs["backbone"], attrs["linear_layers"], attrs["full_layers"], attrs["delta_chunk"],
             attrs["delta_heads_per_step"], attrs["experts_shared"]) == (
                 "hybrid_linear", 3, 1, 64, 8, 1)

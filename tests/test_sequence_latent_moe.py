@@ -305,9 +305,9 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_whole_layer():
         for lo in range(0, 32, 2):
             config = _config(num_experts=32, experts_per_token=4, experts_held=(lo, lo + 2))
             share = {**drawn, **{k: drawn[k][lo:lo + 2] for k in ("w_gate", "w_up", "w_down")}}
-            with_shared, stats = latent_moe._experts(config, x, share, real)
+            with_shared, stats = latent_moe._experts(config, "cpu", x, share, real)
             alone = {**share, "s_down": jnp.zeros_like(drawn["s_down"])}
-            without, _ = latent_moe._experts(config, x, alone, real)
+            without, _ = latent_moe._experts(config, "cpu", x, alone, real)
             assert int(stats["dropped"]) == 0
             held += int(stats["held_assignments"])
             routed = routed + (without - x)
@@ -361,12 +361,13 @@ def test_the_softmax_router_is_what_it_was_beside_the_split():
     config = _config(experts_held=(2, 6))
     share = {**drawn, **{k: drawn[k][2:6] for k in ("w_gate", "w_up", "w_down")}}
     with jax.default_matmul_precision("highest"):
-        y, stats = sparse_moe._moe(config, x[0], share, real[0])
+        y, stats = sparse_moe._moe(config, "cpu", x[0], share, real[0])
         probs = jax.nn.softmax(x[0] @ drawn["router"], axis=-1)
     top = np.asarray(jax.lax.top_k(probs, 2)[1])[:90]
     load = np.bincount(top.reshape(-1), minlength=8)
     assert sorted(stats) == ["assignments", "aux", "dropped", "held_assignments",
-                             "held_load_max", "passes", "passes_run"]
+                             "held_load_max", "passes", "passes_run", "sum_rows",
+                             "sum_slots"]
     assert int(stats["assignments"]) == 180 and int(stats["dropped"]) == 0
     assert int(stats["held_assignments"]) == load[2:6].sum()
     assert int(stats["held_load_max"]) == load[2:6].max()
@@ -496,7 +497,7 @@ def test_the_engine_takes_the_backbone_at_the_cells_sizes():
     assert sparse_moe.moe_chunk_of(config) >= 16384
     assert sparse_moe.pass_plan(config, 16384)[0] >= 2 * 16384 * 8 * 16 // 256
     assert sa.heads_per_step(config.num_kv_heads, 1) == 8
-    attrs = fit_attrs(config, 4, 8, 2)
+    attrs = fit_attrs(config, 4, 8, 2, "cpu")
     assert (attrs["backbone"], attrs["layers"], attrs["dense_layers"], attrs["mtp_depth"],
             attrs["experts_shared"], attrs["experts_total"], attrs["experts_held"],
             attrs["experts_per_token"]) == ("latent_moe", 5, 1, 1, 1, 256, 16, 8)

@@ -6,7 +6,9 @@ several times ``index_topk`` long; the three programs of
 ``ops/sparse_attention.py`` in interpret mode against their plain twins; the
 k-th largest against ``jax.lax.top_k``, ties included; the eight shares of a
 layer add up to the whole layer; the experts' passes under an even, a skewed,
-a whole and an empty routing; the selection kept from the forward pass, one bit
+a whole and an empty routing, their rows brought back by position and by runs
+(``ops/run_sum.py`` in interpret mode against ``_sum_by_position``, a float32
+sum of float32 rows); the selection kept from the forward pass, one bit
 a pair, and read by the rematerialised layer in place of working it again;
 the engine takes the backbone by name."""
 
@@ -22,6 +24,7 @@ from predictionio_tpu.models.sequence.model import (
     make_fit, score_next_items_batch, train_sasrec,
 )
 from predictionio_tpu.models.sequence.sparse_moe import SparseMoEConfig
+from predictionio_tpu.ops import run_sum
 from predictionio_tpu.ops import sparse_attention as sa
 
 VOCAB, T, ROWS, TOPK = 256, 64, 3, 16
@@ -305,7 +308,7 @@ def test_the_eight_shares_add_up_to_the_whole_layer(params):
         config = _config(experts_held=(e, e + 1))
         share = {"router": drawn["router"],
                  **{k: drawn[k][e:e + 1] for k in ("w_gate", "w_up", "w_down")}}
-        y, stats = sparse_moe._moe(config, u, share, real)
+        y, stats = sparse_moe._moe(config, "cpu", u, share, real)
         assert int(stats["dropped"]) == 0
         held += int(stats["held_assignments"])
         total = total + y
@@ -319,8 +322,14 @@ ROUTINGS = {"even": ((2, 4), 186, 1), "skewed": ((2, 4), 192, 3),
             "all-held": ((0, 16), 186, 1), "none-held": ((2, 4), 186, 0)}
 
 
+#: how a pass's rows come back onto their tokens -> the ``attention`` that asks for it
+SUM_PATHS = {"positions": "plain", "runs": "flash"}
+
+
+@pytest.mark.parametrize("path", list(SUM_PATHS))
 @pytest.mark.parametrize("routing", list(ROUTINGS))
-def test_the_passes_give_the_references_output_and_gradients_whatever_the_router_sends(routing):
+def test_the_passes_give_the_references_output_and_gradients_whatever_the_router_sends(
+        routing, path):
     """192 tokens, 2 of 16 experts a token, experts 2 and 3 held: a pass works
     128 sorted rows (twice the even share of 48, in whole 128s) and three
     passes cover the worst case of 384. **even**: a seeded router, one pass
@@ -331,10 +340,13 @@ def test_the_passes_give_the_references_output_and_gradients_whatever_the_router
     bound is the worst case, one pass. **none-held**: no token is sent to a
     held expert: no pass runs, zeros out, finite gradients. Output and the
     gradients to the tokens, the router and the three expert matrices against
-    ``reference_keye.experts_part``."""
+    ``reference_keye.experts_part``, with the rows brought back by position
+    and by runs (the program interpreted)."""
     held, n_real, run = ROUTINGS[routing]
     n = 3 * T
-    config = _config(num_experts=16, experts_held=held, moe_chunk=None)
+    config = _config(num_experts=16, experts_held=held, moe_chunk=None,
+                     attention=SUM_PATHS[path])
+    assert sparse_moe.sum_path(config, "cpu") == path
     dims = {**DIMS, "experts_held": held}
     assert sparse_moe.moe_chunk_of(config) >= n      # one chunk of tokens
     assert sparse_moe.pass_plan(config, n) == ((384, 1) if routing == "all-held" else (128, 3))
@@ -353,7 +365,7 @@ def test_the_passes_give_the_references_output_and_gradients_whatever_the_router
     real = jnp.asarray(np.arange(n) < n_real)
 
     def program(u, layer):
-        y, stats = sparse_moe._moe(config, u, layer, real)
+        y, stats = sparse_moe._moe(config, "cpu", u, layer, real)
         return (y * weight).sum(), (y, stats)
 
     def reference(u, layer):
@@ -367,6 +379,10 @@ def test_the_passes_give_the_references_output_and_gradients_whatever_the_router
             jax.value_and_grad(reference, (0, 1), has_aux=True))(u, layer)
     assert int(stats["dropped"]) == 0
     assert (int(stats["passes"]), int(stats["passes_run"])) == (1 if routing == "all-held" else 3, run)
+    # what the forward sums read: a pass's rows by runs, every token's slots by position
+    bound = sparse_moe.pass_plan(config, n)[0]
+    assert int(stats["sum_slots"]) == run * n * 2
+    assert int(stats["sum_rows"]) == run * (bound if path == "runs" else n * 2)
     sent = np.isin(np.asarray(experts)[:n_real], np.arange(*held)).sum()
     assert int(stats["held_assignments"]) == sent
     if routing == "skewed":
@@ -383,6 +399,138 @@ def test_the_passes_give_the_references_output_and_gradients_whatever_the_router
         assert np.isfinite(have[name]).all(), name
         assert np.abs(have[name] - g).max() <= 1e-4 * max(np.abs(g).max(), 1e-6), name
         assert routing == "none-held" or np.abs(g).max() > 0, name
+
+
+def _a_pass(n, slots, rows, live, pattern, seed):
+    """A pass as ``_one_pass`` holds it, drawn: ``live`` of its ``rows`` rows
+    belong each to another slot of the ``n`` tokens' ``slots``. ``(row, pos,
+    mine)``: a row's assignment ``[rows]``, and for every slot the row that
+    holds it and whether one does. ``pattern`` "full": token 3 holds a row in
+    every slot and tokens 2 and 4 none."""
+    rng = np.random.default_rng(seed)
+    free = np.arange(n * slots).reshape(n, slots)
+    if pattern == "full":
+        taken = np.concatenate([free[3], rng.choice(
+            np.delete(free, [2, 3, 4], axis=0).reshape(-1), live - slots, replace=False)])
+    else:
+        taken = rng.choice(free.reshape(-1), live, replace=False)
+    row = np.zeros(rows, np.int32)
+    row[:live] = rng.permutation(taken)          # the sort's order is by expert, not by token
+    row[live:] = rng.integers(0, n * slots, rows - live)
+    pos, mine = np.zeros(n * slots, np.int32), np.zeros(n * slots, bool)
+    pos[row[:live]], mine[row[:live]] = np.arange(live), True
+    return row, pos.reshape(n, slots), mine.reshape(n, slots)
+
+
+#: (tokens, slots a token, rows a pass, live rows, pattern): the three cells'
+#: ``R / n`` and ``K`` with about half the rows live, then the edges. At 640
+#: tokens and 800 rows the blocks are 256 x 256: three token blocks, a last row
+#: block of 32 rows, and row blocks that hold rows of two token blocks
+RUN_SHAPES = {
+    "sparse-cell": (512, 8, 1024, 500, "random"),
+    "hybrid-cell": (512, 10, 640, 330, "random"),
+    "latent-cell": (512, 8, 512, 250, "random"),
+    "ragged-rows": (640, 8, 800, 700, "random"),
+    "every-row-live": (320, 4, 384, 384, "random"),
+    "a-token-with-every-slot": (64, 8, 128, 40, "full"),
+    "no-live-row": (320, 8, 512, 0, "random"),
+}
+
+
+@pytest.mark.parametrize("rows_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("weighted", [True, False], ids=["gates", "unit"])
+@pytest.mark.parametrize("shape", list(RUN_SHAPES))
+def test_the_sum_by_runs_is_the_sum_by_position(shape, weighted, rows_dtype):
+    """``_sum_by_runs`` (one gather of the pass's rows into token order, one
+    program, interpreted here) against ``_sum_by_position`` on a drawn pass:
+    weighted by the gates as ``_give_back`` sums, by 0 and 1 as ``_take_rows``'
+    transpose does; the rows past the live ones hold NaN, as a grouped matmul
+    may leave them. A float32 row arrives whole: the two sums differ by the
+    order of a token's additions alone, and the same rows rounded to bfloat16
+    on their way are another result, far outside that."""
+    n, slots, rows, live, pattern = RUN_SHAPES[shape]
+    row, pos, mine = _a_pass(n, slots, rows, live, pattern, seed=len(shape))
+    rng = np.random.default_rng(3)
+    values = rng.standard_normal((rows, 128)).astype(np.float32)
+    values[live:] = np.nan
+    values = jnp.asarray(values).astype(rows_dtype)
+    gates = (jnp.asarray(rng.uniform(0.05, 1.0, (n, slots)), jnp.float32) if weighted
+             else jnp.ones((n, slots), jnp.float32))
+    is_live = jnp.arange(rows) < live
+    want = np.asarray(sparse_moe._sum_by_position(
+        values, jnp.asarray(pos), jnp.where(jnp.asarray(mine), gates, 0.0)))
+    runs = run_sum.plan(jnp.where(is_live, jnp.asarray(row) // slots, run_sum.NO_TOKEN), n)
+    by_row = jnp.where(is_live, gates.reshape(-1)[jnp.asarray(row)], 0.0)
+    by_runs = lambda v: np.asarray(sparse_moe._sum_by_runs(  # noqa: E731
+        v, by_row, runs, (n, slots), True, unit=not weighted))
+    have = by_runs(values)
+    assert have.shape == want.shape == (n, 128) and have.dtype == np.float32
+    held = mine.sum(axis=1)
+    assert not have[held == 0].any() and (held == 0).any()       # a token with no row: zeros
+    close = lambda a: np.abs(a - want).max() <= 2e-6 * max(np.abs(want).max(), 1.0)  # noqa: E731
+    assert close(have), np.abs(have - want).max()
+    if live and rows_dtype == "float32":
+        rounded = values.astype(jnp.bfloat16).astype(jnp.float32)
+        assert not close(by_runs(rounded))
+    if live:
+        assert np.abs(want).max() > 1.0
+    # the shapes hold what their names say
+    tb, rb = run_sum.blocks_of(n, rows)
+    spans = np.asarray(runs.count)
+    if shape == "ragged-rows":
+        assert (tb, rb, rows % rb, len(spans)) == (256, 256, 32, 3) and runs.perm.shape == (1024,)
+        # a row block shared by two token blocks: the spans overlap
+        assert spans.sum() > len(np.unique(np.concatenate(
+            [f + np.arange(c) for f, c in zip(np.asarray(runs.first), spans)])))
+    if shape == "a-token-with-every-slot":
+        assert held[3] == slots and held[2] == held[4] == 0
+    if shape == "no-live-row":
+        assert not spans.any() and not have.any()
+    if shape == "every-row-live":
+        assert held.sum() == rows
+
+
+@pytest.mark.parametrize("compute_dtype", ["float32", "bfloat16"])
+def test_a_pass_gives_the_same_output_and_gradients_by_runs_and_by_position(compute_dtype):
+    """``_one_pass`` with its rows brought back by position and by runs, on
+    the two passes a skewed routing takes (``_experts_chunk``: 96 tokens,
+    experts 2 and 3 of 16 held, every token's first choice expert 2 and every
+    other token's second expert 3: 135 rows in passes of 128): the output and the
+    gradients to the tokens, the gates and the three expert matrices. In
+    float32 the two differ by the order of a token's additions; with bfloat16
+    rows the backward sum is rounded once, after the float32 sum, both ways."""
+    config = _config(compute_dtype=compute_dtype, num_experts=16, experts_held=(2, 4))
+    dtype = jnp.dtype(compute_dtype)
+    rng = np.random.default_rng(8)
+    n, slots = 96, 2
+    u = jnp.asarray(rng.standard_normal((n, 32)), jnp.float32).astype(dtype)
+    second = np.where(np.arange(n) % 2, 3, rng.integers(4, 16, n))
+    experts = np.stack([np.full(n, 2), second], axis=1).astype(np.int32)
+    gates = jnp.asarray(rng.uniform(0.2, 0.8, (n, slots)), jnp.float32)
+    real = jnp.asarray(np.arange(n) < 90)
+    shapes = sparse_moe.param_shapes(config)["layers"]
+    drawn = seeded_histories.make_params(
+        {k: shapes[k][1:] for k in ("w_gate", "w_up", "w_down")}, seed=21)
+    weights = tuple(jnp.asarray(drawn[k] * 5).astype(dtype) for k in ("w_gate", "w_up", "w_down"))
+    weight = jnp.asarray(rng.standard_normal((n, 32)), jnp.float32)
+
+    def run(interpret):
+        def program(u, gates, *weights):
+            y, worked, ran = sparse_moe._experts_chunk(
+                config, interpret, *weights, u, jnp.asarray(experts), gates, real)
+            return (y * weight).sum(), (y, worked, ran)
+        return jax.jit(jax.value_and_grad(program, (0, 1, 2, 3, 4), has_aux=True))(
+            u, gates, *weights)
+
+    (_, (want_y, worked, ran)), want = run(None)
+    (_, (have_y, worked_too, ran_too)), have = run(True)
+    assert int(ran) == int(ran_too) == 2 and int(worked) == int(worked_too) == 90 + 45
+    assert sparse_moe.pass_plan(config, n) == (128, 2)
+    tolerance = 2e-6 if compute_dtype == "float32" else 1e-2
+    for a, b in zip((have_y, *have), (want_y, *want)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(b).max() > 0 and np.isfinite(a).all()
+        assert np.abs(a - b).max() <= tolerance * np.abs(b).max()
 
 
 # ---- the template ------------------------------------------------------------

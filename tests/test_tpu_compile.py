@@ -74,12 +74,14 @@ def _kernel_instructions(text: str, name: str) -> list[str]:
 #: libtpu 0.0.34). A change that shares code with them (PR 37: the experts'
 #: passes, the attention programs that gained a mode without a mask) leaves
 #: both programs as they were, instruction for instruction; one that means to
-#: change a step re-measures its cell and writes the new digest here
+#: change a step re-measures its cell and writes the new digest here (PR 41:
+#: the sparse cell's, whose experts' rows come back by runs; the looped one's
+#: is PR 36's still)
 ACCEPTED_STEPS = {
     "ouro-2.6b-d8.train-histories":
         "7849910ae58d05dc248f996c7c3e71238090eae70f3d76ad3486b9b4d5c342ef",
     "keye-vl2-30b-a3b-ep8.train-lifelong-histories":
-        "e60f828da966cc0e25a6890def91fc059bb85c8b791fbb37e5477956d0559e2e",
+        "26e9048e75bdca7b628882f22ab0ac7fed327ef5d42695ce9660c14c9389637d",
 }
 
 
@@ -636,7 +638,9 @@ def test_the_lifelong_histories_cells_step_fits_the_chip_at_6_layers_and_scopes_
     8,192 at the published widths, 16 of 128 experts held, an eighth of the
     vocabulary) at the 6 layers ``benchmarks/configs/keye-vl2-30b-a3b-ep8.json``
     holds: Mosaic takes the three programs of ``ops/sparse_attention.py`` at
-    that size, the grouped matmuls lower to the chip's own ragged dot, no row
+    that size and ``ops/run_sum.py``'s for a pass of 32,768 rows onto 16,384
+    tokens (float32 rows forward, bfloat16 ones backward), the grouped matmuls
+    lower to the chip's own ragged dot, no row
     of the experts' path is scattered, the peak is under the chip's 15.75 GB,
     and every program sits under the scope the benchmark's reader looks for.
     The selection is worked once: the index and select programs stand in the
@@ -655,6 +659,10 @@ def test_the_lifelong_histories_cells_step_fits_the_chip_at_6_layers_and_scopes_
         experts_held=(0, 16), num_layers=6, index_heads=16, index_dim=64, index_topk=2048,
         batch_size=2)
     assert sparse_moe.count_params(config) == 659_187_712
+    # the run sum: 64 blocks of 256 tokens, each over at most the 9 row blocks
+    # of 256 that 256 x 8 rows can span
+    assert sparse_moe.pass_plan(config, 16384) == (32768, 4)
+    assert _run_sum_grid(16384, 8, 32768) == (64, 9)
     _, _, step_fn, seq_shard = seq_model.make_fit(config, mesh)
     rep = NamedSharding(mesh, P())
     sds = lambda shape, dtype, sh: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)  # noqa: E731
@@ -667,7 +675,7 @@ def test_the_lifelong_histories_cells_step_fits_the_chip_at_6_layers_and_scopes_
     batch = {k: sds((2, 8192), jnp.int32, seq_shard) for k in ("seq", "target")}
     compiled = step_fn.lower(params, opt_state, batch, sds((2,), jnp.uint32, rep)).compile()
     peak = compiled.memory_analysis().peak_memory_in_bytes
-    assert 13.5e9 < peak < 15.5e9, peak       # 15.04 GB (14.96 before the selection was kept)
+    assert 13.5e9 < peak < 15.5e9, peak       # 14.97 GB (15.04 with the sum by position)
     text = compiled.as_text()
     assert _digest(text) == ACCEPTED_STEPS["keye-vl2-30b-a3b-ep8.train-lifelong-histories"]
     calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
@@ -690,8 +698,9 @@ def test_the_lifelong_histories_cells_step_fits_the_chip_at_6_layers_and_scopes_
         r"(%ragged-dot-none(?:\.\d+)?) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
     assert len(grouped) == 24, grouped
     assert all(scopes_sparse.stage_of(g, "ragged-dot-none") == "experts" for g in grouped)
-    # rows come back onto their tokens by gathers: a scatter of rows cost the
-    # chip more than the whole of the experts (PERF.md PR 33). The step's two
+    # rows come back onto their tokens by a gather into token order and a
+    # program that sums each token's run: a scatter of rows cost the chip more
+    # than the whole of the experts (PERF.md PR 33). The step's two
     # scatters are the embedding's gradient and the transpose of the router's
     # top-k (scalars into [tokens x experts]); XLA leaves some without a name
     scatters = re.findall(r'= (\S+?)\{\S* scatter\(([^\n]*)', text)
@@ -702,8 +711,9 @@ def test_the_lifelong_histories_cells_step_fits_the_chip_at_6_layers_and_scopes_
     # gradient, in the forward pass alone (the packing lies under ``select``,
     # the unpacking under ``kernel``, forward and recomputed);
     # the experts' rows and grouped matmuls forward and again, the sum back onto
-    # the tokens not again (the backward of ``take`` is its ``sum``: the cast
-    # after it is fused away); every attention program under its leaf
+    # the tokens not again (the backward of ``take`` is its ``sum``, which
+    # writes the compute dtype itself); every program under its leaf, the sum's
+    # in the first pass and in the scanned body, forward and backward
     seen = {(p.stage, p.leaf, p.phase) for p in _places(text)}
     every = ("forward", "recomputed", "backward")
     want = {("attention", leaf): every for leaf in ("norm", "qkv", "rope", "kernel", "out")}
@@ -717,8 +727,42 @@ def test_the_lifelong_histories_cells_step_fits_the_chip_at_6_layers_and_scopes_
     again = re.findall(r'op_name="([^"]*seq\.[^"]*/again/[^"]*)"', text)
     assert again and all("transpose(jvp(seq.pass1))" in name and "/moe/experts/" in name
                          for name in again)
-    assert {scopes_leaf.place_of(c).leaf for c in calls if "seq." in c} == {
-        "index", "select", "kernel"}
+    programs = [scopes_leaf.place_of(c) for c in calls if "seq." in c]
+    assert {p.leaf for p in programs} == {"index", "select", "kernel", "sum"}
+    assert sorted(p.phase for p in programs if (p.stage, p.leaf) == ("experts", "sum")) == [
+        "backward", "backward", "forward", "forward"]
+
+
+def _run_sum_grid(n: int, slots: int, rows: int) -> tuple:
+    """The grid of ``ops/run_sum.py``'s program as a step traces it for a pass
+    of ``rows`` rows onto ``n`` tokens of ``slots`` slots: blocks from the
+    shapes alone."""
+    from predictionio_tpu.ops import run_sum
+
+    traced = jax.make_jaxpr(lambda v, w, t: run_sum.sum_runs(
+        v, w, run_sum.plan(t, n), n, slots, unit=False))(
+            jax.ShapeDtypeStruct((rows, 2048), jnp.float32),
+            jax.ShapeDtypeStruct((rows,), jnp.float32), jax.ShapeDtypeStruct((rows,), jnp.int32))
+    (grid,) = [eqn.params["grid_mapping"].grid for eqn in traced.jaxpr.eqns
+               if eqn.primitive.name == "pallas_call"]
+    return grid
+
+
+def _the_sums_are_programs(text: str, calls: list, each: int) -> None:
+    """Of a compiled step's device programs (``calls``: their ``op_name``s),
+    ``each`` lie under ``experts/.../sum`` forward and as many backward, every
+    other program of the step lies under ``attention`` or the delta rule, and
+    no scatter lies under ``moe/experts``."""
+    import re
+
+    from benchmarks import scopes_leaf
+
+    places = [scopes_leaf.place_of(c) for c in calls if "seq." in c]
+    sums = [p.phase for p in places if (p.stage, p.leaf) == ("experts", "sum")]
+    assert sorted(sums) == ["backward"] * each + ["forward"] * each, sums
+    assert {p.stage for p in places if p.leaf != "sum"} <= {"attention", "layers"}
+    scatters = re.findall(r'= \S+?\{\S* scatter\(([^\n]*)', text)
+    assert scatters and not [rest for rest in scatters if "moe/experts" in rest]
 
 
 def test_the_hybrid_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persistent_cache):
@@ -727,15 +771,16 @@ def test_the_hybrid_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
     full one, 32 of 512 experts held, an eighth of the vocabulary): Mosaic takes
     the delta rule's state pass and its transpose at 128 chunks of 64, blocks
     of 8 of the 64 row-heads a grid step (a grid of 8 x 128), and the
-    attention programs with no mask operand at head width 256, the peak is
+    attention programs with no mask operand at head width 256, and the run
+    sum's for a pass of 20,480 rows of 10 slots a token, the peak is
     under the chip's 15.75 GB, and every program and every leaf sits under the
     scope the benchmark's readers look for. A linear mixer's state pass stands
     forward, recomputed and (its transpose) backward; the full layer's
     attention forward, recomputed and as ``dq`` and ``dkv``."""
     import re
 
-    from benchmarks import scopes_hybrid, scopes_seq, scopes_sparse
-    from predictionio_tpu.models.sequence import hybrid, model as seq_model
+    from benchmarks import scopes_hybrid, scopes_leaf, scopes_seq, scopes_sparse
+    from predictionio_tpu.models.sequence import hybrid, model as seq_model, sparse_moe
     from predictionio_tpu.ops import delta_rule
 
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "seq"))
@@ -747,6 +792,8 @@ def test_the_hybrid_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
         experts_per_token=10, experts_held=(0, 32), shared_expert_dim=512, batch_size=2)
     assert hybrid.count_params(config) == 625_667_136
     assert hybrid.delta_heads_per_step(config, 2) == 8
+    assert sparse_moe.pass_plan(config, 16384) == (20480, 8)
+    assert _run_sum_grid(16384, 10, 20480) == (64, 11)
     _, _, step_fn, seq_shard = seq_model.make_fit(config, mesh)
     rep = NamedSharding(mesh, P())
     sds = lambda shape, dtype, sh: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)  # noqa: E731
@@ -781,8 +828,11 @@ def test_the_hybrid_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
     kinds = [scopes_seq.kernel_kind(c) for c in calls]
     assert kinds.count("forward") == 2 and kinds.count("backward") == 2
     assert not [c for c in calls if scopes_sparse.parse_stage(c) in ("index", "select")]
-    # the held experts: the sparse backbone's passes, XLA's own ragged dot
+    # the held experts: the sparse backbone's passes, XLA's own ragged dot, and
+    # the rows back by runs (the linear layers' scan and the full layer, each
+    # the first pass and the scanned body): programs under ``sum``, no scatter
     assert re.search(r"%ragged-dot-none(?:\.\d+)? = [^\n]*tpu_custom_call", text)
+    _the_sums_are_programs(text, calls, 4)
     places = {scopes_hybrid.place_of(name) for name in re.findall(r'op_name="([^"]*)"', text)}
     assert {leaf for kind, leaf in places - {None} if kind == "linear"} >= set(
         scopes_hybrid.LEAVES)
@@ -799,12 +849,13 @@ def test_the_latent_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
     chip's 15.75 GB, and every program and every new scope sits where the
     benchmark's readers look for it: the attention forward, recomputed and as
     ``dq`` and ``dkv`` in the dense layer, in the scanned expert layers and
-    under ``mtp``; the two latent paths inside ``qkv``; the bias's move under
-    ``seq.optimizer``."""
+    under ``mtp``; the run sum's programs for a pass of 16,384 rows, as many as
+    tokens, in the expert layers and the module; the two latent paths inside
+    ``qkv``; the bias's move under ``seq.optimizer``."""
     import re
 
     from benchmarks import scopes_latent, scopes_leaf, scopes_seq, scopes_sparse
-    from predictionio_tpu.models.sequence import latent_moe, model as seq_model
+    from predictionio_tpu.models.sequence import latent_moe, model as seq_model, sparse_moe
     from predictionio_tpu.ops import sparse_attention as sa
 
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "seq"))
@@ -822,6 +873,8 @@ def test_the_latent_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
     grids = [eqn.params["grid_mapping"].grid for eqn in traced.jaxpr.eqns
              if eqn.primitive.name == "pallas_call"]
     assert grids == [(2, 4, 8192 // sa.BLOCK_Q, 8192 // sa.BLOCK_K)] == [(2, 4, 32, 16)], grids
+    assert sparse_moe.pass_plan(config, 16384) == (16384, 8)
+    assert _run_sum_grid(16384, 8, 16384) == (64, 9)
     _, _, step_fn, seq_shard = seq_model.make_fit(config, mesh)
     rep = NamedSharding(mesh, P())
     sds = lambda shape, dtype, sh: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)  # noqa: E731
@@ -841,11 +894,16 @@ def test_the_latent_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
     text = compiled.as_text()
     calls = [c for c in re.findall(
         r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text) if "seq." in c]
-    # the dense layer, the scan's body and the module: each forward, again, dq and dkv
-    assert len(calls) == 12 and all(scopes_leaf.place_of(c).leaf == "kernel" for c in calls)
+    # the dense layer, the scan's body and the module: each forward, again, dq
+    # and dkv; the experts' rows back by runs in the scan's body and the module
+    attention = [c for c in calls if scopes_leaf.place_of(c).stage == "attention"]
+    assert len(attention) == 12 and all(
+        scopes_leaf.place_of(c).leaf == "kernel" for c in attention)
+    _the_sums_are_programs(text, calls, 4)
     kinds = [scopes_seq.kernel_kind(c) for c in calls]
     assert kinds.count("forward") == 6 and kinds.count("backward") == 6
-    assert sum("mtp" in scopes_latent.places_of(c) for c in calls) == 4
+    assert sum("mtp" in scopes_latent.places_of(c) for c in attention) == 4
+    assert sum("mtp" in scopes_latent.places_of(c) for c in calls) == 8      # and its four sums
     assert not [c for c in calls if scopes_sparse.parse_stage(c) in ("index", "select")]
     # the held experts: the sparse backbone's passes, XLA's own ragged dot
     assert re.search(r"%ragged-dot-none(?:\.\d+)? = [^\n]*tpu_custom_call", text)
