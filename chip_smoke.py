@@ -55,6 +55,8 @@ SPARSE_FIT_FACTS = ("experts_total", "experts_held", "experts_per_token", "index
                     "moe_assignments", "moe_held_assignments", "moe_held_load_max",
                     "moe_dropped", "moe_passes", "moe_passes_run", "moe_sum_rows",
                     "moe_sum_slots", "selected_pairs", "causal_pairs", "selection_kept_bytes")
+#: the three streamed backbones': how a layer's attention is transposed
+ATTENTION_FIT_FACTS = ("attention_backward_programs", "attention_backward_heads_per_step")
 #: and the hybrid backbone's: its layers by kind and what the delta rule carries
 HYBRID_FIT_FACTS = ("experts_shared", "linear_layers", "full_layers", "delta_chunk",
                     "delta_heads_per_step", "delta_state_bytes", "delta_kept_bytes")
@@ -338,7 +340,8 @@ class Smoke:
                          last_loss=float(said["last_loss"]))
             # what the sparse backbone adds to the line: its share and its counts
             facts.update({k: float(said[k])
-                          for k in SPARSE_FIT_FACTS + HYBRID_FIT_FACTS + LATENT_FIT_FACTS
+                          for k in (SPARSE_FIT_FACTS + ATTENTION_FIT_FACTS + HYBRID_FIT_FACTS
+                                    + LATENT_FIT_FACTS)
                           if k in said})
             if "moe_sum" in said:          # how the experts' rows come back: a word
                 facts["moe_sum"] = said["moe_sum"]
@@ -378,6 +381,21 @@ class Smoke:
             raise PhaseFailed(f"{name}: how the experts' rows come back: {facts}")
         if leaves["sum_programs"] != (["backward", "forward"] if by_runs else []):
             raise PhaseFailed(f"{name}: the programs under experts/sum: {leaves['sum_programs']}")
+
+    def one_backward_program(self, name: str, facts: dict, leaves: dict) -> None:
+        """Where the package's programs run, a block's attention is transposed
+        by one program (``ops/sparse_attention.py``: ``dq``, ``dk`` and ``dv``
+        from a tile's scores formed once): the fit says so, and the compiled
+        step holds under ``attention/kernel`` one backward program to every two
+        forward ones (the pass and the pass worked again). Elsewhere none."""
+        on_chip = self.device["platform"] == "tpu"
+        programs = leaves["attention_programs"]
+        if (facts.get("attention_backward_programs") != int(on_chip)
+                or not facts.get("attention_backward_heads_per_step", 0) >= 1):
+            raise PhaseFailed(f"{name}: how the attention's backward pass is worked: {facts}")
+        if (2 * programs["backward"] != programs["forward"]
+                or (programs["backward"] >= 1) != on_chip):
+            raise PhaseFailed(f"{name}: the programs under attention/kernel: {programs}")
 
     def query_all(self, url: str, queries: list[dict]) -> tuple[list, float]:
         answers, lat = [], []
@@ -722,6 +740,7 @@ class Smoke:
             raise PhaseFailed(f"train_sequence_sparse_moe: `again` not in the backward pass"
                               f" alone: {leaves}")
         self.rows_come_back("train_sequence_sparse_moe", facts, leaves)
+        self.one_backward_program("train_sequence_sparse_moe", facts, leaves)
         self.line("train_sequence_sparse_moe", t0, **facts, users=8, events=int(users.size),
                   max_len=max_len, leaf_scopes=len(leaves["leaves"]),
                   again_in_backward=leaves["again_backward"], **widths)
@@ -801,6 +820,7 @@ class Smoke:
         leaves = self.step_leaves("sequence_hybrid_linear_leaves", algorithm, max_len,
                                   {"experts": SPARSE_LEAVES["experts"]})
         self.rows_come_back("train_sequence_hybrid_linear", facts, leaves)
+        self.one_backward_program("train_sequence_hybrid_linear", facts, leaves)
         self.line("train_sequence_hybrid_linear", t0, **facts, users=4, events=int(users.size),
                   max_len=max_len, **widths)
 
@@ -885,6 +905,7 @@ class Smoke:
             raise PhaseFailed(f"train_sequence_latent_moe: loss not finite and falling: {first} -> {last}")
         leaves = self.step_leaves("sequence_latent_moe_leaves", algorithm, max_len, LATENT_LEAVES)
         self.rows_come_back("train_sequence_latent_moe", facts, leaves)
+        self.one_backward_program("train_sequence_latent_moe", facts, leaves)
         self.line("train_sequence_latent_moe", t0, **facts, users=4, events=int(users.size),
                   max_len=max_len, leaf_scopes=len(leaves["leaves"]), **widths)
 
@@ -1093,8 +1114,10 @@ def child_sequence_step_leaves(params: dict) -> dict:
     ``algorithm`` (2,000 items), compiled for this device: for every
     ``stage/leaf`` of ``want`` the phases (``forward``, ``backward``) in which
     the compiled text carries the leaf scope under its stage, how often
-    ``again`` shows in either, and the phases in which a device program
-    (``tpu_custom_call``) lies under ``experts/.../sum``. A program served
+    ``again`` shows in either, the phases in which a device program
+    (``tpu_custom_call``) lies under ``experts/.../sum``, and how many lie under
+    ``attention/.../kernel`` forward (the pass worked again in the backward
+    pass is a forward program) and backward. A program served
     from the compile cache is read as it was served: the cache's key has to
     cover the names (``utils/platform.configure_compile_cache``)."""
     import jax
@@ -1128,8 +1151,11 @@ def child_sequence_step_leaves(params: dict) -> dict:
     programs = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
     under_sum = sorted({phase(name) for name in programs
                         if "/experts/" in name and "/sum/" in name})
+    attention = ["forward" if "rematted_computation" in name else phase(name)
+                 for name in programs if "/attention/" in name and "/kernel/" in name]
     return {"device": rep, "leaves": leaves, "again_forward": again.count("forward"),
-            "again_backward": again.count("backward"), "sum_programs": under_sum}
+            "again_backward": again.count("backward"), "sum_programs": under_sum,
+            "attention_programs": {k: attention.count(k) for k in ("forward", "backward")}}
 
 
 def _load_model(engine_dir: str, instance_id: str):

@@ -62,8 +62,9 @@ mathematics with none of this):
   state every chunk starts from is held (``delta_kept_bytes``); between the
   passes nothing of the rule is;
 - the full layer's attention is ``ops/sparse_attention.causal_attention``: the
-  sparse backbone's three programs (8 query heads a key-value head, K and V
-  streamed) with no mask operand; off the TPU its plain twin;
+  sparse backbone's attention programs, forward and one backward (8 query
+  heads a key-value head, K and V streamed), with no mask operand; off the TPU
+  its plain twin;
 - matmul inputs are ``compute_dtype`` (bfloat16) with float32 accumulation;
   the state, ``g``, ``beta``, the l2 norms, the triangular system, the router,
   norms, rotary positions, softmax, residual stream, loss, master weights and
@@ -286,6 +287,13 @@ def delta_heads_per_step(c: HybridConfig, rows: int) -> int:
     return delta_rule.heads_per_step(
         rows * c.linear_value_heads, c.delta_chunk, c.linear_key_dim, c.linear_value_dim,
         jnp.dtype(c.compute_dtype).itemsize)
+
+
+def attention_backward_heads_per_step(c: HybridConfig) -> int:
+    """The key-value heads a grid step of the full layer's backward attention
+    program works on a row of ``max_len``: the sparse backbone's grouped heads
+    with no mask operand."""
+    return sparse_moe.attention_backward_heads_per_step(c, masked=False)
 
 
 def _norm0(x, weight, eps):

@@ -262,6 +262,15 @@ def latent_bytes_per_token(c: LatentMoEConfig) -> int:
     return (c.kv_rank + c.rope_dim) * jnp.dtype(c.compute_dtype).itemsize
 
 
+def attention_backward_heads_per_step(c: LatentMoEConfig) -> int:
+    """The heads a grid step of the attention's backward program works on a row
+    of ``max_len``: each head has a key and a value of its own (from the shapes
+    alone)."""
+    return sa.backward_heads_per_step(
+        c.num_heads, 1, c.score_dim, c.value_dim, c.max_len,
+        jnp.dtype(c.compute_dtype).itemsize)
+
+
 # ---- latent attention --------------------------------------------------------
 
 def _rope_tables(t: int, dim: int, theta: float):

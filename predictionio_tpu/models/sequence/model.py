@@ -376,15 +376,19 @@ def train_sasrec(
 
 #: the span's attributes the ``seq_fit:`` line repeats (a backbone that has them)
 _FIT_LINE_ATTRS = ("experts_total", "experts_held", "experts_per_token", "experts_shared",
-                   "moe_sum", "index_topk", "kv_heads", "selection_kept_bytes", "linear_layers",
+                   "moe_sum", "attention_backward_programs", "attention_backward_heads_per_step",
+                   "index_topk", "kv_heads", "selection_kept_bytes", "linear_layers",
                    "full_layers", "delta_chunk", "delta_heads_per_step", "delta_state_bytes",
                    "delta_kept_bytes", "dense_layers", "mtp_depth", "latent_q_rank",
                    "latent_kv_rank", "score_width", "value_width", "latent_bytes_per_token",
                    "router_bias_leaves")
 _BACKBONES = {LoopedConfig: "looped", SparseMoEConfig: "sparse_moe",
               HybridConfig: "hybrid_linear", LatentMoEConfig: "latent_moe"}
-#: the backbones with routed experts of which the program holds a share
-_EXPERTS = (SparseMoEConfig, HybridConfig, LatentMoEConfig)
+#: the backbones with routed experts of which the program holds a share, whose
+#: attention is ``ops/sparse_attention``'s streamed programs
+_EXPERT_MODULES = {SparseMoEConfig: sparse_moe, HybridConfig: hybrid,
+                   LatentMoEConfig: latent_moe}
+_EXPERTS = tuple(_EXPERT_MODULES)
 
 
 def fit_attrs(config, param_bytes: int, opt_state_bytes: int, rows: int,
@@ -414,7 +418,12 @@ def fit_attrs(config, param_bytes: int, opt_state_bytes: int, rows: int,
             attrs.update(
                 experts_total=config.num_experts, experts_held=config.held,
                 experts_per_token=config.experts_per_token, kv_heads=config.num_kv_heads,
-                moe_sum=sparse_moe.sum_path(config, platform))
+                moe_sum=sparse_moe.sum_path(config, platform),
+                # the backward pass of a layer's attention: one program where
+                # the package's programs run, the plain twin's transpose elsewhere
+                attention_backward_programs=int(sparse_moe.uses_kernels(config, platform)),
+                attention_backward_heads_per_step=_EXPERT_MODULES[
+                    type(config)].attention_backward_heads_per_step(config))
         if isinstance(config, SparseMoEConfig):
             attrs.update(
                 index_topk=config.index_topk,
@@ -453,8 +462,7 @@ def _score_fn(config):
                     config, attention, params, seqs, last))
             return _SCORE_CACHE[config]
         if isinstance(config, _EXPERTS):
-            module = {SparseMoEConfig: sparse_moe, HybridConfig: hybrid,
-                      LatentMoEConfig: latent_moe}[type(config)]
+            module = _EXPERT_MODULES[type(config)]
             _SCORE_CACHE[config] = jax.jit(functools.partial(module.score_last, config))
             return _SCORE_CACHE[config]
         model = SASRec(config, None)

@@ -297,6 +297,15 @@ def selection_kept_bytes(c: SparseMoEConfig, rows: int) -> int:
     return c.num_layers * rows * -(-c.max_len // 8) * c.max_len if c.remat else 0
 
 
+def attention_backward_heads_per_step(c, masked: bool = True) -> int:
+    """The key-value heads a grid step of the attention's backward program works
+    on a row of ``max_len`` (``ops/sparse_attention``, from the shapes alone);
+    ``masked``: the selection's tile is one of the step's blocks."""
+    return sa.backward_heads_per_step(
+        c.num_kv_heads, c.num_heads // c.num_kv_heads, c.head_dim, c.head_dim, c.max_len,
+        jnp.dtype(c.compute_dtype).itemsize, masked)
+
+
 def _attention(c: SparseMoEConfig, backend: str, rope, h, p, ip, real, probe=None):
     """``(o, counts)``: the attention output before ``Wo`` ``[B, T, H x hd]``
     on the normed input ``h``, and what it selected. ``probe``: query

@@ -18,16 +18,26 @@ is what runs off the TPU and what the tests hold the programs to):
   of the block has more ties than places;
 - :func:`sparse_attention`: softmax attention of ``q`` ``[B, T, H, D]`` on
   ``k``, ``v`` ``[B, T, KV, D]`` (``H // KV`` query heads a key-value head)
-  over the pairs the mask selects, with the matching custom VJP. The grid is
+  over the pairs the mask selects, with the matching custom VJP: a forward
+  program and one backward program. The grid of both is
   ``(B, KV, query blocks, key blocks)``: a step holds one block of K and V and
-  one tile of the mask for all the query heads of the group, the running
-  (max, sum, acc) live in VMEM scratch across the key blocks, and blocks
-  above the diagonal are neither fetched nor worked. Matmul inputs are
-  bfloat16 with float32 accumulation; the softmax is float32.
+  one tile of the mask for all the query heads of the group, and blocks
+  above the diagonal are neither fetched nor worked. Forward, the running
+  (max, sum, acc) live in VMEM scratch across the key blocks. Backward, a
+  tile's scores, probabilities, ``dp = do v'`` and ``ds = p (dp - delta)``
+  are formed once and all three gradients taken from them, five dots a
+  tile: ``dq`` is the query block's output, summed over its key blocks;
+  ``dk`` and ``dv`` of the step's key-value heads are **held in VMEM over the
+  whole row** (float32 ``[T, D]`` and ``[T, DV]`` a head, one buffer each), a
+  tile adding into its key block's rows, and go to HBM once, when the grid
+  moves to the next key-value heads. Every accumulator is added to in the
+  order two separate programs would take: keys ascending for ``dq``, query
+  blocks ascending and heads ascending inside for ``dk`` and ``dv``. Matmul
+  inputs are bfloat16 with float32 accumulation; the softmax is float32.
 
 The mask is the whole contract of which pairs count: causality is in it (the
 selection takes causal keys only) and nothing else masks a pair.
-:func:`causal_attention` is the same three programs with no mask operand:
+:func:`causal_attention` is the same two programs with no mask operand:
 every causal pair counts, and a tile's mask is made from its positions. Rows are
 left-aligned, so a padded position follows every event of its row and no
 real query can select it; a padded query's output is never read.
@@ -38,7 +48,16 @@ the width that is carried: the two may differ (latent attention scores over
 with their query heads: one where a key-value head serves eight query heads or
 more, several where each serves few (with a key and value of its own for every
 query head, a step of one head would be mostly its own overhead, and its
-per-query scalars one lane wide).
+per-query scalars one lane wide). The backward program takes its own count,
+:func:`backward_heads_per_step`, from the shapes alone: the most of the same
+candidates whose ``dk`` and ``dv`` over the row fit ``VMEM_LIMIT_BYTES`` beside
+the step's blocks (:func:`backward_step_bytes`); at 8,192 positions one head
+of 128 + 128 or 256 + 256, four ungrouped heads of 192 + 128 where the forward
+program takes eight. Its tile of queries is twice the forward program's where
+that still fits (:func:`backward_query_block`: 512 in the first two cases, 256
+in the third). A row too long for one head's pair (past some 57,000 positions at
+128 + 128, 26,000 at 256 + 256) is refused when the step is traced, with the
+numbers.
 
 Layout (see ``flash_attention.py`` for what the hardware asks): tensors are
 ``[B, H, T, D]`` at the Pallas boundary; per-query scalars (logsumexp, delta)
@@ -91,11 +110,6 @@ def _params(*semantics):
 def _last_key_block(qi, bq: int, bk: int):
     """The last key block that holds a causal pair with query block ``qi``."""
     return (qi * bq + bq - 1) // bk
-
-
-def _first_query_block(ki, bq: int, bk: int):
-    """The first query block that holds a causal pair with key block ``ki``."""
-    return (ki * bk) // bq
 
 
 # ---- index scores -----------------------------------------------------------
@@ -334,92 +348,119 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref, m_scr, l_scr, a
             lse_ref[0, 0, :, h:h + 1] = m_scr[h] + jnp.log(l)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
-               *, bq: int, bk: int):
+def _bwd_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, *, bq: int, bk: int):
+    """One tile's scores, probabilities and ``ds``, and all three gradients
+    from them. ``dq_ref`` is the query block's sum over the key blocks;
+    ``dk_ref`` and ``dv_ref`` hold the step's key-value heads over the whole
+    row, a tile adding into its key block's rows."""
     qi, ki = pl.program_id(2), pl.program_id(3)
     group = q_ref.shape[1] // k_ref.shape[1]
 
+    @pl.when((qi == 0) & (ki == 0))
+    def _():
+        dk_ref[...] = jnp.zeros(dk_ref.shape, jnp.float32)
+        dv_ref[...] = jnp.zeros(dv_ref.shape, jnp.float32)
+
     @pl.when(ki == 0)
     def _():
-        dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
+        dq_ref[...] = jnp.zeros(dq_ref.shape, jnp.float32)
 
     @pl.when(ki <= _last_key_block(qi, bq, bk))
     def _():
+        rows = pl.ds(pl.multiple_of(ki * bk, bk), bk)
         for j in range(k_ref.shape[1]):
             k, v, on = k_ref[0, j], v_ref[0, j], _tile(mask_ref, qi, ki, bq, bk)
-            for h in range(j * group, (j + 1) * group):
-                s = _dot(q_ref[0, h], k, 1, 1)
-                p = jnp.where(on, jnp.exp(s - lse_ref[0, 0, :, h:h + 1]), 0.0)
-                ds = p * (_dot(do_ref[0, h], v, 1, 1) - delta_ref[0, 0, :, h:h + 1])
-                dq_scr[h] = dq_scr[h] + _dot(ds.astype(k.dtype), k, 1, 0)
-
-    @pl.when(ki == pl.num_programs(3) - 1)
-    def _():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, *scratch, bq: int, bk: int):
-    """``scratch``: a key-value head of the step's ``dk``, then its ``dv``."""
-    ki, qi = pl.program_id(2), pl.program_id(3)
-    group = q_ref.shape[1] // k_ref.shape[1]
-    sums = tuple(zip(scratch[0::2], scratch[1::2]))
-
-    @pl.when(qi == 0)
-    def _():
-        for dk_scr, dv_scr in sums:
-            dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
-            dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
-
-    @pl.when(qi >= _first_query_block(ki, bq, bk))
-    def _():
-        for j, (dk_scr, dv_scr) in enumerate(sums):
-            k, v, on = k_ref[0, j], v_ref[0, j], _tile(mask_ref, qi, ki, bq, bk)
-            dk, dv = dk_scr[...], dv_scr[...]
+            dk, dv = dk_ref[0, j, rows], dv_ref[0, j, rows]
             for h in range(j * group, (j + 1) * group):
                 q, do = q_ref[0, h], do_ref[0, h]
                 s = _dot(q, k, 1, 1)
                 p = jnp.where(on, jnp.exp(s - lse_ref[0, 0, :, h:h + 1]), 0.0)
                 dv = dv + _dot(p.astype(do.dtype), do, 0, 0)
-                ds = p * (_dot(do, v, 1, 1) - delta_ref[0, 0, :, h:h + 1])
-                dk = dk + _dot(ds.astype(q.dtype), q, 0, 0)
-            dk_scr[...], dv_scr[...] = dk, dv
+                ds = (p * (_dot(do, v, 1, 1) - delta_ref[0, 0, :, h:h + 1])).astype(q.dtype)
+                dk = dk + _dot(ds, q, 0, 0)
+                dq_ref[0, h] = dq_ref[0, h] + _dot(ds, k, 1, 0)
+            dk_ref[0, j, rows], dv_ref[0, j, rows] = dk, dv
 
-    @pl.when(qi == pl.num_programs(3) - 1)
-    def _():
-        for j, (dk_scr, dv_scr) in enumerate(sums):
-            dk_ref[0, j] = dk_scr[...].astype(dk_ref.dtype)
-            dv_ref[0, j] = dv_scr[...].astype(dv_ref.dtype)
+
+def _step_heads(kv: int, g: int) -> list:
+    """The counts of key-value heads a grid step may work, most first: those
+    that divide ``kv`` and bring its query heads to at most ``STEP_HEADS``."""
+    return [s for s in range(min(kv, max(1, STEP_HEADS // g)), 0, -1) if kv % s == 0]
 
 
 def heads_per_step(kv: int, g: int) -> int:
     """Key-value heads a grid step works, from the shapes alone: as many as
     bring its query heads to ``STEP_HEADS``, of those that divide ``kv``."""
-    return next(s for s in range(min(kv, max(1, STEP_HEADS // g)), 0, -1) if kv % s == 0)
+    return _step_heads(kv, g)[0]
 
 
-def _specs(s: int, g: int, d: int, dv: int, bq: int, bk: int, by_key: bool):
+def backward_step_bytes(s: int, g: int, d: int, dv: int, t: int, itemsize: int,
+                        masked: bool, bq: int = BLOCK_Q, bk: int = BLOCK_K) -> int:
+    """VMEM a step of the backward program holds for ``s`` key-value heads of
+    ``g`` query heads each over a row of ``t``: ``dk`` and ``dv`` of the whole
+    row once (float32), the step's other blocks twice (the pipeline's two
+    buffers), and the float32 tiles of its working. A width fills whole lane
+    tiles of 128, so a score width of 192 is held as 256."""
+    lanes = lambda w: -(-w // 128) * 128  # noqa: E731
+    d, dv, bq, bk = lanes(d), lanes(dv), min(bq, t), min(bk, t)
+    resident = 4 * s * t * (d + dv)
+    blocks = (itemsize * s * (g * bq * (d + dv) + bk * (d + dv))      # q, do; k, v
+              + 2 * 4 * bq * lanes(s * g)                             # logsumexp, delta
+              + 4 * s * g * bq * d                                    # dq
+              + masked * bq * bk)
+    return resident + 2 * blocks + 6 * 4 * bq * bk
+
+
+def backward_heads_per_step(kv: int, g: int, d: int, dv: int, t: int, itemsize: int,
+                            masked: bool = False, bq: int = BLOCK_Q, bk: int = BLOCK_K) -> int:
+    """Key-value heads a grid step of the backward program works, from the
+    shapes alone: the most of :func:`heads_per_step`'s candidates whose
+    ``dk`` and ``dv`` over the whole row fit VMEM beside the step's blocks."""
+    held = lambda s: backward_step_bytes(s, g, d, dv, t, itemsize, masked, bq, bk)  # noqa: E731
+    for s in _step_heads(kv, g):
+        if held(s) <= VMEM_LIMIT_BYTES:
+            return s
+    raise ValueError(
+        f"a row of {t} positions is too long for the attention's backward program: dk and dv of"
+        f" one key-value head (widths {d} and {dv}) with its blocks take {held(1):,} bytes of"
+        f" VMEM, over the {VMEM_LIMIT_BYTES:,} a program may hold")
+
+
+def backward_query_block(s: int, g: int, d: int, dv: int, t: int, itemsize: int,
+                         masked: bool, bq: int, bk: int) -> int:
+    """Queries a tile of the backward program, from the shapes alone: twice
+    the forward program's where the row divides into them and the step still
+    fits VMEM (a key block, its mask's tile and its rows of ``dk`` and ``dv``
+    are then read and written half as often), else the forward program's."""
+    wide = 2 * bq
+    fits = backward_step_bytes(s, g, d, dv, t, itemsize, masked, wide, bk) <= VMEM_LIMIT_BYTES
+    return wide if t % wide == 0 and fits else bq
+
+
+def _specs(s: int, g: int, d: int, dv: int, bq: int, bk: int, t: int):
     """Block specs of one call for ``s`` key-value heads a step of ``g`` query
-    heads each, scores over ``d`` and values of ``dv``. The forward and ``dq``
-    walk (b, kv / s, qi, ki) with the key block clamped to the last one under
-    the diagonal; ``dkv`` walks (b, kv / s, ki, qi) with the query block
-    clamped to the first at it. A clamped step names the block the step before
-    it held: nothing is fetched."""
-    if by_key:
-        at = lambda ki, qi: (jnp.maximum(qi, _first_query_block(ki, bq, bk)), ki)  # noqa: E731
-    else:
-        at = lambda qi, ki: (qi, jnp.minimum(ki, _last_key_block(qi, bq, bk)))  # noqa: E731
+    heads each, scores over ``d`` and values of ``dv``, on the grid
+    (b, kv / s, qi, ki) with the key block clamped to the last one under the
+    diagonal. A clamped step names the block the step before it held: nothing
+    is fetched. ``dk`` and ``dv`` are the step's heads over the whole row, one
+    buffer each, in VMEM while (b, kv / s) stands and written when it moves."""
+    at = lambda qi, ki: (qi, jnp.minimum(ki, _last_key_block(qi, bq, bk)))  # noqa: E731
 
-    def spec(block, index):
-        return pl.BlockSpec(block, lambda b, kv, i, j: index(b, kv, *at(i, j)))
+    def spec(block, index, **kw):
+        return pl.BlockSpec(block, lambda b, kv, i, j: index(b, kv, *at(i, j)), **kw)
 
     by_query = lambda b, kv, qi, ki: (b, kv, qi, 0)  # noqa: E731
     by_keys = lambda b, kv, qi, ki: (b, kv, ki, 0)  # noqa: E731
+    whole = lambda b, kv, qi, ki: (b, kv, 0, 0)  # noqa: E731
+    once = pl.Buffered(1)
     return {
         "q": spec((1, s * g, bq, d), by_query), "o": spec((1, s * g, bq, dv), by_query),
         "k": spec((1, s, bk, d), by_keys), "v": spec((1, s, bk, dv), by_keys),
         "mask": spec((1, bq, bk), lambda b, kv, qi, ki: (b, qi, ki)),
         "row": spec((1, 1, bq, s * g), by_query),
+        "dk": spec((1, s, t, d), whole, pipeline_mode=once),
+        "dv": spec((1, s, t, dv), whole, pipeline_mode=once),
     }
 
 
@@ -440,7 +481,7 @@ def _forward(q, k, v, mask, block_q, block_k, interpret):
     g = h // kv
     s = heads_per_step(kv, g)
     bq, bk = _block(block_q, t), _block(block_k, t)
-    sp = _specs(s, g, d, dv, bq, bk, by_key=False)
+    sp = _specs(s, g, d, dv, bq, bk, t)
     masked = (mask,) if mask is not None else ()
     scaled = (q.astype(jnp.float32) * d ** -0.5).astype(q.dtype)
     out, lse = pl.pallas_call(
@@ -469,41 +510,32 @@ def _bwd(block_q, block_k, interpret, res, g_out):
     b, t, h, d = q.shape
     kv, dv = k.shape[2], v.shape[3]
     g = h // kv
-    s = heads_per_step(kv, g)
+    masked = (mask,) if mask is not None else ()
     bq, bk = _block(block_q, t), _block(block_k, t)
+    shape = (g, d, dv, t, q.dtype.itemsize, bool(masked))
+    s = backward_heads_per_step(kv, *shape, bq, bk)
+    bq = backward_query_block(s, *shape, bq, bk)
     scale = d ** -0.5
-    # delta[b, t, h] = rowsum(dO o O), laid out like the logsumexp
+    # delta[b, t, h] = rowsum(dO o O); it and the logsumexp laid out for the
+    # heads this program takes a step, which may be fewer than the forward's
     delta = jnp.einsum("bthd,bthd->bth", g_out.astype(jnp.float32),
                        out.astype(jnp.float32))
-    delta = jnp.transpose(delta.reshape(b, t, kv // s, s * g), (0, 2, 1, 3))
+    lse = jnp.transpose(lse, (0, 2, 1, 3)).reshape(b, t, h)
+    lse, delta = (jnp.transpose(x.reshape(b, t, kv // s, s * g), (0, 2, 1, 3))
+                  for x in (lse, delta))
     qs = _heads_first((q.astype(jnp.float32) * scale).astype(q.dtype))
     kt, vt, do = _heads_first(k), _heads_first(v), _heads_first(g_out.astype(q.dtype))
-    masked = (mask,) if mask is not None else ()
-    ins = lambda sp: ([sp["q"], sp["k"], sp["v"]] + [sp["mask"]] * len(masked)  # noqa: E731
-                      + [sp["o"], sp["row"], sp["row"]])
-
-    sp = _specs(s, g, d, dv, bq, bk, by_key=False)
-    dq = pl.pallas_call(
-        _kernel(_dq_kernel, masked, bq=bq, bk=bk),
+    sp = _specs(s, g, d, dv, bq, bk, t)
+    dq, dk, d_v = pl.pallas_call(
+        _kernel(_bwd_kernel, masked, bq=bq, bk=bk),
         grid=(b, kv // s, t // bq, t // bk),
-        in_specs=ins(sp),
-        out_specs=sp["q"],
-        out_shape=jax.ShapeDtypeStruct((b, h, t, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((s * g, bq, d), jnp.float32)],
-        compiler_params=_params("parallel", "parallel", "parallel", "arbitrary"),
-        interpret=interpret,
-    )(qs, kt, vt, *masked, do, lse, delta)
-
-    sp = _specs(s, g, d, dv, bq, bk, by_key=True)
-    dk, d_v = pl.pallas_call(
-        _kernel(_dkv_kernel, masked, bq=bq, bk=bk),
-        grid=(b, kv // s, t // bk, t // bq),
-        in_specs=ins(sp),
-        out_specs=[sp["k"], sp["v"]],
-        out_shape=[jax.ShapeDtypeStruct((b, kv, t, d), jnp.float32),
+        in_specs=([sp["q"], sp["k"], sp["v"]] + [sp["mask"]] * len(masked)
+                  + [sp["o"], sp["row"], sp["row"]]),
+        out_specs=[sp["q"], sp["dk"], sp["dv"]],
+        out_shape=[jax.ShapeDtypeStruct((b, h, t, d), jnp.float32),
+                   jax.ShapeDtypeStruct((b, kv, t, d), jnp.float32),
                    jax.ShapeDtypeStruct((b, kv, t, dv), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32), pltpu.VMEM((bk, dv), jnp.float32)] * s,
-        compiler_params=_params("parallel", "parallel", "parallel", "arbitrary"),
+        compiler_params=_params("parallel", "parallel", "arbitrary", "arbitrary"),
         interpret=interpret,
     )(qs, kt, vt, *masked, do, lse, delta)
 

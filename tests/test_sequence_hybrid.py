@@ -335,6 +335,54 @@ def test_attention_programs_without_a_mask_match_plain_attention_at_width_256():
         assert np.abs(np.asarray(a - g)).max() < 1e-4 * np.abs(np.asarray(g)).max(), name
 
 
+@pytest.mark.parametrize("t,bq,bk", [(128, 64, 32), (128, 16, 64), (64, sa.BLOCK_Q, sa.BLOCK_K)],
+                         ids=["query-block-over-key-block", "key-block-over-query-block",
+                              "one-block"])
+def test_the_backward_program_without_a_mask_gives_the_twins_gradients(t, bq, bk):
+    """The one backward program in its causal mode, 8 query heads on a
+    key-value head of width 256 (the full layer's group): ``dq``, ``dk`` and
+    ``dv`` against the plain twin's, at tiles whose edges differ either way
+    (the key block clamped at the diagonal) and at one block."""
+    rng = np.random.default_rng(t + bq)
+    b, h, kv, d = 1, 8, 1, 256
+    q, k, v = (jnp.asarray(rng.standard_normal(s), jnp.float32)
+               for s in ((b, t, h, d), (b, t, kv, d), (b, t, kv, d)))
+    weight = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
+    program = lambda q, k, v: sa.causal_attention(q, k, v, bq, bk, True)  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        have = jax.grad(lambda *a: (program(*a) * weight).sum(), (0, 1, 2))(q, k, v)
+        want = jax.grad(lambda *a: (sa.causal_attention_plain(*a) * weight).sum(),
+                        (0, 1, 2))(q, k, v)
+    for name, a, g in zip("qkv", have, want):
+        assert a.shape == g.shape, name
+        assert np.abs(np.asarray(a - g)).max() < 1e-4 * np.abs(np.asarray(g)).max(), name
+
+
+def test_the_full_layers_backward_program_holds_one_key_value_head_a_step():
+    """The cell's full layer (2 key-value heads of 8 query heads, 256 + 256, a
+    row of 8,192, bfloat16): one key-value head a step, 16.8 MB of ``dk`` and
+    ``dv`` in the 30 the step holds. A row too long for one head's pair is
+    refused when the step is traced, with the numbers."""
+    cell = _config(num_heads=16, num_kv_heads=2, head_dim=256, max_len=8192,
+                   compute_dtype="bfloat16", attention="auto")
+    assert hybrid.attention_backward_heads_per_step(cell) == 1
+    held = sa.backward_step_bytes(1, 8, 256, 256, 8192, 2, False)
+    assert 4 * 8192 * 512 < held < 32e6
+    assert sa.backward_query_block(1, 8, 256, 256, 8192, 2, False, sa.BLOCK_Q, sa.BLOCK_K) == 512
+    attrs = fit_attrs(cell, 4, 8, 2, "tpu")
+    assert (attrs["attention_backward_programs"], attrs["attention_backward_heads_per_step"]) == (
+        1, 1)
+    assert fit_attrs(cell, 4, 8, 2, "cpu")["attention_backward_programs"] == 0
+    assert sa.backward_heads_per_step(2, 8, 256, 256, 16384, 2) == 1
+    with pytest.raises(ValueError, match=r"a row of 65536 positions is too long(.|\n)*"
+                                         r"widths 256 and 256(.|\n)*67,108,864"):
+        sa.backward_heads_per_step(2, 8, 256, 256, 65536, 2)
+    q = jax.ShapeDtypeStruct((1, 65536, 8, 256), jnp.bfloat16)
+    with pytest.raises(ValueError, match="too long for the attention's backward program"):
+        jax.eval_shape(jax.grad(lambda q, k, v: sa.causal_attention(q, k, v).sum().astype(
+            jnp.float32), (0, 1, 2)), q, q, q)
+
+
 # ---- the experts -------------------------------------------------------------
 
 def test_the_shares_and_the_shared_expert_once_add_up_to_the_whole_layer():
@@ -447,6 +495,8 @@ def test_the_backbone_learns_a_cycle_and_reports_its_fit(caplog):
     assert (attrs["linear_layers"], attrs["full_layers"], attrs["delta_chunk"],
             attrs["experts_shared"], attrs["experts_held"]) == (1, 1, 4, 1, 4)
     assert attrs["delta_heads_per_step"] == 8                      # 32 rows x 2 value heads
+    assert (attrs["attention_backward_programs"],
+            attrs["attention_backward_heads_per_step"]) == (0, 2)       # ``attention="plain"``
     assert attrs["delta_state_bytes"] == 2 * 8 * 8 * 4
     assert attrs["delta_kept_bytes"] == 32 * 2 * 2 * 8 * 8 * 4     # rows x chunks x a state
     assert attrs["moe_dropped"] == 0 and attrs["moe_held_assignments"] == attrs["moe_assignments"]

@@ -260,6 +260,55 @@ def test_a_grid_step_takes_key_value_heads_up_to_eight_query_heads():
     assert sa.heads_per_step(4, 8) == sa.heads_per_step(2, 8) == sa.heads_per_step(2, 16) == 1
 
 
+@pytest.mark.parametrize("fit", [4, 2, 1])
+def test_the_backward_program_takes_fewer_heads_a_step_where_the_row_is_long(fit, monkeypatch):
+    """Four heads with a key and a value each, scores over 24 and values of
+    16, a row of 96 in tiles of 32 by 48. The forward program takes all four a
+    step; the backward program as many as hold ``dk`` and ``dv`` of the whole
+    row within the limit, so with room for ``fit`` heads it takes ``fit`` and
+    reads the logsumexp laid out for them: the three gradients are the twin's
+    whatever it takes, and to the bit the same."""
+    rng = np.random.default_rng(11)
+    b, t, heads, d, dv = 2, 96, 4, 24, 16
+    q, k, v = (jnp.asarray(rng.standard_normal(s), jnp.float32)
+               for s in ((b, t, heads, d), (b, t, heads, d), (b, t, heads, dv)))
+    weight = jnp.asarray(rng.standard_normal((b, t, heads, dv)), jnp.float32)
+    grads = lambda fn: jax.grad(lambda *a: (fn(*a) * weight).sum(), (0, 1, 2))(q, k, v)  # noqa: E731
+    programs = lambda q, k, v: sa.causal_attention(q, k, v, 32, 48, True)  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        want = grads(sa.causal_attention_plain)
+        whole = grads(programs)
+        monkeypatch.setattr(sa, "VMEM_LIMIT_BYTES",
+                            sa.backward_step_bytes(fit, 1, d, dv, t, 4, False, 32, 48))
+        assert sa.heads_per_step(heads, 1) == 4
+        assert sa.backward_heads_per_step(heads, 1, d, dv, t, 4, False, 32, 48) == fit
+        have = grads(programs)
+    for name, a, w, g in zip("qkv", have, whole, want):
+        assert np.array_equal(np.asarray(a), np.asarray(w)), name
+        assert np.abs(np.asarray(a - g)).max() < 1e-4 * np.abs(np.asarray(g)).max(), name
+
+
+def test_the_cells_backward_program_takes_four_heads_a_step():
+    """The cell's shape (32 heads with a key and a value each, 192 + 128, a
+    row of 8,192, bfloat16): a head's ``dk`` and ``dv`` are 12.6 MB in VMEM
+    (192 is held as 256 lanes), so the forward program's eight heads would
+    take 100 MB and the backward program takes four, 60.8 MB of the 64 MiB;
+    a quarter of the row leaves room for eight, four times the row for one."""
+    cell = (32, 1, 192, 128, 8192, 2)
+    assert sa.backward_step_bytes(1, *cell[1:], False) - sa.backward_step_bytes(
+        1, 1, 192, 128, 4096, 2, False) == 4 * 4096 * (256 + 128)
+    assert sa.backward_step_bytes(8, *cell[1:], False) > 100e6
+    assert sa.backward_step_bytes(4, *cell[1:], False) == 60_817_408 < sa.VMEM_LIMIT_BYTES
+    assert sa.backward_heads_per_step(*cell) == 4
+    # the tile of queries stays the forward program's: twice it would take 68 MB
+    assert sa.backward_query_block(4, *cell[1:], False, sa.BLOCK_Q, sa.BLOCK_K) == sa.BLOCK_Q
+    assert sa.backward_query_block(2, *cell[1:], False, sa.BLOCK_Q, sa.BLOCK_K) == 2 * sa.BLOCK_Q
+    assert [sa.backward_heads_per_step(32, 1, 192, 128, t, 2)
+            for t in (2048, 4096, 16384, 32768)] == [8, 4, 2, 1]
+    with pytest.raises(ValueError, match="a row of 65536 positions is too long"):
+        sa.backward_heads_per_step(32, 1, 192, 128, 65536, 2)
+
+
 def test_a_length_the_block_does_not_divide_is_refused():
     q = jnp.zeros((1, 100, 2, 24))
     with pytest.raises(ValueError, match="not a multiple of the block"):
@@ -497,7 +546,11 @@ def test_the_engine_takes_the_backbone_at_the_cells_sizes():
     assert sparse_moe.moe_chunk_of(config) >= 16384
     assert sparse_moe.pass_plan(config, 16384)[0] >= 2 * 16384 * 8 * 16 // 256
     assert sa.heads_per_step(config.num_kv_heads, 1) == 8
+    assert latent_moe.attention_backward_heads_per_step(config) == 4
+    assert fit_attrs(config, 4, 8, 2, "tpu")["attention_backward_programs"] == 1
     attrs = fit_attrs(config, 4, 8, 2, "cpu")
+    assert (attrs["attention_backward_programs"],
+            attrs["attention_backward_heads_per_step"]) == (0, 4)
     assert (attrs["backbone"], attrs["layers"], attrs["dense_layers"], attrs["mtp_depth"],
             attrs["experts_shared"], attrs["experts_total"], attrs["experts_held"],
             attrs["experts_per_token"]) == ("latent_moe", 5, 1, 1, 1, 256, 16, 8)

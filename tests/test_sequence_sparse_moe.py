@@ -21,7 +21,7 @@ from benchmarks import reference_keye as ref
 from benchmarks import seeded_histories
 from predictionio_tpu.models.sequence import sparse_moe
 from predictionio_tpu.models.sequence.model import (
-    make_fit, score_next_items_batch, train_sasrec,
+    fit_attrs, make_fit, score_next_items_batch, train_sasrec,
 )
 from predictionio_tpu.models.sequence.sparse_moe import SparseMoEConfig
 from predictionio_tpu.ops import run_sum
@@ -285,6 +285,69 @@ def test_attention_program_matches_masked_plain_attention_forward_and_gradients(
     for a, g in zip(jax.grad(total(program), (0, 1, 2))(*f32),
                     jax.grad(total(twin), (0, 1, 2))(*f32)):
         assert np.abs(np.asarray(a) - np.asarray(g)).max() < 1e-4 * np.abs(np.asarray(g)).max()
+
+
+#: heads, key-value heads, T, block_q, block_k, how the selection is drawn
+BACKWARD_CASES = {
+    "empty-tiles-8-heads-a-kv-head": (8, 1, 512, 64, 64, "window"),
+    "query-block-over-key-block": (4, 2, 256, 128, 64, "drawn"),
+    "key-block-over-query-block": (4, 2, 256, 32, 128, "drawn"),
+    "one-block": (4, 2, 48, sa.BLOCK_Q, sa.BLOCK_K, "drawn"),
+    "several-kv-heads-a-step": (4, 4, 128, 32, 64, "drawn"),
+}
+
+
+@pytest.mark.parametrize("case", list(BACKWARD_CASES))
+def test_the_backward_program_gives_the_twins_three_gradients(case):
+    """The one backward program (a tile's ``s``, ``p`` and ``ds`` once; ``dq``
+    a query block's sum, ``dk`` and ``dv`` the whole row's, a tile adding into
+    its key block's rows) against the plain twin's gradients, in float32: with
+    tiles under the diagonal that select nothing (a window of 40 keys: the
+    last tile of queries, twice the forward program's, sees none of the first
+    two key blocks), with tiles whose two edges differ either way (the
+    diagonal's clamp), at one block, and with several key-value heads a step."""
+    h, kv, t, bq, bk, drawn = BACKWARD_CASES[case]
+    rng = np.random.default_rng(len(case))
+    b, d = 2, 32
+    pos = np.arange(t)
+    causal = pos[None, :] <= pos[:, None]
+    if drawn == "window":
+        mask = np.broadcast_to(causal & (pos[None, :] > pos[:, None] - 40), (b, t, t))
+        assert sa.backward_query_block(1, h, d, d, t, 4, True, bq, bk) == 2 * bq
+        assert not mask[:, -2 * bq:, :2 * bk].any()
+    else:
+        mask = (rng.random((b, t, t)) < 0.4) & causal | np.eye(t, dtype=bool)
+    mask = jnp.asarray(mask, jnp.int8)
+    q, k, v = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
+               for shape in ((b, t, h, d), (b, t, kv, d), (b, t, kv, d)))
+    weight = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
+    program = lambda q, k, v: sa.sparse_attention(q, k, v, mask, bq, bk, True)  # noqa: E731
+    twin = lambda q, k, v: sa.sparse_attention_plain(q, k, v, mask)  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        have = jax.grad(lambda *a: (program(*a) * weight).sum(), (0, 1, 2))(q, k, v)
+        want = jax.grad(lambda *a: (twin(*a) * weight).sum(), (0, 1, 2))(q, k, v)
+    for name, a, g in zip("qkv", have, want):
+        assert a.shape == g.shape and a.dtype == g.dtype, name
+        assert np.abs(np.asarray(a - g)).max() < 1e-4 * np.abs(np.asarray(g)).max(), name
+
+
+def test_the_backward_program_takes_its_heads_a_step_from_the_shapes():
+    """The cell's shape (4 key-value heads of 8 query heads, 128 + 128, a row
+    of 8,192, bfloat16, a mask's tile): one key-value head a step, its ``dk``
+    and ``dv`` 8.4 MB of the 17 the step holds; the fit's span says so, and
+    says that off the TPU no program runs."""
+    cell = _config(num_heads=32, num_kv_heads=4, head_dim=128, max_len=8192,
+                   compute_dtype="bfloat16", attention="auto")
+    assert sparse_moe.attention_backward_heads_per_step(cell) == 1
+    held = sa.backward_step_bytes(1, 8, 128, 128, 8192, 2, True)
+    assert 4 * 8192 * 256 < held < 18e6
+    # and a tile of 512 queries, twice the forward program's: 25 MB
+    assert sa.backward_query_block(1, 8, 128, 128, 8192, 2, True, sa.BLOCK_Q, sa.BLOCK_K) == 512
+    assert sa.backward_query_block(1, 8, 128, 128, 768, 2, True, sa.BLOCK_Q, sa.BLOCK_K) == 256
+    attrs = fit_attrs(cell, 4, 8, 2, "cpu")
+    assert (attrs["attention_backward_programs"], attrs["attention_backward_heads_per_step"]) == (
+        0, 1)
+    assert fit_attrs(cell, 4, 8, 2, "tpu")["attention_backward_programs"] == 1
 
 
 # ---- the experts -------------------------------------------------------------
@@ -608,7 +671,10 @@ def test_the_backbone_learns_a_cycle_and_reports_its_fit(caplog):
     # one layer of 32 rows of 8 positions, a bit a pair, kept for the backward pass
     assert (attrs["rematerialised"], attrs["selection_kept_bytes"]) == ("layer", 32 * 8)
     line = next(r.getMessage() for r in caplog.records if "seq_fit:" in r.getMessage())
+    assert (attrs["attention_backward_programs"],
+            attrs["attention_backward_heads_per_step"]) == (0, 2)       # ``attention="plain"``
     for word in ("backbone=sparse_moe", "experts_held=4", "experts_total=4", "index_topk=4",
+                 "attention_backward_programs=0", "attention_backward_heads_per_step=2",
                  "selection_kept_bytes=256", "moe_dropped=0", "moe_held_load_max=",
                  "selected_pairs="):
         assert word in line, (word, line)

@@ -76,12 +76,13 @@ def _kernel_instructions(text: str, name: str) -> list[str]:
 #: both programs as they were, instruction for instruction; one that means to
 #: change a step re-measures its cell and writes the new digest here (PR 41:
 #: the sparse cell's, whose experts' rows come back by runs; the looped one's
-#: is PR 36's still)
+#: is PR 36's still; PR 42: the sparse cell's again, whose attention's backward
+#: pass is one program where it was two)
 ACCEPTED_STEPS = {
     "ouro-2.6b-d8.train-histories":
         "7849910ae58d05dc248f996c7c3e71238090eae70f3d76ad3486b9b4d5c342ef",
     "keye-vl2-30b-a3b-ep8.train-lifelong-histories":
-        "26e9048e75bdca7b628882f22ab0ac7fed327ef5d42695ce9660c14c9389637d",
+        "b145a5bbda1425b96c0b687b9641f86606382da6ca15368a7bcf725455617e87",
 }
 
 
@@ -637,8 +638,9 @@ def test_the_lifelong_histories_cells_step_fits_the_chip_at_6_layers_and_scopes_
     """The step of ``keye-vl2-30b-a3b-ep8.train-lifelong-histories`` (2 rows of
     8,192 at the published widths, 16 of 128 experts held, an eighth of the
     vocabulary) at the 6 layers ``benchmarks/configs/keye-vl2-30b-a3b-ep8.json``
-    holds: Mosaic takes the three programs of ``ops/sparse_attention.py`` at
-    that size and ``ops/run_sum.py``'s for a pass of 32,768 rows onto 16,384
+    holds: Mosaic takes the programs of ``ops/sparse_attention.py`` at that
+    size (index, select, the forward attention and its one backward program,
+    which holds a key-value head's ``dk`` and ``dv`` of the whole row in VMEM) and ``ops/run_sum.py``'s for a pass of 32,768 rows onto 16,384
     tokens (float32 rows forward, bfloat16 ones backward), the grouped matmuls
     lower to the chip's own ragged dot, no row
     of the experts' path is scattered, the peak is under the chip's 15.75 GB,
@@ -683,9 +685,12 @@ def test_the_lifelong_histories_cells_step_fits_the_chip_at_6_layers_and_scopes_
     kinds = [scopes_sparse.kernel_kind(c) for c in calls]
     # forward: one index, one select and one attention program; recomputed:
     # the attention program alone, on the selection the forward pass kept;
-    # backward: dq and dkv. The experts' grouped matmuls are custom calls too
+    # backward: one program for dq, dk and dv (PR 42; two before). The experts'
+    # grouped matmuls are custom calls too
     assert stages.count("index") == 1 and stages.count("select") == 1
-    assert kinds.count("forward") == 2 and kinds.count("backward") == 2
+    assert kinds.count("forward") == 2 and kinds.count("backward") == 1
+    assert sparse_moe.attention_backward_heads_per_step(config) == 1
+    assert _backward_attention_grid((2, 8192, 32, 128), 4, 128, masked=True) == (2, 4, 16, 16)
     assert sparse_moe.selection_kept_bytes(config, 2) == 6 * 2 * 1024 * 8192
     assert re.search(r"u8\[6,2,1024,8192\]", text)
     # the held experts: XLA's own ragged dot, which keeps its own name and no
@@ -733,6 +738,25 @@ def test_the_lifelong_histories_cells_step_fits_the_chip_at_6_layers_and_scopes_
         "backward", "backward", "forward", "forward"]
 
 
+def _backward_attention_grid(q_shape: tuple, kv: int, dv: int, masked: bool = False) -> tuple:
+    """The grid of ``ops/sparse_attention.py``'s backward program as a step
+    traces it for bfloat16 ``q`` of ``q_shape`` on ``kv`` key-value heads and
+    values of ``dv``: (rows, key-value heads over those a step, tiles of
+    queries, tiles of keys), heads and the tile of queries from the shapes."""
+    from predictionio_tpu.ops import sparse_attention as sa
+
+    b, t, _, d = q_shape
+    q, k, v = (jax.ShapeDtypeStruct(s, jnp.bfloat16)
+               for s in (q_shape, (b, t, kv, d), (b, t, kv, dv)))
+    mask = jax.ShapeDtypeStruct((b, t, t), jnp.int8) if masked else None
+    out, lse = jax.eval_shape(lambda q, k, v, mask: sa._forward(
+        q, k, v, mask, sa.BLOCK_Q, sa.BLOCK_K, False), q, k, v, mask)
+    traced = jax.make_jaxpr(lambda q, k, v, mask, out, lse: sa._bwd(
+        sa.BLOCK_Q, sa.BLOCK_K, False, (q, k, v, mask, out, lse), out))(q, k, v, mask, out, lse)
+    (call,) = [eqn for eqn in traced.jaxpr.eqns if eqn.primitive.name == "pallas_call"]
+    return call.params["grid_mapping"].grid
+
+
 def _run_sum_grid(n: int, slots: int, rows: int) -> tuple:
     """The grid of ``ops/run_sum.py``'s program as a step traces it for a pass
     of ``rows`` rows onto ``n`` tokens of ``slots`` slots: blocks from the
@@ -776,7 +800,8 @@ def test_the_hybrid_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
     under the chip's 15.75 GB, and every program and every leaf sits under the
     scope the benchmark's readers look for. A linear mixer's state pass stands
     forward, recomputed and (its transpose) backward; the full layer's
-    attention forward, recomputed and as ``dq`` and ``dkv``."""
+    attention forward, recomputed and as one backward program, a key-value
+    head's ``dk`` and ``dv`` (16.8 MB) in VMEM for the whole row."""
     import re
 
     from benchmarks import scopes_hybrid, scopes_leaf, scopes_seq, scopes_sparse
@@ -826,7 +851,9 @@ def test_the_hybrid_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
         "backward" if "transpose(" in c else "forward" for c in names)
     assert phases(rule) == ["backward", "forward", "recomputed"]
     kinds = [scopes_seq.kernel_kind(c) for c in calls]
-    assert kinds.count("forward") == 2 and kinds.count("backward") == 2
+    assert kinds.count("forward") == 2 and kinds.count("backward") == 1
+    assert hybrid.attention_backward_heads_per_step(config) == 1
+    assert _backward_attention_grid((2, 8192, 16, 256), 2, 256) == (2, 2, 16, 16)
     assert not [c for c in calls if scopes_sparse.parse_stage(c) in ("index", "select")]
     # the held experts: the sparse backbone's passes, XLA's own ragged dot, and
     # the rows back by runs (the linear layers' scan and the full layer, each
@@ -845,10 +872,13 @@ def test_the_latent_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
     256 experts held, the prediction module, an eighth of the vocabulary):
     Mosaic takes the attention programs with no mask operand at a score width
     of 192 (one and a half lane tiles) and a value width of 128, eight of the
-    32 heads a grid step (a grid of 2 x 4 x 32 x 16), the peak is under the
+    32 heads a grid step (a grid of 2 x 4 x 32 x 16) and four a step of the
+    backward program, whose ``dk`` and ``dv`` of the whole row stay in VMEM (a
+    grid of 2 x 8 x 32 x 16: its tile of queries stays 256, where the sparse and
+    hybrid cells' takes 512), the peak is under the
     chip's 15.75 GB, and every program and every new scope sits where the
     benchmark's readers look for it: the attention forward, recomputed and as
-    ``dq`` and ``dkv`` in the dense layer, in the scanned expert layers and
+    one backward program in the dense layer, in the scanned expert layers and
     under ``mtp``; the run sum's programs for a pass of 16,384 rows, as many as
     tokens, in the expert layers and the module; the two latent paths inside
     ``qkv``; the bias's move under ``seq.optimizer``."""
@@ -873,6 +903,9 @@ def test_the_latent_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
     grids = [eqn.params["grid_mapping"].grid for eqn in traced.jaxpr.eqns
              if eqn.primitive.name == "pallas_call"]
     assert grids == [(2, 4, 8192 // sa.BLOCK_Q, 8192 // sa.BLOCK_K)] == [(2, 4, 32, 16)], grids
+    # the backward program: four heads a step, their dk and dv over the row
+    assert latent_moe.attention_backward_heads_per_step(config) == 4
+    assert _backward_attention_grid((2, 8192, 32, 192), 32, 128) == (2, 8, 32, 16)
     assert sparse_moe.pass_plan(config, 16384) == (16384, 8)
     assert _run_sum_grid(16384, 8, 16384) == (64, 9)
     _, _, step_fn, seq_shard = seq_model.make_fit(config, mesh)
@@ -894,16 +927,17 @@ def test_the_latent_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
     text = compiled.as_text()
     calls = [c for c in re.findall(
         r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text) if "seq." in c]
-    # the dense layer, the scan's body and the module: each forward, again, dq
-    # and dkv; the experts' rows back by runs in the scan's body and the module
+    # the dense layer, the scan's body and the module: each forward, again and
+    # one backward program (PR 42; dq and dkv before: 12 programs and 6
+    # backward); the experts' rows back by runs in the scan's body and the module
     attention = [c for c in calls if scopes_leaf.place_of(c).stage == "attention"]
-    assert len(attention) == 12 and all(
+    assert len(attention) == 9 and all(
         scopes_leaf.place_of(c).leaf == "kernel" for c in attention)
     _the_sums_are_programs(text, calls, 4)
     kinds = [scopes_seq.kernel_kind(c) for c in calls]
-    assert kinds.count("forward") == 6 and kinds.count("backward") == 6
-    assert sum("mtp" in scopes_latent.places_of(c) for c in attention) == 4
-    assert sum("mtp" in scopes_latent.places_of(c) for c in calls) == 8      # and its four sums
+    assert kinds.count("forward") == 6 and kinds.count("backward") == 3
+    assert sum("mtp" in scopes_latent.places_of(c) for c in attention) == 3
+    assert sum("mtp" in scopes_latent.places_of(c) for c in calls) == 7      # and its four sums
     assert not [c for c in calls if scopes_sparse.parse_stage(c) in ("index", "select")]
     # the held experts: the sparse backbone's passes, XLA's own ragged dot
     assert re.search(r"%ragged-dot-none(?:\.\d+)? = [^\n]*tpu_custom_call", text)
