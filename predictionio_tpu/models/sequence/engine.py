@@ -28,13 +28,9 @@ from predictionio_tpu.controller import (
 from predictionio_tpu.controller.base import SanityCheck
 from predictionio_tpu.data.store import PEventStore
 from predictionio_tpu.models._als_common import score_buffer_rows, topk_item_scores
-from predictionio_tpu.models.sequence.looped import LoopedConfig
-from predictionio_tpu.models.sequence.hybrid import HybridConfig
-from predictionio_tpu.models.sequence.latent_moe import LatentMoEConfig
-from predictionio_tpu.models.sequence.sparse_moe import SparseMoEConfig
 from predictionio_tpu.ops.flash_attention import tiles_worked
 from predictionio_tpu.models.sequence.model import (
-    SASRecConfig,
+    BACKBONES,
     score_next_items,
     score_next_items_batch,
     train_sasrec,
@@ -183,8 +179,7 @@ class SequencePreparator(Preparator):
 @dataclass
 class SASRecModel:
     params: dict
-    # the backbone it was trained with
-    config: SASRecConfig | LoopedConfig | SparseMoEConfig | HybridConfig | LatentMoEConfig
+    config: object                     # the configuration of the backbone it was trained with
     item_ids: list[str]
     item_index: dict[str, int]
     histories: dict[str, np.ndarray]   # user id -> shifted (+1) id sequence
@@ -199,159 +194,40 @@ class SASRecModel:
     event_names: list[str] = None
 
 
-class SASRecAlgorithm(TPUAlgorithm):
-    """Params: ``backbone`` ("sasrec", the default, "looped", "sparse_moe",
-    "hybrid_linear" or "latent_moe");
-    learningRate, batchSize, epochs, seed, maxLen (must match the
-    preparator's), attention ("auto" | "flash" | "plain") and seqParallel
-    ("ring" | "ulysses", the sequence-parallel attention strategy when the
-    mesh has a >1 ``seq`` axis) for all. "sasrec" reads embedDim, numHeads,
-    numBlocks, ffnDim, dropout; "looped" (``models/sequence/looped.py``) reads
-    hiddenSize, numHeads, headDim, ffnDim, numLayers, utSteps, ropeTheta,
-    rmsNormEps, exitBeta, earlyExitThreshold; "sparse_moe"
-    (``models/sequence/sparse_moe.py``) reads hiddenSize, numHeads,
-    numKvHeads, headDim, expertDim, numExperts, expertsPerToken, expertsHeld
-    (``[lo, hi]``: the experts this program holds of the ``numExperts``; the
-    default is all), numLayers, indexHeads, indexDim, indexTopk, ropeTheta,
-    rmsNormEps, auxLossCoef; "hybrid_linear" (``models/sequence/hybrid.py``:
-    periods of fullAttentionInterval layers, the last a gated full-attention
-    layer, the others gated-delta-rule linear-attention layers) reads
-    hiddenSize, numLayers, fullAttentionInterval, linearKeyHeads,
-    linearValueHeads, linearKeyDim, linearValueDim, convKernel, numHeads,
-    numKvHeads, headDim, partialRotaryFactor, expertDim, numExperts,
-    expertsPerToken, expertsHeld, sharedExpertDim, ropeTheta, rmsNormEps,
-    auxLossCoef; "latent_moe" (``models/sequence/latent_moe.py``: latent
-    attention, denseLayers leading dense layers, then experts chosen by sigmoid
-    scores plus a bias the step moves against the load, a shared expert, and a
-    module that predicts a second event ahead) reads hiddenSize, numLayers,
-    denseLayers, numHeads, qLoraRank, kvLoraRank, qkNopeHeadDim, qkRopeHeadDim,
-    vHeadDim, ffnDim, expertDim, numExperts, expertsPerToken, expertsHeld,
-    sharedExpertDim, routedScalingFactor, mtpDepth, mtpLossCoef,
-    balanceLossCoef, biasUpdateRate, ropeTheta, rmsNormEps."""
+#: the engine parameters every backbone reads -> their field: the fit's, and
+#: the two selectors of how attention is worked
+FIT_PARAMS = {"learningRate": "learning_rate", "batchSize": "batch_size", "epochs": "epochs",
+              "seed": "seed", "seqParallel": "seq_parallel", "attention": "attention"}
 
-    BACKBONES = ("sasrec", "looped", "sparse_moe", "hybrid_linear", "latent_moe")
+
+class SASRecAlgorithm(TPUAlgorithm):
+    """Params: ``backbone`` (one of ``BACKBONES``; "sasrec" is the default),
+    maxLen (must match the preparator's), historyMode ("model" | "live"), the
+    fit's (``FIT_PARAMS``) and the backbone's own widths (its module's
+    ``ENGINE_PARAMS``). ``docs/templates.md`` lists every name, backbone by
+    backbone, with what it means."""
+
+    BACKBONES = tuple(BACKBONES)
 
     def _config(self, num_items: int, max_len: int):
+        """The backbone's configuration: each engine parameter it reads, cast
+        to the type of its field's default, which is also what a parameter
+        left out takes."""
         p = self.params
         backbone = p.get_or("backbone", "sasrec")
         if backbone not in self.BACKBONES:
             raise ValueError(
                 f"backbone={backbone!r}: want one of "
                 + ", ".join(repr(b) for b in self.BACKBONES))
-        shared = dict(
-            num_items=num_items,
-            max_len=max_len,
-            batch_size=p.get_or("batchSize", 256),
-            epochs=p.get_or("epochs", 10),
-            seed=p.get_or("seed", 0),
-            seq_parallel=p.get_or("seqParallel", "ring"),
-            attention=p.get_or("attention", "auto"),
-        )
-        if backbone == "looped":
-            d = LoopedConfig(num_items=num_items)  # the defaults, in one place
-            return LoopedConfig(
-                hidden_size=p.get_or("hiddenSize", d.hidden_size),
-                num_heads=p.get_or("numHeads", d.num_heads),
-                head_dim=p.get_or("headDim", d.head_dim),
-                ffn_dim=p.get_or("ffnDim", d.ffn_dim),
-                num_layers=p.get_or("numLayers", d.num_layers),
-                ut_steps=p.get_or("utSteps", d.ut_steps),
-                rope_theta=float(p.get_or("ropeTheta", d.rope_theta)),
-                rms_eps=float(p.get_or("rmsNormEps", d.rms_eps)),
-                exit_beta=float(p.get_or("exitBeta", d.exit_beta)),
-                early_exit_threshold=float(
-                    p.get_or("earlyExitThreshold", d.early_exit_threshold)),
-                learning_rate=p.get_or("learningRate", d.learning_rate),
-                **shared,
-            )
-        if backbone == "sparse_moe":
-            d = SparseMoEConfig(num_items=num_items)  # the defaults, in one place
-            experts = p.get_or("numExperts", d.num_experts)
-            return SparseMoEConfig(
-                hidden_size=p.get_or("hiddenSize", d.hidden_size),
-                num_heads=p.get_or("numHeads", d.num_heads),
-                num_kv_heads=p.get_or("numKvHeads", d.num_kv_heads),
-                head_dim=p.get_or("headDim", d.head_dim),
-                expert_dim=p.get_or("expertDim", d.expert_dim),
-                num_experts=experts,
-                experts_per_token=p.get_or("expertsPerToken", d.experts_per_token),
-                experts_held=tuple(p.get_or("expertsHeld", (0, experts))),
-                num_layers=p.get_or("numLayers", d.num_layers),
-                index_heads=p.get_or("indexHeads", d.index_heads),
-                index_dim=p.get_or("indexDim", d.index_dim),
-                index_topk=p.get_or("indexTopk", d.index_topk),
-                rope_theta=float(p.get_or("ropeTheta", d.rope_theta)),
-                rms_eps=float(p.get_or("rmsNormEps", d.rms_eps)),
-                aux_coef=float(p.get_or("auxLossCoef", d.aux_coef)),
-                learning_rate=p.get_or("learningRate", d.learning_rate),
-                **shared,
-            )
-        if backbone == "hybrid_linear":
-            d = HybridConfig(num_items=num_items)  # the defaults, in one place
-            experts = p.get_or("numExperts", d.num_experts)
-            return HybridConfig(
-                hidden_size=p.get_or("hiddenSize", d.hidden_size),
-                num_layers=p.get_or("numLayers", d.num_layers),
-                full_attention_interval=p.get_or(
-                    "fullAttentionInterval", d.full_attention_interval),
-                linear_key_heads=p.get_or("linearKeyHeads", d.linear_key_heads),
-                linear_value_heads=p.get_or("linearValueHeads", d.linear_value_heads),
-                linear_key_dim=p.get_or("linearKeyDim", d.linear_key_dim),
-                linear_value_dim=p.get_or("linearValueDim", d.linear_value_dim),
-                conv_kernel=p.get_or("convKernel", d.conv_kernel),
-                num_heads=p.get_or("numHeads", d.num_heads),
-                num_kv_heads=p.get_or("numKvHeads", d.num_kv_heads),
-                head_dim=p.get_or("headDim", d.head_dim),
-                rotary_fraction=float(p.get_or("partialRotaryFactor", d.rotary_fraction)),
-                expert_dim=p.get_or("expertDim", d.expert_dim),
-                num_experts=experts,
-                experts_per_token=p.get_or("expertsPerToken", d.experts_per_token),
-                experts_held=tuple(p.get_or("expertsHeld", (0, experts))),
-                shared_expert_dim=p.get_or("sharedExpertDim", d.shared_expert_dim),
-                rope_theta=float(p.get_or("ropeTheta", d.rope_theta)),
-                rms_eps=float(p.get_or("rmsNormEps", d.rms_eps)),
-                aux_coef=float(p.get_or("auxLossCoef", d.aux_coef)),
-                learning_rate=p.get_or("learningRate", d.learning_rate),
-                **shared,
-            )
-        if backbone == "latent_moe":
-            d = LatentMoEConfig(num_items=num_items)  # the defaults, in one place
-            experts = p.get_or("numExperts", d.num_experts)
-            return LatentMoEConfig(
-                hidden_size=p.get_or("hiddenSize", d.hidden_size),
-                num_layers=p.get_or("numLayers", d.num_layers),
-                dense_layers=p.get_or("denseLayers", d.dense_layers),
-                num_heads=p.get_or("numHeads", d.num_heads),
-                q_rank=p.get_or("qLoraRank", d.q_rank),
-                kv_rank=p.get_or("kvLoraRank", d.kv_rank),
-                nope_dim=p.get_or("qkNopeHeadDim", d.nope_dim),
-                rope_dim=p.get_or("qkRopeHeadDim", d.rope_dim),
-                value_dim=p.get_or("vHeadDim", d.value_dim),
-                ffn_dim=p.get_or("ffnDim", d.ffn_dim),
-                expert_dim=p.get_or("expertDim", d.expert_dim),
-                num_experts=experts,
-                experts_per_token=p.get_or("expertsPerToken", d.experts_per_token),
-                experts_held=tuple(p.get_or("expertsHeld", (0, experts))),
-                shared_expert_dim=p.get_or("sharedExpertDim", d.shared_expert_dim),
-                routed_scale=float(p.get_or("routedScalingFactor", d.routed_scale)),
-                mtp_depth=p.get_or("mtpDepth", d.mtp_depth),
-                mtp_coef=float(p.get_or("mtpLossCoef", d.mtp_coef)),
-                balance_coef=float(p.get_or("balanceLossCoef", d.balance_coef)),
-                bias_rate=float(p.get_or("biasUpdateRate", d.bias_rate)),
-                rope_theta=float(p.get_or("ropeTheta", d.rope_theta)),
-                rms_eps=float(p.get_or("rmsNormEps", d.rms_eps)),
-                learning_rate=p.get_or("learningRate", d.learning_rate),
-                **shared,
-            )
-        return SASRecConfig(
-            embed_dim=p.get_or("embedDim", 32),
-            num_heads=p.get_or("numHeads", 2),
-            num_blocks=p.get_or("numBlocks", 2),
-            ffn_dim=p.get_or("ffnDim", 64),
-            dropout=p.get_or("dropout", 0.0),
-            learning_rate=p.get_or("learningRate", 1e-3),
-            **shared,
-        )
+        module = BACKBONES[backbone]
+        defaults = module.CONFIG(num_items=num_items)
+        fields = {"num_items": num_items, "max_len": max_len}
+        for name, field in {**module.ENGINE_PARAMS, **FIT_PARAMS}.items():
+            default = getattr(defaults, field)
+            if field == "experts_held":       # all of the numExperts given
+                default = (0, fields["num_experts"])
+            fields[field] = type(default)(p.get_or(name, default))
+        return module.CONFIG(**fields)
 
     def train(self, ctx, prepared: PackedSequences) -> SASRecModel:
         p = self.params

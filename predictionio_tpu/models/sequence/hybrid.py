@@ -29,12 +29,10 @@ For one row ``x`` ``[T, D]``, ``n(.)`` the zero-centred RMSNorm
   ``x <- x + (attn sigmoid(gate)) W_o``;
 - **experts, every layer**: ``u = n2(x)``; the router, its top
   ``experts_per_token``, the renormalised gates, the held experts' part of the
-  sum and the load-balancing loss are ``sparse_moe._moe``'s, as they stand
+  sum and the load-balancing loss are ``experts.moe``'s, as they stand
   (``experts_held = (lo, hi)``: the router is whole, the other chips' parts are
   theirs to add, nothing stands in for them); beside it the shared expert,
-  worked on every token: ``sigmoid(u . w_sg) W2_s(silu(W1_s u) W3_s u)``. Of an
-  expert-parallel deployment's shares each adds the shared expert's part; it
-  is counted once when shares are added up;
+  worked on every token: ``sigmoid(u . w_sg) W2_s(silu(W1_s u) W3_s u)``;
 - final norm, an untied head, cross-entropy at the positions with a target
   plus ``aux_coef`` times the mean over the layers of the load-balancing loss.
 
@@ -69,7 +67,7 @@ mathematics with none of this):
   the state, ``g``, ``beta``, the l2 norms, the triangular system, the router,
   norms, rotary positions, softmax, residual stream, loss, master weights and
   Adam's moments are float32;
-- the head and loss are ``looped._exit_ce``'s chunks of positions.
+- the head and loss are ``blocks.exit_ce``'s chunks of positions.
 """
 
 from __future__ import annotations
@@ -78,18 +76,15 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from predictionio_tpu.models.sequence import looped, sparse_moe
+from predictionio_tpu.models.sequence import blocks, experts
 from predictionio_tpu.ops import delta_rule, sparse_attention as sa
 
-#: Device scopes of a training step beside ``looped``'s and ``sparse_moe``'s: a
+#: Device scopes of a training step beside ``blocks``'s and ``experts``'s: a
 #: linear layer's mixer under ``seq.pass1/layers/linear_attention`` (one
 #: component, so a reader that looks for ``attention`` does not take it), the
-#: full layer's under ``attention`` with ``looped``'s leaves, the shared expert
-#: under ``moe/shared``.
+#: full layer's under ``attention`` with ``blocks``'s leaves.
 SCOPE_LINEAR = "linear_attention"
-SCOPE_SHARED = "shared"
 #: Leaves under ``linear_attention`` beside ``norm``, ``qkv`` (the two input
 #: projections) and ``out``: ``conv`` (the depthwise convolution and its silu),
 #: ``gates`` (beta, g, the l2 norms), ``delta`` (the chunked rule and its
@@ -101,9 +96,7 @@ SCOPE_GATED_NORM = "gated_norm"
 
 
 @dataclass(frozen=True)
-class HybridConfig:
-    num_items: int              # real item vocab; id 0 is reserved for padding
-    max_len: int = 64
+class HybridConfig(experts.ExpertsConfig):
     hidden_size: int = 64
     num_layers: int = 4
     full_attention_interval: int = 4   # the last layer of every so many is the full one
@@ -116,34 +109,13 @@ class HybridConfig:
     num_kv_heads: int = 2
     head_dim: int = 32
     rotary_fraction: float = 0.25      # of a head's dimensions, the first, are rotated
-    expert_dim: int = 32
-    num_experts: int = 8
-    experts_per_token: int = 2
-    experts_held: tuple = (0, 8)       # [lo, hi) of the experts: this program's share
     shared_expert_dim: int = 32
     rope_theta: float = 1e7
-    rms_eps: float = 1e-6
     aux_coef: float = 0.001
-    learning_rate: float = 3e-4
-    batch_size: int = 256
-    epochs: int = 10
-    seed: int = 0
-    seq_parallel: str = "ring"
-    attention: str = "auto"
-    # how the step is worked: what the tests vary, and no engine parameter
-    compute_dtype: str = "bfloat16"   # matmul inputs; accumulation is float32
-    remat: bool = True
-    head_chunk: int | None = None     # None: from looped.HEAD_CHUNK_BYTES; 0: whole
-    moe_chunk: int | None = None      # None: from sparse_moe.MOE_CHUNK_BYTES
-    delta_chunk: int = delta_rule.CHUNK
+    delta_chunk: int = delta_rule.CHUNK   # as ``remat``: what the tests vary
 
     def __post_init__(self):
-        object.__setattr__(self, "experts_held", tuple(int(e) for e in self.experts_held))
-        lo, hi = self.experts_held
-        if not 0 <= lo < hi <= self.num_experts:
-            raise ValueError(
-                f"experts_held={self.experts_held}: want 0 <= lo < hi <= num_experts="
-                f"{self.num_experts}")
+        super().__post_init__()
         if self.full_attention_interval < 2 or self.num_layers % self.full_attention_interval:
             raise ValueError(
                 f"num_layers={self.num_layers} must be whole periods of"
@@ -153,27 +125,10 @@ class HybridConfig:
                                  "linear_key_heads")):
             if many % few:
                 raise ValueError(f"{what}={few} must divide the {many} heads it serves")
-        if not 1 <= self.experts_per_token <= self.num_experts:
-            raise ValueError(
-                f"experts_per_token={self.experts_per_token}: want 1 .. num_experts")
-        if self.attention not in ("auto", "flash", "plain"):
-            raise ValueError(
-                f"attention={self.attention!r} must be one of 'auto' | 'flash' | 'plain'")
-        if self.compute_dtype not in ("bfloat16", "float32"):
-            raise ValueError(
-                f"compute_dtype={self.compute_dtype!r}: want 'bfloat16' or 'float32'")
         if self.rotary_dim % 2 or not 0 < self.rotary_dim <= self.head_dim:
             raise ValueError(
                 f"rotary_fraction={self.rotary_fraction} of head_dim={self.head_dim} must"
                 " be an even count of dimensions")
-
-    @property
-    def vocab(self) -> int:
-        return self.num_items + 1  # +1 for the padding id 0
-
-    @property
-    def held(self) -> int:
-        return self.experts_held[1] - self.experts_held[0]
 
     @property
     def periods(self) -> int:
@@ -186,6 +141,18 @@ class HybridConfig:
     @property
     def rotary_dim(self) -> int:
         return int(self.head_dim * self.rotary_fraction)
+
+
+CONFIG = HybridConfig
+ENGINE_PARAMS = {
+    **experts.ENGINE_PARAMS, "hiddenSize": "hidden_size", "numLayers": "num_layers",
+    "fullAttentionInterval": "full_attention_interval", "linearKeyHeads": "linear_key_heads",
+    "linearValueHeads": "linear_value_heads", "linearKeyDim": "linear_key_dim",
+    "linearValueDim": "linear_value_dim", "convKernel": "conv_kernel", "numHeads": "num_heads",
+    "numKvHeads": "num_kv_heads", "headDim": "head_dim", "partialRotaryFactor": "rotary_fraction",
+    "sharedExpertDim": "shared_expert_dim", "ropeTheta": "rope_theta", "rmsNormEps": "rms_eps",
+    "auxLossCoef": "aux_coef",
+}
 
 
 def param_shapes(c: HybridConfig) -> dict:
@@ -228,40 +195,24 @@ def param_shapes(c: HybridConfig) -> dict:
     }
 
 
-#: zero-centred norm weights start at 0, the gated norm's plain weight at 1
-_ZEROS = ("n1", "n2", "q_norm", "k_norm", "final_norm")
-#: the projections that write into the residual stream
-_WRITERS = ("w_out", "wo", "w_down", "s_down")
-_is_shape = lambda x: isinstance(x, tuple)  # noqa: E731
-
-
 def init_params(c: HybridConfig, rng) -> dict:
-    """As ``sparse_moe.init_params`` (the embedding N(0, 1), matrices
-    N(0, 0.02), those that write into the residual stream N(0, 0.02 / sqrt(2 L)))
-    with the family's own: ``A_log = log U(0, 16)``, ``dt_bias = 1``, the conv
-    weights U(-1/2, 1/2) (``torch.nn.Conv1d``'s default at a fan-in of 4)."""
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(param_shapes(c), is_leaf=_is_shape)
-    out = []
-    for n, (path, shape) in enumerate(leaves):
-        name, key = path[-1].key, jax.random.fold_in(rng, n)
-        if name in _ZEROS:
-            out.append(jnp.zeros(shape, jnp.float32))
-        elif name in ("norm", "dt_bias"):
-            out.append(jnp.ones(shape, jnp.float32))
-        elif name == "a_log":
-            out.append(jnp.log(jax.random.uniform(key, shape, jnp.float32, 1e-3, 16.0)))
-        elif name == "conv":
-            out.append(jax.random.uniform(key, shape, jnp.float32, -0.5, 0.5))
-        else:
-            std = (1.0 if name == "embed" else
-                   0.02 / np.sqrt(2 * c.num_layers) if name in _WRITERS else 0.02)
-            out.append(std * jax.random.normal(key, shape, jnp.float32))
-    return jax.tree_util.tree_unflatten(treedef, out)
+    """The embedding N(0, 1), matrices N(0, 0.02), those that write into the
+    residual stream scaled down (``blocks.writer_stds``), with the
+    family's own: zero-centred norm weights 0 and the gated norm's plain weight
+    1, ``A_log = log U(0, 16)``, ``dt_bias = 1``, the conv weights U(-1/2, 1/2)
+    (``torch.nn.Conv1d``'s default at a fan-in of 4)."""
+    return blocks.draw_params(
+        param_shapes(c), rng, ones=("norm", "dt_bias"),
+        zeros=("n1", "n2", "q_norm", "k_norm", "final_norm"),
+        stds=blocks.writer_stds(("w_out", "wo", "w_down", "s_down"), c.num_layers),
+        draws={"a_log": lambda key, shape: jnp.log(
+                   jax.random.uniform(key, shape, jnp.float32, 1e-3, 16.0)),
+               "conv": lambda key, shape: jax.random.uniform(
+                   key, shape, jnp.float32, -0.5, 0.5)})
 
 
 def count_params(c: HybridConfig) -> int:
-    return sum(int(np.prod(s)) for s in jax.tree_util.tree_leaves(
-        param_shapes(c), is_leaf=_is_shape))
+    return blocks.count_params(param_shapes(c))
 
 
 def delta_state_bytes(c: HybridConfig) -> int:
@@ -291,13 +242,27 @@ def delta_heads_per_step(c: HybridConfig, rows: int) -> int:
 
 def attention_backward_heads_per_step(c: HybridConfig) -> int:
     """The key-value heads a grid step of the full layer's backward attention
-    program works on a row of ``max_len``: the sparse backbone's grouped heads
-    with no mask operand."""
-    return sparse_moe.attention_backward_heads_per_step(c, masked=False)
+    program works on a row of ``max_len`` (``ops/sparse_attention``, from the
+    shapes alone): grouped heads with no mask operand."""
+    return sa.backward_heads_per_step(
+        c.num_kv_heads, c.num_heads // c.num_kv_heads, c.head_dim, c.head_dim, c.max_len,
+        jnp.dtype(c.compute_dtype).itemsize, False)
 
 
-def _norm0(x, weight, eps):
-    return looped._rms_norm(x, 1.0 + weight, eps)
+def fit_attrs(c: HybridConfig, rows: int, platform: str) -> dict:
+    """The backbone's part of the fit's span, for a step on ``rows`` rows."""
+    return {
+        **blocks.decoder_fit_attrs(c, c.num_layers, halves=True),
+        **experts.fit_attrs(c, platform, attention_backward_heads_per_step(c), shared=True),
+        "kv_heads": c.num_kv_heads, "selection_kept_bytes": 0,
+        "linear_layers": c.linear_layers, "full_layers": c.periods,
+        "delta_chunk": c.delta_chunk, "delta_heads_per_step": delta_heads_per_step(c, rows),
+        "delta_state_bytes": delta_state_bytes(c), "delta_kept_bytes": delta_kept_bytes(c, rows),
+    }
+
+
+def norm0(x, weight, eps):
+    return blocks.rms_norm(x, 1.0 + weight, eps)
 
 
 # ---- the mixers --------------------------------------------------------------
@@ -322,12 +287,12 @@ def _linear_attention(c: HybridConfig, backend: str, h, p, real):
     hk, hv, dk, dv = (c.linear_key_heads, c.linear_value_heads, c.linear_key_dim,
                       c.linear_value_dim)
     on = real[..., None]
-    with jax.named_scope(looped.SCOPE_QKV):
+    with jax.named_scope(blocks.SCOPE_QKV):
         # two products, so that the gate z is not a view into one array that
         # holds q, k and v until the backward pass is done with z
         w_mixed, w_z = jnp.split(p["w_qkvz"], [2 * hk * dk + hv * dv], axis=-1)
-        mixed, z = looped._matmul(h, w_mixed, dtype), looped._matmul(h, w_z, dtype)
-        beta, a = jnp.split(looped._matmul(h, p["w_ba"], dtype), 2, axis=-1)
+        mixed, z = blocks.matmul(h, w_mixed, dtype), blocks.matmul(h, w_z, dtype)
+        beta, a = jnp.split(blocks.matmul(h, p["w_ba"], dtype), 2, axis=-1)
     with jax.named_scope(SCOPE_CONV):
         # kept: the input alone; the products and the silu are worked again
         mixed = jax.checkpoint(lambda x, w: jax.nn.silu(_causal_conv(x, w)))(
@@ -341,9 +306,9 @@ def _linear_attention(c: HybridConfig, backend: str, h, p, real):
     with jax.named_scope(SCOPE_DELTA):
         o = delta_rule.gated_delta_rule(
             q, k, v.reshape(b, t, hv, dv), g, beta, chunk=c.delta_chunk, dtype=dtype,
-            kernels=sparse_moe.uses_kernels(c, backend), interpret=backend != "tpu")
+            kernels=blocks.uses_kernels(c, backend), interpret=backend != "tpu")
     with jax.named_scope(SCOPE_GATED_NORM):
-        y = looped._rms_norm(o, p["norm"], c.rms_eps) * jax.nn.silu(z.reshape(b, t, hv, dv))
+        y = blocks.rms_norm(o, p["norm"], c.rms_eps) * jax.nn.silu(z.reshape(b, t, hv, dv))
     return y.reshape(b, t, hv * dv)
 
 
@@ -352,19 +317,19 @@ def _full_attention(c: HybridConfig, backend: str, rope, h, p):
     dtype = jnp.dtype(c.compute_dtype)
     b, t, _ = h.shape
     hd, rd = c.head_dim, c.rotary_dim
-    with jax.named_scope(looped.SCOPE_QKV):
-        q, gate = jnp.split(looped._matmul(h, p["wq"], dtype).reshape(b, t, c.num_heads, 2 * hd),
+    with jax.named_scope(blocks.SCOPE_QKV):
+        q, gate = jnp.split(blocks.matmul(h, p["wq"], dtype).reshape(b, t, c.num_heads, 2 * hd),
                             2, axis=-1)
-        k, v = (looped._matmul(h, p[w], dtype).reshape(b, t, c.num_kv_heads, hd)
+        k, v = (blocks.matmul(h, p[w], dtype).reshape(b, t, c.num_kv_heads, hd)
                 for w in ("wk", "wv"))
-    with jax.named_scope(looped.SCOPE_NORM):
-        q, k = _norm0(q, p["q_norm"], c.rms_eps), _norm0(k, p["k_norm"], c.rms_eps)
-    with jax.named_scope(looped.SCOPE_ROPE):
-        q, k = (jnp.concatenate([looped._rotate(x[..., :rd], *rope), x[..., rd:]], axis=-1)
+    with jax.named_scope(blocks.SCOPE_NORM):
+        q, k = norm0(q, p["q_norm"], c.rms_eps), norm0(k, p["k_norm"], c.rms_eps)
+    with jax.named_scope(blocks.SCOPE_ROPE):
+        q, k = (jnp.concatenate([blocks.rotate(x[..., :rd], *rope), x[..., rd:]], axis=-1)
                 for x in (q, k))
-    with jax.named_scope(looped.SCOPE_KERNEL):
+    with jax.named_scope(blocks.SCOPE_KERNEL):
         q, k, v = q.astype(dtype), k.astype(dtype), v.astype(dtype)
-        if sparse_moe.uses_kernels(c, backend):
+        if blocks.uses_kernels(c, backend):
             out = sa.causal_attention(q, k, v, sa.BLOCK_Q, sa.BLOCK_K, backend != "tpu")
         else:
             out = sa.causal_attention_plain(q, k, v)
@@ -372,41 +337,24 @@ def _full_attention(c: HybridConfig, backend: str, rope, h, p):
     return out.reshape(b, t, -1)
 
 
-def _experts(c: HybridConfig, backend: str, x, p, real):
-    """``(x', stats)``: the held routed experts' part and the shared expert
-    added to the residual stream ``x`` [B, T, D]."""
-    dtype = jnp.dtype(c.compute_dtype)
-    with jax.named_scope(sparse_moe.SCOPE_MOE):
-        with jax.named_scope(looped.SCOPE_NORM):
-            u = _norm0(x, p["n2"], c.rms_eps)
-        flat = u.reshape(-1, u.shape[-1])
-        y, stats = sparse_moe._moe(c, backend, flat, p, real.reshape(-1))
-        with jax.named_scope(SCOPE_SHARED):
-            inner = (jax.nn.silu(looped._matmul(flat, p["s_gate"], dtype))
-                     * looped._matmul(flat, p["s_up"], dtype))
-            gate = jax.nn.sigmoid(jnp.matmul(flat, p["s_g"], precision=jax.lax.Precision.HIGHEST))
-            y = y + gate[:, None] * looped._matmul(inner, p["s_down"], dtype)
-        return x + y.reshape(x.shape), stats
-
-
 def _linear_mixer(c: HybridConfig, backend: str, real, x, p):
     dtype = jnp.dtype(c.compute_dtype)
     with jax.named_scope(SCOPE_LINEAR):
-        with jax.named_scope(looped.SCOPE_NORM):
-            h = _norm0(x, p["n1"], c.rms_eps)
+        with jax.named_scope(blocks.SCOPE_NORM):
+            h = norm0(x, p["n1"], c.rms_eps)
         y = _linear_attention(c, backend, h, p, real)
-        with jax.named_scope(looped.SCOPE_OUT):
-            return x + looped._matmul(y, p["w_out"], dtype)
+        with jax.named_scope(blocks.SCOPE_OUT):
+            return x + blocks.matmul(y, p["w_out"], dtype)
 
 
 def _full_mixer(c: HybridConfig, backend: str, rope, x, p):
     dtype = jnp.dtype(c.compute_dtype)
-    with jax.named_scope(looped.SCOPE_ATTENTION):
-        with jax.named_scope(looped.SCOPE_NORM):
-            h = _norm0(x, p["n1"], c.rms_eps)
+    with jax.named_scope(blocks.SCOPE_ATTENTION):
+        with jax.named_scope(blocks.SCOPE_NORM):
+            h = norm0(x, p["n1"], c.rms_eps)
         out = _full_attention(c, backend, rope, h, p)
-        with jax.named_scope(looped.SCOPE_OUT):
-            return x + looped._matmul(out, p["wo"], dtype)
+        with jax.named_scope(blocks.SCOPE_OUT):
+            return x + blocks.matmul(out, p["wo"], dtype)
 
 
 # ---- the stack ---------------------------------------------------------------
@@ -414,21 +362,21 @@ def _full_mixer(c: HybridConfig, backend: str, rope, x, p):
 def hidden_states(c: HybridConfig, backend: str, params, seq):
     """``(x, stats)``: the residual stream after the last layer ``[B, T, D]``
     and every layer's counts ``[layers, ...]``, under the pass's scope."""
-    with jax.named_scope(looped.SCOPE_EMBED):
+    with jax.named_scope(blocks.SCOPE_EMBED):
         real = seq > 0
-        rope = looped._rope_tables(seq.shape[1], c.rotary_dim, c.rope_theta)
+        rope = blocks.rope_tables(seq.shape[1], c.rotary_dim, c.rope_theta)
         x = jnp.take(params["embed"], seq, axis=0)
 
     kept = jax.checkpoint if c.remat else (lambda half: half)
-    experts = kept(lambda x, p: _experts(c, backend, x, p, real))
+    expert_half = kept(lambda x, p: experts.expert_half(c, backend, x, p, real, norm0))
     linear_mixer = kept(lambda x, p: _linear_mixer(c, backend, real, x, p))
     full_mixer = kept(lambda x, p: _full_mixer(c, backend, rope, x, p))
 
     def linear(carry, layer):
-        return experts(linear_mixer(carry, layer), layer)
+        return expert_half(linear_mixer(carry, layer), layer)
 
     def full(carry, layer):
-        return experts(full_mixer(carry, layer), layer)
+        return expert_half(full_mixer(carry, layer), layer)
 
     def period(carry, p):
         carry, stats = jax.lax.scan(linear, carry, p["linear"])
@@ -436,7 +384,7 @@ def hidden_states(c: HybridConfig, backend: str, params, seq):
         return carry, jax.tree_util.tree_map(
             lambda a, b: jnp.concatenate([a, b[None]]), stats, last)
 
-    with jax.named_scope(looped.SCOPE_PASS.format(1)), jax.named_scope(looped.SCOPE_LAYERS):
+    with jax.named_scope(blocks.SCOPE_PASS.format(1)), jax.named_scope(blocks.SCOPE_LAYERS):
         x, stats = jax.lax.scan(period, x, params["periods"])
     return x, jax.tree_util.tree_map(lambda a: a.reshape(-1, *a.shape[2:]), stats)
 
@@ -445,32 +393,16 @@ def make_loss(c: HybridConfig, mesh):
     """``loss_fn(params, batch, rng) -> (loss, aux)`` for the trainer's step;
     ``aux`` is scalars: the two terms of the loss and the step's counts, under
     ``sparse_moe.make_loss``'s names."""
-    backend = sparse_moe._backend_of(mesh)
+    backend = blocks.backend_of(mesh, whole_rows=True)
 
     def loss_fn(params, batch, rng):
         del rng  # no dropout in this block
         seq, targets = batch["seq"], batch["target"]
         x, stats = hidden_states(c, backend, params, seq)
-        with jax.named_scope(looped.SCOPE_PASS.format(1)), jax.named_scope(looped.SCOPE_EXIT):
-            h = _norm0(x, params["final_norm"], c.rms_eps)
-            ce = looped._exit_ce(c, h.reshape(-1, h.shape[-1]), params["head"],
-                                 targets.reshape(-1))
-            mask = (targets.reshape(-1) > 0).astype(jnp.float32)
-            ce = (ce * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+        with jax.named_scope(blocks.SCOPE_PASS.format(1)), jax.named_scope(blocks.SCOPE_EXIT):
+            ce = blocks.masked_ce(c, x, params["final_norm"], params["head"], targets, norm0)
             aux_loss = stats["aux"].mean()
-            held = stats["held_assignments"].sum()
-            out = {
-                "ce": ce, "aux_loss": aux_loss,
-                "moe_assignments": stats["assignments"].sum(),
-                "moe_held_assignments": held,
-                "moe_held_load_max": stats["held_load_max"].max(),
-                "moe_held_load_mean": held / (c.num_layers * c.held),
-                "moe_dropped": stats["dropped"].sum(),
-                "moe_passes": stats["passes"].sum(),
-                "moe_passes_run": stats["passes_run"].sum(),
-                "moe_sum_rows": stats["sum_rows"].sum(),
-                "moe_sum_slots": stats["sum_slots"].sum(),
-            }
+            out = {"ce": ce, "aux_loss": aux_loss, **experts.counts(c, stats)}
             return ce + c.aux_coef * aux_loss, out
 
     return loss_fn
@@ -479,7 +411,5 @@ def make_loss(c: HybridConfig, mesh):
 def score_last(c: HybridConfig, params, seqs, last):
     """Next-item scores [B, V] at position ``last`` of each row: the whole
     history a query (no state is carried from one query to the next)."""
-    x, _ = hidden_states(c, sparse_moe._backend_of(None), params, seqs)
-    h = _norm0(x, params["final_norm"], c.rms_eps)
-    h = jnp.take_along_axis(h, last[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    return looped._matmul(h, params["head"].T, jnp.dtype(c.compute_dtype))
+    x, _ = hidden_states(c, blocks.backend_of(None), params, seqs)
+    return blocks.score_last(c, x, params["final_norm"], params["head"], last, norm0)

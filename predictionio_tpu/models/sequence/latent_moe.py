@@ -25,13 +25,13 @@ one row ``x`` ``[T, D]``, ``n(.)`` RMSNorm with a plain weight:
   float32; ``E_t`` the ``experts_per_token`` largest of ``s + b``;
   ``g = s[E_t]`` (without ``b``), ``g <- routed_scale g / (sum g + 1e-20)``;
   ``x <- x + sum_{e in E_t, e held here} g_e FFN_e(u) + FFN_shared(u)``; the
-  shared expert has no gate. ``experts_held`` is ``sparse_moe``'s: the router
+  shared expert has no gate. ``experts_held`` is ``experts.py``'s: the router
   is whole, this program adds the held experts' part, and of an
   expert-parallel deployment's shares each adds the shared expert's part;
 - **the bias** ``b`` (``router_bias``, ``[E]`` a layer, float32) is in no
   gradient and has no moments: after a step it moves by ``bias_rate`` against
   that step's load, ``b_e += bias_rate sign(mean(load) - load_e)``
-  (``move_bias``, which ``model.make_train_step`` runs inside the step);
+  (``move``, which ``model.make_train_step`` runs inside the step);
 - **the balance loss** that goes with it, a row at a time:
   ``sum_e f_e P_e``, ``f_e = E / (K T_r)`` times the row's real positions that
   chose ``e``, ``P_e`` the row's mean of ``s_e / sum_j s_j``; the mean over
@@ -55,13 +55,13 @@ with none of this):
   of width ``nope_dim + rope_dim`` and ``v`` of ``value_dim``: ``k_r`` is laid
   beside every head's ``k_nope`` in HBM, eight heads a grid step; off the TPU
   its plain twin;
-- the router is this module's (``_route``); the held experts' passes are
-  ``sparse_moe._moe``'s, as they stand;
+- the router is this module's (``route``); the held experts' passes are
+  ``experts.moe``'s, as they stand;
 - matmul inputs are ``compute_dtype`` (bfloat16) with float32 accumulation;
   the two latent norms, the router's matmul, sigmoid, the bias, top-k and
   gates, the residual stream, norms, rotary positions, softmax, losses, master
   weights and Adam's moments are float32;
-- the heads and losses are ``looped._exit_ce``'s chunks of positions.
+- the heads and losses are ``blocks.exit_ce``'s chunks of positions.
 """
 
 from __future__ import annotations
@@ -71,19 +71,16 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from predictionio_tpu.models.sequence import looped, sparse_moe
+from predictionio_tpu.models.sequence import blocks, experts
 from predictionio_tpu.ops import sparse_attention as sa
 
-#: Device scopes beside ``looped``'s and ``sparse_moe``'s: inside
-#: ``attention/qkv`` the two latent paths; the shared expert under
-#: ``moe/shared`` (the hybrid's name); the prediction module under
+#: Device scopes beside ``blocks``'s and ``experts``'s: inside
+#: ``attention/qkv`` the two latent paths; the prediction module under
 #: ``seq.pass1/mtp`` with ``merge``, the layer's own ``layers/...`` and
 #: ``exit``; the bias's move under ``seq.optimizer/bias``.
 SCOPE_Q_LATENT = "q_latent"
 SCOPE_KV_LATENT = "kv_latent"
-SCOPE_SHARED = "shared"
 SCOPE_MTP = "mtp"
 SCOPE_MERGE = "merge"
 SCOPE_BIAS = "bias"
@@ -92,9 +89,7 @@ BIAS = "router_bias"
 
 
 @dataclass(frozen=True)
-class LatentMoEConfig:
-    num_items: int              # real item vocab; id 0 is reserved for padding
-    max_len: int = 64
+class LatentMoEConfig(experts.ExpertsConfig):
     hidden_size: int = 64
     num_layers: int = 3         # the dense layers and the expert layers, not the module
     dense_layers: int = 1       # leading layers with a dense MLP
@@ -105,10 +100,6 @@ class LatentMoEConfig:
     rope_dim: int = 8           # the rotary key's, shared by the heads
     value_dim: int = 16
     ffn_dim: int = 128          # a dense layer's MLP
-    expert_dim: int = 32
-    num_experts: int = 8
-    experts_per_token: int = 2
-    experts_held: tuple = (0, 8)    # [lo, hi) of the experts: this program's share
     shared_expert_dim: int = 32
     routed_scale: float = 2.5
     mtp_depth: int = 1          # 0: no prediction module
@@ -116,51 +107,17 @@ class LatentMoEConfig:
     balance_coef: float = 1e-4
     bias_rate: float = 1e-3
     rope_theta: float = 3.2e7
-    rms_eps: float = 1e-6
-    learning_rate: float = 3e-4
-    batch_size: int = 256
-    epochs: int = 10
-    seed: int = 0
-    seq_parallel: str = "ring"
-    attention: str = "auto"
-    # how the step is worked: what the tests vary, and no engine parameter
-    compute_dtype: str = "bfloat16"   # matmul inputs; accumulation is float32
-    remat: bool = True
-    head_chunk: int | None = None     # None: from looped.HEAD_CHUNK_BYTES; 0: whole
-    moe_chunk: int | None = None      # None: from sparse_moe.MOE_CHUNK_BYTES
 
     def __post_init__(self):
-        object.__setattr__(self, "experts_held", tuple(int(e) for e in self.experts_held))
-        lo, hi = self.experts_held
-        if not 0 <= lo < hi <= self.num_experts:
-            raise ValueError(
-                f"experts_held={self.experts_held}: want 0 <= lo < hi <= num_experts="
-                f"{self.num_experts}")
+        super().__post_init__()
         if not 0 <= self.dense_layers < self.num_layers:
             raise ValueError(
                 f"dense_layers={self.dense_layers}: want 0 .. num_layers - 1 ="
                 f" {self.num_layers - 1} (an expert layer at least)")
-        if not 1 <= self.experts_per_token <= self.num_experts:
-            raise ValueError(
-                f"experts_per_token={self.experts_per_token}: want 1 .. num_experts")
         if self.mtp_depth not in (0, 1):
             raise ValueError(f"mtp_depth={self.mtp_depth}: want 0 or 1")
-        if self.attention not in ("auto", "flash", "plain"):
-            raise ValueError(
-                f"attention={self.attention!r} must be one of 'auto' | 'flash' | 'plain'")
-        if self.compute_dtype not in ("bfloat16", "float32"):
-            raise ValueError(
-                f"compute_dtype={self.compute_dtype!r}: want 'bfloat16' or 'float32'")
         if self.rope_dim % 2:
             raise ValueError(f"rope_dim={self.rope_dim} must be even (rotary pairs)")
-
-    @property
-    def vocab(self) -> int:
-        return self.num_items + 1  # +1 for the padding id 0
-
-    @property
-    def held(self) -> int:
-        return self.experts_held[1] - self.experts_held[0]
 
     @property
     def expert_layers(self) -> int:
@@ -178,6 +135,18 @@ class LatentMoEConfig:
     @property
     def score_dim(self) -> int:
         return self.nope_dim + self.rope_dim
+
+
+CONFIG = LatentMoEConfig
+ENGINE_PARAMS = {
+    **experts.ENGINE_PARAMS, "hiddenSize": "hidden_size", "numLayers": "num_layers",
+    "denseLayers": "dense_layers", "numHeads": "num_heads", "qLoraRank": "q_rank",
+    "kvLoraRank": "kv_rank", "qkNopeHeadDim": "nope_dim", "qkRopeHeadDim": "rope_dim",
+    "vHeadDim": "value_dim", "ffnDim": "ffn_dim", "sharedExpertDim": "shared_expert_dim",
+    "routedScalingFactor": "routed_scale", "mtpDepth": "mtp_depth", "mtpLossCoef": "mtp_coef",
+    "balanceLossCoef": "balance_coef", "biasUpdateRate": "bias_rate", "ropeTheta": "rope_theta",
+    "rmsNormEps": "rms_eps",
+}
 
 
 def param_shapes(c: LatentMoEConfig) -> dict:
@@ -218,40 +187,24 @@ def param_shapes(c: LatentMoEConfig) -> dict:
     return shapes
 
 
-_NORMS = ("n1", "n2", "q_norm", "kv_norm", "final_norm", "embed_norm", "hidden_norm")
-#: the projections that write into the residual stream
-_WRITERS = ("wo", "w_down", "s_down")
-_is_shape = lambda x: isinstance(x, tuple)  # noqa: E731
-
-
 def init_params(c: LatentMoEConfig, rng) -> dict:
-    """As ``sparse_moe.init_params`` (norm weights 1, the embedding N(0, 1),
-    matrices N(0, 0.02), those that write into the residual stream
-    N(0, 0.02 / sqrt(2 L))); the router's bias starts at 0."""
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(param_shapes(c), is_leaf=_is_shape)
-    out = []
-    for n, (path, shape) in enumerate(leaves):
-        name = path[-1].key
-        if name in _NORMS:
-            out.append(jnp.ones(shape, jnp.float32))
-        elif name == BIAS:
-            out.append(jnp.zeros(shape, jnp.float32))
-        else:
-            std = (1.0 if name == "embed" else
-                   0.02 / np.sqrt(2 * c.num_layers) if name in _WRITERS else 0.02)
-            out.append(std * jax.random.normal(jax.random.fold_in(rng, n), shape, jnp.float32))
-    return jax.tree_util.tree_unflatten(treedef, out)
+    """Norm weights 1, the embedding N(0, 1), matrices N(0, 0.02), those that
+    write into the residual stream scaled down (``blocks.writer_stds``); the
+    router's bias starts at 0."""
+    return blocks.draw_params(
+        param_shapes(c), rng, zeros=(BIAS,),
+        ones=("n1", "n2", "q_norm", "kv_norm", "final_norm", "embed_norm", "hidden_norm"),
+        stds=blocks.writer_stds(("wo", "w_down", "s_down"), c.num_layers))
 
 
 def count_params(c: LatentMoEConfig) -> int:
     """The trained parameters: every leaf but the routers' biases."""
-    leaves = jax.tree_util.tree_flatten_with_path(param_shapes(c), is_leaf=_is_shape)[0]
-    return sum(int(np.prod(shape)) for path, shape in leaves if path[-1].key != BIAS)
+    return blocks.count_params(param_shapes(c), but=(BIAS,))
 
 
 def trained_labels(params) -> dict:
     """``"train"`` or ``"fixed"`` for every leaf: a router's bias is fixed as
-    far as the optimizer goes (``move_bias`` moves it)."""
+    far as the optimizer goes (``move`` moves it)."""
     return jax.tree_util.tree_map_with_path(
         lambda path, _: "fixed" if path[-1].key == BIAS else "train", params)
 
@@ -271,16 +224,29 @@ def attention_backward_heads_per_step(c: LatentMoEConfig) -> int:
         jnp.dtype(c.compute_dtype).itemsize)
 
 
+def fit_attrs(c: LatentMoEConfig, rows: int, platform: str) -> dict:
+    """The backbone's part of the fit's span."""
+    return {
+        **blocks.decoder_fit_attrs(c, c.num_layers, halves=True),
+        **experts.fit_attrs(c, platform, attention_backward_heads_per_step(c), shared=True),
+        "kv_heads": c.num_kv_heads, "selection_kept_bytes": 0,
+        "dense_layers": c.dense_layers, "mtp_depth": c.mtp_depth,
+        "latent_q_rank": c.q_rank, "latent_kv_rank": c.kv_rank,
+        "score_width": c.score_dim, "value_width": c.value_dim,
+        "latent_bytes_per_token": latent_bytes_per_token(c), "router_bias_leaves": c.routers,
+    }
+
+
 # ---- latent attention --------------------------------------------------------
 
-def _rope_tables(t: int, dim: int, theta: float):
+def rope_tables(t: int, dim: int, theta: float):
     """``cos, sin`` of ``[T, dim]``, a frequency for each pair ``(2i, 2i + 1)``."""
     inv = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
     angle = jnp.repeat(jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :], 2, axis=-1)
     return jnp.cos(angle), jnp.sin(angle)
 
 
-def _rotate(x, cos, sin):
+def rotate(x, cos, sin):
     """Rotary positions on ``x`` [B, T, H, dim], pairs interleaved: ``(x[2i],
     x[2i + 1])`` turns by the position's angle ``i``. The pair's other member
     comes by a roll either way and a choice by parity: no strided access."""
@@ -295,23 +261,23 @@ def _attention(c: LatentMoEConfig, backend: str, rope, h, p):
     dtype = jnp.dtype(c.compute_dtype)
     b, t, _ = h.shape
     heads, dn = c.num_heads, c.nope_dim
-    with jax.named_scope(looped.SCOPE_QKV):
+    with jax.named_scope(blocks.SCOPE_QKV):
         with jax.named_scope(SCOPE_Q_LATENT):
-            c_q = looped._rms_norm(looped._matmul(h, p["w_qa"], dtype), p["q_norm"], c.rms_eps)
-            q = looped._matmul(c_q, p["w_qb"], dtype).reshape(b, t, heads, c.score_dim)
+            c_q = blocks.rms_norm(blocks.matmul(h, p["w_qa"], dtype), p["q_norm"], c.rms_eps)
+            q = blocks.matmul(c_q, p["w_qb"], dtype).reshape(b, t, heads, c.score_dim)
         with jax.named_scope(SCOPE_KV_LATENT):
-            c_kv, k_r = jnp.split(looped._matmul(h, p["w_kva"], dtype), [c.kv_rank], axis=-1)
-            c_kv = looped._rms_norm(c_kv, p["kv_norm"], c.rms_eps)
+            c_kv, k_r = jnp.split(blocks.matmul(h, p["w_kva"], dtype), [c.kv_rank], axis=-1)
+            c_kv = blocks.rms_norm(c_kv, p["kv_norm"], c.rms_eps)
             k_nope, v = jnp.split(
-                looped._matmul(c_kv, p["w_kvb"], dtype).reshape(b, t, heads, dn + c.value_dim),
+                blocks.matmul(c_kv, p["w_kvb"], dtype).reshape(b, t, heads, dn + c.value_dim),
                 [dn], axis=-1)
-    with jax.named_scope(looped.SCOPE_ROPE):
-        q = jnp.concatenate([q[..., :dn], _rotate(q[..., dn:], *rope)], axis=-1)
-        k_r = _rotate(k_r[:, :, None, :], *rope)          # one key a position, every head's
+    with jax.named_scope(blocks.SCOPE_ROPE):
+        q = jnp.concatenate([q[..., :dn], rotate(q[..., dn:], *rope)], axis=-1)
+        k_r = rotate(k_r[:, :, None, :], *rope)          # one key a position, every head's
         k = jnp.concatenate([k_nope, jnp.broadcast_to(k_r, (b, t, heads, c.rope_dim))], axis=-1)
-    with jax.named_scope(looped.SCOPE_KERNEL):
+    with jax.named_scope(blocks.SCOPE_KERNEL):
         q, k, v = q.astype(dtype), k.astype(dtype), v.astype(dtype)
-        if sparse_moe.uses_kernels(c, backend):
+        if blocks.uses_kernels(c, backend):
             out = sa.causal_attention(q, k, v, sa.BLOCK_Q, sa.BLOCK_K, backend != "tpu")
         else:
             out = sa.causal_attention_plain(q, k, v)
@@ -320,99 +286,74 @@ def _attention(c: LatentMoEConfig, backend: str, rope, h, p):
 
 def _mixer(c: LatentMoEConfig, backend: str, rope, x, p):
     dtype = jnp.dtype(c.compute_dtype)
-    with jax.named_scope(looped.SCOPE_ATTENTION):
-        with jax.named_scope(looped.SCOPE_NORM):
-            h = looped._rms_norm(x, p["n1"], c.rms_eps)
+    with jax.named_scope(blocks.SCOPE_ATTENTION):
+        with jax.named_scope(blocks.SCOPE_NORM):
+            h = blocks.rms_norm(x, p["n1"], c.rms_eps)
         out = _attention(c, backend, rope, h, p)
-        with jax.named_scope(looped.SCOPE_OUT):
-            return x + looped._matmul(out, p["wo"], dtype)
+        with jax.named_scope(blocks.SCOPE_OUT):
+            return x + blocks.matmul(out, p["wo"], dtype)
 
 
 # ---- the MLPs ----------------------------------------------------------------
 
-def _swiglu(u, w_gate, w_up, w_down, dtype):
-    inner = jax.nn.silu(looped._matmul(u, w_gate, dtype)) * looped._matmul(u, w_up, dtype)
-    return looped._matmul(inner, w_down, dtype)
-
-
 def _dense_mlp(c: LatentMoEConfig, x, p):
-    with jax.named_scope(looped.SCOPE_MLP):
-        with jax.named_scope(looped.SCOPE_NORM):
-            u = looped._rms_norm(x, p["n2"], c.rms_eps)
-        return x + _swiglu(u, p["w_gate"], p["w_up"], p["w_down"], jnp.dtype(c.compute_dtype))
+    with jax.named_scope(blocks.SCOPE_MLP):
+        with jax.named_scope(blocks.SCOPE_NORM):
+            u = blocks.rms_norm(x, p["n2"], c.rms_eps)
+        return x + blocks.swiglu(u, p["w_gate"], p["w_up"], p["w_down"],
+                                 jnp.dtype(c.compute_dtype))
 
 
-def _route(c: LatentMoEConfig, u, p, real, rows: int):
+def route(c: LatentMoEConfig, u, p, real, rows: int):
     """``(experts, gates, stats)`` for the normed tokens ``u`` [N, D] of
     ``rows`` rows: the ``experts_per_token`` largest of the sigmoid scores plus
     the bias, their gates from the scores alone, and the layer's counts under
-    ``sparse_moe._route``'s names with the whole ``load`` [E] beside them;
+    ``experts.route``'s names with the whole ``load`` [E] beside them;
     ``aux`` is the balance loss, a row at a time."""
     scores = jax.nn.sigmoid(jnp.matmul(u, p["router"], precision=jax.lax.Precision.HIGHEST))
-    experts = jax.lax.top_k(scores + jax.lax.stop_gradient(p[BIAS]), c.experts_per_token)[1]
-    picked = jnp.take_along_axis(scores, experts, axis=-1)
+    chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(p[BIAS]), c.experts_per_token)[1]
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
     gates = c.routed_scale * picked / (picked.sum(axis=-1, keepdims=True) + 1e-20)
     by_row = lambda a: a.reshape(rows, -1, *a.shape[1:])  # noqa: E731
     on = by_row(real)
-    chosen = (by_row(experts)[..., None] == jnp.arange(c.num_experts)) & on[..., None, None]
-    row_load = chosen.sum(axis=(1, 2))                                   # [rows, E]
+    takes = (by_row(chosen)[..., None] == jnp.arange(c.num_experts)) & on[..., None, None]
+    row_load = takes.sum(axis=(1, 2))                                    # [rows, E]
     count = jnp.maximum(on.sum(axis=1), 1).astype(jnp.float32)[:, None]
     share = by_row(scores / scores.sum(axis=-1, keepdims=True))
     mean_share = jnp.where(on[..., None], share, 0.0).sum(axis=1) / count
     often = row_load.astype(jnp.float32) * (c.num_experts / c.experts_per_token) / count
     load = row_load.sum(axis=0)
-    return experts, gates, {"aux": (often * mean_share).sum(axis=-1).mean(),
-                            **sparse_moe.load_stats(c, load), "load": load}
-
-
-def _experts(c: LatentMoEConfig, backend: str, x, p, real):
-    """``(x', stats)``: the held routed experts' part and the shared expert
-    added to the residual stream ``x`` [B, T, D]."""
-    dtype = jnp.dtype(c.compute_dtype)
-    with jax.named_scope(sparse_moe.SCOPE_MOE):
-        with jax.named_scope(looped.SCOPE_NORM):
-            u = looped._rms_norm(x, p["n2"], c.rms_eps)
-        flat = u.reshape(-1, u.shape[-1])
-        y, stats = sparse_moe._moe(c, backend, flat, p, real.reshape(-1),
-                                   functools.partial(_route, rows=x.shape[0]))
-        with jax.named_scope(SCOPE_SHARED):
-            y = y + _swiglu(flat, p["s_gate"], p["s_up"], p["s_down"], dtype)
-        return x + y.reshape(x.shape), stats
+    return chosen, gates, {"aux": (often * mean_share).sum(axis=-1).mean(),
+                            **experts.load_stats(c, load), "load": load}
 
 
 # ---- the stack and the module -------------------------------------------------
 
 def _halves(c: LatentMoEConfig, backend: str, rope):
-    """``(mixer, dense_mlp, experts)``, each rematerialised from its input."""
+    """``(mixer, dense_mlp, expert_half)``, each rematerialised from its input."""
     kept = jax.checkpoint if c.remat else (lambda half: half)
     return (kept(lambda x, p: _mixer(c, backend, rope, x, p)),
             kept(lambda x, p: _dense_mlp(c, x, p)),
-            kept(lambda x, p, real: _experts(c, backend, x, p, real)))
+            kept(lambda x, p, real: experts.expert_half(
+                c, backend, x, p, real, route=functools.partial(route, rows=x.shape[0]))))
 
 
 def hidden_states(c: LatentMoEConfig, backend: str, params, seq):
     """``(x, stats, rope)``: the residual stream after the last layer of the
     stack ``[B, T, D]``, every expert layer's counts ``[expert_layers, ...]``
     and the positions' tables, under the pass's scope."""
-    with jax.named_scope(looped.SCOPE_EMBED):
+    with jax.named_scope(blocks.SCOPE_EMBED):
         real = seq > 0
-        rope = _rope_tables(seq.shape[1], c.rope_dim, c.rope_theta)
+        rope = rope_tables(seq.shape[1], c.rope_dim, c.rope_theta)
         x = jnp.take(params["embed"], seq, axis=0)
-    mixer, dense_mlp, experts = _halves(c, backend, rope)
-    with jax.named_scope(looped.SCOPE_PASS.format(1)), jax.named_scope(looped.SCOPE_LAYERS):
+    mixer, dense_mlp, expert_half = _halves(c, backend, rope)
+    with jax.named_scope(blocks.SCOPE_PASS.format(1)), jax.named_scope(blocks.SCOPE_LAYERS):
         if c.dense_layers:
             x, _ = jax.lax.scan(lambda x, p: (dense_mlp(mixer(x, p), p), None), x,
                                 params["dense"])
-        x, stats = jax.lax.scan(lambda x, p: experts(mixer(x, p), p, real), x, params["layers"])
+        x, stats = jax.lax.scan(lambda x, p: expert_half(mixer(x, p), p, real), x,
+                                params["layers"])
     return x, stats, rope
-
-
-def _masked_ce(c: LatentMoEConfig, h, head, targets):
-    """The mean cross-entropy of ``h`` [B, T, D] over the positions with a
-    target, through the chunked head."""
-    ce = looped._exit_ce(c, h.reshape(-1, h.shape[-1]), head, targets.reshape(-1))
-    mask = (targets.reshape(-1) > 0).astype(jnp.float32)
-    return (ce * mask).sum() / jnp.maximum(mask.sum(), 1.0)
 
 
 def _predict_ahead(c: LatentMoEConfig, backend: str, rope, params, z, targets):
@@ -421,65 +362,51 @@ def _predict_ahead(c: LatentMoEConfig, backend: str, rope, params, z, targets):
     ``target_{i+1}``; a position without a ``target_i`` is a padded slot."""
     p, dtype = params["mtp"], jnp.dtype(c.compute_dtype)
     kept = jax.checkpoint if c.remat else (lambda part: part)
-    mixer, _, experts = _halves(c, backend, rope)
+    mixer, _, expert_half = _halves(c, backend, rope)
 
     def merge(z, embed, p):
-        event = looped._rms_norm(jnp.take(embed, targets, axis=0), p["embed_norm"], c.rms_eps)
-        state = looped._rms_norm(z, p["hidden_norm"], c.rms_eps)
-        return looped._matmul(jnp.concatenate([event, state], axis=-1), p["merge"], dtype)
+        event = blocks.rms_norm(jnp.take(embed, targets, axis=0), p["embed_norm"], c.rms_eps)
+        state = blocks.rms_norm(z, p["hidden_norm"], c.rms_eps)
+        return blocks.matmul(jnp.concatenate([event, state], axis=-1), p["merge"], dtype)
 
-    with jax.named_scope(looped.SCOPE_PASS.format(1)), jax.named_scope(SCOPE_MTP):
+    with jax.named_scope(blocks.SCOPE_PASS.format(1)), jax.named_scope(SCOPE_MTP):
         with jax.named_scope(SCOPE_MERGE):
             m = kept(merge)(z, params["embed"], p)
-        with jax.named_scope(looped.SCOPE_LAYERS):
-            m, stats = experts(mixer(m, p["layer"]), p["layer"], targets > 0)
-        with jax.named_scope(looped.SCOPE_EXIT):
-            h = looped._rms_norm(m, p["final_norm"], c.rms_eps)
+        with jax.named_scope(blocks.SCOPE_LAYERS):
+            m, stats = expert_half(mixer(m, p["layer"]), p["layer"], targets > 0)
+        with jax.named_scope(blocks.SCOPE_EXIT):
             ahead = jnp.pad(targets[:, 1:], ((0, 0), (0, 1)))
-            return _masked_ce(c, h, params["head"], ahead), stats
+            return blocks.masked_ce(c, m, p["final_norm"], params["head"], ahead), stats
 
 
 def make_loss(c: LatentMoEConfig, mesh):
     """``loss_fn(params, batch, rng) -> (loss, aux)`` for the trainer's step;
     ``aux`` is the three terms of the loss, the step's counts under
     ``sparse_moe.make_loss``'s names and ``router_load`` [routers, E], which
-    ``move_bias`` takes out again."""
-    backend = sparse_moe._backend_of(mesh)
+    ``move`` takes out again."""
+    backend = blocks.backend_of(mesh, whole_rows=True)
 
     def loss_fn(params, batch, rng):
         del rng  # no dropout in this block
         seq, targets = batch["seq"], batch["target"]
         x, stats, rope = hidden_states(c, backend, params, seq)
-        with jax.named_scope(looped.SCOPE_PASS.format(1)), jax.named_scope(looped.SCOPE_EXIT):
-            h = looped._rms_norm(x, params["final_norm"], c.rms_eps)
-            ce = _masked_ce(c, h, params["head"], targets)
+        with jax.named_scope(blocks.SCOPE_PASS.format(1)), jax.named_scope(blocks.SCOPE_EXIT):
+            ce = blocks.masked_ce(c, x, params["final_norm"], params["head"], targets)
         mtp_ce = jnp.float32(0.0)
         if c.mtp_depth:
             mtp_ce, ahead = _predict_ahead(c, backend, rope, params, x, targets)
             stats = jax.tree_util.tree_map(lambda a, b: jnp.concatenate([a, b[None]]),
                                            stats, ahead)
-        with jax.named_scope(looped.SCOPE_PASS.format(1)), jax.named_scope(looped.SCOPE_EXIT):
+        with jax.named_scope(blocks.SCOPE_PASS.format(1)), jax.named_scope(blocks.SCOPE_EXIT):
             balance = stats["aux"].mean()
-            held = stats["held_assignments"].sum()
-            out = {
-                "ce": ce, "mtp_ce": mtp_ce, "balance": balance,
-                "moe_assignments": stats["assignments"].sum(),
-                "moe_held_assignments": held,
-                "moe_held_load_max": stats["held_load_max"].max(),
-                "moe_held_load_mean": held / (c.routers * c.held),
-                "moe_dropped": stats["dropped"].sum(),
-                "moe_passes": stats["passes"].sum(),
-                "moe_passes_run": stats["passes_run"].sum(),
-                "moe_sum_rows": stats["sum_rows"].sum(),
-                "moe_sum_slots": stats["sum_slots"].sum(),
-                "router_load": stats["load"],
-            }
+            out = {"ce": ce, "mtp_ce": mtp_ce, "balance": balance,
+                   **experts.counts(c, stats), "router_load": stats["load"]}
             return ce + c.mtp_coef * mtp_ce + c.balance_coef * balance, out
 
     return loss_fn
 
 
-def move_bias(c: LatentMoEConfig, params, aux):
+def move(c: LatentMoEConfig, params, aux):
     """``(params, aux)`` after a step: every router's bias moved by
     ``bias_rate`` against the load the step's ``aux["router_load"]`` counted
     (up for an expert under the mean, down for one over it), and ``aux``
@@ -502,7 +429,5 @@ def move_bias(c: LatentMoEConfig, params, aux):
 def score_last(c: LatentMoEConfig, params, seqs, last):
     """Next-item scores [B, V] at position ``last`` of each row: the stack
     without the prediction module, the whole history a query."""
-    x, _, _ = hidden_states(c, sparse_moe._backend_of(None), params, seqs)
-    h = looped._rms_norm(x, params["final_norm"], c.rms_eps)
-    h = jnp.take_along_axis(h, last[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    return looped._matmul(h, params["head"].T, jnp.dtype(c.compute_dtype))
+    x, _, _ = hidden_states(c, blocks.backend_of(None), params, seqs)
+    return blocks.score_last(c, x, params["final_norm"], params["head"], last)

@@ -3,7 +3,7 @@
 ``y[t] = sum over the rows r with token[r] == t of weight[r] rows[r]``, in
 float32, for a short array of rows ``[R, D]`` of which every token holds at
 most ``most``: what a pass of the routed experts hands back
-(``models/sequence/sparse_moe.py``). No token looks for its rows: the rows are
+(``models/sequence/experts.py``). No token looks for its rows: the rows are
 put in token order once (``plan``: one sort of ``R`` token ids, the rows of no
 token last), so a token's rows are a run and a block of tokens' rows a range,
 and one program (``sum_runs``) writes ``y`` a block of tokens at a time from

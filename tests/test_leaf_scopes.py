@@ -19,6 +19,7 @@ import pytest
 from jax.sharding import Mesh
 
 from benchmarks import scopes_leaf
+from predictionio_tpu.models.sequence import experts as experts_module
 from predictionio_tpu.models.sequence import looped, model as seq_model, sparse_moe
 from predictionio_tpu.parallel import als
 import test_als
@@ -93,7 +94,7 @@ def test_every_leaf_is_under_its_stage_forward_and_backward(op_names, backbone):
 def test_again_marks_the_forward_half_of_the_experts_backward_rule(op_names):
     # (the CPU compiler leaves a reduction's inner computation a name cut short)
     names = {n for n in op_names["sparse_moe"] if "seq." in n}
-    again = [n for n in names if sparse_moe.SCOPE_AGAIN in n.split("/")]
+    again = [n for n in names if experts_module.SCOPE_AGAIN in n.split("/")]
     assert again and all(
         "transpose(jvp(seq.pass1))" in n and "/moe/experts/" in n
         and scopes_leaf.place_of(n).phase == "recomputed" for n in again)
@@ -101,14 +102,14 @@ def test_again_marks_the_forward_half_of_the_experts_backward_rule(op_names):
     # not needed again
     assert {scopes_leaf.place_of(n).leaf for n in again} == {None, "take", "grouped"}
     # the pullback is outside it, whatever the transposition makes of the name
-    pulled = [n for n in names if f"transpose({sparse_moe.SCOPE_AGAIN})" in n]
+    pulled = [n for n in names if f"transpose({experts_module.SCOPE_AGAIN})" in n]
     assert pulled and all(scopes_leaf.place_of(n).phase == "backward" for n in pulled)
     assert {scopes_leaf.place_of(n).leaf for n in pulled} == {"take", "give", "sum"}
     by_runs = [scopes_leaf.place_of(n) for n in op_names["sparse_moe-programs"]
-               if f"transpose({sparse_moe.SCOPE_AGAIN})" in n]
+               if f"transpose({experts_module.SCOPE_AGAIN})" in n]
     assert {p.leaf for p in by_runs if p is not None} == {"give", "sum"}
-    assert not [n for n in names if sparse_moe.SCOPE_AGAIN in n and "transpose(" not in n]
-    assert not [n for n in op_names["looped"] if sparse_moe.SCOPE_AGAIN in n.split("/")]
+    assert not [n for n in names if experts_module.SCOPE_AGAIN in n and "transpose(" not in n]
+    assert not [n for n in op_names["looped"] if experts_module.SCOPE_AGAIN in n.split("/")]
 
 
 @pytest.mark.parametrize("backbone", list(CONFIGS))

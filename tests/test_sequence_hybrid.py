@@ -16,7 +16,7 @@ import pytest
 
 from benchmarks import reference_qwen3next as ref
 from benchmarks import seeded_hybrid
-from predictionio_tpu.models.sequence import hybrid
+from predictionio_tpu.models.sequence import experts as experts_module, hybrid
 from predictionio_tpu.models.sequence.hybrid import HybridConfig
 from predictionio_tpu.models.sequence.model import (
     fit_attrs, make_fit, score_next_items_batch, train_sasrec,
@@ -403,9 +403,11 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_whole_layer():
         for lo in range(0, 8, 2):
             config = _config(experts_held=(lo, lo + 2))
             share = {**drawn, **{k: drawn[k][lo:lo + 2] for k in ("w_gate", "w_up", "w_down")}}
-            with_shared, stats = hybrid._experts(config, "cpu", x, share, real)
+            with_shared, stats = experts_module.expert_half(
+                config, "cpu", x, share, real, hybrid.norm0)
             alone = {**share, "s_down": jnp.zeros_like(drawn["s_down"])}
-            without, _ = hybrid._experts(config, "cpu", x, alone, real)
+            without, _ = experts_module.expert_half(
+                config, "cpu", x, alone, real, hybrid.norm0)
             assert int(stats["dropped"]) == 0
             held += int(stats["held_assignments"])
             routed = routed + (without - x)
@@ -420,7 +422,6 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_whole_layer():
 
 def test_the_engine_takes_the_backbone_at_the_cells_sizes():
     from predictionio_tpu.controller.base import Params
-    from predictionio_tpu.models.sequence import sparse_moe
     from predictionio_tpu.models.sequence.engine import SASRecAlgorithm
 
     config = SASRecAlgorithm(Params({
@@ -434,8 +435,8 @@ def test_the_engine_takes_the_backbone_at_the_cells_sizes():
     assert (config.periods, config.linear_layers, config.rotary_dim) == (1, 3, 64)
     assert hybrid.count_params(config) == 625_667_136
     # a whole layer's tokens at once: a pass of 20,480 rows is twice their even share
-    assert sparse_moe.moe_chunk_of(config) >= 16384
-    assert sparse_moe.pass_plan(config, 16384) == (20480, 8)
+    assert experts_module.moe_chunk_of(config) >= 16384
+    assert experts_module.pass_plan(config, 16384) == (20480, 8)
     # a row's states, 3 layers x 32 heads x 128 x 128 float32; a layer's chunks' for 2 rows
     assert hybrid.delta_state_bytes(config) == 3 * 32 * 128 * 128 * 4
     assert hybrid.delta_kept_bytes(config, 2) == 2 * 128 * 32 * 128 * 128 * 4

@@ -12,6 +12,7 @@ move and Adam's step inside one program; the module's reading and scoring; the
 engine takes the backbone by name."""
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -22,7 +23,7 @@ import pytest
 
 from benchmarks import reference_joyai as ref
 from benchmarks import seeded_latent
-from predictionio_tpu.models.sequence import latent_moe, sparse_moe
+from predictionio_tpu.models.sequence import experts as experts_module, latent_moe
 from predictionio_tpu.models.sequence.latent_moe import BIAS, LatentMoEConfig
 from predictionio_tpu.models.sequence.model import (
     fit_attrs, make_fit, score_next_items_batch, train_sasrec,
@@ -318,7 +319,7 @@ def test_a_length_the_block_does_not_divide_is_refused():
 def test_rotary_pairs_are_interleaved_and_the_key_is_one_for_all_heads():
     rng = np.random.default_rng(2)
     x = jnp.asarray(rng.standard_normal((2, 40, 3, 8)), jnp.float32)
-    have = latent_moe._rotate(x, *latent_moe._rope_tables(40, 8, 3.2e7))
+    have = latent_moe.rotate(x, *latent_moe.rope_tables(40, 8, 3.2e7))
     want = jnp.stack([ref.rope_interleaved(row, 3.2e7) for row in x])
     assert np.abs(np.asarray(have - want)).max() < 1e-6
     # position 0 is left as it is, a pair's norm everywhere
@@ -351,12 +352,15 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_whole_layer():
     with jax.default_matmul_precision("highest"):
         want = ref.experts_mlp(drawn, x[0], real[0], dims, ref.SOUND)[0] - x[0]
         routed, shared, held = 0.0, None, 0
+        route = functools.partial(latent_moe.route, rows=x.shape[0])
         for lo in range(0, 32, 2):
             config = _config(num_experts=32, experts_per_token=4, experts_held=(lo, lo + 2))
             share = {**drawn, **{k: drawn[k][lo:lo + 2] for k in ("w_gate", "w_up", "w_down")}}
-            with_shared, stats = latent_moe._experts(config, "cpu", x, share, real)
+            with_shared, stats = experts_module.expert_half(
+                config, "cpu", x, share, real, route=route)
             alone = {**share, "s_down": jnp.zeros_like(drawn["s_down"])}
-            without, _ = latent_moe._experts(config, "cpu", x, alone, real)
+            without, _ = experts_module.expert_half(
+                config, "cpu", x, alone, real, route=route)
             assert int(stats["dropped"]) == 0
             held += int(stats["held_assignments"])
             routed = routed + (without - x)
@@ -377,7 +381,7 @@ def test_selection_reads_the_score_plus_the_bias_and_gates_the_score_alone():
     u = x[0]
     with jax.default_matmul_precision("highest"):
         scores = np.asarray(jax.nn.sigmoid(u @ drawn["router"]))
-        route = lambda bias: latent_moe._route(  # noqa: E731
+        route = lambda bias: latent_moe.route(  # noqa: E731
             config, u, {**drawn, BIAS: jnp.asarray(bias, jnp.float32)}, real[0], rows=1)
         plain_experts, plain_gates, _ = route(np.zeros(8))
         biased = np.zeros(8, np.float32)
@@ -403,14 +407,14 @@ def test_selection_reads_the_score_plus_the_bias_and_gates_the_score_alone():
 
 
 def test_the_softmax_router_is_what_it_was_beside_the_split():
-    """``sparse_moe._moe`` with no router named is the softmax router's layer:
+    """``experts.moe`` with no router named is the softmax router's layer:
     its counts and its auxiliary loss, as the sparse and the hybrid backbones
     read them."""
     x, real, drawn = _expert_layer(8)
     config = _config(experts_held=(2, 6))
     share = {**drawn, **{k: drawn[k][2:6] for k in ("w_gate", "w_up", "w_down")}}
     with jax.default_matmul_precision("highest"):
-        y, stats = sparse_moe._moe(config, "cpu", x[0], share, real[0])
+        y, stats = experts_module.moe(config, "cpu", x[0], share, real[0])
         probs = jax.nn.softmax(x[0] @ drawn["router"], axis=-1)
     top = np.asarray(jax.lax.top_k(probs, 2)[1])[:90]
     load = np.bincount(top.reshape(-1), minlength=8)
@@ -543,8 +547,8 @@ def test_the_engine_takes_the_backbone_at_the_cells_sizes():
     assert shapes["layers"][BIAS] == (4, 256) and shapes["mtp"]["layer"][BIAS] == (256,)
     assert latent_moe.latent_bytes_per_token(config) == (512 + 64) * 2
     # a whole layer's tokens at once: a pass of 16,384 rows is twice their even share
-    assert sparse_moe.moe_chunk_of(config) >= 16384
-    assert sparse_moe.pass_plan(config, 16384)[0] >= 2 * 16384 * 8 * 16 // 256
+    assert experts_module.moe_chunk_of(config) >= 16384
+    assert experts_module.pass_plan(config, 16384)[0] >= 2 * 16384 * 8 * 16 // 256
     assert sa.heads_per_step(config.num_kv_heads, 1) == 8
     assert latent_moe.attention_backward_heads_per_step(config) == 4
     assert fit_attrs(config, 4, 8, 2, "tpu")["attention_backward_programs"] == 1

@@ -16,17 +16,15 @@ import pytest
 
 from benchmarks import reference_ouro as ref
 from benchmarks import seeded_histories
-from predictionio_tpu.models.sequence import looped
+from predictionio_tpu.models.sequence import blocks, looped
 from predictionio_tpu.models.sequence.looped import LoopedConfig
 from predictionio_tpu.models.sequence.model import (
     SASRec,
     SASRecConfig,
-    _logits,
-    attend,
     score_next_items_batch,
     train_sasrec,
 )
-from predictionio_tpu.parallel.ring_attention import plain_attention
+from predictionio_tpu.models.sequence.sasrec import logits as _logits
 
 VOCAB, T, ROWS = 512, 32, 6
 DIMS = dict(num_heads=4, head_dim=16, rope_theta=1e6, rms_eps=1e-6, ut_steps=4)
@@ -45,10 +43,6 @@ def _config(**kw) -> LoopedConfig:
                 remat=True, head_chunk=64)
     base.update(kw)
     return LoopedConfig(**base)
-
-
-def _plain(q, k, v, mask):
-    return plain_attention(q, k, v, causal=True, mask=mask)
 
 
 @pytest.fixture(scope="module")
@@ -81,11 +75,11 @@ def reference(params, batch):
 
 
 def _system(config, params, batch):
-    loss_fn = looped.make_loss(config, _plain)
+    loss_fn = looped.make_loss(config, None)
     (loss, aux), grads = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))(
         params, batch, None)
     logits, p = jax.jit(
-        lambda pr, s: looped.forward_exits(config, _plain, pr, s))(params, batch["seq"])
+        lambda pr, s: looped.forward_exits(config, None, pr, s))(params, batch["seq"])
     return {"logits": np.asarray(logits), "p": np.asarray(p), "loss": float(loss),
             "exit_ce": np.asarray(aux["exit_ce"]), "exit_p": np.asarray(aux["exit_p"]),
             "grads": jax.tree_util.tree_map(np.asarray, grads)}
@@ -166,7 +160,7 @@ def test_the_head_chunk_follows_the_vocabulary_and_no_engine_parameter_reaches_i
 
     at = lambda items: looped.head_chunk_of(LoopedConfig(num_items=items))  # noqa: E731
     assert at(49_151) == 2048 and at(1_000_000) == 128 and at(511) == 196_608
-    assert 4 * 49_152 * at(49_151) == looped.HEAD_CHUNK_BYTES
+    assert 4 * 49_152 * at(49_151) == blocks.HEAD_CHUNK_BYTES
     assert looped.head_chunk_of(LoopedConfig(num_items=9, head_chunk=0)) == 0
     asked = SASRecAlgorithm(Params({
         "backbone": "looped", "computeDtype": "float32", "remat": False,
@@ -181,12 +175,8 @@ def test_bfloat16_matmul_inputs_stay_close_to_the_reference(params, batch, refer
                        _leaf(reference["grads"], "layers.wq")) < 5e-2
 
 
-def _flash(q, k, v, mask):
-    return attend(q, k, v, mask, None, "flash", "ring")
-
-
 def test_flash_attention_in_the_looped_layer_matches_plain(params, batch, system):
-    loss_fn = looped.make_loss(_config(), _flash)
+    loss_fn = looped.make_loss(_config(attention="flash"), None)
     (loss, _), grads = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))(
         params, batch, None)
     assert abs(float(loss) - system["loss"]) < 1e-5
@@ -207,8 +197,8 @@ def two_block_rows(params):
     target[:, :-1] = seq[:, 1:]
     rows = {"seq": seq, "target": target}
     out = {}
-    for name, attention in (("plain", _plain), ("flash", _flash)):
-        loss_fn = looped.make_loss(_config(max_len=256), attention)
+    for name in ("plain", "flash"):
+        loss_fn = looped.make_loss(_config(max_len=256, attention=name), None)
         (loss, aux), grads = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))(
             params, rows, None)
         out[name] = {"loss": float(loss), "exit_ce": np.asarray(aux["exit_ce"]),

@@ -19,7 +19,7 @@ import pytest
 
 from benchmarks import reference_keye as ref
 from benchmarks import seeded_histories
-from predictionio_tpu.models.sequence import sparse_moe
+from predictionio_tpu.models.sequence import experts as experts_module, sparse_moe
 from predictionio_tpu.models.sequence.model import (
     fit_attrs, make_fit, score_next_items_batch, train_sasrec,
 )
@@ -200,13 +200,13 @@ def test_packing_the_selection_and_unpacking_it_returns_the_mask(t, topk):
     scores = jnp.asarray(np.round(rng.standard_normal((2, t, t)) / 0.5) * 0.5 + 0.0, jnp.float32)
     mask = sa.select_topk_plain(scores, topk)
     assert mask.dtype == jnp.int8 and int(mask.sum(-1).max()) == topk
-    packed = sparse_moe._pack_rows(mask)
+    packed = sparse_moe.pack_rows(mask)
     assert packed.dtype == jnp.uint8 and packed.shape == (2, -(-t // 8), t)
     want = np.zeros((2, -(-t // 8) * 8, t), np.uint8)
     want[:, :t] = np.asarray(mask)
     assert (np.asarray(packed) == np.packbits(
         want.reshape(2, -1, 8, t), axis=2, bitorder="little")[:, :, 0]).all()
-    back = sparse_moe._unpack_rows(packed, t)
+    back = sparse_moe.unpack_rows(packed, t)
     assert back.dtype == jnp.int8 and (np.asarray(back) == np.asarray(mask)).all()
 
 
@@ -371,7 +371,7 @@ def test_the_eight_shares_add_up_to_the_whole_layer(params):
         config = _config(experts_held=(e, e + 1))
         share = {"router": drawn["router"],
                  **{k: drawn[k][e:e + 1] for k in ("w_gate", "w_up", "w_down")}}
-        y, stats = sparse_moe._moe(config, "cpu", u, share, real)
+        y, stats = experts_module.moe(config, "cpu", u, share, real)
         assert int(stats["dropped"]) == 0
         held += int(stats["held_assignments"])
         total = total + y
@@ -409,10 +409,10 @@ def test_the_passes_give_the_references_output_and_gradients_whatever_the_router
     n = 3 * T
     config = _config(num_experts=16, experts_held=held, moe_chunk=None,
                      attention=SUM_PATHS[path])
-    assert sparse_moe.sum_path(config, "cpu") == path
+    assert experts_module.sum_path(config, "cpu") == path
     dims = {**DIMS, "experts_held": held}
-    assert sparse_moe.moe_chunk_of(config) >= n      # one chunk of tokens
-    assert sparse_moe.pass_plan(config, n) == ((384, 1) if routing == "all-held" else (128, 3))
+    assert experts_module.moe_chunk_of(config) >= n      # one chunk of tokens
+    assert experts_module.pass_plan(config, n) == ((384, 1) if routing == "all-held" else (128, 3))
     shapes = sparse_moe.param_shapes(config)["layers"]
     layer = seeded_histories.make_params(
         {k: shapes[k][1:] for k in ("router", "w_gate", "w_up", "w_down")}, seed=13)
@@ -428,7 +428,7 @@ def test_the_passes_give_the_references_output_and_gradients_whatever_the_router
     real = jnp.asarray(np.arange(n) < n_real)
 
     def program(u, layer):
-        y, stats = sparse_moe._moe(config, "cpu", u, layer, real)
+        y, stats = experts_module.moe(config, "cpu", u, layer, real)
         return (y * weight).sum(), (y, stats)
 
     def reference(u, layer):
@@ -443,7 +443,7 @@ def test_the_passes_give_the_references_output_and_gradients_whatever_the_router
     assert int(stats["dropped"]) == 0
     assert (int(stats["passes"]), int(stats["passes_run"])) == (1 if routing == "all-held" else 3, run)
     # what the forward sums read: a pass's rows by runs, every token's slots by position
-    bound = sparse_moe.pass_plan(config, n)[0]
+    bound = experts_module.pass_plan(config, n)[0]
     assert int(stats["sum_slots"]) == run * n * 2
     assert int(stats["sum_rows"]) == run * (bound if path == "runs" else n * 2)
     sent = np.isin(np.asarray(experts)[:n_real], np.arange(*held)).sum()
@@ -520,11 +520,11 @@ def test_the_sum_by_runs_is_the_sum_by_position(shape, weighted, rows_dtype):
     gates = (jnp.asarray(rng.uniform(0.05, 1.0, (n, slots)), jnp.float32) if weighted
              else jnp.ones((n, slots), jnp.float32))
     is_live = jnp.arange(rows) < live
-    want = np.asarray(sparse_moe._sum_by_position(
+    want = np.asarray(experts_module.sum_by_position(
         values, jnp.asarray(pos), jnp.where(jnp.asarray(mine), gates, 0.0)))
     runs = run_sum.plan(jnp.where(is_live, jnp.asarray(row) // slots, run_sum.NO_TOKEN), n)
     by_row = jnp.where(is_live, gates.reshape(-1)[jnp.asarray(row)], 0.0)
-    by_runs = lambda v: np.asarray(sparse_moe._sum_by_runs(  # noqa: E731
+    by_runs = lambda v: np.asarray(experts_module.sum_by_runs(  # noqa: E731
         v, by_row, runs, (n, slots), True, unit=not weighted))
     have = by_runs(values)
     assert have.shape == want.shape == (n, 128) and have.dtype == np.float32
@@ -579,7 +579,7 @@ def test_a_pass_gives_the_same_output_and_gradients_by_runs_and_by_position(comp
 
     def run(interpret):
         def program(u, gates, *weights):
-            y, worked, ran = sparse_moe._experts_chunk(
+            y, worked, ran = experts_module.experts_chunk(
                 config, interpret, *weights, u, jnp.asarray(experts), gates, real)
             return (y * weight).sum(), (y, worked, ran)
         return jax.jit(jax.value_and_grad(program, (0, 1, 2, 3, 4), has_aux=True))(
@@ -588,7 +588,7 @@ def test_a_pass_gives_the_same_output_and_gradients_by_runs_and_by_position(comp
     (_, (want_y, worked, ran)), want = run(None)
     (_, (have_y, worked_too, ran_too)), have = run(True)
     assert int(ran) == int(ran_too) == 2 and int(worked) == int(worked_too) == 90 + 45
-    assert sparse_moe.pass_plan(config, n) == (128, 2)
+    assert experts_module.pass_plan(config, n) == (128, 2)
     tolerance = 2e-6 if compute_dtype == "float32" else 1e-2
     for a, b in zip((have_y, *have), (want_y, *want)):
         a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
@@ -610,8 +610,8 @@ def test_the_engine_takes_the_backbone_and_names_all_three_when_it_refuses():
     assert isinstance(config, SparseMoEConfig) and config.held == 16
     assert sparse_moe.count_params(config) == 659_187_712
     # a whole layer's tokens: a pass of 32,768 rows is twice their even share
-    assert sparse_moe.moe_chunk_of(config) == 16384
-    assert sparse_moe.pass_plan(config, 16384) == (32768, 4)
+    assert experts_module.moe_chunk_of(config) == 16384
+    assert experts_module.pass_plan(config, 16384) == (32768, 4)
     whole = SASRecAlgorithm(Params({"backbone": "sparse_moe", "numExperts": 16}))._config(12, 64)
     assert whole.experts_held == (0, 16)
     with pytest.raises(ValueError, match="'sasrec', 'looped', 'sparse_moe'"):

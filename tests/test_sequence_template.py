@@ -60,6 +60,26 @@ class TestSASRecModel:
             hits += int(np.argmax(scores) == want)
         assert hits >= 10, f"only {hits}/12 next-items predicted"
 
+    def test_a_seeded_fit_gives_the_losses_it_gave(self):
+        """The default backbone has no benchmark cell and no compiled step on
+        record: its seeded fit on one CPU device is held to the losses PR 42's
+        tree gave (every step's, float32 to the bit), so that a change to the
+        trainer or to the pieces the backbones share cannot move it unseen."""
+        import jax
+        from jax.sharding import Mesh
+
+        mesh = Mesh(np.array(jax.devices("cpu")[:1]).reshape(1, 1), ("data", "seq"))
+        _, losses = train_sasrec(_config(), cyclic_sequences(), mesh, log_every=1)
+        assert [float(x).hex() for x in losses] == [
+            "0x1.00539c0000000p+2", "0x1.c2e2340000000p+1", "0x1.7feb660000000p+1",
+            "0x1.4cd2d40000000p+1", "0x1.2bf2560000000p+1", "0x1.14f69a0000000p+1",
+            "0x1.f8a4e20000000p+0", "0x1.dce09c0000000p+0", "0x1.b7cb2c0000000p+0",
+            "0x1.97309a0000000p+0", "0x1.7c849a0000000p+0", "0x1.59d6e60000000p+0",
+            "0x1.406a420000000p+0", "0x1.21483e0000000p+0", "0x1.171e460000000p+0",
+            "0x1.ec004c0000000p-1", "0x1.c1ba6a0000000p-1", "0x1.a4d0460000000p-1",
+            "0x1.8abc920000000p-1", "0x1.48745c0000000p-1", "0x1.2ceae60000000p-1",
+            "0x1.0339400000000p-1", "0x1.0aaa340000000p-1", "0x1.e8c7d20000000p-2"]
+
     def test_sp_training_runs_and_learns(self):
         """dp=2 x sp=4: ring attention on the training path."""
         config = _config()
@@ -77,7 +97,8 @@ class TestSASRecModel:
         import jax.numpy as jnp
         import optax
 
-        from predictionio_tpu.models.sequence.model import SASRec, _logits
+        from predictionio_tpu.models.sequence.model import SASRec
+        from predictionio_tpu.models.sequence.sasrec import logits as _logits
 
         seqs = cyclic_sequences(n=16)
         targets = np.zeros_like(seqs)
@@ -99,6 +120,82 @@ class TestSASRecModel:
             return float((ce * mask).sum() / mask.sum())
 
         assert abs(loss_for(_mesh(1, 1)) - loss_for(_mesh(2, 4))) < 1e-4
+
+
+# ---- the table of backbones ----------------------------------------------------
+
+#: what ``SASRecAlgorithm._config(100, maxLen)`` gave on PR 42's tree for each
+#: backbone's ``examples/sequence/engine*.json``, field by field
+HOW = dict(rms_eps=1e-6, seed=0, epochs=10, seq_parallel="ring", attention="auto",
+           compute_dtype="bfloat16", remat=True, head_chunk=None)
+EXPERTS = dict(expert_dim=64, num_experts=16, experts_per_token=4, experts_held=(0, 16),
+               moe_chunk=None, learning_rate=0.0003, batch_size=16, max_len=256, **HOW)
+EXAMPLES = {
+    "sasrec": ("engine.json", "SASRecConfig", dict(
+        max_len=64, embed_dim=32, num_heads=2, num_blocks=2, ffn_dim=64, dropout=0.0,
+        learning_rate=0.001, batch_size=256, epochs=10, seed=0, seq_parallel="ring",
+        attention="auto")),
+    "looped": ("engine-looped.json", "LoopedConfig", dict(
+        max_len=64, hidden_size=128, num_heads=4, head_dim=32, ffn_dim=352, num_layers=2,
+        ut_steps=4, rope_theta=1000000.0, exit_beta=0.1, early_exit_threshold=1.0,
+        learning_rate=0.0003, batch_size=64, **HOW)),
+    "sparse_moe": ("engine-sparse-moe.json", "SparseMoEConfig", dict(
+        hidden_size=128, num_heads=8, num_kv_heads=2, head_dim=16, num_layers=2, index_heads=4,
+        index_dim=16, index_topk=64, rope_theta=10000000.0, aux_coef=0.001, **EXPERTS)),
+    "hybrid_linear": ("engine-hybrid-linear.json", "HybridConfig", dict(
+        hidden_size=128, num_layers=4, full_attention_interval=4, linear_key_heads=2,
+        linear_value_heads=4, linear_key_dim=32, linear_value_dim=32, conv_kernel=4,
+        num_heads=8, num_kv_heads=2, head_dim=32, rotary_fraction=0.25, shared_expert_dim=64,
+        rope_theta=10000000.0, aux_coef=0.001, delta_chunk=64, **EXPERTS)),
+    "latent_moe": ("engine-latent-moe.json", "LatentMoEConfig", dict(
+        hidden_size=128, num_layers=3, dense_layers=1, num_heads=8, q_rank=96, kv_rank=32,
+        nope_dim=32, rope_dim=16, value_dim=32, ffn_dim=448, shared_expert_dim=64,
+        routed_scale=2.5, mtp_depth=1, mtp_coef=0.3, balance_coef=0.0001, bias_rate=0.001,
+        rope_theta=32000000.0, **EXPERTS)),
+}
+
+
+@pytest.mark.parametrize("backbone", sorted(EXAMPLES))
+def test_a_backbone_is_one_module_behind_the_table(backbone):
+    """Every entry of the trainer's table exports the seam's names, the
+    engine's ``BACKBONES`` are the table's keys, and the engine's one loop over
+    the module's ``ENGINE_PARAMS`` reads the backbone's example ``engine.json``
+    to the configuration the ladders gave, values and types."""
+    import dataclasses
+    import inspect
+    import json
+    import os
+
+    from predictionio_tpu.controller.base import Params
+    from predictionio_tpu.models.sequence import model as seq_model
+    from predictionio_tpu.models.sequence.engine import FIT_PARAMS, SASRecAlgorithm
+
+    assert SASRecAlgorithm.BACKBONES == tuple(seq_model.BACKBONES) and len(EXAMPLES) == len(
+        seq_model.BACKBONES)
+    module = seq_model.BACKBONES[backbone]
+    file, name, fields = EXAMPLES[backbone]
+    assert module.CONFIG.__name__ == name and dataclasses.is_dataclass(module.CONFIG)
+    for function, arguments in (("init_params", ["c", "rng"]), ("make_loss", ["c", "mesh"]),
+                                ("score_last", ["c", "params", "seqs", "last"]),
+                                ("fit_attrs", ["c", "rows", "platform"])):
+        assert list(inspect.signature(getattr(module, function)).parameters) == arguments
+    assert hasattr(module, "move") <= hasattr(module, "trained_labels")
+    known = {f.name for f in dataclasses.fields(module.CONFIG)}
+    assert set(module.ENGINE_PARAMS.values()) | set(FIT_PARAMS.values()) <= known
+    assert not set(module.ENGINE_PARAMS) & set(FIT_PARAMS)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "examples", "sequence", file)) as f:
+        doc = json.load(f)
+    params = doc["algorithms"][0]["params"]
+    assert params.get("backbone", "sasrec") == backbone
+    # every name the example gives is one the engine reads
+    assert set(params) - {"backbone"} <= set(module.ENGINE_PARAMS) | set(FIT_PARAMS)
+    config = SASRecAlgorithm(Params(params))._config(100, doc["preparator"]["params"]["maxLen"])
+    want = module.CONFIG(num_items=100, **fields)
+    assert config == want and seq_model.backbone_named(config) == (backbone, module)
+    assert len(fields) + 1 == len(known)          # the literal names every field
+    for field in known:
+        assert type(getattr(config, field)) is type(getattr(want, field)), field
 
 
 @pytest.fixture()

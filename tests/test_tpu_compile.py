@@ -69,20 +69,26 @@ def _kernel_instructions(text: str, name: str) -> list[str]:
     )
 
 
-#: sha256 of ``_without_names`` of the two accepted sequence cells' compiled
-#: steps, as PR 36's tree compiled them for the described v5e (jax 0.9.0,
+#: sha256 of ``_without_names`` of the four sequence cells' compiled steps, the
+#: first two as PR 36's tree compiled them for the described v5e (jax 0.9.0,
 #: libtpu 0.0.34). A change that shares code with them (PR 37: the experts'
 #: passes, the attention programs that gained a mode without a mask) leaves
 #: both programs as they were, instruction for instruction; one that means to
 #: change a step re-measures its cell and writes the new digest here (PR 41:
 #: the sparse cell's, whose experts' rows come back by runs; the looped one's
 #: is PR 36's still; PR 42: the sparse cell's again, whose attention's backward
-#: pass is one program where it was two)
+#: pass is one program where it was two; PR 43: the hybrid and the latent
+#: cell's, written from PR 42's tree before PR 43 moved the backbones' shared
+#: pieces, so that all four held that refactor to the same programs)
 ACCEPTED_STEPS = {
     "ouro-2.6b-d8.train-histories":
         "7849910ae58d05dc248f996c7c3e71238090eae70f3d76ad3486b9b4d5c342ef",
     "keye-vl2-30b-a3b-ep8.train-lifelong-histories":
         "b145a5bbda1425b96c0b687b9641f86606382da6ca15368a7bcf725455617e87",
+    "qwen3-next-80b-a3b-ep16.train-lifelong-histories":
+        "ce5c863a7fedcad164c52410cc3811218a989c37ec47646f06454128bb167475",
+    "joyai-llm-flash-ep16.train-lifelong-histories":
+        "8e7876fed48375e0bb2904f6aebe302d446d7ce548a40ba523f80f2c81290626",
 }
 
 
@@ -652,7 +658,7 @@ def test_the_lifelong_histories_cells_step_fits_the_chip_at_6_layers_and_scopes_
     import re
 
     from benchmarks import scopes_leaf, scopes_sparse
-    from predictionio_tpu.models.sequence import model as seq_model, sparse_moe
+    from predictionio_tpu.models.sequence import experts, model as seq_model, sparse_moe
 
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "seq"))
     config = sparse_moe.SparseMoEConfig(
@@ -663,7 +669,7 @@ def test_the_lifelong_histories_cells_step_fits_the_chip_at_6_layers_and_scopes_
     assert sparse_moe.count_params(config) == 659_187_712
     # the run sum: 64 blocks of 256 tokens, each over at most the 9 row blocks
     # of 256 that 256 x 8 rows can span
-    assert sparse_moe.pass_plan(config, 16384) == (32768, 4)
+    assert experts.pass_plan(config, 16384) == (32768, 4)
     assert _run_sum_grid(16384, 8, 32768) == (64, 9)
     _, _, step_fn, seq_shard = seq_model.make_fit(config, mesh)
     rep = NamedSharding(mesh, P())
@@ -805,7 +811,7 @@ def test_the_hybrid_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
     import re
 
     from benchmarks import scopes_hybrid, scopes_leaf, scopes_seq, scopes_sparse
-    from predictionio_tpu.models.sequence import hybrid, model as seq_model, sparse_moe
+    from predictionio_tpu.models.sequence import experts, hybrid, model as seq_model
     from predictionio_tpu.ops import delta_rule
 
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "seq"))
@@ -817,7 +823,7 @@ def test_the_hybrid_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
         experts_per_token=10, experts_held=(0, 32), shared_expert_dim=512, batch_size=2)
     assert hybrid.count_params(config) == 625_667_136
     assert hybrid.delta_heads_per_step(config, 2) == 8
-    assert sparse_moe.pass_plan(config, 16384) == (20480, 8)
+    assert experts.pass_plan(config, 16384) == (20480, 8)
     assert _run_sum_grid(16384, 10, 20480) == (64, 11)
     _, _, step_fn, seq_shard = seq_model.make_fit(config, mesh)
     rep = NamedSharding(mesh, P())
@@ -844,6 +850,7 @@ def test_the_hybrid_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
     peak = compiled.memory_analysis().peak_memory_in_bytes
     assert 14.0e9 < peak < 15.6e9, peak       # 15.25 GB
     text = compiled.as_text()
+    assert _digest(text) == ACCEPTED_STEPS["qwen3-next-80b-a3b-ep16.train-lifelong-histories"]
     calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
     rule = [c for c in calls if scopes_hybrid.place_of(c) == ("linear", "delta")]
     phases = lambda names: sorted(  # noqa: E731
@@ -885,7 +892,7 @@ def test_the_latent_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
     import re
 
     from benchmarks import scopes_latent, scopes_leaf, scopes_seq, scopes_sparse
-    from predictionio_tpu.models.sequence import latent_moe, model as seq_model, sparse_moe
+    from predictionio_tpu.models.sequence import experts, latent_moe, model as seq_model
     from predictionio_tpu.ops import sparse_attention as sa
 
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "seq"))
@@ -906,7 +913,7 @@ def test_the_latent_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
     # the backward program: four heads a step, their dk and dv over the row
     assert latent_moe.attention_backward_heads_per_step(config) == 4
     assert _backward_attention_grid((2, 8192, 32, 192), 32, 128) == (2, 8, 32, 16)
-    assert sparse_moe.pass_plan(config, 16384) == (16384, 8)
+    assert experts.pass_plan(config, 16384) == (16384, 8)
     assert _run_sum_grid(16384, 8, 16384) == (64, 9)
     _, _, step_fn, seq_shard = seq_model.make_fit(config, mesh)
     rep = NamedSharding(mesh, P())
@@ -925,6 +932,7 @@ def test_the_latent_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
     peak = compiled.memory_analysis().peak_memory_in_bytes
     assert 14.0e9 < peak < 15.6e9, peak       # 15.18 GB
     text = compiled.as_text()
+    assert _digest(text) == ACCEPTED_STEPS["joyai-llm-flash-ep16.train-lifelong-histories"]
     calls = [c for c in re.findall(
         r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text) if "seq." in c]
     # the dense layer, the scan's body and the module: each forward, again and
