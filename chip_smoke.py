@@ -65,6 +65,10 @@ HYBRID_FIT_FACTS = ("experts_shared", "linear_layers", "full_layers", "delta_chu
 LATENT_FIT_FACTS = ("dense_layers", "mtp_depth", "latent_q_rank", "latent_kv_rank",
                     "score_width", "value_width", "latent_bytes_per_token",
                     "router_bias_leaves", "router_bias_abs_max", "mtp_ce", "balance")
+#: and the window backbone's: its layers by kind, the band and what walks it
+WINDOW_FIT_FACTS = ("window", "window_layers", "heads_window", "heads_full", "window_pairs",
+                    "window_tiles_walked", "window_tiles_needed", "rope_tables",
+                    "window_attention_backward_heads_per_step")
 #: the leaf scopes a compiled sequence step has to carry under each stage
 #: (``jax.named_scope``; the strings are ``looped``'s and ``sparse_moe``'s), and
 #: of them those whose backward pass is work of its own
@@ -77,6 +81,10 @@ SPARSE_LEAVES = {**LAYER_LEAVES, "moe": ("norm",),
 LATENT_LEAVES = {**SPARSE_LEAVES, "qkv": ("q_latent", "kv_latent"), "mlp": ("norm",),
                  "moe": ("norm", "shared"),
                  "mtp": ("merge", "q_latent", "kv_latent", "kernel", "shared", "exit")}
+#: the window backbone's: the window layers' mixer under its own stage, the
+#: dense layer's MLP and the shared expert
+WINDOW_LEAVES = {**SPARSE_LEAVES, "mlp": ("norm",), "moe": ("norm", "shared"),
+                 "window_attention": LAYER_LEAVES["attention"]}
 BACKWARD_LEAVES = {"norm", "qkv", "rope", "kernel", "out", "grouped", "give", "sum",
                    "q_latent", "kv_latent", "shared", "merge", "exit"}
 
@@ -341,7 +349,7 @@ class Smoke:
             # what the sparse backbone adds to the line: its share and its counts
             facts.update({k: float(said[k])
                           for k in (SPARSE_FIT_FACTS + ATTENTION_FIT_FACTS + HYBRID_FIT_FACTS
-                                    + LATENT_FIT_FACTS)
+                                    + LATENT_FIT_FACTS + WINDOW_FIT_FACTS)
                           if k in said})
             if "moe_sum" in said:          # how the experts' rows come back: a word
                 facts["moe_sum"] = said["moe_sum"]
@@ -396,6 +404,10 @@ class Smoke:
         if (2 * programs["backward"] != programs["forward"]
                 or (programs["backward"] >= 1) != on_chip):
             raise PhaseFailed(f"{name}: the programs under attention/kernel: {programs}")
+        banded = leaves["window_programs"]
+        if (2 * banded["backward"] != banded["forward"]
+                or (banded["backward"] >= 1) != (on_chip and bool(facts.get("window_layers")))):
+            raise PhaseFailed(f"{name}: the programs under window_attention/kernel: {banded}")
 
     def query_all(self, url: str, queries: list[dict]) -> tuple[list, float]:
         answers, lat = [], []
@@ -909,6 +921,85 @@ class Smoke:
         self.line("train_sequence_latent_moe", t0, **facts, users=4, events=int(users.size),
                   max_len=max_len, leaf_scopes=len(leaves["leaves"]), **widths)
 
+    def phase_train_sequence_window_moe(self) -> None:
+        """The sequence template's window backbone through ``pio train`` at the
+        published widths (a full, dense layer of 48 heads, then a period of one
+        window layer of 64 heads over a window of 512 and a full layer, 8 of 256
+        experts held beside the shared one: 213 M parameters, 0.85 GB, under the
+        1e9 bytes sqlite takes in a BLOB; a rehearsal cuts the widths): a few
+        steps on one batch of users whose histories are four windows long. The
+        ``seq_fit:`` line has to name the backbone, its layers by kind, the two
+        head counts, the window and its pairs beside the causal ones, the tiles
+        the banded programs walk, two rotary tables, the held experts and no
+        dropped token: a silent fall to another backbone, to the causal triangle
+        on the window layers or to one table would show."""
+        import numpy as np
+
+        t0 = time.time()
+        out = pio("app_new_window", ["app", "new", "SmokeWindowApp"], self.env, 120)
+        app_id = int(re.search(r"ID: (\d+)", out).group(1))
+        rng = np.random.default_rng(SEED + 4)
+        max_len = 128 if self.rehearsal else 2048
+        lengths = rng.integers(max_len, max_len + 64, size=4)
+        users = np.repeat(np.arange(4), lengths)
+        items = (np.minimum(rng.random(users.size) ** 2.2, 0.999999) * 2_000).astype(np.int64)
+        events = os.path.join(self.basedir, "window_events.jsonl")
+        write_events(events, users, items, np.ones(users.size, np.float32))
+        pio("import_window", ["import", "--appid", str(app_id), "--input", events], self.env, 300)
+        os.unlink(events)
+        widths = ({"hiddenSize": 64, "numAttentionHeadsPerLayer": [6, 8, 6], "numKvHeads": 2,
+                   "headDim": 16, "slidingWindow": 32, "ffnDim": 128, "expertDim": 32,
+                   "numExperts": 16, "expertsPerToken": 4, "expertsHeld": [0, 4],
+                   "sharedExpertDim": 32, "fullRopeOriginalLen": 32}
+                  if self.rehearsal else
+                  {"hiddenSize": 2048, "numAttentionHeadsPerLayer": [48, 64, 48], "numKvHeads": 8,
+                   "headDim": 128, "slidingWindow": 512, "ffnDim": 8192, "expertDim": 512,
+                   "numExperts": 256, "expertsPerToken": 8, "expertsHeld": [0, 8],
+                   "sharedExpertDim": 512, "fullRopeOriginalLen": 1024})
+        algorithm = dict(backbone="window_moe",
+                         layerTypes=["full_attention", "sliding_attention", "full_attention"],
+                         mlpLayerTypes=["dense", "sparse", "sparse"], batchSize=4, epochs=6,
+                         learningRate=3e-4, **widths)
+
+        def edit(v):
+            v["datasource"]["params"]["appName"] = "SmokeWindowApp"
+            v["preparator"]["params"]["maxLen"] = max_len
+            v["algorithms"][0]["params"].update(algorithm)
+            v["sparkConf"] = {"pio.mesh_shape": [1, 1], "pio.mesh_axes": ["data", "seq"]}
+
+        seq_dir = self.engine_dir("sequence_window_moe", "sequence", edit)
+        facts = self.train("train_sequence_window_moe", seq_dir, 900)
+        held = widths["expertsHeld"][1] - widths["expertsHeld"][0]
+        full, wide, _ = widths["numAttentionHeadsPerLayer"]
+        if (facts.get("backbone") != "window_moe" or facts.get("steps") != 6
+                or (facts.get("window_layers"), facts.get("full_layers")) != (1, 2)
+                or (facts.get("heads_window"), facts.get("heads_full")) != (wide, full)
+                or facts.get("experts_held") != held or facts.get("experts_shared") != 1
+                or facts.get("experts_total") != widths["numExperts"]):
+            raise PhaseFailed(
+                f"train_sequence_window_moe: not six steps of a full dense layer, a window layer"
+                f" and a full one with {held} of {widths['numExperts']} experts held: {facts}")
+        # a row of four windows: the band holds w (w + 1) / 2 + (T - w) w pairs of
+        # the triangle's T (T + 1) / 2, and the programs walk no fewer tiles than it fills
+        w = widths["slidingWindow"]
+        if (facts.get("window") != w or facts.get("rope_tables") != 2
+                or facts.get("window_pairs") != w * (w + 1) // 2 + (max_len - w) * w
+                or facts.get("causal_pairs") != max_len * (max_len + 1) // 2
+                or not 0 < facts.get("window_tiles_needed", 0) <= facts.get("window_tiles_walked", 0)):
+            raise PhaseFailed(f"train_sequence_window_moe: the band and its tiles: {facts}")
+        if facts.get("moe_dropped") != 0 or not (
+                0 < facts.get("moe_held_assignments", 0) < facts["moe_assignments"]):
+            raise PhaseFailed(f"train_sequence_window_moe: tokens dropped, or no share: {facts}")
+        first, last = facts["first_loss"], facts["last_loss"]
+        if not (first == first and last == last and last < first < float("inf")):
+            raise PhaseFailed(f"train_sequence_window_moe: loss not finite and falling: {first} -> {last}")
+        leaves = self.step_leaves("sequence_window_moe_leaves", algorithm, max_len, WINDOW_LEAVES)
+        self.rows_come_back("train_sequence_window_moe", facts, leaves)
+        self.one_backward_program("train_sequence_window_moe", facts, leaves)
+        self.line("train_sequence_window_moe", t0, **facts, users=4, events=int(users.size),
+                  max_len=max_len, leaf_scopes=len(leaves["leaves"]),
+                  window_programs=leaves["window_programs"], **widths)
+
     def phase_sharded(self) -> None:
         self.phase_device(with_status=False)
         if self.device["count"] != 4:
@@ -972,6 +1063,7 @@ def main(argv=None) -> int:
                 smoke.phase_train_sequence_sparse_moe,
                 smoke.phase_train_sequence_hybrid_linear,
                 smoke.phase_train_sequence_latent_moe,
+                smoke.phase_train_sequence_window_moe,
             ]
         for phase in phases:
             try:
@@ -1116,8 +1208,9 @@ def child_sequence_step_leaves(params: dict) -> dict:
     the compiled text carries the leaf scope under its stage, how often
     ``again`` shows in either, the phases in which a device program
     (``tpu_custom_call``) lies under ``experts/.../sum``, and how many lie under
-    ``attention/.../kernel`` forward (the pass worked again in the backward
-    pass is a forward program) and backward. A program served
+    ``attention/.../kernel`` and under ``window_attention/.../kernel`` forward
+    (the pass worked again in the backward pass is a forward program) and
+    backward. A program served
     from the compile cache is read as it was served: the cache's key has to
     cover the names (``utils/platform.configure_compile_cache``)."""
     import jax
@@ -1151,11 +1244,13 @@ def child_sequence_step_leaves(params: dict) -> dict:
     programs = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
     under_sum = sorted({phase(name) for name in programs
                         if "/experts/" in name and "/sum/" in name})
-    attention = ["forward" if "rematted_computation" in name else phase(name)
-                 for name in programs if "/attention/" in name and "/kernel/" in name]
+    attention, banded = (["forward" if "rematted_computation" in name else phase(name)
+                          for name in programs if f"/{stage}/" in name and "/kernel/" in name]
+                         for stage in ("attention", "window_attention"))
     return {"device": rep, "leaves": leaves, "again_forward": again.count("forward"),
             "again_backward": again.count("backward"), "sum_programs": under_sum,
-            "attention_programs": {k: attention.count(k) for k in ("forward", "backward")}}
+            "attention_programs": {k: attention.count(k) for k in ("forward", "backward")},
+            "window_programs": {k: banded.count(k) for k in ("forward", "backward")}}
 
 
 def _load_model(engine_dir: str, instance_id: str):

@@ -1,5 +1,5 @@
 """The routed experts of the sequence template's expert backbones
-(``sparse_moe``, ``hybrid``, ``latent_moe``): a router's choice worked as
+(``sparse_moe``, ``hybrid``, ``latent_moe``, ``window_moe``): a router's choice worked as
 grouped matmuls over the experts this program holds, beside a shared expert
 every token takes where the layer has one.
 
@@ -368,6 +368,34 @@ def route(c, u, p, real):
     mean_p = jnp.where(real[:, None], probs, 0.0).sum(axis=0) / count
     aux = c.num_experts * jnp.sum(load.astype(jnp.float32) / count * mean_p)
     return experts, gates, {"aux": aux, **load_stats(c, load)}
+
+
+def sigmoid_route(c, u, p, real, rows: int, bias=None):
+    """``(experts, gates, stats)`` of a router that scores by sigmoid, for the
+    normed tokens ``u`` [N, D] of ``rows`` rows: the ``experts_per_token``
+    largest of the scores (plus ``bias`` [E] where the layer has one, which no
+    gradient reaches), their gates from the scores alone, renormalised and
+    times ``c.routed_scale``, and the layer's counts under ``route``'s names
+    with the whole ``load`` [E] beside them; ``aux`` is the balance loss, a
+    row at a time: ``sum_e f_e P_e``, ``f_e = E / (K T_r)`` times the row's
+    real positions that chose ``e``, ``P_e`` the row's mean of
+    ``s_e / sum_j s_j``, the mean over the rows."""
+    scores = jax.nn.sigmoid(jnp.matmul(u, p["router"], precision=jax.lax.Precision.HIGHEST))
+    ranked = scores if bias is None else scores + jax.lax.stop_gradient(bias)
+    chosen = jax.lax.top_k(ranked, c.experts_per_token)[1]
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    gates = c.routed_scale * picked / (picked.sum(axis=-1, keepdims=True) + 1e-20)
+    by_row = lambda a: a.reshape(rows, -1, *a.shape[1:])  # noqa: E731
+    on = by_row(real)
+    takes = (by_row(chosen)[..., None] == jnp.arange(c.num_experts)) & on[..., None, None]
+    row_load = takes.sum(axis=(1, 2))                                    # [rows, E]
+    count = jnp.maximum(on.sum(axis=1), 1).astype(jnp.float32)[:, None]
+    share = by_row(scores / scores.sum(axis=-1, keepdims=True))
+    mean_share = jnp.where(on[..., None], share, 0.0).sum(axis=1) / count
+    often = row_load.astype(jnp.float32) * (c.num_experts / c.experts_per_token) / count
+    load = row_load.sum(axis=0)
+    return chosen, gates, {"aux": (often * mean_share).sum(axis=-1).mean(),
+                            **load_stats(c, load), "load": load}
 
 
 def held_experts(c, backend: str, u, p, experts, gates, real, stats):

@@ -305,26 +305,10 @@ def _dense_mlp(c: LatentMoEConfig, x, p):
 
 
 def route(c: LatentMoEConfig, u, p, real, rows: int):
-    """``(experts, gates, stats)`` for the normed tokens ``u`` [N, D] of
-    ``rows`` rows: the ``experts_per_token`` largest of the sigmoid scores plus
-    the bias, their gates from the scores alone, and the layer's counts under
-    ``experts.route``'s names with the whole ``load`` [E] beside them;
-    ``aux`` is the balance loss, a row at a time."""
-    scores = jax.nn.sigmoid(jnp.matmul(u, p["router"], precision=jax.lax.Precision.HIGHEST))
-    chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(p[BIAS]), c.experts_per_token)[1]
-    picked = jnp.take_along_axis(scores, chosen, axis=-1)
-    gates = c.routed_scale * picked / (picked.sum(axis=-1, keepdims=True) + 1e-20)
-    by_row = lambda a: a.reshape(rows, -1, *a.shape[1:])  # noqa: E731
-    on = by_row(real)
-    takes = (by_row(chosen)[..., None] == jnp.arange(c.num_experts)) & on[..., None, None]
-    row_load = takes.sum(axis=(1, 2))                                    # [rows, E]
-    count = jnp.maximum(on.sum(axis=1), 1).astype(jnp.float32)[:, None]
-    share = by_row(scores / scores.sum(axis=-1, keepdims=True))
-    mean_share = jnp.where(on[..., None], share, 0.0).sum(axis=1) / count
-    often = row_load.astype(jnp.float32) * (c.num_experts / c.experts_per_token) / count
-    load = row_load.sum(axis=0)
-    return chosen, gates, {"aux": (often * mean_share).sum(axis=-1).mean(),
-                            **experts.load_stats(c, load), "load": load}
+    """``experts.sigmoid_route`` with the layer's bias in the selection: the
+    ``experts_per_token`` largest of the sigmoid scores plus the bias, their
+    gates from the scores alone; ``aux`` is the balance loss, a row at a time."""
+    return experts.sigmoid_route(c, u, p, real, rows, bias=p[BIAS])
 
 
 # ---- the stack and the module -------------------------------------------------

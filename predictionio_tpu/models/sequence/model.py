@@ -40,7 +40,7 @@ from predictionio_tpu.parallel.mesh import (
     put_global,
 )
 from predictionio_tpu.models.sequence import (
-    blocks, hybrid, latent_moe, looped, sasrec, sparse_moe,
+    blocks, hybrid, latent_moe, looped, sasrec, sparse_moe, window_moe,
 )
 from predictionio_tpu.models.sequence.sasrec import SASRec, SASRecConfig  # noqa: F401
 
@@ -48,7 +48,7 @@ logger = logging.getLogger("pio.sequence")
 
 #: the ``backbone`` engine parameter -> the module that is that backbone
 BACKBONES = {"sasrec": sasrec, "looped": looped, "sparse_moe": sparse_moe,
-             "hybrid_linear": hybrid, "latent_moe": latent_moe}
+             "hybrid_linear": hybrid, "latent_moe": latent_moe, "window_moe": window_moe}
 
 
 def backbone_named(config) -> tuple:

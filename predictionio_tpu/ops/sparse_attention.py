@@ -38,7 +38,12 @@ is what runs off the TPU and what the tests hold the programs to):
 The mask is the whole contract of which pairs count: causality is in it (the
 selection takes causal keys only) and nothing else masks a pair.
 :func:`causal_attention` is the same two programs with no mask operand:
-every causal pair counts, and a tile's mask is made from its positions. Rows are
+every causal pair counts, and a tile's mask is made from its positions; with a
+``window`` the pairs are those of the band ``t - window < s <= t`` and the
+programs walk the band's tiles alone (the grid's key axis is as long as the
+most key blocks a query block's band touches, counted from the band's first key
+block and clamped at the diagonal's: a tile outside the band is neither fetched
+nor worked). Rows are
 left-aligned, so a padded position follows every event of its row and no
 real query can select it; a padded query's output is never read.
 
@@ -110,6 +115,52 @@ def _params(*semantics):
 def _last_key_block(qi, bq: int, bk: int):
     """The last key block that holds a causal pair with query block ``qi``."""
     return (qi * bq + bq - 1) // bk
+
+
+def _first_key_block(qi, bq: int, bk: int, window):
+    """The first key block that holds a pair of query block ``qi``'s band
+    (``t - window < s``); 0 with no window. ``qi`` traced or a Python int."""
+    if window is None:
+        return 0
+    reach = qi * bq - (window - 1)
+    return (jnp.maximum(reach, 0) if isinstance(reach, jax.Array) else max(reach, 0)) // bk
+
+
+def _key_block(qi, j, bq: int, bk: int, window):
+    """The key block step ``j`` of the grid's key axis works for query block
+    ``qi``: ``j`` itself with no window, else counted from the band's first."""
+    return j if window is None else _first_key_block(qi, bq, bk, window) + j
+
+
+def band_key_blocks(t: int, bq: int, bk: int, window) -> int:
+    """The length of the grid's key axis: the most key blocks the pairs of one
+    query block touch: the row's with no window, else the band's."""
+    return max(_last_key_block(qi, bq, bk) - _first_key_block(qi, bq, bk, window) + 1
+               for qi in range(t // bq))
+
+
+def tiles_of(kv: int, g: int, d: int, dv: int, t: int, itemsize: int) -> tuple:
+    """``((queries, keys) of the forward program's tile, of the backward
+    program's)`` for a call with no mask operand on a row of ``t``, from the
+    shapes alone, at the module's ``BLOCK_Q`` and ``BLOCK_K``."""
+    bq, bk = _block(BLOCK_Q, t), _block(BLOCK_K, t)
+    shape = (g, d, dv, t, itemsize, False)
+    s = backward_heads_per_step(kv, *shape, bq, bk)
+    return (bq, bk), (backward_query_block(s, *shape, bq, bk), bk)
+
+
+def band_tiles(t: int, bq: int, bk: int, window) -> int:
+    """The tiles a program works on a row of ``t`` a head: every query block's
+    key blocks from its band's first to the diagonal's."""
+    return sum(_last_key_block(qi, bq, bk) - _first_key_block(qi, bq, bk, window) + 1
+               for qi in range(t // bq))
+
+
+def band_pairs(t: int, window) -> int:
+    """The pairs ``t' - window < s <= t'`` of a row of ``t`` positions (the
+    causal triangle's with no window)."""
+    w = t if window is None else min(window, t)
+    return w * (w + 1) // 2 + (t - w) * w
 
 
 # ---- index scores -----------------------------------------------------------
@@ -298,13 +349,15 @@ def sparse_attention_plain(q, k, v, mask):
     return out.reshape(b, t, h, -1).astype(q.dtype)
 
 
-def _tile(mask_ref, qi, ki, bq: int, bk: int):
+def _tile(mask_ref, qi, ki, bq: int, bk: int, window=None):
     """The pairs of tile ``(qi, ki)`` that count: the mask's, or with no mask
-    operand the causal ones."""
+    operand the causal ones, of them with a window those of the band
+    (``t - window < s <= t``), from the tile's positions."""
     if mask_ref is not None:
         return mask_ref[0].astype(jnp.int32) != 0
-    return (ki * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-            <= qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0))
+    key = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+    query = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+    return key <= query if window is None else (key <= query) & (key > query - window)
 
 
 def _kernel(kernel, masked, **static):
@@ -316,11 +369,12 @@ def _kernel(kernel, masked, **static):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, bq: int, bk: int):
-    qi, ki = pl.program_id(2), pl.program_id(3)
+                *, bq: int, bk: int, window=None):
+    qi, step = pl.program_id(2), pl.program_id(3)
+    ki = _key_block(qi, step, bq, bk, window)
     heads, group = q_ref.shape[1], q_ref.shape[1] // k_ref.shape[1]
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _():
         m_scr[...] = jnp.full(m_scr.shape, _NEG, jnp.float32)
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
@@ -329,7 +383,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref, m_scr, l_scr, a
     @pl.when(ki <= _last_key_block(qi, bq, bk))
     def _():
         for j in range(k_ref.shape[1]):
-            k, v, on = k_ref[0, j], v_ref[0, j], _tile(mask_ref, qi, ki, bq, bk)
+            k, v, on = k_ref[0, j], v_ref[0, j], _tile(mask_ref, qi, ki, bq, bk, window)
             for h in range(j * group, (j + 1) * group):
                 s = jnp.where(on, _dot(q_ref[0, h], k, 1, 1), _NEG)
                 m_old = m_scr[h]
@@ -340,7 +394,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref, m_scr, l_scr, a
                 acc_scr[h] = acc_scr[h] * scale + _dot(p.astype(v.dtype), v, 1, 0)
                 m_scr[h] = m_new
 
-    @pl.when(ki == pl.num_programs(3) - 1)
+    @pl.when(step == pl.num_programs(3) - 1)
     def _():
         for h in range(heads):
             l = jnp.maximum(l_scr[h], 1e-20)
@@ -349,20 +403,21 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref, m_scr, l_scr, a
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref, *, bq: int, bk: int):
+                dq_ref, dk_ref, dv_ref, *, bq: int, bk: int, window=None):
     """One tile's scores, probabilities and ``ds``, and all three gradients
     from them. ``dq_ref`` is the query block's sum over the key blocks;
     ``dk_ref`` and ``dv_ref`` hold the step's key-value heads over the whole
     row, a tile adding into its key block's rows."""
-    qi, ki = pl.program_id(2), pl.program_id(3)
+    qi, step = pl.program_id(2), pl.program_id(3)
+    ki = _key_block(qi, step, bq, bk, window)
     group = q_ref.shape[1] // k_ref.shape[1]
 
-    @pl.when((qi == 0) & (ki == 0))
+    @pl.when((qi == 0) & (step == 0))
     def _():
         dk_ref[...] = jnp.zeros(dk_ref.shape, jnp.float32)
         dv_ref[...] = jnp.zeros(dv_ref.shape, jnp.float32)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _():
         dq_ref[...] = jnp.zeros(dq_ref.shape, jnp.float32)
 
@@ -370,7 +425,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
     def _():
         rows = pl.ds(pl.multiple_of(ki * bk, bk), bk)
         for j in range(k_ref.shape[1]):
-            k, v, on = k_ref[0, j], v_ref[0, j], _tile(mask_ref, qi, ki, bq, bk)
+            k, v, on = k_ref[0, j], v_ref[0, j], _tile(mask_ref, qi, ki, bq, bk, window)
             dk, dv = dk_ref[0, j, rows], dv_ref[0, j, rows]
             for h in range(j * group, (j + 1) * group):
                 q, do = q_ref[0, h], do_ref[0, h]
@@ -438,14 +493,16 @@ def backward_query_block(s: int, g: int, d: int, dv: int, t: int, itemsize: int,
     return wide if t % wide == 0 and fits else bq
 
 
-def _specs(s: int, g: int, d: int, dv: int, bq: int, bk: int, t: int):
+def _specs(s: int, g: int, d: int, dv: int, bq: int, bk: int, t: int, window=None):
     """Block specs of one call for ``s`` key-value heads a step of ``g`` query
     heads each, scores over ``d`` and values of ``dv``, on the grid
     (b, kv / s, qi, ki) with the key block clamped to the last one under the
-    diagonal. A clamped step names the block the step before it held: nothing
+    diagonal; with a window the key axis counts from the band's first key
+    block. A clamped step names the block the step before it held: nothing
     is fetched. ``dk`` and ``dv`` are the step's heads over the whole row, one
     buffer each, in VMEM while (b, kv / s) stands and written when it moves."""
-    at = lambda qi, ki: (qi, jnp.minimum(ki, _last_key_block(qi, bq, bk)))  # noqa: E731
+    at = lambda qi, ki: (qi, jnp.minimum(_key_block(qi, ki, bq, bk, window),  # noqa: E731
+                                         _last_key_block(qi, bq, bk)))
 
     def spec(block, index, **kw):
         return pl.BlockSpec(block, lambda b, kv, i, j: index(b, kv, *at(i, j)), **kw)
@@ -475,18 +532,18 @@ def sparse_attention(q, k, v, mask, block_q=BLOCK_Q, block_k=BLOCK_K, interpret=
     return _forward(q, k, v, mask, block_q, block_k, interpret)[0]
 
 
-def _forward(q, k, v, mask, block_q, block_k, interpret):
+def _forward(q, k, v, mask, block_q, block_k, interpret, window=None):
     b, t, h, d = q.shape
     kv, dv = k.shape[2], v.shape[3]
     g = h // kv
     s = heads_per_step(kv, g)
     bq, bk = _block(block_q, t), _block(block_k, t)
-    sp = _specs(s, g, d, dv, bq, bk, t)
+    sp = _specs(s, g, d, dv, bq, bk, t, window)
     masked = (mask,) if mask is not None else ()
     scaled = (q.astype(jnp.float32) * d ** -0.5).astype(q.dtype)
     out, lse = pl.pallas_call(
-        _kernel(_fwd_kernel, masked, bq=bq, bk=bk),
-        grid=(b, kv // s, t // bq, t // bk),
+        _kernel(_fwd_kernel, masked, bq=bq, bk=bk, window=window),
+        grid=(b, kv // s, t // bq, band_key_blocks(t, bq, bk, window)),
         in_specs=[sp["q"], sp["k"], sp["v"]] + [sp["mask"]] * len(masked),
         out_specs=[sp["o"], sp["row"]],
         out_shape=[jax.ShapeDtypeStruct((b, h, t, dv), q.dtype),
@@ -505,7 +562,7 @@ def _fwd(q, k, v, mask, block_q, block_k, interpret):
     return out, (q, k, v, mask, out, lse)
 
 
-def _bwd(block_q, block_k, interpret, res, g_out):
+def _bwd(block_q, block_k, interpret, res, g_out, window=None):
     q, k, v, mask, out, lse = res
     b, t, h, d = q.shape
     kv, dv = k.shape[2], v.shape[3]
@@ -525,10 +582,10 @@ def _bwd(block_q, block_k, interpret, res, g_out):
                   for x in (lse, delta))
     qs = _heads_first((q.astype(jnp.float32) * scale).astype(q.dtype))
     kt, vt, do = _heads_first(k), _heads_first(v), _heads_first(g_out.astype(q.dtype))
-    sp = _specs(s, g, d, dv, bq, bk, t)
+    sp = _specs(s, g, d, dv, bq, bk, t, window)
     dq, dk, d_v = pl.pallas_call(
-        _kernel(_bwd_kernel, masked, bq=bq, bk=bk),
-        grid=(b, kv // s, t // bq, t // bk),
+        _kernel(_bwd_kernel, masked, bq=bq, bk=bk, window=window),
+        grid=(b, kv // s, t // bq, band_key_blocks(t, bq, bk, window)),
         in_specs=([sp["q"], sp["k"], sp["v"]] + [sp["mask"]] * len(masked)
                   + [sp["o"], sp["row"], sp["row"]]),
         out_specs=[sp["q"], sp["dk"], sp["dv"]],
@@ -548,24 +605,43 @@ def _bwd(block_q, block_k, interpret, res, g_out):
 sparse_attention.defvjp(_fwd, _bwd)
 
 
-def causal_attention_plain(q, k, v):
-    """:func:`sparse_attention_plain` over every causal pair."""
+def causal_attention_plain(q, k, v, window=None):
+    """:func:`sparse_attention_plain` over every causal pair, with a
+    ``window`` those of the band ``t - window < s <= t``."""
     at = jnp.arange(q.shape[1])
-    causal = jnp.broadcast_to(at[None, :] <= at[:, None], (q.shape[0],) + (q.shape[1],) * 2)
-    return sparse_attention_plain(q, k, v, causal)
+    on = at[None, :] <= at[:, None]
+    if window is not None:
+        on = on & (at[None, :] > at[:, None] - window)
+    return sparse_attention_plain(
+        q, k, v, jnp.broadcast_to(on, (q.shape[0],) + (q.shape[1],) * 2))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def causal_attention(q, k, v, block_q=BLOCK_Q, block_k=BLOCK_K, interpret=False):
+def band_of(window, t: int):
+    """``window`` as the programs take it: None where it holds the whole row."""
+    if window is not None and window < 1:
+        raise ValueError(f"window={window}: want at least 1 (a query reads itself)")
+    return None if window is None or window >= t else int(window)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def causal_attention(q, k, v, block_q=BLOCK_Q, block_k=BLOCK_K, interpret=False, window=None):
     """:func:`sparse_attention` over every causal pair, with no mask operand:
     q [B, T, H, D], k [B, T, KV, D], v [B, T, KV, DV] -> [B, T, H, DV]. A
-    padded position follows its row's events, so no real query reads it."""
-    return _forward(q, k, v, None, block_q, block_k, interpret)[0]
+    padded position follows its row's events, so no real query reads it.
+    ``window``: a query reads itself and the ``window - 1`` positions before
+    it (``t - window < s <= t``) and the programs walk the band's tiles only:
+    the grid's key axis is as long as the most key blocks a query block's band
+    touches (:func:`band_key_blocks`), counted from the band's first."""
+    return _forward(q, k, v, None, block_q, block_k, interpret, band_of(window, q.shape[1]))[0]
 
 
-def _causal_fwd(q, k, v, block_q, block_k, interpret):
-    out, lse = _forward(q, k, v, None, block_q, block_k, interpret)
+def _causal_fwd(q, k, v, block_q, block_k, interpret, window):
+    out, lse = _forward(q, k, v, None, block_q, block_k, interpret, band_of(window, q.shape[1]))
     return out, (q, k, v, None, out, lse)
 
 
-causal_attention.defvjp(_causal_fwd, _bwd)
+def _causal_bwd(block_q, block_k, interpret, window, res, g_out):
+    return _bwd(block_q, block_k, interpret, res, g_out, band_of(window, res[0].shape[1]))
+
+
+causal_attention.defvjp(_causal_fwd, _causal_bwd)

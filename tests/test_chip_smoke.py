@@ -16,7 +16,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PHASES = ["device", "compile_cache", "ingest", "train_als", "als_full_width",
           "serve_als", "train_serve_ncf", "train_sequence_looped",
           "train_sequence_sparse_moe", "train_sequence_hybrid_linear",
-          "train_sequence_latent_moe"]
+          "train_sequence_latent_moe", "train_sequence_window_moe"]
 
 
 def _run(args, tmp_path, timeout, **env_overrides):
@@ -78,6 +78,17 @@ def test_rehearsal_reaches_every_phase_then_refuses_the_cpu(tmp_path):
     # the sparse step's eleven, the dense layer's norm, the shared expert, the
     # two latent paths, and six of them again under the prediction module
     assert latent["leaf_scopes"] == 21
+    window = by_phase["train_sequence_window_moe"]
+    assert window["backbone"] == "window_moe" and window["last_loss"] < window["first_loss"]
+    assert (window["window_layers"], window["full_layers"], window["experts_shared"]) == (1, 2, 1)
+    assert (window["heads_window"], window["heads_full"], window["rope_tables"]) == (8, 6, 2)
+    assert (window["experts_held"], window["experts_total"], window["moe_dropped"]) == (4, 16, 0)
+    # a window of 32 on rows of 128: 32 x 33 / 2 + 96 x 32 pairs of the triangle's 8,256
+    assert (window["window"], window["window_pairs"], window["causal_pairs"]) == (32, 3600, 8256)
+    assert window["window_programs"] == {"forward": 0, "backward": 0}   # no chip, no programs
+    # the sparse step's eleven, the dense layer's norm, the shared expert and
+    # the window layers' five
+    assert window["leaf_scopes"] == 18
 
 
 def test_without_a_chip_the_default_run_stops_at_the_device_phase(tmp_path):

@@ -850,7 +850,10 @@ class TestCompletionRetry:
             before = bridge._inflight._value
             bridge._deliver(w, {"i": 9}, b"doomed", is_query=True)
             deadline = time.monotonic() + 5
-            while bridge._retry.depth() and time.monotonic() < deadline:
+            # the retry thread takes the entry off its queue and then gives the
+            # permit back: wait for both, not for the queue alone
+            while ((bridge._retry.depth() or bridge._inflight._value == before)
+                   and time.monotonic() < deadline):
                 time.sleep(0.005)
             assert bridge._retry.depth() == 0
             # dropped, not delivered -- and the admission permit came back

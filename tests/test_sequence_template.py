@@ -152,6 +152,16 @@ EXAMPLES = {
         nope_dim=32, rope_dim=16, value_dim=32, ffn_dim=448, shared_expert_dim=64,
         routed_scale=2.5, mtp_depth=1, mtp_coef=0.3, balance_coef=0.0001, bias_rate=0.001,
         rope_theta=32000000.0, **EXPERTS)),
+    # PR 44's backbone: what the same loop gives for its example
+    "window_moe": ("engine-window-moe.json", "WindowMoEConfig", dict(
+        hidden_size=128,
+        layer_types=("full_attention",) + ("sliding_attention",) * 3 + ("full_attention",),
+        mlp_layer_types=("dense",) + ("sparse",) * 4, heads_per_layer=(6, 8, 8, 8, 6),
+        num_kv_heads=2, head_dim=32, window=64, ffn_dim=448, shared_expert_dim=64,
+        routed_scale=2.5, balance_coef=0.0001, full_rope_theta=500000.0, full_rope_factor=4.0,
+        full_rope_original_len=64, full_rope_beta_fast=64.0, full_rope_beta_slow=1.0,
+        full_rope_attention_factor=1.1386294361119891, full_rotary_fraction=0.5,
+        window_rope_theta=10000.0, **EXPERTS)),
 }
 
 

@@ -89,6 +89,8 @@ ACCEPTED_STEPS = {
         "ce5c863a7fedcad164c52410cc3811218a989c37ec47646f06454128bb167475",
     "joyai-llm-flash-ep16.train-lifelong-histories":
         "8e7876fed48375e0bb2904f6aebe302d446d7ce548a40ba523f80f2c81290626",
+    "laguna-xs2-ep16.train-lifelong-histories":
+        "efbc787d8b03ef0222096d4e05e0c8073d466fb4f096dd5523ab05f5f64eb1c3",
 }
 
 
@@ -956,6 +958,95 @@ def test_the_latent_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
                     if "mtp" in scopes_latent.places_of(n)}
     assert {"attention", "exit", "layers"} <= under_module
     assert any("/seq.optimizer/bias/" in n for n in names)
+    seen = {(p.stage, p.leaf) for p in map(scopes_leaf.place_of, names) if p and p.leaf}
+    assert {("attention", leaf) for leaf in ("norm", "qkv", "rope", "kernel", "out")} <= seen
+    assert {("mlp", "norm"), ("moe", "norm"), ("experts", "grouped")} <= seen
+
+
+def test_the_window_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persistent_cache):
+    """The step of ``laguna-xs2-ep16.train-lifelong-histories`` (2 rows of 8,192
+    at the published widths: layer 0 full and dense, then one period of three
+    window layers of 64 heads and a full layer of 48, 16 of 256 experts held,
+    an eighth of the vocabulary): Mosaic takes the attention programs with no
+    mask operand at 6 query heads a key-value head (the full layers: a grid of
+    2 x 8 x 32 x 16, the row's sixteen key blocks) and with a window of 512 at
+    8 (the window layers: 2 x 8 x 32 x 2, the two key blocks a query block's
+    band touches; the backward program 2 x 8 x 16 x 2), the peak is under the
+    chip's 15.75 GB, and every program sits where the benchmark's readers look:
+    the full layers' under ``attention`` (layer 0 and the period's: forward,
+    again and one backward program each), the window layers' under
+    ``window_attention`` in the scan inside the period's, the run sum's in both
+    kinds of expert layer."""
+    import re
+
+    from benchmarks import scopes_leaf, scopes_seq, scopes_sparse, scopes_window
+    from predictionio_tpu.models.sequence import experts, model as seq_model, window_moe
+    from predictionio_tpu.ops import sparse_attention as sa
+
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "seq"))
+    full, window = window_moe.FULL, window_moe.WINDOW
+    config = window_moe.WindowMoEConfig(
+        num_items=12_543, max_len=8192, hidden_size=2048,
+        layer_types=(full, window, window, window, full),
+        mlp_layer_types=("dense", "sparse", "sparse", "sparse", "sparse"),
+        heads_per_layer=(48, 64, 64, 64, 48), num_kv_heads=8, head_dim=128, window=512,
+        ffn_dim=8192, expert_dim=512, num_experts=256, experts_per_token=8,
+        experts_held=(0, 16), shared_expert_dim=512, batch_size=2)
+    assert window_moe.count_params(config) == 490_297_344
+
+    def forward_grid(heads, band):
+        q = jax.ShapeDtypeStruct((2, 8192, heads, 128), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((2, 8192, 8, 128), jnp.bfloat16)
+        traced = jax.make_jaxpr(lambda q, k, v: sa._forward(
+            q, k, v, None, sa.BLOCK_Q, sa.BLOCK_K, False, band))(q, kv, kv)
+        (grid,) = [eqn.params["grid_mapping"].grid for eqn in traced.jaxpr.eqns
+                   if eqn.primitive.name == "pallas_call"]
+        return grid
+
+    assert forward_grid(48, None) == (2, 8, 32, 16) and forward_grid(64, 512) == (2, 8, 32, 2)
+    assert _backward_attention_grid((2, 8192, 48, 128), 8, 128) == (2, 8, 16, 16)
+    assert sa.tiles_of(8, 8, 128, 128, 8192, 2) == ((256, 512), (512, 512))
+    assert sa.band_key_blocks(8192, 512, 512, 512) == 2
+    assert experts.pass_plan(config, 16384) == (16384, 8)
+    _, _, step_fn, seq_shard = seq_model.make_fit(config, mesh)
+    rep = NamedSharding(mesh, P())
+    sds = lambda shape, dtype, sh: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)  # noqa: E731
+    params = jax.tree_util.tree_map(
+        lambda shape: sds(shape, jnp.float32, rep), window_moe.param_shapes(config),
+        is_leaf=lambda x: isinstance(x, tuple))
+    opt_state = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype, rep),
+        jax.eval_shape(seq_model.optimizer_of(config).init, params))
+    batch = {k: sds((2, 8192), jnp.int32, seq_shard) for k in ("seq", "target")}
+    compiled = step_fn.lower(params, opt_state, batch, sds((2,), jnp.uint32, rep)).compile()
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert 11.0e9 < peak < 15.75e9, peak       # 11.49 GB
+    text = compiled.as_text()
+    assert _digest(text) == ACCEPTED_STEPS["laguna-xs2-ep16.train-lifelong-histories"]
+    calls = [c for c in re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text) if "seq." in c]
+    # layer 0 and the period's full layer: each forward, again and one backward
+    attention = [c for c in calls if scopes_leaf.place_of(c).stage == "attention"]
+    assert len(attention) == 6 and all(
+        scopes_leaf.place_of(c).leaf == "kernel" for c in attention)
+    kinds = [scopes_seq.kernel_kind(c) for c in calls]
+    assert kinds.count("forward") == 4 and kinds.count("backward") == 2
+    # the three window layers are one scan's body: forward, again, backward
+    banded = [c for c in calls if scopes_window.place_of(c) is not None]
+    assert [scopes_window.place_of(c) for c in banded] == [("window", "kernel")] * 3
+    assert sorted(scopes_leaf.phase_of(c) for c in banded) == [
+        "backward", "forward", "recomputed"]
+    assert all(scopes_leaf.place_of(c).stage == "layers" for c in banded)
+    assert not [c for c in calls if scopes_sparse.parse_stage(c) in ("index", "select")]
+    # no array of the row by the row: the band's mask is made in the tiles (the
+    # one [2, 8192, 8192] is layer 0's SwiGLU of 8,192, positions by width)
+    assert not re.search(r"(?:pred|s8|u8|s32)\[(?:\d+,)*8192,8192\]", text)
+    assert not re.search(r"\[(?:\d+,){2,}8192,8192\]", text)
+    assert re.search(r"%ragged-dot-none(?:\.\d+)? = [^\n]*tpu_custom_call", text)
+    _the_sums_are_programs(text, [c for c in calls if c not in banded], 4)
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    places = {scopes_window.place_of(n) for n in names} - {None}
+    assert {leaf for _, leaf in places} >= set(scopes_window.LEAVES)
     seen = {(p.stage, p.leaf) for p in map(scopes_leaf.place_of, names) if p and p.leaf}
     assert {("attention", leaf) for leaf in ("norm", "qkv", "rope", "kernel", "out")} <= seen
     assert {("mlp", "norm"), ("moe", "norm"), ("experts", "grouped")} <= seen
