@@ -351,8 +351,9 @@ class Smoke:
                           for k in (SPARSE_FIT_FACTS + ATTENTION_FIT_FACTS + HYBRID_FIT_FACTS
                                     + LATENT_FIT_FACTS + WINDOW_FIT_FACTS)
                           if k in said})
-            if "moe_sum" in said:          # how the experts' rows come back: a word
-                facts["moe_sum"] = said["moe_sum"]
+            for word in ("moe_sum", "conv_block"):   # how the experts' rows come back, and
+                if word in said:                      # the conv's tile: words
+                    facts[word] = said[word]
         timings = re.search(r"stage timings: (.*)$", text, re.M)
         if timings:
             facts["stage_timings"] = timings.group(1).strip()
@@ -826,6 +827,12 @@ class Smoke:
         if facts.get("delta_heads_per_step") != next(g for g in (8, 4, 2, 1) if row_heads % g == 0):
             raise PhaseFailed(f"train_sequence_hybrid_linear: {row_heads} row-heads, and a grid"
                               f" step of the state pass takes {facts.get('delta_heads_per_step')}")
+        # the conv's programs: a tile of positions by channels on the chip, XLA's
+        # passes on the host
+        tiled = re.fullmatch(r"\d+x\d+", str(facts.get("conv_block"))) is not None
+        if tiled != (self.device["platform"] == "tpu"):
+            raise PhaseFailed(f"train_sequence_hybrid_linear: the conv's tile reads"
+                              f" {facts.get('conv_block')}")
         first, last = facts["first_loss"], facts["last_loss"]
         if not (first == first and last == last and last < first < float("inf")):
             raise PhaseFailed(f"train_sequence_hybrid_linear: loss not finite and falling: {first} -> {last}")

@@ -48,8 +48,17 @@ mathematics with none of this):
   own input (``remat``): the forward pass keeps the residual stream before
   each, ``2 L`` states ``[B, T, D]``, and nothing else, and a backward pass
   holds one half's intermediates at a time (a linear mixer's and its experts'
-  together did not leave the chip room). Inside a linear mixer the conv keeps
-  its input and works its products and silu again;
+  together did not leave the chip room);
+- a linear mixer's conv, its silu, the mask of empty positions and the split
+  into q, k, v are ``ops/causal_conv.causal_conv_silu``: on a TPU one Pallas
+  program forward and one backward, each reading the projection's float32
+  output once and writing q, k, v (or ``dx`` and a partial ``dw``) once, a
+  tile of ``conv_block`` positions by channels a grid step (1,024 by 512 in
+  the published widths, from the shapes alone) with the 8 rows beside it, the
+  tile walked 32 rows at a time in registers. It keeps for the backward pass
+  what the plain expression's ``jax.checkpoint`` keeps, the projection's
+  output, and forms the pre-activation again in VMEM; off the TPU its plain
+  twin, XLA's pad, shifted slices, silu and split;
 - the delta rule is worked ``delta_chunk`` positions at a time
   (``ops/delta_rule.gated_delta_rule``): the triangular system and the
   products inside the chunks for all chunks at once, the state by a pass over
@@ -78,7 +87,7 @@ import jax
 import jax.numpy as jnp
 
 from predictionio_tpu.models.sequence import blocks, experts
-from predictionio_tpu.ops import delta_rule, sparse_attention as sa
+from predictionio_tpu.ops import causal_conv, delta_rule, sparse_attention as sa
 
 #: Device scopes of a training step beside ``blocks``'s and ``experts``'s: a
 #: linear layer's mixer under ``seq.pass1/layers/linear_attention`` (one
@@ -240,6 +249,17 @@ def delta_heads_per_step(c: HybridConfig, rows: int) -> int:
         jnp.dtype(c.compute_dtype).itemsize)
 
 
+def conv_block(c: HybridConfig, platform: str) -> str:
+    """The tile of the conv's programs on a row of ``max_len``, positions by
+    channels (``ops/causal_conv.block_of``, from the shapes alone); ``plain``
+    where XLA works the conv."""
+    if not blocks.uses_kernels(c, platform):
+        return "plain"
+    keys = c.linear_key_heads * c.linear_key_dim
+    return "x".join(map(str, causal_conv.block_of(
+        c.max_len, (keys, keys, c.linear_value_heads * c.linear_value_dim))))
+
+
 def attention_backward_heads_per_step(c: HybridConfig) -> int:
     """The key-value heads a grid step of the full layer's backward attention
     program works on a row of ``max_len`` (``ops/sparse_attention``, from the
@@ -257,6 +277,7 @@ def fit_attrs(c: HybridConfig, rows: int, platform: str) -> dict:
         "kv_heads": c.num_kv_heads, "selection_kept_bytes": 0,
         "linear_layers": c.linear_layers, "full_layers": c.periods,
         "delta_chunk": c.delta_chunk, "delta_heads_per_step": delta_heads_per_step(c, rows),
+        "conv_block": conv_block(c, platform),
         "delta_state_bytes": delta_state_bytes(c), "delta_kept_bytes": delta_kept_bytes(c, rows),
     }
 
@@ -266,14 +287,6 @@ def norm0(x, weight, eps):
 
 
 # ---- the mixers --------------------------------------------------------------
-
-def _causal_conv(x, weight):
-    """``y[b, t, c] = sum_i weight[c, i] x[b, t - (K - 1) + i, c]``, zero before
-    the row: ``x`` [B, T, C], ``weight`` [C, K]."""
-    t, width = x.shape[1], weight.shape[1]
-    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
-    return sum(padded[:, i:i + t] * weight[:, i] for i in range(width))
-
 
 def _l2_normalised(x):
     return x * jax.lax.rsqrt((x * x).sum(axis=-1, keepdims=True) + 1e-6)
@@ -287,6 +300,7 @@ def _linear_attention(c: HybridConfig, backend: str, h, p, real):
     hk, hv, dk, dv = (c.linear_key_heads, c.linear_value_heads, c.linear_key_dim,
                       c.linear_value_dim)
     on = real[..., None]
+    kernels, interpret = blocks.uses_kernels(c, backend), backend != "tpu"
     with jax.named_scope(blocks.SCOPE_QKV):
         # two products, so that the gate z is not a view into one array that
         # holds q, k and v until the backward pass is done with z
@@ -294,10 +308,12 @@ def _linear_attention(c: HybridConfig, backend: str, h, p, real):
         mixed, z = blocks.matmul(h, w_mixed, dtype), blocks.matmul(h, w_z, dtype)
         beta, a = jnp.split(blocks.matmul(h, p["w_ba"], dtype), 2, axis=-1)
     with jax.named_scope(SCOPE_CONV):
-        # kept: the input alone; the products and the silu are worked again
-        mixed = jax.checkpoint(lambda x, w: jax.nn.silu(_causal_conv(x, w)))(
-            jnp.where(on, mixed, 0.0), p["conv"])
-        q, k, v = jnp.split(mixed, [hk * dk, 2 * hk * dk], axis=-1)
+        # kept either way: the input alone; the products and the silu are worked
+        # again. v enters the rule's products as the compute dtype: it is written so
+        split = (hk * dk, hk * dk, hv * dv), ("float32", "float32", dtype.name)
+        q, k, v = (causal_conv.causal_conv_silu(mixed, p["conv"], real, *split, interpret)
+                   if kernels else
+                   causal_conv.causal_conv_silu_plain(mixed, p["conv"], real, *split))
     with jax.named_scope(SCOPE_GATES):
         beta = jnp.where(on, jax.nn.sigmoid(beta), 0.0)
         g = jnp.where(on, -jnp.exp(p["a_log"]) * jax.nn.softplus(a + p["dt_bias"]), 0.0)
@@ -306,7 +322,7 @@ def _linear_attention(c: HybridConfig, backend: str, h, p, real):
     with jax.named_scope(SCOPE_DELTA):
         o = delta_rule.gated_delta_rule(
             q, k, v.reshape(b, t, hv, dv), g, beta, chunk=c.delta_chunk, dtype=dtype,
-            kernels=blocks.uses_kernels(c, backend), interpret=backend != "tpu")
+            kernels=kernels, interpret=interpret)
     with jax.named_scope(SCOPE_GATED_NORM):
         y = blocks.rms_norm(o, p["norm"], c.rms_eps) * jax.nn.silu(z.reshape(b, t, hv, dv))
     return y.reshape(b, t, hv * dv)

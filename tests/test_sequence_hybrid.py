@@ -131,9 +131,12 @@ def test_loss_auxiliary_loss_and_every_gradient_match_the_reference(
     assert int(aux["moe_dropped"]) == 0
 
 
-def test_bfloat16_matmul_inputs_stay_near_the_reference(params, batch, sound):
-    """As the cell runs it: bfloat16 into every product, float32 out of it."""
-    (loss, aux), grads = _step(_config(compute_dtype="bfloat16"), params, batch)
+@pytest.mark.parametrize("attention", ["plain", "flash"])
+def test_bfloat16_matmul_inputs_stay_near_the_reference(params, batch, sound, attention):
+    """As the cell runs it: bfloat16 into every product, float32 out of it;
+    with the programs the conv writes v in bfloat16 and reads its cotangent so."""
+    (loss, aux), grads = _step(_config(compute_dtype="bfloat16", attention=attention),
+                               params, batch)
     want, want_aux, want_grads = sound
     assert abs(float(loss) - float(want)) < 5e-3
     assert abs(float(aux["aux_loss"]) - float(want_aux["aux_loss"])) < 1e-3
@@ -160,7 +163,8 @@ def test_each_control_of_the_reference_reads_other_gradients(
 
 
 REWORKED = {"no-remat": dict(remat=False), "chunk-16": dict(delta_chunk=16),
-            "chunk-64": dict(delta_chunk=64), "head-whole": dict(head_chunk=0)}
+            "chunk-64": dict(delta_chunk=64), "head-whole": dict(head_chunk=0),
+            "programs": dict(attention="flash")}
 
 
 @pytest.mark.parametrize("case", list(REWORKED))
@@ -286,6 +290,12 @@ def test_a_grid_step_takes_the_row_heads_that_divide_and_fit():
     assert hybrid.delta_heads_per_step(config, 2) == 8
     assert fit_attrs(config, 4, 8, 2, "cpu")["delta_heads_per_step"] == 8
     assert fit_attrs(_config(), 4, 8, 3, "cpu")["delta_heads_per_step"] == 4     # 3 rows x 4 heads
+    # the conv's tile, where its programs run: 8,192 positions of 2 x 16 x 128 + 32 x 128 channels
+    auto = _config(max_len=8192, linear_key_heads=16, linear_value_heads=32, linear_key_dim=128,
+                   linear_value_dim=128, attention="auto")
+    assert fit_attrs(auto, 4, 8, 2, "tpu")["conv_block"] == "1024x512"
+    assert fit_attrs(auto, 4, 8, 2, "cpu")["conv_block"] == "plain"
+    assert fit_attrs(_config(attention="flash"), 4, 8, 3, "cpu")["conv_block"] == "64x96"
 
 
 def test_a_position_without_beta_or_decay_leaves_the_state():
@@ -496,6 +506,7 @@ def test_the_backbone_learns_a_cycle_and_reports_its_fit(caplog):
     assert (attrs["linear_layers"], attrs["full_layers"], attrs["delta_chunk"],
             attrs["experts_shared"], attrs["experts_held"]) == (1, 1, 4, 1, 4)
     assert attrs["delta_heads_per_step"] == 8                      # 32 rows x 2 value heads
+    assert attrs["conv_block"] == "plain"                          # ``attention="plain"``
     assert (attrs["attention_backward_programs"],
             attrs["attention_backward_heads_per_step"]) == (0, 2)       # ``attention="plain"``
     assert attrs["delta_state_bytes"] == 2 * 8 * 8 * 4
@@ -503,7 +514,8 @@ def test_the_backbone_learns_a_cycle_and_reports_its_fit(caplog):
     assert attrs["moe_dropped"] == 0 and attrs["moe_held_assignments"] == attrs["moe_assignments"]
     line = next(r.getMessage() for r in caplog.records if "seq_fit:" in r.getMessage())
     for word in ("backbone=hybrid_linear", "linear_layers=1", "full_layers=1", "delta_chunk=4",
-                 "delta_heads_per_step=8", "delta_state_bytes=512", "delta_kept_bytes=",
+                 "delta_heads_per_step=8", "conv_block=plain", "delta_state_bytes=512",
+                 "delta_kept_bytes=",
                  "experts_shared=1", "moe_dropped=0"):
         assert word in line, (word, line)
 

@@ -79,14 +79,15 @@ def _kernel_instructions(text: str, name: str) -> list[str]:
 #: is PR 36's still; PR 42: the sparse cell's again, whose attention's backward
 #: pass is one program where it was two; PR 43: the hybrid and the latent
 #: cell's, written from PR 42's tree before PR 43 moved the backbones' shared
-#: pieces, so that all four held that refactor to the same programs)
+#: pieces, so that all four held that refactor to the same programs; PR 45: the
+#: hybrid cell's, whose conv, silu, mask and split are one program a phase)
 ACCEPTED_STEPS = {
     "ouro-2.6b-d8.train-histories":
         "7849910ae58d05dc248f996c7c3e71238090eae70f3d76ad3486b9b4d5c342ef",
     "keye-vl2-30b-a3b-ep8.train-lifelong-histories":
         "b145a5bbda1425b96c0b687b9641f86606382da6ca15368a7bcf725455617e87",
     "qwen3-next-80b-a3b-ep16.train-lifelong-histories":
-        "ce5c863a7fedcad164c52410cc3811218a989c37ec47646f06454128bb167475",
+        "2f21b774ecb119f3e79279643342e53deb6b382d785d88822a5196d4f04e4e36",
     "joyai-llm-flash-ep16.train-lifelong-histories":
         "8e7876fed48375e0bb2904f6aebe302d446d7ce548a40ba523f80f2c81290626",
     "laguna-xs2-ep16.train-lifelong-histories":
@@ -802,12 +803,15 @@ def test_the_hybrid_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
     of 8,192 at the published widths, one period of three linear layers and a
     full one, 32 of 512 experts held, an eighth of the vocabulary): Mosaic takes
     the delta rule's state pass and its transpose at 128 chunks of 64, blocks
-    of 8 of the 64 row-heads a grid step (a grid of 8 x 128), and the
+    of 8 of the 64 row-heads a grid step (a grid of 8 x 128), the conv's two
+    programs at tiles of 1,024 positions by 512 channels, q, k and v each an
+    output of the forward one and a cotangent operand of the backward one, and the
     attention programs with no mask operand at head width 256, and the run
     sum's for a pass of 20,480 rows of 10 slots a token, the peak is
     under the chip's 15.75 GB, and every program and every leaf sits under the
-    scope the benchmark's readers look for. A linear mixer's state pass stands
-    forward, recomputed and (its transpose) backward; the full layer's
+    scope the benchmark's readers look for. A linear mixer's state pass and its
+    conv stand forward, recomputed and (their transposes) backward, and nothing
+    else of the conv's scope moves a ``[2, 8192, 8192]`` array; the full layer's
     attention forward, recomputed and as one backward program, a key-value
     head's ``dk`` and ``dv`` (16.8 MB) in VMEM for the whole row."""
     import re
@@ -825,6 +829,7 @@ def test_the_hybrid_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
         experts_per_token=10, experts_held=(0, 32), shared_expert_dim=512, batch_size=2)
     assert hybrid.count_params(config) == 625_667_136
     assert hybrid.delta_heads_per_step(config, 2) == 8
+    assert hybrid.conv_block(config, "tpu") == "1024x512"
     assert experts.pass_plan(config, 16384) == (20480, 8)
     assert _run_sum_grid(16384, 10, 20480) == (64, 11)
     _, _, step_fn, seq_shard = seq_model.make_fit(config, mesh)
@@ -859,6 +864,13 @@ def test_the_hybrid_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
         "recomputed" if "rematted_computation" in c else
         "backward" if "transpose(" in c else "forward" for c in names)
     assert phases(rule) == ["backward", "forward", "recomputed"]
+    conv = [c for c in calls if scopes_hybrid.place_of(c) == ("linear", "conv")]
+    assert phases(conv) == ["backward", "forward", "recomputed"]
+    # the split and the cotangents' joining are the programs' own: beside them
+    # the scope holds the mask's and the taps' small arrays alone
+    beside = re.findall(r"= \(?(f32|bf16)\[2,8192,\d{4}\][^\n]*? (fusion|copy)\([^\n]*"
+                        r'op_name="[^"]*linear_attention/conv/', text)
+    assert not beside, beside
     kinds = [scopes_seq.kernel_kind(c) for c in calls]
     assert kinds.count("forward") == 2 and kinds.count("backward") == 1
     assert hybrid.attention_backward_heads_per_step(config) == 1
