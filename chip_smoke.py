@@ -351,8 +351,10 @@ class Smoke:
                           for k in (SPARSE_FIT_FACTS + ATTENTION_FIT_FACTS + HYBRID_FIT_FACTS
                                     + LATENT_FIT_FACTS + WINDOW_FIT_FACTS)
                           if k in said})
-            for word in ("moe_sum", "conv_block"):   # how the experts' rows come back, and
-                if word in said:                      # the conv's tile: words
+            # how the experts' rows come back, the conv's tile and the tiles of
+            # the programs that write the attention's operands: words
+            for word in ("moe_sum", "conv_block", "rope_block", "window_rope_block"):
+                if word in said:
                     facts[word] = said[word]
         timings = re.search(r"stage timings: (.*)$", text, re.M)
         if timings:
@@ -409,6 +411,24 @@ class Smoke:
         if (2 * banded["backward"] != banded["forward"]
                 or (banded["backward"] >= 1) != (on_chip and bool(facts.get("window_layers")))):
             raise PhaseFailed(f"{name}: the programs under window_attention/kernel: {banded}")
+
+    def operands_written_once(self, name: str, facts: dict, leaves: dict) -> None:
+        """Where the package's programs run, a layer's attention operands are
+        written by ``ops/rope_layout.py``'s one program a phase: the fit gives
+        its tile (``plain`` where XLA works the rotation), and the compiled
+        step holds under ``attention/rope`` and ``window_attention/rope`` one
+        backward program to every two forward ones, as under ``kernel``."""
+        on_chip = self.device["platform"] == "tpu"
+        blocks = [facts.get("rope_block")] + (
+            [facts.get("window_rope_block")] if facts.get("window_layers") else [])
+        if any((re.fullmatch(r"\d+x\d+", str(b)) is not None) != on_chip for b in blocks):
+            raise PhaseFailed(f"{name}: the tile of the operands' programs reads {blocks}")
+        for stage, wanted in (("attention", True), ("window_attention",
+                                                    bool(facts.get("window_layers")))):
+            programs = leaves["rope_programs"][stage]
+            if (2 * programs["backward"] != programs["forward"]
+                    or (programs["backward"] >= 1) != (on_chip and wanted)):
+                raise PhaseFailed(f"{name}: the programs under {stage}/rope: {programs}")
 
     def query_all(self, url: str, queries: list[dict]) -> tuple[list, float]:
         answers, lat = [], []
@@ -754,6 +774,7 @@ class Smoke:
                               f" alone: {leaves}")
         self.rows_come_back("train_sequence_sparse_moe", facts, leaves)
         self.one_backward_program("train_sequence_sparse_moe", facts, leaves)
+        self.operands_written_once("train_sequence_sparse_moe", facts, leaves)
         self.line("train_sequence_sparse_moe", t0, **facts, users=8, events=int(users.size),
                   max_len=max_len, leaf_scopes=len(leaves["leaves"]),
                   again_in_backward=leaves["again_backward"], **widths)
@@ -840,6 +861,7 @@ class Smoke:
                                   {"experts": SPARSE_LEAVES["experts"]})
         self.rows_come_back("train_sequence_hybrid_linear", facts, leaves)
         self.one_backward_program("train_sequence_hybrid_linear", facts, leaves)
+        self.operands_written_once("train_sequence_hybrid_linear", facts, leaves)
         self.line("train_sequence_hybrid_linear", t0, **facts, users=4, events=int(users.size),
                   max_len=max_len, **widths)
 
@@ -1003,6 +1025,7 @@ class Smoke:
         leaves = self.step_leaves("sequence_window_moe_leaves", algorithm, max_len, WINDOW_LEAVES)
         self.rows_come_back("train_sequence_window_moe", facts, leaves)
         self.one_backward_program("train_sequence_window_moe", facts, leaves)
+        self.operands_written_once("train_sequence_window_moe", facts, leaves)
         self.line("train_sequence_window_moe", t0, **facts, users=4, events=int(users.size),
                   max_len=max_len, leaf_scopes=len(leaves["leaves"]),
                   window_programs=leaves["window_programs"], **widths)
@@ -1217,7 +1240,7 @@ def child_sequence_step_leaves(params: dict) -> dict:
     (``tpu_custom_call``) lies under ``experts/.../sum``, and how many lie under
     ``attention/.../kernel`` and under ``window_attention/.../kernel`` forward
     (the pass worked again in the backward pass is a forward program) and
-    backward. A program served
+    backward, and the same under the two stages' ``rope``. A program served
     from the compile cache is read as it was served: the cache's key has to
     cover the names (``utils/platform.configure_compile_cache``)."""
     import jax
@@ -1251,13 +1274,19 @@ def child_sequence_step_leaves(params: dict) -> dict:
     programs = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
     under_sum = sorted({phase(name) for name in programs
                         if "/experts/" in name and "/sum/" in name})
-    attention, banded = (["forward" if "rematted_computation" in name else phase(name)
-                          for name in programs if f"/{stage}/" in name and "/kernel/" in name]
-                         for stage in ("attention", "window_attention"))
+    stages = ("attention", "window_attention")
+
+    def under(leaf: str) -> list:
+        return [["forward" if "rematted_computation" in name else phase(name)
+                 for name in programs if f"/{stage}/" in name and f"/{leaf}/" in name]
+                for stage in stages]
+
+    attention, banded = under("kernel")
+    by_phase = lambda found: {k: found.count(k) for k in ("forward", "backward")}  # noqa: E731
     return {"device": rep, "leaves": leaves, "again_forward": again.count("forward"),
             "again_backward": again.count("backward"), "sum_programs": under_sum,
-            "attention_programs": {k: attention.count(k) for k in ("forward", "backward")},
-            "window_programs": {k: banded.count(k) for k in ("forward", "backward")}}
+            "attention_programs": by_phase(attention), "window_programs": by_phase(banded),
+            "rope_programs": dict(zip(stages, map(by_phase, under("rope"))))}
 
 
 def _load_model(engine_dir: str, instance_id: str):

@@ -1,8 +1,10 @@
 """What every backbone of the sequence template is built from: the device
 scopes of a training step, the fields every decoder's configuration holds, the
 parameter draw, the block's small pieces (norm, rotary positions, matmul,
-SwiGLU), the chunked head and its loss, and the template's attention on its
-mesh. The backbones import this module (the expert ones through
+SwiGLU), the chunked head and its loss, the template's attention on its
+mesh, and the streamed attention of the sparse, hybrid and window backbones
+with the operands it reads (``rope_operands``, ``attention_of``). The backbones
+import this module (the expert ones through
 ``experts.py``) and none imports another; nothing here imports a backbone.
 
 How the pieces are worked, for all of them: matmul inputs are cast to
@@ -21,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from predictionio_tpu.ops import rope_layout, sparse_attention as sa
 from predictionio_tpu.ops.flash_attention import flash_attention
 from predictionio_tpu.parallel.ring_attention import plain_attention, ring_attention
 from predictionio_tpu.parallel.ulysses import ulysses_attention
@@ -41,9 +44,11 @@ SCOPE_OPTIMIZER = "seq.optimizer"
 #: Leaves under the stages, by class of operation, the same names in every
 #: backbone: ``norm`` at a layer's RMSNorm call sites (the exit's final norm
 #: stays the exit's), ``qkv`` the input projections and the reshape to heads,
-#: ``rope`` the rotary positions of ``q`` and ``k``, ``kernel`` the attention
-#: itself (the Pallas programs and the transposes and casts around them),
-#: ``out`` the output projection and the residual add.
+#: ``rope`` the rotary positions of ``q`` and ``k`` (in the streamed backbones,
+#: where the package's programs run, the programs that write the attention's
+#: operands: ``rope_operands``), ``kernel`` the attention itself (the Pallas
+#: programs and the transposes and casts around them), ``out`` the output
+#: projection and the residual add.
 SCOPE_NORM = "norm"
 SCOPE_QKV = "qkv"
 SCOPE_ROPE = "rope"
@@ -167,11 +172,9 @@ def rope_tables(t: int, head_dim: int, theta: float):
     return jnp.cos(angle), jnp.sin(angle)
 
 
-def rotate(x, cos, sin):
-    """x [B, T, H, hd] float32."""
-    half = x.shape[-1] // 2
-    turned = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
-    return x * cos[None, :, None, :] + turned * sin[None, :, None, :]
+#: x [B, T, H, hd] float32 turned by ``cos, sin`` [T, rd] over the first ``rd``
+#: of a head, the rest passed through (the plain twin of the rotation's program)
+rotate = rope_layout.rotate
 
 
 def matmul(x, w, dtype):
@@ -253,6 +256,46 @@ def uses_kernels(c, backend: str) -> bool:
     """Whether the package's Pallas programs work the step (``attention``:
     "auto" takes them on a TPU, "flash" everywhere, "plain" nowhere)."""
     return c.attention == "flash" or (c.attention == "auto" and backend == "tpu")
+
+
+def rope_operands(c, backend: str, q, k, v, rope):
+    """What a layer's streamed attention reads (:func:`attention_of`), from the
+    projections' float32 outputs q [B, T, H, hd], k, v [B, T, KV, hd] and the
+    layer's table, for the scope ``rope``. Where the package's programs run,
+    ``ops/rope_layout.py``'s one program a phase writes them once: rotated,
+    q scaled for the scores, cast to the compute dtype and laid heads-first as
+    the attention programs read them. Elsewhere q and k rotated, float32."""
+    if not uses_kernels(c, backend):
+        return rotate(q, *rope), rotate(k, *rope), v
+    b, t, h, _ = q.shape
+    return rope_layout.rope_layout(
+        *(x.reshape(b, t, -1) for x in (q, k, v)), *rope, (h, k.shape[2]), c.compute_dtype,
+        backend != "tpu")
+
+
+def attention_of(c, backend: str, q, k, v, mask=None, window=None):
+    """Attention [B, T, H, hd] in the compute dtype on :func:`rope_operands`'
+    q, k, v, for the scope ``kernel``: over the pairs ``mask`` [B, T, T]
+    selects, or every causal pair, with a ``window`` those of the band. The
+    programs of ``ops/sparse_attention.py`` on the operands as they lie, or
+    their plain twins."""
+    if uses_kernels(c, backend):
+        return sa.heads_first_attention(q, k, v, mask, sa.BLOCK_Q, sa.BLOCK_K,
+                                        backend != "tpu", window)
+    q, k, v = (x.astype(jnp.dtype(c.compute_dtype)) for x in (q, k, v))
+    if mask is not None:
+        return sa.sparse_attention_plain(q, k, v, mask)
+    return sa.causal_attention_plain(q, k, v, window)
+
+
+def rope_block(c, platform: str, heads: int, kv_heads: int, head_dim: int) -> str:
+    """The tile of the programs that write a layer's attention operands on a
+    row of ``max_len``, positions by lanes of q (``ops/rope_layout.tile_of``,
+    from the shapes alone); ``plain`` where XLA works the rotation."""
+    if not uses_kernels(c, platform):
+        return "plain"
+    bt, lanes, _ = rope_layout.tile_of((heads, kv_heads), head_dim, head_dim, c.max_len)
+    return f"{bt}x{lanes}"
 
 
 def attend(c, mesh, q, k, v, pad_mask):

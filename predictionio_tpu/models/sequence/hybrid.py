@@ -68,10 +68,13 @@ mathematics with none of this):
   ``B x HV``, from the shapes alone). While a layer's backward pass runs, the
   state every chunk starts from is held (``delta_kept_bytes``); between the
   passes nothing of the rule is;
-- the full layer's attention is ``ops/sparse_attention.causal_attention``: the
-  sparse backbone's attention programs, forward and one backward (8 query
-  heads a key-value head, K and V streamed), with no mask operand; off the TPU
-  its plain twin;
+- the full layer's attention is the sparse backbone's attention programs,
+  forward and one backward (8 query heads a key-value head, K and V streamed),
+  with no mask operand, on operands that ``ops/rope_layout.py``'s one program
+  a phase writes from the normed q and k and from v: turned over the first
+  ``rotary_dim`` of a head, q scaled, cast and laid heads-first
+  (``blocks.rope_operands`` under ``rope``, ``blocks.attention_of`` under
+  ``kernel``); off the TPU ``blocks.rotate`` and the plain twin;
 - matmul inputs are ``compute_dtype`` (bfloat16) with float32 accumulation;
   the state, ``g``, ``beta``, the l2 norms, the triangular system, the router,
   norms, rotary positions, softmax, residual stream, loss, master weights and
@@ -278,6 +281,7 @@ def fit_attrs(c: HybridConfig, rows: int, platform: str) -> dict:
         "linear_layers": c.linear_layers, "full_layers": c.periods,
         "delta_chunk": c.delta_chunk, "delta_heads_per_step": delta_heads_per_step(c, rows),
         "conv_block": conv_block(c, platform),
+        "rope_block": blocks.rope_block(c, platform, c.num_heads, c.num_kv_heads, c.head_dim),
         "delta_state_bytes": delta_state_bytes(c), "delta_kept_bytes": delta_kept_bytes(c, rows),
     }
 
@@ -332,7 +336,7 @@ def _full_attention(c: HybridConfig, backend: str, rope, h, p):
     """The full mixer's gated output before ``W_o`` ``[B, T, H x hd]``."""
     dtype = jnp.dtype(c.compute_dtype)
     b, t, _ = h.shape
-    hd, rd = c.head_dim, c.rotary_dim
+    hd = c.head_dim
     with jax.named_scope(blocks.SCOPE_QKV):
         q, gate = jnp.split(blocks.matmul(h, p["wq"], dtype).reshape(b, t, c.num_heads, 2 * hd),
                             2, axis=-1)
@@ -341,14 +345,9 @@ def _full_attention(c: HybridConfig, backend: str, rope, h, p):
     with jax.named_scope(blocks.SCOPE_NORM):
         q, k = norm0(q, p["q_norm"], c.rms_eps), norm0(k, p["k_norm"], c.rms_eps)
     with jax.named_scope(blocks.SCOPE_ROPE):
-        q, k = (jnp.concatenate([blocks.rotate(x[..., :rd], *rope), x[..., rd:]], axis=-1)
-                for x in (q, k))
+        q, k, v = blocks.rope_operands(c, backend, q, k, v, rope)
     with jax.named_scope(blocks.SCOPE_KERNEL):
-        q, k, v = q.astype(dtype), k.astype(dtype), v.astype(dtype)
-        if blocks.uses_kernels(c, backend):
-            out = sa.causal_attention(q, k, v, sa.BLOCK_Q, sa.BLOCK_K, backend != "tpu")
-        else:
-            out = sa.causal_attention_plain(q, k, v)
+        out = blocks.attention_of(c, backend, q, k, v)
         out = out.astype(jnp.float32) * jax.nn.sigmoid(gate)
     return out.reshape(b, t, -1)
 

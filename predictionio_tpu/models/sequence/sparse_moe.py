@@ -52,8 +52,12 @@ with none of this):
 - on a TPU (``attention`` "auto") index scores, selection and attention are
   the three programs of ``ops/sparse_attention.py``: scores in tiles over the
   causal triangle, the k-th largest by bisection with a block of queries'
-  scores in VMEM, attention with K and V streamed a block at a time; elsewhere
-  their ``jax.numpy`` twins;
+  scores in VMEM, attention with K and V streamed a block at a time; the
+  attention's operands are written by ``ops/rope_layout.py``'s one program a
+  phase (``blocks.rope_operands`` under ``rope``: q and k turned, q scaled,
+  all three cast and laid heads-first as the programs read them; its
+  transpose turns the backward program's ``dq``, ``dk``, ``dv`` back);
+  elsewhere their ``jax.numpy`` twins and ``blocks.rotate``;
 - the head and loss are ``blocks.exit_ce``'s chunks of positions.
 """
 
@@ -196,6 +200,7 @@ def fit_attrs(c: SparseMoEConfig, rows: int, platform: str) -> dict:
         **blocks.decoder_fit_attrs(c, c.num_layers),
         **experts.fit_attrs(c, platform, attention_backward_heads_per_step(c), shared=False),
         "index_topk": c.index_topk, "kv_heads": c.num_kv_heads,
+        "rope_block": blocks.rope_block(c, platform, c.num_heads, c.num_kv_heads, c.head_dim),
         # what a rematerialised layer keeps beside its input: one bit a pair
         "selection_kept_bytes": selection_kept_bytes(c, rows),
     }
@@ -213,7 +218,7 @@ def _attention(c: SparseMoEConfig, backend: str, rope, h, p, ip, real, probe=Non
                    for w, n in (("wq", c.num_heads), ("wk", c.num_kv_heads),
                                 ("wv", c.num_kv_heads)))
     with jax.named_scope(blocks.SCOPE_ROPE):
-        q, k = blocks.rotate(q, *rope), blocks.rotate(k, *rope)
+        q, k, v = blocks.rope_operands(c, backend, q, k, v, rope)
     with jax.named_scope(SCOPE_INDEX):
         # the selection is a hard top-k: no gradient, to the input or the indexer
         hs, ip = jax.lax.stop_gradient((h, ip))
@@ -237,12 +242,7 @@ def _attention(c: SparseMoEConfig, backend: str, rope, h, p, ip, real, probe=Non
         # a rematerialised layer starts from these bits (``hidden_states``)
         kept = checkpoint_name(pack_rows(mask), KEPT_SELECTION)
     with jax.named_scope(blocks.SCOPE_KERNEL):
-        mask = unpack_rows(kept, t)
-        q, k, v = q.astype(dtype), k.astype(dtype), v.astype(dtype)
-        if kernels:
-            out = sa.sparse_attention(q, k, v, mask, sa.BLOCK_Q, sa.BLOCK_K, interpret)
-        else:
-            out = sa.sparse_attention_plain(q, k, v, mask)
+        out = blocks.attention_of(c, backend, q, k, v, unpack_rows(kept, t))
     return out.reshape(b, t, -1), counts
 
 

@@ -44,9 +44,14 @@ with none of this):
   whole period, ``[W', ...]``); a ``layer_types`` that does not group so is
   refused. Each half of a layer keeps its input alone and is worked again in
   the backward pass (``remat``), as ``hybrid.py``;
-- attention is ``ops/sparse_attention.causal_attention``, a window layer's
-  with ``window``: its programs, forward and the one backward, walk the tiles
-  of the band alone; off the TPU the plain twin. A full layer's lies under
+- attention is ``ops/sparse_attention.py``'s causal programs, a window layer's
+  with ``window``: forward and the one backward, they walk the tiles of the
+  band alone. Their operands are written by ``ops/rope_layout.py``'s one
+  program a phase (``blocks.rope_operands``, under ``rope``): q and k turned by
+  the layer's table, q scaled, all three cast and laid heads-first as the
+  programs read them (``blocks.attention_of``, under ``kernel``), and the
+  backward program's ``dq``, ``dk``, ``dv`` turned back by its transpose; off
+  the TPU ``blocks.rotate`` and the plain twin. A full layer's lies under
   the scope ``attention``, a window layer's under ``window_attention`` (one
   component, so a reader that looks for ``attention`` does not take it), with
   ``blocks``'s leaves below both;
@@ -279,6 +284,9 @@ def fit_attrs(c: WindowMoEConfig, rows: int, platform: str) -> dict:
         "window_tile": f"{bq}x{bk}", "window_tiles_walked": walked,
         "window_tiles_needed": round(2 * pairs / (bq * bk), 3),
         "rope_tables": 2,
+        "rope_block": blocks.rope_block(c, platform, g.heads_full, c.num_kv_heads, c.head_dim),
+        "window_rope_block": blocks.rope_block(c, platform, g.heads_window, c.num_kv_heads,
+                                               c.head_dim),
     }
 
 
@@ -333,7 +341,7 @@ def _attention(c: WindowMoEConfig, backend: str, kind: str, rope, h, p):
     of ``kind`` on the normed input ``h``."""
     dtype = jnp.dtype(c.compute_dtype)
     b, t, _ = h.shape
-    hd, rd = c.head_dim, rope[0].shape[-1]
+    hd = c.head_dim
     window = c.window if kind == WINDOW else None
     with jax.named_scope(blocks.SCOPE_QKV):
         q = blocks.matmul(h, p["wq"], dtype).reshape(b, t, -1, hd)
@@ -341,15 +349,9 @@ def _attention(c: WindowMoEConfig, backend: str, kind: str, rope, h, p):
                 for w in ("wk", "wv"))
         gate = jax.nn.sigmoid(blocks.matmul(h, p["wg"], dtype))          # [B, T, H]
     with jax.named_scope(blocks.SCOPE_ROPE):
-        q, k = (blocks.rotate(x, *rope) if rd == hd else
-                jnp.concatenate([blocks.rotate(x[..., :rd], *rope), x[..., rd:]], axis=-1)
-                for x in (q, k))
+        q, k, v = blocks.rope_operands(c, backend, q, k, v, rope)
     with jax.named_scope(blocks.SCOPE_KERNEL):
-        q, k, v = q.astype(dtype), k.astype(dtype), v.astype(dtype)
-        if blocks.uses_kernels(c, backend):
-            out = sa.causal_attention(q, k, v, sa.BLOCK_Q, sa.BLOCK_K, backend != "tpu", window)
-        else:
-            out = sa.causal_attention_plain(q, k, v, window)
+        out = blocks.attention_of(c, backend, q, k, v, window=window)
         out = out.astype(jnp.float32) * gate[..., None]
     return out.reshape(b, t, -1)
 

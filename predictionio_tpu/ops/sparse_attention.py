@@ -68,7 +68,19 @@ Layout (see ``flash_attention.py`` for what the hardware asks): tensors are
 ``[B, H, T, D]`` at the Pallas boundary; per-query scalars (logsumexp, delta)
 are ``[B, KV / S, T, S G]`` for ``S`` key-value heads a step of ``G`` query
 heads each, the step's heads on the lanes, so a head's column is a static lane
-slice.
+slice. **Who writes the operands.** :func:`heads_first_attention` takes
+``q`` (scaled by ``D ** -0.5``), ``k`` and ``v`` as the programs read them,
+heads-first in the compute dtype, keeps them as its residuals and hands the
+backward program's ``dq``, ``dk``, ``dv`` on as it wrote them (float32,
+heads-first): the sparse, hybrid and window backbones call it on what
+``ops/rope_layout.py``'s one program a phase wrote from the projections'
+outputs, so no XLA pass lies between the projections and the programs in
+either direction. :func:`sparse_attention` and :func:`causal_attention` take
+``[B, T, H, D]`` in the compute dtype and are the same programs behind XLA's
+passes (the scale and its two roundings, three transposes, and all of them
+again for the backward program and after it): the latent backbone's entry
+point, whose rotary key is laid out by its own code, and the twins' tests'.
+The output, its cotangent, ``delta`` and the logsumexp are XLA's either way.
 """
 
 from __future__ import annotations
@@ -533,27 +545,34 @@ def sparse_attention(q, k, v, mask, block_q=BLOCK_Q, block_k=BLOCK_K, interpret=
 
 
 def _forward(q, k, v, mask, block_q, block_k, interpret, window=None):
-    b, t, h, d = q.shape
-    kv, dv = k.shape[2], v.shape[3]
+    scaled = (q.astype(jnp.float32) * q.shape[3] ** -0.5).astype(q.dtype)
+    return _forward_programs(_heads_first(scaled), _heads_first(k), _heads_first(v), mask,
+                             block_q, block_k, interpret, window)
+
+
+def _forward_programs(qs, k, v, mask, block_q, block_k, interpret, window=None):
+    """The forward program on operands laid heads-first, ``qs`` scaled:
+    ``(out [B, T, H, DV], logsumexp)``."""
+    b, h, t, d = qs.shape
+    kv, dv = k.shape[1], v.shape[3]
     g = h // kv
     s = heads_per_step(kv, g)
     bq, bk = _block(block_q, t), _block(block_k, t)
     sp = _specs(s, g, d, dv, bq, bk, t, window)
     masked = (mask,) if mask is not None else ()
-    scaled = (q.astype(jnp.float32) * d ** -0.5).astype(q.dtype)
     out, lse = pl.pallas_call(
         _kernel(_fwd_kernel, masked, bq=bq, bk=bk, window=window),
         grid=(b, kv // s, t // bq, band_key_blocks(t, bq, bk, window)),
         in_specs=[sp["q"], sp["k"], sp["v"]] + [sp["mask"]] * len(masked),
         out_specs=[sp["o"], sp["row"]],
-        out_shape=[jax.ShapeDtypeStruct((b, h, t, dv), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((b, h, t, dv), qs.dtype),
                    jax.ShapeDtypeStruct((b, kv // s, t, s * g), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((s * g, bq, 1), jnp.float32),
                         pltpu.VMEM((s * g, bq, 1), jnp.float32),
                         pltpu.VMEM((s * g, bq, dv), jnp.float32)],
         compiler_params=_params("parallel", "parallel", "parallel", "arbitrary"),
         interpret=interpret,
-    )(_heads_first(scaled), _heads_first(k), _heads_first(v), *masked)
+    )(qs, k, v, *masked)
     return _heads_first(out), lse
 
 
@@ -564,15 +583,28 @@ def _fwd(q, k, v, mask, block_q, block_k, interpret):
 
 def _bwd(block_q, block_k, interpret, res, g_out, window=None):
     q, k, v, mask, out, lse = res
-    b, t, h, d = q.shape
-    kv, dv = k.shape[2], v.shape[3]
+    scale = q.shape[3] ** -0.5
+    qs = _heads_first((q.astype(jnp.float32) * scale).astype(q.dtype))
+    dq, dk, dv = _backward_programs(qs, _heads_first(k), _heads_first(v), mask, out, lse, g_out,
+                                    block_q, block_k, interpret, window)
+    # dq was taken against the scaled q; dk already carries the scale
+    grads = ((_heads_first(dq) * scale).astype(q.dtype), _heads_first(dk).astype(k.dtype),
+             _heads_first(dv).astype(v.dtype))
+    return grads + ((np.zeros(mask.shape, jax.dtypes.float0),) if mask is not None else ())
+
+
+def _backward_programs(qs, k, v, mask, out, lse, g_out, block_q, block_k, interpret, window=None):
+    """The backward program on operands laid heads-first, ``qs`` scaled, the
+    output and its cotangent ``[B, T, H, DV]``: ``dq`` (against ``qs``),
+    ``dk``, ``dv`` as it writes them, float32 and heads-first."""
+    b, h, t, d = qs.shape
+    kv, dv = k.shape[1], v.shape[3]
     g = h // kv
     masked = (mask,) if mask is not None else ()
     bq, bk = _block(block_q, t), _block(block_k, t)
-    shape = (g, d, dv, t, q.dtype.itemsize, bool(masked))
+    shape = (g, d, dv, t, qs.dtype.itemsize, bool(masked))
     s = backward_heads_per_step(kv, *shape, bq, bk)
     bq = backward_query_block(s, *shape, bq, bk)
-    scale = d ** -0.5
     # delta[b, t, h] = rowsum(dO o O); it and the logsumexp laid out for the
     # heads this program takes a step, which may be fewer than the forward's
     delta = jnp.einsum("bthd,bthd->bth", g_out.astype(jnp.float32),
@@ -580,10 +612,9 @@ def _bwd(block_q, block_k, interpret, res, g_out, window=None):
     lse = jnp.transpose(lse, (0, 2, 1, 3)).reshape(b, t, h)
     lse, delta = (jnp.transpose(x.reshape(b, t, kv // s, s * g), (0, 2, 1, 3))
                   for x in (lse, delta))
-    qs = _heads_first((q.astype(jnp.float32) * scale).astype(q.dtype))
-    kt, vt, do = _heads_first(k), _heads_first(v), _heads_first(g_out.astype(q.dtype))
+    do = _heads_first(g_out.astype(qs.dtype))
     sp = _specs(s, g, d, dv, bq, bk, t, window)
-    dq, dk, d_v = pl.pallas_call(
+    return pl.pallas_call(
         _kernel(_bwd_kernel, masked, bq=bq, bk=bk, window=window),
         grid=(b, kv // s, t // bq, band_key_blocks(t, bq, bk, window)),
         in_specs=([sp["q"], sp["k"], sp["v"]] + [sp["mask"]] * len(masked)
@@ -594,12 +625,7 @@ def _bwd(block_q, block_k, interpret, res, g_out, window=None):
                    jax.ShapeDtypeStruct((b, kv, t, dv), jnp.float32)],
         compiler_params=_params("parallel", "parallel", "arbitrary", "arbitrary"),
         interpret=interpret,
-    )(qs, kt, vt, *masked, do, lse, delta)
-
-    # dq was taken against the scaled q; dk already carries the scale
-    grads = ((_heads_first(dq) * scale).astype(q.dtype), _heads_first(dk).astype(k.dtype),
-             _heads_first(d_v).astype(v.dtype))
-    return grads + tuple(np.zeros(m.shape, jax.dtypes.float0) for m in masked)
+    )(qs, k, v, *masked, do, lse, delta)
 
 
 sparse_attention.defvjp(_fwd, _bwd)
@@ -645,3 +671,39 @@ def _causal_bwd(block_q, block_k, interpret, window, res, g_out):
 
 
 causal_attention.defvjp(_causal_fwd, _causal_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def heads_first_attention(qs, k, v, mask=None, block_q=BLOCK_Q, block_k=BLOCK_K,
+                          interpret=False, window=None):
+    """The same two programs on operands laid as they read them
+    (``ops/rope_layout.py`` writes them so): ``qs`` [B, H, T, D] **scaled by
+    ``D ** -0.5``**, k [B, KV, T, D], v [B, KV, T, DV] -> [B, T, H, DV], over
+    the pairs ``mask`` (int8 [B, T, T]) selects or, with None, every causal
+    pair, with a ``window`` those of the band. No pass lies between the
+    operands and the programs, forward or backward: the residuals are the
+    operands, and the cotangents of ``qs`` (against the scaled q), ``k`` and
+    ``v`` are handed on as the backward program wrote them, float32 and
+    heads-first, for the operands' writer to round and turn back."""
+    return _heads_first_fwd(qs, k, v, mask, block_q, block_k, interpret, window)[0]
+
+
+def _heads_first_fwd(qs, k, v, mask, block_q, block_k, interpret, window):
+    if mask is not None and window is not None:
+        raise ValueError("a mask is the whole contract of which pairs count: no window beside it")
+    out, lse = _forward_programs(qs, k, v, mask, block_q, block_k, interpret,
+                                 band_of(window, qs.shape[2]))
+    return out, (qs, k, v, mask, out, lse)
+
+
+def _heads_first_bwd(block_q, block_k, interpret, window, res, g_out):
+    # the cotangents leave in float32 for operands of the compute dtype: JAX
+    # hands a cotangent on as a rule returns it, and the writer's rule rounds
+    *operands, out, lse = res
+    grads = _backward_programs(*operands, out, lse, g_out, block_q, block_k, interpret,
+                               band_of(window, out.shape[1]))
+    mask = operands[3]
+    return (*grads, None if mask is None else np.zeros(mask.shape, jax.dtypes.float0))
+
+
+heads_first_attention.defvjp(_heads_first_fwd, _heads_first_bwd)

@@ -86,6 +86,10 @@ def test_rehearsal_reaches_every_phase_then_refuses_the_cpu(tmp_path):
     # a window of 32 on rows of 128: 32 x 33 / 2 + 96 x 32 pairs of the triangle's 8,256
     assert (window["window"], window["window_pairs"], window["causal_pairs"]) == (32, 3600, 8256)
     assert window["window_programs"] == {"forward": 0, "backward": 0}   # no chip, no programs
+    # nor the operands' programs: XLA works the rotation, and the fits say so
+    assert [phase[word] for phase, word in (
+        (sparse, "rope_block"), (hybrid, "rope_block"), (window, "rope_block"),
+        (window, "window_rope_block"))] == ["plain"] * 4
     # the sparse step's eleven, the dense layer's norm, the shared expert and
     # the window layers' five
     assert window["leaf_scopes"] == 18
