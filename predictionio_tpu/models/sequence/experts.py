@@ -1,7 +1,22 @@
 """The routed experts of the sequence template's expert backbones
-(``sparse_moe``, ``hybrid``, ``latent_moe``, ``window_moe``): a router's choice worked as
-grouped matmuls over the experts this program holds, beside a shared expert
-every token takes where the layer has one.
+(``sparse_moe``, ``hybrid``, ``latent_moe``, ``window_moe``, ``cca_moe``): a
+router's choice worked as grouped matmuls over the experts this program holds,
+beside a shared expert every token takes where the layer has one.
+
+A router is ``route(c, u, p, real) -> (experts, gates, stats)``. Two things a
+router may do beyond that (``cca_moe``'s does both):
+
+- **a choice beyond** ``num_experts``. ``experts`` may hold indexes from
+  ``num_experts`` up: choices that no program of any chip holds, whose output
+  is zero (a token that skips the layer's experts). Such an assignment is in no
+  pass, adds nothing and moves no expert's gradient; the router counts it
+  (``load_of(..., choices=)``) and reports it beside the experts' own counts
+  (``skip_assignments``: ``counts`` gives ``moe_skip_assignments``), so
+  ``assignments`` stays the assignments to experts;
+- **a carry**. With ``carry`` given, ``moe`` and ``expert_half`` call
+  ``route(c, u, p, real, carry)``, the router's state of the layer before, and
+  the router returns its own state fourth, which they hand back last: a second
+  value that travels from layer to layer beside the residual stream.
 
 ``experts_held = (lo, hi)`` names the experts this program holds, as one chip
 of an expert-parallel deployment does: the router is whole (every chip routes
@@ -74,6 +89,9 @@ MOE_CHUNK_BYTES = 256 << 20
 #: (single experts up to 1.58 of theirs, PR 32; the sum over those held is
 #: steadier than any one); a router that sends more takes further passes
 MOE_ROWS_OVER_EVEN = 2
+
+#: a router's bias that no gradient reaches: the leaf no optimizer touches
+BIAS = "router_bias"
 
 #: engine parameter -> field, for the backbones' own tables
 ENGINE_PARAMS = {"expertDim": "expert_dim", "numExperts": "num_experts",
@@ -338,20 +356,26 @@ def experts_chunk(c, interpret, w_gate, w_up, w_down, u, experts, gates,
     return y, worked.sum(), (worked > 0).sum()
 
 
-def load_of(c, experts, real):
+def load_of(c, experts, real, choices: int | None = None):
     """The assignments of real tokens to every expert ``[E]`` under a router's
-    choice ``experts`` [N, K]."""
-    chosen = (experts[..., None] == jnp.arange(c.num_experts)) & real[:, None, None]
+    choice ``experts`` [N, K]; with ``choices``, to every one of a router's
+    ``choices`` (the experts first, then those that no program holds)."""
+    chosen = (experts[..., None] == jnp.arange(choices or c.num_experts)) & real[:, None, None]
     return chosen.sum(axis=(0, 1))
 
 
 def load_stats(c, load) -> dict:
     """A layer's counts of its ``load`` [E]: every assignment, those to held
-    experts, the most one held expert takes."""
+    experts, the most one held expert takes. A ``load`` longer than
+    ``num_experts`` also says how many took a choice that is no expert."""
     lo, hi = c.experts_held
     held_load = load[lo:hi]
+    beyond = {}
+    if load.shape[0] > c.num_experts:
+        load, past = load[:c.num_experts], load[c.num_experts:]
+        beyond = {"skip_assignments": past.sum()}
     return {"assignments": load.sum(), "held_assignments": held_load.sum(),
-            "held_load_max": held_load.max()}
+            "held_load_max": held_load.max(), **beyond}
 
 
 def route(c, u, p, real):
@@ -424,7 +448,7 @@ def held_experts(c, backend: str, u, p, experts, gates, real, stats):
     return y.reshape(-1, y.shape[-1])[:n], stats
 
 
-def moe(c, backend: str, u, p, real, route=route):
+def moe(c, backend: str, u, p, real, route=route, carry=None):
     """``(y, stats)``: the held experts' part of the routed sum for the normed
     tokens ``u`` [N, D], and the layer's counts. ``backend`` is the platform
     the layer runs on, which decides how a pass's rows come back
@@ -432,11 +456,13 @@ def moe(c, backend: str, u, p, real, route=route):
     the layer's router, under ``moe/route`` (this backbone's and the hybrid's
     is the softmax ``_route``; the latent backbone brings its own); the held
     experts' work under ``moe/experts`` is the same for all. ``real`` [N]: a
-    padded slot is routed nowhere and counts nowhere."""
+    padded slot is routed nowhere and counts nowhere. With a ``carry`` [N, R]
+    the router takes it fifth and its own comes back third."""
     with jax.named_scope(SCOPE_ROUTE):
-        experts, gates, stats = route(c, u, p, real)
+        experts, gates, stats, *carried = route(
+            c, u, p, real, *(() if carry is None else (carry,)))
     with jax.named_scope(SCOPE_EXPERTS):
-        return held_experts(c, backend, u, p, experts, gates, real, stats)
+        return (*held_experts(c, backend, u, p, experts, gates, real, stats), *carried)
 
 def shared_expert(u, p, dtype):
     """The shared expert every token takes, ``u`` [N, D]: a SwiGLU of the
@@ -449,26 +475,46 @@ def shared_expert(u, p, dtype):
     return gate[:, None] * blocks.matmul(inner, p["s_down"], dtype)
 
 
-def expert_half(c, backend: str, x, p, real, norm=blocks.rms_norm, route=route):
+def expert_half(c, backend: str, x, p, real, norm=blocks.rms_norm, route=route,
+                carry=None, merge=jnp.add):
     """``(x', stats)``: a layer's second half on the residual stream ``x``
     [B, T, D] with the layer's parameters ``p``: ``norm`` by ``n2``, the held
     routed experts' part and, where ``p`` holds one, the shared expert added to
-    ``x``. ``real`` [B, T]."""
+    ``x`` (``merge(x, y)`` where the layer merges otherwise). ``real`` [B, T].
+    With a ``carry`` [B, T, R], the router's state of the layer before, the
+    router's own comes back third."""
     with jax.named_scope(SCOPE_MOE):
         with jax.named_scope(blocks.SCOPE_NORM):
             u = norm(x, p["n2"], c.rms_eps)
         flat = u.reshape(-1, u.shape[-1])
-        y, stats = moe(c, backend, flat, p, real.reshape(-1), route)
+        y, stats, *carried = moe(c, backend, flat, p, real.reshape(-1), route,
+                                 None if carry is None else carry.reshape(-1, carry.shape[-1]))
         if "s_gate" in p:
             with jax.named_scope(SCOPE_SHARED):
                 y = y + shared_expert(flat, p, jnp.dtype(c.compute_dtype))
-        return x + y.reshape(x.shape), stats
+        return (merge(x, y.reshape(x.shape)), stats,
+                *(r.reshape(*x.shape[:-1], -1) for r in carried))
+
+
+def trained_labels(params) -> dict:
+    """``"train"`` or ``"fixed"`` for every leaf: a router's bias (``BIAS``) is
+    fixed as far as the optimizer goes (a backbone's ``move`` moves it)."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: "fixed" if path[-1].key == BIAS else "train", params)
+
+
+def bias_step(rate: float, load):
+    """What a step adds to a router's bias for the ``load`` [..., choices] it
+    counted: ``rate`` up for a choice under the even load, down for one over."""
+    return rate * jnp.sign(load.mean(axis=-1, keepdims=True) - load)
 
 
 def counts(c, stats) -> dict:
     """The scalars a loss reports of its expert layers, from their stacked
     ``stats`` ``[layers with a router, ...]``."""
     held = stats["held_assignments"].sum()
+    beyond = ({"moe_skip_assignments": stats["skip_assignments"].sum()}
+              if "skip_assignments" in stats else {})
     return {
         "moe_assignments": stats["assignments"].sum(),
         "moe_held_assignments": held,
@@ -479,4 +525,5 @@ def counts(c, stats) -> dict:
         "moe_passes_run": stats["passes_run"].sum(),
         "moe_sum_rows": stats["sum_rows"].sum(),
         "moe_sum_slots": stats["sum_slots"].sum(),
+        **beyond,
     }

@@ -84,8 +84,7 @@ SCOPE_KV_LATENT = "kv_latent"
 SCOPE_MTP = "mtp"
 SCOPE_MERGE = "merge"
 SCOPE_BIAS = "bias"
-#: the leaf no optimizer touches
-BIAS = "router_bias"
+BIAS = experts.BIAS
 
 
 @dataclass(frozen=True)
@@ -202,11 +201,8 @@ def count_params(c: LatentMoEConfig) -> int:
     return blocks.count_params(param_shapes(c), but=(BIAS,))
 
 
-def trained_labels(params) -> dict:
-    """``"train"`` or ``"fixed"`` for every leaf: a router's bias is fixed as
-    far as the optimizer goes (``move`` moves it)."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, _: "fixed" if path[-1].key == BIAS else "train", params)
+#: a router's bias is fixed as far as the optimizer goes (``move`` moves it)
+trained_labels = experts.trained_labels
 
 
 def latent_bytes_per_token(c: LatentMoEConfig) -> int:
@@ -398,7 +394,7 @@ def move(c: LatentMoEConfig, params, aux):
     aux = dict(aux)
     load = aux.pop("router_load").astype(jnp.float32)                   # [routers, E]
     with jax.named_scope(SCOPE_BIAS):
-        moved = c.bias_rate * jnp.sign(load.mean(axis=-1, keepdims=True) - load)
+        moved = experts.bias_step(c.bias_rate, load)
         layers = {**params["layers"], BIAS: params["layers"][BIAS] + moved[:c.expert_layers]}
         params = {**params, "layers": layers}
         largest = jnp.abs(layers[BIAS]).max()

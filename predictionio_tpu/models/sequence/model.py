@@ -19,7 +19,11 @@ A backbone is one module that exports its configuration's dataclass
 ``score_last(c, params, seqs, last)``, ``fit_attrs(c, rows, platform)`` (its
 part of the fit's span, in the order the ``seq_fit:`` line prints it) and,
 where the loss's gradient does not train every leaf, ``trained_labels(params)``
-and ``move(c, params, aux)``. Nothing here knows a backbone beyond that.
+and ``move(c, params, aux)`` (the latent backbone's routers' biases, and the
+compressed-convolution backbone's). Nothing here knows a backbone beyond that:
+what a backbone's layers hand one another beside the residual stream (the
+compressed-convolution backbone's router state, the carry of its layer scan)
+stays inside its module, and the contract is as it was.
 """
 
 from __future__ import annotations
@@ -40,7 +44,7 @@ from predictionio_tpu.parallel.mesh import (
     put_global,
 )
 from predictionio_tpu.models.sequence import (
-    blocks, hybrid, latent_moe, looped, sasrec, sparse_moe, window_moe,
+    blocks, cca_moe, hybrid, latent_moe, looped, sasrec, sparse_moe, window_moe,
 )
 from predictionio_tpu.models.sequence.sasrec import SASRec, SASRecConfig  # noqa: F401
 
@@ -48,7 +52,8 @@ logger = logging.getLogger("pio.sequence")
 
 #: the ``backbone`` engine parameter -> the module that is that backbone
 BACKBONES = {"sasrec": sasrec, "looped": looped, "sparse_moe": sparse_moe,
-             "hybrid_linear": hybrid, "latent_moe": latent_moe, "window_moe": window_moe}
+             "hybrid_linear": hybrid, "latent_moe": latent_moe, "window_moe": window_moe,
+             "cca_moe": cca_moe}
 
 
 def backbone_named(config) -> tuple:

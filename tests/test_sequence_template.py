@@ -162,6 +162,13 @@ EXAMPLES = {
         full_rope_original_len=64, full_rope_beta_fast=64.0, full_rope_beta_slow=1.0,
         full_rope_attention_factor=1.1386294361119891, full_rotary_fraction=0.5,
         window_rope_theta=10000.0, **EXPERTS)),
+    # PR 48's backbone: what the same loop gives for its example
+    "cca_moe": ("engine-cca-moe.json", "CcaMoEConfig", dict(
+        hidden_size=128, num_layers=3, num_heads=4, num_kv_heads=2, head_dim=16, conv_time0=2,
+        conv_time1=2, router_dim=32, bias_rate=0.001, rope_theta=5000000.0,
+        rotary_fraction=0.5, **{**EXPERTS, "expert_dim": 128, "num_experts": 8,
+                                "experts_per_token": 1, "experts_held": (0, 8),
+                                "rms_eps": 1e-5})),
 }
 
 
