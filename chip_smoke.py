@@ -879,7 +879,10 @@ class Smoke:
         no dropped token, a second cross-entropy and a bias that the steps
         moved by no more than ``biasUpdateRate`` each: a silent fall to another
         backbone, to attention with one width, to a stack without its module or
-        to a bias that Adam trains would show."""
+        to a bias that Adam trains would show. Where the package's programs run,
+        the line gives the tile of the program that writes the attention's
+        operands (``rope_block``) and the compiled step holds one backward of
+        them to two forward under ``attention/rope``, as under ``kernel``."""
         import numpy as np
 
         t0 = time.time()
@@ -947,6 +950,7 @@ class Smoke:
         leaves = self.step_leaves("sequence_latent_moe_leaves", algorithm, max_len, LATENT_LEAVES)
         self.rows_come_back("train_sequence_latent_moe", facts, leaves)
         self.one_backward_program("train_sequence_latent_moe", facts, leaves)
+        self.operands_written_once("train_sequence_latent_moe", facts, leaves)
         self.line("train_sequence_latent_moe", t0, **facts, users=4, events=int(users.size),
                   max_len=max_len, leaf_scopes=len(leaves["leaves"]), **widths)
 

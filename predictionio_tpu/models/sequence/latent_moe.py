@@ -51,10 +51,15 @@ with none of this):
   scanned; each half of a layer (attention, MLP) keeps its input alone and is
   worked again in the backward pass (``remat``), as ``hybrid.py``; the
   module's merge, its two halves and both heads do the same;
-- attention is ``ops/sparse_attention.causal_attention`` with ``q`` and ``k``
-  of width ``nope_dim + rope_dim`` and ``v`` of ``value_dim``: ``k_r`` is laid
-  beside every head's ``k_nope`` in HBM, eight heads a grid step; off the TPU
-  its plain twin;
+- attention is ``ops/sparse_attention.heads_first_attention`` with ``q`` and
+  ``k`` of width ``nope_dim + rope_dim`` and ``v`` of ``value_dim``, eight
+  heads a grid step, on operands that ``ops/rope_layout.latent_rope_layout``'s
+  one program a phase writes from the projections' outputs (under ``rope``):
+  ``[q_nope | q_rope]`` turned, scaled for the scores, cast and laid
+  heads-first, ``k_r`` turned once and laid beside every head's ``k_nope`` in
+  HBM, ``v`` taken out of ``W_kvb``'s heads; its transpose sums the key's
+  cotangent over the heads. Off the TPU XLA works the rotation and the
+  attention's plain twin;
 - the router is this module's (``route``); the held experts' passes are
   ``experts.moe``'s, as they stand;
 - matmul inputs are ``compute_dtype`` (bfloat16) with float32 accumulation;
@@ -73,7 +78,7 @@ import jax
 import jax.numpy as jnp
 
 from predictionio_tpu.models.sequence import blocks, experts
-from predictionio_tpu.ops import sparse_attention as sa
+from predictionio_tpu.ops import rope_layout, sparse_attention as sa
 
 #: Device scopes beside ``blocks``'s and ``experts``'s: inside
 #: ``attention/qkv`` the two latent paths; the prediction module under
@@ -220,6 +225,17 @@ def attention_backward_heads_per_step(c: LatentMoEConfig) -> int:
         jnp.dtype(c.compute_dtype).itemsize)
 
 
+def rope_block(c: LatentMoEConfig, platform: str) -> str:
+    """``blocks.rope_block`` for this backbone's operands: the tile of
+    ``ops/rope_layout.latent_rope_layout``'s programs on a row of ``max_len``,
+    positions by lanes of q; ``plain`` where XLA works the rotation."""
+    if not blocks.uses_kernels(c, platform):
+        return "plain"
+    bt, lanes, _ = rope_layout.latent_tile_of(c.num_heads, c.nope_dim, c.rope_dim, c.value_dim,
+                                              c.max_len)
+    return f"{bt}x{lanes}"
+
+
 def fit_attrs(c: LatentMoEConfig, rows: int, platform: str) -> dict:
     """The backbone's part of the fit's span."""
     return {
@@ -230,6 +246,7 @@ def fit_attrs(c: LatentMoEConfig, rows: int, platform: str) -> dict:
         "latent_q_rank": c.q_rank, "latent_kv_rank": c.kv_rank,
         "score_width": c.score_dim, "value_width": c.value_dim,
         "latent_bytes_per_token": latent_bytes_per_token(c), "router_bias_leaves": c.routers,
+        "rope_block": rope_block(c, platform),
     }
 
 
@@ -242,42 +259,43 @@ def rope_tables(t: int, dim: int, theta: float):
     return jnp.cos(angle), jnp.sin(angle)
 
 
-def rotate(x, cos, sin):
-    """Rotary positions on ``x`` [B, T, H, dim], pairs interleaved: ``(x[2i],
-    x[2i + 1])`` turns by the position's angle ``i``. The pair's other member
-    comes by a roll either way and a choice by parity: no strided access."""
-    even = jnp.arange(x.shape[-1]) % 2 == 0
-    turned = jnp.where(even, -jnp.roll(x, -1, axis=-1), jnp.roll(x, 1, axis=-1))
-    return x * cos[None, :, None, :] + turned * sin[None, :, None, :]
+#: rotary positions on [B, T, H, dim], pairs interleaved ``(2i, 2i + 1)``
+rotate = rope_layout.rotate_pairs
+
+
+def _operands(c: LatentMoEConfig, backend: str, q, kv, k_r, rope):
+    """What the attention reads (``blocks.attention_of``), for the scope
+    ``rope``, from the projections' float32 outputs: ``q`` [B, T, H x
+    score_dim] a head's ``[q_nope | q_rope]``, ``kv`` [B, T, H x (nope_dim +
+    value_dim)] a head's ``[k_nope | v]`` and the rotary key ``k_r`` [B, T,
+    rope_dim]. Where the package's programs run,
+    ``ops/rope_layout.latent_rope_layout``'s one program a phase writes them
+    once: turned, q scaled for the scores, cast and laid heads-first, the key
+    beside every head's ``k_nope``. Elsewhere the same operands [B, T, H, .],
+    float32."""
+    if blocks.uses_kernels(c, backend):
+        return rope_layout.latent_rope_layout(q, kv, k_r, *rope, c.num_heads, c.compute_dtype,
+                                              backend != "tpu")
+    return rope_layout.latent_operands(q, kv, k_r, *rope, c.num_heads)
 
 
 def _attention(c: LatentMoEConfig, backend: str, rope, h, p):
     """The attention output before ``W_o`` ``[B, T, H x value_dim]`` on the
     normed input ``h``."""
     dtype = jnp.dtype(c.compute_dtype)
-    b, t, _ = h.shape
-    heads, dn = c.num_heads, c.nope_dim
     with jax.named_scope(blocks.SCOPE_QKV):
         with jax.named_scope(SCOPE_Q_LATENT):
             c_q = blocks.rms_norm(blocks.matmul(h, p["w_qa"], dtype), p["q_norm"], c.rms_eps)
-            q = blocks.matmul(c_q, p["w_qb"], dtype).reshape(b, t, heads, c.score_dim)
+            q = blocks.matmul(c_q, p["w_qb"], dtype)
         with jax.named_scope(SCOPE_KV_LATENT):
             c_kv, k_r = jnp.split(blocks.matmul(h, p["w_kva"], dtype), [c.kv_rank], axis=-1)
             c_kv = blocks.rms_norm(c_kv, p["kv_norm"], c.rms_eps)
-            k_nope, v = jnp.split(
-                blocks.matmul(c_kv, p["w_kvb"], dtype).reshape(b, t, heads, dn + c.value_dim),
-                [dn], axis=-1)
+            kv = blocks.matmul(c_kv, p["w_kvb"], dtype)
     with jax.named_scope(blocks.SCOPE_ROPE):
-        q = jnp.concatenate([q[..., :dn], rotate(q[..., dn:], *rope)], axis=-1)
-        k_r = rotate(k_r[:, :, None, :], *rope)          # one key a position, every head's
-        k = jnp.concatenate([k_nope, jnp.broadcast_to(k_r, (b, t, heads, c.rope_dim))], axis=-1)
+        q, k, v = _operands(c, backend, q, kv, k_r, rope)
     with jax.named_scope(blocks.SCOPE_KERNEL):
-        q, k, v = q.astype(dtype), k.astype(dtype), v.astype(dtype)
-        if blocks.uses_kernels(c, backend):
-            out = sa.causal_attention(q, k, v, sa.BLOCK_Q, sa.BLOCK_K, backend != "tpu")
-        else:
-            out = sa.causal_attention_plain(q, k, v)
-    return out.astype(jnp.float32).reshape(b, t, -1)
+        out = blocks.attention_of(c, backend, q, k, v)
+    return out.astype(jnp.float32).reshape(*h.shape[:2], -1)
 
 
 def _mixer(c: LatentMoEConfig, backend: str, rope, x, p):

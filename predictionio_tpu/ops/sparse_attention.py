@@ -72,14 +72,16 @@ slice. **Who writes the operands.** :func:`heads_first_attention` takes
 ``q`` (scaled by ``D ** -0.5``), ``k`` and ``v`` as the programs read them,
 heads-first in the compute dtype, keeps them as its residuals and hands the
 backward program's ``dq``, ``dk``, ``dv`` on as it wrote them (float32,
-heads-first): the sparse, hybrid and window backbones call it on what
-``ops/rope_layout.py``'s one program a phase wrote from the projections'
-outputs, so no XLA pass lies between the projections and the programs in
+heads-first): the sparse, hybrid, window, compressed-convolution and latent
+backbones call it on what ``ops/rope_layout.py``'s one program a phase wrote
+from the projections' outputs (``rope_layout``; for the latent backbone
+``latent_rope_layout``, which lays the one rotary key beside every head's
+``k_nope``), so no XLA pass lies between the projections and the programs in
 either direction. :func:`sparse_attention` and :func:`causal_attention` take
 ``[B, T, H, D]`` in the compute dtype and are the same programs behind XLA's
 passes (the scale and its two roundings, three transposes, and all of them
-again for the backward program and after it): the latent backbone's entry
-point, whose rotary key is laid out by its own code, and the twins' tests'.
+again for the backward program and after it): no backbone calls them since
+PR 49, the tests hold the heads-first entry point and the twins to them.
 The output, its cotangent, ``delta`` and the logsumexp are XLA's either way.
 """
 

@@ -88,8 +88,8 @@ def test_rehearsal_reaches_every_phase_then_refuses_the_cpu(tmp_path):
     assert window["window_programs"] == {"forward": 0, "backward": 0}   # no chip, no programs
     # nor the operands' programs: XLA works the rotation, and the fits say so
     assert [phase[word] for phase, word in (
-        (sparse, "rope_block"), (hybrid, "rope_block"), (window, "rope_block"),
-        (window, "window_rope_block"))] == ["plain"] * 4
+        (sparse, "rope_block"), (hybrid, "rope_block"), (latent, "rope_block"),
+        (window, "rope_block"), (window, "window_rope_block"))] == ["plain"] * 5
     # the sparse step's eleven, the dense layer's norm, the shared expert and
     # the window layers' five
     assert window["leaf_scopes"] == 18

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from predictionio_tpu.models.sequence import blocks, hybrid, sparse_moe, window_moe
+from predictionio_tpu.models.sequence import blocks, hybrid, latent_moe, sparse_moe, window_moe
 from predictionio_tpu.models.sequence.model import fit_attrs
 from predictionio_tpu.ops import rope_layout as rl, sparse_attention as sa
 
@@ -304,3 +304,177 @@ def test_the_fits_say_which_tile_wrote_the_operands():
         on_chip, on_host = (fit_attrs(config, 4, 8, 2, platform) for platform in ("tpu", "cpu"))
         assert {k: on_chip[k] for k in want} == want
         assert {k: on_host[k] for k in want} == dict.fromkeys(want, "plain")
+
+
+# ---- latent attention's operands: pairs interleaved, one rotary key ----------------
+
+#: (H, dn, dr, dv): the latent cell's heads (128 + 64 scored, 128 carried: a
+#: head of q is one and a half lane tiles), one head, and toys: four heads
+#: narrower than a lane tile, and values narrower than the scores' nope part
+LATENT = {"cell": (32, 128, 64, 128), "one-head": (1, 16, 8, 16), "toy": (4, 16, 8, 16),
+          "narrow-values": (6, 32, 16, 16)}
+
+
+def _latent_inputs(case: str, t: int, draw: str, seed: int = 0):
+    h, dn, dr, dv = LATENT[case]
+    rng = np.random.default_rng(seed + t + h)
+    q, kv, k_r = (jnp.asarray(rng.standard_normal((2, t, n), dtype=np.float32))
+                  for n in (h * (dn + dr), h * (dn + dv), dr))
+    cos, sin = latent_moe.rope_tables(t, dr, 1e4)
+    if draw == "exact":
+        q, kv, k_r, cos, sin = map(_eight_bits, (q, kv, k_r, cos, sin))
+    return (q, kv, k_r, cos, sin), h
+
+
+def _assert_the_keys_sum_is_close(have, want):
+    """``dk_r`` is a float32 sum over the heads in the program's own order."""
+    assert have.shape == want.shape and have.dtype == want.dtype == jnp.float32
+    assert np.abs(np.asarray(have) - np.asarray(want)).max() <= 1e-6 * np.abs(want).max()
+
+
+def _latent_same(draw: str, have, want):
+    """``(., ., dk_r)``: the first two as the draw allows, the key's sum to
+    float32 rounding."""
+    SAME[draw](have[:2], want[:2])
+    _assert_the_keys_sum_is_close(have[2], want[2])
+
+
+#: 20: a row shorter than ``ROWS``, one block; 48: one block longer than the
+#: row; 80 in blocks of 32: two and a half; 200: blocks of 128 from the shapes
+LATENT_ROWS = [(20, None, "exact"), (48, None, "exact"), (80, 32, "exact"), (200, None, "exact"),
+               (80, 32, "real")]
+
+
+@pytest.mark.parametrize("t,block,draw", LATENT_ROWS,
+                         ids=["20-exact", "48-exact", "80-exact", "200-exact", "80-real"])
+@pytest.mark.parametrize("case", list(LATENT))
+def test_the_latent_program_writes_the_plain_expressions_operands(case, t, block, draw):
+    """``[q_nope | turn(q_rope)]`` scaled, ``[k_nope | turn(k_r)]`` with the
+    one key in every head, and ``v`` out of ``W_kvb``'s heads."""
+    (q, kv, k_r, cos, sin), h = _latent_inputs(case, t, draw)
+    _, dn, dr, dv = LATENT[case]
+    have = jax.jit(lambda *a: rl.latent_rope_layout(*a, h, "bfloat16", True, block))(
+        q, kv, k_r, cos, sin)
+    want = jax.jit(lambda *a: rl.latent_rope_layout_plain(*a, h, "bfloat16"))(q, kv, k_r, cos, sin)
+    assert [x.dtype for x in have] == [bf16] * 3
+    assert [x.shape for x in have] == [(2, h, t, dn + dr), (2, h, t, dn + dr), (2, h, t, dv)]
+    SAME[draw](have, want)
+    # roundings and no sum: v, the lanes of k without a position, and of q to the scale's
+    _assert_equal([have[2], have[1][..., :dn]], [want[2], want[1][..., :dn]])
+    # the key is every head's
+    assert all(np.array_equal(have[1][:, 0, :, dn:], have[1][:, a, :, dn:]) for a in range(h))
+
+
+def _latent_parents_transpose(cos, sin, h: int, dn: int, dv: int, dtype):
+    """What the parent's passes make of the backward program's float32
+    heads-first ``dq``, ``dk``, ``dv``: the scale, the cast and the transpose
+    (``ops/sparse_attention._bwd``), then the transposes of the casts, the
+    concatenations, the broadcast (a sum over the heads) and the rotations
+    (``latent_moe._attention`` at the parent), to the cotangents of ``W_qb``'s,
+    ``W_kvb``'s and the rotary key's outputs."""
+    dr = cos.shape[1]
+
+    def run(dq, dk, dv_):
+        given = ((_hf(dq) * (dn + dr) ** -0.5).astype(dtype), _hf(dk).astype(dtype),
+                 _hf(dv_).astype(dtype))
+        b, t = given[0].shape[:2]
+
+        def operands(q, kv, k_r):
+            return tuple(x.astype(dtype) for x in rl.latent_operands(q, kv, k_r, cos, sin, h))
+
+        at = [jnp.zeros((b, t, n), jnp.float32) for n in (h * (dn + dr), h * (dn + dv), dr)]
+        return jax.vjp(operands, *at)[1](given)
+
+    return run
+
+
+@pytest.mark.parametrize("draw,dtype", [("exact", "bfloat16"), ("real", "bfloat16"),
+                                        ("real", "float32")])
+@pytest.mark.parametrize("case,t,block", [("cell", 48, None), ("one-head", 20, None),
+                                          ("toy", 80, 32), ("narrow-values", 200, None)],
+                         ids=lambda v: str(v))
+def test_the_latent_transpose_takes_the_backward_programs_cotangents_as_the_parents_passes_did(
+        case, t, block, draw, dtype):
+    """``dq`` and ``dkv`` are roundings and, on the rotary lanes, a sum of two
+    products; ``dk_r`` is the sum over the heads of the rounded rotary part of
+    ``dk``, turned back."""
+    (q, kv, k_r, cos, sin), h = _latent_inputs(case, t, draw)
+    _, dn, dr, dv = LATENT[case]
+    rng = np.random.default_rng(1)
+    cts = tuple(jnp.asarray(rng.standard_normal((2, h, t, w), dtype=np.float32))
+                for w in (dn + dr, dn + dr, dv))
+    have = jax.jit(lambda *c: rl._latent_bwd(h, dtype, True, block, (cos, sin), c))(*cts)
+    want = jax.jit(_latent_parents_transpose(cos, sin, h, dn, dv, jnp.dtype(dtype)))(*cts)
+    assert have[3:] == (None, None)                    # the table has no cotangent
+    _latent_same(draw, have[:3], want)
+    # a rounding and no sum: all of dkv, and the lanes of q without a position
+    _assert_equal([have[1], have[0].reshape(2, t, h, -1)[..., :dn]],
+                  [want[1], want[0].reshape(2, t, h, -1)[..., :dn]])
+    if h == 1:                                         # one head: no sum in dk_r either
+        SAME[draw](have[2:3], want[2:])
+
+
+@pytest.mark.parametrize("draw", list(SAME))
+@pytest.mark.parametrize("case,t,block", [("cell", 48, None), ("one-head", 80, 32),
+                                          ("toy", 80, 32), ("narrow-values", 20, None)],
+                         ids=lambda v: str(v))
+def test_the_latent_vjp_is_the_plain_expressions(case, t, block, draw):
+    """``jax.vjp`` of both, cotangents in the compute dtype."""
+    (q, kv, k_r, cos, sin), h = _latent_inputs(case, t, draw)
+
+    def pulled(layout):
+        def run(q, kv, k_r, cts):
+            return jax.vjp(lambda q, kv, k_r: layout(q, kv, k_r, cos, sin), q, kv, k_r)[1](cts)
+        return jax.jit(run)
+
+    program = lambda *a: rl.latent_rope_layout(*a, h, "bfloat16", True, block)  # noqa: E731
+    plain = lambda *a: rl.latent_rope_layout_plain(*a, h, "bfloat16")  # noqa: E731
+    cts = _cotangents(jax.eval_shape(plain, q, kv, k_r, cos, sin), bf16, draw)
+    have, want = pulled(program)(q, kv, k_r, cts), pulled(plain)(q, kv, k_r, cts)
+    _latent_same(draw, have, want)
+    _assert_equal(have[1:2], want[1:2])                # dkv: roundings alone
+
+
+@pytest.mark.parametrize("case", ["one-head", "toy", "narrow-values"])
+def test_the_latent_ops_together_are_the_path_they_replace(case):
+    """From the three projections' float32 outputs to the attention's output
+    and back to their cotangents: the operands' programs and the heads-first
+    attention against what ``latent_moe._attention`` did at the parent (the
+    rotations, the concatenations, the casts, ``causal_attention``), on an
+    exact draw."""
+    t = 64
+    (q, kv, k_r, cos, sin), h = _latent_inputs(case, t, "exact")
+    dv = LATENT[case][3]
+    g_out = jnp.asarray(np.random.default_rng(5).standard_normal((2, t, h, dv), dtype=np.float32),
+                        bf16)
+
+    def new(q, kv, k_r):
+        ops = rl.latent_rope_layout(q, kv, k_r, cos, sin, h, "bfloat16", True)
+        return sa.heads_first_attention(*ops, None, 32, 32, True)
+
+    def old(q, kv, k_r):
+        q, k, v = (x.astype(bf16) for x in rl.latent_operands(q, kv, k_r, cos, sin, h))
+        return sa.causal_attention(q, k, v, 32, 32, True)
+
+    (out, dq, dkv, dk_r), (want_out, want_dq, want_dkv, want_dk_r) = (
+        jax.jit(lambda q, kv, k_r, f=f: (lambda out, pull: (out, *pull(g_out)))(
+            *jax.vjp(f, q, kv, k_r)))(q, kv, k_r) for f in (new, old))
+    _assert_equal([out, dq, dkv], [want_out, want_dq, want_dkv])
+    _assert_the_keys_sum_is_close(dk_r, want_dk_r)
+
+
+def test_the_latent_tile_comes_from_the_shapes_and_the_fit_says_it():
+    """The cell's layer at 8,192 positions: four heads a step, half of what the
+    attention programs take (six lane tiles of q, eight of kv); a head that is
+    not whole lane tiles takes all the heads a step."""
+    bt, lanes, s = rl.latent_tile_of(32, 128, 64, 128, 8192)
+    assert (bt, lanes, s) == (256, 768, 4)
+    held = 2 * 4 * bt * (s * (256 + 256 + 128) + 768 + 1024 + 128)    # float32, two buffers
+    assert held <= rl.BLOCK_VMEM_BYTES < 2 * held
+    assert rl.latent_tile_of(4, 16, 8, 16, 200) == (128, 96, 4)
+    assert rl.latent_tile_of(4, 16, 8, 16, 20) == (20, 96, 4)        # a row shorter than a chunk
+    assert rl.latent_tile_of(1, 128, 64, 128, 64) == (64, 192, 1)    # one head: not whole tiles
+    config = latent_moe.LatentMoEConfig(
+        num_items=50, max_len=8192, num_heads=32, nope_dim=128, rope_dim=64, value_dim=128)
+    on_chip, on_host = (fit_attrs(config, 4, 8, 2, platform) for platform in ("tpu", "cpu"))
+    assert (on_chip["rope_block"], on_host["rope_block"]) == ("256x768", "plain")

@@ -84,8 +84,10 @@ def _kernel_instructions(text: str, name: str) -> list[str]:
 #: PR 46: the sparse, the hybrid and the window cell's, written from PR 46's
 #: final tree: ``ops/rope_layout.py``'s one program a phase writes their
 #: attention programs' operands, rotated, scaled, cast and heads-first, where
-#: XLA's passes did, and the programs read them as they lie; the looped and
-#: the latent cell's rotate by their own code and stand)
+#: XLA's passes did, and the programs read them as they lie; PR 49: the latent
+#: cell's, written from PR 49's final tree: ``latent_rope_layout``'s one
+#: program a phase writes its operands, the one rotary key beside every head's
+#: ``k_nope``; only the looped cell still rotates by its own code)
 ACCEPTED_STEPS = {
     "ouro-2.6b-d8.train-histories":
         "7849910ae58d05dc248f996c7c3e71238090eae70f3d76ad3486b9b4d5c342ef",
@@ -94,7 +96,7 @@ ACCEPTED_STEPS = {
     "qwen3-next-80b-a3b-ep16.train-lifelong-histories":
         "7e2f6d327669f7fff9c34a134263d21b4841eed263f67bc6b45fdd055b24dcd9",
     "joyai-llm-flash-ep16.train-lifelong-histories":
-        "8e7876fed48375e0bb2904f6aebe302d446d7ce548a40ba523f80f2c81290626",
+        "bf2313e68cc85ad6a42e92268996e253034e3a22899ea550ce40feeb466f4eeb",
     "laguna-xs2-ep16.train-lifelong-histories":
         "50a02a6db3f754ae12925d2cb7a6a53cb29f41fec8728df6ce2e50f0abcce9e5",
 }
@@ -940,7 +942,8 @@ def test_the_latent_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
     chip's 15.75 GB, and every program and every new scope sits where the
     benchmark's readers look for it: the attention forward, recomputed and as
     one backward program in the dense layer, in the scanned expert layers and
-    under ``mtp``; the run sum's programs for a pass of 16,384 rows, as many as
+    under ``mtp``, each behind ``ops/rope_layout.latent_rope_layout``'s program
+    under ``rope`` (PR 49: 256 positions of four heads a step); the run sum's programs for a pass of 16,384 rows, as many as
     tokens, in the expert layers and the module; the two latent paths inside
     ``qkv``; the bias's move under ``seq.optimizer``."""
     import re
@@ -984,22 +987,30 @@ def test_the_latent_cells_step_fits_the_chip_and_scopes_its_work(topo, no_persis
     batch = {k: sds((2, 8192), jnp.int32, seq_shard) for k in ("seq", "target")}
     compiled = step_fn.lower(params, opt_state, batch, sds((2,), jnp.uint32, rep)).compile()
     peak = compiled.memory_analysis().peak_memory_in_bytes
-    assert 14.0e9 < peak < 15.6e9, peak       # 15.18 GB
+    assert 14.0e9 < peak < 15.6e9, peak       # 15.01 GB (15.18 before PR 49)
     text = compiled.as_text()
     assert _digest(text) == ACCEPTED_STEPS["joyai-llm-flash-ep16.train-lifelong-histories"]
     calls = [c for c in re.findall(
         r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text) if "seq." in c]
     # the dense layer, the scan's body and the module: each forward, again and
     # one backward program (PR 42; dq and dkv before: 12 programs and 6
-    # backward); the experts' rows back by runs in the scan's body and the module
+    # backward), under ``kernel``, and as many of ``latent_rope_layout``'s under
+    # ``rope`` (PR 49); the experts' rows back by runs in the scan's body and
+    # the module
     attention = [c for c in calls if scopes_leaf.place_of(c).stage == "attention"]
-    assert len(attention) == 9 and all(
-        scopes_leaf.place_of(c).leaf == "kernel" for c in attention)
+    assert sorted(scopes_leaf.place_of(c).leaf for c in attention) == (
+        ["kernel"] * 9 + ["rope"] * 9)
+    _the_operands_are_written_once(calls, {"attention": 3})
+    assert latent_moe.fit_attrs(config, 2, "tpu")["rope_block"] == "256x768"
     _the_sums_are_programs(text, calls, 4)
+    # ``scopes_seq.kernel_kind`` takes every program under ``attention`` for an
+    # attention call, the operands' too
     kinds = [scopes_seq.kernel_kind(c) for c in calls]
-    assert kinds.count("forward") == 6 and kinds.count("backward") == 3
-    assert sum("mtp" in scopes_latent.places_of(c) for c in attention) == 3
-    assert sum("mtp" in scopes_latent.places_of(c) for c in calls) == 7      # and its four sums
+    assert kinds.count("forward") == 12 and kinds.count("backward") == 6
+    under_module = [c for c in attention if "mtp" in scopes_latent.places_of(c)]
+    assert sorted(scopes_leaf.place_of(c).leaf for c in under_module) == (
+        ["kernel"] * 3 + ["rope"] * 3)
+    assert sum("mtp" in scopes_latent.places_of(c) for c in calls) == 10     # and its four sums
     assert not [c for c in calls if scopes_sparse.parse_stage(c) in ("index", "select")]
     # the held experts: the sparse backbone's passes, XLA's own ragged dot
     assert re.search(r"%ragged-dot-none(?:\.\d+)? = [^\n]*tpu_custom_call", text)
