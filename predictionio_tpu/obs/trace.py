@@ -47,6 +47,15 @@ logger = logging.getLogger("pio.trace")
 #: span in the process shares one time axis
 _PC_TO_WALL = time.time() - time.perf_counter()
 
+
+def epoch_seconds(pc: float) -> float:
+    """A ``time.perf_counter()`` reading on the spans' axis: the epoch
+    seconds a ``SpanRecord.start_s`` taken at that reading would hold. A
+    reader that timed something with its own ``perf_counter`` (a benchmark
+    driver's window, ``utils.platform``'s compile table) places it among
+    the spans with this."""
+    return pc + _PC_TO_WALL
+
 _TRACEPARENT_RE = re.compile(
     r"^[0-9a-f]{2}-([0-9a-f]{32})-([0-9a-f]{16})-([0-9a-f]{2})$"
 )
@@ -80,6 +89,21 @@ def current_context() -> "tuple[str, str] | None":
 #: rest of the span lifecycle combined); ids need collision resistance,
 #: not unpredictability. getrandbits is one C call, atomic under the GIL.
 _id_rand = random.Random(os.urandom(16))
+
+
+def record_under_current(op: str, start_pc: float, end_pc: float,
+                         attrs: dict | None = None) -> bool:
+    """Record an explicitly timed span (``time.perf_counter()`` readings)
+    as a child of the calling thread's active span, in that span's own
+    tracer, whichever service opened it. False where the thread has no
+    active span: nothing is recorded, and no standalone trace is made."""
+    stack = getattr(_tls, "stack", None)
+    if not stack:
+        return False
+    parent = stack[-1]
+    parent._tracer.record_span(parent.trace_id, op, start_pc, end_pc,
+                               parent_id=parent.span_id, attrs=attrs)
+    return True
 
 
 def new_trace_id() -> str:

@@ -1276,6 +1276,182 @@ class TestCompileCounters:
             thread.stop()
 
 
+class TestCompileTimeline:
+    """``utils.platform``'s listeners keep what JAX tells them: each trace,
+    lowering and compile a span under the thread's active span, and a column
+    of the program's row in ``compile_report()``."""
+
+    CHILD = (
+        "import json, numpy as np\n"
+        "from predictionio_tpu.utils.platform import (\n"
+        "    compile_report, device_report, ensure_backend)\n"
+        "from predictionio_tpu.utils.metrics import global_registry\n"
+        "from predictionio_tpu.obs.trace import global_tracer\n"
+        "ensure_backend()\n"
+        "import jax\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)\n"
+        "def timeline_probe(x):\n"
+        "    return (x @ x.T).sum() * 3.0\n"
+        "with global_tracer().span('train.run'):\n"
+        "    jax.jit(timeline_probe)(np.ones((64, 64), np.float32)).block_until_ready()\n"
+        "print(json.dumps({\n"
+        "    'rows': compile_report(), 'programs': device_report()['programs'],\n"
+        "    'totals': {n: global_registry().counter_value(n) for n in (\n"
+        "        'pio_jit_trace_seconds_total', 'pio_jit_lower_seconds_total',\n"
+        "        'pio_jit_compile_seconds_total', 'pio_jit_compiles_total')},\n"
+        "    'ops': [[s['op'], s.get('attrs', {})] for t in\n"
+        "            global_tracer().snapshot()['recent'] for s in t['spans']]}))\n"
+    )
+
+    @staticmethod
+    def _child(**env) -> dict:
+        import os
+        import subprocess
+        import sys
+
+        proc = subprocess.run(
+            [sys.executable, "-c", TestCompileTimeline.CHILD], text=True,
+            env=dict(os.environ, JAX_PLATFORMS="cpu", **env),
+            capture_output=True, timeout=300)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        return json.loads(proc.stdout.strip().splitlines()[-1])
+
+    @staticmethod
+    def _probe_row(out: dict) -> dict:
+        (row,) = [r for r in out["rows"] if r["program"] == "jit(timeline_probe)"]
+        return row
+
+    @staticmethod
+    def _fresh(name: str):
+        import jax
+
+        def fn(x):
+            return x * 5.0 - 2.0
+
+        fn.__name__ = fn.__qualname__ = name
+        return jax.jit(fn)
+
+    def test_a_compile_under_an_open_span_leaves_three_children(self):
+        import numpy as np
+
+        from predictionio_tpu.utils.platform import count_compiles
+
+        count_compiles()
+        tracer = Tracer()
+        with tracer.span("query.predict") as root:
+            self._fresh("under_a_span")(np.arange(5, dtype=np.float32)).block_until_ready()
+        (trace,) = tracer.snapshot()["recent"]
+        children = [s for s in trace["spans"] if s["op"].startswith("jit.")]
+        assert [s["op"] for s in children] == ["jit.trace", "jit.lower", "jit.compile"]
+        assert all(s["parentId"] == root.span_id for s in children)
+        assert [s["attrs"]["program"] for s in children] == [
+            "under_a_span", "jit(under_a_span)", "jit(under_a_span)"]
+        assert children[2]["attrs"]["cache"] == "none"  # the suite's cache is off
+        # laid end to end inside their parent, on its clock
+        assert 0 <= children[0]["offsetMs"] <= children[1]["offsetMs"] <= children[2]["offsetMs"]
+        assert children[2]["offsetMs"] + children[2]["durationMs"] <= trace["durationMs"] + 1.0
+
+    def test_with_no_span_open_the_row_is_in_the_table_and_the_ring_gains_no_trace(self):
+        import numpy as np
+
+        from predictionio_tpu.obs.trace import global_tracer
+        from predictionio_tpu.utils.platform import compile_report, count_compiles
+
+        count_compiles()
+        traces = len(global_tracer().snapshot(limit=1000)["recent"])
+        fn = self._fresh("with_no_span")
+        fn(np.arange(5, dtype=np.float32)).block_until_ready()
+        assert len(global_tracer().snapshot(limit=1000)["recent"]) == traces
+        (row,) = [r for r in compile_report() if r["program"] == "jit(with_no_span)"]
+        assert row["trace_s"] > 0 and row["lower_s"] > 0 and row["compile_s"] > 0
+        assert row["cache"] == "none"
+        assert row["end_s"] - row["start_s"] >= row["trace_s"] + row["lower_s"] + row["compile_s"]
+        assert abs(row["end_s"] - time.time()) < 60.0  # epoch seconds, the spans' axis
+        # a new shape is a new program: a second row, the first left as it was
+        fn(np.arange(6, dtype=np.float32)).block_until_ready()
+        again = [r for r in compile_report() if r["program"] == "jit(with_no_span)"]
+        assert len(again) == 2 and again[0] == row
+
+    def test_the_row_says_what_the_cache_said(self, tmp_path):
+        """As ``TestCompileCounters``'s child: a cache of its own, the threshold
+        at 0. Cold it compiles and writes (``miss``), warm it loads (``hit``)."""
+        env = {"JAX_ENABLE_COMPILATION_CACHE": "true",
+               "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
+        cold, warm = self._child(**env), self._child(**env)
+        assert self._probe_row(cold)["cache"] == "miss"
+        assert self._probe_row(warm)["cache"] == "hit"
+        assert ["jit.compile", {"program": "jit(timeline_probe)", "cache": "hit"}] in warm["ops"]
+        longest = {p["program"]: p["cache"] for p in warm["programs"]["longest_compiles"]}
+        assert longest["jit(timeline_probe)"] == "hit"
+
+    def test_with_the_cache_off_the_totals_are_the_sum_of_the_rows(self):
+        out = self._child(JAX_ENABLE_COMPILATION_CACHE="false")
+        assert self._probe_row(out)["cache"] == "none"
+        rows, totals = out["rows"], out["totals"]
+        assert len(rows) == totals["pio_jit_compiles_total"] == out["programs"]["count"]
+        # ``x @ x.T`` traces a helper inside the probe's trace: the totals count
+        # its seconds twice, the row holds them apart
+        assert self._probe_row(out)["nested_s"] > 0
+        summed = sum(r[c] for r in rows for c in ("trace_s", "lower_s", "compile_s", "nested_s"))
+        assert summed == pytest.approx(
+            sum(v for n, v in totals.items() if n.endswith("seconds_total")), rel=1e-9)
+        assert sum(r["compile_s"] for r in rows) == pytest.approx(
+            totals["pio_jit_compile_seconds_total"], rel=1e-9)
+        # the first span of the process (the snapshot's last: newest first),
+        # and the compiles under train.run
+        op, attrs = out["ops"][-1]
+        assert op == "backend.init"
+        assert attrs["platform"] == "cpu" and attrs["devices"] >= 1 and attrs["cache_dir"]
+        assert ["jit.trace", {"program": "timeline_probe"}] in out["ops"]
+
+    def test_tracing_off_leaves_the_totals_counting_and_records_nothing(self):
+        out = self._child(PIO_TRACING="0", JAX_ENABLE_COMPILATION_CACHE="false")
+        assert out["rows"] == [] and out["ops"] == []
+        assert out["programs"] == {"count": 0, "trace_s": 0, "lower_s": 0, "compile_s": 0,
+                                   "longest_compiles": []}
+        assert out["totals"]["pio_jit_compiles_total"] >= 1
+        assert out["totals"]["pio_jit_compile_seconds_total"] > 0
+
+    def test_300_compiles_fill_the_table_and_leave_the_ring_alone(self):
+        """A sequence cell with its reference makes hundreds of compile events
+        with no span open; the readers of ``als.pack`` and ``seq.fit`` walk the
+        ring of 128 traces after them."""
+        from jax import monitoring
+        from jax._src import dispatch
+
+        from benchmarks.layer_metrics._program import span
+        from predictionio_tpu.obs.trace import global_tracer
+        from predictionio_tpu.utils import platform
+
+        platform.count_compiles()
+        with global_tracer().span("als.pack", attrs={"edges": 300}):
+            pass
+        for k in range(300):
+            now = time.time()
+            for event in (dispatch.JAXPR_TRACE_EVENT, dispatch.JAXPR_TO_MLIR_MODULE_EVENT,
+                          dispatch.BACKEND_COMPILE_EVENT):
+                monitoring.record_scalar(event, now, fun_name=f"jit(flood_{k})")
+                monitoring.record_event_time_span(event, now, now + 0.001,
+                                                  fun_name=f"jit(flood_{k})")
+        assert span("als.pack")["attrs"] == {"edges": 300}
+        rows = platform.compile_report()
+        assert len(rows) == platform.PROGRAM_ROWS < 300
+        assert rows[-1]["program"] == "jit(flood_299)" and rows[-1]["compile_s"] > 0
+        assert rows[0]["program"] == f"jit(flood_{300 - platform.PROGRAM_ROWS})"
+
+    def test_the_public_clock_is_the_spans_own(self):
+        tracer = Tracer()
+        pc = _pc()
+        with tracer.span("clocked"):
+            pass
+        record = tracer._recent[-1][0]
+        assert trace_mod.epoch_seconds(pc) == pytest.approx(record.start_s, abs=1e-3)
+        assert trace_mod.epoch_seconds(pc) == pytest.approx(time.time(), abs=1.0)
+
+    def test_a_thread_with_no_span_records_under_nothing(self):
+        assert trace_mod.record_under_current("jit.compile", _pc(), _pc()) is False
+
+
 class TestProgramSpans:
     def test_als_pack_carries_the_packers_counts(self):
         import numpy as np
