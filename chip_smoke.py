@@ -342,7 +342,8 @@ class Smoke:
                 **{k: int(said[k]) for k in ("blocks", "blocks_chunked", "max_chunks", "blocked_solve")})
         seq = re.search(r"^.*seq_fit: (platform=.*)$", text, re.M)
         if seq:
-            said = dict(re.findall(r"(\w+)=(\S+)", seq.group(1)))
+            # a value runs to the next `` word=`` (``attention_operands`` is words)
+            said = dict(re.findall(r"(\w+)=(.*?)(?= \w+=|$)", seq.group(1)))
             facts.update(backbone=said["backbone"], steps=int(said["steps"]),
                          first_loss=float(said["first_loss"]),
                          last_loss=float(said["last_loss"]))
@@ -351,9 +352,11 @@ class Smoke:
                           for k in (SPARSE_FIT_FACTS + ATTENTION_FIT_FACTS + HYBRID_FIT_FACTS
                                     + LATENT_FIT_FACTS + WINDOW_FIT_FACTS)
                           if k in said})
-            # how the experts' rows come back, the conv's tile and the tiles of
-            # the programs that write the attention's operands: words
-            for word in ("moe_sum", "conv_block", "rope_block", "window_rope_block"):
+            # how the experts' rows come back, the conv's tile, the tiles of the
+            # programs that write the attention's operands and how the flash
+            # kernel takes its own: words
+            for word in ("moe_sum", "conv_block", "rope_block", "window_rope_block",
+                         "attention_operands"):
                 if word in said:
                     facts[word] = said[word]
         timings = re.search(r"stage timings: (.*)$", text, re.M)
@@ -696,7 +699,19 @@ class Smoke:
         first, last = facts["first_loss"], facts["last_loss"]
         if not (first == first and last == last and last < first < float("inf")):
             raise PhaseFailed(f"train_sequence_looped: loss not finite and falling: {first} -> {last}")
-        leaves = self.step_leaves("sequence_looped_leaves", algorithm, 256, LOOPED_LEAVES)
+        # heads of whole lane tiles on the chip: the flash programs take q, k, v
+        # where the projections wrote them and turn the rotary positions
+        # themselves, so the step has no ``rope`` leaf; elsewhere XLA rotates
+        on_chip = self.device["platform"] == "tpu"
+        in_programs = on_chip and widths["headDim"] % 128 == 0
+        operands = ("in place, rotated in the programs" if in_programs else
+                    "transposed" if on_chip else "plain")
+        if facts.get("attention_operands") != operands:
+            raise PhaseFailed(f"train_sequence_looped: the fit's attention_operands is not"
+                              f" {operands!r}: {facts}")
+        want = {**LOOPED_LEAVES, "attention": tuple(
+            leaf for leaf in LOOPED_LEAVES["attention"] if leaf != "rope" or not in_programs)}
+        leaves = self.step_leaves("sequence_looped_leaves", algorithm, 256, want)
         self.line("train_sequence_looped", t0, **facts, users=32, events=int(users.size),
                   leaf_scopes=len(leaves["leaves"]), **widths)
 

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from predictionio_tpu.ops import rope_layout, sparse_attention as sa
-from predictionio_tpu.ops.flash_attention import flash_attention
+from predictionio_tpu.ops.flash_attention import flash_attention, operands_in_place
 from predictionio_tpu.parallel.ring_attention import plain_attention, ring_attention
 from predictionio_tpu.parallel.ulysses import ulysses_attention
 
@@ -46,9 +46,10 @@ SCOPE_OPTIMIZER = "seq.optimizer"
 #: stays the exit's), ``qkv`` the input projections and the reshape to heads,
 #: ``rope`` the rotary positions of ``q`` and ``k`` (in the streamed backbones,
 #: where the package's programs run, the programs that write the attention's
-#: operands: ``rope_operands``), ``kernel`` the attention itself (the Pallas
-#: programs and the transposes and casts around them), ``out`` the output
-#: projection and the residual add.
+#: operands: ``rope_operands``; nothing where the flash kernel's programs turn
+#: them themselves: ``attention_operands``), ``kernel`` the attention itself
+#: (the Pallas programs and the transposes and casts around them), ``out`` the
+#: output projection and the residual add.
 SCOPE_NORM = "norm"
 SCOPE_QKV = "qkv"
 SCOPE_ROPE = "rope"
@@ -298,29 +299,52 @@ def rope_block(c, platform: str, heads: int, kv_heads: int, head_dim: int) -> st
     return f"{bt}x{lanes}"
 
 
-def attend(c, mesh, q, k, v, pad_mask):
+def attention_operands(c, platform: str, head_dim: int) -> str:
+    """How :func:`attend`'s q, k, v reach the flash kernel on ``platform`` and
+    its results leave (``ops/flash_attention.operands_in_place``, from the
+    head's width alone): as blocks of the projections' own arrays, the rotary
+    positions turned inside the programs; through ``[B, H, T, D]`` transposes
+    around them, XLA's rotation before; ``plain`` where the kernel does not
+    run. (A mesh that shares a row over a ``seq`` axis rotates by XLA before
+    its ring or Ulysses attention whatever this says.)"""
+    if not uses_kernels(c, platform):
+        return "plain"
+    return "in place, rotated in the programs" if operands_in_place(head_dim) else "transposed"
+
+
+def attend(c, mesh, q, k, v, pad_mask, rope=None):
     """Causal attention with the padded keys masked, q, k, v [B, T, H, D],
-    mesh-aware: ring or Ulysses attention (``c.seq_parallel``) when the mesh
-    has a >1 ``seq`` axis, else the Pallas flash kernel or the
-    materialized-score reference (``uses_kernels``)."""
+    mesh-aware, under the scope ``kernel``: ring or Ulysses attention
+    (``c.seq_parallel``) when the mesh has a >1 ``seq`` axis, else the Pallas
+    flash kernel or the materialized-score reference (``uses_kernels``).
+    ``rope``: the layer's ``cos, sin`` [T, D] where q and k come unrotated. The
+    flash kernel's programs turn them on heads of whole lane tiles
+    (:func:`attention_operands`); on every other path ``rotate`` does first,
+    under the scope ``rope``."""
     backend = backend_of(mesh)
     use_flash = uses_kernels(c, backend)
-    if mesh is not None and mesh.shape.get("seq", 1) > 1:
-        if c.seq_parallel == "ulysses":
-            # ulysses gathers full sequences per chip, so the flash
-            # kernel slots in as its local attention
-            return ulysses_attention(q, k, v, mesh, axis_name="seq",
-                                     causal=True, mask=pad_mask,
-                                     use_flash=use_flash)
-        # ring attention IS the online softmax across shards; its
-        # per-step scores are already [Tl, Tl] blocks, so "flash"
-        # asks for nothing it does not already do
-        return ring_attention(q, k, v, mesh, axis_name="seq",
-                              causal=True, mask=pad_mask)
-    if use_flash:
-        # O(T*D) memory: scores never materialize (ops/flash_attention)
-        return flash_attention(
-            q, k, v, pad_mask, causal=True,
-            interpret=backend != "tpu",
-        )
-    return plain_attention(q, k, v, causal=True, mask=pad_mask)
+    seq_parallel = mesh is not None and mesh.shape.get("seq", 1) > 1
+    in_programs = use_flash and not seq_parallel and operands_in_place(q.shape[-1])
+    if rope is not None and not in_programs:
+        with jax.named_scope(SCOPE_ROPE):
+            q, k, rope = rotate(q, *rope), rotate(k, *rope), None
+    with jax.named_scope(SCOPE_KERNEL):
+        if seq_parallel:
+            if c.seq_parallel == "ulysses":
+                # ulysses gathers full sequences per chip, so the flash
+                # kernel slots in as its local attention
+                return ulysses_attention(q, k, v, mesh, axis_name="seq",
+                                         causal=True, mask=pad_mask,
+                                         use_flash=use_flash)
+            # ring attention IS the online softmax across shards; its
+            # per-step scores are already [Tl, Tl] blocks, so "flash"
+            # asks for nothing it does not already do
+            return ring_attention(q, k, v, mesh, axis_name="seq",
+                                  causal=True, mask=pad_mask)
+        if use_flash:
+            # O(T*D) memory: scores never materialize (ops/flash_attention)
+            return flash_attention(
+                q, k, v, pad_mask, causal=True,
+                interpret=backend != "tpu", rope=rope,
+            )
+        return plain_attention(q, k, v, causal=True, mask=pad_mask)

@@ -38,7 +38,8 @@ with none of this):
   the backward pass (``ut_steps x L`` saved ``[B, T, D]`` states);
 - the head and loss of an exit are ``blocks.exit_ce``'s chunks of positions;
 - attention is the template's on its mesh (``blocks.attend``): ring or Ulysses
-  attention over a ``seq`` axis, else the flash kernel or the plain reference.
+  attention over a ``seq`` axis, else the flash kernel (which, at heads of whole
+  lane tiles, turns the rotary positions itself) or the plain reference.
 """
 
 from __future__ import annotations
@@ -119,7 +120,8 @@ def count_params(c: LoopedConfig) -> int:
 
 def fit_attrs(c: LoopedConfig, rows: int, platform: str) -> dict:
     """The backbone's part of the fit's span."""
-    return {**blocks.decoder_fit_attrs(c, c.num_layers, c.ut_steps), "selection_kept_bytes": 0}
+    return {**blocks.decoder_fit_attrs(c, c.num_layers, c.ut_steps), "selection_kept_bytes": 0,
+            "attention_operands": blocks.attention_operands(c, platform, c.head_dim)}
 
 
 # ---- the block -----------------------------------------------------------
@@ -134,10 +136,7 @@ def _layer(c: LoopedConfig, mesh, rope, pad_mask, h, p):
         with jax.named_scope(blocks.SCOPE_QKV):
             q, k, v = (blocks.matmul(z, p[w], dtype).reshape(b, t, c.num_heads, c.head_dim)
                        for w in ("wq", "wk", "wv"))
-        with jax.named_scope(blocks.SCOPE_ROPE):
-            q, k = blocks.rotate(q, *rope), blocks.rotate(k, *rope)
-        with jax.named_scope(blocks.SCOPE_KERNEL):
-            out = blocks.attend(c, mesh, q, k, v, pad_mask).reshape(b, t, -1)
+        out = blocks.attend(c, mesh, q, k, v, pad_mask, rope).reshape(b, t, -1)
         with jax.named_scope(blocks.SCOPE_OUT):
             out = blocks.matmul(out, p["wo"], dtype)
         with jax.named_scope(blocks.SCOPE_NORM):

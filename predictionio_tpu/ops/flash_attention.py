@@ -15,7 +15,9 @@ shard, this kernel keeps the memory footprint O(T * D) so per-chip
 sequences can grow until HBM, not VMEM-score-matrix, is the limit.
 
 Shapes follow plain_attention: q, k, v [B, T, H, D]; optional ``mask``
-[B, T]; causal masking over absolute positions. On CPU test backends the
+[B, T]; causal masking over absolute positions; optional ``rope``, the
+rotary table where the programs are to turn q and k themselves (its contract
+is below). On CPU test backends the
 kernels run in interpret mode (tests pin fwd+grad against plain_attention
 at the valid positions).
 
@@ -64,9 +66,19 @@ Real-hardware layout constraints (learned the hard way -- interpret mode
 checks none of this):
 
 - Blocks must keep their last two dims (8, 128)-divisible or equal to the
-  array dims. The public [B, T, H, D] layout blocks as (1, BQ, 1, D) with
-  a second-minor 1 != H, so tensors transpose to [B, H, T, D] at the
-  pallas boundary and blocks become (1, 1, BQ, D).
+  array dims. A four-dimensional block (1, BQ, 1, D) of the public
+  [B, T, H, D] layout is refused: its second-minor 1 is neither. Read as
+  [B, T, H x D] (a reshape, no copy) the same array blocks as
+  (1, BQ, hb x D) at lane block ``g``, which is legal wherever
+  ``D % 128 == 0``, and a head is then a static lane slice of the block.
+  That is the shape rule (:func:`operands_in_place`), from the head's width
+  alone: heads of whole lane tiles are read and written **in place**, as
+  blocks of the arrays the projections wrote and the backward products read
+  (q, k, v, ``do`` in; the output, ``dq``, ``dk``, ``dv`` out; XLA moves
+  nothing); any other width (the ``sasrec`` backbone's heads of 16)
+  transposes to [B, H, T, D] at the pallas boundary and blocks as
+  (1, hb, BQ, D). The kernels' bodies are written once: :func:`_head` is
+  where a head lies in either block.
 - Row operands (mask, lse, delta) carry a singleton middle axis --
   [B, 1, T] / [B*H, 1, T] -- so their (1, T)-shaped blocks match the
   array's own last-two dims.
@@ -79,6 +91,21 @@ checks none of this):
   ("dynamic load with unaligned indices"): all row selection lives in the
   BlockSpec index maps (per-program DMA), and in-kernel dynamic slices are
   lane/sublane slices at 128-multiple offsets only.
+
+The contract of ``rope``. ``rope=(cos, sin)``, each [T, D] float32 in the
+rotate-half convention over the whole head (``blocks.rope_tables``), says
+that q and k come **unrotated** and the programs turn them, in VMEM, on the
+blocks they hold anyway; only with the in-place blocks (``D % 128 == 0``;
+anything else is refused, and the caller rotates first). The table is two
+more operands, held whole ([T, D] padded with the rows; ``sin`` carries its
+partner's sign, :func:`_signed_sin`), and the half-turn a lane roll by
+``D / 2``. Forward and ``dq`` turn their query block once a program and a
+key block as it is read; ``dkv`` its key block once and a query block as it
+is read; ``dq`` and ``dk`` are turned back (the rotation's transpose) once,
+before they are stored, so the gradients that leave are with respect to the
+unrotated q and k, as ``rotate``'s own transpose gives. All of it float32:
+the products and sums of ``rope_layout.rotate``. The table is a function of
+the positions alone and gets no cotangent. ``rope=None``: nothing is turned.
 """
 
 from __future__ import annotations
@@ -158,30 +185,74 @@ def _key_blocks(qi, first, last, causal: bool):
     return first, jnp.where((qi >= first) & (qi <= last), hi + 1, first)
 
 
+def operands_in_place(head_dim: int) -> bool:
+    """The shape rule: heads that are whole lane tiles are read and written as
+    blocks of the projections' own ``[B, T, H x D]`` arrays; any other width
+    goes through ``[B, H, T, D]`` (the module docstring has why)."""
+    return head_dim % 128 == 0
+
+
+def _head(ref, hb: int, hh: int, rows=slice(None)):
+    """Where head ``hh``'s ``rows`` lie in a block of ``hb`` heads, to load or
+    to store ``[rows, D]``: a plane of the heads-first block ``[1, HB, T, D]``,
+    a lane slice of the in-place block ``[1, T, HB x D]``."""
+    if len(ref.shape) == 4:
+        return (0, hh, rows, slice(None))
+    d = ref.shape[2] // hb
+    return (0, rows, slice(hh * d, (hh + 1) * d))
+
+
+def _head_shape(ref, hb: int) -> tuple[int, int]:
+    """``(rows, D)`` of one head of a block, in either layout."""
+    if len(ref.shape) == 4:
+        return ref.shape[2], ref.shape[3]
+    return ref.shape[1], ref.shape[2] // hb
+
+
+def _rope(table, rows):
+    """``(turn, back)`` for ``[rows, D]`` float32 blocks at the positions
+    ``rows``: the rotary positions and their transpose, from the table's two
+    refs ``[T, D]`` (``cos``, and ``sin`` with its partner's sign:
+    :func:`_signed_sin`). The half-turn is a lane roll by ``D / 2``, which
+    for a whole head is the same lanes either way. No table: both are the
+    identity."""
+    if not table:
+        return (lambda x: x), (lambda g: g)
+    cos, sin = (ref[rows, :] for ref in table)
+    half = cos.shape[1] // 2
+    turn = lambda x: x * cos + pltpu.roll(x, half, 1) * sin      # noqa: E731
+    back = lambda g: g * cos + pltpu.roll(g * sin, half, 1)      # noqa: E731
+    return turn, back
+
+
 def _fwd_kernel(
     first_ref,  # [B] SMEM: the row's first block with a valid position
     last_ref,   # [B] SMEM: its last
-    q_ref,      # [1, HB, BQ, D]
-    k_ref,      # [1, HB, T, D]
-    v_ref,      # [1, HB, T, D]
+    q_ref,      # [1, HB, BQ, D], or in place [1, BQ, HB x D]
+    k_ref,      # [1, HB, T, D], or in place [1, T, HB x D]
+    v_ref,      # as k_ref
     mask_ref,   # [1, 1, T]
     qmask_ref,  # [1, BQ, 1]
-    out_ref,    # [1, HB, BQ, D]
-    lse_ref,    # [HB, 1, BQ]
-    *, causal: bool, sm_scale: float, block_k: int,
+    *rest,      # with ``rope``: cos_ref, sin_ref [T, D]; then the outputs:
+                # out_ref as q_ref, lse_ref [HB, 1, BQ]
+    causal: bool, sm_scale: float, block_k: int,
 ):
+    *table, out_ref, lse_ref = rest
     b, qi = pl.program_id(0), pl.program_id(2)
-    hb, bq, d = q_ref.shape[1:]
+    hb = lse_ref.shape[0]
+    bq, d = _head_shape(q_ref, hb)
     q_valid = qmask_ref[0, :, :]
-    qs = [q_ref[0, hh, :, :].astype(jnp.float32) for hh in range(hb)]
+    turn_q, _ = _rope(table, pl.ds(qi * bq, bq))
+    qs = [turn_q(q_ref[_head(q_ref, hb, hh)].astype(jnp.float32)) for hh in range(hb)]
 
     def body(kb, carry):
         keys = pl.ds(kb * block_k, block_k)
         valid = _valid(q_valid, qi * bq, mask_ref[0, :, keys], kb * block_k, causal)
+        turn_k, _ = _rope(table, keys)
         out = []
         for hh, (acc, m, l) in enumerate(carry):
-            k_blk = k_ref[0, hh, keys, :].astype(jnp.float32)
-            v_blk = v_ref[0, hh, keys, :].astype(jnp.float32)
+            k_blk = turn_k(k_ref[_head(k_ref, hb, hh, keys)].astype(jnp.float32))
+            v_blk = v_ref[_head(v_ref, hb, hh, keys)].astype(jnp.float32)
             s = jnp.where(valid, _dot(qs[hh], k_blk, 1, 1) * sm_scale, _NEG)
             m_new = jnp.maximum(m, s.max(axis=1))
             p = jnp.exp(s - m_new[:, None]) * valid             # [BQ, BK]
@@ -196,7 +267,7 @@ def _fwd_kernel(
     lo, hi = _key_blocks(qi, first_ref[b], last_ref[b], causal)
     for hh, (acc, m, l) in enumerate(
             jax.lax.fori_loop(lo, hi, body, (init,) * hb)):
-        out_ref[0, hh, :, :] = (
+        out_ref[_head(out_ref, hb, hh)] = (
             acc / jnp.maximum(l, 1e-20)[:, None]).astype(out_ref.dtype)
         lse_ref[hh, 0, :] = m + jnp.log(jnp.maximum(l, 1e-20))
 
@@ -204,16 +275,20 @@ def _fwd_kernel(
 def _dq_kernel(
     first_ref, last_ref,
     q_ref, k_ref, v_ref, mask_ref, qmask_ref, do_ref, lse_ref, delta_ref,
-    dq_ref,
-    *, causal: bool, sm_scale: float, block_k: int,
+    *rest,      # with ``rope``: cos_ref, sin_ref; then dq_ref as q_ref
+    causal: bool, sm_scale: float, block_k: int,
 ):
-    """dQ for one query block: dq = sum_kb (P o (dP - delta)) K * scale."""
+    """dQ for one query block: dq = sum_kb (P o (dP - delta)) K * scale, with
+    ``rope`` against the rotated q and K and turned back before it is stored."""
+    *table, dq_ref = rest
     b, qi = pl.program_id(0), pl.program_id(2)
-    hb, bq, d = q_ref.shape[1:]
+    hb = lse_ref.shape[0]
+    bq, d = _head_shape(q_ref, hb)
     q_valid = qmask_ref[0, :, :]
+    turn_q, back_q = _rope(table, pl.ds(qi * bq, bq))
     heads = [
-        (q_ref[0, hh, :, :].astype(jnp.float32),
-         do_ref[0, hh, :, :].astype(jnp.float32),
+        (turn_q(q_ref[_head(q_ref, hb, hh)].astype(jnp.float32)),
+         do_ref[_head(do_ref, hb, hh)].astype(jnp.float32),
          lse_ref[hh, 0, :][:, None], delta_ref[hh, 0, :][:, None])
         for hh in range(hb)
     ]
@@ -221,10 +296,11 @@ def _dq_kernel(
     def body(kb, dqs):
         keys = pl.ds(kb * block_k, block_k)
         valid = _valid(q_valid, qi * bq, mask_ref[0, :, keys], kb * block_k, causal)
+        turn_k, _ = _rope(table, keys)
         out = []
         for hh, (q, do, lse, delta) in enumerate(heads):
-            k_blk = k_ref[0, hh, keys, :].astype(jnp.float32)
-            v_blk = v_ref[0, hh, keys, :].astype(jnp.float32)
+            k_blk = turn_k(k_ref[_head(k_ref, hb, hh, keys)].astype(jnp.float32))
+            v_blk = v_ref[_head(v_ref, hb, hh, keys)].astype(jnp.float32)
             s = _dot(q, k_blk, 1, 1) * sm_scale
             p = jnp.where(valid, jnp.exp(s - lse), 0.0)
             ds = p * (_dot(do, v_blk, 1, 1) - delta) * sm_scale
@@ -235,31 +311,36 @@ def _dq_kernel(
     dqs = jax.lax.fori_loop(
         lo, hi, body, (jnp.zeros((bq, d), jnp.float32),) * hb)
     for hh, dq in enumerate(dqs):
-        dq_ref[0, hh, :, :] = dq.astype(dq_ref.dtype)
+        dq_ref[_head(dq_ref, hb, hh)] = back_q(dq).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(
     first_ref, last_ref,
     q_ref, k_ref, v_ref, mask_ref, qmask_ref, do_ref, lse_ref, delta_ref,
-    dk_ref, dv_ref,
-    *, causal: bool, sm_scale: float, block_q: int,
+    *rest,      # with ``rope``: cos_ref, sin_ref; then dk_ref, dv_ref as k_ref
+    causal: bool, sm_scale: float, block_q: int,
 ):
     """dK/dV for one key block: loop over the query blocks that can hold a
-    counting pair with it."""
+    counting pair with it; with ``rope`` against the rotated K and queries,
+    dK turned back before it is stored."""
+    *table, dk_ref, dv_ref = rest
     b, ki = pl.program_id(0), pl.program_id(2)
     first, last = first_ref[b], last_ref[b]
-    hb, bk, d = k_ref.shape[1:]
+    hb = lse_ref.shape[0]
+    bk, d = _head_shape(k_ref, hb)
     k_valid = mask_ref[0, :, pl.ds(ki * bk, bk)]
-    heads = [(k_ref[0, hh, :, :].astype(jnp.float32),
-              v_ref[0, hh, :, :].astype(jnp.float32)) for hh in range(hb)]
+    turn_k, back_k = _rope(table, pl.ds(ki * bk, bk))
+    heads = [(turn_k(k_ref[_head(k_ref, hb, hh)].astype(jnp.float32)),
+              v_ref[_head(v_ref, hb, hh)].astype(jnp.float32)) for hh in range(hb)]
 
     def body(qb, carry):
         rows = pl.ds(qb * block_q, block_q)
         valid = _valid(qmask_ref[0, rows, :], qb * block_q, k_valid, ki * bk, causal)
+        turn_q, _ = _rope(table, rows)
         out = []
         for hh, ((k_blk, v_blk), (dk, dv)) in enumerate(zip(heads, carry)):
-            q = q_ref[0, hh, rows, :].astype(jnp.float32)
-            do = do_ref[0, hh, rows, :].astype(jnp.float32)
+            q = turn_q(q_ref[_head(q_ref, hb, hh, rows)].astype(jnp.float32))
+            do = do_ref[_head(do_ref, hb, hh, rows)].astype(jnp.float32)
             lse = lse_ref[hh, 0, rows][:, None]
             delta = delta_ref[hh, 0, rows][:, None]
             s = _dot(q, k_blk, 1, 1) * sm_scale
@@ -275,16 +356,16 @@ def _dkv_kernel(
     hi = jnp.where((ki >= first) & (ki <= last), last + 1, lo)
     for hh, (dk, dv) in enumerate(
             jax.lax.fori_loop(lo, hi, body, ((zero, zero),) * hb)):
-        dk_ref[0, hh, :, :] = dk.astype(dk_ref.dtype)
-        dv_ref[0, hh, :, :] = dv.astype(dv_ref.dtype)
+        dk_ref[_head(dk_ref, hb, hh)] = back_k(dk).astype(dk_ref.dtype)
+        dv_ref[_head(dv_ref, hb, hh)] = dv.astype(dv_ref.dtype)
 
 
-def _pad_t(x, t_padded):
-    pad = t_padded - x.shape[1]
+def _pad_t(x, t_padded, axis=1):
+    pad = t_padded - x.shape[axis]
     if pad == 0:
         return x
     widths = [(0, 0)] * x.ndim
-    widths[1] = (0, pad)
+    widths[axis] = (0, pad)
     return jnp.pad(x, widths)
 
 
@@ -310,20 +391,29 @@ def _specs(t, h_dim, d, bq, hb):
     """(index-mapped) block specs shared by the three kernels; the grid is
     (B, H // hb, T // bq), ``hb`` heads a program.
 
-    Device tensors are [B, H, T, D]; row operands are [B, 1, T] (mask) and
-    [B*H, 1, T] (lse/delta), the query side's validity a column [B, T, 1];
-    all row selection is in the index maps, which also receive (and ignore)
-    the two scalar-prefetch operands.
+    Device tensors are [B, T, H x D] where the heads are whole lane tiles
+    (:func:`operands_in_place`: a program's heads are a lane block) and
+    [B, H, T, D] elsewhere; row operands are [B, 1, T] (mask) and [B*H, 1, T]
+    (lse/delta), the query side's validity a column [B, T, 1], the rotary
+    table [T, D], held whole; all row selection is in the index maps, which
+    also receive (and ignore) the two scalar-prefetch operands.
     """
     groups = h_dim // hb
     spec = lambda block, index: pl.BlockSpec(
         block, lambda b, g, i, *_: index(b, g, i))
+    if operands_in_place(d):
+        blk = spec((1, bq, hb * d), lambda b, g, i: (b, i, g))
+        full = spec((1, t, hb * d), lambda b, g, i: (b, 0, g))
+    else:
+        blk = spec((1, hb, bq, d), lambda b, g, i: (b, g, i, 0))
+        full = spec((1, hb, t, d), lambda b, g, i: (b, g, 0, 0))
     return {
-        "blk": spec((1, hb, bq, d), lambda b, g, i: (b, g, i, 0)),
-        "full": spec((1, hb, t, d), lambda b, g, i: (b, g, 0, 0)),
+        "blk": blk,
+        "full": full,
         "mask": spec((1, 1, t), lambda b, g, i: (b, 0, 0)),
         "qmask_blk": spec((1, bq, 1), lambda b, g, i: (b, i, 0)),
         "qmask_full": spec((1, t, 1), lambda b, g, i: (b, 0, 0)),
+        "table": spec((t, d), lambda b, g, i: (0, 0)),
         #: one query block of these heads' lse/delta rows
         "row_blk": spec((hb, 1, bq), lambda b, g, i: (b * groups + g, 0, i)),
         #: their full lse/delta rows (dkv loops over the query blocks)
@@ -331,12 +421,14 @@ def _specs(t, h_dim, d, bq, hb):
     }
 
 
-def _plan(q, mask, sm_scale):
-    """What the three calls of one attention share, from q [B, T, H, D] and
-    ``mask`` [B, T] (None: every position): the score scale, T padded to
-    whole blocks, the operands taken of the mask (the two block bounds, the
-    key side's row form, the query side's column form, padding invalid), the
-    block specs and the grid (BLOCK_Q == BLOCK_K: one grid for all three)."""
+def _plan(q, mask, sm_scale, rope):
+    """What the three calls of one attention share, from q [B, T, H, D],
+    ``mask`` [B, T] (None: every position) and ``rope`` (None: no rotation):
+    the score scale, T padded to whole blocks, the operands taken of the mask
+    (the two block bounds, the key side's row form, the query side's column
+    form, padding invalid), the rotary table's two operands (none without
+    ``rope``), the block specs and the grid (BLOCK_Q == BLOCK_K: one grid for
+    all three)."""
     b, t, h, d = q.shape
     scale = sm_scale if sm_scale is not None else d**-0.5
     t_padded = -(-t // BLOCK_Q) * BLOCK_Q
@@ -346,7 +438,22 @@ def _plan(q, mask, sm_scale):
     masks = (*_block_bounds(maskp, BLOCK_Q), maskp[:, None, :], maskp[:, :, None])
     hb = _heads_per_program(h, t_padded, d, q.dtype.itemsize, BLOCK_Q)
     specs = _specs(t_padded, h, d, BLOCK_Q, hb)
-    return scale, t_padded, masks, specs, (b, h // hb, t_padded // BLOCK_Q)
+    table = ()
+    if rope is not None:
+        if not operands_in_place(d) or rope[0].shape != (t, d):
+            raise ValueError(
+                f"rope: tables of {rope[0].shape} on heads of {d}: the programs rotate a"
+                f" whole head of whole lane tiles by tables [T, D] = {(t, d)}")
+        table = tuple(_pad_t(x.astype(jnp.float32), t_padded, axis=0)
+                      for x in (rope[0], _signed_sin(rope[1])))
+    return scale, t_padded, masks, table, specs, (b, h // hb, t_padded // BLOCK_Q)
+
+
+def _signed_sin(sin):
+    """``sin`` [T, D] with the sign of a lane's partner in the half-turn: lane
+    ``j < D / 2`` takes ``-x[j + D / 2]``, the others ``x[j - D / 2]``."""
+    d = sin.shape[1]
+    return jnp.where(jnp.arange(d) < d // 2, -sin, sin)
 
 
 def _call(kernel, grid, in_specs, out_specs, out_shape, interpret):
@@ -365,8 +472,33 @@ def _to_bhtd(x):
     return jnp.transpose(x, (0, 2, 1, 3))
 
 
+def _laid(x, t_padded):
+    """An operand [B, T, H, D] as the programs read it, T padded to whole
+    blocks: [B, T, H x D], the array as it lies, where the heads are whole
+    lane tiles; else transposed to [B, H, T, D]."""
+    b, _, h, d = x.shape
+    x = _pad_t(x, t_padded)
+    return x.reshape(b, t_padded, h * d) if operands_in_place(d) else _to_bhtd(x)
+
+
+def _laid_struct(shape, t_padded, like):
+    """The programs' result for [B, T, H, D] = ``shape`` in :func:`_laid`'s
+    layout."""
+    b, _, h, d = shape
+    laid = (b, t_padded, h * d) if operands_in_place(d) else (b, h, t_padded, d)
+    return _struct(laid, like.dtype, like)
+
+
+def _unlaid(x, shape):
+    """A result of the programs back as [B, T, H, D] = ``shape``."""
+    b, t, h, d = shape
+    if operands_in_place(d):
+        return x[:, :t].reshape(shape)
+    return _to_bhtd(x)[:, :t]
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def flash_attention(q, k, v, mask, causal=True, sm_scale=None, interpret=False):
+def flash_attention(q, k, v, mask, causal=True, sm_scale=None, interpret=False, rope=None):
     """Flash attention. q,k,v [B, T, H, D] -> [B, T, H, D].
 
     ``mask``: [B, T], the positions of each row that hold something, or
@@ -375,29 +507,13 @@ def flash_attention(q, k, v, mask, causal=True, sm_scale=None, interpret=False):
     docstring has the contract), where ``plain_attention`` would return an
     average -- such positions are padding and must be loss-masked by the
     caller either way.
+
+    ``rope``: ``(cos, sin)`` of [T, D], or None. With it ``q`` and ``k`` come
+    unrotated and the programs turn them (the module docstring has the
+    contract); only where ``D % 128 == 0``.
     """
-    out, _ = _flash_fwd(q, k, v, mask, causal, sm_scale, interpret)
+    out, _ = _flash_fwd(q, k, v, mask, causal, sm_scale, interpret, rope)
     return out
-
-
-def _flash_forward(q, k, v, mask, causal, sm_scale, interpret):
-    b, t, h, d = q.shape
-    scale, t_padded, (first, last, maskp, qmaskp), sp, grid = _plan(q, mask, sm_scale)
-    qp, kp, vp = (_pad_t(x, t_padded) for x in (q, k, v))
-    out, lse = _call(
-        functools.partial(
-            _fwd_kernel, causal=causal, sm_scale=scale, block_k=BLOCK_K
-        ),
-        grid,
-        [sp["blk"], sp["full"], sp["full"], sp["mask"], sp["qmask_blk"]],
-        [sp["blk"], sp["row_blk"]],
-        [
-            _struct((b, h, t_padded, d), q.dtype, q),
-            _struct((b * h, 1, t_padded), jnp.float32, q),
-        ],
-        interpret,
-    )(first, last, _to_bhtd(qp), _to_bhtd(kp), _to_bhtd(vp), maskp, qmaskp)
-    return _to_bhtd(out)[:, :t], lse
 
 
 def _struct(shape, dtype, like):
@@ -408,15 +524,33 @@ def _struct(shape, dtype, like):
     return shape_struct(shape, dtype, like)
 
 
-def _flash_fwd(q, k, v, mask, causal, sm_scale, interpret):
-    out, lse = _flash_forward(q, k, v, mask, causal, sm_scale, interpret)
-    return out, (q, k, v, mask, out, lse)
+def _flash_fwd(q, k, v, mask, causal, sm_scale, interpret, rope):
+    scale, t_padded, (first, last, maskp, qmaskp), table, sp, grid = _plan(
+        q, mask, sm_scale, rope)
+    out, lse = _call(
+        functools.partial(
+            _fwd_kernel, causal=causal, sm_scale=scale, block_k=BLOCK_K
+        ),
+        grid,
+        [sp["blk"], sp["full"], sp["full"], sp["mask"], sp["qmask_blk"],
+         *[sp["table"]] * len(table)],
+        [sp["blk"], sp["row_blk"]],
+        [
+            _laid_struct(q.shape, t_padded, q),
+            _struct((q.shape[0] * q.shape[2], 1, t_padded), jnp.float32, q),
+        ],
+        interpret,
+    )(first, last, *(_laid(x, t_padded) for x in (q, k, v)), maskp, qmaskp, *table)
+    out = _unlaid(out, q.shape)
+    return out, (q, k, v, mask, rope, out, lse)
 
 
 def _flash_bwd(causal, sm_scale, interpret, res, g):
-    q, k, v, mask, out, lse = res
+    q, k, v, mask, rope, out, lse = res
     b, t, h, d = q.shape
-    scale, t_padded, (first, last, maskp, qmaskp), sp, grid = _plan(q, mask, sm_scale)
+    scale, t_padded, (first, last, maskp, qmaskp), table, sp, grid = _plan(
+        q, mask, sm_scale, rope)
+    table_specs = [sp["table"]] * len(table)
     mask_grad = (
         None if mask is None else np.zeros(mask.shape, jax.dtypes.float0)
     )
@@ -424,45 +558,37 @@ def _flash_bwd(causal, sm_scale, interpret, res, g):
     # delta[b,h,i] = rowsum(dO o O): the softmax-jacobian correction term
     delta = jnp.einsum("bthd,bthd->bht", g.astype(jnp.float32),
                        out.astype(jnp.float32)).reshape(b * h, 1, t)
-
-    qp, kp, vp, gp = (_pad_t(x, t_padded) for x in (q, k, v, g))
     lsep = lse  # already t_padded long: it never left the padded domain
     deltap = jnp.pad(delta, ((0, 0), (0, 0), (0, t_padded - t)))
 
-    qt, kt, vt, gt = (_to_bhtd(x) for x in (qp, kp, vp, gp))
+    operands = (first, last, *(_laid(x, t_padded) for x in (q, k, v)), maskp, qmaskp,
+                _laid(g, t_padded), lsep, deltap, *table)
     dq = _call(
         functools.partial(_dq_kernel, causal=causal, sm_scale=scale, block_k=BLOCK_K),
         grid,
         [
             sp["blk"], sp["full"], sp["full"], sp["mask"], sp["qmask_blk"],
-            sp["blk"], sp["row_blk"], sp["row_blk"],
+            sp["blk"], sp["row_blk"], sp["row_blk"], *table_specs,
         ],
         sp["blk"],
-        _struct((b, h, t_padded, d), q.dtype, q),
+        _laid_struct(q.shape, t_padded, q),
         interpret,
-    )(first, last, qt, kt, vt, maskp, qmaskp, gt, lsep, deltap)
+    )(*operands)
 
     dk, dv = _call(
         functools.partial(_dkv_kernel, causal=causal, sm_scale=scale, block_q=BLOCK_Q),
         grid,
         [
             sp["full"], sp["blk"], sp["blk"], sp["mask"], sp["qmask_full"],
-            sp["full"], sp["row_full"], sp["row_full"],
+            sp["full"], sp["row_full"], sp["row_full"], *table_specs,
         ],
         [sp["blk"], sp["blk"]],
-        [
-            _struct((b, h, t_padded, d), k.dtype, k),
-            _struct((b, h, t_padded, d), v.dtype, v),
-        ],
+        [_laid_struct(k.shape, t_padded, k), _laid_struct(v.shape, t_padded, v)],
         interpret,
-    )(first, last, qt, kt, vt, maskp, qmaskp, gt, lsep, deltap)
+    )(*operands)
 
-    return (
-        _to_bhtd(dq)[:, :t],
-        _to_bhtd(dk)[:, :t],
-        _to_bhtd(dv)[:, :t],
-        mask_grad,
-    )
+    # the table is a function of the positions alone: no cotangent
+    return (*(_unlaid(x, q.shape) for x in (dq, dk, dv)), mask_grad, None)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)

@@ -51,6 +51,7 @@ def test_rehearsal_reaches_every_phase_then_refuses_the_cpu(tmp_path):
     assert all(r["agrees"] for r in by_phase["als_full_width"]["runs"])
     looped = by_phase["train_sequence_looped"]
     assert looped["backbone"] == "looped" and looped["last_loss"] < looped["first_loss"]
+    assert looped["attention_operands"] == "plain"   # no chip: XLA rotates, under ``rope``
     sparse = by_phase["train_sequence_sparse_moe"]
     assert sparse["backbone"] == "sparse_moe" and sparse["last_loss"] < sparse["first_loss"]
     assert (sparse["experts_held"], sparse["experts_total"], sparse["moe_dropped"]) == (4, 16, 0)

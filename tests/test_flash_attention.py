@@ -1,13 +1,18 @@
 """Flash-attention kernel parity tests (interpret mode on the CPU backend)."""
 
+import functools
+import importlib
+from unittest import mock
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from predictionio_tpu.ops.flash_attention import (
-    BLOCK_Q, flash_attention, tiles_worked,
+    BLOCK_Q, flash_attention, operands_in_place, tiles_worked,
 )
+from predictionio_tpu.ops.rope_layout import rotate
 from predictionio_tpu.parallel.ring_attention import plain_attention
 
 
@@ -283,3 +288,136 @@ class TestHeadsAProgram:
         assert _heads_per_program(4, 2048, 64, 4, BLOCK_Q) == 1
         assert _heads_per_program(16, 256, 128, 4, BLOCK_Q) == 4
         assert _heads_per_program(2, 512, 32, 4, BLOCK_Q) == 2
+
+
+# -- blocks of the projections' own arrays, the rotation inside the programs ----
+
+FLASH = importlib.import_module("predictionio_tpu.ops.flash_attention")
+RESULTS = ("out", "lse", "dq", "dk", "dv")
+
+
+def _rows(case: str) -> np.ndarray:
+    """Two rows' validity [2, T]: a padding mask (events first, the second
+    block of one row empty), a mask with a hole, a ``T`` that is no multiple of
+    the block."""
+    t = 200 if case == "ragged_t" else 256
+    first = {"padding": "events_first_short", "hole": "hole", "ragged_t": "events_first"}[case]
+    return np.stack([_layout(first, 256)[:t], _layout("full", 256)[:t]])
+
+
+def _operands(case: str, d: int):
+    """``(q, k, v, w)`` [2, T, 2, d] and the case's mask."""
+    valid = _rows(case)
+    rng = np.random.default_rng(len(case) + d)
+    return (*(jnp.asarray(rng.normal(size=(*valid.shape, 2, d)), jnp.float32)
+              for _ in range(4)), jnp.asarray(valid))
+
+
+@functools.lru_cache(maxsize=None)
+def _everything(transposed: bool = False):
+    """``(q, k, v, w, mask, rope) -> (out, lse, dq, dk, dv)`` of one causal
+    attention with the cotangent ``w``, one program for the three calls (a
+    case of the same shapes compiles nothing); ``transposed``: through
+    [B, H, T, D] whatever the heads' width, the rule patched out here."""
+    def run(q, k, v, w, mask, rope=None):
+        rule = (lambda head_dim: False) if transposed else FLASH.operands_in_place
+        with mock.patch.object(FLASH, "operands_in_place", rule):
+            out, res = FLASH._flash_fwd(q, k, v, mask, True, None, True, rope)
+            return (out, res[-1], *FLASH._flash_bwd(True, None, True, res, w)[:3])
+
+    return jax.jit(run)
+
+
+def _program_operand_ranks(fn, *args) -> set:
+    """The ranks of the q-sized float32 operands of the Pallas programs in
+    ``fn``: 3 in place, 4 transposed."""
+    def programs(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from programs(sub)
+
+    found = list(programs(jax.make_jaxpr(fn)(*args).jaxpr))
+    assert len(found) == 3
+    return {len(v.aval.shape) for eqn in found for v in eqn.invars
+            if v.aval.dtype == jnp.float32 and v.aval.size >= args[0].size}
+
+
+def _assert_close_at_valid(got, want, mask, atol):
+    """The kernel's ``RESULTS`` against another attention's at the valid
+    positions (the logsumexp where the other has one); the kernel's exactly 0
+    at the others."""
+    valid = np.asarray(mask)
+    for mine, other, name in zip(got, want, RESULTS):
+        if name == "lse":
+            if other is not None:
+                np.testing.assert_allclose(mine, other, atol=atol, err_msg=name)
+            continue
+        mine, other = np.asarray(mine), np.asarray(other)
+        np.testing.assert_allclose(mine[valid], other[valid], atol=atol, err_msg=name)
+        assert not mine[~valid].any(), f"{name} at an invalid position"
+
+
+@functools.lru_cache(maxsize=None)
+def _plain_everything():
+    """``(q, k, v, w, mask) -> (out, None, dq, dk, dv)`` of ``plain_attention``,
+    the cotangent stopped at the invalid positions."""
+    def run(q, k, v, w, mask):
+        out, vjp = jax.vjp(lambda q, k, v: plain_attention(q, k, v, causal=True, mask=mask),
+                           q, k, v)
+        return (out, None, *vjp(w * mask[:, :, None, None]))
+
+    return jax.jit(run)
+
+
+class TestOperandsInPlace:
+    """Heads of whole lane tiles are blocks of [B, T, H x D] as the projections
+    wrote it; any other width goes through [B, H, T, D]: the width alone says."""
+
+    @pytest.mark.parametrize("d", [128, 16])
+    def test_the_heads_width_alone_chooses_the_blocks(self, d):
+        q, k, v, w, mask = _operands("ragged_t", d)
+        assert operands_in_place(d) == (d == 128)
+        assert _program_operand_ranks(_everything(), q, k, v, w, mask) == (
+            {3} if d == 128 else {4})
+        assert _program_operand_ranks(_everything(transposed=True), q, k, v, w, mask) == {4}
+
+    @pytest.mark.parametrize("case", ["padding", "hole", "ragged_t"])
+    @pytest.mark.parametrize("d", [128, 16])
+    def test_either_layout_gives_the_plain_results(self, d, case):
+        """At 128 the in-place blocks give what the transposed blocks give on
+        the same inputs, to the bit (the same arithmetic, other addresses); at
+        either width what ``plain_attention`` gives at the valid positions."""
+        q, k, v, w, mask = _operands(case, d)
+        got = _everything()(q, k, v, w, mask)
+        if d == 128:
+            for mine, other, name in zip(
+                    got, _everything(transposed=True)(q, k, v, w, mask), RESULTS):
+                np.testing.assert_array_equal(mine, other, err_msg=name)
+        _assert_close_at_valid(got, _plain_everything()(q, k, v, w, mask), mask, 1e-4)
+
+    @pytest.mark.parametrize("case", ["padding", "hole", "ragged_t"])
+    def test_the_programs_rotate_as_rotate_does(self, case):
+        """``rope=``: the output, and the gradients with respect to the
+        unrotated q and k, of ``blocks.rotate`` followed by the kernel without
+        it, and of ``plain_attention`` at the valid positions."""
+        from predictionio_tpu.models.sequence.blocks import rope_tables
+
+        q, k, v, w, mask = _operands(case, 128)
+        rope = rope_tables(q.shape[1], 128, 1e4)
+        got = _everything()(q, k, v, w, mask, rope)
+        (rq, rk), unrotated = jax.vjp(lambda q, k: (rotate(q, *rope), rotate(k, *rope)), q, k)
+        for attention, atol in ((_everything(), 2e-5), (_plain_everything(), 1e-4)):
+            out, lse, dq, dk, dv = attention(rq, rk, v, w, mask)
+            _assert_close_at_valid(got, (out, lse, *unrotated((dq, dk)), dv), mask, atol)
+
+    def test_a_table_the_programs_cannot_turn_by_is_refused(self):
+        from predictionio_tpu.models.sequence.blocks import rope_tables
+
+        q, k, v, _ = _inputs(b=1, t=40, h=2, d=16, masked=False)
+        with pytest.raises(ValueError, match="rope"):
+            flash_attention(q, k, v, None, interpret=True, rope=rope_tables(40, 16, 1e4))
+        q, k, v, _ = _inputs(b=1, t=40, h=1, d=128, masked=False)
+        with pytest.raises(ValueError, match="rope"):
+            flash_attention(q, k, v, None, interpret=True, rope=rope_tables(40, 64, 1e4))

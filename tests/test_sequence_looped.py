@@ -220,6 +220,63 @@ def test_flash_over_two_blocks_gives_every_plain_gradient(two_block_rows, name):
     assert _rel(got, want) < 1e-4
 
 
+@pytest.fixture(scope="module")
+def whole_lane_heads():
+    """Two heads of 128, the width at which the flash kernel reads q, k, v as
+    blocks of the projections' arrays and turns the rotary positions itself
+    (``blocks.attention_operands``): ragged rows of 160 slots, two of the
+    kernel's blocks once padded. The looped loss and its gradients under both
+    attentions, and the leaf scopes of both steps."""
+    import re
+
+    params = seeded_histories.make_params(
+        seeded_histories.param_shapes(VOCAB, 64, 256, 176, 2), seed=7)
+    rng = np.random.default_rng(6)
+    seq = np.zeros((3, 160), np.int32)
+    for row, n in enumerate((60, 128, 160)):
+        seq[row, :n] = rng.integers(1, VOCAB, n)
+    target = np.zeros_like(seq)
+    target[:, :-1] = seq[:, 1:]
+    rows = {"seq": seq, "target": target}
+    out = {}
+    for name in ("plain", "flash"):
+        config = _config(max_len=160, num_heads=2, head_dim=128, attention=name)
+        step = jax.jit(jax.value_and_grad(looped.make_loss(config, None), has_aux=True))
+        (loss, aux), grads = step(params, rows, None)
+        names = re.findall(r'loc\("([^"]*)"', step.lower(params, rows, None).as_text(
+            debug_info=True))
+        out[name] = {"loss": float(loss), "exit_ce": np.asarray(aux["exit_ce"]),
+                     "grads": jax.tree_util.tree_map(np.asarray, grads),
+                     "leaves": {leaf for leaf in ("qkv", "rope", "kernel", "out")
+                                if any(f"/attention/{leaf}/" in n for n in names)},
+                     "operands": looped.fit_attrs(config, 3, "cpu")["attention_operands"]}
+    return out
+
+
+def test_heads_of_whole_lane_tiles_are_rotated_in_the_programs(whole_lane_heads):
+    """No ``rope`` leaf where the kernel runs on heads of 128: the programs
+    turn q and k; the plain path still rotates under it. The fit says which."""
+    flash, plain = whole_lane_heads["flash"], whole_lane_heads["plain"]
+    assert flash["leaves"] == {"qkv", "kernel", "out"}
+    assert plain["leaves"] == {"qkv", "rope", "kernel", "out"}
+    assert (flash["operands"], plain["operands"]) == (
+        "in place, rotated in the programs", "plain")
+    narrow = _config(attention="flash")
+    assert looped.fit_attrs(narrow, 3, "cpu")["attention_operands"] == "transposed"
+    assert looped.fit_attrs(_config(attention="auto"), 3, "tpu")["attention_operands"] == (
+        "transposed")
+    assert abs(flash["loss"] - plain["loss"]) < 1e-5
+    np.testing.assert_allclose(flash["exit_ce"], plain["exit_ce"], atol=1e-5)
+
+
+@pytest.mark.parametrize("name", PARAM_NAMES)
+def test_heads_of_whole_lane_tiles_give_every_plain_gradient(whole_lane_heads, name):
+    got = _leaf(whole_lane_heads["flash"]["grads"], name)
+    want = _leaf(whole_lane_heads["plain"]["grads"], name)
+    assert np.linalg.norm(want) > 0
+    assert _rel(got, want) < 1e-4
+
+
 # ---- wrong loops must fail the same comparison ----------------------------
 
 def _wrong_loop(kind: str, params, seq, targets):
